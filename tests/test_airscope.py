@@ -230,6 +230,51 @@ def test_detect_peak_env_override(monkeypatch):
     assert p.flops_per_s == 1e15
     assert p.bytes_per_s == 2e12
     assert p.source == "env"
+    # half an override would leave the other half to be invented
+    monkeypatch.delenv("TPU_AIR_PEAK_BYTES")
+    with pytest.raises(ValueError, match="together"):
+        perf.detect_peak()
+
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+@pytest.mark.parametrize("device, want", [
+    (None, None),  # this host's own CPU backend: no peak, no fraction
+    (_FakeDevice("tpu", "TPU v5 lite"), (197e12, 819e9, "TPU v5 lite")),
+    (_FakeDevice("tpu", "TPU v99"), ValueError),
+    (_FakeDevice("gpu", "H100"), ValueError),
+])
+def test_detect_peak_by_device(monkeypatch, device, want):
+    """A known TPU kind has both peaks from one table; an unknown device
+    raises; the CPU has none — and then the ledger publishes rates but no
+    roofline share."""
+    import jax
+
+    monkeypatch.delenv("TPU_AIR_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("TPU_AIR_PEAK_BYTES", raising=False)
+    if device is not None:
+        monkeypatch.setattr(jax, "devices", lambda *a: [device])
+    if want is ValueError:
+        with pytest.raises(ValueError, match="no peak"):
+            perf.detect_peak()
+        return
+    peak = perf.detect_peak()
+    if want is None:
+        assert peak is None
+        led = PerfLedger(peak)
+        led.record_program("step", ProgramCost(1e9, 1e9), 1.0)
+        snap = led.snapshot()
+        assert snap["peak"] is None
+        assert snap["totals"]["roofline_fraction"] is None
+        assert snap["programs"]["step"]["roofline_fraction"] is None
+        assert snap["totals"]["flops_per_s"] == pytest.approx(1e9)
+        merged = perf.merge_ledger_snapshots([snap, snap])
+        assert merged["totals"]["roofline_fraction"] is None
+    else:
+        assert (peak.flops_per_s, peak.bytes_per_s, peak.source) == want
 
 
 # ---------------------------------------------------------------------------
@@ -479,10 +524,13 @@ def _labels_of(sample):
     return dict(re.findall(r'(\w+)="([^"]*)"', sample[1]))
 
 
-def test_metrics_exposition_parses_line_by_line():
+def test_metrics_exposition_parses_line_by_line(monkeypatch):
     from tpu_air.engine.metrics import EngineMetrics, unregister
     from tpu_air.observability import dashboard
 
+    # a configured peak: off-chip the roofline families are absent
+    monkeypatch.setenv("TPU_AIR_PEAK_FLOPS", "1e12")
+    monkeypatch.setenv("TPU_AIR_PEAK_BYTES", "1e11")
     m = EngineMetrics(name="airscope-expo", num_slots=4)
     try:
         m.observe_gauges(queue_depth=2, slot_occupancy=3,

@@ -132,7 +132,7 @@ print("DEFAULT_INIT_GCS_OK")
 
 
 def test_gcs_on_default_init_path():
-    """VERDICT r2 item 6: single-host ``tpu_air.init()`` runs the control
+    """Single-host ``tpu_air.init()`` runs the control
     plane by default (reference: ray.init() always starts GCS, SURVEY.md
     par.3.6) -- membership observable via tpu_air.nodes(), actors appear in
     the directory, and the wiring survives a daemon restart (heartbeat

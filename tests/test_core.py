@@ -267,7 +267,7 @@ def test_actor_pool_map_unordered(air):
     assert out == [i * i for i in range(6)]
 
 
-# -- oversubscribed actor creation queues (VERDICT r1 #8) --------------------
+# -- oversubscribed actor creation queues ------------------------------------
 
 
 def test_oversubscribed_actor_creation_queues(air):

@@ -234,7 +234,7 @@ def test_chain(air, taxi_like):
     assert out.to_pandas()["z"].max() == pytest.approx(2.0)
 
 
-# -- streaming data plane (VERDICT r1 #6) ------------------------------------
+# -- streaming data plane ----------------------------------------------------
 
 
 def test_shape_ops_never_materialize_on_driver(air, monkeypatch):

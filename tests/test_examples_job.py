@@ -61,7 +61,7 @@ def test_tune_hpo_example():
 
 
 def test_strict_mode_fails_loudly_without_assets(monkeypatch):
-    """VERDICT r2 item 5: --strict must exit nonzero with the REAL error
+    """--strict must exit nonzero with the REAL error
     when assets are missing — never a silent synthetic fallback.  Forced
     offline so the failure is fast and deterministic."""
     import subprocess, sys

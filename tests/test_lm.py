@@ -224,7 +224,7 @@ def test_chunked_head_loss_pads_non_divisible_lengths():
 
 
 def test_lm_trainer_sequence_parallel_fit(air):
-    """VERDICT-style Trainer coherence for SP: long-context training is a
+    """Trainer coherence for SP: long-context training is a
     ScalingConfig field (sequence_parallel=N) through the standard
     fit() -> Result -> Checkpoint contract, not a bespoke script."""
     import numpy as np

@@ -139,7 +139,7 @@ def test_open_arena_missing_compiler_is_none(tmp_path, monkeypatch):
 
 
 # --------------------------------------------------------------------------
-# object spilling (VERDICT r2 item 8; Introduction…ipynb:cc-3 "object spilling")
+# object spilling (Introduction…ipynb:cc-3 "object spilling")
 # --------------------------------------------------------------------------
 
 

@@ -1,4 +1,4 @@
-"""Real-asset test tier (VERDICT r2 item 5 / r3 next-round #8).
+"""Real-asset test tier.
 
 Two lanes over the SAME tests:
 

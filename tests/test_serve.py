@@ -108,7 +108,7 @@ def _kill_replica_process(replica):
 
 
 def test_replica_crash_failover_and_restart(air):
-    """VERDICT r2 item 7: requests keep succeeding after one replica dies
+    """Requests keep succeeding after one replica dies
     mid-traffic; the controller respawns it back to num_replicas."""
     import os
     import time

@@ -4,7 +4,7 @@ Parity oracle: the Rust ``tokenizers`` Unigram model (same algorithm the HF
 fast T5 tokenizer runs), configured with an identical toy vocabulary and
 T5-style Metaspace handling.  This proves the Viterbi segmentation and the
 ModelProto wire round-trip without needing the sentencepiece wheel or
-network access (VERDICT r1 item 5).  When a real FLAN-T5 ``tokenizer.json``
+network access.  When a real FLAN-T5 ``tokenizer.json``
 is present locally the same parity check runs on the real 32k vocab.
 """
 

@@ -224,7 +224,7 @@ def test_gbdt_trainer_w8(air):
 
 def test_tensor_parallel_trainer(air):
     """ScalingConfig(model_parallel=2) shards params over the model axis in
-    the user-facing Trainer (VERDICT r2 missing 3): per-device param bytes
+    the user-facing Trainer: per-device param bytes
     shrink, loss stays finite, and a dp=2 x tp=2 mesh is actually built."""
     ds = make_alpaca_like(32)
     tok, pp = tokenize_preprocessor()
@@ -282,7 +282,7 @@ def test_tensor_parallel_matches_dp_loss(air):
 def test_distributed_gbdt_matches_single_process(air):
     """ScalingConfig(num_workers=4): 4 worker actors each fit ONLY their row
     shard, growing IDENTICAL trees from allreduce-merged histograms (rabit
-    semantics, VERDICT r3 weak #4; reference: 5-worker XGBoostTrainer,
+    semantics; reference: 5-worker XGBoostTrainer,
     Introduction_to_Ray_AI_Runtime.ipynb:cc-32)."""
     rng = np.random.default_rng(3)
     n = 480

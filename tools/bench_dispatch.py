@@ -1,4 +1,4 @@
-"""Task-dispatch microbenchmark (VERDICT r4 #6; docs/NATIVE_RUNTIME.md
+"""Task-dispatch microbenchmark (docs/NATIVE_RUNTIME.md
 deviation 1).
 
 Measures what the Python control half actually costs per task, so the

@@ -286,6 +286,9 @@ def main() -> None:
         from tpu_air.engine import DisaggRouter
         from tpu_air.train import Checkpoint
 
+        # this process holds the JAX backend (the engines above run in it);
+        # the prefill workers ask for no chip lease, so on a chip host the
+        # runtime keeps them on the CPU
         tpu_air.init()
         ckpt = Checkpoint.from_model(model_config=cfg, params=params)
         router = DisaggRouter(
@@ -359,8 +362,8 @@ def main() -> None:
                                      - pre["prefix_tokens_reused"]),
             "cow_copies": post["cow_copies"] - pre["cow_copies"],
             "pages_total": post["pages_total"],
-            "roofline_fraction": round(
-                perf_totals.get("roofline_fraction", 0.0), 6),
+            # None off-chip: a roofline share is a device number
+            "roofline_fraction": perf_totals.get("roofline_fraction"),
             "model_flops_per_s": round(perf_totals.get("flops_per_s", 0.0), 1),
             "goodput_ratio": round(perf_goodput.get("goodput_ratio", 0.0), 4),
             "peak_source": (perf.get("peak") or {}).get("source"),

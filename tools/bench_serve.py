@@ -326,13 +326,43 @@ def _run_phase(interactive_rps, background_rps, duration_s, prompts,
         "arrivals": len(clients),
         "wall_s": round(wall, 3),
         "tokens_per_s": round(total_tokens / wall, 2) if wall else 0.0,
-        "roofline_fraction": round(
-            (perf.get("totals") or {}).get("roofline_fraction", 0.0), 6),
+        # None off-chip: a roofline share is a device number
+        "roofline_fraction": (perf.get("totals") or {}).get(
+            "roofline_fraction"),
         "goodput_ratio": round(
             (perf.get("goodput") or {}).get("goodput_ratio", 0.0), 4),
         "classes": by_class,
         "proxy_counters_delta": _counter_delta(_scrape_admission(), before),
     }
+
+
+def _tiny_lm_checkpoint():
+    """Runs in a pooled worker: seeded LMConfig.tiny weights as a
+    directory checkpoint, and the platform a worker without a chip lease
+    (as the replicas of the rate phases are) computes on."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_air.models.lm import CausalLM, LMConfig
+    from tpu_air.train import Checkpoint
+
+    cfg = LMConfig.tiny()
+    params = CausalLM(cfg).init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32))["params"]
+    return (Checkpoint.from_model(model_config=cfg, params=params),
+            jax.default_backend())
+
+
+def _publish_weights(ckpt, store_root, probe_prompts):
+    """Runs in a pooled worker: publish the checkpoint's own weights to the
+    weight store with a canary probe pinned under them."""
+    from tpu_air.serve import WeightStore
+    from tpu_air.serve.weights import compute_probe
+
+    model, params = ckpt.get_model()
+    return WeightStore(store_root).publish(
+        params, metadata={"bench": True},
+        probe=compute_probe(model, params, probe_prompts, max_new=4))
 
 
 def main():
@@ -350,25 +380,21 @@ def main():
     ap.add_argument("--out", type=str, default=None)
     args = ap.parse_args()
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    import jax.numpy as jnp
     import numpy as np
 
     import tpu_air
     from tpu_air import serve
     from tpu_air.engine import EngineConfig
-    from tpu_air.models.lm import CausalLM, LMConfig
     from tpu_air.observability import tracing
     from tpu_air.observability import watch as watch_mod
     from tpu_air.serve import AdmissionPolicy, EngineDeployment
-    from tpu_air.train import Checkpoint
 
-    cfg = LMConfig.tiny()
-    model = CausalLM(cfg)
-    params = model.init(jax.random.PRNGKey(0),
-                        jnp.ones((1, 8), jnp.int32))["params"]
-    ckpt = Checkpoint.from_model(model_config=cfg, params=params)
+    # This process starts chip-leased replicas, so it computes nothing with
+    # JAX itself (a driver that starts a backend holds the chips): the
+    # checkpoint and the published weights are made in pooled workers.
+    tpu_air.init(num_cpus=4, num_chips=8)
+    ckpt, platform = tpu_air.get(
+        tpu_air.remote(_tiny_lm_checkpoint).remote())
 
     rng = random.Random(args.seed)
     np_rng = np.random.RandomState(args.seed)
@@ -383,7 +409,6 @@ def main():
     # per replica and sheds at 6; batch queues at 6, sheds at 12
     policy = AdmissionPolicy(queue_soft=2.0, queue_high=6.0, queue_hard=12.0)
 
-    tpu_air.init(num_cpus=4, num_chips=8)
     tracing.enable()
     # airwatch rides along for the whole run: serve.run starts the
     # FleetScraper against each phase's deployment, and the cost ledger
@@ -406,7 +431,7 @@ def main():
             "admission": {"queue_soft": policy.queue_soft,
                           "queue_high": policy.queue_high,
                           "queue_hard": policy.queue_hard},
-            "platform": jax.default_backend(),
+            "platform": platform,
         },
     }
     try:
@@ -439,7 +464,6 @@ def main():
         from tpu_air.engine.metrics import merge_snapshots
         from tpu_air.serve import WeightsController, WeightStore
         from tpu_air.serve.proxy import replica_engine_stats
-        from tpu_air.serve.weights import compute_probe
 
         h = serve.run(
             EngineDeployment.options(
@@ -451,9 +475,8 @@ def main():
         _post("/engine", {"prompt": prompts[0], "priority": "batch",
                           "max_new_tokens": args.max_new}, timeout=300.0)
         store = WeightStore(tempfile.mkdtemp(prefix="bench-wstore-"))
-        store.publish(
-            params, metadata={"bench": True},
-            probe=compute_probe(model, params, prompts[:2], max_new=4))
+        tpu_air.get(tpu_air.remote(_publish_weights).remote(
+            ckpt, store.root, prompts[:2]))
         ctl = WeightsController(h, store.root, probe_prompts=prompts[:2],
                                 probe_max_new=4, soak_s=0.3)
         promote_out = {}

@@ -74,7 +74,9 @@ def render_postmortem(data: dict, out=sys.stdout) -> None:
           f"retired={s.get('requests_retired')} "
           f"queue={s.get('queue_depth')}\n")
         if totals:
-            w(f"  roofline_fraction={totals.get('roofline_fraction', 0):.3f} "
+            share = totals.get("roofline_fraction")  # None off-chip
+            w(f"  roofline_fraction="
+              f"{'n/a' if share is None else format(share, '.3f')} "
               f"flops/s={totals.get('flops_per_s', 0):.3e}\n")
         if goodput:
             w(f"  goodput_ratio={goodput.get('goodput_ratio', 0):.3f} "

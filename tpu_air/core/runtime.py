@@ -16,9 +16,14 @@ processes:
   (Overview_of_Ray.ipynb:cc-44, Scaling_batch_inference.ipynb:cc-115).
 
 Scheduling resources are **CPUs and TPU chips** (not GPUs): an actor asking
-for ``num_chips=k`` receives a lease of k physical chip ids, exported to its
-process as ``TPU_AIR_CHIP_IDS`` so the parallel layer can build the matching
-sub-mesh (SURVEY.md §2B raylet row: "placement = sub-mesh assignment").
+for ``num_chips=k`` receives a lease of k physical chip ids.  A chip belongs
+to one process at a time, so a lease is an ownership, not an index: the
+actor's worker is confined to those chips before its JAX backend starts
+(``chips.py``), a worker without a lease computes on the CPU, the driver
+starts no backend while chips are out on lease, and a chip returns to the
+pool only when the process that held it has exited.  On a host without chips
+(the virtual CPU mesh of the tests) a lease indexes the virtual devices
+instead (SURVEY.md §2B raylet row: "placement = sub-mesh assignment").
 
 Workers may themselves submit tasks / create actors (nested ``.remote``):
 control messages ride the worker⇄driver pipe up to the scheduler, and results
@@ -40,7 +45,7 @@ import multiprocessing.connection as mpc
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Tuple
 
-from . import serialization
+from . import chips, serialization
 from .object_store import ObjectRef, ObjectStore, new_object_id
 
 # airtrace propagation (stdlib-only module; the observability package pulls
@@ -228,6 +233,9 @@ class _ActorState:
     resources: Dict[str, float] = field(default_factory=dict)
     dead: bool = False
     pending: int = 0
+    # set once the dead actor's claim is back in the pool (its process has
+    # exited): what a second killer waits for
+    released: threading.Event = field(default_factory=threading.Event)
 
 
 # --------------------------------------------------------------------------
@@ -235,6 +243,7 @@ class _ActorState:
 # --------------------------------------------------------------------------
 
 _worker_ctx: Optional["_WorkerContext"] = None
+_sent_report: Optional[Dict[str, Any]] = None  # last device report shipped
 
 
 class _WorkerContext:
@@ -269,16 +278,25 @@ def _store_result(store: ObjectStore, object_id: str, fn, args, kwargs):
         return False
 
 
-def _send_done(worker_id: int, task_id: str) -> None:
+def _send_done(worker_id: int, task_id: str, leased: bool = False) -> None:
     """Send the task-complete control message, piggybacking any spans this
     worker recorded since the last done (engine spans, nested task spans) so
-    the driver's recorder sees one merged timeline.  The common untraced
-    case ships the plain 3-tuple."""
+    the driver's recorder sees one merged timeline — and, from a worker that
+    holds a chip lease and has started its backend, what JAX sees in here
+    (``chips.device_report``).  The common untraced, lease-less case ships
+    the plain 3-tuple."""
+    global _sent_report
     spans = _tracing.drain_if_any()
-    if spans is None:
+    report = chips.device_report() if leased else None
+    if report == _sent_report:
+        report = None  # unchanged since the last done: a serving replica's
+        # polls are actor tasks, and in steady state they ship nothing extra
+    else:
+        _sent_report = report
+    if spans is None and report is None:
         _worker_ctx.send(("done", worker_id, task_id))
     else:
-        _worker_ctx.send(("done", worker_id, task_id, spans))
+        _worker_ctx.send(("done", worker_id, task_id, spans, report))
 
 
 def _load_payload(store: ObjectStore, spec: dict):
@@ -305,7 +323,10 @@ def _worker_main(
     conn: mpc.Connection,
     driver_env: Optional[Dict[str, str]] = None,
 ):
-    global _worker_ctx
+    global _worker_ctx, _runtime
+    # a forked worker inherits the driver's Runtime object with none of its
+    # threads; a worker is never the driver
+    _runtime = None
     if driver_env:
         # apply the driver's environ as of spawn time (forkserver children
         # otherwise see the env snapshot from forkserver start) — must happen
@@ -320,10 +341,13 @@ def _worker_main(
     # above — re-read both
     _tracing._sync_from_env()
     _faults._sync_from_env()
+    chips.sync_jax_config_from_env()
     store = ObjectStore(store_root)
     _worker_ctx = _WorkerContext(conn, store, worker_id)
     actors: Dict[str, Any] = {}
     failed_actors: Dict[str, _ErrorSentinel] = {}
+    leased = False         # this worker hosts an actor that holds chips
+    pool_confined = False  # a pooled task worker was kept off the chips
     while True:
         try:
             msg = conn.recv()
@@ -334,6 +358,11 @@ def _worker_main(
             return
         spec = msg[1]
         if kind == "task":
+            if not pool_confined:
+                # pooled task workers hold no lease: keep them off the chips
+                # before the first task can start a backend
+                chips.confine([])
+                pool_confined = True
             fn, args, kwargs = _load_payload(store, spec)
             try:
                 args, kwargs = _resolve_args(store, args, kwargs)
@@ -348,15 +377,20 @@ def _worker_main(
             _send_done(worker_id, spec["task_id"])
         elif kind == "actor_create":
             chip_ids = spec.get("chip_ids") or []
+            try:
+                # The lease becomes this process's whole view of the chips
+                # BEFORE anything below can start a backend (unpickling the
+                # class may import jax; its __init__ may compute).
+                chips.confine(chip_ids)
+            except chips.ChipLeaseError as e:
+                failed_actors[spec["actor_id"]] = _ErrorSentinel(
+                    repr(e), traceback.format_exc())
+                store.put(failed_actors[spec["actor_id"]], spec["task_id"])
+                _send_done(worker_id, spec["task_id"])
+                continue
             if chip_ids:
-                # Export the chip lease so the parallel layer (mesh.py) builds
-                # this actor's sub-mesh from exactly these devices.
-                os.environ["TPU_AIR_CHIP_IDS"] = ",".join(str(c) for c in chip_ids)
-            else:
-                # a chip-LESS actor must not inherit a lease from the parent
-                # env (e.g. forked mid-SPMD-fit while the driver holds the
-                # cluster lease in its own environ)
-                os.environ.pop("TPU_AIR_CHIP_IDS", None)
+                leased = True
+                chips.watch_compiles()
             cls, args, kwargs = _load_payload(store, spec)
             args, kwargs = _resolve_args(store, args, kwargs)
             cname = getattr(cls, "__name__", None) or "actor"
@@ -370,7 +404,7 @@ def _worker_main(
                 failed_actors[spec["actor_id"]] = inst
             else:
                 actors[spec["actor_id"]] = inst
-            _send_done(worker_id, spec["task_id"])
+            _send_done(worker_id, spec["task_id"], leased)
         elif kind == "actor_task":
             inst = actors.get(spec["actor_id"])
             _, args, kwargs = _load_payload(store, spec)
@@ -394,7 +428,7 @@ def _worker_main(
                 with _tracing.task_span(name, spec.get("trace_ctx")) as sp:
                     if not _store_result(store, spec["task_id"], method, args, kwargs):
                         sp.set_status("error")
-            _send_done(worker_id, spec["task_id"])
+            _send_done(worker_id, spec["task_id"], leased)
 
 
 # --------------------------------------------------------------------------
@@ -409,6 +443,22 @@ def _kill_quietly(proc) -> None:
         proc.kill()
     except (OSError, ProcessLookupError):
         pass
+
+
+def _stop_process(proc, grace: float) -> bool:
+    """Give ``proc`` ``grace`` seconds to exit by itself, then SIGTERM, then
+    SIGKILL.  True once it is gone — only then may a chip it held go to
+    another process.  One thread per process: the caller owns the reap."""
+    proc.join(timeout=grace)
+    for stop in (proc.terminate, proc.kill):
+        if not proc.is_alive():
+            break
+        try:
+            stop()
+        except (OSError, ProcessLookupError):
+            pass
+        proc.join(timeout=5)
+    return not proc.is_alive()
 
 
 def _sweep_stale_sessions(base: str, spill_base: str = "/var/tmp") -> None:
@@ -488,7 +538,16 @@ class Runtime:
         self.store = ObjectStore(self.store_root, create=True)
         self.num_cpus = num_cpus if num_cpus is not None else max(2, os.cpu_count() or 2)
         if num_chips is None:
-            num_chips = int(os.environ.get("TPU_AIR_NUM_CHIPS", "0") or 0)
+            # an explicit count wins (argument, then env); otherwise the
+            # chips a backend started here would open — counted from the
+            # device nodes, so no backend is left alive in the driver
+            env_chips = os.environ.get("TPU_AIR_NUM_CHIPS")
+            if env_chips:
+                num_chips = int(env_chips)
+            elif chips.accelerator_expected():
+                num_chips = chips.local_chip_count()
+            else:
+                num_chips = 0
         self.num_chips = num_chips
         # Topology for lease SHAPES (docs/MULTIHOST.md §2): chip g lives on
         # host g // chips_per_host.  Single host (the default) degenerates to
@@ -509,6 +568,10 @@ class Runtime:
         # task_id -> trace id, for traced tasks only: lets worker-death
         # sentinels carry the trace id of the request they killed
         self.task_trace: Dict[str, str] = {}
+        # worker_id -> what JAX saw inside that chip-leased worker (latest
+        # chips.device_report, piggybacked on its done messages); outlives
+        # the worker so a caller can read it after fit()/predict() returned
+        self._device_reports: Dict[int, Dict[str, Any]] = {}
         self.queue: List[_TaskSpec] = []
         # Actor creations wait in their own FIFO queue for resources (chip
         # leases especially) instead of spin-waiting in the caller — an
@@ -632,6 +695,14 @@ class Runtime:
                 self._gcs_client = None
                 return None
 
+    def device_reports(self) -> List[Dict[str, Any]]:
+        """What JAX saw inside each chip-leased worker that has started a
+        backend — platform, device kind and count, its lease, its compile
+        seconds and cache traffic (``chips.device_report``) — oldest worker
+        first, dead workers included."""
+        with self.lock:
+            return [dict(r) for r in self._device_reports.values()]
+
     def nodes(self) -> List[Dict]:
         """Cluster membership with heartbeat liveness, from the control plane
         (``ray.nodes()`` analog).  [] when the GCS is unavailable."""
@@ -641,13 +712,14 @@ class Runtime:
     def _pick_ctx(self):
         """fork is fast, but forking after a JAX/XLA backend is live in this
         process inherits dead compiler threadpools → child deadlocks on its
-        first jax op.  Once a backend exists, switch to a preloaded
-        FORKSERVER: the server process imports the heavy module graph once
-        (worker_preload.py — jax/pandas/numpy, no backend init) and children
-        fork from it in ~10ms, vs ~3s of re-imports per spawn worker."""
+        first jax op.  Once a backend exists (a CPU backend: a driver that
+        holds the chips is refused a lease, ``_check_satisfiable``), switch
+        to a preloaded FORKSERVER: the server process imports the
+        heavy module graph once (worker_preload.py — jax/pandas/numpy, no
+        backend init) and children fork from it in ~10ms, vs ~3s of
+        re-imports per spawn worker."""
         if self.mp_ctx.get_start_method() == "fork":
-            xb = sys.modules.get("jax._src.xla_bridge")
-            if xb is not None and getattr(xb, "_backends", None):
+            if chips.backend_live():
                 if self._fs_ctx is None:
                     # NB: the forkserver is a process-global singleton; the
                     # preload applies to any other forkserver user in this
@@ -727,6 +799,12 @@ class Runtime:
             if len(msg) > 3 and msg[3]:
                 _tracing.recorder().record_many(msg[3])
             with self.lock:
+                if len(msg) > 4 and msg[4]:
+                    self._device_reports[wid] = dict(
+                        msg[4], worker_id=wid, actor_id=worker.actor_id)
+                    while len(self._device_reports) > 256:
+                        self._device_reports.pop(
+                            next(iter(self._device_reports)))
                 res = self.task_resources.pop(task_id, None)
                 self.task_worker.pop(task_id, None)
                 self.task_trace.pop(task_id, None)
@@ -783,26 +861,24 @@ class Runtime:
                         ),
                         task_id,
                     )
-            dead_actor = None
+            dead_actor = claim = st = None
             if worker.actor_id and worker.actor_id in self.actors:
                 st = self.actors[worker.actor_id]
-                # st.dead means kill_actor already released the claim — a
-                # killed worker's pipe-close lands here too, and releasing
-                # twice inflates avail until free_chips.pop underflows
+                # st.dead means kill_actor owns the claim — a killed
+                # worker's pipe-close lands here too, and releasing twice
+                # inflates avail until free_chips.pop underflows
                 if not st.dead:
                     st.dead = True
                     dead_actor = worker.actor_id
                     if st.name:
                         self.named_actors.pop(st.name, None)
-                    # release the FULL claim (cpu + chip), exactly like
-                    # kill_actor — chip avail comes back via st.resources,
-                    # the physical ids via free_chips
-                    self._release(st.resources)
-                    st.resources = {}
-                    self.free_chips.extend(st.chip_ids)
-                    st.chip_ids = []
+                    claim = self._take_claim(st)
             self.workers.pop(worker.worker_id, None)
         if dead_actor:
+            # the pipe closes before the process is gone; the FULL claim
+            # (cpu + chip) comes back once it is, exactly like kill_actor
+            self._return_claim(
+                claim, _stop_process(worker.proc, grace=2), st)
             self._gcs("mark_actor_dead", dead_actor)
         # flight recorder (outside the lock: dump() scrapes snapshot()/
         # engine_stats(), which re-take it); no-op unless
@@ -879,6 +955,32 @@ class Runtime:
         for c in ids:
             self.free_chips.remove(c)
         return ids
+
+    @staticmethod
+    def _take_claim(st: _ActorState) -> Tuple[Dict[str, float], List[int]]:
+        """Detach a dying actor's claim; whoever takes it returns it with
+        ``_return_claim`` after the actor's process has exited.  Caller
+        holds the lock."""
+        claim = (st.resources, st.chip_ids)
+        st.resources, st.chip_ids = {}, []
+        return claim
+
+    def _return_claim(self, claim, process_gone: bool,
+                      st: _ActorState) -> None:
+        """A chip is free when the process that held it has exited, not
+        before: the next holder's backend cannot open a chip that a dying
+        process still has open.  A process that survived SIGKILL keeps its
+        chips out of the pool."""
+        resources, chip_ids = claim
+        if chip_ids and not process_gone:
+            print(f"tpu_air: worker holding chips {chip_ids} did not exit; "
+                  "they stay out of the pool", file=sys.stderr)
+            resources = {k: v for k, v in resources.items() if k != "chip"}
+            chip_ids = []
+        with self.lock:
+            self._release(resources)
+            self.free_chips.extend(chip_ids)
+        st.released.set()
 
     def _acquire(self, res: Dict[str, float]):
         for k, v in res.items():
@@ -1022,6 +1124,12 @@ class Runtime:
                     f"resource request {res} exceeds cluster total {total}"
                 )
         nchips = int(res.get("chip", 0))
+        if nchips and chips.accelerator_expected() and chips.backend_live():
+            raise TpuAirError(
+                "this process has started a JAX backend, so it holds the "
+                "host's chips and no worker can be given one: keep JAX "
+                "computation out of the driver, or run without tpu_air "
+                "workers")
         if nchips > self.chips_per_host and nchips % self.chips_per_host != 0:
             raise TpuAirError(
                 f"chip lease of {nchips} spans hosts and must be a multiple "
@@ -1043,6 +1151,11 @@ class Runtime:
             _faults.perturb(
                 "runtime.task", key=getattr(fn, "__name__", "") or "")
         self._check_satisfiable(resources)
+        if resources.get("chip") and chips.accelerator_expected():
+            raise TpuAirError(
+                "a task cannot hold a chip: tasks run in pooled workers, "
+                "which stay on the CPU, and a chip belongs to one process "
+                "until it exits — ask for num_chips on an actor")
         task_id = new_object_id()
         payload, payload_ref = self._pack_payload((fn, args, kwargs))
         spec = _TaskSpec(task_id, payload, payload_ref, resources,
@@ -1457,26 +1570,31 @@ class Runtime:
                 self._notify_objects()
                 return
             st = self.actors.get(actor_id)
-            if st is None or st.dead:  # already released (double-kill / crash)
+            if st is None:
                 return
-            st.dead = True
-            if st.name:
-                self.named_actors.pop(st.name, None)
-            self._release(st.resources)
-            st.resources = {}
-            self.free_chips.extend(st.chip_ids)
-            st.chip_ids = []
-            worker = st.worker
-            worker.alive = False
-            self.workers.pop(worker.worker_id, None)
+            owner = not st.dead
+            if owner:
+                st.dead = True
+                if st.name:
+                    self.named_actors.pop(st.name, None)
+                claim = self._take_claim(st)
+                worker = st.worker
+                worker.alive = False
+                self.workers.pop(worker.worker_id, None)
+        if not owner:
+            # double-kill / crash: someone else owns the claim and is
+            # waiting for the process to exit.  Wait with them, so that for
+            # every caller "killed" means "its chips are free" —
+            # serve.shutdown() racing a watcher's kill must not hand the
+            # next deployment a shrunken pool.
+            st.released.wait(timeout=15)
+            return
         self._gcs("mark_actor_dead", actor_id)
         try:
             worker.conn.send(("shutdown",))
         except OSError:
             pass
-        worker.proc.join(timeout=2)
-        if worker.proc.is_alive():
-            worker.proc.terminate()
+        self._return_claim(claim, _stop_process(worker.proc, grace=2), st)
         self._schedule()  # freed chips/cpus may place queued actors
 
     # -- object plane ---------------------------------------------------------
@@ -1545,9 +1663,7 @@ class Runtime:
             except OSError:
                 pass
         for w in workers:
-            w.proc.join(timeout=1)
-            if w.proc.is_alive():
-                w.proc.terminate()
+            _stop_process(w.proc, grace=1)
         if self._gcs_heartbeat is not None:
             self._gcs_heartbeat.stop()
         # airlint: disable=CC001 — shutdown-time teardown: _gcs() holds
@@ -1558,6 +1674,7 @@ class Runtime:
             self._gcs_client = None
         if self._gcs_proc is not None:
             self._gcs_proc.kill()
+            self._gcs_proc.wait()
             self._gcs_proc = None
         self.store.destroy()
 
@@ -1567,6 +1684,28 @@ class Runtime:
 # --------------------------------------------------------------------------
 
 _runtime: Optional[Runtime] = None
+
+#: where compiled programs are kept when the environment names no place: one
+#: fixed, git-ignored directory in the checkout.  The path is part of the
+#: cache key, so it is never a temporary name, a pid or a time.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
+
+
+def place_compile_cache() -> str:
+    """The program's one compile-cache rule: ``JAX_COMPILATION_CACHE_DIR``
+    stays as the environment set it; unset, it becomes
+    :data:`DEFAULT_COMPILE_CACHE`.  ``init()`` runs this before any worker
+    is spawned, and workers inherit it through the environment they are
+    handed.  Returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.environ["JAX_COMPILATION_CACHE_DIR"] = DEFAULT_COMPILE_CACHE
+    jax = sys.modules.get("jax")
+    if jax is not None:  # imported before the variable was set
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def init(
@@ -1593,16 +1732,14 @@ def init(
         if include_dashboard:  # honor an explicit request on reinit too
             _start_dashboard(dashboard_port)
         return _runtime
+    place_compile_cache()
     # multi-host rendezvous first (no-op unless the TPU_AIR_COORDINATOR env
-    # contract is set): after this, jax sees the global device list and this
-    # process knows its rank (SURVEY.md §3.6 "initialize the multi-host
-    # runtime on every host")
-    try:
-        from tpu_air.parallel import distributed as _dist
+    # contract is set, and then a failure is the caller's to see): after
+    # this, jax sees the global device list and this process knows its rank
+    # (SURVEY.md §3.6 "initialize the multi-host runtime on every host")
+    from tpu_air.parallel import distributed as _dist
 
-        _dist.ensure_initialized()
-    except Exception as e:  # rendezvous failure must not mask the local path
-        print(f"tpu_air: multi-host rendezvous failed: {e}", file=sys.stderr)
+    _dist.ensure_initialized()
     _runtime = Runtime(num_cpus=num_cpus, num_chips=num_chips, **kwargs)
     if include_dashboard is None:
         include_dashboard = os.environ.get("TPU_AIR_DASHBOARD", "0") == "1"

@@ -98,10 +98,16 @@ class MeshEngine(InferenceEngine):
             chips = rt.lease_chips(n, timeout=lease_timeout)
             self._lease = chips
             self._runtime = rt
-            # lease ids index the global device list; wrap for CPU test
-            # meshes whose virtual chip count exceeds the local platform
+            # a driver-level lease indexes this process's device list
             all_devs = jax.devices()
-            return [all_devs[i % len(all_devs)] for i in chips]
+            beyond = [i for i in chips if i >= len(all_devs)]
+            if beyond:
+                rt.release_chips(chips)
+                self._lease = None
+                raise ValueError(
+                    f"lease {list(chips)} names device(s) {beyond}; this "
+                    f"process sees {len(all_devs)}")
+            return [all_devs[i] for i in chips]
         devs = visible_devices()
         if len(devs) < n:
             raise ValueError(

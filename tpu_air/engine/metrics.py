@@ -33,6 +33,7 @@ from tpu_air.observability.perf import (
     PerfLedger,
     ProgramCost,
     cumulative_from_summary,
+    detect_peak,
     merge_ledger_snapshots,
     merge_summaries,
 )
@@ -80,7 +81,7 @@ class EngineMetrics:
         self._step_h = Histogram()
         self._token_stamps: Deque[Any] = deque()  # (t, n) for tokens/s
         # roofline + goodput accumulator (engine records program costs)
-        self.ledger = PerfLedger()
+        self.ledger = PerfLedger(detect_peak())
         # paged-KV gauges (empty for slab engines — snapshot shape is then
         # unchanged from the slab era)
         self.kvpool: Dict[str, Any] = {}
@@ -597,20 +598,23 @@ def prometheus_lines(snapshots: Dict[str, Dict[str, Any]] = None) -> list:
         perf = snap.get("perf") or {}
         totals = perf.get("totals") or {}
         if totals.get("seconds"):
-            b.raw("tpu_air_engine_roofline_fraction",
-                  f"tpu_air_engine_roofline_fraction{tag} "
-                  f"{totals['roofline_fraction']:.6f}")
+            # roofline families only where the engine's device has a peak
+            # (perf.detect_peak): a CPU run publishes rates, not shares
+            if totals["roofline_fraction"] is not None:
+                b.raw("tpu_air_engine_roofline_fraction",
+                      f"tpu_air_engine_roofline_fraction{tag} "
+                      f"{totals['roofline_fraction']:.6f}")
+                for kind, p in sorted((perf.get("programs") or {}).items()):
+                    b.raw("tpu_air_engine_program_roofline_fraction",
+                          f"tpu_air_engine_program_roofline_fraction"
+                          f'{{engine="{label}",program="{kind}"}} '
+                          f"{p['roofline_fraction']:.6f}")
             b.raw("tpu_air_engine_flops_per_s",
                   f"tpu_air_engine_flops_per_s{tag} "
                   f"{totals['flops_per_s']:.6g}")
             b.raw("tpu_air_engine_hbm_bytes_per_s",
                   f"tpu_air_engine_hbm_bytes_per_s{tag} "
                   f"{totals['bytes_per_s']:.6g}")
-            for kind, p in sorted((perf.get("programs") or {}).items()):
-                b.raw("tpu_air_engine_program_roofline_fraction",
-                      f"tpu_air_engine_program_roofline_fraction"
-                      f'{{engine="{label}",program="{kind}"}} '
-                      f"{p['roofline_fraction']:.6f}")
         goodput = perf.get("goodput") or {}
         if goodput.get("total"):
             b.raw("tpu_air_engine_goodput_ratio",

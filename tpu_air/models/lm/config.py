@@ -32,7 +32,7 @@ class LMConfig:
     tie_embeddings: bool = True
     # "auto" picks per-trace by sequence length: dense below
     # flash_min_seq_len, the Pallas flash kernel at/above it (measured v5e
-    # crossover — BASELINE.md kernel table).  "ring" stays explicit: it
+    # crossover — docs/KERNELS.md).  "ring" stays explicit: it
     # needs a sequence mesh axis.
     attention: str = "auto"           # auto | dense | flash | ring
     flash_min_seq_len: int = 1024

@@ -228,7 +228,7 @@ class CausalSelfAttention(nn.Module):
         if impl == "auto":
             # trace-time shape dispatch: the einsum path wins short
             # sequences, the Pallas kernel wins at/above the measured
-            # crossover (no user flag — VERDICT r3 weak #2); off-TPU and
+            # crossover (no user flag); off-TPU and
             # tile-degenerate shapes stay dense (interpret-mode flash and
             # 1-wide tiles are both perf cliffs)
             from tpu_air.ops.flash_attention import auto_dispatch_ok

@@ -39,7 +39,7 @@ class T5Config:
     # Attention dispatch.  ``attention_impl`` picks per-call at TRACE time:
     # * "auto"   — einsum below ``flash_min_seq_len``, Pallas flash at or
     #   above it (the measured v5e crossover: dense wins at 512, flash is
-    #   3.5-5x at >=2048 — BASELINE.md kernel table); no user flag needed.
+    #   3.5-5x at >=2048 — docs/KERNELS.md); no user flag needed.
     # * "einsum" — always the XLA dense path.
     # * "flash"  — always the Pallas kernel where eligible.
     # Flash is only eligible off the cached-decode path with structured

@@ -20,9 +20,9 @@ Three pieces, each usable alone:
   not from per-request token counts.
 
 * :class:`PerfLedger` — accumulates ``(cost, seconds)`` per program kind
-  into achieved flops/s and bytes/s, a roofline fraction against a
-  detected-or-configured peak (:func:`detect_peak` — CPU fallback
-  constants keep tier-1 meaningful everywhere), and a goodput split of
+  into achieved flops/s and bytes/s, a roofline fraction against the
+  device's peak (:func:`detect_peak` — no peak on the CPU, so no fraction
+  there: a roofline share is a device number), and a goodput split of
   emitted tokens into useful vs. wasted work (shed-after-prefill,
   re-prefilled-on-cache-miss, dead-stream; spec-decode rejections plug in
   as just another category).
@@ -281,33 +281,19 @@ def exemplar_trace_id(summary: Dict[str, Any],
 
 # -- peak detection ----------------------------------------------------------
 
-# bf16 peak FLOPs/s and HBM bytes/s per chip by PJRT device_kind (public
-# spec sheets; same tables bench.py steers its on-chip headlines with)
-_PEAK_FLOPS: Dict[str, float] = {
-    "TPU v3": 123e12,
-    "TPU v4": 275e12,
-    "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5": 459e12,
-    "TPU v5p": 459e12,
-    "TPU v6 lite": 918e12,
-    "TPU v6e": 918e12,
-    "TPU7x": 2307e12,
+# (bf16 peak FLOP/s, HBM bytes/s) of one chip by PJRT device_kind, from the
+# public spec sheets (Google Cloud TPU documentation, system architecture
+# pages).  One table, so a kind has both numbers or neither.
+_PEAKS: Dict[str, tuple] = {
+    "TPU v3": (123e12, 900e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v5p": (459e12, 2765e9),
+    "TPU v6 lite": (918e12, 1640e9),
+    "TPU v6e": (918e12, 1640e9),
 }
-_PEAK_HBM_BYTES: Dict[str, float] = {
-    "TPU v3": 900e9,
-    "TPU v4": 1228e9,
-    "TPU v5 lite": 819e9,
-    "TPU v5e": 819e9,
-    "TPU v5p": 2765e9,
-    "TPU v6 lite": 1640e9,
-    "TPU v6e": 1640e9,
-}
-# CPU fallback: a nominal desktop-class core complex (placeholder so the
-# roofline fraction is nonzero and stable in CPU tier-1/bench runs; the
-# absolute value is NOT a hardware claim — the `source` field says so)
-_CPU_PEAK_FLOPS = 5e11
-_CPU_PEAK_BYTES = 5e10
 
 
 @dataclass(frozen=True)
@@ -316,39 +302,35 @@ class PeakSpec:
 
     flops_per_s: float
     bytes_per_s: float
-    source: str  # "env" | device_kind | "cpu-fallback"
+    source: str  # "env" | the device_kind table key
 
 
-def detect_peak() -> PeakSpec:
-    """Resolve the peak spec: env overrides (``TPU_AIR_PEAK_FLOPS``,
-    ``TPU_AIR_PEAK_BYTES``) win; otherwise the accelerator's device_kind
-    table; otherwise CPU fallback constants."""
+def detect_peak() -> Optional[PeakSpec]:
+    """The peak of the device this process computes on.  Both env overrides
+    together (``TPU_AIR_PEAK_FLOPS`` and ``TPU_AIR_PEAK_BYTES``) win; a TPU
+    is looked up by ``device_kind`` and a kind the tables do not hold is an
+    error, not a default; the CPU has no peak here (None), so the ledger
+    publishes no roofline fraction from a CPU run."""
     env_f = os.environ.get("TPU_AIR_PEAK_FLOPS")
     env_b = os.environ.get("TPU_AIR_PEAK_BYTES")
     if env_f or env_b:
-        return PeakSpec(
-            flops_per_s=float(env_f) if env_f else _CPU_PEAK_FLOPS,
-            bytes_per_s=float(env_b) if env_b else _CPU_PEAK_BYTES,
-            source="env",
-        )
-    kind = ""
-    try:
-        import jax
+        if not (env_f and env_b):
+            raise ValueError(
+                "set TPU_AIR_PEAK_FLOPS and TPU_AIR_PEAK_BYTES together")
+        return PeakSpec(float(env_f), float(env_b), source="env")
+    import jax
 
-        dev = jax.devices()[0]
-        if dev.platform == "tpu":
-            kind = dev.device_kind
-    except Exception:  # noqa: BLE001 — no backend at all: fall back
-        kind = ""
-    if kind:
-        for k in sorted(_PEAK_FLOPS, key=len, reverse=True):
-            if kind.startswith(k):
-                return PeakSpec(
-                    flops_per_s=_PEAK_FLOPS[k],
-                    bytes_per_s=_PEAK_HBM_BYTES.get(k, _CPU_PEAK_BYTES),
-                    source=k,
-                )
-    return PeakSpec(_CPU_PEAK_FLOPS, _CPU_PEAK_BYTES, source="cpu-fallback")
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return None
+    for k in sorted(_PEAKS, key=len, reverse=True):
+        if dev.device_kind.startswith(k):
+            return PeakSpec(*_PEAKS[k], source=k)
+    raise ValueError(
+        f"no peak FLOP/s and bytes/s on record for device kind "
+        f"{dev.device_kind!r} ({dev.platform}); add it to "
+        "observability/perf.py with its source, or set TPU_AIR_PEAK_FLOPS "
+        "and TPU_AIR_PEAK_BYTES")
 
 
 # -- analytic cost model -----------------------------------------------------
@@ -491,9 +473,11 @@ class PerfLedger:
     """Per-engine accumulator: program costs → achieved rates + roofline
     fraction; token categories → goodput ratio.  Thread-safe."""
 
-    def __init__(self, peak: Optional[PeakSpec] = None):
+    def __init__(self, peak: Optional[PeakSpec]):
+        """``peak=None``: no device peak (a CPU run) — rates are still
+        accumulated, every ``roofline_fraction`` is None."""
         self._lock = threading.Lock()
-        self._peak = peak or detect_peak()
+        self._peak = peak
         self._programs: Dict[str, Dict[str, float]] = {}
         self._tokens: Dict[str, int] = {}
 
@@ -532,9 +516,17 @@ class PerfLedger:
             programs: Dict[str, Any] = {}
             tot_flops = tot_bytes = tot_seconds = 0.0
             tot_ideal = 0.0
+
+            def fraction(ideal: float, secs: float) -> Optional[float]:
+                # no device peak (a CPU run): no roofline share
+                if self._peak is None:
+                    return None
+                return ideal / secs if secs else 0.0
+
             for kind, p in sorted(self._programs.items()):
                 secs = p["seconds"]
-                ideal = self._ideal_seconds(p["flops"], p["bytes"])
+                ideal = (self._ideal_seconds(p["flops"], p["bytes"])
+                         if self._peak else 0.0)
                 programs[kind] = {
                     "calls": int(p["calls"]),
                     "flops": p["flops"],
@@ -543,7 +535,7 @@ class PerfLedger:
                     "tokens": int(p["tokens"]),
                     "flops_per_s": p["flops"] / secs if secs else 0.0,
                     "bytes_per_s": p["bytes"] / secs if secs else 0.0,
-                    "roofline_fraction": ideal / secs if secs else 0.0,
+                    "roofline_fraction": fraction(ideal, secs),
                 }
                 tot_flops += p["flops"]
                 tot_bytes += p["bytes"]
@@ -554,7 +546,7 @@ class PerfLedger:
                          if cat != "useful")
             total = useful + wasted
             return {
-                "peak": {
+                "peak": None if self._peak is None else {
                     "flops_per_s": self._peak.flops_per_s,
                     "bytes_per_s": self._peak.bytes_per_s,
                     "source": self._peak.source,
@@ -568,8 +560,7 @@ class PerfLedger:
                     if tot_seconds else 0.0,
                     "bytes_per_s": tot_bytes / tot_seconds
                     if tot_seconds else 0.0,
-                    "roofline_fraction": tot_ideal / tot_seconds
-                    if tot_seconds else 0.0,
+                    "roofline_fraction": fraction(tot_ideal, tot_seconds),
                 },
                 "goodput": {
                     **{cat: int(n) for cat, n in sorted(self._tokens.items())},
@@ -588,11 +579,10 @@ def merge_ledger_snapshots(snaps: Iterable[Dict[str, Any]]
     snaps = [s for s in snaps if s]
     if not snaps:
         return {}
-    peak = snaps[0].get("peak") or {
-        "flops_per_s": _CPU_PEAK_FLOPS, "bytes_per_s": _CPU_PEAK_BYTES,
-        "source": "cpu-fallback"}
-    ledger = PerfLedger(PeakSpec(peak["flops_per_s"], peak["bytes_per_s"],
-                                 peak.get("source", "merged")))
+    peak = snaps[0].get("peak")
+    ledger = PerfLedger(peak and PeakSpec(
+        peak["flops_per_s"], peak["bytes_per_s"],
+        peak.get("source", "merged")))
     for s in snaps:
         for kind, p in (s.get("programs") or {}).items():
             ledger.record_program(

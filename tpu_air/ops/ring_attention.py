@@ -53,12 +53,15 @@ def ring_attention(
     causal: bool = False,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
+    interpret: Optional[bool] = None,
 ):
     """Attention over sequence-sharded q/k/v inside shard_map/pmap.
 
     ``q/k/v``: (batch·heads, L_local, head_dim) — the local sequence shard.
     Must run inside a mapped context where ``axis_name`` is a mesh axis of
     size P; returns the local (batch·heads, L_local, head_dim) output shard.
+    ``interpret`` goes to the flash kernel as given (None: interpret mode
+    off-TPU, like its siblings).
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -75,7 +78,8 @@ def ring_attention(
         # Keeping ``bias=None`` is load-bearing — the bias path falls back
         # to the dense-recompute VJP, while these branches keep the
         # blockwise Pallas BACKWARD (O(L) memory) on the training path.
-        kw = dict(scale=scale, block_q=block_q, block_k=block_k)
+        kw = dict(scale=scale, block_q=block_q, block_k=block_k,
+                  interpret=interpret)
 
         def diagonal(q, kk, vv):
             return flash_attention_with_lse(q, kk, vv, causal=True, **kw)
@@ -111,7 +115,8 @@ def ring_attention(
 
 def ring_attention_sharded(q, k, v, mesh, *, axis_name: str = "sequence",
                            causal: bool = False, scale=None,
-                           block_q: Optional[int] = None, block_k: Optional[int] = None):
+                           block_q: Optional[int] = None, block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None):
     """Convenience wrapper: shard (bh, L, d) arrays over ``axis_name`` of
     ``mesh`` and run ring attention via shard_map."""
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -121,7 +126,7 @@ def ring_attention_sharded(q, k, v, mesh, *, axis_name: str = "sequence",
     spec = P(None, axis_name, None)
     body = functools.partial(
         ring_attention, axis_name=axis_name, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, interpret=interpret,
     )
     fn = shard_map_unchecked(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec

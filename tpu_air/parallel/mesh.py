@@ -20,10 +20,11 @@ sub-meshes of the same slice (§7 hard-part 1).
 from __future__ import annotations
 
 import math
-import os
-from typing import List, Optional, Sequence, Tuple
+from typing import Optional, Sequence
 
 import numpy as np
+
+from tpu_air.core.chips import ChipLeaseError, confined, leased_chip_ids
 
 
 def _jax():
@@ -32,25 +33,31 @@ def _jax():
     return jax
 
 
-def leased_chip_ids() -> Optional[List[int]]:
-    """Chip ids granted to this process by the scheduler, or None (all)."""
-    raw = os.environ.get("TPU_AIR_CHIP_IDS")
-    if not raw:
-        return None
-    return [int(x) for x in raw.split(",") if x != ""]
-
-
 def visible_devices():
-    """Devices this process may use: the leased subset, else all devices."""
-    jax = _jax()
-    devs = jax.devices()
+    """Devices this process may use: its lease, else all devices.
+
+    A worker on a chip host was confined to its lease before its backend
+    started (``core/chips.py``), so every device it sees is its own.
+    Anywhere else — the virtual CPU mesh, or a driver-level lease over the
+    global device list of a multi-host run — the lease indexes
+    ``jax.devices()``.  A lease this process cannot honour is an error,
+    never another device."""
+    devs = _jax().devices()
     lease = leased_chip_ids()
     if lease is None:
         return list(devs)
-    # Lease ids index the global device list; tolerate leases larger than the
-    # local platform (CPU test meshes) by wrapping.
-    n = len(devs)
-    return [devs[i % n] for i in lease]
+    if confined():
+        if len(devs) != len(lease):
+            raise ChipLeaseError(
+                f"confined to chips {lease} but the backend shows "
+                f"{len(devs)} device(s)")
+        return list(devs)
+    beyond = [i for i in lease if not 0 <= i < len(devs)]
+    if beyond:
+        raise ChipLeaseError(
+            f"lease {lease} names device(s) {beyond}; this process sees "
+            f"{len(devs)}")
+    return [devs[i] for i in lease]
 
 
 def topology() -> dict:

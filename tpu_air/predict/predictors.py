@@ -24,6 +24,19 @@ import pandas as pd
 from tpu_air.predict.predictor import Predictor
 
 
+def _checkpoint_tokenizer(checkpoint, tokenizer):
+    """The tokenizer a generative predictor decodes with: the instance
+    given, else the checkpoint's (loaded as the class given, if one was).
+    A checkpoint trained on token ids carries none; generation then returns
+    id strings."""
+    if tokenizer is not None and not isinstance(tokenizer, type):
+        return tokenizer
+    try:
+        return checkpoint.get_tokenizer(tokenizer)
+    except FileNotFoundError:
+        return None
+
+
 class T5GenerativePredictor(Predictor):
     """Batched text generation from a T5 checkpoint (predictor.py:14-106 analog)."""
 
@@ -56,11 +69,8 @@ class T5GenerativePredictor(Predictor):
             params = jax.tree_util.tree_map(
                 lambda x: x.astype(jnp.dtype(dtype)) if hasattr(x, "astype") else x, params
             )
-        tok = tokenizer
-        if tok is None or isinstance(tok, type):
-            loaded = checkpoint.get_tokenizer(tok if isinstance(tok, type) else None)
-            tok = loaded
-        return cls(model, params, tok, checkpoint.get_preprocessor())
+        return cls(model, params, _checkpoint_tokenizer(checkpoint, tokenizer),
+                   checkpoint.get_preprocessor())
 
     def _predict_numpy(
         self,
@@ -125,15 +135,8 @@ class LMGenerativePredictor(Predictor):
                 lambda x: x.astype(jnp.dtype(dtype)) if hasattr(x, "astype") else x,
                 params,
             )
-        tok = tokenizer
-        if tok is None or isinstance(tok, type):
-            try:
-                tok = checkpoint.get_tokenizer(tok if isinstance(tok, type) else None)
-            except FileNotFoundError:
-                # token-id corpora (LMTrainer's input) train without a
-                # tokenizer; generation then returns id strings
-                tok = None
-        return cls(model, params, tok, checkpoint.get_preprocessor())
+        return cls(model, params, _checkpoint_tokenizer(checkpoint, tokenizer),
+                   checkpoint.get_preprocessor())
 
     def _predict_numpy(
         self,

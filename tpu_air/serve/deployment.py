@@ -192,7 +192,7 @@ class _Replica:
 
 class DeploymentHandle:
     """Least-loaded handle over a deployment's live replica actors, with
-    failure semantics (VERDICT r2 item 7; reference: "a managed group of Ray
+    failure semantics (reference: "a managed group of Ray
     actors that ... handle requests load-balanced across them", cc-79):
 
     * replica choice is LEAST-LOADED over the engine gauges the last

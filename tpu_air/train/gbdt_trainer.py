@@ -313,7 +313,7 @@ def _distributed_gbdt_loop(config, world, label_column, num_boost_round,
                            objective, is_classif) -> None:
     """ScalingConfig(num_workers=N) path: N worker actors, each seeing ONLY
     its row shard, growing IDENTICAL trees from allreduce-merged histograms
-    (rabit semantics — VERDICT r3 weak #4; reference trains 5 rabit
+    (rabit semantics; reference trains 5 rabit
     workers).  Rank identity is asserted at every checkpoint round, so
     divergence is a hard training error, not silent skew."""
     import tpu_air

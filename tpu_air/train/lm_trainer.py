@@ -169,9 +169,8 @@ def _lm_tp_loop(config, args, model_config, preprocessor, mp) -> None:
     a (data, model) mesh with the LM sharding rules
     (parallel/sharding.lm_param_spec) — params and optimizer state live
     1/N-per-device on the ``model`` axis, XLA inserts the TP collectives.
-    The param-sharding story for the LM family beyond replication
-    (VERDICT r3 weak #7): the long-context SP axis scales CONTEXT, this
-    axis scales the MODEL."""
+    The param-sharding story for the LM family beyond replication: the
+    long-context SP axis scales CONTEXT, this axis scales the MODEL."""
     import jax
     import jax.numpy as jnp
     from functools import partial

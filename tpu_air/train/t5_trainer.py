@@ -57,15 +57,20 @@ class TrainingArguments:
             self.per_device_eval_batch_size = self.per_device_train_batch_size
 
 
-def collate(batch_df, keys, seq_len: Optional[int] = None) -> Dict[str, np.ndarray]:
-    """DataFrame of per-row token lists → stacked int32 arrays."""
+def collate(batch_df, keys,
+            seq_lens: Optional[Dict[str, int]] = None) -> Dict[str, np.ndarray]:
+    """DataFrame of per-row token lists → stacked int32 arrays.  With
+    ``seq_lens`` every column must have the length recorded for it (one
+    compiled step serves the run; the decoder side may be shorter than the
+    encoder side, as in the W1 shape: encoder 512, decoder 128)."""
     out = {}
     for k in keys:
         col = [np.asarray(v, dtype=np.int32) for v in batch_df[k]]
         out[k] = np.stack(col)
-        if seq_len is not None and out[k].shape[1] != seq_len:
+        if seq_lens is not None and out[k].shape[1] != seq_lens[k]:
             raise ValueError(
-                f"column {k} has seq len {out[k].shape[1]}, expected {seq_len}"
+                f"column {k} has seq len {out[k].shape[1]}, expected "
+                f"{seq_lens[k]}"
             )
     return out
 
@@ -171,7 +176,8 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
     # -- params -------------------------------------------------------------
     sample = next(train_ds.iter_batches(batch_size=2, batch_format="pandas"))
     sample_batch = collate(sample, keys)
-    seq_len = sample_batch["input_ids"].shape[1]
+    seq_lens = {k: v.shape[1] for k, v in sample_batch.items()}
+    seq_len = seq_lens["input_ids"]
 
     resume_dir = config.get("resume_from_checkpoint")
     pretrained = config.get("pretrained_params")
@@ -197,7 +203,7 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
 
     # Per-device param residency: with tp>1 the model-sharded leaves occupy
     # 1/tp of their bytes on each chip — the property that lets T5-XL fit
-    # where replication cannot (VERDICT r2 missing 3).  Reported so tests and
+    # where replication cannot.  Reported so tests and
     # users can verify the shrink actually happened.
     leaves = jax.tree_util.tree_leaves(params)
     params_bytes_total = int(sum(x.nbytes for x in leaves))
@@ -209,6 +215,10 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
             for x in leaves
         )
     )
+
+    # distinct devices that hold the parameters — with the batch's below,
+    # the proof that a dp mesh is really spread over the lease
+    param_devices = len({d for x in leaves for d in x.sharding.device_set})
 
     # -- steps --------------------------------------------------------------
     def loss_from_batch(p, batch, dropout_rng):
@@ -286,7 +296,7 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
         ):
             if len(batch_df) < global_bs:
                 continue
-            batch = put_batch(collate(batch_df, keys, seq_len))
+            batch = put_batch(collate(batch_df, keys, seq_lens))
             params, opt_state, loss, rng = train_step(params, opt_state, batch, rng)
             losses.append(loss)
             tokens += global_bs * seq_len
@@ -310,6 +320,8 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
             ),
             "params_bytes_total": params_bytes_total,
             "params_bytes_per_device": params_bytes_per_device,
+            "param_devices": param_devices,
+            "batch_devices": len(batch_sharding.device_set),
         }
 
         if eval_ds is not None and args.evaluation_strategy == "epoch":
@@ -329,7 +341,7 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
                         )
                     batch_df = pd.concat([batch_df, pad_rows], ignore_index=True)
                 parts.append(
-                    eval_step(params, put_batch(collate(batch_df, keys, seq_len)))
+                    eval_step(params, put_batch(collate(batch_df, keys, seq_lens)))
                 )
             # one post-loop sync keeps eval dispatch pipelined (airlint JX004)
             tot = sum(float(loss) * int(ntok) for loss, ntok in parts)  # airlint: disable=JX004 — epoch cadence, not the step path
