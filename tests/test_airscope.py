@@ -577,20 +577,6 @@ def test_metrics_exposition_parses_line_by_line(monkeypatch):
     assert "tpu_air_slo_burn_rate" in families
 
 
-def test_step_timer_summary_histogram_backed():
-    from tpu_air.observability.profiler import step_timer
-
-    t = step_timer()
-    assert t.summary() == {"steps": 0}
-    for _ in range(20):
-        with t.step():
-            pass
-    s = t.summary()
-    assert s["steps"] == 20
-    assert s["p50_s"] <= s["p95_s"] <= s["max_s"] * (1 + 1e-9)
-    assert len(t.durations) == 20  # raw list still available
-
-
 # ---------------------------------------------------------------------------
 # exemplar → /api/traces join over live HTTP (the tier-1 acceptance path)
 # ---------------------------------------------------------------------------

@@ -4,7 +4,6 @@ import json
 import os
 import sys
 import textwrap
-import time
 import urllib.request
 
 import pytest
@@ -20,6 +19,10 @@ def _get_json(url):
 def test_dashboard_endpoints(air):
     from tpu_air.observability import start_dashboard, stop_dashboard
 
+    if not tpu_air.is_initialized():
+        # the session's runtime is gone when test_lease_stress.py's fixture
+        # ran earlier in this process (it shuts down whatever it finds)
+        tpu_air.init(num_cpus=4, num_chips=8)
     url = start_dashboard(port=0)  # ephemeral port: parallel-test safe
     try:
         cluster = _get_json(f"{url}/api/cluster")
@@ -58,18 +61,6 @@ def test_snapshot_tracks_actors(air):
     snap = snapshot()
     assert len(snap["actors"]) >= 1
     tpu_air.kill(a)
-
-
-def test_step_timer():
-    from tpu_air.observability import step_timer
-
-    t = step_timer()
-    for _ in range(5):
-        with t.step():
-            time.sleep(0.001)
-    s = t.summary()
-    assert s["steps"] == 5
-    assert s["mean_s"] > 0 and s["p95_s"] >= s["p50_s"]
 
 
 @pytest.fixture()

@@ -1,0 +1,236 @@
+"""Host phases on the profiler's clock (``observability.profiler.phase``):
+the primitive, and the phases the engine step, the train loop and the
+worker's actor call open — names, counts and nesting as docs/OBSERVABILITY.md
+lists them.  A ``jax.profiler`` session on the CPU holds the ``/host:CPU``
+plane, so all of it is checked here."""
+
+import glob
+import os
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+import tpu_air
+from tpu_air.observability.profiler import phase
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _phases(trace_dir):
+    """Every ``layer.part`` event of the newest capture under ``trace_dir``,
+    ordered by start: ``(name, start_ns, end_ns, {count: value})``.  Selected
+    by name: the line is called ``python3`` whatever the thread is."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.split(".")[0] in ("engine", "train", "worker",
+                                             "test"):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return sorted(out, key=lambda p: p[1])
+
+
+def _inside(phases, parent):
+    return [p for p in phases
+            if p is not parent and parent[1] <= p[1] and p[2] <= parent[2]]
+
+
+def test_phase_in_a_session_lands_on_the_host_plane_with_its_counts(tmp_path):
+    import jax
+
+    with phase("test.before", n=1):  # no session: nothing, and no error
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with phase("test.outer", live=37, batch=64, method="poll"):
+            with phase("test.inner"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    with phase("test.after", n=2):
+        pass
+    got = _phases(str(tmp_path))
+    assert [p[0] for p in got] == ["test.outer", "test.inner"]
+    outer, inner = got
+    assert outer[3] == {"live": 37, "batch": 64, "method": "poll"}
+    assert inner[3] == {}
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    assert inner[2] - inner[1] >= 2_000_000
+
+
+def test_phase_without_jax_is_the_shared_noop_and_imports_nothing():
+    code = (
+        "import sys\n"
+        "from tpu_air.observability.profiler import phase\n"
+        "a, b = phase('x.y', n=1), phase('x.z')\n"
+        "with a as got:\n"
+        "    with b:\n"
+        "        pass\n"
+        "assert a is b and got is None, (a, b, got)\n"
+        "assert 'jax' not in sys.modules\n"
+        "import tpu_air.core.runtime\n"
+        "assert 'jax' not in sys.modules\n"
+        "print('inert')\n"
+    )
+    out = subprocess.run([sys.executable, "-c", code], cwd=_REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "inert"
+
+
+@pytest.fixture(scope="module")
+def engine_phases(tmp_path_factory):
+    """A tiny ``T5Engine`` stepped by hand through three windows (five
+    prompts, two rows a window) inside one session."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_air.engine import T5Engine, T5EngineConfig
+    from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
+
+    cfg = T5Config.tiny()
+    model = T5ForConditionalGeneration(cfg)
+    ones = jnp.ones((1, 8), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ones, ones, ones[:, :4])["params"]
+    engine = T5Engine(model, params,
+                      T5EngineConfig(max_batch=2, max_input_len=8,
+                                     max_new_tokens=6),
+                      auto_start=False, name="t5-phase-test")
+    rng = np.random.RandomState(0)
+    prompts = [list(map(int, rng.randint(2, cfg.vocab_size, size=n)))
+               for n in (3, 8, 5, 4, 6)]
+    trace_dir = str(tmp_path_factory.mktemp("engine-trace"))
+    engine.generate(prompts[:2], max_new_tokens=2)  # compile outside the trace
+    before = engine.metrics.snapshot()["tokens_emitted"]
+    jax.profiler.start_trace(trace_dir)
+    try:
+        streams = [engine.submit(p, max_new_tokens=2 + i)
+                   for i, p in enumerate(prompts)]
+        steps = 0
+        while not engine.idle():
+            engine.step()
+            steps += 1
+            assert steps < 100, "the engine failed to drain"
+    finally:
+        jax.profiler.stop_trace()
+    tokens = sum(len(s.result(5.0)) for s in streams)
+    emitted = engine.metrics.snapshot()["tokens_emitted"] - before
+    engine.close()
+    return _phases(trace_dir), tokens, emitted
+
+
+def test_engine_step_holds_dispatch_readback_emit_in_order(engine_phases):
+    phases, _, _ = engine_phases
+    steps = [p for p in phases if p[0] == "engine.step"]
+    assert len(steps) >= 5
+    for st in steps:
+        kids = _inside(phases, st)
+        assert [k[0] for k in kids] == [
+            "engine.dispatch", "engine.readback", "engine.emit"]
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+        assert 1 <= st[3]["live"] <= st[3]["batch"] == 2
+        assert kids[2][3] == {"emitted": st[3]["live"]}
+    # no phase per row or per token: three children a step, one prefill a
+    # window, nothing else from the engine
+    assert {p[0] for p in phases} == {
+        "engine.prefill", "engine.step", "engine.dispatch",
+        "engine.readback", "engine.emit"}
+    assert len(phases) == 4 * len(steps) + 3
+
+
+def test_engine_prefill_counts_and_emitted_sum_to_the_engines_tokens(
+        engine_phases):
+    phases, tokens, emitted = engine_phases
+    prefills = [p for p in phases if p[0] == "engine.prefill"]
+    # five requests, two rows a window; what each window left in the queue
+    assert [(p[3]["rows"], p[3]["batch"], p[3]["queued"])
+            for p in prefills] == [(2, 2, 3), (2, 2, 1), (1, 2, 0)]
+    assert not any(_inside(phases, p) for p in prefills)
+    first = sum(p[3]["rows"] for p in prefills)
+    later = sum(p[3]["emitted"] for p in phases if p[0] == "engine.emit")
+    assert first + later == emitted == tokens
+
+
+def test_train_loop_phases_per_step_and_epoch(air, tmp_path):
+    """``t5_train_loop`` in this process, one epoch of three steps."""
+    import jax
+
+    from tpu_air import data as tad
+    from tpu_air.models.t5 import T5Config
+    from tpu_air.train import TrainingArguments, session
+    from tpu_air.train.t5_trainer import t5_train_loop
+
+    ndev = len(jax.devices())
+    rng = np.random.RandomState(0)
+    rows = [{"input_ids": rng.randint(2, 300, size=12),
+             "attention_mask": np.ones(12, np.int64),
+             "labels": rng.randint(2, 300, size=6)}
+            for _ in range(3 * ndev)]
+    sess = session.Session(str(tmp_path / "run"),
+                           datasets={"train": tad.from_items(rows)}, sinks=[])
+    session._set_active(sess)
+    config = {"model_config": T5Config.tiny(),
+              "training_args": TrainingArguments(
+                  per_device_train_batch_size=1, num_train_epochs=1,
+                  evaluation_strategy="no", save_strategy="no")}
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        t5_train_loop(config)
+    finally:
+        jax.profiler.stop_trace()
+        session._set_active(None)
+    assert sess.history[-1]["steps"] == 3
+    phases = _phases(str(tmp_path / "trace"))
+    inputs = [p for p in phases if p[0] == "train.input"]
+    kids = [[k[0] for k in _inside(phases, p)] for p in inputs]
+    # three steps, then the next() that found the data exhausted
+    assert kids == [["train.next_batch", "train.collate",
+                     "train.put_batch"]] * 3 + [["train.next_batch"]]
+    assert [p[3]["step"] for p in inputs] == [0, 1, 2, 3]
+    assert [p[3] for p in phases if p[0] == "train.dispatch"] == [
+        {"step": 0}, {"step": 1}, {"step": 2}]
+    syncs = [p for p in phases if p[0] == "train.epoch_sync"]
+    assert [p[3] for p in syncs] == [{"epoch": 1}]
+    assert syncs[0][1] >= max(p[2] for p in phases if p[0] != "train.epoch_sync")
+
+
+class _Traced:
+    """An actor that opens and closes a profiler session in its own worker."""
+
+    def start(self, trace_dir):
+        import jax
+
+        jax.profiler.start_trace(trace_dir)
+        return True
+
+    def poll(self, i):
+        return i
+
+    def stop(self):
+        import jax
+
+        jax.profiler.stop_trace()
+        return True
+
+
+def test_worker_actor_task_one_phase_a_call_with_its_method(air, tmp_path):
+    actor = tpu_air.remote(_Traced).remote()
+    assert tpu_air.get(actor.start.remote(str(tmp_path)))
+    assert tpu_air.get([actor.poll.remote(i) for i in range(7)]) == list(
+        range(7))
+    assert tpu_air.get(actor.stop.remote())
+    calls = [p for p in _phases(str(tmp_path)) if p[0] == "worker.actor_task"]
+    # start's own phase began before the session and stop's ended after it:
+    # the session holds the calls in between, whole
+    assert [p[3] for p in calls] == [{"method": "poll"}] * 7
+    assert all(a[2] <= b[1] for a, b in zip(calls, calls[1:]))

@@ -52,6 +52,7 @@ from .object_store import ObjectRef, ObjectStore, new_object_id
 # in nothing heavy at import time)
 from tpu_air.faults import plan as _faults
 from tpu_air.observability import tracing as _tracing
+from tpu_air.observability.profiler import phase as _phase
 
 # --------------------------------------------------------------------------
 # errors
@@ -406,29 +407,37 @@ def _worker_main(
                 actors[spec["actor_id"]] = inst
             _send_done(worker_id, spec["task_id"], leased)
         elif kind == "actor_task":
-            inst = actors.get(spec["actor_id"])
-            _, args, kwargs = _load_payload(store, spec)
-            if inst is None:
-                init_err = failed_actors.get(spec["actor_id"])
-                store.put(
-                    init_err
-                    if init_err is not None
-                    else _ErrorSentinel("ActorDiedError('actor failed to initialize')", ""),
-                    spec["task_id"],
-                )
-            else:
-                try:
-                    args, kwargs = _resolve_args(store, args, kwargs)
-                    method = getattr(inst, spec["method"])
-                except RemoteError as e:
-                    store.put(_ErrorSentinel(repr(e), e.remote_traceback), spec["task_id"])
-                    _send_done(worker_id, spec["task_id"])
-                    continue
-                name = f"actor.{type(inst).__name__}.{spec['method']}"
-                with _tracing.task_span(name, spec.get("trace_ctx")) as sp:
-                    if not _store_result(store, spec["task_id"], method, args, kwargs):
-                        sp.set_status("error")
-            _send_done(worker_id, spec["task_id"], leased)
+            # everything one actor call costs inside this worker: payload,
+            # arguments, the method, storing the result, the done message
+            with _phase("worker.actor_task", method=spec["method"]):
+                _actor_task(store, spec, actors.get(spec["actor_id"]),
+                            failed_actors.get(spec["actor_id"]))
+                _send_done(worker_id, spec["task_id"], leased)
+
+
+def _actor_task(store: ObjectStore, spec: dict, inst: Any,
+                init_err: Optional[_ErrorSentinel]) -> None:
+    """Run one actor call and leave its result, or its error, in the store
+    under the call's task id."""
+    _, args, kwargs = _load_payload(store, spec)
+    if inst is None:
+        store.put(
+            init_err
+            if init_err is not None
+            else _ErrorSentinel("ActorDiedError('actor failed to initialize')", ""),
+            spec["task_id"],
+        )
+        return
+    try:
+        args, kwargs = _resolve_args(store, args, kwargs)
+        method = getattr(inst, spec["method"])
+    except RemoteError as e:
+        store.put(_ErrorSentinel(repr(e), e.remote_traceback), spec["task_id"])
+        return
+    name = f"actor.{type(inst).__name__}.{spec['method']}"
+    with _tracing.task_span(name, spec.get("trace_ctx")) as sp:
+        if not _store_result(store, spec["task_id"], method, args, kwargs):
+            sp.set_status("error")
 
 
 # --------------------------------------------------------------------------

@@ -41,6 +41,7 @@ from tpu_air.models.t5.generate import (
     make_t5_decode_step_fn,
     make_t5_prefill_fn,
 )
+from tpu_air.observability.profiler import phase
 
 from .metrics import EngineMetrics, unregister
 from .scheduler import Scheduler
@@ -230,6 +231,13 @@ class T5Engine:
         reqs = self.scheduler.pop_admissible(self.config.max_batch)
         if not reqs:
             return False
+        with phase("engine.prefill", rows=len(reqs),
+                   batch=self.config.max_batch,
+                   queued=self.scheduler.depth()):
+            self._prefill_window(reqs)
+        return True
+
+    def _prefill_window(self, reqs: List[Request]) -> None:
         cfg = self.config
         b, li = cfg.max_batch, cfg.max_input_len
         ids = np.full((b, li), self.pad_token_id, np.int32)
@@ -258,34 +266,38 @@ class T5Engine:
                 self._retire(win, row)
         self.metrics.record_tokens(emitted)
         self._window = win if win.live_rows() else None
-        return True
 
     def _decode_window(self) -> None:
         win = self._window
-        t0 = time.monotonic()
-        win.cache, nxt = self._decode_step(
-            self.params, win.cache, jnp.asarray(win.cur_tok), win.enc,
-            jnp.asarray(win.enc_mask),
-        )
-        nxt = np.asarray(nxt)
-        dt = time.monotonic() - t0
-        emitted = 0
-        for row in win.live_rows():
-            # airlint: disable=JX004 — nxt is the np.asarray'd step result;
-            # the single device sync already happened above the loop
-            token = int(nxt[row])
-            req = win.requests[row]
-            req.stream._emit(token)
-            emitted += 1
-            win.cur_tok[row] = token
-            win.budget_left[row] -= 1
-            if win.budget_left[row] == 0 or token == self.eos_token_id:
-                self._retire(win, row)
-        self.metrics.record_step(dt, emitted)
-        if not win.live_rows():
-            # window drained: drop its cache, admit the next batch on the
-            # following step
-            self._window = None
+        live = win.live_rows()
+        with phase("engine.step", live=len(live),
+                   batch=self.config.max_batch):
+            t0 = time.monotonic()
+            with phase("engine.dispatch"):
+                win.cache, nxt = self._decode_step(
+                    self.params, win.cache, jnp.asarray(win.cur_tok), win.enc,
+                    jnp.asarray(win.enc_mask),
+                )
+            with phase("engine.readback"):
+                nxt = np.asarray(nxt)
+            dt = time.monotonic() - t0
+            # one phase around the walk over the live rows, none per row
+            with phase("engine.emit", emitted=len(live)):
+                for row in live:
+                    # airlint: disable=JX004 — nxt is the np.asarray'd step
+                    # result; the single device sync already happened above
+                    token = int(nxt[row])
+                    req = win.requests[row]
+                    req.stream._emit(token)
+                    win.cur_tok[row] = token
+                    win.budget_left[row] -= 1
+                    if win.budget_left[row] == 0 or token == self.eos_token_id:
+                        self._retire(win, row)
+                self.metrics.record_step(dt, len(live))
+                if not win.live_rows():
+                    # window drained: drop its cache, admit the next batch
+                    # on the following step
+                    self._window = None
 
     def _retire(self, win: _Window, row: int) -> None:
         win.requests[row].stream._finish()

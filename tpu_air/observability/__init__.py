@@ -8,7 +8,7 @@ endpoint over the driver runtime's live state (SURVEY.md §2B dashboard row,
 """
 
 from .dashboard import start_dashboard, stop_dashboard, snapshot
-from .profiler import profile_trace, step_timer
+from .profiler import phase, profile_trace
 from . import perf
 from . import postmortem
 from . import slo
@@ -19,12 +19,12 @@ from . import watch
 
 __all__ = [
     "perf",
+    "phase",
     "postmortem",
     "profile_trace",
     "slo",
     "snapshot",
     "start_dashboard",
-    "step_timer",
     "stop_dashboard",
     "timeseries",
     "trace_export",

@@ -1,9 +1,9 @@
 """Profiling hooks (SURVEY.md §5 tracing: "per-step timing in the trainer
 loop, JAX profiler hooks (xplane traces)").
 
-* ``step_timer`` — lightweight wall/step accounting used by the trainer loops
-  (the reference's only in-repo tracing is %%time cells and time.time deltas,
-  Overview_of_Ray.ipynb:cc-18,24,47 — this is the structured version).
+* ``phase`` — a named host phase with counts, as a
+  ``jax.profiler.TraceAnnotation``: the engine step, the worker's actor call
+  and the train loop mark theirs, on the device trace's clock.
 * ``profile_trace`` — context manager around ``jax.profiler.trace`` producing
   xplane/perfetto traces viewable in TensorBoard or ui.perfetto.dev.
 """
@@ -11,70 +11,31 @@ loop, JAX profiler hooks (xplane traces)").
 from __future__ import annotations
 
 import contextlib
-import time
-from typing import Any, Dict, Iterator, Optional
+import sys
+from typing import Any, Iterator, Optional
 
 from . import tracing as _tracing
-from .perf import Histogram
 
 
-class step_timer:
-    """Accumulates per-step wall times; cheap enough for every train step.
+_INERT = contextlib.nullcontext()  # reusable and re-entrant: one for all
 
-    >>> t = step_timer()
-    >>> with t.step():  # around each train_step
-    ...     ...
-    >>> t.summary()  # {'steps': N, 'mean_s': ..., 'p50_s': ..., 'p95_s': ...}
 
-    Quantiles come from an airscope log-bucketed :class:`Histogram` — the
-    same estimator the engine metrics use, so a trainer's p95 and the
-    dashboard's p95 agree on method (the raw ``durations`` list stays
-    available for exact math downstream).
+def phase(name: str, **counts: Any):
+    """A host phase on the profiler's clock: ``with phase("engine.step",
+    live=37, batch=64): ...`` is a ``jax.profiler.TraceAnnotation`` — an
+    event on the host thread's row of an xplane capture, its counts the
+    event's stats — live exactly while a profiler session is (``--trace 1``,
+    ``profile_trace``, TensorBoard's capture) and well under a microsecond
+    otherwise.  It never imports JAX itself: a process that has not (the
+    driver, a pooled task worker) gets a shared no-op.
 
-    With ``span_name`` set AND tracing enabled, every step additionally
-    lands as an airtrace span (parented under the ambient context) so the
-    same numbers show up on the request/trial timeline; the default path
-    stays a bare perf_counter delta.
-    """
-
-    def __init__(self, span_name: Optional[str] = None):
-        self.durations: list = []
-        self._hist = Histogram()
-        self._span_name = span_name
-
-    @contextlib.contextmanager
-    def step(self) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            dt = time.perf_counter() - t0
-            self.durations.append(dt)
-            self._hist.observe(dt)
-            if self._span_name is not None and _tracing.enabled():
-                end = _tracing.now_ns()
-                ctx = _tracing.current_context()
-                _tracing.record_span(
-                    self._span_name,
-                    trace_id=ctx.trace_id if ctx else None,
-                    parent_id=ctx.span_id if ctx else None,
-                    start_ns=end - int(dt * 1e9),
-                    end_ns=end,
-                    attrs={"step": len(self.durations)},
-                )
-
-    def summary(self) -> Dict[str, Any]:
-        s = self._hist.summary()
-        if not s.get("count"):
-            return {"steps": 0}
-        return {
-            "steps": s["count"],
-            "total_s": s["sum"],
-            "mean_s": s["mean"],
-            "p50_s": s["p50"],
-            "p95_s": s["p95"],
-            "max_s": s["max"],
-        }
+    Names are lower-case ``layer.part`` (docs/OBSERVABILITY.md lists them)
+    and never start with ``trace_`` or ``$``; counts are integers or short
+    strings known at entry.  Phases do not go into airtrace's ring buffer."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _INERT
+    return jax.profiler.TraceAnnotation(name, **counts)
 
 
 @contextlib.contextmanager

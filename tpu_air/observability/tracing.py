@@ -1,9 +1,9 @@
 """airtrace — span-based distributed tracing for the tpu_air stack.
 
 Every observability surface before this module was point-in-time
-(``EngineMetrics`` gauges, ``/api/*`` snapshots, ``step_timer`` summaries).
-This module adds the *per-request timeline*: W3C-style trace/span IDs, a
-process-local lock-protected ring-buffer :class:`SpanRecorder`, and context
+(``EngineMetrics`` gauges, ``/api/*`` snapshots).  This module adds the
+*per-request timeline*: W3C-style trace/span IDs, a process-local
+lock-protected ring-buffer :class:`SpanRecorder`, and context
 propagation across every boundary the stack has —
 
 * HTTP proxy → replica actor: ``serve/proxy.py`` opens a root span per
@@ -16,9 +16,9 @@ propagation across every boundary the stack has —
 * engine internals: ``engine/scheduler.py`` + ``engine/engine.py`` stamp
   queue-wait / prefill / per-slot decode residency and emit the request's
   span tree at retirement (no hot-loop work — see "cost story" below);
-* train: ``train/session.py`` emits per-iteration spans so ``step_timer``
-  numbers land in the same timeline, and ``profiler.profile_trace`` records
-  a span carrying its xplane log dir for on-chip correlation.
+* train: ``train/session.py`` emits per-iteration spans, and
+  ``profiler.profile_trace`` records a span carrying its xplane log dir for
+  on-chip correlation.
 
 Cost story — **zero-cost when off** (the default): the module-level flag is
 read by :func:`enabled`; every instrumentation site either guards on it or

@@ -95,8 +95,8 @@ class Session:
 
     def _emit_iteration_span(self) -> None:
         """One ``train.iteration`` span per report, covering the window
-        since the previous report (what ``step_timer`` summarizes) so the
-        trial's cadence is visible on the same timeline as everything else."""
+        since the previous report, so the trial's cadence is visible on the
+        same timeline as everything else."""
         now = _tracing.now_ns()
         if self._trace_ctx is None:
             # no ambient context at construction (tracing enabled later, or

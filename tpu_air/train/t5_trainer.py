@@ -25,6 +25,8 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from tpu_air.observability.profiler import phase
+
 from .checkpoint import Checkpoint
 from .trainer import BaseTrainer
 
@@ -291,19 +293,32 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
         tokens = 0
         losses = []
         nsteps = 0
-        for batch_df in train_ds.iter_batches(
+        batches = train_ds.iter_batches(
             batch_size=global_bs, batch_format="pandas", drop_last=True
-        ):
-            if len(batch_df) < global_bs:
-                continue
-            batch = put_batch(collate(batch_df, keys, seq_lens))
-            params, opt_state, loss, rng = train_step(params, opt_state, batch, rng)
+        )
+        while True:
+            # host time to fetch, collate and place one global batch (the
+            # last one of an epoch holds only the next() that found no more)
+            with phase("train.input", step=nsteps):
+                with phase("train.next_batch"):
+                    batch_df = next(batches, None)
+                if batch_df is None:
+                    break
+                if len(batch_df) < global_bs:
+                    continue
+                with phase("train.collate"):
+                    host_batch = collate(batch_df, keys, seq_lens)
+                with phase("train.put_batch"):
+                    batch = put_batch(host_batch)
+            with phase("train.dispatch", step=nsteps):
+                params, opt_state, loss, rng = train_step(params, opt_state, batch, rng)
             losses.append(loss)
             tokens += global_bs * seq_len
             nsteps += 1
             if args.max_steps_per_epoch and nsteps >= args.max_steps_per_epoch:
                 break
-        train_loss = float(jnp.mean(jnp.stack(losses))) if losses else float("nan")
+        with phase("train.epoch_sync", epoch=epoch + 1):
+            train_loss = float(jnp.mean(jnp.stack(losses))) if losses else float("nan")
         dt = time.time() - t0
         metrics: Dict[str, Any] = {
             "epoch": epoch + 1,
