@@ -8,6 +8,9 @@ a ``v5e:2x2`` host that is not attached and compiles for it; each case asserts
 the Mosaic kernel is really in the program (``tpu_custom_call``), so a path
 that quietly took interpret mode fails.  A compile that passes is not a chip
 run: numbers and results come from ``chip_smoke.py`` and ``-m tpu``.
+
+The last test compiles ``generate`` itself at the W3 shape and reads what the
+compiler made of the decode cache's layout (no kernel in it).
 """
 
 import os
@@ -138,3 +141,30 @@ def test_kernel_compiles_for_v5e(v5e, case):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{case}: compiled without the Pallas kernel in it"
+
+
+@pytest.mark.parametrize("early_stop", [True, False], ids=["while", "scan"])
+def test_generate_streams_the_cache_unpadded_on_v5e(v5e, early_stop):
+    """``generate`` at the W3 shape (FLAN-T5-base, 256 x 512, bf16, 128 new
+    tokens) under both loop forms: the chip's compiler keeps no row-major
+    4-D copy of a cache slab (minor pair (12, 64) tiled to (16, 128), 2.67 x
+    the bytes) and the program's temporaries stay near the flat cache's 6 GB.
+    With the dense path under the while-loop they were 14.1 GB (PERF.md, PR
+    25).  tests/test_t5.py holds the same at the jaxpr; this holds what XLA
+    makes of it."""
+    from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
+    from tpu_air.models.t5.generate import make_generate_fn
+
+    cfg = T5Config.flan_t5_base()
+    cfg.dtype = "bfloat16"
+    model = T5ForConditionalGeneration(cfg)
+    one = jnp.ones((1, 8), jnp.int32)
+    params = jax.tree_util.tree_map(
+        lambda s: _struct(s.shape, jnp.bfloat16, v5e),
+        jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), one, one, one))["params"])
+    ids = _struct((256, 512), jnp.int32, v5e)
+    compiled = make_generate_fn(model, 128, early_stop=early_stop).lower(
+        params, ids, ids, _struct((2,), jnp.uint32, v5e)).compile()
+    assert "bf16[256,512,12,64]{3,2,1,0" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9

@@ -2,6 +2,8 @@
 SURVEY.md §4.3: distributed/kernel tests must run without TPU hardware) and
 ring attention across the virtual 8-device mesh."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -419,11 +421,10 @@ def test_decode_attention_rejects_bad_shapes():
         decode_attention(q, k, v, block_k=7)
 
 
-def test_t5_decode_pallas_generate_matches_einsum():
-    """End-to-end dispatch: greedy generation with
-    decode_attention_impl="pallas" must be token-identical to the einsum
-    decode path, for bf16-class AND int8 caches (the kernel replaces both
-    the self- and cross-attention cached steps)."""
+@functools.lru_cache(maxsize=None)
+def _tiny_greedy_tokens(impl, int8, early_stop):
+    """Greedy ids of the tiny fp32 model under one decode dispatch value and
+    one loop form (``early_stop`` True: ``lax.while_loop``; False: ``lax.scan``)."""
     import dataclasses
 
     from tpu_air.models.t5.config import T5Config
@@ -431,21 +432,28 @@ def test_t5_decode_pallas_generate_matches_einsum():
     from tpu_air.models.t5.modeling import T5ForConditionalGeneration
 
     cfg = T5Config.tiny()
-    model = T5ForConditionalGeneration(cfg)
-    rng = jax.random.PRNGKey(0)
     enc = jnp.ones((2, 8), jnp.int32)
-    params = model.init(rng, enc, jnp.ones_like(enc),
-                        jnp.ones((2, 6), jnp.int32))["params"]
+    params = T5ForConditionalGeneration(cfg).init(
+        jax.random.PRNGKey(0), enc, jnp.ones_like(enc),
+        jnp.ones((2, 6), jnp.int32))["params"]
     ids = jnp.array([[4, 5, 6, 1, 0, 0], [7, 8, 9, 2, 1, 0]], jnp.int32)
     mask = (ids != 0).astype(jnp.int32)
-    for int8 in (False, True):
-        outs = {}
-        for impl in ("einsum", "auto", "flat", "pallas"):
-            c = dataclasses.replace(
-                cfg, decode_attention_impl=impl, decode_cache_int8=int8)
-            m = T5ForConditionalGeneration(c)
-            outs[impl] = np.asarray(generate(m, params, ids, mask,
-                                             max_new_tokens=6))
-        for impl in ("auto", "flat", "pallas"):
-            np.testing.assert_array_equal(outs["einsum"], outs[impl],
-                                          err_msg=f"impl={impl} int8={int8}")
+    c = dataclasses.replace(
+        cfg, decode_attention_impl=impl, decode_cache_int8=int8)
+    return np.asarray(generate(
+        T5ForConditionalGeneration(c), params, ids, mask, max_new_tokens=6,
+        early_stop=early_stop))
+
+
+@pytest.mark.parametrize("early_stop", [True, False], ids=["while", "scan"])
+@pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])
+@pytest.mark.parametrize("impl", ["auto", "flat", "pallas"])
+def test_t5_decode_pallas_generate_matches_einsum(impl, int8, early_stop):
+    """End-to-end dispatch, fp32: greedy generation under every value of
+    ``decode_attention_impl`` is token for token what the explicit dense path
+    (``"einsum"``, the comparison value) gives, for full-width AND int8 caches
+    and under both loop forms ``generate`` compiles: the default ``"auto"``
+    attends over the flat slab in the self- and the cross-attention step."""
+    np.testing.assert_array_equal(
+        _tiny_greedy_tokens("einsum", int8, early_stop),
+        _tiny_greedy_tokens(impl, int8, early_stop))

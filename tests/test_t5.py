@@ -202,16 +202,23 @@ def test_byte_tokenizer_save_load(tmp_path):
     assert tok2.model_max_length == 77
 
 
-def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch):
+@pytest.mark.parametrize("impl", ["auto", "einsum"])
+def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, impl):
     """early_stop=True (the torch model.generate stopping criterion) must
     produce the identical sequences as the fixed-budget scan and actually
-    stop once every sequence emitted EOS."""
+    stop once every sequence emitted EOS: under the default dispatch (flat
+    slabs) and under the explicit dense path, which must also agree with
+    each other token for token (fp32)."""
+    import dataclasses
+
     import jax
     import jax.numpy as jnp
 
     from tpu_air.models.t5.generate import make_generate_fn
 
-    cfg, model, params = tiny
+    cfg, _, params = tiny
+    model = T5ForConditionalGeneration(
+        dataclasses.replace(cfg, decode_attention_impl=impl))
     rng = jax.random.PRNGKey(3)
     ids = jax.random.randint(rng, (2, 12), 2, cfg.vocab_size, jnp.int32)
     mask = jnp.ones((2, 12), jnp.int32)
@@ -222,6 +229,13 @@ def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch):
     seq_b, steps_b = fn_early(params, ids, mask, rng)
     np.testing.assert_array_equal(np.asarray(seq_a), np.asarray(seq_b))
     assert int(steps_a) == 16
+    if impl != "einsum":
+        dense = T5ForConditionalGeneration(
+            dataclasses.replace(cfg, decode_attention_impl="einsum"))
+        for early in (True, False):
+            seq_d, _ = make_generate_fn(dense, 16, early_stop=early)(
+                params, ids, mask, rng)
+            np.testing.assert_array_equal(np.asarray(seq_a), np.asarray(seq_d))
 
     # force EOS on step one by patching the sampler (the loop under test,
     # not the model): a fresh fn traces against the patched module global
@@ -347,3 +361,115 @@ def test_generate_feature_composition_int8_earlystop_bucketing(tiny):
     got = np.asarray(generate(m8, params, ids[:5], mask[:5], max_new_tokens=6))
     np.testing.assert_array_equal(got, base[:5])
     assert base.shape == (8, 6)
+
+
+@pytest.mark.parametrize("impl", ["auto", "flat", "pallas", "einsum"])
+def test_cached_step_logits_match_uncached_forward(tiny, impl):
+    """Teacher-forced, fp32: the logits of every cached single-token step
+    (flat self- and cross-attention slabs) equal the full uncached decoder
+    forward's at that position, to 2e-4 of the logits' range."""
+    import dataclasses
+
+    from tpu_air.models.t5.generate import init_cache
+
+    cfg, _, params = tiny
+    model = T5ForConditionalGeneration(
+        dataclasses.replace(cfg, decode_attention_impl=impl))
+    rng = np.random.default_rng(11)
+    ids = jnp.asarray(rng.integers(2, cfg.vocab_size, (3, 10)), jnp.int32)
+    mask = jnp.asarray([[1] * 10, [1] * 7 + [0] * 3, [1] * 4 + [0] * 6], jnp.int32)
+    dec = jnp.asarray(rng.integers(2, cfg.vocab_size, (3, 6)), jnp.int32)
+    dec = dec.at[:, 0].set(cfg.decoder_start_token_id)
+    want = np.asarray(model.apply({"params": params}, ids, mask, dec))
+
+    enc = model.apply({"params": params}, ids, mask, method=model.encode)
+    cache = init_cache(model, params, 3, 6, enc, mask)
+    got = []
+    for t in range(6):
+        logits, upd = model.apply(
+            {"params": params, "cache": cache}, dec[:, t:t + 1], enc, mask,
+            decode=True, mutable=["cache"], method=model.decode)
+        cache = upd["cache"]
+        got.append(np.asarray(logits[:, 0]))
+    got = np.stack(got, axis=1)
+    span = float(want.max() - want.min())
+    np.testing.assert_allclose(got, want, atol=2e-4 * span, rtol=0)
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (list, tuple)) else (v,):
+            inner = getattr(x, "jaxpr", x)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _sub_jaxprs(eqn):
+            yield from _all_eqns(sub)
+
+
+def _slab_views(jaxpr, b, lengths, h, d):
+    """Equations that make a 4-D ``[b, L, h, d]`` array for a cache length
+    ``L`` in ``lengths``: the only 4-D view a reshape of a flat
+    ``[b, L, h*d]`` slab into heads can give."""
+    views = {(b, L, h, d) for L in lengths}
+    return [eqn for eqn in _all_eqns(jaxpr)
+            if any(tuple(v.aval.shape) in views for v in eqn.outvars)]
+
+
+def _decode_bodies(kind, impl, int8):
+    """The jaxprs of the cached decode step as ``kind`` builds it: the body
+    of ``generate``'s loop (``while`` / ``scan``) or the engine's whole step."""
+    import dataclasses
+
+    from tpu_air.models.t5.generate import (
+        make_generate_fn, make_t5_decode_step_fn, make_t5_prefill_fn)
+
+    cfg = dataclasses.replace(T5Config.tiny(), decode_attention_impl=impl,
+                              decode_cache_int8=int8)
+    model = T5ForConditionalGeneration(cfg)
+    b, enc_len, new = 3, 10, 6
+    ids = jnp.ones((b, enc_len), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), ids, ids, ids[:, :2]))["params"]
+    dims = (b, (enc_len, new + 1), cfg.num_heads, cfg.d_kv)
+    if kind == "step":
+        tok, cache, enc = jax.eval_shape(
+            make_t5_prefill_fn(model, new + 1), params, ids, ids)
+        jaxpr = jax.make_jaxpr(make_t5_decode_step_fn(model))(
+            params, cache, tok, enc, ids)
+        return [jaxpr.jaxpr], dims
+    fn = make_generate_fn(model, new, early_stop=(kind == "while"))
+    jaxpr = jax.make_jaxpr(fn)(params, ids, ids, jax.random.PRNGKey(0))
+    key = {"while": "body_jaxpr", "scan": "jaxpr"}[kind]
+    bodies = [e.params[key].jaxpr for e in _all_eqns(jaxpr.jaxpr)
+              if e.primitive.name == kind]
+    return bodies, dims
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])
+@pytest.mark.parametrize("kind", ["while", "scan", "step"])
+def test_cached_step_never_views_a_slab_in_4d(kind, int8):
+    """Under ``"auto"`` no equation of the decode body reshapes or transposes
+    a flat ``[b, L, h*d]`` cache slab to a 4-D ``[b, L, h, d]`` array: the
+    TPU tiles that view's minor pair (12, 64) to (16, 128), 2.67 x the bytes,
+    and whether XLA keeps it padded depends on the loop around the step
+    (PERF.md, PR 25).  Held for ``generate``'s while-loop and scan and for
+    the engine's donated-cache step, full-width and int8 caches."""
+    bodies, dims = _decode_bodies(kind, "auto", int8)
+    assert bodies, f"no {kind} in the program"
+    bad = [e for body in bodies for e in _slab_views(body, *dims)]
+    assert not bad, "\n".join(str(e) for e in bad[:4])
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])
+def test_slab_view_check_sees_the_dense_path(int8):
+    """The check above is not vacuous: the explicit dense comparison path
+    (``"einsum"``) does view every layer's slabs in 4-D, and is found."""
+    bodies, dims = _decode_bodies("while", "einsum", int8)
+    bad = [e for body in bodies for e in _slab_views(body, *dims)]
+    n_slabs = 2 * 2 * T5Config.tiny().num_decoder_layers
+    assert len(bad) >= n_slabs, len(bad)

@@ -57,18 +57,20 @@ class T5Config:
     # tested at tolerance in tests/test_t5.py.
     decode_cache_int8: bool = False
     # Cached-decode attention dispatch (ops/decode_attention.py).  Caches
-    # are stored FLAT [b, L, h*d] (the 4-D layout cost 2.67x physical HBM
-    # bytes to tile padding — the r5 decode bottleneck).  "auto" follows
-    # the BENCH r5 measurement at the W3 dials: full-width caches decode
-    # through XLA's dense path reconstructed from the flat slab (179.2
-    # seq/s, 0.80 of the v5e HBM roofline — XLA's own fusion wins once
-    # the carry layout is flat), int8 caches through the flat block-
-    # diagonal formulation whose scale FOLDS never materialize a
-    # dequantized slab (213.7 seq/s vs a 9.4 GB/step materialization
-    # bound).  Explicit values pin one path: "flat" = block-diagonal
-    # formulation; "einsum" = dense reconstruction; "pallas" = the fused
-    # kernel (measured slower — kept as the measured alternative,
-    # interpret mode off-TPU).
+    # are stored FLAT [b, L, h*d] (a row-major 4-D slab costs 2.67x the
+    # HBM bytes to tile padding).  "auto" attends over the slab as stored,
+    # through the flat block-diagonal formulation, for full-width and int8
+    # caches alike, so the layout XLA streams does not depend on the loop
+    # or program around the step.  Measured on the v5e (PERF.md, PR 25):
+    # t5base-batchgen, predict()'s early_stop while-loop, 256 x 512 bf16:
+    # 72.5 % of the HBM roofline, where the dense path ("auto" up to PR 24;
+    # ledger, PR 24) read 37.0 % and 86.7 seq/s; the engine's step on
+    # FLAN-T5-large at batch 64: 7.6 ms against 11.8.  Explicit values pin
+    # one path: "flat" = what "auto" takes; "einsum" = the dense comparison
+    # path over a 4-D view of the slab (87.6 % under generate()'s
+    # fixed-trip scan, 37.0 % under the while-loop: XLA's layout choice,
+    # not the caller's); "pallas" = the fused kernel (63.7 % in the same
+    # while-loop; interpret mode off-TPU).
     decode_attention_impl: str = "auto"
 
     def __post_init__(self):

@@ -157,15 +157,16 @@ class Attention(nn.Module):
             return (q8.astype(jnp.float32) * scale).astype(dtype)
 
         # Decode caches are stored FLAT: [b, L, h*d], scales [b, 1, h*d]
-        # (cross, per-channel) / [b, L, h] (self, per-position).  The r5
-        # profile found the 4-D [b, L, h, d] slab layout was the decode
-        # bottleneck: TPU tiles the last two dims (12, 64) up to (16, 128)
-        # — 2.67x physical HBM bytes — and XLA streamed those padded
-        # slabs at ~92% of the roofline, i.e. the chip was fast, the
-        # LAYOUT was the waste.  h*d = 768 is six clean (8, 128) tiles,
-        # zero padding.  The cached single-token step then attends via
-        # the flat block-diagonal formulation (``flat_decode_attention``)
-        # or the Pallas kernel, never materializing a [b, L, h, d] copy.
+        # (cross, per-channel) / [b, L, h] (self, per-position).  A 4-D
+        # [b, L, h, d] slab is the decode bottleneck wherever XLA keeps it
+        # row-major: TPU tiles the last two dims (12, 64) up to (16, 128)
+        # — 2.67x physical HBM bytes — and streams those padded bytes at
+        # ~92% of the roofline, i.e. the chip is fast, the LAYOUT is the
+        # waste.  h*d = 768 is six clean (8, 128) tiles, zero padding.
+        # The cached single-token step attends over the slab as stored
+        # (``flat_decode_attention``, or the Pallas kernel if asked), and
+        # never takes a [b, L, h, d] view of it
+        # (tests/test_t5.py::test_cached_step_never_views_a_slab_in_4d).
         dk_impl = getattr(cfg, "decode_attention_impl", "auto")
         dk_scales = (None, None)
         cached_step = False    # k/v hold FLAT cache slabs, not [b,k,h,d]
@@ -297,23 +298,22 @@ class Attention(nn.Module):
             # Single-token step over flat cache slabs.  Structured-mask
             # contract: mask here is batch-shared (decode causal row) or
             # None.
-            # Measured dispatch (BENCH r5, W3 dials, flat storage): bf16
-            # decodes FASTER through XLA's dense path reconstructed from
-            # the flat slab (179.2 seq/s, 0.80 of roofline) than through
-            # the block-diagonal formulation (161.2, 0.715) — given the
-            # flat carry layout, XLA's own attention fusion wins.  int8
-            # must NOT reconstruct (dequant materializes, pessimistic
-            # bound 9.4 GB/step vs 3.3): the fold-based flat path wins
-            # there (213.7 seq/s).  So "auto" = einsum for full-width
-            # caches, flat folds for int8.
-            impl_eff = dk_impl
-            if dk_impl == "auto" and dk_scales[0] is None:
-                impl_eff = "einsum"
+            # "auto" attends over the flat slab for EVERY cache, full-width
+            # and int8, self and cross (PR 25).  The dense path in the else
+            # branch needs a [b, L, h, d] view of the slab, and what XLA
+            # makes of that view depends on the program around the step:
+            # one length-minor copy hoisted out of generate()'s fixed-trip
+            # scan, a padded row-major view (2.67x the bytes an iteration)
+            # under the early_stop while-loop predict() runs, a relayout
+            # copy of every slab every step in the engine's donated-cache
+            # step (PERF.md, PR 25).  "einsum" stays as the explicit dense
+            # comparison value, and as the fallback for what is not a plain
+            # single-token step (qlen > 1, live dropout, per-row mask).
             fast_ok = (
                 qlen == 1
                 and (deterministic or cfg.dropout_rate == 0)
                 and (mask is None or mask.shape[0] == 1)
-                and impl_eff != "einsum"
+                and dk_impl != "einsum"
             )
             if fast_ok:
                 if mask is not None and not (
@@ -354,8 +354,8 @@ class Attention(nn.Module):
                         dk_scales[0], dk_scales[1], cfg.num_heads, dtype,
                     )
             else:
-                # legacy/comparison path: materialize the dequantized 4-D
-                # slab and fall through to the dense einsum below
+                # comparison / fallback path: view the (dequantized) slab in
+                # 4-D and fall through to the dense einsum below
                 bsz = k.shape[0]
                 hpd = (cfg.num_heads, cfg.d_kv)
                 ks_, vs_ = dk_scales

@@ -9,17 +9,24 @@ to (16, 128), a 2.67x physical-byte inflation, and XLA streamed those
 padded bytes at ~92% of the roofline.  The fix is layout + formulation,
 not a bespoke kernel:
 
-* ``flat_decode_attention`` — the DEFAULT path (pure XLA): caches stored
-  flat ``[b, L, h*d]`` (768 = six clean (8, 128) tiles, zero padding),
-  all heads riding ONE batched MXU matmul per contraction via
-  block-diagonal expansion.  Measured 732 GB/s = 89% of roofline in
-  isolation; end-to-end it cut the W3 decode step ~2x (bf16) / ~3.2x
-  (int8) vs the padded einsum.
+* ``flat_decode_attention`` — what ``decode_attention_impl="auto"`` takes
+  for every cache since PR 25 (pure XLA): caches stored flat
+  ``[b, L, h*d]`` (768 = six clean (8, 128) tiles, zero padding), all
+  heads riding ONE batched MXU matmul per contraction via block-diagonal
+  expansion, and no ``[b, L, h, d]`` view of a slab anywhere in the step.
+  On the v5e (PERF.md, PR 25; FLAN-T5-base, 256 x 512, bf16) the decode
+  iteration is 10.6 ms under ``generate``'s ``early_stop`` while-loop and
+  10.0 ms under its fixed-trip scan, 72.5 % and 76.9 % of the HBM
+  roofline.  The dense path over a 4-D view of the same flat slab, which
+  ``"auto"`` took for full-width caches up to PR 24, is whatever layout XLA
+  assigns the view: 8.8 ms (87.6 %) under the scan, which is all r05
+  measured, and 20.8 ms (37.0 %; ``t5base-batchgen`` in the ledger, PR 24:
+  86.7 seq/s) under the while-loop that ``predict`` runs.
 * ``decode_attention`` — the same computation as a fused Pallas kernel
   (online softmax over L-chunks, int8 dequant folded into operands so
-  int8 slabs stay int8 into VMEM).  Measured SLOWER than the flat XLA
-  path (229 GB/s isolated; per-program overhead at b=256 x 1-chunk
-  grids dominates) — kept as the measured alternative and as the
+  int8 slabs stay int8 into VMEM).  Slower than the flat XLA path: 12.1 ms
+  an iteration (63.7 %) in the same while-loop (per-program overhead at
+  b=256 x 1-chunk grids) — kept as the measured alternative and as the
   scaffold for shapes XLA fuses badly, selectable via
   ``T5Config.decode_attention_impl="pallas"``.
 
@@ -309,14 +316,14 @@ def gather_pages(pool: jax.Array, block_table: jax.Array) -> jax.Array:
 
 def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
                            num_heads, dtype):
-    """Single-token attention over FLAT cache slabs ``[b, L, h*d]`` —
-    the r5 decode fix.  All heads ride ONE batched MXU matmul per
-    contraction via block-diagonal expansion (selector ``E``), so the
-    slab streams from HBM exactly once in its unpadded storage layout:
-    measured 732 GB/s (89% of v5e roofline) vs 283 GB/s logical for the
-    padded 4-D einsum it replaces.  int8 scales fold into the math
-    (cross per-channel -> q / context; self per-position -> scores /
-    probs) — the dequantized slab is never materialized.
+    """Single-token attention over FLAT cache slabs ``[b, L, h*d]``.
+    All heads ride ONE batched MXU matmul per contraction via block-
+    diagonal expansion (selector ``E``), so the slab streams from HBM
+    exactly once in its unpadded storage layout, under any loop or program
+    boundary (the module docstring has the chip's numbers).  int8 scales
+    fold into the math (cross per-channel -> q / context; self
+    per-position -> scores / probs) — the dequantized slab is never
+    materialized.
 
     q [b, 1, h, d]; bias_hl additive f32 [h, L] (carries causal masking);
     kv_mask [b, L]; k_scale/v_scale None or [b, 1, h*d] (per-channel) or
