@@ -143,6 +143,26 @@ def test_kernel_compiles_for_v5e(v5e, case):
         f"{case}: compiled without the Pallas kernel in it"
 
 
+def test_expert_product_compiles_with_its_kernel_for_v5e(v5e, monkeypatch):
+    """The routed expert product (ops/moe.py) at OLMoE's widths and the
+    serving cell's two shapes, 64 rows (a decode step) and 128 (a prefill
+    chunk) of top-8 over 64 experts: three calls of the grouped-matmul
+    kernel each.  ``expert_ffn`` takes the kernel where the backend is a TPU;
+    the described chip is not the backend, so the test says so."""
+    from tpu_air.ops import moe
+
+    monkeypatch.setattr(moe.jax, "default_backend", lambda: "tpu")
+    for rows in (64, 128):
+        args = (_struct((rows, 2048), jnp.bfloat16, v5e),
+                _struct((rows, 8), jnp.int32, v5e),
+                _struct((rows, 8), jnp.float32, v5e),
+                _struct((64, 2048, 1024), jnp.bfloat16, v5e),
+                _struct((64, 2048, 1024), jnp.bfloat16, v5e),
+                _struct((64, 1024, 2048), jnp.bfloat16, v5e))
+        text = jax.jit(moe.expert_ffn).lower(*args).compile().as_text()
+        assert text.count("tpu_custom_call") == 3, rows
+
+
 @pytest.mark.parametrize("early_stop", [True, False], ids=["while", "scan"])
 def test_generate_streams_the_cache_unpadded_on_v5e(v5e, early_stop):
     """``generate`` at the W3 shape (FLAN-T5-base, 256 x 512, bf16, 128 new

@@ -58,6 +58,7 @@ from tpu_air.models.lm.generate import (
 from tpu_air.faults import plan as _faults
 from tpu_air.observability import tracing as _tracing
 from tpu_air.observability import perf as _perf
+from tpu_air.observability.profiler import phase
 
 from .kvpool import PagedKVPool
 from .metrics import EngineMetrics, unregister
@@ -725,7 +726,10 @@ class InferenceEngine:
                 pending,
                 key=lambda s: (s.plan.chunks_left, s.request.request_id),
             )
-            self._run_chunk(slot)
+            with phase("engine.prefill", tokens=self.config.page_len,
+                       start=slot.plan.next_start,
+                       queued=self.scheduler.depth()):
+                self._run_chunk(slot)
             ran = True
         return ran
 
@@ -1009,58 +1013,66 @@ class InferenceEngine:
         return 0
 
     def _decode_all(self) -> None:
-        t0 = time.monotonic()
-        if self.paged:
-            # non-decoding rows (free OR mid-prefill) ride along pointed at
-            # the null page: their ride-along scatter can't touch a live or
-            # prefix-shared page.  The authoritative table stays host-side.
-            table = self.pool.block_table.copy()
-            for s in self.slots.slots:
-                if not s.active or s.prefilling:
-                    table[s.index] = self._null_entry(s.index)
-            if self.adapters_enabled:
-                # per-slot LoRA rows gathered the way the table is: one
-                # host array in, no retrace, row 0 = exact-zero delta
-                self.cache, nxt = self._decode_step(
-                    self.params, self.cache,
-                    jnp.asarray(self._cur_tok), jnp.asarray(self._pos),
-                    jnp.asarray(table),
-                    self._adapter_a, self._adapter_b,
-                    jnp.asarray(self._adapter_ids_host),
-                )
-            else:
-                self.cache, nxt = self._decode_step(
-                    self.params, self.cache,
-                    jnp.asarray(self._cur_tok), jnp.asarray(self._pos),
-                    jnp.asarray(table),
-                )
-        else:
+        live = [s for s in self.slots.active_slots() if not s.prefilling]
+        with phase("engine.step", live=len(live),
+                   batch=self.config.num_slots):
+            t0 = time.monotonic()
+            with phase("engine.dispatch"):
+                nxt = self._dispatch_decode()
+            with phase("engine.readback"):
+                nxt = np.asarray(nxt)
+            dt = time.monotonic() - t0
+            if self._decode_cost is not None:
+                self.metrics.record_program(
+                    "decode_step", self._decode_cost, dt)
+            if len(nxt) > self.config.num_slots:
+                # sparse experts: the step's routing counters ride behind
+                # the tokens (make_paged_decode_body)
+                self.metrics.record_routing(
+                    nxt[self.config.num_slots:-1], int(nxt[-1]))
+            # one phase around the walk over the live rows, none per row
+            with phase("engine.emit", emitted=len(live)):
+                for slot in live:
+                    # airlint: disable=JX004 — nxt is the np.asarray'd step
+                    # result; the single device sync already happened above
+                    token = int(nxt[slot.index])
+                    slot.request.stream._emit(token)
+                    slot.pos += 1
+                    slot.budget_left -= 1
+                    self._cur_tok[slot.index] = token
+                    self._pos[slot.index] = slot.pos
+                    if slot.budget_left == 0 or (
+                        self.eos_token_id is not None
+                        and token == self.eos_token_id
+                    ):
+                        self._retire(slot)
+                self.metrics.record_step(dt, len(live))
+
+    def _dispatch_decode(self):
+        """Issue one pool decode step; returns the step's (device) output."""
+        if not self.paged:
             self.cache, nxt = self._decode_step(
                 self.params, self.cache,
                 jnp.asarray(self._cur_tok), jnp.asarray(self._pos),
             )
-        nxt = np.asarray(nxt)
-        dt = time.monotonic() - t0
-        if self._decode_cost is not None:
-            self.metrics.record_program("decode_step", self._decode_cost, dt)
-        emitted = 0
-        for slot in self.slots.active_slots():
-            if slot.prefilling:
-                continue
-            # airlint: disable=JX004 — nxt is the np.asarray'd step result;
-            # the single device sync already happened above the loop
-            token = int(nxt[slot.index])
-            slot.request.stream._emit(token)
-            emitted += 1
-            slot.pos += 1
-            slot.budget_left -= 1
-            self._cur_tok[slot.index] = token
-            self._pos[slot.index] = slot.pos
-            if slot.budget_left == 0 or (
-                self.eos_token_id is not None and token == self.eos_token_id
-            ):
-                self._retire(slot)
-        self.metrics.record_step(dt, emitted)
+            return nxt
+        # non-decoding rows (free OR mid-prefill) ride along pointed at
+        # the null page: their ride-along scatter can't touch a live or
+        # prefix-shared page.  The authoritative table stays host-side.
+        table = self.pool.block_table.copy()
+        for s in self.slots.slots:
+            if not s.active or s.prefilling:
+                table[s.index] = self._null_entry(s.index)
+        args = (self.params, self.cache,
+                jnp.asarray(self._cur_tok), jnp.asarray(self._pos),
+                jnp.asarray(table))
+        if self.adapters_enabled:
+            # per-slot LoRA rows gathered the way the table is: one
+            # host array in, no retrace, row 0 = exact-zero delta
+            args += (self._adapter_a, self._adapter_b,
+                     jnp.asarray(self._adapter_ids_host))
+        self.cache, nxt = self._decode_step(*args)
+        return nxt
 
     # -- retirement ----------------------------------------------------------
     def _retire(self, slot: Slot) -> None:

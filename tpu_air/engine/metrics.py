@@ -28,6 +28,8 @@ from typing import Any, Deque, Dict, Optional
 
 from collections import deque
 
+import numpy as np
+
 from tpu_air.observability.perf import (
     Histogram,
     PerfLedger,
@@ -99,6 +101,11 @@ class EngineMetrics:
         # base model), recorded at retirement — airwatch's cost-ledger feed
         # (same absent-until-used contract: empty until the first retire)
         self.tenants: Dict[str, Dict[str, float]] = {}
+        # sparse-expert routing (absent for dense models): assignments the
+        # decode steps made in all, and to each expert (summed over layers)
+        self.moe_expert_load = None   # int64 [E] once a step reported
+        self.moe_experts_streamed = 0
+        self.moe_steps = 0
         register(self)
 
     def set_topology(self, **kw: Any) -> None:
@@ -232,6 +239,17 @@ class EngineMetrics:
             self._token_stamps.append((now, tokens))
             self._trim_stamps(now)
 
+    def record_routing(self, counts, streamed: int) -> None:
+        """One decode step's routing, as read back with its tokens: the
+        assignments to each expert ``[E]`` (decoding rows only, summed over
+        layers) and the experts the step streamed (summed over layers)."""
+        with self._lock:
+            self.moe_experts_streamed += streamed
+            self.moe_steps += 1
+            if self.moe_expert_load is None:
+                self.moe_expert_load = np.zeros(len(counts), np.int64)
+            self.moe_expert_load += counts
+
     def record_program(self, kind: str, cost: ProgramCost,
                        seconds: float) -> None:
         """Ledger feed: one compiled-program execution's analytic cost and
@@ -334,6 +352,11 @@ class EngineMetrics:
             if self.tenants:
                 out["tenants"] = {t: dict(d)
                                   for t, d in self.tenants.items()}
+            if self.moe_expert_load is not None:
+                out["moe_assignments"] = int(self.moe_expert_load.sum())
+                out["moe_expert_load"] = self.moe_expert_load.tolist()
+                out["moe_experts_streamed"] = self.moe_experts_streamed
+                out["moe_steps"] = self.moe_steps
         out["tokens_per_s"] = self.tokens_per_s()
         return out
 

@@ -16,6 +16,13 @@ class LMConfig:
     * ``"ring"``  — ring attention over the ``sequence_axis`` mesh axis
       (ops/ring_attention.py): each device holds L/P of the sequence and
       K/V shards rotate over ICI, so context length scales with the mesh.
+
+    ``num_experts > 0`` makes every block's feed-forward a routed expert
+    layer (``modeling.SparseExperts``): ``num_experts`` SwiGLU experts of
+    width ``d_ff``, each token summed over its ``num_experts_per_tok`` most
+    probable ones.  ``qk_norm`` puts an RMSNorm over the whole q and k
+    projections before the split into heads.  Both are what OLMoE publishes
+    (``hf_import.lm_config_from_hf`` maps its key names onto these).
     """
 
     vocab_size: int = 32000
@@ -42,12 +49,20 @@ class LMConfig:
     block_k: Optional[int] = None
     pad_token_id: int = 0
     eos_token_id: Optional[int] = None  # None: generation never early-stops
+    num_experts: int = 0          # 0: one dense SwiGLU a block
+    num_experts_per_tok: int = 0
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.head_dim is None:
             self.head_dim = self.d_model // self.n_heads
         if self.d_ff is None:
             self.d_ff = int(8 * self.d_model / 3 + 255) // 256 * 256
+        if self.num_experts and not (
+                1 <= self.num_experts_per_tok <= self.num_experts):
+            raise ValueError(
+                f"num_experts_per_tok {self.num_experts_per_tok} is not in "
+                f"1..num_experts ({self.num_experts})")
 
     def to_dict(self) -> dict:
         return asdict(self)

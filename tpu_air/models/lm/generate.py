@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from tpu_air.models.sampling import sample_token
 
 from .config import LMConfig
-from .modeling import CausalLM, head_weight
+from .modeling import CausalLM, expert_assignments, head_weight
 
 
 def init_cache(model: CausalLM, batch_size: int):
@@ -322,6 +322,52 @@ def init_paged_cache(model: CausalLM, num_slots: int, num_pages: int,
     return rebuild(base)
 
 
+def _apply_paged(model: CausalLM, slot_len: int):
+    """``fn(params, cache, ids, positions) -> (cache', hidden, rows)``: the
+    model applied once over a paged cache, the way both engine bodies do.
+    ``rows`` is ``[layers, tokens, E]`` expert assignments for a
+    sparse-expert model (``modeling.expert_assignments``), else None."""
+    cfg = model.config
+    dmodel = CausalLM(LMConfig.from_dict(
+        {**cfg.to_dict(), "max_seq_len": slot_len}))
+    mutable = ["cache", "intermediates"] if cfg.num_experts else ["cache"]
+
+    def apply(params, cache, ids, positions):
+        hidden, vars_ = dmodel.apply(
+            {"params": params, "cache": cache}, ids, positions,
+            decode=True, return_hidden=True, mutable=mutable,
+        )
+        rows = (expert_assignments(vars_["intermediates"])
+                if cfg.num_experts else None)
+        return vars_["cache"], hidden, rows
+
+    return apply
+
+
+def make_paged_decode_logits_body(model: CausalLM, slot_len: int):
+    """``fn(params, cache, tok, pos, block_table) -> (cache', h, logits,
+    rows)``: one paged decode step up to the head — ``h [S, D]`` float32
+    last hidden states, ``logits [S, V]`` float32, ``rows`` as
+    :func:`_apply_paged` gives them.  :func:`make_paged_decode_body` samples
+    from it; a checker that wants the step's logits (the benchmark's
+    comparison with the reference) calls this, so both run one program
+    text."""
+    cfg = model.config
+    apply = _apply_paged(model, slot_len)
+
+    def logits_step(params, cache, tok, pos, block_table):
+        pos = pos.astype(jnp.int32)
+        cache = _map_cache_index(cache, lambda _: pos)
+        cache = _map_cache_leaf(
+            cache, "block_table",
+            lambda _: block_table.astype(jnp.int32))
+        cache, hidden, rows = apply(params, cache, tok[:, None], pos[:, None])
+        h = hidden[:, -1].astype(jnp.float32)
+        return cache, h, h @ head_weight(params, cfg).astype(jnp.float32), rows
+
+    return logits_step
+
+
 def make_paged_decode_body(model: CausalLM, slot_len: int,
                            adapters: bool = False):
     """The UNJITTED paged decode step body: ``fn(params, cache, tok, pos,
@@ -337,32 +383,34 @@ def make_paged_decode_body(model: CausalLM, slot_len: int,
     ``(h @ bank_a[id]) @ bank_b[id]`` gathered exactly the way the block
     table gathers pages: one dynamic-gather per step, no per-tenant
     retrace.  Bank row 0 is the zero adapter, so slots with id 0 compute
-    an exact-zero delta and stay bit-identical to the base model."""
-    cfg = model.config
-    dcfg = {**cfg.to_dict(), "max_seq_len": slot_len}
+    an exact-zero delta and stay bit-identical to the base model.
+
+    For a sparse-expert model (``config.num_experts``) ``next_tok`` is
+    ``[S + E + 1]``: the ``S`` tokens; then the step's assignments to each
+    of the ``E`` experts summed over layers and over the DECODING rows
+    (``pos > 0``: the engine keeps a free or prefilling row at position 0,
+    and a decoding row is past its prompt); then the number of experts the
+    step streamed, summed over layers (an expert any row was routed to, idle
+    rows too: they are computed like the rest).  One array, so the routing
+    counters reach the host in the read-back that fetches the tokens."""
+    logits_step = make_paged_decode_logits_body(model, slot_len)
 
     def step(params, cache, tok, pos, block_table,
              bank_a=None, bank_b=None, adapter_ids=None):
-        dmodel = CausalLM(LMConfig.from_dict(dcfg))
-        pos = pos.astype(jnp.int32)
-        cache = _map_cache_index(cache, lambda _: pos)
-        cache = _map_cache_leaf(
-            cache, "block_table",
-            lambda _: block_table.astype(jnp.int32))
-        hidden, vars_ = dmodel.apply(
-            {"params": params, "cache": cache}, tok[:, None], pos[:, None],
-            decode=True, return_hidden=True, mutable=["cache"],
-        )
-        head_w = head_weight(params, cfg).astype(jnp.float32)
-        h = hidden[:, -1].astype(jnp.float32)
-        logits = h @ head_w
+        cache, h, logits, rows = logits_step(params, cache, tok, pos,
+                                             block_table)
         if adapters:
             a = bank_a[adapter_ids]                      # [S, d, r]
             b = bank_b[adapter_ids]                      # [S, r, V]
             logits = logits + jnp.einsum(
                 "sr,srv->sv", jnp.einsum("sd,sdr->sr", h, a), b)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return vars_["cache"], nxt
+        if rows is not None:
+            live = (pos > 0).astype(jnp.int32)[None, :, None]
+            nxt = jnp.concatenate([
+                nxt, (rows * live).sum((0, 1)),
+                (rows.sum(1) > 0).sum(dtype=jnp.int32)[None]])
+        return cache, nxt
 
     if not adapters:
         def base_step(params, cache, tok, pos, block_table):
@@ -381,8 +429,39 @@ def make_lm_paged_decode_step_fn(model: CausalLM, slot_len: int,
     scatter can't touch a live or prefix-shared page).  ``adapters=True``
     appends the LoRA bank args (see :func:`make_paged_decode_body`); the
     banks are NOT donated — they persist across steps like params."""
-    return jax.jit(make_paged_decode_body(model, slot_len, adapters),
-                   donate_argnums=(1,))
+    body = make_paged_decode_body(model, slot_len, adapters)
+    # the program's name in a device trace ("XLA Modules": jit_<name>)
+    body.__name__ = "lm_paged_decode_step"
+    return jax.jit(body, donate_argnums=(1,))
+
+
+def make_prefill_chunk_logits_body(model: CausalLM, page_len: int,
+                                   slot_len: int):
+    """``fn(params, cache, ids, p0, last_local, table_row) -> (cache',
+    h_last, logits)``: one prefill chunk up to the head — ``h_last [D]`` the
+    float32 hidden state at ``last_local``, ``logits [V]`` float32 there.
+    :func:`make_prefill_chunk_body` samples from it (see
+    :func:`make_paged_decode_logits_body`)."""
+    cfg = model.config
+    apply = _apply_paged(model, slot_len)
+
+    def logits_chunk(params, cache, ids, p0, last_local, table_row):
+        p0 = p0.astype(jnp.int32)
+        # leaf shapes must stay [S]/[S, npg] across chunk and decode calls
+        # (shape-stable donation); only row 0 is consulted at b=1
+        cache = _map_cache_index(
+            cache, lambda v: jnp.full(v.shape, p0, jnp.int32))
+        cache = _map_cache_leaf(
+            cache, "block_table",
+            lambda v: jnp.broadcast_to(
+                table_row.astype(jnp.int32)[None], v.shape))
+        positions = (p0 + jnp.arange(page_len, dtype=jnp.int32))[None]
+        cache, hidden, _ = apply(params, cache, ids, positions)
+        h_last = hidden[0, last_local.astype(jnp.int32)].astype(jnp.float32)
+        return cache, h_last, h_last @ head_weight(params, cfg).astype(
+            jnp.float32)
+
+    return logits_chunk
 
 
 def make_prefill_chunk_body(model: CausalLM, page_len: int, slot_len: int,
@@ -398,33 +477,16 @@ def make_prefill_chunk_body(model: CausalLM, page_len: int, slot_len: int,
     and the final chunk's first greedy token gets the same LoRA head
     delta as the decode body, so a tenant's stream is adapter-consistent
     from token 0."""
-    cfg = model.config
-    dcfg = {**cfg.to_dict(), "max_seq_len": slot_len}
+    logits_chunk = make_prefill_chunk_logits_body(model, page_len, slot_len)
 
     def prefill_chunk(params, cache, ids, p0, last_local, table_row,
                       bank_a=None, bank_b=None, adapter_id=None):
-        dmodel = CausalLM(LMConfig.from_dict(dcfg))
-        p0 = p0.astype(jnp.int32)
-        # leaf shapes must stay [S]/[S, npg] across chunk and decode calls
-        # (shape-stable donation); only row 0 is consulted at b=1
-        cache = _map_cache_index(
-            cache, lambda v: jnp.full(v.shape, p0, jnp.int32))
-        cache = _map_cache_leaf(
-            cache, "block_table",
-            lambda v: jnp.broadcast_to(
-                table_row.astype(jnp.int32)[None], v.shape))
-        positions = (p0 + jnp.arange(page_len, dtype=jnp.int32))[None]
-        hidden, vars_ = dmodel.apply(
-            {"params": params, "cache": cache}, ids, positions,
-            decode=True, return_hidden=True, mutable=["cache"],
-        )
-        head_w = head_weight(params, cfg).astype(jnp.float32)
-        h_last = hidden[0, last_local.astype(jnp.int32)].astype(jnp.float32)
-        logits = h_last @ head_w
+        cache, h_last, logits = logits_chunk(params, cache, ids, p0,
+                                             last_local, table_row)
         if adapters:
             logits = logits + (h_last @ bank_a[adapter_id]) @ bank_b[adapter_id]
         tok = jnp.argmax(logits).astype(jnp.int32)
-        return vars_["cache"], tok
+        return cache, tok
 
     if not adapters:
         def base_chunk(params, cache, ids, p0, last_local, table_row):
@@ -456,9 +518,9 @@ def make_lm_prefill_chunk_fn(model: CausalLM, page_len: int, slot_len: int,
     Fixed shapes -> ONE compiled program covers every prompt length; the
     engine interleaves these calls between decode steps so long prompts
     stream in without stalling in-flight decodes."""
-    return jax.jit(make_prefill_chunk_body(model, page_len, slot_len,
-                                           adapters),
-                   donate_argnums=(1,))
+    body = make_prefill_chunk_body(model, page_len, slot_len, adapters)
+    body.__name__ = "lm_prefill_chunk"
+    return jax.jit(body, donate_argnums=(1,))
 
 
 def page_copy_body(cache, dst, src):

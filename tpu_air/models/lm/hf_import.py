@@ -1,0 +1,138 @@
+"""Published (Hugging Face) OLMoE configuration and weights -> ``LMConfig``
+and this framework's ``CausalLM`` parameter tree.
+
+Beside T5's importer (models/t5/hf_import.py).  Pure numpy: the converter
+only transposes, permutes and stacks, so it works on any element type (the
+benchmark hands it 16-bit views of bf16 tensors).
+
+The one boundary that is not a renaming is the rotary embedding.  The
+published model pairs dimension ``i`` of a head with ``i + d/2`` ("rotate
+half"); ``modeling.rope`` pairs ``2i`` with ``2i + 1``.  A dot product of a
+rotated query and key is the same in any ordering of a head's dimensions as
+long as both use it, so the importer reorders the COLUMNS of the q and k
+projections inside each head (``rope_columns``): column ``2i`` of the
+program is the published column ``i``, column ``2i + 1`` the published
+``i + d/2``.  The q and k RMSNorms sit between projection and rope, act
+elementwise over the whole ``h*d`` vector and divide by a mean no ordering
+changes, so their weights are reordered the same way.  v, o and everything
+else are untouched.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable, Dict
+
+import numpy as np
+
+from .config import LMConfig
+
+#: published keys this importer maps, and the ``LMConfig`` field of each
+HF_KEYS = {
+    "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads",
+    "intermediate_size": "d_ff",          # the width of ONE expert
+    "num_experts": "num_experts",
+    "num_experts_per_tok": "num_experts_per_tok",
+    "vocab_size": "vocab_size",
+    "max_position_embeddings": "max_seq_len",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rmsnorm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+
+#: published keys that must hold exactly this value: what the layer as
+#: written in modeling.py computes (no bias, no clipping, top-k weights not
+#: renormalised, plain rope, SiLU gate, every head with its own K/V)
+HF_FIXED = {
+    "model_type": "olmoe",
+    "attention_bias": False,
+    "clip_qkv": None,
+    "norm_topk_prob": False,
+    "rope_scaling": None,
+    "hidden_act": "silu",
+}
+
+
+def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
+                      **overrides: Any) -> LMConfig:
+    """``LMConfig`` of a published ``olmoe`` ``config.json`` (a dict).
+    Refuses a configuration whose layer this framework does not compute."""
+    for key, want in HF_FIXED.items():
+        if hf.get(key, want) != want:
+            raise ValueError(
+                f"published {key}={hf.get(key)!r}: only {want!r} is "
+                "implemented (models/lm/modeling.py)")
+    kv = hf.get("num_key_value_heads", hf["num_attention_heads"])
+    if kv != hf["num_attention_heads"]:
+        raise ValueError(
+            f"num_key_value_heads {kv} != num_attention_heads: grouped K/V "
+            "heads are not implemented")
+    fields = {ours: hf[theirs] for theirs, ours in HF_KEYS.items()}
+    fields.update(
+        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
+        qk_norm=True,  # part of the published olmoe layer, not a key
+        pad_token_id=hf.get("pad_token_id") or 0,
+        eos_token_id=hf.get("eos_token_id"),
+        dtype=dtype)
+    fields.update(overrides)
+    return LMConfig(**fields)
+
+
+def rope_columns(n_heads: int, head_dim: int) -> np.ndarray:
+    """``perm`` with ``program[..., j] = published[..., perm[j]]`` over the
+    ``n_heads * head_dim`` outputs of a q or k projection."""
+    half = head_dim // 2
+    inside = np.empty(head_dim, np.int64)
+    inside[0::2] = np.arange(half)
+    inside[1::2] = np.arange(half) + half
+    return (np.arange(n_heads)[:, None] * head_dim + inside[None]).reshape(-1)
+
+
+def _t(w) -> np.ndarray:
+    return np.ascontiguousarray(np.asarray(w).T)
+
+
+def convert_olmoe_layer(get: Callable[[str], Any], i: int,
+                        config: LMConfig) -> Dict[str, Any]:
+    """Layer ``i`` of the tree, from ``get(published tensor name)``."""
+    pre = f"model.layers.{i}."
+    perm = rope_columns(config.n_heads, config.head_dim)
+    attn = {
+        "q": {"kernel": _t(get(pre + "self_attn.q_proj.weight"))[:, perm]},
+        "k": {"kernel": _t(get(pre + "self_attn.k_proj.weight"))[:, perm]},
+        "v": {"kernel": _t(get(pre + "self_attn.v_proj.weight"))},
+        "o": {"kernel": _t(get(pre + "self_attn.o_proj.weight"))},
+        "q_norm": {"weight": np.asarray(
+            get(pre + "self_attn.q_norm.weight"))[perm]},
+        "k_norm": {"weight": np.asarray(
+            get(pre + "self_attn.k_norm.weight"))[perm]},
+    }
+    experts = range(config.num_experts)
+    stack = lambda which: np.stack([  # noqa: E731
+        _t(get(f"{pre}mlp.experts.{e}.{which}_proj.weight")) for e in experts])
+    return {
+        "attn": attn,
+        "attn_norm": {"weight": np.asarray(
+            get(pre + "input_layernorm.weight"))},
+        "mlp_norm": {"weight": np.asarray(
+            get(pre + "post_attention_layernorm.weight"))},
+        "moe": {"router": _t(get(pre + "mlp.gate.weight")),
+                "gate": stack("gate"), "up": stack("up"),
+                "down": stack("down")},
+    }
+
+
+def convert_olmoe_state_dict(get: Callable[[str], Any],
+                             config: LMConfig) -> Dict[str, Any]:
+    """The whole ``CausalLM`` parameter tree from a published ``olmoe``
+    state dict, given as ``get(name)`` (``sd.__getitem__`` for a dict)."""
+    params: Dict[str, Any] = {
+        "embedding": np.asarray(get("model.embed_tokens.weight")),
+        "final_norm": {"weight": np.asarray(get("model.norm.weight"))},
+    }
+    if not config.tie_embeddings:
+        params["lm_head"] = {"kernel": _t(get("lm_head.weight"))}
+    for i in range(config.n_layers):
+        params[f"layer_{i}"] = convert_olmoe_layer(get, i, config)
+    return params

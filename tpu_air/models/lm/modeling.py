@@ -80,8 +80,13 @@ class CausalSelfAttention(nn.Module):
             return nn.Dense(out, use_bias=False, dtype=dtype,
                             kernel_init=nn.initializers.normal(0.02), name=name)
 
-        q = proj("q", h * d)(x).reshape(b, l, h, d).transpose(0, 2, 1, 3)
-        k = proj("k", h * d)(x).reshape(b, l, h, d).transpose(0, 2, 1, 3)
+        q, k = proj("q", h * d)(x), proj("k", h * d)(x)
+        if cfg.qk_norm:
+            # over the whole h*d projection, before the split into heads
+            q = RMSNorm(cfg.rmsnorm_eps, dtype, name="q_norm")(q)
+            k = RMSNorm(cfg.rmsnorm_eps, dtype, name="k_norm")(k)
+        q = q.reshape(b, l, h, d).transpose(0, 2, 1, 3)
+        k = k.reshape(b, l, h, d).transpose(0, 2, 1, 3)
         v = proj("v", h * d)(x).reshape(b, l, h, d).transpose(0, 2, 1, 3)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
@@ -281,6 +286,51 @@ class SwiGLU(nn.Module):
         return dense("down", cfg.d_model)(gate * up)
 
 
+class SparseExperts(nn.Module):
+    """Routed feed-forward: ``y[t] = sum over the top-k experts e of p[t, e]
+    * down_e(silu(gate_e x[t]) * up_e x[t])`` with ``p = softmax(x @ router)``
+    in float32 and NOT renormalised over the chosen k.  Every assignment is
+    computed (``ops.moe.expert_ffn``: no capacity, nothing dropped or
+    re-routed, whatever the load).  Sows ``expert_rows`` ``[tokens, E]``
+    int32 (1 where the token went to the expert) into ``intermediates`` for
+    callers that make it mutable: the engine's routing counters."""
+
+    config: LMConfig
+
+    @nn.compact
+    def __call__(self, x: Array) -> Array:
+        from tpu_air.ops.moe import expert_ffn
+
+        cfg = self.config
+        dtype = jnp.dtype(cfg.dtype)
+        e, k, d, f = (cfg.num_experts, cfg.num_experts_per_tok, cfg.d_model,
+                      cfg.d_ff)
+        init = nn.initializers.normal(0.02)
+        router = self.param("router", init, (d, e), jnp.float32)
+        gate = self.param("gate", init, (e, d, f), jnp.float32)
+        up = self.param("up", init, (e, d, f), jnp.float32)
+        down = self.param("down", init, (e, f, d), jnp.float32)
+        t = x.reshape(-1, d)
+        logits = jnp.dot(t.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        probs, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        self.sow("intermediates", "expert_rows",
+                 jnp.zeros((t.shape[0], e), jnp.int32).at[
+                     jnp.arange(t.shape[0])[:, None], chosen].set(1))
+        y = expert_ffn(t.astype(dtype), chosen, probs, gate.astype(dtype),
+                       up.astype(dtype), down.astype(dtype))
+        return y.astype(dtype).reshape(x.shape)
+
+
+def expert_assignments(intermediates) -> Array:
+    """``[layers, tokens, E]`` int32: what every ``SparseExperts`` layer
+    sowed as ``expert_rows`` (callers sum or count over the layers: their
+    order here is the tree's, not the model's)."""
+    return jnp.stack([
+        v for path, v in jax.tree_util.tree_flatten_with_path(intermediates)[0]
+        if any(getattr(p, "key", None) == "expert_rows" for p in path)])
+
+
 class Block(nn.Module):
     config: LMConfig
 
@@ -294,9 +344,10 @@ class Block(nn.Module):
             RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(x), positions,
             decode=decode,
         ))
-        x = x + drop(SwiGLU(cfg, name="mlp")(
-            RMSNorm(cfg.rmsnorm_eps, dtype, name="mlp_norm")(x)
-        ))
+        # the feed-forward kind follows from the configuration's numbers
+        ff = (SparseExperts(cfg, name="moe") if cfg.num_experts
+              else SwiGLU(cfg, name="mlp"))
+        x = x + drop(ff(RMSNorm(cfg.rmsnorm_eps, dtype, name="mlp_norm")(x)))
         return x
 
 

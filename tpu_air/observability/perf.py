@@ -370,6 +370,15 @@ class LMCostModel:
       positions (QK^T and AV, 2 flops/MAC each).
     * KV bytes/position: ``L * 2*H*Dh * b`` (K and V, all layers).
 
+    Sparse experts (``config.num_experts = E > 0``, ``k`` a token): the
+    feed-forward STORES ``E * 3*D*F`` parameters a layer plus the ``D*E``
+    router, and a token COMPUTES with ``k * 3*D*F`` of them plus the router,
+    so stored bytes and operations part ways.  A program streams every
+    expert at least one of its tokens is routed to; with ``n`` assignments
+    spread over ``E`` experts that is priced at its expectation under even
+    routing, ``E * (1 - (1 - 1/E)**n)`` experts a layer (63.98 of 64 at a
+    128-token chunk of top-8), an upper estimate when routing is skewed.
+
     Norms, rotary embeddings and softmax are omitted (≪1% of the matmul
     budget at any real geometry); the model is deliberately closed-form so
     identical claims can be recomputed anywhere (arXiv:2204.06514 §4).
@@ -383,15 +392,40 @@ class LMCostModel:
         self.d_ff = int(config.d_ff)
         self.vocab_size = int(config.vocab_size)
         self.tie_embeddings = bool(getattr(config, "tie_embeddings", True))
+        self.num_experts = int(getattr(config, "num_experts", 0) or 0)
+        self.experts_per_tok = int(
+            getattr(config, "num_experts_per_tok", 0) or 0)
         self.dtype_bytes = _DTYPE_BYTES.get(
             str(getattr(config, "dtype", "float32")), 4)
 
     # -- derived geometry ----------------------------------------------------
     @property
+    def _attn_params(self) -> int:
+        return 4 * self.d_model * self.n_heads * self.head_dim
+
+    @property
+    def _expert_params(self) -> int:
+        """One SwiGLU of width ``d_ff``: the dense feed-forward, or one
+        expert."""
+        return 3 * self.d_model * self.d_ff
+
+    def _layer_params(self, experts: int) -> int:
+        """Matrix parameters of the layers with ``experts`` experts counted
+        in each (a dense model has the one feed-forward and no router)."""
+        ff = self._expert_params
+        if self.num_experts:
+            ff = experts * ff + self.d_model * self.num_experts
+        return self.n_layers * (self._attn_params + ff)
+
+    @property
     def matmul_params(self) -> int:
-        hd = self.n_heads * self.head_dim
-        return self.n_layers * (
-            4 * self.d_model * hd + 3 * self.d_model * self.d_ff)
+        """Matrix parameters STORED in the layers."""
+        return self._layer_params(self.num_experts)
+
+    @property
+    def active_matmul_params(self) -> int:
+        """Matrix parameters one token COMPUTES with in the layers."""
+        return self._layer_params(self.experts_per_tok)
 
     @property
     def param_count(self) -> int:
@@ -404,9 +438,28 @@ class LMCostModel:
     def param_bytes(self) -> int:
         return self.param_count * self.dtype_bytes
 
+    def experts_touched(self, tokens: int) -> float:
+        """Experts of one layer a program over ``tokens`` tokens streams
+        (expectation under even routing); 0 for a dense model."""
+        if not self.num_experts:
+            return 0.0
+        e = self.num_experts
+        return e * (1.0 - (1.0 - 1.0 / e) ** (tokens * self.experts_per_tok))
+
+    def streamed_param_bytes(self, tokens: int) -> float:
+        """Parameter bytes a program over ``tokens`` tokens reads: all of
+        them for a dense model (the embedding table is read by row, but is
+        priced whole as it always was); for sparse experts only the experts
+        touched."""
+        if not self.num_experts:
+            return float(self.param_bytes)
+        idle = self.num_experts - self.experts_touched(tokens)
+        return (self.param_count - self.n_layers * idle
+                * self._expert_params) * self.dtype_bytes
+
     @property
     def linear_flops_per_token(self) -> float:
-        return 2.0 * (self.matmul_params
+        return 2.0 * (self.active_matmul_params
                       + self.d_model * self.vocab_size)
 
     @property
@@ -427,7 +480,7 @@ class LMCostModel:
         of occupancy — that is what the machine executes)."""
         flops = rows * (self.linear_flops_per_token
                         + self.attention_flops(attended))
-        hbm = (self.param_bytes
+        hbm = (self.streamed_param_bytes(rows)
                + rows * attended * self.kv_bytes_per_position   # KV read
                + rows * self.kv_bytes_per_position)             # KV write
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=rows)
@@ -442,7 +495,7 @@ class LMCostModel:
         attended_sum = c * start_pos + c * (c + 1) / 2.0
         flops = (c * self.linear_flops_per_token
                  + self.attention_flops(attended_sum))
-        hbm = (self.param_bytes
+        hbm = (self.streamed_param_bytes(c)
                + (start_pos + c) * self.kv_bytes_per_position   # prefix read
                + c * self.kv_bytes_per_position)                # KV write
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=c)
