@@ -14,6 +14,8 @@ Layers under test:
   * the T5 window engine: parity with offline T5 generate.
 """
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -21,6 +23,7 @@ import pytest
 
 from tpu_air.engine import (
     BlockAllocator,
+    EngineClosedError,
     EngineConfig,
     InferenceEngine,
     KVPoolOOMError,
@@ -529,4 +532,176 @@ def test_t5_window_engine_matches_offline_generate():
     for s, w in zip(streams, want):
         assert s.result(5.0) == w
     assert engine.metrics.snapshot()["requests_completed"] == 5
+    engine.close()
+
+
+# -- one step in flight: EOS learnt a step late, nothing uploaded a step ------
+
+
+@pytest.fixture(scope="module")
+def t5_tiny():
+    """Tiny T5 weights, three prompts and their first eight greedy tokens
+    (no early stop): what a window must stream, up to the EOS a test picks."""
+    from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
+    from tpu_air.models.t5.generate import generate as t5_generate
+
+    cfg = T5Config.tiny()
+    model = T5ForConditionalGeneration(cfg)
+    enc = jnp.ones((2, 8), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), enc, jnp.ones_like(enc),
+                        jnp.ones((2, 6), jnp.int32))["params"]
+    rng = np.random.RandomState(71)  # the parity test's prompts, three of them
+    prompts = [list(map(int, rng.randint(2, 384, size=rng.randint(3, 8))))
+               for _ in range(5)][1:4]
+    ids = np.full((len(prompts), 8), cfg.pad_token_id, np.int32)
+    for r, p in enumerate(prompts):
+        ids[r, :len(p)] = p
+    mask = (ids != cfg.pad_token_id).astype(np.int32)
+    ref = np.asarray(t5_generate(model, params, jnp.asarray(ids),
+                                 attention_mask=jnp.asarray(mask),
+                                 max_new_tokens=8, early_stop=False)).tolist()
+    # row 0 brings a token at its fifth place that it has not had before and
+    # that row 1 never has: the EOS of the tests below
+    eos = ref[0][4]
+    assert eos not in ref[0][:4] and eos not in ref[1], ref
+    return cfg, params, prompts, ref, eos
+
+
+def _t5_engine(t5_tiny, name, eos=None, max_new=8, **kw):
+    """A hand-stepped window engine over the fixture's weights whose model
+    ends a row on ``eos`` (the weights do not know which token that is)."""
+    import dataclasses
+
+    from tpu_air.models.t5 import T5ForConditionalGeneration
+
+    cfg, params = t5_tiny[:2]
+    if eos is not None:
+        cfg = dataclasses.replace(cfg, eos_token_id=eos)
+    return T5Engine(
+        T5ForConditionalGeneration(cfg), params,
+        T5EngineConfig(max_batch=2, max_input_len=8, max_new_tokens=max_new),
+        name=name, **kw)
+
+
+def _run_dry(engine):
+    steps = 0
+    while not engine.idle():
+        engine.step()
+        steps += 1
+        assert steps < 200, "t5 engine failed to drain"
+
+
+@pytest.mark.parametrize("other_budget, issued, ahead, dropped", [
+    # the other row runs on to its budget of 8: 7 steps, each one read
+    (8, 7, 6, 0),
+    # the other row ends on its budget of 3, so the row that ends on EOS at
+    # its fifth token is the window's last: the sixth token's step is out
+    # when the host learns of it, and nobody reads it
+    (3, 5, 4, 1),
+], ids=["another-row-runs-on", "the-last-live-row"])
+def test_t5_window_learns_of_eos_one_step_late_and_emits_nothing_past_it(
+        t5_tiny, other_budget, issued, ahead, dropped):
+    _, _, prompts, ref, eos = t5_tiny
+    engine = _t5_engine(t5_tiny, f"t5-eos-{other_budget}", eos=eos,
+                        auto_start=False)
+    ending = engine.submit(prompts[0], 8)
+    other = engine.submit(prompts[1], other_budget)
+    _run_dry(engine)
+    assert ending.result(5.0) == ref[0][:5] and ending.result()[-1] == eos
+    assert other.result(5.0) == ref[1][:other_budget]
+    snap = engine.metrics.snapshot()
+    assert (snap["steps_issued"], snap["steps_ahead"],
+            snap["steps_dropped"]) == (issued, ahead, dropped)
+    assert snap["tokens_emitted"] == 5 + other_budget
+    # the next window opens behind the dropped step and streams as ever
+    late = engine.submit(prompts[2], 4)
+    assert not engine.idle()
+    engine.drain()
+    assert not engine.drained()
+    _run_dry(engine)
+    assert late.result(5.0) == ref[2][:4]
+    assert engine.idle() and engine.drained()
+    snap = engine.metrics.snapshot()
+    assert (snap["steps_issued"], snap["steps_dropped"]) == (issued + 3,
+                                                             dropped)
+    assert snap["requests_completed"] == 3
+    engine.close()
+    assert engine.metrics.snapshot()["steps_dropped"] == dropped
+
+
+def test_t5_step_counters_reach_metrics_only_from_an_engine_that_issues():
+    from tpu_air.engine.metrics import (
+        EngineMetrics,
+        merge_snapshots,
+        prometheus_lines,
+        unregister,
+    )
+
+    ahead, plain = EngineMetrics("ahead-test"), EngineMetrics("plain-test")
+    try:
+        for is_ahead in (False, True, True):
+            ahead.record_issue(is_ahead)
+        ahead.record_dropped_step()
+        snaps = {"a": ahead.snapshot(), "p": plain.snapshot()}
+    finally:
+        unregister("ahead-test")
+        unregister("plain-test")
+    assert "steps_issued" not in snaps["p"]
+    merged = merge_snapshots(snaps)
+    assert (merged["steps_issued"], merged["steps_ahead"],
+            merged["steps_dropped"]) == (3, 2, 1)
+    assert "steps_issued" not in merge_snapshots({"p": snaps["p"]})
+    lines = prometheus_lines(snaps)
+    for key, n in (("issued", 3), ("ahead", 2), ("dropped", 1)):
+        assert f'tpu_air_engine_steps_{key}{{engine="a"}} {n}' in lines
+    assert not any('steps_issued{engine="p"}' in ln for ln in lines)
+
+
+def test_t5_close_with_a_step_in_flight_fails_live_streams_and_joins(
+        t5_tiny):
+    import threading
+
+    _, _, prompts, ref, _ = t5_tiny
+    engine = _t5_engine(t5_tiny, "t5-close-test", max_new=512)
+    stream = engine.submit(prompts[0], 512)
+    deadline = time.monotonic() + 60.0
+    while not stream.tokens_so_far():
+        assert time.monotonic() < deadline, "no first token"
+        time.sleep(0.001)
+    engine.close()
+    with pytest.raises(EngineClosedError):
+        stream.result(5.0)
+    got = stream.tokens_so_far()
+    assert 1 <= len(got) < 512 and got[:8] == ref[0][:len(got)]
+    snap = engine.metrics.snapshot()
+    # the first token is the prefill's; every issued step was read and
+    # emitted, but the one the close dropped
+    assert snap["steps_dropped"] == 1
+    assert snap["steps_issued"] == len(got)
+    assert engine._window is None and engine._thread is None
+    assert not any(t.name == "tpu-air-t5-close-test"
+                   for t in threading.enumerate())
+    with pytest.raises(EngineClosedError):
+        engine.submit(prompts[1])
+
+
+def test_t5_decode_steps_upload_nothing(t5_tiny):
+    """The step path takes its tokens, mask, encoder output and cache from
+    the device: with host-to-device transfers disallowed, explicit ones
+    too, every step of an open window runs (the parent uploaded ``cur_tok``
+    and ``enc_mask`` each step and fails here)."""
+    _, _, prompts, ref, _ = t5_tiny
+    engine = _t5_engine(t5_tiny, "t5-upload-test", auto_start=False)
+    streams = [engine.submit(p, 8) for p in prompts[:2]]
+    engine.step()  # the window opens: ids and mask go up, once
+    assert not engine.idle()
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        with pytest.raises(Exception, match="host-to-device"):
+            jnp.asarray(np.zeros(2, np.int32))  # the guard is enforced here
+        steps = 0
+        while engine._window is not None:
+            engine.step()
+            steps += 1
+    assert steps == 7
+    assert [s.result(5.0) for s in streams] == ref[:2]
     engine.close()

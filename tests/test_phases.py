@@ -111,7 +111,7 @@ def engine_phases(tmp_path_factory):
                for n in (3, 8, 5, 4, 6)]
     trace_dir = str(tmp_path_factory.mktemp("engine-trace"))
     engine.generate(prompts[:2], max_new_tokens=2)  # compile outside the trace
-    before = engine.metrics.snapshot()["tokens_emitted"]
+    before = engine.metrics.snapshot()
     jax.profiler.start_trace(trace_dir)
     try:
         streams = [engine.submit(p, max_new_tokens=2 + i)
@@ -124,9 +124,11 @@ def engine_phases(tmp_path_factory):
     finally:
         jax.profiler.stop_trace()
     tokens = sum(len(s.result(5.0)) for s in streams)
-    emitted = engine.metrics.snapshot()["tokens_emitted"] - before
+    after = engine.metrics.snapshot()
     engine.close()
-    return _phases(trace_dir), tokens, emitted
+    counted = {k: after[k] - before[k] for k in (
+        "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped")}
+    return _phases(trace_dir), tokens, counted
 
 
 def test_engine_step_holds_dispatch_readback_emit_in_order(engine_phases):
@@ -135,22 +137,30 @@ def test_engine_step_holds_dispatch_readback_emit_in_order(engine_phases):
     assert len(steps) >= 5
     for st in steps:
         kids = _inside(phases, st)
-        assert [k[0] for k in kids] == [
-            "engine.dispatch", "engine.readback", "engine.emit"]
+        # the next step goes out (at most once, and first), then the step
+        # before it is read back, then emitted
+        assert [k[0] for k in kids] == (
+            ["engine.dispatch"] * st[3]["ahead"]
+            + ["engine.readback", "engine.emit"])
         assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
         assert 1 <= st[3]["live"] <= st[3]["batch"] == 2
-        assert kids[2][3] == {"emitted": st[3]["live"]}
-    # no phase per row or per token: three children a step, one prefill a
-    # window, nothing else from the engine
+        assert kids[-1][3] == {"emitted": st[3]["live"]}
+    # budgets 2, 3 | 4, 5 | 6: each window's last token step has nothing to
+    # issue ahead of it (the window's first went out at its opening)
+    ahead = [st[3]["ahead"] for st in steps]
+    assert ahead == [1, 0] + [1, 1, 1, 0] + [1, 1, 1, 1, 0]
+    # no phase per row or per token: a read-back and an emit a step, a
+    # dispatch where one was issued, one prefill a window, nothing else
     assert {p[0] for p in phases} == {
         "engine.prefill", "engine.step", "engine.dispatch",
         "engine.readback", "engine.emit"}
-    assert len(phases) == 4 * len(steps) + 3
+    assert len(phases) == 3 * len(steps) + sum(ahead) + 3
 
 
 def test_engine_prefill_counts_and_emitted_sum_to_the_engines_tokens(
         engine_phases):
-    phases, tokens, emitted = engine_phases
+    phases, tokens, counted = engine_phases
+    emitted = counted["tokens_emitted"]
     prefills = [p for p in phases if p[0] == "engine.prefill"]
     # five requests, two rows a window; what each window left in the queue
     assert [(p[3]["rows"], p[3]["batch"], p[3]["queued"])
@@ -159,6 +169,20 @@ def test_engine_prefill_counts_and_emitted_sum_to_the_engines_tokens(
     first = sum(p[3]["rows"] for p in prefills)
     later = sum(p[3]["emitted"] for p in phases if p[0] == "engine.emit")
     assert first + later == emitted == tokens
+
+
+def test_engine_step_ahead_counts_are_the_engines_step_counters(
+        engine_phases):
+    phases, _, counted = engine_phases
+    steps = [p for p in phases if p[0] == "engine.step"]
+    dispatches = [p for p in phases if p[0] == "engine.dispatch"]
+    # a dispatch phase is a step issued ahead; each window's first step goes
+    # out inside its engine.prefill; budgets end these windows, so every
+    # issued step is read by one engine.step
+    assert counted["steps_ahead"] == len(dispatches) == sum(
+        st[3]["ahead"] for st in steps)
+    assert counted["steps_issued"] == len(steps) == len(dispatches) + 3
+    assert counted["steps_dropped"] == 0
 
 
 def test_train_loop_phases_per_step_and_epoch(air, tmp_path):

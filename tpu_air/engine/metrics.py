@@ -106,6 +106,12 @@ class EngineMetrics:
         self.moe_expert_load = None   # int64 [E] once a step reported
         self.moe_experts_streamed = 0
         self.moe_steps = 0
+        # one-step-ahead decode (T5Engine; absent for engines that issue
+        # and read a step in turn): decode steps issued, those issued
+        # before the step before was read back, those never read
+        self.steps_issued = 0
+        self.steps_ahead = 0
+        self.steps_dropped = 0
         register(self)
 
     def set_topology(self, **kw: Any) -> None:
@@ -239,6 +245,19 @@ class EngineMetrics:
             self._token_stamps.append((now, tokens))
             self._trim_stamps(now)
 
+    def record_issue(self, ahead: bool) -> None:
+        """One decode step handed to the device; ``ahead`` when the step
+        before it had not been read back yet."""
+        with self._lock:
+            self.steps_issued += 1
+            self.steps_ahead += bool(ahead)
+
+    def record_dropped_step(self) -> None:
+        """An issued step nobody read: its window closed (every live row
+        ended on EOS one step earlier) or the engine did."""
+        with self._lock:
+            self.steps_dropped += 1
+
     def record_routing(self, counts, streamed: int) -> None:
         """One decode step's routing, as read back with its tokens: the
         assignments to each expert ``[E]`` (decoding rows only, summed over
@@ -357,6 +376,10 @@ class EngineMetrics:
                 out["moe_expert_load"] = self.moe_expert_load.tolist()
                 out["moe_experts_streamed"] = self.moe_experts_streamed
                 out["moe_steps"] = self.moe_steps
+            if self.steps_issued:
+                out["steps_issued"] = self.steps_issued
+                out["steps_ahead"] = self.steps_ahead
+                out["steps_dropped"] = self.steps_dropped
         out["tokens_per_s"] = self.tokens_per_s()
         return out
 
@@ -416,6 +439,9 @@ def merge_snapshots(snapshots: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
                                        for e in entries]),
         }
     out["priority"] = prio
+    for key in ("steps_issued", "steps_ahead", "steps_dropped"):
+        if any(key in s for s in snaps):
+            out[key] = sum(int(s.get(key, 0)) for s in snaps)
     perfs = [s.get("perf") for s in snaps if s.get("perf")]
     if perfs:
         out["perf"] = merge_ledger_snapshots(perfs)
@@ -498,6 +524,12 @@ _FAMILIES = [
      "admissions taken out of FIFO order"),
     ("tpu_air_engine_prefill_chunks", "counter",
      "prefill chunk programs executed"),
+    ("tpu_air_engine_steps_issued", "counter",
+     "decode steps handed to the device (one-step-ahead engines)"),
+    ("tpu_air_engine_steps_ahead", "counter",
+     "decode steps issued before the step before was read back"),
+    ("tpu_air_engine_steps_dropped", "counter",
+     "decode steps issued and never read (the window closed on EOS)"),
     ("tpu_air_engine_roofline_fraction", "gauge",
      "achieved fraction of the analytic roofline (perf ledger totals)"),
     ("tpu_air_engine_flops_per_s", "gauge",
@@ -589,7 +621,8 @@ def prometheus_lines(snapshots: Dict[str, Dict[str, Any]] = None) -> list:
                 b.declare(fam, "gauge", f"paged KV pool: {key}")
                 kvpool_declared.add(fam)
             b.raw(fam, f"{fam}{tag} {val:g}")
-        for key in ("reordered_admits", "prefill_chunks"):
+        for key in ("reordered_admits", "prefill_chunks", "steps_issued",
+                    "steps_ahead", "steps_dropped"):
             if key in snap:
                 b.raw(f"tpu_air_engine_{key}",
                       f"tpu_air_engine_{key}{tag} {snap[key]}")

@@ -22,6 +22,26 @@ therefore a WINDOW engine, honest about that boundary:
   causal-LM slot engine exists to avoid; per-slot cross-attn slabs remain
   the open item before T5 can join the slot pool (ROADMAP).
 
+ONE STEP IN FLIGHT.  Within a window the decode loop runs one step ahead
+of its host: step N+1 is issued from step N's tokens as they lie on the
+device (the step's ``int32[b]`` output is the next call's ``tok``; the
+encoder mask is uploaded once, when the window opens), and only then is step
+N read back, emitted and retired from — so the host's whole visit (issue,
+read-back, the walk over the rows) runs under a device step instead of
+between two.  What follows from reading one step late:
+
+* budgets are host state, so a step is issued only if some row that was live
+  after the last processed read-back still has budget for it: a window whose
+  rows end on their budgets issues exactly the steps it reads;
+* a row that ends on EOS is learnt of one step late.  It rides along for
+  that step the way retired rows ride until the window closes, and its extra
+  token is discarded, never emitted;
+* if the last live rows all end on EOS, the one issued step is dropped with
+  the window's cache (``steps_dropped``); the next window's prefill queues
+  behind it on the device.
+
+Depth is one and fixed.  The step path uploads nothing.
+
 Greedy by construction: token streams are identical to offline T5
 ``generate`` with ``early_stop=True`` on the same window batch.
 """
@@ -89,15 +109,24 @@ class T5EngineConfig:
 
 
 class _Window:
-    """One in-flight batch: device cache + per-row host bookkeeping."""
+    """One in-flight batch: device state + per-row host bookkeeping.
 
-    def __init__(self, requests: List[Request], cache, enc, enc_mask):
-        self.requests: List[Optional[Request]] = list(requests)
+    ``cache``, ``enc`` and ``enc_mask`` live on the device for the window's
+    life.  ``unread`` is the ``int32[b]`` device output of the one issued
+    step the host has not read back yet: the next step's ``tok``, and what
+    the next read-back copies.  ``mark`` is when the stream's current token
+    step began: the end of the last read-back, or for a window's first step
+    its issue."""
+
+    def __init__(self, requests: List[Optional[Request]], cache, enc,
+                 enc_mask):
+        self.requests = requests
         self.cache = cache
         self.enc = enc
         self.enc_mask = enc_mask
-        self.cur_tok = np.zeros((enc_mask.shape[0],), np.int32)
-        self.budget_left = np.zeros((enc_mask.shape[0],), np.int64)
+        self.unread = None
+        self.mark = 0.0
+        self.budget_left = np.zeros((len(requests),), np.int64)
 
     def live_rows(self):
         return [i for i, r in enumerate(self.requests) if r is not None]
@@ -192,8 +221,10 @@ class T5Engine:
     # -- the engine loop -----------------------------------------------------
     def step(self) -> bool:
         """One engine iteration: open a window if none is in flight (one
-        prefill over the queued batch), else one decode step.  Returns True
-        if any work happened."""
+        prefill over the queued batch, and the window's first decode step
+        issued behind it), else one token step: issue the next decode step,
+        then read back and emit the one before.  Returns True if any work
+        happened."""
         with self._step_lock:
             worked = False
             if self._window is None:
@@ -210,6 +241,8 @@ class T5Engine:
             return worked
 
     def idle(self) -> bool:
+        # an issued step lives in its window (``_Window.unread``) and a
+        # window closes when nothing is left to read: no window, no step
         with self._step_lock:  # _window is step-loop state (see step())
             return self.scheduler.depth() == 0 and self._window is None
 
@@ -247,11 +280,12 @@ class T5Engine:
             mask[row, :len(req.prompt)] = 1
         # rows past len(reqs) are dead filler: all-pad, zero mask — their
         # decode outputs are discarded host-side
-        tok, cache, enc = self._prefill(
-            self.params, jnp.asarray(ids), jnp.asarray(mask))
-        tok = np.asarray(tok)
+        mask_dev = jnp.asarray(mask)
+        tok_dev, cache, enc = self._prefill(
+            self.params, jnp.asarray(ids), mask_dev)
+        tok = np.asarray(tok_dev)
         rows: List[Optional[Request]] = list(reqs) + [None] * (b - len(reqs))
-        win = _Window(rows, cache, enc, mask)
+        win = _Window(rows, cache, enc, mask_dev)
         now = time.monotonic()
         emitted = 0
         for row, req in enumerate(reqs):
@@ -260,27 +294,43 @@ class T5Engine:
             self.metrics.record_ttft(now - req.submitted_at)
             req.stream._emit(first)
             emitted += 1
-            win.cur_tok[row] = first
             win.budget_left[row] = req.max_new_tokens - 1
             if win.budget_left[row] == 0 or first == self.eos_token_id:
                 self._retire(win, row)
         self.metrics.record_tokens(emitted)
-        self._window = win if win.live_rows() else None
+        if win.live_rows():
+            # the window's first step, from the prefill's tokens as they
+            # lie on the device; nothing is in flight, so it is not ahead
+            self._issue(win, tok_dev, ahead=False)
+            win.mark = time.monotonic()
+            self._window = win
+
+    def _issue(self, win: _Window, tok, ahead: bool) -> None:
+        """Issue one decode step from the device tokens ``tok``.  The cache
+        is donated; ``tok`` is not, so the host can still read it back."""
+        win.cache, win.unread = self._decode_step(
+            self.params, win.cache, tok, win.enc, win.enc_mask)
+        self.metrics.record_issue(ahead)
 
     def _decode_window(self) -> None:
         win = self._window
         live = win.live_rows()
+        # budgets are host state: step N+1 is worth issuing only if a live
+        # row has a token left after the one step N holds for it
+        ahead = any(win.budget_left[row] >= 2 for row in live)
         with phase("engine.step", live=len(live),
-                   batch=self.config.max_batch):
-            t0 = time.monotonic()
-            with phase("engine.dispatch"):
-                win.cache, nxt = self._decode_step(
-                    self.params, win.cache, jnp.asarray(win.cur_tok), win.enc,
-                    jnp.asarray(win.enc_mask),
-                )
+                   batch=self.config.max_batch, ahead=int(ahead)):
+            unread, win.unread = win.unread, None
+            if ahead:
+                # out before step N is read: the device runs it while the
+                # host reads, emits and retires below
+                with phase("engine.dispatch"):
+                    self._issue(win, unread, ahead=True)
             with phase("engine.readback"):
-                nxt = np.asarray(nxt)
-            dt = time.monotonic() - t0
+                nxt = np.asarray(unread)
+            # what this token step cost the stream: read-back to read-back
+            now = time.monotonic()
+            dt, win.mark = now - win.mark, now
             # one phase around the walk over the live rows, none per row
             with phase("engine.emit", emitted=len(live)):
                 for row in live:
@@ -289,7 +339,6 @@ class T5Engine:
                     token = int(nxt[row])
                     req = win.requests[row]
                     req.stream._emit(token)
-                    win.cur_tok[row] = token
                     win.budget_left[row] -= 1
                     if win.budget_left[row] == 0 or token == self.eos_token_id:
                         self._retire(win, row)
@@ -297,7 +346,15 @@ class T5Engine:
                 if not win.live_rows():
                     # window drained: drop its cache, admit the next batch
                     # on the following step
-                    self._window = None
+                    self._drop_window()
+
+    def _drop_window(self) -> None:
+        """Close the window.  A step still unread (its last live rows ended
+        on EOS, or the engine is closing) is dropped with the cache: the
+        device finishes it and whatever comes next queues behind."""
+        if self._window.unread is not None:
+            self.metrics.record_dropped_step()
+        self._window = None
 
     def _retire(self, win: _Window, row: int) -> None:
         win.requests[row].stream._finish()
@@ -335,7 +392,7 @@ class T5Engine:
             if self._window is not None:
                 for row in self._window.live_rows():
                     self._window.requests[row].stream._finish(err)
-                self._window = None
+                self._drop_window()
         unregister(self.name)
 
     def __enter__(self) -> "T5Engine":
