@@ -539,14 +539,11 @@ def _decode_step_bytes(config, batch, enc_len, max_decode_len) -> dict:
         "total_bytes": cross_kv + self_kv + params_b,
     }
     if int8_cache:
-        # honest caveat: the reduced cross AND self slab bytes assume no
-        # dequantized slab is materialized.  On the default flat decode
-        # path (decode_attention_impl="auto"/"pallas") that holds BY
-        # CONSTRUCTION — scales fold into q/scores/probs/context, never a
-        # slab-wide multiply.  On the legacy "einsum" comparison path XLA
-        # may materialize the widened K/V; the materialization-pessimistic
-        # upper bound (every int8 slab re-expanded full-width each step)
-        # is reported alongside for that case.
+        # the reduced cross AND self slab bytes assume no dequantized slab
+        # is materialized: the flat decode step folds the scales into
+        # q/scores/probs/context, never a slab-wide multiply.  The
+        # materialization-pessimistic upper bound (every int8 slab
+        # re-expanded full-width each step) is reported alongside.
         out["assumes_fused_dequant"] = True
         cross_kv_wide = 2 * batch * enc_len * h_d * bytes_el * layers
         self_kv_wide = 2 * batch * max_decode_len * h_d * bytes_el * layers
@@ -593,8 +590,6 @@ def _measure_generation(model, config, params, batch=256, enc_len=512,
         "batch": batch,
         "enc_len": enc_len,
         "max_new_tokens": max_new_tokens,
-        "decode_attention_impl": getattr(config, "decode_attention_impl",
-                                         "auto"),
         "seq_per_sec": round(batch / per, 1),
         "new_tokens_per_sec": round(batch * max_new_tokens / per, 1),
         "call_s": round(per, 3),
@@ -983,7 +978,7 @@ def main() -> int:
     results["einsum"] = _measure_slope(
         model, config, params, batch, enc_len, dec_len, steps_short)
     # flash path (Pallas kernel)
-    flash_config = T5Config.from_dict({**config.to_dict(), "use_flash_attention": True})
+    flash_config = T5Config.from_dict({**config.to_dict(), "attention_impl": "flash"})
     results["flash"] = _measure_slope(
         T5ForConditionalGeneration(flash_config), flash_config, params,
         batch, enc_len, dec_len, steps_short)
@@ -993,15 +988,6 @@ def main() -> int:
         sections["long_context_attention"] = _measure_long_context_attention()
     if budget_left("generation"):
         sections["generation"] = _measure_generation(model, config, params)
-    if budget_left("generation_flat"):
-        # block-diagonal flat-formulation comparison, measured side-by-side
-        # with "auto" above (auto = dense-from-flat for bf16 per
-        # BENCH_r05.json: 179.2 vs 161.2 seq/s) so the dispatch choice
-        # stays pinned to data round over round
-        cfg_fl = T5Config.from_dict({**config.to_dict(),
-                                     "decode_attention_impl": "flat"})
-        sections["generation_flat_blockdiag"] = _measure_generation(
-            T5ForConditionalGeneration(cfg_fl), cfg_fl, params)
     if budget_left("generation_int8"):
         # opt-in int8 cross-KV cache: halves the dominant decode HBM term —
         # measured side-by-side so the artifact shows the delta

@@ -56,9 +56,8 @@ _HERE = os.path.dirname(os.path.abspath(__file__))
 
 @dataclass(frozen=True)
 class Sizes:
-    """The shapes the phases run at: W1 fine-tune and W3 batch generation
-    (BENCH_r04.json / BENCH_r05.json dials), and a serve window of the same
-    encoder length."""
+    """The shapes the phases run at: W1 fine-tune and W3 batch generation,
+    and a serve window of the same encoder length."""
 
     train_batch: int = 32      # per device
     enc_len: int = 512

@@ -9,8 +9,9 @@ the Mosaic kernel is really in the program (``tpu_custom_call``), so a path
 that quietly took interpret mode fails.  A compile that passes is not a chip
 run: numbers and results come from ``chip_smoke.py`` and ``-m tpu``.
 
-The last test compiles ``generate`` itself at the W3 shape and reads what the
-compiler made of the decode cache's layout (no kernel in it).
+The last two tests compile the flat decode attention and ``generate`` itself
+at the W3 shape and read what the compiler made of the decode cache's layout
+(no kernel in them).
 """
 
 import os
@@ -25,7 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import (  # noqa: E402
     Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding)
 
-from tpu_air.ops.decode_attention import decode_attention  # noqa: E402
+from tpu_air.ops.decode_attention import flat_decode_attention  # noqa: E402
 from tpu_air.ops.flash_attention import flash_attention  # noqa: E402
 from tpu_air.ops.ring_attention import ring_attention_sharded  # noqa: E402
 
@@ -88,25 +89,6 @@ def _flash_causal(devs, grad: bool):
     return fwd, (qkv, qkv, qkv)
 
 
-def _decode(devs, cache):
-    """One decode token against the W3 cross-attention cache (b 256, L 512):
-    bf16, int8 with a scale per position, int8 with a scale per channel."""
-    b, L = 256, 512
-    q = _struct((b, 1, H, D), jnp.bfloat16, devs)
-    kv = _struct((b, L, H * D),
-                 jnp.bfloat16 if cache == "bf16" else jnp.int8, devs)
-    mask = _struct((b, L), jnp.int32, devs)
-    if cache == "bf16":
-        fn = lambda q, k, v, mask: decode_attention(  # noqa: E731
-            q, k, v, kv_mask=mask, interpret=False)
-        return fn, (q, kv, kv, mask)
-    scale = _struct((b, L, H, 1) if cache == "int8_pos" else (b, 1, H, D),
-                    jnp.float32, devs)
-    fn = lambda q, k, v, mask, ks, vs: decode_attention(  # noqa: E731
-        q, k, v, kv_mask=mask, k_scale=ks, v_scale=vs, interpret=False)
-    return fn, (q, kv, kv, mask, scale, scale)
-
-
 def _ring(devs):
     """Causal ring attention over the four chips of the host, L 4096 (1024 on
     each chip)."""
@@ -128,9 +110,6 @@ CASES = {
     "flash_fwd_t5_bias_mask": _flash_t5,
     "flash_fwd_causal_2048": lambda d: _flash_causal(d, grad=False),
     "flash_bwd_causal_2048": lambda d: _flash_causal(d, grad=True),
-    "decode_bf16": lambda d: _decode(d, "bf16"),
-    "decode_int8_per_position": lambda d: _decode(d, "int8_pos"),
-    "decode_int8_per_channel": lambda d: _decode(d, "int8_chan"),
     "ring_causal_4_chips": _ring,
 }
 
@@ -161,6 +140,27 @@ def test_expert_product_compiles_with_its_kernel_for_v5e(v5e, monkeypatch):
                 _struct((64, 1024, 2048), jnp.bfloat16, v5e))
         text = jax.jit(moe.expert_ffn).lower(*args).compile().as_text()
         assert text.count("tpu_custom_call") == 3, rows
+
+
+@pytest.mark.parametrize("cache", ["bf16", "int8_per_position",
+                                   "int8_per_channel"])
+def test_flat_decode_attention_compiles_for_v5e(v5e, cache):
+    """One decode token against the W3 cross-attention cache (b 256, L 512):
+    bf16, int8 with a scale per position, int8 with a scale per channel.  The
+    chip's compiler takes the step every decode cell runs and makes no
+    ``[256, 512, 12, 64]`` array of a slab, of any type."""
+    b, L = 256, 512
+    q = _struct((b, 1, H, D), jnp.bfloat16, v5e)
+    kv = _struct((b, L, H * D),
+                 jnp.bfloat16 if cache == "bf16" else jnp.int8, v5e)
+    mask = _struct((b, L), jnp.int32, v5e)
+    scale = {"bf16": None,
+             "int8_per_position": _struct((b, L, H), jnp.float32, v5e),
+             "int8_per_channel": _struct((b, 1, H * D), jnp.float32, v5e)}[cache]
+    fn = lambda q, k, v, mask, ks, vs: flat_decode_attention(  # noqa: E731
+        q, k, v, None, mask, ks, vs, H, jnp.bfloat16)
+    compiled = jax.jit(fn).lower(q, kv, kv, mask, scale, scale).compile()
+    assert "[256,512,12,64]" not in compiled.as_text()
 
 
 @pytest.mark.parametrize("early_stop", [True, False], ids=["while", "scan"])

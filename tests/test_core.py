@@ -347,7 +347,7 @@ def test_chip_lease_shapes_follow_topology():
 def test_task_pool_grows_to_num_cpus(air):
     """Driver-submitted task parallelism must reach num_cpus, not stall at
     the initial min(2, num_cpus) pool (W9's 20-parallel-tasks contract,
-    Overview_of_Ray.ipynb:cc-41; found by tools/bench_dispatch.py r5)."""
+    Overview_of_Ray.ipynb:cc-41; found by a dispatch benchmark in r5)."""
     import time as _t
 
     def nap():

@@ -142,12 +142,9 @@ def _offline(model, params, prompt, max_new, eos):
     return out
 
 
-def test_mesh_engine_requires_paged_and_divisible(lm):
+def test_mesh_engine_requires_divisible_slots(lm):
     _cfg, model, params = lm
-    with pytest.raises(ValueError):
-        MeshEngine(model, params, EngineConfig(kv_mode="slab"), dp=2, tp=1,
-                   auto_start=False)
-    with pytest.raises(ValueError):
+    with pytest.raises(ValueError, match="not divisible by dp"):
         MeshEngine(model, params, EngineConfig(num_slots=3), dp=2, tp=1,
                    auto_start=False)
 

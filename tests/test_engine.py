@@ -124,13 +124,15 @@ def test_slot_manager_lowest_row_and_reuse():
     assert m.acquire().index == 0  # lowest free row first
 
 
-def test_engine_config_buckets():
-    cfg = EngineConfig(slot_len=48)
-    assert cfg.buckets() == (1, 2, 4, 8, 16, 32, 48)
-    assert cfg.bucket_for(5) == 8
-    assert cfg.bucket_for(48) == 48
-    with pytest.raises(ValueError):
-        cfg.bucket_for(49)
+def test_engine_config_rejects_removed_options():
+    """One KV layout: a caller that still names the removed layout options
+    is told so at construction (a ``TypeError``), not silently ignored."""
+    with pytest.raises(TypeError, match="kv_mode"):
+        EngineConfig(kv_mode="slab")
+    with pytest.raises(TypeError, match="prefill_buckets"):
+        EngineConfig(prefill_buckets=(8, 16))
+    cfg = EngineConfig(slot_len=48, page_len=16)
+    assert (cfg.pages_per_slot(), cfg.pool_pages()) == (3, 8 * 3 + 1)
 
 
 # ---------------------------------------------------------------------------

@@ -301,23 +301,46 @@ def test_scheduler_reorder_window_zero_is_strict_fifo():
     assert s.reordered_admits == 0 and s.depth() == 3
 
 
+@pytest.mark.parametrize("page_len", [8, 16])
+def test_gather_pages_places_every_position(page_len):
+    """``gather_pages``: position ``p`` of slot ``s`` is
+    ``pool[table[s, p // C], p % C]``, for unreached entries on the null
+    page and for a page two rows share (a prefix hit) alike."""
+    from tpu_air.ops.decode_attention import gather_pages
+
+    C, npg, hd = page_len, 3, 4
+    P = 6
+    # every pool element names its own (page, offset, channel)
+    pool = jnp.asarray(np.arange(P * C * hd).reshape(P, C, hd), jnp.float32)
+    table = np.array([[4, 2, NULL_PAGE],      # two pages reached
+                      [4, 5, 1],              # shares page 4 with row 0
+                      [NULL_PAGE] * 3],       # a free slot
+                     np.int32)
+    got = np.asarray(gather_pages(pool, jnp.asarray(table)))
+    assert got.shape == (3, npg * C, hd)
+    want = np.asarray(pool)
+    for s in range(3):
+        for p in range(npg * C):
+            np.testing.assert_array_equal(
+                got[s, p], want[table[s, p // C], p % C])
+
+
 # ---------------------------------------------------------------------------
 # the paged engine, end to end
 # ---------------------------------------------------------------------------
 
 
-def test_paged_engine_matches_offline_and_slab(lm):
+def test_paged_engine_matches_offline_and_mesh(lm):
     """The ISSUE acceptance anchor: the paged engine is token-identical to
-    offline greedy generate — and to the slab engine and the sharded
-    MeshEngine (dp=2, tp=2 over the forced-8-device CPU host) — on the
-    same burst."""
+    offline greedy generate — and so is the sharded MeshEngine (dp=2, tp=2
+    over the forced-8-device CPU host) — on the same burst."""
     from tpu_air.engine import MeshEngine
 
     cfg, model, params = lm
     prompts = _prompts(seed=21, n=6)
     max_new = 8
     outs = {}
-    for mode in ("paged", "slab", "mesh"):
+    for mode in ("paged", "mesh"):
         if mode == "mesh":
             if len(jax.devices()) < 4:
                 continue  # rig needs the conftest's forced device count
@@ -331,7 +354,7 @@ def test_paged_engine_matches_offline_and_slab(lm):
             engine = InferenceEngine(
                 model, params,
                 EngineConfig(num_slots=3, slot_len=64, max_new_tokens=max_new,
-                             kv_mode=mode, page_len=8),
+                             page_len=8),
                 auto_start=False, name=f"kvpool-parity-{mode}",
             )
         streams = [engine.submit(p) for p in prompts]

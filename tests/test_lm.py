@@ -402,3 +402,58 @@ def test_lm_trainer_tensor_parallel_fit(air):
     )
     r2 = bad.fit()
     assert r2.error is not None and "cannot be combined" in str(r2.error)
+
+
+def _all_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else (v,):
+                inner = getattr(x, "jaxpr", x)
+                if hasattr(inner, "eqns"):
+                    yield from _all_eqns(inner)
+
+
+@pytest.mark.parametrize("kind", ["paged_step", "generate_step"])
+def test_lm_cached_step_never_views_a_slab_in_4d(kind):
+    """PR 25's guard (tests/test_t5.py) on the LM's two single-token steps:
+    no equation of the engine's paged decode step, nor of offline
+    ``generate``'s loop body, makes a ``[b, L, h, d]`` (or ``[b, h, L, d]``)
+    array for a cache length ``L``: both attend over the flat
+    ``[b, L, h*d]`` slab as stored.  The prefill's one-time view is outside
+    the loop body and is found, so the check can see one."""
+    from tpu_air.models.lm.generate import (
+        init_paged_cache, make_lm_generate_fn, make_lm_paged_decode_step_fn)
+
+    cfg = tiny_cfg()
+    model = CausalLM(cfg)
+    h, d = cfg.n_heads, cfg.head_dim
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.ones((1, 8), jnp.int32)))["params"]
+
+    def views(jaxpr, b, L):
+        shapes = {(b, L, h, d), (b, h, L, d)}
+        return [e for e in _all_eqns(jaxpr)
+                if any(tuple(v.aval.shape) in shapes for v in e.outvars)]
+
+    if kind == "paged_step":
+        S, slot_len, C = 3, 32, 8
+        npg = slot_len // C
+        cache = jax.eval_shape(
+            lambda: init_paged_cache(model, S, 1 + S * npg, C, npg))
+        jaxpr = jax.make_jaxpr(make_lm_paged_decode_step_fn(model, slot_len))(
+            params, cache, jnp.zeros((S,), jnp.int32),
+            jnp.zeros((S,), jnp.int32), jnp.zeros((S, npg), jnp.int32))
+        assert not views(jaxpr.jaxpr, S, slot_len)
+        return
+    b, lp, new = 3, 10, 6
+    fn = make_lm_generate_fn(model, new, eos_token_id=1, early_stop=True)
+    jaxpr = jax.make_jaxpr(fn)(
+        params, jnp.ones((b, lp), jnp.int32), jax.random.PRNGKey(0))
+    bodies = [e.params["body_jaxpr"].jaxpr for e in _all_eqns(jaxpr.jaxpr)
+              if e.primitive.name == "while"]
+    assert bodies, "no while-loop in generate"
+    for body in bodies:
+        assert not views(body, b, lp + new)
+    assert views(jaxpr.jaxpr, b, lp + new), "the prefill's view was not seen"

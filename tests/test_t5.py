@@ -202,13 +202,12 @@ def test_byte_tokenizer_save_load(tmp_path):
     assert tok2.model_max_length == 77
 
 
-@pytest.mark.parametrize("impl", ["auto", "einsum"])
-def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, impl):
+@pytest.mark.parametrize("int8", [False, True], ids=["auto", "int8"])
+def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, int8):
     """early_stop=True (the torch model.generate stopping criterion) must
     produce the identical sequences as the fixed-budget scan and actually
-    stop once every sequence emitted EOS: under the default dispatch (flat
-    slabs) and under the explicit dense path, which must also agree with
-    each other token for token (fp32)."""
+    stop once every sequence emitted EOS, over a full-width and an int8
+    cache."""
     import dataclasses
 
     import jax
@@ -218,7 +217,7 @@ def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, imp
 
     cfg, _, params = tiny
     model = T5ForConditionalGeneration(
-        dataclasses.replace(cfg, decode_attention_impl=impl))
+        dataclasses.replace(cfg, decode_cache_int8=int8))
     rng = jax.random.PRNGKey(3)
     ids = jax.random.randint(rng, (2, 12), 2, cfg.vocab_size, jnp.int32)
     mask = jnp.ones((2, 12), jnp.int32)
@@ -229,13 +228,6 @@ def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, imp
     seq_b, steps_b = fn_early(params, ids, mask, rng)
     np.testing.assert_array_equal(np.asarray(seq_a), np.asarray(seq_b))
     assert int(steps_a) == 16
-    if impl != "einsum":
-        dense = T5ForConditionalGeneration(
-            dataclasses.replace(cfg, decode_attention_impl="einsum"))
-        for early in (True, False):
-            seq_d, _ = make_generate_fn(dense, 16, early_stop=early)(
-                params, ids, mask, rng)
-            np.testing.assert_array_equal(np.asarray(seq_a), np.asarray(seq_d))
 
     # force EOS on step one by patching the sampler (the loop under test,
     # not the model): a fresh fn traces against the patched module global
@@ -363,18 +355,20 @@ def test_generate_feature_composition_int8_earlystop_bucketing(tiny):
     assert base.shape == (8, 6)
 
 
-@pytest.mark.parametrize("impl", ["auto", "flat", "pallas", "einsum"])
-def test_cached_step_logits_match_uncached_forward(tiny, impl):
+@pytest.mark.parametrize("int8", [False, True], ids=["auto", "int8"])
+def test_cached_step_logits_match_uncached_forward(tiny, int8):
     """Teacher-forced, fp32: the logits of every cached single-token step
     (flat self- and cross-attention slabs) equal the full uncached decoder
-    forward's at that position, to 2e-4 of the logits' range."""
+    forward's at that position, to 2e-4 of the logits' range; over an int8
+    cache to the 5 % of the largest logit that
+    ``test_int8_cross_kv_cache_numerics`` allows quantisation."""
     import dataclasses
 
     from tpu_air.models.t5.generate import init_cache
 
     cfg, _, params = tiny
     model = T5ForConditionalGeneration(
-        dataclasses.replace(cfg, decode_attention_impl=impl))
+        dataclasses.replace(cfg, decode_cache_int8=int8))
     rng = np.random.default_rng(11)
     ids = jnp.asarray(rng.integers(2, cfg.vocab_size, (3, 10)), jnp.int32)
     mask = jnp.asarray([[1] * 10, [1] * 7 + [0] * 3, [1] * 4 + [0] * 6], jnp.int32)
@@ -392,8 +386,11 @@ def test_cached_step_logits_match_uncached_forward(tiny, impl):
         cache = upd["cache"]
         got.append(np.asarray(logits[:, 0]))
     got = np.stack(got, axis=1)
-    span = float(want.max() - want.min())
-    np.testing.assert_allclose(got, want, atol=2e-4 * span, rtol=0)
+    if int8:
+        atol = 0.05 * float(np.abs(want).max())
+    else:
+        atol = 2e-4 * float(want.max() - want.min())
+    np.testing.assert_allclose(got, want, atol=atol, rtol=0)
 
 
 def _sub_jaxprs(eqn):
@@ -420,7 +417,7 @@ def _slab_views(jaxpr, b, lengths, h, d):
             if any(tuple(v.aval.shape) in views for v in eqn.outvars)]
 
 
-def _decode_bodies(kind, impl, int8):
+def _decode_bodies(kind, int8):
     """The jaxprs of the cached decode step as ``kind`` builds it: the body
     of ``generate``'s loop (``while`` / ``scan``) or the engine's whole step."""
     import dataclasses
@@ -428,8 +425,7 @@ def _decode_bodies(kind, impl, int8):
     from tpu_air.models.t5.generate import (
         make_generate_fn, make_t5_decode_step_fn, make_t5_prefill_fn)
 
-    cfg = dataclasses.replace(T5Config.tiny(), decode_attention_impl=impl,
-                              decode_cache_int8=int8)
+    cfg = dataclasses.replace(T5Config.tiny(), decode_cache_int8=int8)
     model = T5ForConditionalGeneration(cfg)
     b, enc_len, new = 3, 10, 6
     ids = jnp.ones((b, enc_len), jnp.int32)
@@ -453,23 +449,48 @@ def _decode_bodies(kind, impl, int8):
 @pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])
 @pytest.mark.parametrize("kind", ["while", "scan", "step"])
 def test_cached_step_never_views_a_slab_in_4d(kind, int8):
-    """Under ``"auto"`` no equation of the decode body reshapes or transposes
+    """No equation of the decode body reshapes or transposes
     a flat ``[b, L, h*d]`` cache slab to a 4-D ``[b, L, h, d]`` array: the
     TPU tiles that view's minor pair (12, 64) to (16, 128), 2.67 x the bytes,
     and whether XLA keeps it padded depends on the loop around the step
     (PERF.md, PR 25).  Held for ``generate``'s while-loop and scan and for
     the engine's donated-cache step, full-width and int8 caches."""
-    bodies, dims = _decode_bodies(kind, "auto", int8)
+    bodies, dims = _decode_bodies(kind, int8)
     assert bodies, f"no {kind} in the program"
     bad = [e for body in bodies for e in _slab_views(body, *dims)]
     assert not bad, "\n".join(str(e) for e in bad[:4])
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])
-def test_slab_view_check_sees_the_dense_path(int8):
-    """The check above is not vacuous: the explicit dense comparison path
-    (``"einsum"``) does view every layer's slabs in 4-D, and is found."""
-    bodies, dims = _decode_bodies("while", "einsum", int8)
-    bad = [e for body in bodies for e in _slab_views(body, *dims)]
-    n_slabs = 2 * 2 * T5Config.tiny().num_decoder_layers
-    assert len(bad) >= n_slabs, len(bad)
+@pytest.mark.parametrize("how", ["reshape", "reference"])
+def test_slab_view_check_sees_a_4d_view(how):
+    """The check above is not vacuous: it finds the view in a program that
+    reshapes a flat ``[b, L, h*d]`` slab into heads, and in the dense
+    reference over such a view."""
+    from tpu_air.ops.decode_attention import decode_attention_reference
+
+    b, L, h, d = 3, 10, 4, 16
+    slab = jnp.zeros((b, L, h * d), jnp.float32)
+    q = jnp.zeros((b, 1, h, d), jnp.float32)
+    if how == "reshape":
+        jaxpr = jax.make_jaxpr(lambda x: x.reshape(b, L, h, d).sum())(slab)
+    else:
+        jaxpr = jax.make_jaxpr(decode_attention_reference)(
+            q, slab.reshape(b, L, h, d).astype(jnp.bfloat16),
+            slab.reshape(b, L, h, d).astype(jnp.bfloat16))
+    assert _slab_views(jaxpr.jaxpr, b, (L, 7), h, d)
+    assert not _slab_views(jaxpr.jaxpr, b, (7,), h, d)
+
+
+def test_saved_config_with_removed_keys_still_loads():
+    """A checkpoint saved before PR 29 names ``decode_attention_impl`` and
+    ``use_flash_attention`` in its config: ``from_dict`` drops what is no
+    field, keeps the rest, and the result builds the same model."""
+    import dataclasses
+    import json
+
+    saved = {**T5Config.tiny().to_dict(), "decode_attention_impl": "pallas",
+             "use_flash_attention": False, "d_ff": 96}
+    cfg = T5Config.from_dict(saved)
+    assert cfg == dataclasses.replace(T5Config.tiny(), d_ff=96)
+    assert not hasattr(cfg, "decode_attention_impl")
+    assert T5Config.from_json(json.dumps(saved)) == cfg

@@ -367,14 +367,15 @@ def test_adapter_lifecycle_guards(lm):
     engine.close()
 
 
-def test_adapters_rejected_off_paged_and_on_mesh(lm):
+def test_adapters_rejected_on_mesh(lm):
+    from tpu_air.engine import MeshEngine
+
     cfg, model, params = lm
-    with pytest.raises(ValueError, match="paged"):
-        InferenceEngine(
+    with pytest.raises(ValueError, match="single-chip"):
+        MeshEngine(
             model, params,
-            EngineConfig(num_slots=1, slot_len=32, kv_mode="slab",
-                         adapter_slots=1),
-            auto_start=False)
+            EngineConfig(num_slots=2, slot_len=32, adapter_slots=1),
+            dp=1, tp=1, auto_start=False)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,10 @@
 """Short-sequence flash-attention tile sweep.
 
-The Pallas kernel loses to XLA dense at seq 512 with the auto tiles
-(`BENCH_r04.json` `tokens_per_sec`: 92,077 flash against 142,848 einsum on
-the W1 step); this sweeps (block_q, block_k) candidates at short sequence
-lengths on the chip and prints a table, so the crossover either moves down
-or the 512-einsum default is confirmed with data.  Slope-timed (two scan
+The Pallas kernel loses to XLA dense at seq 512 with the auto tiles (bare
+W1 steps of July 2026, git history before PR 29: 92,077 tokens/s flash
+against 142,848 einsum; no benchmark cell); this sweeps (block_q, block_k)
+candidates at short sequence lengths on the chip and prints a table, so the
+crossover either moves down or the 512-einsum default is confirmed with data.  Slope-timed (two scan
 lengths; fixed dispatch and sync costs cancel — see bench.py's module
 docstring).
 

@@ -1,8 +1,7 @@
 """tpu_air.engine — continuous-batching online inference.
 
 A fixed pool of sequence slots over per-layer KV storage — block-table
-PAGED pools with prefix sharing and chunked prefill by default
-(``kvpool/``), or the PR 1 flat slabs (``kv_mode="slab"``) — one
+PAGED pools with prefix sharing and chunked prefill (``kvpool/``) — one
 persistent compiled decode step, admission/retirement between steps, and
 per-token streaming back to callers.  The T5 family runs through
 :class:`T5Engine`, a window-level variant over the batch-synchronized T5
@@ -22,7 +21,7 @@ from .kvpool import (
 )
 from .metrics import EngineMetrics, snapshot_all
 from .scheduler import Scheduler
-from .slots import Slot, SlotManager, make_insert_fn
+from .slots import Slot, SlotManager
 from .t5_engine import T5Engine, T5EngineConfig
 from .types import (
     EngineClosedError,
@@ -57,6 +56,5 @@ __all__ = [
     "SlotManager",
     "T5Engine",
     "T5EngineConfig",
-    "make_insert_fn",
     "snapshot_all",
 ]

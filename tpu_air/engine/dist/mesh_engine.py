@@ -58,8 +58,6 @@ class MeshEngine(InferenceEngine):
                  lease_timeout: Optional[float] = 60.0,
                  auto_start: bool = True, name: str = "mesh-engine"):
         cfg = config or EngineConfig()
-        if cfg.kv_mode != "paged":
-            raise ValueError("MeshEngine requires kv_mode='paged'")
         if cfg.adapter_slots > 0:
             raise ValueError(
                 "adapter_slots is single-chip-only for now: the sharded "
@@ -125,7 +123,7 @@ class MeshEngine(InferenceEngine):
     def _pages_per_replica(self) -> int:
         cfg = self.config
         if cfg.num_pages is None:
-            # slab-equivalent capacity per replica, each with its own null
+            # every slot of a replica can fill its slot_len, plus its own null
             # page (dp * this stays dp-divisible, unlike S*ppslot + 1)
             return (cfg.num_slots // self._dp) * cfg.pages_per_slot() + 1
         if cfg.num_pages % self._dp != 0:
@@ -160,9 +158,6 @@ class MeshEngine(InferenceEngine):
             self.model, cfg.page_len, cfg.slot_len, self.mesh,
             self._param_sh, self._cache_sh)
         self._copy_fn = make_sharded_page_copy_fn(self.mesh, self._cache_sh)
-
-    def _build_slab_state(self) -> None:  # pragma: no cover — ctor rejects
-        raise ValueError("MeshEngine requires kv_mode='paged'")
 
     # -- per-replica admission ------------------------------------------------
     def _begin_admission_round(self) -> None:

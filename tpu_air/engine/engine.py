@@ -3,36 +3,30 @@
 One :class:`InferenceEngine` owns a fixed pool of ``S`` sequence slots and
 keeps a single persistent jit-compiled decode step alive over that pool for
 its whole lifetime (the cache is donated — device KV updates in place,
-never copied).  Two KV layouts share the host loop (``EngineConfig.kv_mode``):
-
-* **paged** (default) — per-layer page pools ``[P, page_len, h*d]`` plus a
-  host block table mapping slot positions onto refcounted pages
-  (engine/kvpool/).  Prompts prefill in page-sized CHUNKS interleaved
-  between decode steps (one compiled chunk program covers every prompt
-  length); prompts sharing a cached prefix skip the covered chunks and
-  share the physical pages, copy-on-write on the first divergent append.
-* **slab** — the PR 1 layout: one private ``[slot_len]`` KV row per slot,
-  whole-prompt bucketed prefill.  Kept as the bench baseline and for the
-  T5 window engine.
+never copied).  The slots' K/V live in per-layer page pools
+``[P, page_len, h*d]`` plus a host block table mapping slot positions onto
+refcounted pages (engine/kvpool/).  Prompts prefill in page-sized CHUNKS
+interleaved between decode steps (one compiled chunk program covers every
+prompt length); prompts sharing a cached prefix skip the covered chunks and
+share the physical pages, copy-on-write on the first divergent append.
 
 Requests flow through three host-side phases BETWEEN device steps:
 
-1. **admission** — FIFO from the scheduler queue (paged: gated on KV-page
+1. **admission** — FIFO from the scheduler queue, gated on KV-page
    capacity with a bounded reorder window so a big blocked head can't
-   starve small requests behind it).
-2. **prefill** — slab: one bucketed B=1 prefill per request, grafted into
-   the slab row; paged: up to ``prefill_chunks_per_step`` chunk calls per
-   engine step, shortest-remaining-prompt first, so short-request TTFT
-   stays flat while long prompts stream in.
+   starve small requests behind it.
+2. **prefill** — up to ``prefill_chunks_per_step`` chunk calls per engine
+   step, shortest-remaining-prompt first, so short-request TTFT stays flat
+   while long prompts stream in.
 3. **decode + retirement** — one fixed-shape step over all ``S`` rows;
    a row that emits EOS (inclusive) or exhausts its budget is released on
-   the next host visit (paged: its private pages return to the free list;
-   its prompt's pages stay resident in the prefix cache for future hits).
+   the next host visit (its private pages return to the free list; its
+   prompt's pages stay resident in the prefix cache for future hits).
 
 Correctness anchor: with greedy decoding the engine's emitted tokens are
-token-identical to offline ``generate()`` on the same prompts — in BOTH
-kv modes — tests/test_engine.py pins this on CPU for burst, staggered and
-trickle arrival schedules.
+token-identical to offline ``generate()`` on the same prompts —
+tests/test_engine.py pins this on CPU for burst, staggered and trickle
+arrival schedules.
 """
 
 from __future__ import annotations
@@ -47,11 +41,8 @@ import jax.numpy as jnp
 
 from tpu_air.models.lm.generate import (
     init_paged_cache,
-    init_slot_cache,
-    make_lm_decode_step_fn,
     make_lm_paged_decode_step_fn,
     make_lm_prefill_chunk_fn,
-    make_lm_prefill_fn,
     make_page_copy_fn,
 )
 
@@ -63,7 +54,7 @@ from tpu_air.observability.profiler import phase
 from .kvpool import PagedKVPool
 from .metrics import EngineMetrics, unregister
 from .scheduler import Scheduler
-from .slots import Slot, SlotManager, make_insert_fn
+from .slots import Slot, SlotManager
 from .types import (
     PRIORITIES,
     EngineClosedError,
@@ -103,21 +94,12 @@ class InferenceEngine:
                 f"slot_len {cfg.slot_len} exceeds the model's max_seq_len "
                 f"{model.config.max_seq_len}"
             )
-        if cfg.kv_mode not in ("paged", "slab"):
-            raise ValueError(f"unknown kv_mode {cfg.kv_mode!r}")
-        self.paged = cfg.kv_mode == "paged"
         self.adapters_enabled = cfg.adapter_slots > 0
-        if self.adapters_enabled and not self.paged:
-            raise ValueError(
-                "adapter_slots requires the paged engine (kv_mode='paged')")
 
         # device side: the persistent donated KV pool + compiled phases
-        # (subclasses override the builders — MeshEngine swaps in a sharded
-        # pool/cache and pjit-wrapped step fns, same host loop)
-        if self.paged:
-            self._build_paged_state()
-        else:
-            self._build_slab_state()
+        # (MeshEngine overrides the builder: a sharded pool/cache and
+        # pjit-wrapped step fns, same host loop)
+        self._build_paged_state()
 
         # host side: authoritative per-slot state the step args come from
         self._cur_tok = np.zeros((cfg.num_slots,), np.int32)
@@ -172,7 +154,7 @@ class InferenceEngine:
         if auto_start:
             self.start()
 
-    # -- device-state builders (overridden by engine/dist MeshEngine) --------
+    # -- device-state builder (overridden by engine/dist MeshEngine) ---------
     def _build_paged_state(self) -> None:
         cfg = self.config
         self.pool = PagedKVPool(
@@ -197,14 +179,6 @@ class InferenceEngine:
             self._adapter_a = jnp.zeros((A + 1, mc.d_model, r), jnp.float32)
             self._adapter_b = jnp.zeros((A + 1, r, mc.vocab_size),
                                         jnp.float32)
-
-    def _build_slab_state(self) -> None:
-        cfg = self.config
-        self.pool = None
-        self.cache = init_slot_cache(self.model, cfg.num_slots, cfg.slot_len)
-        self._decode_step = make_lm_decode_step_fn(self.model, cfg.slot_len)
-        self._insert = make_insert_fn()
-        self._prefill_fns: Dict[int, Any] = {}  # bucket -> compiled
 
     # -- submission (any thread) ---------------------------------------------
     def _make_request(self, prompt, max_new_tokens, stream,
@@ -322,9 +296,6 @@ class InferenceEngine:
         ``first_token`` and goes straight to decode — same capacity gate
         and deferral as a normal submit, so pool exhaustion queues the
         handoff instead of dropping it."""
-        if not self.paged:
-            raise ValueError(
-                "submit_prefilled requires a paged engine (kv_mode='paged')")
         # a handoff rides through a drain: the router admitted it before the
         # drain started and its prefill already ran on another replica
         req = self._make_request(prompt, max_new_tokens, stream, priority,
@@ -347,7 +318,7 @@ class InferenceEngine:
     # -- the engine loop -----------------------------------------------------
     def step(self) -> bool:
         """One deterministic engine iteration: admit into free slots, run
-        the prefill quantum (paged), then one pool decode step if anything
+        the prefill quantum, then one pool decode step if anything
         is decoding.  Returns True if any work happened (callers loop
         ``while engine.step(): ...`` to drain)."""
         with self._step_lock:
@@ -361,29 +332,21 @@ class InferenceEngine:
                 for req in self.scheduler.pop_admissible(
                     self.slots.free_count(), self._admit_gate()
                 ):
-                    if self.paged:
-                        self._admit_paged(req)
-                    else:
-                        self._admit(req)
+                    self._admit(req)
                     worked = True
-            if self.paged and self._prefill_quantum():
+            if self._prefill_quantum():
                 worked = True
             if any(not s.prefilling for s in self.slots.active_slots()):
                 self._decode_all()
                 worked = True
-            gauges: Dict[str, Any] = {}
-            if self.paged:
-                gauges = dict(
-                    kvpool=self.pool.stats(),
-                    reordered_admits=self.scheduler.reordered_admits,
-                    prefill_chunks=self._chunks_run,
-                )
             self.metrics.observe_gauges(
                 self.scheduler.depth(), self.slots.occupancy(),
                 queue_by_class=self.scheduler.depth_by_class(),
                 draining=self._draining,
                 deadline_expired=self.scheduler.deadline_expired,
-                **gauges
+                kvpool=self.pool.stats(),
+                reordered_admits=self.scheduler.reordered_admits,
+                prefill_chunks=self._chunks_run,
             )
             return worked
 
@@ -440,9 +403,6 @@ class InferenceEngine:
         owns the stream's future); their source streams are abandoned
         unfinished, and the proxy re-pins pollers at the destination.
         """
-        if not self.paged:
-            raise ValueError(
-                "migrate_out requires a paged engine (kv_mode='paged')")
         self.preempt()
         from .dist.kv_transfer import extract_kv_pages  # lazy: avoids cycle
 
@@ -494,9 +454,6 @@ class InferenceEngine:
         continues from the exact cursor — zero prefill chunks run, and
         greedy continuations are token-identical to the stream never
         having moved."""
-        if not self.paged:
-            raise ValueError(
-                "submit_migrated requires a paged engine (kv_mode='paged')")
         from .dist.kv_transfer import validate_kv_payload  # lazy: no cycle
 
         prompt = [int(t) for t in payload["prompt"]]
@@ -530,31 +487,29 @@ class InferenceEngine:
 
     def _admit_gate(self):
         """Per-round admission predicate handed to the scheduler.  Combines
-        the paged page-capacity gate with the interactive slot reserve
+        the page-capacity gate with the interactive slot reserve
         (``EngineConfig.reserved_interactive_slots``): a non-interactive
         request may only take a slot while MORE than ``reserved`` slots
         would stay free after this round's takes — so a lower-class burst
         can never occupy the whole pool and an arriving interactive request
-        admits immediately.  Returns None (no gate — the scheduler's pure
-        pop) when neither applies, preserving the slab fast path exactly."""
-        page_gate = self._can_admit if self.paged else None
+        admits immediately."""
         reserved = self.config.reserved_interactive_slots
         if reserved <= 0:
-            return page_gate
+            return self._can_admit
 
         def gate(req: Request) -> bool:
             if req.priority != "interactive" and (
                 self.slots.free_count() - self._round_admits <= reserved
             ):
                 return False
-            if page_gate is not None and not page_gate(req):
+            if not self._can_admit(req):
                 return False
             self._round_admits += 1
             return True
 
         return gate
 
-    # -- paged admission -----------------------------------------------------
+    # -- admission -----------------------------------------------------------
     def _begin_admission_round(self) -> None:
         """Reset per-round reservation state before ``pop_admissible``
         probes the queue (the MeshEngine override tracks reservations PER
@@ -576,7 +531,7 @@ class InferenceEngine:
         self._round_reserved += need
         return True
 
-    def _admit_paged(self, req: Request) -> None:
+    def _admit(self, req: Request) -> None:
         """Reserve pages + block-table row; actual compute happens in the
         chunked prefill quantum (no first token yet — TTFT lands when the
         final chunk runs).  A request carrying shipped KV pages skips the
@@ -789,43 +744,6 @@ class InferenceEngine:
             self.cache = self._copy_fn(
                 self.cache, jnp.int32(dst), jnp.int32(src))
         slot.prefilling = False
-        slot.pos = n
-        slot.budget_left = req.max_new_tokens - 1
-        self._cur_tok[slot.index] = first
-        self._pos[slot.index] = n
-        if slot.budget_left == 0 or (
-            self.eos_token_id is not None and first == self.eos_token_id
-        ):
-            self._retire(slot)
-
-    # -- slab admission ------------------------------------------------------
-    def _prefill_for(self, bucket: int):
-        if bucket not in self._prefill_fns:
-            self._prefill_fns[bucket] = make_lm_prefill_fn(self.model, bucket)
-        return self._prefill_fns[bucket]
-
-    def _admit(self, req: Request) -> None:
-        slot = self.slots.acquire()
-        n = len(req.prompt)
-        bucket = self.config.bucket_for(n)
-        ids = np.full((1, bucket), self.model.config.pad_token_id, np.int32)
-        ids[0, :n] = req.prompt
-        tok, segment = self._prefill_for(bucket)(
-            self.params, jnp.asarray(ids), jnp.asarray([n - 1], jnp.int32)
-        )
-        # graft the whole padded segment: pad positions >= n are masked by
-        # the per-row validity check until decode writes overwrite them
-        self.cache = self._insert(self.cache, segment, slot.index)
-        first = int(tok[0])
-        req.first_token_at = time.monotonic()
-        if req.t_submit_ns:  # traced request: stamp TTFT for span emission
-            req.t_first_ns = _tracing.now_ns()
-        self.metrics.record_ttft(req.first_token_at - req.submitted_at,
-                                 req.priority,
-                                 trace_id=(req.trace_ctx or {}).get("trace_id"))
-        req.stream._emit(first)
-        self.metrics.record_tokens(1)  # prefill's first token
-        slot.request = req
         slot.pos = n
         slot.budget_left = req.max_new_tokens - 1
         self._cur_tok[slot.index] = first
@@ -1050,12 +968,6 @@ class InferenceEngine:
 
     def _dispatch_decode(self):
         """Issue one pool decode step; returns the step's (device) output."""
-        if not self.paged:
-            self.cache, nxt = self._decode_step(
-                self.params, self.cache,
-                jnp.asarray(self._cur_tok), jnp.asarray(self._pos),
-            )
-            return nxt
         # non-decoding rows (free OR mid-prefill) ride along pointed at
         # the null page: their ride-along scatter can't touch a live or
         # prefix-shared page.  The authoritative table stays host-side.
@@ -1089,13 +1001,9 @@ class InferenceEngine:
         # the explicit ``tenant`` label when one rides the request (batch
         # lane), else its adapter_id tenant.  Residency runs from first
         # token (pages are fully resident once prefill lands) to
-        # retirement; page count mirrors the pool's own ceil-division for
-        # paged engines, the fixed slot reservation for slab engines.
+        # retirement; page count mirrors the pool's own ceil-division.
         req = slot.request
-        if self.paged:
-            n_pages = -(-slot.pos // self.config.page_len)
-        else:
-            n_pages = self.config.pages_per_slot()
+        n_pages = -(-slot.pos // self.config.page_len)
         resident_s = max(
             0.0, time.monotonic() - (req.first_token_at or req.submitted_at))
         self.metrics.record_tenant_retire(
@@ -1103,10 +1011,9 @@ class InferenceEngine:
             prefilled=len(req.prompt),
             decoded=slot.pos - len(req.prompt) + 1,
             kv_page_seconds=n_pages * resident_s)
-        if self.paged:
-            # private pages return to the free list; prompt pages the prefix
-            # cache registered stay resident for future hits
-            self.pool.release(slot.index)
+        # private pages return to the free list; prompt pages the prefix
+        # cache registered stay resident for future hits
+        self.pool.release(slot.index)
         self.slots.release(slot)
         self._cur_tok[slot.index] = 0
         self._pos[slot.index] = 0
@@ -1138,12 +1045,10 @@ class InferenceEngine:
             # strictly-after: a disaggregated request lands with t_first ==
             # t_admit (its prefill span was recorded on the worker replica)
             attrs = {"slot": slot.index, "prompt_len": len(req.prompt)}
-            if self.paged and slot.plan is not None:
+            if slot.plan is not None:
                 attrs["chunks"] = len(slot.plan.chunk_starts)
                 attrs["prefix_hit"] = slot.plan.prefix_tokens > 0
                 attrs["prefix_tokens"] = slot.plan.prefix_tokens
-            elif not self.paged:
-                attrs["bucket"] = self.config.bucket_for(len(req.prompt))
             _tracing.record_span(
                 "engine.prefill",
                 trace_id=root.trace_id, parent_id=root.span_id,
@@ -1207,8 +1112,7 @@ class InferenceEngine:
                     wasted = slot.pos - len(req.prompt) + 1
                 self.metrics.record_goodput(waste_cat, wasted)
                 req.stream._finish(err)
-                if self.paged:
-                    self.pool.release(slot.index)
+                self.pool.release(slot.index)
                 self.slots.release(slot)
         unregister(self.name)
 

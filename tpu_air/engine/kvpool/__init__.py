@@ -1,9 +1,9 @@
 """tpu_air.engine.kvpool — block-table-paged KV cache for the engine.
 
-Replaces the per-slot slab pool (one `[S, slot_len, h*d]` row per slot)
-with a pool of fixed-size KV *pages* `[P, page_len, h*d]` per layer plus a
-host-side block table mapping each slot's logical positions onto physical
-pages.  Three pieces:
+Where a slot's K/V live is decided here and nowhere else: a pool of
+fixed-size KV *pages* `[P, page_len, h*d]` per layer plus a host-side block
+table mapping each slot's logical positions onto physical pages.  Three
+pieces:
 
 * :class:`BlockAllocator` — refcounted page ids over the device pool, with
   free-list reuse (host bookkeeping; the device arrays live in the engine's
@@ -18,11 +18,9 @@ pages.  Three pieces:
 
 Device-side companions (paged cache init, the paged decode step, the
 chunked-prefill unit, the CoW page copy) live in
-``tpu_air/models/lm/generate.py`` next to the slab entry points they
-generalize; the page layout keeps the flat ``[*, page_len, h*d]``
-last-two-dims contract that won the round-5 roofline study
-(docs/ANALYSIS.md) — ``page_len`` is a multiple of 8 so every page is
-whole (8, 128) tiles.
+``tpu_air/models/lm/generate.py``; the page layout keeps the flat
+``[*, page_len, h*d]`` last-two-dims contract of ``ops/decode_attention.py``
+— ``page_len`` is a multiple of 8 so every page is whole (8, 128) tiles.
 """
 
 from .allocator import BlockAllocator, KVPoolOOMError

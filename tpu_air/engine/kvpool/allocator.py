@@ -79,7 +79,7 @@ class BlockAllocator:
     def decref(self, page: int) -> bool:
         """Drop one reference; returns True when the page went back to the
         free list.  No device-side zeroing — stale bytes in a reused page
-        are masked until overwritten (the slab engine's r5 discipline)."""
+        are masked until overwritten."""
         if not 0 < page < self.num_pages:
             raise ValueError(f"bad page id {page}")
         if self._ref[page] <= 0:

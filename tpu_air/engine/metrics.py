@@ -84,8 +84,7 @@ class EngineMetrics:
         self._token_stamps: Deque[Any] = deque()  # (t, n) for tokens/s
         # roofline + goodput accumulator (engine records program costs)
         self.ledger = PerfLedger(detect_peak())
-        # paged-KV gauges (empty for slab engines — snapshot shape is then
-        # unchanged from the slab era)
+        # paged-KV gauges (empty for T5Engine, which has no page pool)
         self.kvpool: Dict[str, Any] = {}
         self.reordered_admits = 0
         self.prefill_chunks = 0
@@ -280,7 +279,7 @@ class EngineMetrics:
                             rollback: bool = False) -> None:
         """One live weight swap on this engine: the version now serving,
         and the decode-step gap it cost (lock wait + reshard + device_put
-        — the honest ``swap_stall_ms`` the bench gates on).  ``rollback``
+        — the honest ``swap_stall_ms``).  ``rollback``
         marks swaps that restored the prior version."""
         with self._lock:
             w = self.weights
@@ -411,8 +410,7 @@ def merge_snapshots(snapshots: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
     replicas): counters sum, histograms merge bucket-by-bucket — the
     merged p99 is computed over EVERY replica's samples, not a max of
     per-replica quantiles — and ledgers sum into one roofline/goodput
-    view.  Consumed by bench_serve's headline math and anything wanting
-    one number for the fleet."""
+    view, for anything wanting one number for the fleet."""
     snaps = [s for s in snapshots.values() if s]
     out: Dict[str, Any] = {"engines": len(snaps)}
     for key in ("num_slots", "queue_depth", "slot_occupancy",
@@ -465,7 +463,7 @@ def merge_snapshots(snapshots: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
     if ws:
         # fleet view: swaps/rollbacks sum, the serving version is the max
         # (mid-promotion the fleet is legitimately mixed), stall is the
-        # worst replica's worst swap — the number bench_serve headlines
+        # worst replica's worst swap
         out["weights"] = {
             "version": max(int(w.get("version", 0)) for w in ws),
             "swaps": sum(int(w.get("swaps", 0)) for w in ws),
@@ -612,7 +610,7 @@ def prometheus_lines(snapshots: Dict[str, Dict[str, Any]] = None) -> list:
                 b.histogram(fam, {"engine": name},
                             cumulative_from_summary(d),
                             int(d["count"]), float(d.get("sum", 0.0)))
-        # paged-KV pool gauges (absent on slab engines)
+        # paged-KV pool gauges (absent on T5Engine)
         for key, val in sorted((snap.get("kvpool") or {}).items()):
             if isinstance(val, bool) or not isinstance(val, (int, float)):
                 continue

@@ -6,7 +6,7 @@ the :data:`~tpu_air.engine.types.PRIORITIES` classes.  Admission pops
 classes strictly in priority order every engine step — iteration-
 granularity priority, the Orca framing applied to admission — and WITHIN
 a class requests are admitted in arrival order up to the number of free
-slots.  The paged engine additionally passes a ``can_admit`` predicate
+slots.  The engine additionally passes a ``can_admit`` predicate
 (does the KV pool have pages for this request right now?) — and a blocked
 HEAD no longer blocks its class: admission may look at most
 ``reorder_window`` entries past the first request that does not fit and

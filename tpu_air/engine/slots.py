@@ -1,40 +1,37 @@
-"""Slot table + KV-slab insertion for the continuous-batching engine.
+"""Slot table of the continuous-batching engine.
 
-A *slot* is one row of the engine's fixed decode batch: row ``s`` of every
-per-layer flat KV slab ``[S, L_slot, h*d]``.  The host-side
-:class:`SlotManager` tracks which request occupies each row and where its
-context ends; the device side is one jitted ``dynamic_update_slice`` per
-admission that grafts a prefilled cache segment into the free row.
+A *slot* is one row of the engine's fixed decode batch: row ``s`` of the
+block table, whose pages hold the sequence's K/V (engine/kvpool/).  The
+host-side :class:`SlotManager` tracks which request occupies each row and
+where its context ends.
 
-Lifecycle of a slot (docs/SERVING.md §slab lifecycle)::
+Lifecycle of a slot (docs/SERVING.md)::
 
-    free -> [admit] occupied(pos=len(prompt)) -> [decode steps] pos+1 each
-         -> [EOS or budget] free again -- no slab zeroing on retirement:
+    free -> [admit] prefilling (chunks run between decode steps)
+         -> [final chunk] decoding(pos=len(prompt)) -> [decode steps] pos+1
+         -> [EOS or budget] free again -- no page zeroing on retirement:
     stale K/V beyond the next occupant's written positions are masked by
     the per-row validity mask (arange <= index[row]) and progressively
-    overwritten, so retirement costs exactly one host-side list append.
+    overwritten, so retirement is host bookkeeping only.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
-from typing import Any, Dict, List, Optional
-
-import jax
+from typing import Any, List, Optional
 
 from .types import Request
 
 
 @dataclass
 class Slot:
-    """Host bookkeeping for one slab row (or paged block-table row)."""
+    """Host bookkeeping for one block-table row."""
 
     index: int
     request: Optional[Request] = None
     pos: int = 0            # cache write position == tokens in context
     budget_left: int = 0    # decode steps remaining before forced retirement
-    # paged engine only: mid-chunked-prefill flag + the pool's AdmitPlan
+    # mid-chunked-prefill flag + the pool's AdmitPlan
     # (remaining chunk starts, prefix coverage).  A prefilling slot holds
     # pages and a request but does NOT ride the decode step yet.
     prefilling: bool = False
@@ -46,7 +43,7 @@ class Slot:
 
 
 class SlotManager:
-    """Free-list over the ``S`` slab rows."""
+    """Free-list over the ``S`` rows."""
 
     def __init__(self, num_slots: int):
         self.slots: List[Slot] = [Slot(i) for i in range(num_slots)]
@@ -85,30 +82,3 @@ class SlotManager:
         # hands out the lowest free row
         self._free.append(slot.index)
         self._free.sort(reverse=True)
-
-
-def make_insert_fn():
-    """Jitted segment insertion: graft a prefilled cache segment (per-layer
-    ``[1, Lb, h*d]`` slabs) into slab row ``slot`` of the engine cache.
-    The engine cache is donated — insertion updates the pool in place.
-    ``cache_index`` leaves pass through: the decode step overwrites them
-    from the host-authoritative ``pos`` vector every call."""
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def insert(cache: Dict[str, Any], segment: Dict[str, Any], slot):
-        def walk(c, s):
-            out = {}
-            for k, v in c.items():
-                if isinstance(v, dict):
-                    out[k] = walk(v, s[k])
-                elif k == "cache_index":
-                    out[k] = v
-                else:
-                    out[k] = jax.lax.dynamic_update_slice(
-                        v, s[k].astype(v.dtype), (slot, 0, 0)
-                    )
-            return out
-
-        return walk(cache, segment)
-
-    return insert

@@ -12,7 +12,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Iterator, List, Optional, Sequence, Tuple, Union
+from typing import Iterator, List, Optional, Sequence, Union
 
 from tpu_air.core.runtime import TpuAirError
 
@@ -63,20 +63,17 @@ class EngineConfig:
     * ``max_new_tokens`` — default per-request decode budget.
     * ``max_queue`` — queued (not yet admitted) request cap; beyond it
       ``submit`` raises :class:`EngineOverloadedError`.
-    * ``kv_mode`` — ``"paged"`` (default): block-table-paged KV pool with
-      prefix sharing and chunked prefill (``tpu_air/engine/kvpool/``);
-      ``"slab"``: the PR 1 fixed per-slot slabs ``[S, slot_len, h*d]``
-      (kept as the bench baseline and the mode the T5 window engine uses).
-    * ``page_len`` — paged mode: positions per KV page.  Multiples of 8
-      keep every page whole (8, 128) TPU tiles in the flat ``h*d`` layout.
-    * ``num_pages`` — paged mode: physical pages in the pool (page 0 is
-      the pinned null page).  ``None`` → slab-equivalent capacity,
-      ``num_slots * ceil(slot_len / page_len) + 1`` — same HBM as the
-      slab pool; prefix sharing turns the saved pages into headroom.
-    * ``prefix_cache`` — paged mode: keep retired prompts' pages resident
-      (radix over page chunks) so later prompts sharing a prefix skip
-      that prefill and share the physical pages.
-    * ``prefill_chunks_per_step`` — paged mode: prefill chunks run per
+    * ``page_len`` — positions per KV page of the block-table-paged pool
+      (``tpu_air/engine/kvpool/``).  Multiples of 8 keep every page whole
+      (8, 128) TPU tiles in the flat ``h*d`` layout.
+    * ``num_pages`` — physical pages in the pool (page 0 is the pinned
+      null page).  ``None`` → every slot can fill its ``slot_len``,
+      ``num_slots * ceil(slot_len / page_len) + 1``; prefix sharing turns
+      the saved pages into headroom.
+    * ``prefix_cache`` — keep retired prompts' pages resident (radix over
+      page chunks) so later prompts sharing a prefix skip that prefill and
+      share the physical pages.
+    * ``prefill_chunks_per_step`` — prefill chunks run per
       engine step, interleaved between pool decode steps.  1 (default)
       bounds how long any prefill work can delay in-flight decodes, so a
       long prompt streams in page-sized pieces while short requests keep
@@ -95,16 +92,11 @@ class EngineConfig:
       (engine-side shed).  Defaults: interactive 1.0, batch 0.85,
       best_effort 0.5 — as the queue fills, best-effort sheds first,
       then batch, and interactive keeps the full ``max_queue``.
-    * ``prefill_buckets`` — slab mode: prompt-length buckets (ascending);
-      prompts right-pad to the smallest fitting bucket so prefill
-      compiles once per bucket.  ``None`` → powers of two up to
-      ``slot_len``.  Paged mode needs no buckets: every prompt length
-      runs through one compiled page-sized chunk program.
     * ``eos_token_id`` — ``"model"`` (default): use the model config's
       ``eos_token_id``; ``None``: never early-stop (budget-only
       retirement); an int: that id.
     * ``adapter_slots`` — multi-tenant LoRA: rows in the resident adapter
-      bank (0 disables adapters; paged single-chip engines only).  Row 0
+      bank (0 disables adapters; single-chip engines only).  Row 0
       is the pinned zero adapter, so the bank holds ``adapter_slots``
       loadable tenants on top of it.  Per-request selection rides
       ``Request.adapter_id``; the decode step gathers each slot's delta
@@ -118,7 +110,6 @@ class EngineConfig:
     slot_len: int = 256
     max_new_tokens: int = 64
     max_queue: int = 256
-    kv_mode: str = "paged"
     page_len: int = 16
     num_pages: Optional[int] = None
     prefix_cache: bool = True
@@ -126,7 +117,6 @@ class EngineConfig:
     reorder_window: int = 4
     reserved_interactive_slots: int = 0
     queue_shares: Optional[dict] = None
-    prefill_buckets: Optional[Tuple[int, ...]] = None
     eos_token_id: Union[int, None, str] = "model"
     adapter_slots: int = 0
     adapter_rank: int = 4
@@ -147,25 +137,6 @@ class EngineConfig:
         if self.num_pages is not None:
             return self.num_pages
         return self.num_slots * self.pages_per_slot() + 1
-
-    def buckets(self) -> Tuple[int, ...]:
-        if self.prefill_buckets is not None:
-            return tuple(sorted(self.prefill_buckets))
-        out, b = [], 1
-        while b < self.slot_len:
-            out.append(b)
-            b *= 2
-        out.append(self.slot_len)
-        return tuple(out)
-
-    def bucket_for(self, n: int) -> int:
-        for b in self.buckets():
-            if n <= b:
-                return b
-        raise ValueError(
-            f"prompt length {n} exceeds the largest prefill bucket "
-            f"{self.buckets()[-1]} (slot_len={self.slot_len})"
-        )
 
 
 _DONE = object()

@@ -1,11 +1,5 @@
 from .config import LMConfig
-from .generate import (
-    generate,
-    init_slot_cache,
-    make_lm_decode_step_fn,
-    make_lm_generate_fn,
-    make_lm_prefill_fn,
-)
+from .generate import generate, make_lm_generate_fn
 from .modeling import (
     CausalLM,
     head_weight,
@@ -17,10 +11,7 @@ from .modeling import (
 __all__ = [
     "LMConfig",
     "generate",
-    "init_slot_cache",
-    "make_lm_decode_step_fn",
     "make_lm_generate_fn",
-    "make_lm_prefill_fn",
     "CausalLM",
     "head_weight",
     "lm_chunked_loss_with_targets",

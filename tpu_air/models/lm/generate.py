@@ -163,15 +163,19 @@ def make_lm_generate_fn(model: CausalLM, max_new_tokens: int,
 
 
 # ---------------------------------------------------------------------------
-# Continuous-batching entry points (tpu_air.engine)
+# Engine entry points (tpu_air.engine)
 #
 # make_lm_generate_fn keeps prefill and the per-token step private inside one
 # jitted program — right for offline batches, useless for an engine that must
 # admit/retire requests BETWEEN steps.  These expose the same two phases as
-# standalone compiled units over the engine's slot-pool cache layout:
-# per-layer flat slabs [S, L_slot, h*d] plus a PER-ROW cache index (each slot
-# sits at its own position — modeling.py scatters the new token's K/V to
-# (row, index[row]) and masks per row).
+# standalone compiled units over the engine's paged cache layout
+# (tpu_air.engine.kvpool): per-layer page POOLS [num_pages, page_len, h*d]
+# shared by all slots, a PER-ROW cache index (each slot sits at its own
+# position) and a block_table leaf [S, pages_per_slot] mapping each slot's
+# logical positions onto physical pages.  The table and per-slot indices are
+# HOST state (engine/kvpool/pool.py) pushed into the cache dict at every call
+# via leaf mappers, so the donated device cache never round-trips.  Prefill
+# is page-sized CHUNKS: one compiled program for every prompt length.
 # ---------------------------------------------------------------------------
 
 
@@ -193,112 +197,16 @@ def _map_cache_index(cache, fn):
     return _map_cache_leaf(cache, "cache_index", fn)
 
 
-def init_slot_cache(model: CausalLM, num_slots: int, slot_len: int):
-    """Zero KV slab pool for ``num_slots`` sequence slots of ``slot_len``
-    positions each, with PER-SLOT cache indices ([S] int32 vector instead of
-    the offline scalar).  This is the persistent cache the engine's decode
-    step carries (and donates) across its whole lifetime."""
-    dmodel = CausalLM(LMConfig.from_dict(
-        {**model.config.to_dict(), "max_seq_len": slot_len}
-    ))
-    cache = init_cache(dmodel, num_slots)
-    return _map_cache_index(
-        cache, lambda _: jnp.zeros((num_slots,), jnp.int32)
-    )
-
-
-def make_lm_prefill_fn(model: CausalLM, prompt_len: int):
-    """Build a jitted ``fn(params, input_ids, last_index) -> (tok, cache)``:
-    one whole-prompt cached pass producing the first greedy token plus the
-    prompt's KV segment (per-layer ``[B, prompt_len, h*d]`` slabs) ready for
-    ``dynamic_update_slice`` insertion into a free engine slot.
-
-    ``input_ids``: (B, prompt_len) prompts right-padded to the length bucket;
-    ``last_index``: (B,) index of each row's LAST REAL token (the head is
-    applied there, not at the padded end — right-padding can't leak into
-    earlier positions under the causal mask, so bucketed prefill is
-    token-identical to an exact-length prefill)."""
-    cfg = model.config
-
-    @jax.jit
-    def prefill(params, input_ids, last_index):
-        b, lp = input_ids.shape
-        dmodel = CausalLM(LMConfig.from_dict(
-            {**cfg.to_dict(), "max_seq_len": lp}
-        ))
-        cache = init_cache(dmodel, b)
-        positions = jnp.broadcast_to(jnp.arange(lp, dtype=jnp.int32), (b, lp))
-        hidden, vars_ = dmodel.apply(
-            {"params": params, "cache": cache}, input_ids, positions,
-            decode=True, return_hidden=True, mutable=["cache"],
-        )
-        head_w = head_weight(params, cfg).astype(jnp.float32)
-        h_last = jnp.take_along_axis(
-            hidden, last_index[:, None, None].astype(jnp.int32), axis=1
-        )[:, 0]
-        tok = jnp.argmax(
-            h_last.astype(jnp.float32) @ head_w, axis=-1
-        ).astype(jnp.int32)
-        return tok, vars_["cache"]
-
-    return prefill
-
-
-def make_lm_decode_step_fn(model: CausalLM, slot_len: int):
-    """Build THE persistent engine step: a jitted ``fn(params, cache, tok,
-    pos) -> (cache', next_tok)`` over the fixed slot pool, cache donated so
-    the slabs update in place across the engine's lifetime.
-
-    ``tok``/``pos``: (S,) current token and cache position per slot.  Every
-    slot steps every call (fixed shape — the continuous-batching discipline);
-    free slots ride along at pos 0 and their outputs are discarded host-side.
-    Greedy by construction: the engine's correctness anchor is token-equality
-    with offline greedy ``generate``."""
-    cfg = model.config
-    dcfg = {**cfg.to_dict(), "max_seq_len": slot_len}
-
-    from functools import partial
-
-    @partial(jax.jit, donate_argnums=(1,))
-    def step(params, cache, tok, pos):
-        dmodel = CausalLM(LMConfig.from_dict(dcfg))
-        pos = pos.astype(jnp.int32)
-        cache = _map_cache_index(cache, lambda _: pos)
-        hidden, vars_ = dmodel.apply(
-            {"params": params, "cache": cache}, tok[:, None], pos[:, None],
-            decode=True, return_hidden=True, mutable=["cache"],
-        )
-        head_w = head_weight(params, cfg).astype(jnp.float32)
-        nxt = jnp.argmax(
-            hidden[:, -1].astype(jnp.float32) @ head_w, axis=-1
-        ).astype(jnp.int32)
-        return vars_["cache"], nxt
-
-    return step
-
-
-# ---------------------------------------------------------------------------
-# Paged engine entry points (tpu_air.engine.kvpool)
-#
-# Same phases as the slab entry points above, over the paged cache layout:
-# per-layer page POOLS [num_pages, page_len, h*d] shared by all slots plus a
-# block_table leaf [S, pages_per_slot] mapping each slot's logical positions
-# onto physical pages.  The table and per-slot indices are HOST state
-# (engine/kvpool/pool.py) pushed into the cache dict at every call via leaf
-# mappers, so the donated device cache never round-trips.  Prefill is
-# page-sized CHUNKS — one compiled program for every prompt length — instead
-# of the slab path's per-bucket compiles.
-# ---------------------------------------------------------------------------
-
-
 def init_paged_cache(model: CausalLM, num_slots: int, num_pages: int,
                      page_len: int, pages_per_slot: int):
     """Zero paged KV cache: every attention layer gets page pools
     ``[num_pages, page_len, h*d]`` (page 0 = the pinned null page), a
     per-slot index vector ``[S]`` and a block table ``[S, pages_per_slot]``
     of page ids (0 = unreached/null).  This is the persistent donated cache
-    of a paged engine."""
-    base = init_slot_cache(model, num_slots, page_len)
+    of the engine."""
+    dmodel = CausalLM(LMConfig.from_dict(
+        {**model.config.to_dict(), "max_seq_len": page_len}))
+    base = init_cache(dmodel, num_slots)
 
     def rebuild(d):
         out = {}
@@ -422,11 +330,16 @@ def make_paged_decode_body(model: CausalLM, slot_len: int,
 def make_lm_paged_decode_step_fn(model: CausalLM, slot_len: int,
                                  adapters: bool = False):
     """The persistent paged engine step: jitted ``fn(params, cache, tok,
-    pos, block_table) -> (cache', next_tok)``, cache donated.  Identical
-    contract to :func:`make_lm_decode_step_fn` plus the block table
-    ``[S, pages_per_slot]`` int32 (the host pool's authoritative table —
-    rows of non-decoding slots pointed at the null page so their ride-along
-    scatter can't touch a live or prefix-shared page).  ``adapters=True``
+    pos, block_table) -> (cache', next_tok)``, cache donated so the pools
+    update in place across the engine's lifetime.  ``tok``/``pos``: (S,)
+    current token and cache position per slot.  Every slot steps every call
+    (fixed shape — the continuous-batching discipline); free slots ride
+    along at pos 0 and their outputs are discarded host-side.  Greedy by
+    construction: the engine's correctness anchor is token-equality with
+    offline greedy ``generate``.  ``block_table`` ``[S, pages_per_slot]``
+    int32 is the host pool's authoritative table — rows of non-decoding
+    slots pointed at the null page so their ride-along scatter can't touch
+    a live or prefix-shared page.  ``adapters=True``
     appends the LoRA bank args (see :func:`make_paged_decode_body`); the
     banks are NOT donated — they persist across steps like params."""
     body = make_paged_decode_body(model, slot_len, adapters)
@@ -506,7 +419,7 @@ def make_lm_prefill_chunk_fn(model: CausalLM, page_len: int, slot_len: int,
     * ``ids`` ``[1, page_len]`` — the chunk's tokens, right-padded on the
       final (partial) chunk.  Pad positions write don't-care K/V into the
       page tail; the per-slot validity mask hides them until decode
-      appends overwrite them — the slab engine's stale-bytes discipline.
+      appends overwrite them.
     * ``p0`` — the chunk's first global position (page-aligned).
     * ``last_local`` — index of the prompt's last real token WITHIN this
       chunk, valid only on the final chunk; the returned greedy first

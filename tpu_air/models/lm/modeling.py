@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from tpu_air.ops.decode_attention import flat_decode_attention, gather_pages
+
 from .config import LMConfig
 
 Array = jax.Array
@@ -124,9 +126,6 @@ class CausalSelfAttention(nn.Module):
                 # at (table[s, p // C], p % C).  Prefix-shared pages appear
                 # in several rows at once; the null page (id 0) absorbs
                 # writes/reads of masked rows and unreached entries.
-                from tpu_air.ops.decode_attention import (
-                    flat_decode_attention, gather_pages)
-
                 bt = self.variable(
                     "cache", "block_table",
                     lambda: jnp.zeros((b, 1), jnp.int32))
@@ -179,39 +178,12 @@ class CausalSelfAttention(nn.Module):
                 o = _dense_causal_attention(q, k4, v4, scale, q_offset=p0)
                 o = o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
                 return proj("o", cfg.d_model)(o)
-            if i.ndim == 1:
-                # PER-ROW cache index [b] (the continuous-batching engine,
-                # engine/engine.py): every slot sits at its own position, so
-                # the new token's K/V scatter to (row, i[row]) and the
-                # validity mask is per-row.  Positions beyond i[row] may
-                # hold STALE bytes from a retired occupant — masked here,
-                # progressively overwritten by subsequent steps.
-                if l != 1:
-                    raise ValueError(
-                        "per-row cache_index supports single-token decode "
-                        f"steps only; got l={l}"
-                    )
-                from tpu_air.ops.decode_attention import flat_decode_attention
-
-                rows = jnp.arange(b)
-                ck.value = ck.value.at[rows, i].set(
-                    kflat[:, 0].astype(dtype))
-                cv.value = cv.value.at[rows, i].set(
-                    vflat[:, 0].astype(dtype))
-                idx.value = i + 1
-                kvm = jnp.arange(max_len)[None, :] <= i[:, None]
-                o4 = flat_decode_attention(
-                    q.transpose(0, 2, 1, 3) * scale, ck.value, cv.value,
-                    None, kvm, None, None, h, dtype)
-                return proj("o", cfg.d_model)(o4.reshape(b, 1, h * d))
             ck.value = jax.lax.dynamic_update_slice(
                 ck.value, kflat.astype(dtype), (0, i, 0))
             cv.value = jax.lax.dynamic_update_slice(
                 cv.value, vflat.astype(dtype), (0, i, 0))
             idx.value = i + l
             if l == 1:
-                from tpu_air.ops.decode_attention import flat_decode_attention
-
                 # future cache slots are zeros; the kv_mask hides them
                 kvm = jnp.broadcast_to(
                     (jnp.arange(max_len) <= i)[None], (b, max_len))

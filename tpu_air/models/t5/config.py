@@ -44,10 +44,8 @@ class T5Config:
     # * "flash"  — always the Pallas kernel where eligible.
     # Flash is only eligible off the cached-decode path with structured
     # masks and inactive attention dropout (see modeling.Attention).
-    # ``use_flash_attention`` is the legacy force-flash switch (== "flash").
     attention_impl: str = "auto"
     flash_min_seq_len: int = 1024
-    use_flash_attention: bool = False
     # Opt-in int8 cross-attention K/V cache for cached decode: the cross
     # K/V are the dominant HBM term of every decode step (B x enc_len x
     # n_heads x d_kv x 2 x layers, re-read per emitted token); storing them
@@ -56,22 +54,6 @@ class T5Config:
     # default — the reference decodes fp16 (cc-64); numerics parity is
     # tested at tolerance in tests/test_t5.py.
     decode_cache_int8: bool = False
-    # Cached-decode attention dispatch (ops/decode_attention.py).  Caches
-    # are stored FLAT [b, L, h*d] (a row-major 4-D slab costs 2.67x the
-    # HBM bytes to tile padding).  "auto" attends over the slab as stored,
-    # through the flat block-diagonal formulation, for full-width and int8
-    # caches alike, so the layout XLA streams does not depend on the loop
-    # or program around the step.  Measured on the v5e (PERF.md, PR 25):
-    # t5base-batchgen, predict()'s early_stop while-loop, 256 x 512 bf16:
-    # 72.5 % of the HBM roofline, where the dense path ("auto" up to PR 24;
-    # ledger, PR 24) read 37.0 % and 86.7 seq/s; the engine's step on
-    # FLAN-T5-large at batch 64: 7.6 ms against 11.8.  Explicit values pin
-    # one path: "flat" = what "auto" takes; "einsum" = the dense comparison
-    # path over a 4-D view of the slab (87.6 % under generate()'s
-    # fixed-trip scan, 37.0 % under the while-loop: XLA's layout choice,
-    # not the caller's); "pallas" = the fused kernel (63.7 % in the same
-    # while-loop; interpret mode off-TPU).
-    decode_attention_impl: str = "auto"
 
     def __post_init__(self):
         if self.num_decoder_layers is None:

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from tpu_air.ops.decode_attention import flat_decode_attention
+
 from .config import T5Config
 
 Array = jax.Array
@@ -164,10 +166,8 @@ class Attention(nn.Module):
         # ~92% of the roofline, i.e. the chip is fast, the LAYOUT is the
         # waste.  h*d = 768 is six clean (8, 128) tiles, zero padding.
         # The cached single-token step attends over the slab as stored
-        # (``flat_decode_attention``, or the Pallas kernel if asked), and
-        # never takes a [b, L, h, d] view of it
-        # (tests/test_t5.py::test_cached_step_never_views_a_slab_in_4d).
-        dk_impl = getattr(cfg, "decode_attention_impl", "auto")
+        # (``flat_decode_attention``) and never takes a [b, L, h, d] view
+        # of it (tests/test_t5.py::test_cached_step_never_views_a_slab_in_4d).
         dk_scales = (None, None)
         cached_step = False    # k/v hold FLAT cache slabs, not [b,k,h,d]
 
@@ -282,38 +282,29 @@ class Attention(nn.Module):
             and mask is None
             and (deterministic or cfg.dropout_rate == 0)
         )
-        impl = "flash" if cfg.use_flash_attention else getattr(
-            cfg, "attention_impl", "auto"
-        )
-        if impl == "auto":
+        if cfg.attention_impl == "auto":
             from tpu_air.ops.flash_attention import auto_dispatch_ok
 
             use_flash = eligible and (
-                max(qlen, klen) >= getattr(cfg, "flash_min_seq_len", 1024)
+                max(qlen, klen) >= cfg.flash_min_seq_len
                 and auto_dispatch_ok(qlen, klen)
             )
         else:
-            use_flash = eligible and impl == "flash"
+            use_flash = eligible and cfg.attention_impl == "flash"
         if cached_step:
             # Single-token step over flat cache slabs.  Structured-mask
             # contract: mask here is batch-shared (decode causal row) or
             # None.
-            # "auto" attends over the flat slab for EVERY cache, full-width
-            # and int8, self and cross (PR 25).  The dense path in the else
-            # branch needs a [b, L, h, d] view of the slab, and what XLA
-            # makes of that view depends on the program around the step:
-            # one length-minor copy hoisted out of generate()'s fixed-trip
-            # scan, a padded row-major view (2.67x the bytes an iteration)
-            # under the early_stop while-loop predict() runs, a relayout
-            # copy of every slab every step in the engine's donated-cache
-            # step (PERF.md, PR 25).  "einsum" stays as the explicit dense
-            # comparison value, and as the fallback for what is not a plain
+            # The plain step attends over the flat slab for EVERY cache,
+            # full-width and int8, self and cross.  The dense path in the
+            # else branch needs a [b, L, h, d] view of the slab, whose
+            # layout XLA picks from the program around the step (PERF.md,
+            # PR 25); it stays as the only path for what is not a plain
             # single-token step (qlen > 1, live dropout, per-row mask).
             fast_ok = (
                 qlen == 1
                 and (deterministic or cfg.dropout_rate == 0)
                 and (mask is None or mask.shape[0] == 1)
-                and dk_impl != "einsum"
             )
             if fast_ok:
                 if mask is not None and not (
@@ -337,25 +328,13 @@ class Attention(nn.Module):
                     bias_arg = jnp.broadcast_to(
                         comb[0, :, 0, :], (cfg.num_heads, klen)
                     )
-                if dk_impl == "pallas":
-                    from tpu_air.ops.decode_attention import decode_attention
-
-                    ctx = decode_attention(
-                        q, k, v, bias=bias_arg, kv_mask=kv_mask,
-                        k_scale=dk_scales[0], v_scale=dk_scales[1],
-                    )
-                else:
-                    from tpu_air.ops.decode_attention import (
-                        flat_decode_attention,
-                    )
-
-                    ctx = flat_decode_attention(
-                        q, k, v, bias_arg, kv_mask,
-                        dk_scales[0], dk_scales[1], cfg.num_heads, dtype,
-                    )
+                ctx = flat_decode_attention(
+                    q, k, v, bias_arg, kv_mask,
+                    dk_scales[0], dk_scales[1], cfg.num_heads, dtype,
+                )
             else:
-                # comparison / fallback path: view the (dequantized) slab in
-                # 4-D and fall through to the dense einsum below
+                # fallback path: view the (dequantized) slab in 4-D and
+                # fall through to the dense einsum below
                 bsz = k.shape[0]
                 hpd = (cfg.num_heads, cfg.d_kv)
                 ks_, vs_ = dk_scales

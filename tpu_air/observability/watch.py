@@ -19,7 +19,7 @@ Three pieces on top of the ring-buffer store (timeseries.py):
   and quota rejections — cumulative engine/admission counters in, rates
   and totals out, counter resets clamped.  Surfaced as the
   ``tpu_air_tenant_*`` prometheus families, ``/api/tenants``, and the
-  ``chip_seconds_per_1k_tokens`` derived headline bench_serve gates on.
+  ``chip_seconds_per_1k_tokens`` derived headline.
 
 * :class:`AnomalyDetector` — online EWMA mean + EWMA absolute deviation
   (a streaming stand-in for median/MAD) over the 1s tier; a sample whose
