@@ -81,3 +81,24 @@ def air():
     tpu_air.init(num_cpus=4, num_chips=8)
     yield tpu_air
     tpu_air.shutdown()
+
+
+@pytest.fixture(scope="module")
+def lm_live(lm):
+    """The requesting module's tiny causal LM (its ``lm`` fixture) with the
+    kernels times eight.  At its initial weights that model repeats the last
+    token it was given whatever the context holds (the tied embedding
+    outweighs two layers of 0.02-std kernels), so a stream there survives a
+    wrong K/V page; with these every token depends on the whole context."""
+    import jax
+    import numpy as np
+
+    from tpu_air.models.lm.generate import generate
+
+    cfg, model, params = lm
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, x: x * 8.0 if path[-1].key == "kernel" else x, params)
+    ref = np.asarray(generate(model, params, [[7, 3, 9]], max_new_tokens=8,
+                              eos_token_id=None))[0].tolist()
+    assert len(set(ref)) > 4, ref
+    return cfg, model, params

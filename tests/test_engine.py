@@ -166,6 +166,37 @@ def test_token_parity_with_offline_generate(lm, name, arrival_of):
     engine.close()
 
 
+@pytest.mark.parametrize("arrival_of", [lambda i: 0, lambda i: i,
+                                        lambda i: 4 * i],
+                         ids=["burst", "staggered", "trickle"])
+def test_token_parity_where_every_token_depends_on_the_context(
+        lm_live, arrival_of):
+    """The same three arrival shapes on weights whose every token depends on
+    the whole context, with an EOS some rows reach mid-stream: rows join a
+    step from the device, end one step before the host learns of it, and
+    hand their pages on, and each stream is still offline ``generate``'s."""
+    cfg, model, params = lm_live
+    prompts = _prompts(seed=7, n=7)
+    max_new = 10
+    refs = [_offline(model, params, p, max_new, None) for p in prompts]
+    eos = refs[1][4]
+    engine = InferenceEngine(
+        model, params,
+        EngineConfig(num_slots=3, slot_len=64, max_new_tokens=max_new,
+                     page_len=8, eos_token_id=eos),
+        auto_start=False,
+    )
+    streams = _run_schedule(
+        engine, [(arrival_of(i), p) for i, p in enumerate(prompts)])
+    want = [_offline(model, params, p, max_new, eos) for p in prompts]
+    assert [s.result(5.0) for s in streams] == want
+    assert 0 < sum(len(w) < max_new for w in want) < len(want)
+    snap = engine.metrics.snapshot()
+    assert snap["tokens_emitted"] == sum(map(len, want))
+    assert snap["steps_ahead"] >= snap["steps_issued"] - 3
+    engine.close()
+
+
 def test_token_parity_with_eos_retirement(lm):
     """Early-stop path: rows retire the step they emit EOS (id included),
     matching offline generate truncated after the first EOS."""
@@ -212,6 +243,169 @@ def test_slot_reuse_burst_deeper_than_pool(lm):
     for p, s in zip(prompts, streams):
         assert s.result(5.0) == _offline(model, params, p, 6, None)
     assert engine.metrics.snapshot()["requests_completed"] == 7
+    engine.close()
+
+
+# -- one step in flight: the paged loop reads a step after the next went out --
+
+
+def _slot_pages(engine, prompt, n_pages):
+    """The physical pages of the slot that holds ``prompt``, or None."""
+    for slot in engine.slots.active_slots():
+        if slot.request.prompt == prompt:
+            return set(map(int, engine.pool.block_table[slot.index][:n_pages]))
+    return None
+
+
+@pytest.mark.parametrize("another_row", [False, True],
+                         ids=["the-only-row", "another-row-runs-on"])
+def test_eos_with_a_step_in_flight_hands_its_pages_to_the_next_request(
+        lm_live, another_row):
+    """A row that ends on EOS in step N rides step N+1, which went out before
+    N was read.  Its pages are released after that and given to the request
+    that waited for them (the pool is one request deep): the ride-along write
+    and every later step must leave the newcomer's K/V alone, so it streams
+    what a fresh engine streams."""
+    cfg, model, params = lm_live
+    max_new = 10
+    ending, other = [5, 9, 2, 7], [11, 3, 8, 1, 6]
+    late = _prompts(seed=13, n=1, lo=11, hi=12)[0]        # 11 tokens
+    ref = _offline(model, params, ending, max_new, None)
+    eos = ref[2]
+    assert eos not in ref[:2]
+    assert eos not in _offline(model, params, other, max_new, None)
+    assert len(_offline(model, params, late, max_new, eos)) > 3
+    # pages of 8: `ending` and `other` take 2 each, `late` 3 (worst case)
+    pages = 1 + (4 if another_row else 2) + 1
+    ecfg = EngineConfig(num_slots=3, slot_len=32, max_new_tokens=max_new,
+                        page_len=8, num_pages=pages, eos_token_id=eos)
+    engine = InferenceEngine(model, params, ecfg, auto_start=False)
+    first = [engine.submit(p) for p in ([ending, other] if another_row
+                                        else [ending])]
+    waiting = engine.submit(late)
+    engine.step()
+    held = _slot_pages(engine, ending, 2)
+    assert held and _slot_pages(engine, late, 3) is None  # no room yet
+    taken, steps = None, 0
+    while not engine.idle():
+        engine.step()
+        taken = taken or _slot_pages(engine, late, 3)
+        steps += 1
+        assert steps < 200, "engine failed to drain"
+    assert first[0].result(5.0) == ref[:3]
+    assert taken & held, "the newcomer was not given the freed pages"
+    snap = engine.metrics.snapshot()
+    # alone, the step that went out before the EOS was read has no reader
+    assert snap["steps_dropped"] == (0 if another_row else 1)
+    engine.close()
+    fresh = InferenceEngine(model, params, ecfg, auto_start=False)
+    want = fresh.generate([late])[0]
+    fresh.close()
+    assert waiting.result(5.0) == want == _offline(model, params, late,
+                                                   max_new, eos)
+    if another_row:
+        assert first[1].result(5.0) == _offline(model, params, other,
+                                                max_new, eos)
+
+
+@pytest.mark.parametrize("case", ["budget-of-one", "first-token-is-eos"])
+def test_a_row_that_ends_on_its_first_token_never_streams_a_second(lm_live, case):
+    """The first token is read after the step behind it went out.  A budget
+    of one is host state: the row joins no step.  An EOS is learnt at the
+    read: the row rode the one step, whose output is dropped."""
+    cfg, model, params = lm_live
+    prompt, nxt = _prompts(seed=17, n=2)
+    first = _offline(model, params, prompt, 1, None)
+    eos = first[0] if case == "first-token-is-eos" else None
+    engine = InferenceEngine(
+        model, params,
+        EngineConfig(num_slots=2, slot_len=64, max_new_tokens=8,
+                     eos_token_id=eos),
+        auto_start=False,
+    )
+    stream = engine.submit(prompt, 1 if eos is None else 8)
+    while not engine.idle():
+        engine.step()
+    assert stream.result(5.0) == first
+    snap = engine.metrics.snapshot()
+    rode = int(eos is not None)
+    assert (snap.get("steps_issued", 0),
+            snap.get("steps_dropped", 0)) == (rode, rode)
+    assert snap["tokens_emitted"] == 1 and snap["requests_completed"] == 1
+    # the slot and its pages are free again and the loop goes on
+    assert engine.generate([nxt], 6) == [_offline(model, params, nxt, 6, eos)]
+    engine.close()
+
+
+def _with_a_step_unread(lm, prompt, name):
+    cfg, model, params = lm
+    engine = InferenceEngine(
+        model, params,
+        EngineConfig(num_slots=2, slot_len=64, max_new_tokens=10, page_len=8,
+                     eos_token_id=None),
+        auto_start=False, name=name,
+    )
+    stream = engine.submit(prompt)
+    engine.step()
+    engine.step()
+    assert engine._inflight is not None and not engine.idle()
+    return engine, stream
+
+
+def test_migrate_out_settles_the_step_in_flight_first(lm_live):
+    cfg, model, params = lm_live
+    prompt = _prompts(seed=19, n=1)[0]
+    src, stream = _with_a_step_unread(lm_live, prompt, "settle-migrate-src")
+    seen = len(stream.tokens_so_far())
+    (payload,) = src.migrate_out()
+    # the unread step was read and emitted, not lost and not dropped: the
+    # cursor shipped is the device's
+    assert src._inflight is None
+    assert len(payload["streamed"]) == seen + 1
+    assert payload["pos"] == len(prompt) + len(payload["streamed"]) - 1
+    assert src.metrics.snapshot()["steps_dropped"] == 0
+    dst = InferenceEngine(model, params, src.config, auto_start=False,
+                          name="settle-migrate-dst")
+    landed = dst.submit_migrated(payload)
+    while not dst.idle():
+        dst.step()
+    assert landed.result(5.0) == _offline(model, params, prompt, 10, None)
+    src.close()
+    dst.close()
+
+
+def test_swap_params_settles_the_step_in_flight_first(lm_live):
+    cfg, model, params = lm_live
+    prompt = _prompts(seed=23, n=1)[0]
+    engine, stream = _with_a_step_unread(lm_live, prompt, "settle-swap")
+    seen = len(stream.tokens_so_far())
+    engine.swap_params(jax.tree_util.tree_map(np.asarray, params), version=2)
+    # the step in flight kept the weights it was issued with and was read
+    # under the lock; the next one is issued with the new tree
+    assert engine._inflight is None
+    assert len(stream.tokens_so_far()) == seen + 1
+    while not engine.idle():
+        engine.step()
+    assert stream.result(5.0) == _offline(model, params, prompt, 10, None)
+    snap = engine.metrics.snapshot()
+    assert snap["steps_dropped"] == 0
+    # every step but the first and the one after the settle went out ahead
+    assert snap["steps_issued"] - snap["steps_ahead"] == 2
+    engine.close()
+
+
+def test_an_unread_step_keeps_the_engine_from_idle_and_drained(lm_live):
+    cfg, model, params = lm_live
+    prompt = _prompts(seed=29, n=1)[0]
+    engine, stream = _with_a_step_unread(lm_live, prompt, "settle-drain")
+    engine.drain()
+    assert engine.draining and not engine.drained() and not engine.idle()
+    steps = 0
+    while engine.step():
+        steps += 1
+        assert steps < 50, "engine failed to drain"
+    assert engine._inflight is None and engine.idle() and engine.drained()
+    assert stream.result(5.0) == _offline(model, params, prompt, 10, None)
     engine.close()
 
 

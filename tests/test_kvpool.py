@@ -652,6 +652,85 @@ def test_t5_window_learns_of_eos_one_step_late_and_emits_nothing_past_it(
     assert engine.metrics.snapshot()["steps_dropped"] == dropped
 
 
+@pytest.mark.parametrize("other_budget, issued, ahead, dropped", [
+    # the other row joins one iteration later and runs on to its budget of
+    # 8: steps 1..8, each one read, all but the first issued ahead
+    (8, 8, 7, 0),
+    # the other row ends on its budget of 3 (host state: it is in no step
+    # past its last), so the row that ends on EOS at its fifth token is the
+    # last: the sixth token's step is out when the host learns of it
+    (3, 5, 4, 1),
+], ids=["another-row-runs-on", "the-last-live-row"])
+def test_paged_engine_learns_of_eos_one_step_late_and_emits_nothing_past_it(
+        lm_live, other_budget, issued, ahead, dropped):
+    """``InferenceEngine``'s counters for the same two endings as the window
+    engine's case above."""
+    cfg, model, params = lm_live
+    ending, other, late = _prompts(seed=71, n=3)
+    ref = _offline(model, params, ending, 8)
+    eos = ref[4]
+    assert eos not in ref[:4] and eos not in _offline(model, params, other, 8)
+    engine = InferenceEngine(
+        model, params,
+        EngineConfig(num_slots=2, slot_len=64, max_new_tokens=8,
+                     eos_token_id=eos),
+        auto_start=False, name=f"paged-eos-{other_budget}")
+    a = engine.submit(ending, 8)
+    b = engine.submit(other, other_budget)
+    _drain(engine)
+    assert a.result(5.0) == ref[:5]
+    assert b.result(5.0) == _offline(model, params, other, other_budget)
+    snap = engine.metrics.snapshot()
+    assert (snap["steps_issued"], snap["steps_ahead"],
+            snap["steps_dropped"]) == (issued, ahead, dropped)
+    # a row's first token is its last chunk's; the ride-along is not counted
+    assert snap["tokens_emitted"] == 5 + other_budget
+    assert engine._inflight is None and engine.slots.free_count() == 2
+    # the next request is issued behind the dropped step and streams as ever
+    c = engine.submit(late, 4)
+    assert not engine.idle()
+    engine.drain()
+    assert not engine.drained()
+    _drain(engine)
+    assert c.result(5.0) == _offline(model, params, late, 4, eos)
+    assert engine.idle() and engine.drained()
+    snap = engine.metrics.snapshot()
+    assert (snap["steps_issued"], snap["steps_dropped"]) == (
+        issued + len(c.result()) - 1, dropped)
+    assert snap["requests_completed"] == 3
+    engine.close()
+    assert engine.metrics.snapshot()["steps_dropped"] == dropped
+
+
+def test_paged_decode_steps_upload_nothing_between_joins_and_leaves(lm_live):
+    """With its rows settled the paged step takes tokens, positions, block
+    table and cache from the device: with host-to-device transfers
+    disallowed every step runs, until a row ends and the table goes up again
+    (the parent uploaded tokens, positions and the table each step)."""
+    cfg, model, params = lm_live
+    prompts = _prompts(seed=73, n=2)
+    engine = InferenceEngine(
+        model, params,
+        EngineConfig(num_slots=2, slot_len=64, max_new_tokens=9,
+                     eos_token_id=None),
+        auto_start=False, name="paged-upload-test")
+    streams = [engine.submit(p) for p in prompts]
+    engine.step()
+    engine.step()  # both prompts are in: two chunks, two joins
+    engine.step()
+    issued = engine.metrics.snapshot()["steps_issued"]
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        with pytest.raises(Exception, match="host-to-device"):
+            jnp.asarray(np.zeros(2, np.int32))  # the guard is enforced here
+        for _ in range(4):
+            assert engine.step()
+    assert engine.metrics.snapshot()["steps_issued"] == issued + 4
+    _drain(engine)
+    assert [s.result(5.0) for s in streams] == [
+        _offline(model, params, p, 9) for p in prompts]
+    engine.close()
+
+
 def test_t5_step_counters_reach_metrics_only_from_an_engine_that_issues():
     from tpu_air.engine.metrics import (
         EngineMetrics,

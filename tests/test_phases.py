@@ -185,6 +185,111 @@ def test_engine_step_ahead_counts_are_the_engines_step_counters(
     assert counted["steps_dropped"] == 0
 
 
+@pytest.fixture(scope="module")
+def paged_phases(tmp_path_factory):
+    """A tiny ``InferenceEngine`` stepped by hand through five prompts over
+    two slots inside one session."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_air.engine import EngineConfig, InferenceEngine
+    from tpu_air.models.lm import CausalLM, LMConfig
+
+    cfg = LMConfig.tiny()
+    model = CausalLM(cfg)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.ones((1, 8), jnp.int32))["params"]
+    engine = InferenceEngine(
+        model, params,
+        EngineConfig(num_slots=2, slot_len=32, max_new_tokens=6,
+                     eos_token_id=None),
+        auto_start=False, name="paged-phase-test")
+    rng = np.random.RandomState(0)
+    prompts = [list(map(int, rng.randint(2, cfg.vocab_size, size=n)))
+               for n in (3, 8, 5, 4, 6)]
+    trace_dir = str(tmp_path_factory.mktemp("paged-trace"))
+    engine.generate(prompts[:2], max_new_tokens=2)  # compile outside the trace
+    before = engine.metrics.snapshot()
+    jax.profiler.start_trace(trace_dir)
+    try:
+        streams = [engine.submit(p, max_new_tokens=2 + i)
+                   for i, p in enumerate(prompts)]
+        steps = 0
+        while not engine.idle():
+            engine.step()
+            steps += 1
+            assert steps < 100, "the engine failed to drain"
+    finally:
+        jax.profiler.stop_trace()
+    tokens = sum(len(s.result(5.0)) for s in streams)
+    after = engine.metrics.snapshot()
+    engine.close()
+    counted = {k: after[k] - before[k] for k in (
+        "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped")}
+    return _phases(trace_dir), tokens, counted
+
+
+def test_paged_step_holds_dispatch_readback_emit_in_order(paged_phases):
+    phases, _, _ = paged_phases
+    steps = [p for p in phases if p[0] == "engine.step"]
+    assert len(steps) >= 8
+    for st in steps:
+        kids = _inside(phases, st)
+        names = [k[0] for k in kids]
+        issued = names[:1] == ["engine.dispatch"]
+        reads = [k for k in kids if k[0] == "engine.readback"]
+        # the next step goes out (at most once, and first); then the step
+        # before it is read back and emitted; then the first tokens of the
+        # prompts whose last chunk went out this iteration, the same way
+        assert names == (["engine.dispatch"] * issued
+                         + ["engine.readback", "engine.emit"] * len(reads))
+        assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
+        firsts = [r for r in reads if "first" in r[3]]
+        assert len(firsts) <= 1 and reads[len(reads) - len(firsts):] == firsts
+        # ahead: a step went out while the one before it was still unread
+        assert st[3]["ahead"] == int(issued and len(reads) > len(firsts))
+        assert 1 <= st[3]["live"] <= st[3]["batch"] == 2
+        if len(reads) > len(firsts):
+            assert kids[issued + 1][3] == {"emitted": st[3]["live"]}
+        if firsts:
+            assert kids[-1][3] == {"emitted": firsts[0][3]["first"]}
+    # budgets 2..6 over two slots: only the very first step finds nothing
+    # in flight, and the last read has nothing left to issue
+    ahead = [st[3]["ahead"] for st in steps]
+    assert ahead == [0] + [1] * (len(steps) - 2) + [0]
+    assert {p[0] for p in phases} == {
+        "engine.prefill", "engine.step", "engine.dispatch",
+        "engine.readback", "engine.emit"}
+
+
+def test_paged_prefill_is_one_phase_a_chunk_and_holds_no_read(paged_phases):
+    phases, tokens, counted = paged_phases
+    prefills = [p for p in phases if p[0] == "engine.prefill"]
+    # five prompts of one chunk each; the chunk is issued, not waited for:
+    # its first token is read inside the engine.step that follows
+    assert [(p[3]["tokens"], p[3]["start"]) for p in prefills] == [(16, 0)] * 5
+    assert not any(_inside(phases, p) for p in prefills)
+    steps = [p for p in phases if p[0] == "engine.step"]
+    assert not any(_inside(phases, st) and st[1] <= p[1] <= st[2]
+                   for st in steps for p in prefills)
+    emitted = sum(p[3]["emitted"] for p in phases if p[0] == "engine.emit")
+    first = sum(p[3]["first"] for p in phases
+                if p[0] == "engine.readback" and "first" in p[3])
+    assert first == 5 and emitted == counted["tokens_emitted"] == tokens
+
+
+def test_paged_step_ahead_counts_are_the_engines_step_counters(paged_phases):
+    phases, _, counted = paged_phases
+    steps = [p for p in phases if p[0] == "engine.step"]
+    dispatches = [p for p in phases if p[0] == "engine.dispatch"]
+    # every issued step is one engine.dispatch; all but the first went out
+    # ahead; budgets end these streams, so every issued step is read
+    assert counted["steps_issued"] == len(dispatches)
+    assert counted["steps_ahead"] == sum(st[3]["ahead"] for st in steps) == (
+        len(dispatches) - 1)
+    assert counted["steps_dropped"] == 0
+
+
 def test_train_loop_phases_per_step_and_epoch(air, tmp_path):
     """``t5_train_loop`` in this process, one epoch of three steps."""
     import jax

@@ -237,8 +237,10 @@ def test_interactive_ttft_flat_under_batch_flood(lm):
     # per already-admitted non-reserved slot, prefill_chunks_per_step=1),
     # NOT by the flooded queue depth — without the reservation, interactive
     # would wait for a batch slot to decode its full budget and retire.
+    # One iteration more: the engine reads a decode step one iteration after
+    # it issued it, so a slot whose stream ended is free one iteration later.
     chunk_backlog = econf.num_slots - econf.reserved_interactive_slots
-    assert max(over_steps) <= max(base_steps) + chunk_backlog, (
+    assert max(over_steps) <= max(base_steps) + chunk_backlog + 1, (
         base_steps, over_steps)
     # the acceptance criterion as written, wall-clock with CPU-noise floor
     floor = 0.05

@@ -46,6 +46,7 @@ from .sharded import (
     make_sharded_page_copy_fn,
     make_sharded_paged_decode_step_fn,
     make_sharded_prefill_chunk_fn,
+    make_sharded_step_feed_fns,
     paged_cache_shardings,
 )
 
@@ -158,6 +159,7 @@ class MeshEngine(InferenceEngine):
             self.model, cfg.page_len, cfg.slot_len, self.mesh,
             self._param_sh, self._cache_sh)
         self._copy_fn = make_sharded_page_copy_fn(self.mesh, self._cache_sh)
+        self._advance, self._set_row = make_sharded_step_feed_fns(self.mesh)
 
     # -- per-replica admission ------------------------------------------------
     def _begin_admission_round(self) -> None:

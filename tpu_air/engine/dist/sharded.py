@@ -29,9 +29,11 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpu_air.models.lm.generate import (
+    advance_rows_body,
     make_paged_decode_body,
     make_prefill_chunk_body,
     page_copy_body,
+    set_row_body,
 )
 
 
@@ -70,6 +72,15 @@ def make_sharded_paged_decode_step_fn(model, slot_len: int, mesh,
         in_shardings=(param_shardings, cache_shardings, batch, batch, table),
         out_shardings=(cache_shardings, batch),
     )
+
+
+def make_sharded_step_feed_fns(mesh):
+    """``make_lm_step_feed_fns`` for the MeshEngine: the step's ``tok`` and
+    ``pos`` stay over ``data`` between steps, as its ``in_shardings`` want
+    them."""
+    batch = NamedSharding(mesh, P("data"))
+    return (jax.jit(advance_rows_body, out_shardings=(batch, batch)),
+            jax.jit(set_row_body, out_shardings=(batch, batch)))
 
 
 def make_sharded_prefill_chunk_fn(model, page_len: int, slot_len: int, mesh,

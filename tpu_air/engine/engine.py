@@ -10,7 +10,8 @@ interleaved between decode steps (one compiled chunk program covers every
 prompt length); prompts sharing a cached prefix skip the covered chunks and
 share the physical pages, copy-on-write on the first divergent append.
 
-Requests flow through three host-side phases BETWEEN device steps:
+Requests flow through three host-side phases, all of them run UNDER the
+decode step the device is computing (see "one step in flight" below):
 
 1. **admission** — FIFO from the scheduler queue, gated on KV-page
    capacity with a bounded reorder window so a big blocked head can't
@@ -23,6 +24,34 @@ Requests flow through three host-side phases BETWEEN device steps:
    the next host visit (its private pages return to the free list; its
    prompt's pages stay resident in the prefix cache for future hits).
 
+ONE STEP IN FLIGHT.  The loop runs one decode step ahead of its host: step
+N+1 is issued from step N's tokens as they lie on the device, and only then
+is step N read back, emitted and retired from.  The step's ``tok`` and
+``pos`` live on the device (``advance_rows_body`` moves them on behind every
+step, ``set_row_body`` sets the row that joins or leaves), the masked block
+table is uploaded again only when a row joined or left, and a prompt's last
+chunk hands its first token to the step on the device and is read
+afterwards.  So an iteration is: admit; issue the chunk(s) behind the step in
+flight; issue step N+1; read step N and emit it; read the first tokens of
+the prompts that just finished.  What follows from reading one step late:
+
+* budgets are host state, so a row whose budget ends with step N is not in
+  step N+1, and a step is issued only if some row has a token left after
+  the one in flight;
+* a row that ends on EOS in step N is learnt of after N+1 went out: it rides
+  N+1 once.  That write lands past its prompt, in a page reserved for it at
+  admission and private to it (``register`` publishes whole prompt pages
+  only), its pages are released after N+1 was issued, and whatever is given
+  them next runs behind N+1 on the device (the cache is donated through
+  every program).  Its output in N+1 is discarded, never emitted or counted;
+* a step whose rows all ended before it was read is dropped unread
+  (``steps_dropped``), as is the step in flight at ``close``;
+* whatever reads or changes slot state from outside the loop (migration,
+  weight swap and rollback, adapter load and unload) first settles the step
+  in flight: reads it and emits it (``_settle``).
+
+Depth is one and fixed; no option selects the behaviour.
+
 Correctness anchor: with greedy decoding the engine's emitted tokens are
 token-identical to offline ``generate()`` on the same prompts —
 tests/test_engine.py pins this on CPU for burst, staggered and trickle
@@ -33,7 +62,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -43,6 +72,7 @@ from tpu_air.models.lm.generate import (
     init_paged_cache,
     make_lm_paged_decode_step_fn,
     make_lm_prefill_chunk_fn,
+    make_lm_step_feed_fns,
     make_page_copy_fn,
 )
 
@@ -65,6 +95,19 @@ from .types import (
     RequestValidationError,
     ResponseStream,
 )
+
+
+class _IssuedStep(NamedTuple):
+    """A decode step handed to the device and not read yet: its device
+    output, and the rows it decodes with the request each held then."""
+
+    out: Any
+    rows: List[Tuple[Slot, Request]]
+
+    def alive(self) -> List[Slot]:
+        """The rows that still hold the request the step decoded for them
+        (a row that ended since, or was given to another request, is not)."""
+        return [s for s, req in self.rows if s.request is req]
 
 
 class InferenceEngine:
@@ -101,9 +144,24 @@ class InferenceEngine:
         # pjit-wrapped step fns, same host loop)
         self._build_paged_state()
 
-        # host side: authoritative per-slot state the step args come from
-        self._cur_tok = np.zeros((cfg.num_slots,), np.int32)
-        self._pos = np.zeros((cfg.num_slots,), np.int32)
+        # the step's inputs as they lie on the device between steps (see
+        # the module doc): tokens and positions, moved on behind every step;
+        # the masked block table (and adapter rows), uploaded again by the
+        # issue that finds a row joined or left (the first one does)
+        self._tok_dev = jnp.zeros((cfg.num_slots,), jnp.int32)
+        self._pos_dev = jnp.zeros((cfg.num_slots,), jnp.int32)
+        self._table_dev = None
+        self._adapter_ids_dev = None
+        self._riding: set = set()   # rows at a position > 0 on the device
+        # rows that join the next issued step: row -> (first token, on the
+        # device or an int; prompt length)
+        self._joins: Dict[int, Any] = {}
+        # first tokens issued and not read yet: (slot, device scalar)
+        self._firsts: List[Any] = []
+        # the one issued step the host has not read; and when the stream's
+        # current token step began (the last read-back, or its own issue)
+        self._inflight: Optional[_IssuedStep] = None
+        self._mark = 0.0
         self._round_reserved = 0   # pages promised during one admission round
         self._chunks_run = 0       # prefill chunk calls, engine lifetime
 
@@ -171,6 +229,7 @@ class InferenceEngine:
             self.model, cfg.page_len, cfg.slot_len,
             adapters=self.adapters_enabled)
         self._copy_fn = make_page_copy_fn()
+        self._advance, self._set_row = make_lm_step_feed_fns()
         if self.adapters_enabled:
             mc = self.model.config
             A, r = cfg.adapter_slots, cfg.adapter_rank
@@ -317,9 +376,10 @@ class InferenceEngine:
 
     # -- the engine loop -----------------------------------------------------
     def step(self) -> bool:
-        """One deterministic engine iteration: admit into free slots, run
-        the prefill quantum, then one pool decode step if anything
-        is decoding.  Returns True if any work happened (callers loop
+        """One deterministic engine iteration: admit into free slots, issue
+        the prefill quantum, then one token step: issue the next pool decode
+        step if a row has budget for it, then read back and emit the one
+        before.  Returns True if any work happened (callers loop
         ``while engine.step(): ...`` to drain)."""
         with self._step_lock:
             worked = False
@@ -336,8 +396,7 @@ class InferenceEngine:
                     worked = True
             if self._prefill_quantum():
                 worked = True
-            if any(not s.prefilling for s in self.slots.active_slots()):
-                self._decode_all()
+            if self._token_step():
                 worked = True
             self.metrics.observe_gauges(
                 self.scheduler.depth(), self.slots.occupancy(),
@@ -351,7 +410,13 @@ class InferenceEngine:
             return worked
 
     def idle(self) -> bool:
-        return self.scheduler.depth() == 0 and self.slots.occupancy() == 0
+        # airlint: disable=CC001 — GIL-atomic pointer read; an unread step
+        # holds at least one occupied slot (one whose rows all ended is
+        # dropped in the iteration that ended them), so this only spells
+        # out what occupancy already says
+        unread = self._inflight is not None
+        return (self.scheduler.depth() == 0 and self.slots.occupancy() == 0
+                and not unread)
 
     # -- draining (zero-downtime rollout / scale-down) ------------------------
     def drain(self) -> None:
@@ -388,8 +453,9 @@ class InferenceEngine:
         return self._preempting
 
     def migrate_out(self) -> List[Dict[str, Any]]:
-        """Preemption drain: freeze the loop between steps and pull every
-        DECODING slot's live state into portable payloads for
+        """Preemption drain: freeze the loop, settle the step in flight
+        (read and emit it, so every cursor below is the device's) and pull
+        every DECODING slot's live state into portable payloads for
         :meth:`submit_migrated` on a survivor.
 
         Each payload carries everything the destination needs to continue
@@ -408,6 +474,7 @@ class InferenceEngine:
 
         payloads: List[Dict[str, Any]] = []
         with self._step_lock:
+            self._settle()
             for slot in list(self.slots.active_slots()):
                 if slot.prefilling:
                     continue
@@ -436,8 +503,6 @@ class InferenceEngine:
                 # the slot is released without finishing the stream
                 self.pool.release(slot.index)
                 self.slots.release(slot)
-                self._cur_tok[slot.index] = 0
-                self._pos[slot.index] = 0
                 self._adapter_ids_host[slot.index] = 0
         return payloads
 
@@ -561,7 +626,14 @@ class InferenceEngine:
         touch a prefix-shared page), insert the pages, emit the worker's
         first token, and hand the slot straight to decode.  ``register``
         then publishes the now-populated prompt pages to this engine's
-        prefix cache, so later LOCAL submits share them normally."""
+        prefix cache, so later LOCAL submits share them normally.
+
+        A step in flight is left in flight: the row is not in it (a row that
+        rode it for a request that ended is skipped at the read, by
+        identity), the insert below runs behind it on the device, and the
+        row joins the next issued step with ``first`` as its token.  A
+        settle here would retire rows in the middle of an admission round,
+        under the MeshEngine's per-replica reservations."""
         n = len(req.prompt)
         slot.plan = self.pool.admit(
             slot.index, req.prompt, req.max_new_tokens, share=False)
@@ -588,12 +660,12 @@ class InferenceEngine:
         slot.prefilling = False
         slot.pos = n
         slot.budget_left = req.max_new_tokens - 1
-        self._cur_tok[slot.index] = first
-        self._pos[slot.index] = n
         if slot.budget_left == 0 or (
             self.eos_token_id is not None and first == self.eos_token_id
         ):
             self._retire(slot)
+        else:
+            self._joins[slot.index] = (first, n)
 
     def _fail_admission(self, slot: Slot, req: Request,
                         error: BaseException) -> None:
@@ -603,8 +675,6 @@ class InferenceEngine:
         this engine decoding from corrupt pages."""
         self.pool.release(slot.index)
         self.slots.release(slot)
-        self._cur_tok[slot.index] = 0
-        self._pos[slot.index] = 0
         self._adapter_ids_host[slot.index] = 0
         req.stream._finish(error)
 
@@ -619,7 +689,8 @@ class InferenceEngine:
         0; the acceptance test pins it).  The pages are NOT registered
         with the prefix cache: the tail page is mid-append and the
         admitted "prompt" includes generated tokens — publishing it would
-        let a future prompt share a page decode is still writing into."""
+        let a future prompt share a page decode is still writing into.
+        A step in flight stays in flight, as in :meth:`_admit_prefilled`."""
         m = req.migrated
         p = len(req.prompt)          # cache-resident positions 0..p-1
         slot.plan = self.pool.admit(
@@ -646,8 +717,6 @@ class InferenceEngine:
         slot.prefilling = False
         slot.pos = p
         slot.budget_left = req.max_new_tokens - 1
-        self._cur_tok[slot.index] = streamed[-1]
-        self._pos[slot.index] = p
         self.metrics.record_migration(
             "in", len(page_ids), reprefill_chunks=slot.plan.chunks_left)
         self.metrics.record_tenant_migrated(req.tenant or req.adapter_id,
@@ -657,6 +726,8 @@ class InferenceEngine:
             and streamed[-1] == self.eos_token_id
         ):
             self._retire(slot)
+        else:
+            self._joins[slot.index] = (streamed[-1], p)
 
     def _insert_shipped_pages(self, cache, page_ids, payload):
         """Write a disaggregated handoff's KV pages into ``page_ids`` of the
@@ -667,11 +738,13 @@ class InferenceEngine:
         return insert_kv_pages(cache, page_ids, payload)
 
     def _prefill_quantum(self) -> bool:
-        """Run up to ``prefill_chunks_per_step`` prefill chunk calls,
+        """Issue up to ``prefill_chunks_per_step`` prefill chunk calls,
         SHORTEST-REMAINING-PROMPT first (ties: request id = arrival order).
-        Bounding the per-step quantum keeps any single long prompt from
-        stalling in-flight decodes; preferring short remainders keeps
-        short-request TTFT flat while a long prompt streams in."""
+        They queue behind the decode step in flight and ahead of the next
+        one, so the device starts them the moment it is free.  Bounding the
+        per-step quantum keeps any single long prompt from stalling
+        in-flight decodes; preferring short remainders keeps short-request
+        TTFT flat while a long prompt streams in."""
         ran = False
         for _ in range(max(1, self.config.prefill_chunks_per_step)):
             pending = [s for s in self.slots.active_slots() if s.prefilling]
@@ -715,10 +788,11 @@ class InferenceEngine:
                 jnp.int32(last_local), jnp.asarray(row),
             )
         if self._cost_model is not None:
-            # dispatch-time measurement: only the final chunk is host-synced
-            # (int(tok) below), so mid-prompt chunk seconds are the dispatch
-            # cost on an async backend — exact on CPU, a lower bound on TPU
-            # (on-chip rerun is ROADMAP item 5's lane)
+            # dispatch-time measurement: no chunk is host-synced here (the
+            # final chunk's token is read after the next step went out), so
+            # chunk seconds are the dispatch cost on an async backend —
+            # exact on CPU, a lower bound on TPU (on-chip rerun is ROADMAP
+            # item 5's lane)
             self.metrics.record_program(
                 "prefill_chunk",
                 self._cost_model.prefill_chunk_cost(C, p0),
@@ -727,16 +801,9 @@ class InferenceEngine:
         self._chunks_run += 1
         if not plan.done:
             return
-        # final chunk: first token, publication, CoW, hand over to decode
-        first = int(np.asarray(tok))
-        req.first_token_at = time.monotonic()
-        if req.t_submit_ns:  # traced request: stamp TTFT for span emission
-            req.t_first_ns = _tracing.now_ns()
-        self.metrics.record_ttft(req.first_token_at - req.submitted_at,
-                                 req.priority,
-                                 trace_id=(req.trace_ctx or {}).get("trace_id"))
-        req.stream._emit(first)
-        self.metrics.record_tokens(1)  # prefill's first token
+        # final chunk: publication, CoW, hand over to decode.  The first
+        # token stays on the device: the row joins the next issued step with
+        # it, and the host reads it after that step went out (_read_firsts)
         self.pool.register(slot.index, req.prompt)
         cow = self.pool.resolve_cow(slot.index)
         if cow is not None:
@@ -746,20 +813,46 @@ class InferenceEngine:
         slot.prefilling = False
         slot.pos = n
         slot.budget_left = req.max_new_tokens - 1
-        self._cur_tok[slot.index] = first
-        self._pos[slot.index] = n
-        if slot.budget_left == 0 or (
-            self.eos_token_id is not None and first == self.eos_token_id
-        ):
-            self._retire(slot)
+        self._firsts.append((slot, tok))
+        if slot.budget_left:
+            self._joins[slot.index] = (tok, n)
+
+    def _read_firsts(self) -> None:
+        """Read and emit the first tokens of the prompts whose last chunk
+        was issued this iteration (TTFT is stamped here, at the read).  A
+        row whose budget was one token, or whose first token is EOS, retires
+        here; the latter rode the step that went out before this read."""
+        firsts, self._firsts = self._firsts, []
+        with phase("engine.readback", first=len(firsts)):
+            # airlint: disable=JX004 — one read a finished prompt, after the
+            # step it joins went out: the device is busy behind this wait
+            tokens = [int(np.asarray(tok)) for _, tok in firsts]
+        with phase("engine.emit", emitted=len(firsts)):
+            for (slot, _), first in zip(firsts, tokens):
+                req = slot.request
+                req.first_token_at = time.monotonic()
+                if req.t_submit_ns:  # traced request: TTFT for span emission
+                    req.t_first_ns = _tracing.now_ns()
+                self.metrics.record_ttft(
+                    req.first_token_at - req.submitted_at, req.priority,
+                    trace_id=(req.trace_ctx or {}).get("trace_id"))
+                req.stream._emit(first)
+                if slot.budget_left == 0 or (
+                    self.eos_token_id is not None
+                    and first == self.eos_token_id
+                ):
+                    self._retire(slot)
+            self.metrics.record_tokens(len(firsts))  # prefill's first tokens
 
     # -- live weight swap (serve/weights.py) ---------------------------------
     def swap_params(self, new_params, *, version: Optional[int] = None
                     ) -> float:
         """Replace the serving weights BETWEEN decode steps: taken under
-        ``_step_lock``, so no step is mid-flight — slots, host token/pos
-        arrays and the paged pool are untouched, and in-flight streams
-        continue on the new weights at their exact positions.  The new
+        ``_step_lock`` and after the step in flight was settled (read and
+        emitted: that step keeps the old weights, the next issued one takes
+        the new) — slots, the device's token/position vectors and the paged
+        pool are untouched, and in-flight streams continue on the new
+        weights at their exact positions.  The new
         tree is resharded leaf-by-leaf onto the OLD leaves' shardings
         (``device_put`` per leaf — a tp/dp-partitioned checkpoint restores
         onto whatever mesh this engine serves on) after a structure/shape
@@ -775,6 +868,7 @@ class InferenceEngine:
         if _faults.enabled():
             _faults.perturb("weights.swap", key=self.name)
         with self._step_lock:
+            self._settle()
             old_leaves, old_tree = jax.tree_util.tree_flatten(self.params)
             new_leaves, new_tree = jax.tree_util.tree_flatten(new_params)
             if old_tree != new_tree:
@@ -801,12 +895,14 @@ class InferenceEngine:
 
     def rollback_params(self) -> float:
         """Restore the weights :meth:`swap_params` replaced — a pure
-        device-tree pointer swap under ``_step_lock``, no store reads, so
-        rollback works even when the bad publish's store objects are
-        corrupt or already GC'd.  Raises RuntimeError with no prior
+        device-tree pointer swap under ``_step_lock`` (the step in flight is
+        settled first and keeps the weights it was issued with), no store
+        reads, so rollback works even when the bad publish's store objects
+        are corrupt or already GC'd.  Raises RuntimeError with no prior
         version retained."""
         t_req = time.monotonic()
         with self._step_lock:
+            self._settle()
             if self._prev_params is None:
                 raise RuntimeError("no prior weights retained to roll back to")
             # one-shot: clearing the slot frees the bad tree's device memory
@@ -851,7 +947,8 @@ class InferenceEngine:
         r], ``b``: [r, vocab]; rank r <= ``adapter_rank`` zero-pads into
         the bank (zero padding is exact — padded lanes contribute 0).
         A cheap sub-swap: two ``.at[row].set`` writes under ``_step_lock``
-        between decode steps; the jitted step never retraces."""
+        between decode steps (the one in flight is settled first); the
+        jitted step never retraces."""
         if not self.adapters_enabled:
             raise ValueError(
                 "adapters not enabled (EngineConfig.adapter_slots=0)")
@@ -876,6 +973,7 @@ class InferenceEngine:
         pa[:, :r] = a
         pb[:r, :] = b
         with self._step_lock:
+            self._settle()
             with self._adapter_lock:
                 row = self._adapter_rows.get(name)
                 if row is None:
@@ -901,6 +999,7 @@ class InferenceEngine:
         if not self.adapters_enabled:
             return False
         with self._step_lock:
+            self._settle()
             with self._adapter_lock:
                 row = self._adapter_rows.get(name)
                 if row is None:
@@ -930,61 +1029,132 @@ class InferenceEngine:
         scatter never crosses a data shard."""
         return 0
 
-    def _decode_all(self) -> None:
-        live = [s for s in self.slots.active_slots() if not s.prefilling]
-        with phase("engine.step", live=len(live),
-                   batch=self.config.num_slots):
-            t0 = time.monotonic()
-            with phase("engine.dispatch"):
-                nxt = self._dispatch_decode()
-            with phase("engine.readback"):
-                nxt = np.asarray(nxt)
-            dt = time.monotonic() - t0
-            if self._decode_cost is not None:
-                self.metrics.record_program(
-                    "decode_step", self._decode_cost, dt)
-            if len(nxt) > self.config.num_slots:
-                # sparse experts: the step's routing counters ride behind
-                # the tokens (make_paged_decode_body)
-                self.metrics.record_routing(
-                    nxt[self.config.num_slots:-1], int(nxt[-1]))
-            # one phase around the walk over the live rows, none per row
-            with phase("engine.emit", emitted=len(live)):
-                for slot in live:
-                    # airlint: disable=JX004 — nxt is the np.asarray'd step
-                    # result; the single device sync already happened above
-                    token = int(nxt[slot.index])
-                    slot.request.stream._emit(token)
-                    slot.pos += 1
-                    slot.budget_left -= 1
-                    self._cur_tok[slot.index] = token
-                    self._pos[slot.index] = slot.pos
-                    if slot.budget_left == 0 or (
-                        self.eos_token_id is not None
-                        and token == self.eos_token_id
-                    ):
-                        self._retire(slot)
-                self.metrics.record_step(dt, len(live))
+    def _token_step(self, issue: bool = True) -> bool:
+        """One token step: issue the next decode step (``issue``, and a row
+        has budget for it), then read back and emit the step before it and
+        the first tokens of the prompts that just finished.  False when
+        there was nothing to issue and nothing to read."""
+        unread, self._inflight = self._inflight, None
+        reading = unread.alive() if unread else []
+        rows = self._step_rows(reading) if issue else []
+        if unread is None and not rows and not self._firsts:
+            return False
+        ahead = bool(rows) and unread is not None
+        with phase("engine.step", live=len(reading if unread else rows),
+                   batch=self.config.num_slots, ahead=int(ahead)):
+            if rows:
+                # out before step N is read: the device runs it while the
+                # host reads, emits, retires and admits
+                with phase("engine.dispatch"):
+                    self._issue(rows, ahead)
+            if unread is not None:
+                self._read(unread.out, reading)
+            if self._firsts:
+                self._read_firsts()
+            if self._inflight is not None and not self._inflight.alive():
+                # every row of the step that is out ended (on EOS) in the
+                # step just read: nobody will read it
+                self._drop_step()
+        return True
 
-    def _dispatch_decode(self):
-        """Issue one pool decode step; returns the step's (device) output."""
-        # non-decoding rows (free OR mid-prefill) ride along pointed at
-        # the null page: their ride-along scatter can't touch a live or
-        # prefix-shared page.  The authoritative table stays host-side.
-        table = self.pool.block_table.copy()
-        for s in self.slots.slots:
-            if not s.active or s.prefilling:
-                table[s.index] = self._null_entry(s.index)
-        args = (self.params, self.cache,
-                jnp.asarray(self._cur_tok), jnp.asarray(self._pos),
-                jnp.asarray(table))
+    def _settle(self) -> None:
+        """Read and emit the step in flight, and issue none: afterwards the
+        host's slot state is the device's, as between two steps of a loop
+        that reads each step before the next goes out.  What reads or
+        changes slot state from outside the loop calls this first (under
+        ``_step_lock``)."""
+        self._token_step(issue=False)
+
+    def _drop_step(self) -> None:
+        if self._inflight is not None:
+            self._inflight = None
+            self.metrics.record_dropped_step()
+
+    def _step_rows(self, reading: List[Slot]) -> List[Slot]:
+        """The rows the next step decodes: past their prompt, with budget
+        for a token after the one the unread step holds for them
+        (``reading``: its rows).  Budgets are host state, so a row that ends
+        on its budget never rides; one that ends on EOS in the unread step
+        is still here (see the module doc)."""
+        held = {s.index for s in reading}
+        return [s for s in self.slots.active_slots() if not s.prefilling
+                and s.budget_left - (s.index in held) >= 1]
+
+    def _issue(self, rows: List[Slot], ahead: bool) -> None:
+        """Issue one pool decode step over ``rows`` from the inputs on the
+        device, and move those inputs on behind it."""
+        wanted = {s.index for s in rows}
+        leaving = self._riding - wanted
+        for i in leaving:
+            self._set_dev_row(i, 0, 0)
+        for i, (tok, p) in self._joins.items():
+            self._set_dev_row(i, tok, p)
+        if leaving or self._joins:
+            # every other row (free, mid-prefill, or out of budget) rides
+            # along pointed at the null page: its scatter can't touch a live
+            # or prefix-shared page.  The authoritative table stays
+            # host-side; between these events a riding row's pages, reserved
+            # at admission for its whole life, do not change.
+            table = self.pool.block_table.copy()
+            for s in self.slots.slots:
+                if s.index not in wanted:
+                    table[s.index] = self._null_entry(s.index)
+            self._table_dev = jnp.asarray(table)
+            if self.adapters_enabled:
+                # per-slot LoRA rows gathered the way the table is: one
+                # host array in, no retrace, row 0 = exact-zero delta
+                self._adapter_ids_dev = jnp.asarray(
+                    self._adapter_ids_host.copy())
+        self._riding = wanted
+        self._joins = {}
+        args = (self.params, self.cache, self._tok_dev, self._pos_dev,
+                self._table_dev)
         if self.adapters_enabled:
-            # per-slot LoRA rows gathered the way the table is: one
-            # host array in, no retrace, row 0 = exact-zero delta
-            args += (self._adapter_a, self._adapter_b,
-                     jnp.asarray(self._adapter_ids_host))
-        self.cache, nxt = self._decode_step(*args)
-        return nxt
+            args += (self._adapter_a, self._adapter_b, self._adapter_ids_dev)
+        self.cache, out = self._decode_step(*args)
+        self._tok_dev, self._pos_dev = self._advance(
+            self._tok_dev, self._pos_dev, out)
+        self._inflight = _IssuedStep(out, [(s, s.request) for s in rows])
+        self.metrics.record_issue(ahead)
+        if not ahead:
+            self._mark = time.monotonic()
+
+    def _set_dev_row(self, row: int, tok, p: int) -> None:
+        self._tok_dev, self._pos_dev = self._set_row(
+            self._tok_dev, self._pos_dev, np.int32(row),
+            tok if hasattr(tok, "dtype") else np.int32(tok), np.int32(p))
+
+    def _read(self, out, reading: List[Slot]) -> None:
+        """Read one issued step back and emit it to ``reading``, the rows
+        it decoded that are still the requests it decoded them for."""
+        with phase("engine.readback"):
+            nxt = np.asarray(out)
+        # what this token step cost the stream: read-back to read-back, a
+        # chunk that ran between the two steps included
+        now = time.monotonic()
+        dt, self._mark = now - self._mark, now
+        if self._decode_cost is not None:
+            self.metrics.record_program("decode_step", self._decode_cost, dt)
+        if len(nxt) > self.config.num_slots:
+            # sparse experts: the step's routing counters ride behind
+            # the tokens (make_paged_decode_body)
+            self.metrics.record_routing(
+                nxt[self.config.num_slots:-1], int(nxt[-1]))
+        # one phase around the walk over the rows, none per row
+        with phase("engine.emit", emitted=len(reading)):
+            for slot in reading:
+                # airlint: disable=JX004 — nxt is the np.asarray'd step
+                # result; the single device sync already happened above
+                token = int(nxt[slot.index])
+                slot.request.stream._emit(token)
+                slot.pos += 1
+                slot.budget_left -= 1
+                if slot.budget_left == 0 or (
+                    self.eos_token_id is not None
+                    and token == self.eos_token_id
+                ):
+                    self._retire(slot)
+            self.metrics.record_step(dt, len(reading))
 
     # -- retirement ----------------------------------------------------------
     def _retire(self, slot: Slot) -> None:
@@ -1015,8 +1185,6 @@ class InferenceEngine:
         # cache registered stay resident for future hits
         self.pool.release(slot.index)
         self.slots.release(slot)
-        self._cur_tok[slot.index] = 0
-        self._pos[slot.index] = 0
         self._adapter_ids_host[slot.index] = 0
 
     def _emit_request_spans(self, slot: Slot) -> None:
@@ -1114,6 +1282,8 @@ class InferenceEngine:
                 req.stream._finish(err)
                 self.pool.release(slot.index)
                 self.slots.release(slot)
+            # a step still out is never read: the device finishes it
+            self._drop_step()
         unregister(self.name)
 
     def __enter__(self) -> "InferenceEngine":

@@ -348,6 +348,34 @@ def make_lm_paged_decode_step_fn(model: CausalLM, slot_len: int,
     return jax.jit(body, donate_argnums=(1,))
 
 
+def advance_rows_body(tok, pos, out):
+    """``fn(tok, pos, out) -> (tok', pos')``: the engine's device-resident
+    step inputs moved on by the step that just ran on them.  ``tok``/``pos``
+    ``[S]`` int32 are what that step took, ``out`` what it returned (its
+    first ``S`` entries are the tokens; a sparse-expert model's routing
+    counters ride behind).  A decoding row (``pos > 0``) takes its new token
+    and the next position; a row that rode along at position 0 stays at
+    token 0, position 0."""
+    live = pos > 0
+    return (jnp.where(live, out[:tok.shape[0]], tok),
+            pos + live.astype(pos.dtype))
+
+
+def set_row_body(tok, pos, row, t, p):
+    """``fn(tok, pos, row, t, p) -> (tok', pos')``: one row of the step
+    inputs set — a row joining the step (``t`` its first token, as the
+    chunk program left it on the device, ``p`` its prompt length) or
+    leaving it (0, 0)."""
+    return tok.at[row].set(t), pos.at[row].set(p)
+
+
+def make_lm_step_feed_fns():
+    """The two tiny programs that keep the paged step's ``tok`` and ``pos``
+    on the device between steps: jitted :func:`advance_rows_body` (once a
+    step) and :func:`set_row_body` (once a row that joins or leaves)."""
+    return jax.jit(advance_rows_body), jax.jit(set_row_body)
+
+
 def make_prefill_chunk_logits_body(model: CausalLM, page_len: int,
                                    slot_len: int):
     """``fn(params, cache, ids, p0, last_local, table_row) -> (cache',
