@@ -9,12 +9,13 @@ the Mosaic kernel is really in the program (``tpu_custom_call``), so a path
 that quietly took interpret mode fails.  A compile that passes is not a chip
 run: numbers and results come from ``chip_smoke.py`` and ``-m tpu``.
 
-The last two tests compile the flat decode attention and ``generate`` itself
-at the W3 shape and read what the compiler made of the decode cache's layout
-(no kernel in them).
+The last two tests compile the flat decode attention, ``generate`` at the W3
+shape and the engine's step at the serving shape, and read what the compiler
+made of the decode cache's layout (no kernel in them).
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -163,28 +164,152 @@ def test_flat_decode_attention_compiles_for_v5e(v5e, cache):
     assert "[256,512,12,64]" not in compiled.as_text()
 
 
-@pytest.mark.parametrize("early_stop", [True, False], ids=["while", "scan"])
-def test_generate_streams_the_cache_unpadded_on_v5e(v5e, early_stop):
-    """``generate`` at the W3 shape (FLAN-T5-base, 256 x 512, bf16, 128 new
-    tokens) under both loop forms: the chip's compiler keeps no row-major
-    4-D copy of a cache slab (minor pair (12, 64) tiled to (16, 128), 2.67 x
-    the bytes) and the program's temporaries stay near the flat cache's 6 GB.
-    With the dense path under the while-loop they were 14.1 GB (PERF.md, PR
-    25).  tests/test_t5.py holds the same at the jaxpr; this holds what XLA
-    makes of it."""
+def _t5_on(devs, name):
+    """A bf16 FLAN-T5 and the shapes of its parameters on the described chip."""
     from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
-    from tpu_air.models.t5.generate import make_generate_fn
 
-    cfg = T5Config.flan_t5_base()
+    cfg = getattr(T5Config, name)()
     cfg.dtype = "bfloat16"
     model = T5ForConditionalGeneration(cfg)
     one = jnp.ones((1, 8), jnp.int32)
     params = jax.tree_util.tree_map(
-        lambda s: _struct(s.shape, jnp.bfloat16, v5e),
+        lambda s: _struct(s.shape, jnp.bfloat16, devs),
         jax.eval_shape(lambda: model.init(
             jax.random.PRNGKey(0), one, one, one))["params"])
-    ids = _struct((256, 512), jnp.int32, v5e)
-    compiled = make_generate_fn(model, 128, early_stop=early_stop).lower(
-        params, ids, ids, _struct((2,), jnp.uint32, v5e)).compile()
-    assert "bf16[256,512,12,64]{3,2,1,0" not in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 7.5e9
+    return model, params
+
+
+def _generate_w3(devs, early_stop):
+    """``generate`` at the W3 shape: FLAN-T5-base, 256 x 512, 128 new tokens."""
+    from tpu_air.models.t5.generate import make_generate_fn
+
+    model, params = _t5_on(devs, "flan_t5_base")
+    ids = _struct((256, 512), jnp.int32, devs)
+    return make_generate_fn(model, 128, early_stop=early_stop).lower(
+        params, ids, ids, _struct((2,), jnp.uint32, devs)), (256, 12, 64, 512)
+
+
+def _engine_step(devs):
+    """``T5Engine``'s donated-cache step at the ``t5large-serve`` shape:
+    FLAN-T5-large, a window of 64 x 512, a cache for 128 new tokens."""
+    from tpu_air.models.t5.generate import (
+        make_t5_decode_step_fn, make_t5_prefill_fn)
+
+    model, params = _t5_on(devs, "flan_t5_large")
+    ids = _struct((64, 512), jnp.int32, devs)
+    tok, cache, enc = jax.tree_util.tree_map(
+        lambda s: _struct(s.shape, s.dtype, devs),
+        jax.eval_shape(make_t5_prefill_fn(model, 129), params, ids, ids))
+    return make_t5_decode_step_fn(model).lower(
+        params, cache, tok, enc, ids), (64, 16, 64, 512)
+
+
+# program -> (builder, the most its temporaries may take)
+DECODE_PROGRAMS = {
+    "while": (lambda d: _generate_w3(d, True), 7.5e9),
+    "scan": (lambda d: _generate_w3(d, False), 7.5e9),
+    "engine_step": (_engine_step, 0.15e9),
+}
+
+_HBM = r"\{[\d,]*:T\(8,128\)\(2,1\)\}"       # a tiled layout outside fast memory
+_FAST = r"\{[\d,]*:T\(8,128\)\(2,1\)S\(1\)\}"  # ... and in it
+
+
+def _self_slab(b, hd, dec_len=129):
+    """A regex for one layer's self-attention slab, in either order of
+    position and batch, alone or as a one-layer slice of the stacked array."""
+    return rf"(?:bf16|s8)\[(?:1,)?(?:{dec_len},{b}|{b},{dec_len}),{hd}\]"
+
+
+def _slabs_written_back(text, slab):
+    """Self slabs the program holds in fast memory, appends to there and
+    writes back to HBM whole: ``copy-start`` of a slab from ``S(1)`` to HBM."""
+    return len(re.findall(rf"= \({slab}{_HBM}, {slab}{_FAST}, \S+\) copy-start\(",
+                          text))
+
+
+def _slabs_made_anew(text, slab):
+    """Slab-sized arrays the ENTRY computation makes in HBM: what is not a
+    parameter, a bitcast of one, or a ``dynamic-update-slice`` on one in
+    place.  A copy of a slab out of a parameter is one, a write-back too."""
+    entry = text[text.index("\nENTRY "):]
+    made = re.findall(rf"^\s*(?:ROOT )?%(\S+) = {slab}{_HBM} ([\w-]+)\(", entry,
+                      flags=re.M)
+    return len([op for name, op in made
+                if op not in ("parameter", "bitcast")
+                and "dynamic-update-slice" not in name])
+
+
+def test_slab_counters_see_what_the_parent_programs_did():
+    """The two counters are not vacuous: lines of the programs as they were
+    compiled before PR 34 (the ``while`` body's write-back of a layer's slab;
+    the one-step program's copy of a layer out of a stacked parameter)."""
+    slab = _self_slab(256, 768)
+    line = ("  %copy-start.13 = (bf16[256,129,768]{2,1,0:T(8,128)(2,1)}, "
+            "bf16[256,129,768]{2,1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) "
+            "copy-start(%dynamic_update_slice.226)")
+    assert _slabs_written_back(line, slab) == 1
+    prefetch = line.replace("(2,1)}, bf16", "(2,1)S(1)}, bf16").replace(
+        "(2,1)S(1)}, u32", "(2,1)}, u32")       # HBM -> fast memory: a read
+    assert _slabs_written_back(prefetch, slab) == 0
+    entry = ("\nENTRY %main {\n"
+             "  %p = bf16[24,129,64,1024]{3,2,1,0:T(8,128)(2,1)} parameter(0)\n"
+             "  %get-tuple-element.42 = bf16[1,129,64,1024]{3,2,1,0:T(8,128)(2,1)} "
+             "get-tuple-element(%fusion.2009), index=4\n"
+             "  %constant_dynamic-update-slice_fusion.9 = bf16[129,64,1024]"
+             "{2,1,0:T(8,128)(2,1)} fusion(%q), kind=kLoop\n}")
+    assert _slabs_made_anew(entry, _self_slab(64, 1024)) == 1
+
+
+@pytest.mark.parametrize("program", sorted(DECODE_PROGRAMS))
+def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
+    """The three programs that carry the T5 decode cache, as the chip's
+    compiler makes them: ``generate`` at the W3 shape under both loop forms
+    and the engine's donated step at the ``t5large-serve`` shape.
+
+    Cross slabs: ``bf16[b, h, 64, 512]`` laid out length-minor as stored
+    (``{3,2,1,0:T(8,128)(2,1)}``: (64, 512) are whole tiles), nothing copies
+    or transposes an array of a slab's dimensions in any order (the K/V
+    projection writes the layout itself), and no row-major 4-D slab is left
+    (minor pair (12, 64) tiled to (16, 128), 2.67 x the bytes).
+
+    Self slabs, the count that says PR 34's mechanism engages: **no slab is
+    held in fast memory through the step and written back to HBM whole**
+    (before: 23 of base's 24 under the ``while``, 1.2 of the 7.5 GB a step
+    moved; 10 of large's 48 in the engine's step, where a few still are), and
+    the one-step program makes no slab-sized array anew, so it copies no
+    slice of a cache parameter out before reading it (34 of 48 where one
+    array held all layers' slabs, 0.58 GB of temporaries).
+
+    The temporaries stay near the cache's own 6 GB; with the dense path under
+    the while-loop they were 14.1 GB (PERF.md, PR 25).  tests/test_t5.py
+    holds the same at the jaxpr; this holds what XLA makes of it."""
+    import itertools
+
+    build, temp_limit = DECODE_PROGRAMS[program]
+    lowered, cross = build(v5e)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    b, h, d, L = cross
+    name = f"bf16[{b},{h},{d},{L}]"
+    layouts = [lay for ln in text.splitlines() if "cross_attn" in ln
+               for lay in re.findall(re.escape(name) + r"\{([^}S]*)", ln)]
+    layers = 12 if program != "engine_step" else 24
+    assert len(layouts) >= 2 * layers, len(layouts)
+    assert set(layouts) == {"3,2,1,0:T(8,128)(2,1)"}, set(layouts)
+    dims = "|".join(",".join(map(str, p))
+                    for p in set(itertools.permutations(cross)))
+    moved = re.findall(
+        rf"(?:bf16|f32)\[(?:{dims})\]\{{[^}}]*\}} (?:copy|transpose)\(.*", text)
+    assert not moved, moved[:4]
+    assert f"bf16[{b},{L},{h},{d}]{{3,2,1,0" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
+
+    slab = _self_slab(b, h * d)
+    assert re.search(slab, text), "no self slab of the expected shape"
+    written_back = _slabs_written_back(text, slab)
+    if program == "engine_step":
+        assert written_back <= 4, written_back
+        assert _slabs_made_anew(text, slab) == written_back
+    else:
+        assert written_back == 0, written_back

@@ -346,7 +346,7 @@ def test_attention_auto_dispatch_by_seq_len(monkeypatch):
     assert calls, "LM flash not traced at/above the crossover"
 
 
-# -- decode attention over flat slabs (ops/decode_attention.py) --------------
+# -- decode attention over cached slabs (ops/decode_attention.py) ------------
 
 
 def _dk_inputs(b=3, L=96, h=4, d=16, seed=0, dtype=jnp.float32):
@@ -401,6 +401,106 @@ def test_flat_decode_attention_matches_reference(with_bias, with_mask, L, dtype)
                                atol=_DK_TOL[dtype], rtol=_DK_TOL[dtype])
 
 
+@pytest.mark.parametrize("cur", [0, 41, 95])
+@pytest.mark.parametrize("cache", ["float32", "bfloat16", "int8"])
+def test_flat_append_decode_attention_equals_flat_over_the_appended_slab(
+        cache, cur):
+    """The read of a slab a step is about to append to (position-major
+    ``[L, b, h*d]`` as it was, the step's row apart) ==
+    ``flat_decode_attention`` over the ``[b, L, h*d]`` slab with the row in
+    place, at the first, a middle and the last position, with the causal bias
+    a decode step carries and a key mask; whatever the slab held at ``cur``
+    before is never used.  int8: per-position scales, read as after the
+    step."""
+    from tpu_air.ops.decode_attention import (
+        flat_append_decode_attention, flat_decode_attention,
+    )
+
+    b, L, h, d = 3, 96, 4, 16
+    dtype = jnp.float32 if cache == "int8" else jnp.dtype(cache)
+    rng = np.random.default_rng(7)
+    q, k, v, bias, mask = _dk_inputs(dtype=dtype)
+    bias = bias + jnp.where(jnp.arange(L) <= cur, 0.0, -1e9)[None]
+    ks = vs = None
+    if cache == "int8":
+        k = jnp.asarray(rng.integers(-127, 128, (b, L, h * d)), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, (b, L, h * d)), jnp.int8)
+        ks = jnp.asarray(rng.uniform(0.001, 0.02, (b, L, h)), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.001, 0.02, (b, L, h)), jnp.float32)
+    want = flat_decode_attention(q, k, v, bias, mask, ks, vs, h, dtype)
+    # position-major, and junk where the step's row will go
+    pm = lambda x: None if x is None else jnp.swapaxes(x, 0, 1)  # noqa: E731
+    junk = jnp.full((1, b, h * d), 99, k.dtype)
+    got = flat_append_decode_attention(
+        q, jax.lax.dynamic_update_slice(pm(k), junk, (cur, 0, 0)),
+        jax.lax.dynamic_update_slice(pm(v), junk, (cur, 0, 0)),
+        pm(k)[cur:cur + 1], pm(v)[cur:cur + 1], jnp.asarray(cur), bias, mask,
+        pm(ks), pm(vs), h, dtype)
+    assert got.shape == q.shape and got.dtype == dtype
+    tol = _DK_TOL["bfloat16" if cache == "bfloat16" else "float32"]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+
+
+def _minor(x, h):
+    """A flat ``[b, L, h*d]`` slab as the length-minor ``[b, h, d, L]`` one
+    (no lane padding: the op takes any ``L``)."""
+    return jnp.transpose(_heads(x, h), (0, 2, 3, 1))
+
+
+@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("with_mask", [False, True], ids=["nomask", "mask"])
+@pytest.mark.parametrize("L", [128, 129])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_length_minor_decode_attention_matches_reference(with_bias, with_mask,
+                                                         L, dtype):
+    """The read of the T5 cross cache == the dense reference over the same
+    values, same operand contract as the flat read, at a length that is whole
+    lanes and one that is not."""
+    from tpu_air.ops.decode_attention import (
+        decode_attention_reference, length_minor_decode_attention,
+    )
+
+    h = 4
+    q, k, v, bias, mask = _dk_inputs(L=L, h=h, dtype=jnp.dtype(dtype))
+    bias = bias if with_bias else None
+    mask = mask if with_mask else None
+    got = length_minor_decode_attention(q, _minor(k, h), _minor(v, h), bias,
+                                        mask, None, None, jnp.dtype(dtype))
+    want = decode_attention_reference(q, _heads(k, h), _heads(v, h),
+                                      bias=bias, kv_mask=mask)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=_DK_TOL[dtype], rtol=_DK_TOL[dtype])
+
+
+@pytest.mark.parametrize("L", [96, 128, 300])
+def test_length_minor_pads_to_whole_lanes_behind_the_mask(L):
+    """``length_minor`` stores ``[b, L, h, d]`` as ``[b, h, d, Lp]``, ``Lp``
+    the next multiple of 128, zeros past ``L``; with the key mask padded by
+    ``pad_keys`` the read over the padded slab is the read over the real
+    positions."""
+    from tpu_air.ops.decode_attention import (
+        length_minor, length_minor_decode_attention, pad_keys,
+    )
+
+    h = 4
+    q, k, v, bias, mask = _dk_inputs(L=L, h=h)
+    ks, vs = length_minor(_heads(k, h)), length_minor(_heads(v, h))
+    Lp = -(-L // 128) * 128
+    assert ks.shape == (3, h, 16, Lp)
+    np.testing.assert_array_equal(np.asarray(ks[..., :L]), np.asarray(_minor(k, h)))
+    assert not np.asarray(ks[..., L:]).any()
+    got = length_minor_decode_attention(
+        q, ks, vs, pad_keys(bias, Lp), pad_keys(mask, Lp), None, None,
+        jnp.float32)
+    want = length_minor_decode_attention(
+        q, _minor(k, h), _minor(v, h), bias, mask, None, None, jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-6, rtol=2e-6)
+
+
 @pytest.mark.parametrize("kind", ["pos", "chan"])
 def test_flat_decode_attention_int8_scale_folding(kind):
     """int8 slabs never materialize a dequantized copy: scales fold into
@@ -428,19 +528,53 @@ def test_flat_decode_attention_int8_scale_folding(kind):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_fully_masked_row_is_finite_mean_of_v():
-    """The masking contract of the module docstring: a row with no valid key
-    gets a uniform softmax, so its context is the plain mean of V over all
-    positions: finite, not zero.  Rows with valid keys are untouched by it."""
+def test_length_minor_decode_attention_int8_scale_folding():
+    """int8 cross slabs, per-channel scales ``[b, h, d, 1]``: folded into q
+    and the context, equal to the reference over the dequantised values."""
     from tpu_air.ops.decode_attention import (
-        decode_attention_reference, flat_decode_attention,
+        decode_attention_reference, length_minor_decode_attention,
     )
+
+    b, L, h, d = 3, 96, 4, 16
+    rng = np.random.default_rng(1)
+    q, _, _, bias, mask = _dk_inputs()
+    k8 = jnp.asarray(rng.integers(-127, 128, (b, L, h * d)), jnp.int8)
+    v8 = jnp.asarray(rng.integers(-127, 128, (b, L, h * d)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.001, 0.02, (b, h, d, 1)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.001, 0.02, (b, h, d, 1)), jnp.float32)
+    got = length_minor_decode_attention(q, _minor(k8, h), _minor(v8, h), bias,
+                                        mask, ks, vs, jnp.float32)
+    want = decode_attention_reference(
+        q, _heads(k8, h), _heads(v8, h), bias=bias, kv_mask=mask,
+        k_scale=ks.reshape(b, 1, h, d), v_scale=vs.reshape(b, 1, h, d))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def _read(layout, q, k, v, mask, h):
+    from tpu_air.ops.decode_attention import (
+        flat_decode_attention, length_minor_decode_attention,
+    )
+
+    if layout == "flat":
+        return flat_decode_attention(q, k, v, None, mask, None, None, h,
+                                     jnp.float32)
+    return length_minor_decode_attention(q, _minor(k, h), _minor(v, h), None,
+                                         mask, None, None, jnp.float32)
+
+
+@pytest.mark.parametrize("layout", ["flat", "length_minor"])
+def test_fully_masked_row_is_finite_mean_of_v(layout):
+    """The masking contract of the module docstring, for both reads: a row
+    with no valid key gets a uniform softmax, so its context is the plain
+    mean of V over all positions: finite, not zero.  Rows with valid keys are
+    untouched by it."""
+    from tpu_air.ops.decode_attention import decode_attention_reference
 
     h = 4
     q, k, v, _, mask = _dk_inputs()
     mask = mask.at[1].set(0.0)
-    got = np.asarray(flat_decode_attention(q, k, v, None, mask, None, None, h,
-                                           jnp.float32))
+    got = np.asarray(_read(layout, q, k, v, mask, h))
     assert np.isfinite(got).all()
     np.testing.assert_allclose(
         got[1, 0].reshape(-1), np.asarray(v[1]).mean(axis=0),

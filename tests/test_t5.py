@@ -2,6 +2,8 @@
 the torch reference implementation (transformers, random tiny weights — no
 network)."""
 
+import collections
+
 import numpy as np
 import pytest
 
@@ -278,9 +280,9 @@ def test_int8_cross_kv_cache_numerics(tiny):
     assert "cached_key_scale" in cache_b["decoder"]["layer_0"]["cross_attn"]
 
     # self-attn slabs are int8 too (per-position scales)
-    sk = cache_b["decoder"]["layer_0"]["self_attn"]["cached_key"]
+    sk = cache_b["decoder"]["self_keys"]
     assert sk.dtype == jnp.int8, sk.dtype
-    assert "cached_key_scale" in cache_b["decoder"]["layer_0"]["self_attn"]
+    assert "self_key_scales" in cache_b["decoder"]
 
     # run THREE decode steps so the quantized self-cache is actually read
     tok = jnp.full((2, 1), cfg.decoder_start_token_id, jnp.int32)
@@ -393,6 +395,104 @@ def test_cached_step_logits_match_uncached_forward(tiny, int8):
     np.testing.assert_allclose(got, want, atol=atol, rtol=0)
 
 
+def _uncached_greedy(model, params, ids, mask, steps):
+    """Greedy tokens from full (uncached) forwards, a fixed-length one padded
+    to ``steps + 1`` so that it compiles once; ``[b, steps]``."""
+    cfg = model.config
+    dec = jnp.full((ids.shape[0], steps + 1), cfg.pad_token_id, jnp.int32)
+    dec = dec.at[:, 0].set(cfg.decoder_start_token_id)
+    forward = jax.jit(lambda dec: model.apply({"params": params}, ids, mask, dec))
+    for t in range(steps):      # causal: position t sees only dec[:, :t + 1]
+        nxt = jnp.argmax(forward(dec)[:, t], axis=-1).astype(jnp.int32)
+        dec = dec.at[:, t + 1].set(nxt)
+    return np.asarray(dec[:, 1:])
+
+
+@pytest.mark.parametrize("kind", ["while", "scan", "engine"])
+def test_cached_decode_gives_the_uncached_forwards_greedy_tokens(tiny, kind):
+    """float32, ragged prompts: each of the three programs that carry the
+    decode cache (``generate``'s while-loop and scan over one stacked array a
+    kind of self slab, the engine's prefill + donated steps over a tuple a
+    layer) emits the argmax chain of the full forward, up to a row's EOS."""
+    from tpu_air.models.t5.generate import (
+        make_generate_fn, make_t5_decode_step_fn, make_t5_prefill_fn)
+
+    cfg, model, params = tiny
+    rng = np.random.default_rng(5)
+    steps = 7
+    ids = jnp.asarray(rng.integers(2, cfg.vocab_size, (3, 10)), jnp.int32)
+    mask = jnp.asarray([[1] * 10, [1] * 6 + [0] * 4, [1] * 3 + [0] * 7], jnp.int32)
+    ids = ids * mask
+    want = _uncached_greedy(model, params, ids, mask, steps)
+    if kind == "engine":
+        tok, cache, enc = make_t5_prefill_fn(model, steps + 1)(params, ids, mask)
+        step = make_t5_decode_step_fn(model)
+        got = [np.asarray(tok)]
+        for _ in range(steps - 1):
+            cache, tok = step(params, cache, tok, enc, mask)
+            got.append(np.asarray(tok))
+        got = np.stack(got, axis=1)
+    else:
+        got, _ = make_generate_fn(model, steps, early_stop=(kind == "while"))(
+            params, ids, mask, jax.random.PRNGKey(0))
+        got = np.asarray(got)
+    for row_got, row_want in zip(got, want):
+        eos = np.flatnonzero(row_want == cfg.eos_token_id)
+        n = eos[0] + 1 if eos.size else steps
+        np.testing.assert_array_equal(row_got[:n], row_want[:n])
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])
+@pytest.mark.parametrize("form", ["stacked", "tuple"])
+def test_model_returns_the_self_slabs_in_the_form_it_was_given(tiny, form, int8):
+    """The decoder takes its self-attention slabs as one ``[layers, L, b,
+    h*d]`` array a kind (what ``init_cache`` builds, for a loop's carry) or as
+    a tuple of the layers' own arrays (``per_layer_slabs``, for a one-step
+    program's parameters) and returns the form it was given, with the same
+    logits and the same rows appended either way."""
+    import dataclasses
+
+    from tpu_air.models.t5.generate import init_cache, per_layer_slabs
+
+    cfg, _, params = tiny
+    model = T5ForConditionalGeneration(
+        dataclasses.replace(cfg, decode_cache_int8=int8))
+    ids = jnp.asarray(np.random.default_rng(3).integers(2, cfg.vocab_size, (2, 9)),
+                      jnp.int32)
+    mask = jnp.ones_like(ids)
+    enc = model.apply({"params": params}, ids, mask, method=model.encode)
+    stacked = init_cache(model, params, 2, 5, enc, mask)
+    kinds = [k for k in stacked["decoder"] if k.startswith("self_")]
+    assert len(kinds) == (4 if int8 else 2)
+
+    def run(cache):
+        tok = jnp.full((2, 1), cfg.decoder_start_token_id, jnp.int32)
+        outs = []
+        for _ in range(3):
+            logits, upd = model.apply(
+                {"params": params, "cache": cache}, tok, enc, mask,
+                decode=True, mutable=["cache"], method=model.decode)
+            cache = upd["cache"]
+            tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
+            outs.append(np.asarray(logits))
+        return outs, cache["decoder"]
+
+    want_logits, want = run(stacked)
+    if form == "stacked":
+        for k in kinds:
+            assert isinstance(want[k], jax.Array), (k, type(want[k]))
+            assert want[k].shape[:3] == (cfg.num_decoder_layers, 5, 2)
+            assert np.asarray(want[k][:, :3]).any()       # rows 0..2 written
+            assert not np.asarray(want[k][:, 3:]).any()   # and no other
+        return
+    got_logits, got = run(per_layer_slabs(stacked))
+    for a, b in zip(got_logits, want_logits):
+        np.testing.assert_array_equal(a, b)
+    for k in kinds:
+        assert isinstance(got[k], tuple) and len(got[k]) == cfg.num_decoder_layers
+        np.testing.assert_array_equal(np.stack(got[k]), np.asarray(want[k]))
+
+
 def _sub_jaxprs(eqn):
     for v in eqn.params.values():
         for x in v if isinstance(v, (list, tuple)) else (v,):
@@ -417,9 +517,25 @@ def _slab_views(jaxpr, b, lengths, h, d):
             if any(tuple(v.aval.shape) in views for v in eqn.outvars)]
 
 
+def _slab_moves(jaxpr, slab):
+    """Equations that reshape, transpose or copy an array of shape ``slab``."""
+    return [eqn for eqn in _all_eqns(jaxpr)
+            if eqn.primitive.name in ("transpose", "reshape", "copy", "copy_p")
+            and any(tuple(v.aval.shape) == slab for v in eqn.invars)]
+
+
+def _slab_reads(jaxpr, slab):
+    """The contractions that take an array of shape ``slab`` as an operand."""
+    return [eqn for eqn in _all_eqns(jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and any(tuple(v.aval.shape) == slab for v in eqn.invars)]
+
+
 def _decode_bodies(kind, int8):
     """The jaxprs of the cached decode step as ``kind`` builds it: the body
-    of ``generate``'s loop (``while`` / ``scan``) or the engine's whole step."""
+    of ``generate``'s loop (``while`` / ``scan``) or the engine's whole step;
+    with them ``(b, (encoder length, decoder cache length), h, d)`` and the
+    cache tree's shapes as the engine's prefill builds it."""
     import dataclasses
 
     from tpu_air.models.t5.generate import (
@@ -432,45 +548,97 @@ def _decode_bodies(kind, int8):
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), ids, ids, ids[:, :2]))["params"]
     dims = (b, (enc_len, new + 1), cfg.num_heads, cfg.d_kv)
+    tok, cache, enc = jax.eval_shape(
+        make_t5_prefill_fn(model, new + 1), params, ids, ids)
     if kind == "step":
-        tok, cache, enc = jax.eval_shape(
-            make_t5_prefill_fn(model, new + 1), params, ids, ids)
         jaxpr = jax.make_jaxpr(make_t5_decode_step_fn(model))(
             params, cache, tok, enc, ids)
-        return [jaxpr.jaxpr], dims
+        return [jaxpr.jaxpr], dims, cache
     fn = make_generate_fn(model, new, early_stop=(kind == "while"))
     jaxpr = jax.make_jaxpr(fn)(params, ids, ids, jax.random.PRNGKey(0))
     key = {"while": "body_jaxpr", "scan": "jaxpr"}[kind]
     bodies = [e.params[key].jaxpr for e in _all_eqns(jaxpr.jaxpr)
               if e.primitive.name == kind]
-    return bodies, dims
+    return bodies, dims, cache
 
 
+@pytest.mark.parametrize("slabs", ["self", "cross"])
 @pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])
 @pytest.mark.parametrize("kind", ["while", "scan", "step"])
-def test_cached_step_never_views_a_slab_in_4d(kind, int8):
-    """No equation of the decode body reshapes or transposes
+def test_cached_step_never_views_a_slab_in_4d(kind, int8, slabs):
+    """``self``: no equation of the decode body reshapes or transposes
     a flat ``[b, L, h*d]`` cache slab to a 4-D ``[b, L, h, d]`` array: the
     TPU tiles that view's minor pair (12, 64) to (16, 128), 2.67 x the bytes,
     and whether XLA keeps it padded depends on the loop around the step
-    (PERF.md, PR 25).  Held for ``generate``'s while-loop and scan and for
-    the engine's donated-cache step, full-width and int8 caches."""
-    bodies, dims = _decode_bodies(kind, int8)
+    (PERF.md, PR 25); the slabs are position-major, under a loop those of all
+    layers are one ``[layers, L, b, h*d]`` array a kind and the step's rows go
+    into it in one update a kind, after every layer has read its slice, and
+    the engine's one-step program has one array a layer instead (PERF.md, PR
+    33).  ``cross``: the cross-attention slabs are stored
+    length-minor, ``[b, h, d, Lp]`` with ``Lp`` whole 128-lane tiles (scales
+    ``[b, h, d, 1]``), and the body only contracts them: two ``dot_general``
+    a layer read each as stored, nothing reshapes, transposes or copies one.
+    Held for ``generate``'s while-loop and scan and for the engine's
+    donated-cache step, full-width and int8 caches."""
+    bodies, dims, cache = _decode_bodies(kind, int8)
     assert bodies, f"no {kind} in the program"
-    bad = [e for body in bodies for e in _slab_views(body, *dims)]
-    assert not bad, "\n".join(str(e) for e in bad[:4])
+    b, (_, dec_len), h, d = dims
+    decoder = cache["decoder"]
+    n_layers = sum(name.startswith("layer_") for name in decoder)
+    if slabs == "self":
+        bad = [e for body in bodies for e in _slab_views(body, *dims)]
+        assert not bad, "\n".join(str(e) for e in bad[:4])
+        own = (dec_len, b, h * d)                # position-major
+        appends = [collections.Counter(
+            tuple(e.invars[0].aval.shape) for e in _all_eqns(body)
+            if e.primitive.name == "dynamic_update_slice") for body in bodies]
+        if kind == "step":
+            # the engine's step takes each kind as a tuple of the layers' own
+            # arrays (``per_layer_slabs``): one row block written to each
+            for name in ("self_keys", "self_values"):
+                assert [x.shape for x in decoder[name]] == [own] * n_layers
+            assert all(a[own] == 2 * n_layers for a in appends), appends
+            return
+        # under a loop all layers' slabs are one array a kind, appended to
+        # once a step; no layer's own slice of it is written to
+        every = (n_layers,) + own
+        for a in appends:
+            assert a[every] == 2 and a[own] == 0, a
+        return
+    slab = (b, h, d, 128)
+    for name, layer in decoder.items():
+        if not name.startswith("layer_"):
+            continue
+        cross = layer["cross_attn"]
+        assert cross["cached_key"].shape == cross["cached_value"].shape == slab
+        if int8:
+            assert cross["cached_key"].dtype == jnp.int8
+            assert cross["cached_key_scale"].shape == (b, h, d, 1)
+    for body in bodies:
+        moved = _slab_moves(body, slab)
+        assert not moved, "\n".join(str(e) for e in moved[:4])
+        assert len(_slab_reads(body, slab)) == 2 * n_layers
 
 
-@pytest.mark.parametrize("how", ["reshape", "reference"])
+@pytest.mark.parametrize("how", ["reshape", "reference", "transpose"])
 def test_slab_view_check_sees_a_4d_view(how):
-    """The check above is not vacuous: it finds the view in a program that
-    reshapes a flat ``[b, L, h*d]`` slab into heads, and in the dense
-    reference over such a view."""
+    """The checks above are not vacuous: the first finds the view in a
+    program that reshapes a flat ``[b, L, h*d]`` slab into heads, and in the
+    dense reference over such a view; the second finds a length-minor slab
+    transposed back before it is contracted."""
     from tpu_air.ops.decode_attention import decode_attention_reference
 
     b, L, h, d = 3, 10, 4, 16
     slab = jnp.zeros((b, L, h * d), jnp.float32)
     q = jnp.zeros((b, 1, h, d), jnp.float32)
+    if how == "transpose":
+        minor = jnp.zeros((b, h, d, 128), jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda q, x: decode_attention_reference(
+            q, jnp.transpose(x, (0, 3, 1, 2)), jnp.transpose(x, (0, 3, 1, 2))
+        ))(q, minor)
+        assert _slab_moves(jaxpr.jaxpr, minor.shape)
+        assert not _slab_reads(jaxpr.jaxpr, minor.shape)
+        return
     if how == "reshape":
         jaxpr = jax.make_jaxpr(lambda x: x.reshape(b, L, h, d).sum())(slab)
     else:

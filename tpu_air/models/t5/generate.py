@@ -71,6 +71,23 @@ def init_cache(model, params, batch_size: int, max_decode_len: int,
     return cache
 
 
+def per_layer_slabs(cache):
+    """The decode cache with each kind of self-attention slab, one
+    ``[layers, L, b, ...]`` array as ``init_cache`` builds it, split into a
+    tuple of the layers' own arrays; the model takes either and returns what
+    it was given.  For a program that is ONE step and no loop: there the
+    cache arrives as parameters, and the v5e's compiler copies each layer's
+    slice of a parameter out before the step reads it (0.8 GB a step at the
+    ``t5large-serve`` shape, 7.8 -> 9.4 ms; PERF.md, PR 34).  Inside a
+    loop one array is what keeps the slabs out of the compiler's fast-memory
+    round trip (``Decoder``)."""
+    decoder = dict(cache["decoder"])
+    for name, slab in decoder.items():
+        if name.startswith("self_"):
+            decoder[name] = tuple(slab)
+    return {**cache, "decoder": decoder}
+
+
 from tpu_air.models.sampling import sample_token as _sample_token  # noqa: E402
 
 
@@ -191,8 +208,8 @@ def make_t5_prefill_fn(model: T5ForConditionalGeneration,
         enc = model.apply(
             {"params": params}, input_ids, attention_mask, method=model.encode
         )
-        cache = init_cache(model, params, batch, max_decode_len, enc,
-                           attention_mask)
+        cache = per_layer_slabs(init_cache(
+            model, params, batch, max_decode_len, enc, attention_mask))
         tok0 = jnp.full((batch, 1), cfg.decoder_start_token_id, jnp.int32)
         logits, vars_ = model.apply(
             {"params": params, "cache": cache}, tok0, enc, attention_mask,

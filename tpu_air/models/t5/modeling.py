@@ -26,7 +26,12 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from tpu_air.ops.decode_attention import flat_decode_attention
+from tpu_air.ops.decode_attention import (
+    flat_append_decode_attention,
+    length_minor,
+    length_minor_decode_attention,
+    pad_keys,
+)
 
 from .config import T5Config
 
@@ -123,10 +128,16 @@ class Attention(nn.Module):
         position_bias: Optional[Array],  # [1, heads, qlen, klen]
         kv_mask: Optional[Array] = None,  # [batch, klen] 1=attend (structured)
         causal: bool = False,             # structured causal flag
-        decode: bool = False,
+        decode: bool = False,             # no flash: a decoding pass
+        appending: Optional[tuple] = None,  # cached self-attention step
         cross_decode: bool = False,
         deterministic: bool = True,
-    ) -> Array:
+    ):
+        """``appending`` ``(k_slab, v_slab, k_scales, v_scales, index)``:
+        this layer's self-attention slabs ``[L, b, h*d]`` as they were
+        before the step (scales ``[L, b, h]`` or None) and the position the
+        step's rows go to.  The result is then ``(out, rows)``, ``rows`` the
+        step's ``(k, v, k_scales, v_scales)`` as stored, ``[new, b, ...]``."""
         cfg = self.config
         dtype = _dtype(cfg)
         init = nn.initializers.normal(stddev=cfg.d_model**-0.5)
@@ -158,18 +169,22 @@ class Attention(nn.Module):
         def _dequant(q8, scale):
             return (q8.astype(jnp.float32) * scale).astype(dtype)
 
-        # Decode caches are stored FLAT: [b, L, h*d], scales [b, 1, h*d]
-        # (cross, per-channel) / [b, L, h] (self, per-position).  A 4-D
-        # [b, L, h, d] slab is the decode bottleneck wherever XLA keeps it
-        # row-major: TPU tiles the last two dims (12, 64) up to (16, 128)
-        # — 2.67x physical HBM bytes — and streams those padded bytes at
-        # ~92% of the roofline, i.e. the chip is fast, the LAYOUT is the
-        # waste.  h*d = 768 is six clean (8, 128) tiles, zero padding.
-        # The cached single-token step attends over the slab as stored
-        # (``flat_decode_attention``) and never takes a [b, L, h, d] view
-        # of it (tests/test_t5.py::test_cached_step_never_views_a_slab_in_4d).
+        # Decode caches have two layouts, by how they are written
+        # (ops/decode_attention.py).  SELF slabs grow a position a step and
+        # are FLAT and position-major, [L, b, h*d], scales [L, b, h] (per
+        # position); the ``Decoder`` holds them and appends to them, this
+        # module reads them.  CROSS slabs are written once, at cache init,
+        # here, and are LENGTH-MINOR, [b, h, d, Lp], scales [b, h, d, 1]
+        # (per channel): (d_kv, Lp) are whole tiles, Lp the encoder length
+        # padded up to 128 lanes behind a zero key mask.  What neither is,
+        # ever, is a row-major [b, L, h, d]: TPU tiles its last two dims
+        # (12, 64) up to (16, 128), 2.67x the physical HBM bytes.  The
+        # cached single-token step attends over each slab as stored and
+        # makes no copy of one in another order
+        # (tests/test_t5.py::test_cached_step_never_views_a_slab_in_4d).
         dk_scales = (None, None)
-        cached_step = False    # k/v hold FLAT cache slabs, not [b,k,h,d]
+        cached_step = False    # k/v hold cache slabs, not [b, k, h, d]
+        cross_cached = False   # ... and they are the length-minor ones
 
         if cross_decode and self.has_variable("cache", "cached_key"):
             # Cross-attention during cached decode: K/V are an invariant of
@@ -177,97 +192,94 @@ class Attention(nn.Module):
             # the two 512-token projections per decode step was the dominant
             # cost of W3 generation (~12 layers x 2 projections x the full
             # encoder length, per emitted token).
-            k = self.get_variable("cache", "cached_key")       # [b, L, h*d]
+            k = self.get_variable("cache", "cached_key")     # [b, h, d, Lp]
             v = self.get_variable("cache", "cached_value")
-            cached_step = True
+            cached_step = cross_cached = True
             if cache_int8:
                 dk_scales = (
                     self.get_variable("cache", "cached_key_scale"),
                     self.get_variable("cache", "cached_value_scale"),
                 )
+            # the lanes ``length_minor`` added hold no key: masked for
+            # every row, whatever the caller's mask says of the real ones
+            klp = k.shape[-1]
+            if klp != kv_hidden.shape[1]:
+                if kv_mask is None:
+                    kv_mask = jnp.ones(kv_hidden.shape[:2], jnp.float32)
+                kv_mask = pad_keys(kv_mask, klp)
+                if position_bias is not None:
+                    position_bias = pad_keys(position_bias, klp)
+                if mask is not None:
+                    mask = pad_keys(mask, klp)
         else:
             k = dense("k")(kv_hidden)    # [b, k, h, d]
             v = dense("v")(kv_hidden)
             if cross_decode:
-                bsz, klv = k.shape[0], k.shape[1]
+                # one transposed write a cache; XLA folds it into the
+                # projection's output layout
                 if cache_int8:
                     kq, ks = _quant(k)
                     vq, vs = _quant(v)
                     self.variable("cache", "cached_key",
-                                  lambda: kq.reshape(bsz, klv, -1))
+                                  lambda: length_minor(kq))
                     self.variable("cache", "cached_key_scale",
-                                  lambda: ks.reshape(bsz, 1, -1))
+                                  lambda: ks.transpose(0, 2, 3, 1))
                     self.variable("cache", "cached_value",
-                                  lambda: vq.reshape(bsz, klv, -1))
+                                  lambda: length_minor(vq))
                     self.variable("cache", "cached_value_scale",
-                                  lambda: vs.reshape(bsz, 1, -1))
+                                  lambda: vs.transpose(0, 2, 3, 1))
                     # the init pass itself attends with the dequantized
                     # values so its output matches later steps
                     k = _dequant(kq, ks)
                     v = _dequant(vq, vs)
                 else:
                     self.variable("cache", "cached_key",
-                                  lambda: k.reshape(bsz, klv, -1))
+                                  lambda: length_minor(k))
                     self.variable("cache", "cached_value",
-                                  lambda: v.reshape(bsz, klv, -1))
+                                  lambda: length_minor(v))
 
-        if decode:
-            # Pre-allocated flat self-attention slabs; cache vars are
-            # created ahead of time by init_cache (eval_shape) so is_init
-            # only occurs there.  With decode_cache_int8 the slabs are
-            # int8 with a per-(batch, position, head) scale over the
-            # channel dim, quantized incrementally as each step's K/V
-            # land — the self-attention half of the decode-bandwidth
-            # story (cross is quantized whole at cache init above).
-            is_init = not self.has_variable("cache", "cached_key")
-            slab_dtype = jnp.int8 if cache_int8 else dtype
-            bsz, klv = k.shape[0], k.shape[1]
+        appended = None
+        if appending is not None:
+            # Cached self-attention: the decoder hands in this layer's
+            # slices of its slabs as they were before the step, and takes
+            # back the step's rows as they are to be stored (``Decoder``
+            # appends them, for all layers at once).  With
+            # decode_cache_int8 the rows are int8 with a per-(batch,
+            # position, head) scale over the channel dim, quantized as each
+            # step's K/V land — the self-attention half of the
+            # decode-bandwidth story (cross is quantized whole at cache
+            # init above).
+            k_slab, v_slab, ks_slab, vs_slab, cur = appending
+            bsz, new = k.shape[0], k.shape[1]
             hd = cfg.num_heads * cfg.d_kv
-            ck = self.variable("cache", "cached_key", jnp.zeros,
-                               (bsz, klv, hd), slab_dtype)
-            cv = self.variable("cache", "cached_value", jnp.zeros,
-                               (bsz, klv, hd), slab_dtype)
+            ks_rows = vs_rows = None
+
+            def rows_first(x):        # [b, new, ...] -> [new, b, ...]
+                return jnp.swapaxes(x, 0, 1)
+
             if cache_int8:
-                cks = self.variable("cache", "cached_key_scale", jnp.zeros,
-                                    (bsz, klv, cfg.num_heads), jnp.float32)
-                cvs = self.variable("cache", "cached_value_scale", jnp.zeros,
-                                    (bsz, klv, cfg.num_heads), jnp.float32)
-            idx = self.variable(
-                "cache", "cache_index", lambda: jnp.array(0, dtype=jnp.int32)
-            )
-            if not is_init:
-                cur = idx.value
-                if cache_int8:
-                    def _quant_pos(x):
-                        xf = x.astype(jnp.float32)
-                        amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
-                        s = jnp.maximum(amax, 1e-8) / 127.0
-                        x8 = jnp.clip(jnp.round(xf / s), -127, 127)
-                        return x8.astype(jnp.int8), s
+                def _quant_pos(x):
+                    xf = x.astype(jnp.float32)
+                    amax = jnp.max(jnp.abs(xf), axis=-1, keepdims=True)
+                    s = jnp.maximum(amax, 1e-8) / 127.0
+                    x8 = jnp.clip(jnp.round(xf / s), -127, 127)
+                    return x8.astype(jnp.int8), rows_first(s[..., 0])
 
-                    k8, ks_ = _quant_pos(k)
-                    v8, vs_ = _quant_pos(v)
-                    ck.value = jax.lax.dynamic_update_slice(
-                        ck.value, k8.reshape(bsz, klv, hd), (0, cur, 0))
-                    cks.value = jax.lax.dynamic_update_slice(
-                        cks.value, ks_.reshape(bsz, klv, -1), (0, cur, 0))
-                    cv.value = jax.lax.dynamic_update_slice(
-                        cv.value, v8.reshape(bsz, klv, hd), (0, cur, 0))
-                    cvs.value = jax.lax.dynamic_update_slice(
-                        cvs.value, vs_.reshape(bsz, klv, -1), (0, cur, 0))
-                    idx.value = cur + q.shape[1]
-                    k, v = ck.value, cv.value
-                    dk_scales = (cks.value, cvs.value)
-                else:
-                    ck.value = jax.lax.dynamic_update_slice(
-                        ck.value, k.reshape(bsz, klv, hd), (0, cur, 0))
-                    cv.value = jax.lax.dynamic_update_slice(
-                        cv.value, v.reshape(bsz, klv, hd), (0, cur, 0))
-                    idx.value = cur + q.shape[1]
-                    k, v = ck.value, cv.value
-                cached_step = True
+                k, ks_rows = _quant_pos(k)
+                v, vs_rows = _quant_pos(v)
+                # the scales are small: the step reads them as appended to
+                dk_scales = (
+                    jax.lax.dynamic_update_slice(ks_slab, ks_rows, (cur, 0, 0)),
+                    jax.lax.dynamic_update_slice(vs_slab, vs_rows, (cur, 0, 0)),
+                )
+            appended = (rows_first(k.reshape(bsz, new, hd)),
+                        rows_first(v.reshape(bsz, new, hd)), ks_rows, vs_rows)
+            k, v = k_slab, v_slab
+            cached_step = True
 
-        qlen, klen = q.shape[1], k.shape[1]
+        # cached slabs: cross [b, h, d, Lp], self [L, b, h*d]
+        qlen = q.shape[1]
+        klen = k.shape[-1 if cross_cached else 0 if cached_step else 1]
         # Pallas blockwise path: eligible when callers passed the structured
         # mask form (causal flag + key-padding row — never a dense (q, k)
         # tensor), we're not in cached decode (qlen == 1 per-token launches
@@ -278,6 +290,7 @@ class Attention(nn.Module):
         # einsum below the measured crossover, flash at/above it.
         eligible = (
             not decode
+            and not cross_cached
             and qlen > 1
             and mask is None
             and (deterministic or cfg.dropout_rate == 0)
@@ -292,15 +305,17 @@ class Attention(nn.Module):
         else:
             use_flash = eligible and cfg.attention_impl == "flash"
         if cached_step:
-            # Single-token step over flat cache slabs.  Structured-mask
+            # Single-token step over cache slabs.  Structured-mask
             # contract: mask here is batch-shared (decode causal row) or
             # None.
-            # The plain step attends over the flat slab for EVERY cache,
-            # full-width and int8, self and cross.  The dense path in the
-            # else branch needs a [b, L, h, d] view of the slab, whose
+            # The plain step attends over EVERY slab as stored, full-width
+            # and int8: self through ``flat_append_decode_attention``, cross
+            # through ``length_minor_decode_attention``.  The dense path in
+            # the else branch stays as the only path for what is not a
+            # plain single-token step (qlen > 1, live dropout, per-row
+            # mask): over a self slab it needs a [b, L, h, d] view, whose
             # layout XLA picks from the program around the step (PERF.md,
-            # PR 25); it stays as the only path for what is not a plain
-            # single-token step (qlen > 1, live dropout, per-row mask).
+            # PR 25); a cross slab it contracts as stored.
             fast_ok = (
                 qlen == 1
                 and (deterministic or cfg.dropout_rate == 0)
@@ -328,30 +343,42 @@ class Attention(nn.Module):
                     bias_arg = jnp.broadcast_to(
                         comb[0, :, 0, :], (cfg.num_heads, klen)
                     )
-                ctx = flat_decode_attention(
-                    q, k, v, bias_arg, kv_mask,
-                    dk_scales[0], dk_scales[1], cfg.num_heads, dtype,
-                )
+                if cross_cached:
+                    ctx = length_minor_decode_attention(
+                        q, k, v, bias_arg, kv_mask,
+                        dk_scales[0], dk_scales[1], dtype,
+                    )
+                else:
+                    ctx = flat_append_decode_attention(
+                        q, k, v, appended[0], appended[1], cur,
+                        bias_arg, kv_mask,
+                        dk_scales[0], dk_scales[1], cfg.num_heads, dtype,
+                    )
             else:
-                # fallback path: view the (dequantized) slab in 4-D and
-                # fall through to the dense einsum below
-                bsz = k.shape[0]
+                # fallback path: fall through to the dense einsum below,
+                # over the (dequantized) cross slab as stored, over a 4-D
+                # view of a self slab
+                bsz = q.shape[0]
                 hpd = (cfg.num_heads, cfg.d_kv)
                 ks_, vs_ = dk_scales
-                if ks_ is not None:
-                    if ks_.shape[1] == 1:          # cross: per-channel
-                        k = (k.astype(jnp.float32) * ks_).reshape(
-                            bsz, klen, *hpd).astype(dtype)
-                        v = (v.astype(jnp.float32) * vs_).reshape(
-                            bsz, klen, *hpd).astype(dtype)
-                    else:                           # self: per-position
-                        k = (k.reshape(bsz, klen, *hpd).astype(jnp.float32)
-                             * ks_[..., None]).astype(dtype)
-                        v = (v.reshape(bsz, klen, *hpd).astype(jnp.float32)
-                             * vs_[..., None]).astype(dtype)
+                if cross_cached:
+                    if ks_ is not None:             # per-channel
+                        k = _dequant(k, ks_)
+                        v = _dequant(v, vs_)
                 else:
-                    k = k.reshape(bsz, klen, *hpd)
-                    v = v.reshape(bsz, klen, *hpd)
+                    # a copy of this layer's slabs with the rows in place,
+                    # batch-major again
+                    def view(slab, rows, scale):
+                        x = jnp.swapaxes(jax.lax.dynamic_update_slice(
+                            slab, rows, (cur, 0, 0)), 0, 1)
+                        x = x.reshape(bsz, klen, *hpd)
+                        if scale is None:
+                            return x
+                        # per-position
+                        return _dequant(x, jnp.swapaxes(scale, 0, 1)[..., None])
+
+                    k = view(k, appended[0], ks_)
+                    v = view(v, appended[1], vs_)
                 ctx = None
         else:
             ctx = None
@@ -383,7 +410,9 @@ class Attention(nn.Module):
                     c = jnp.tril(jnp.ones((qlen, klen), jnp.float32))
                     mask = mask + ((1.0 - c) * NEG_INF)[None, None]
                 mask = mask.astype(dtype)
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+            # a cached cross slab is [b, h, d, k]; anything else [b, k, h, d]
+            kv_dims = "bhdk" if cross_cached else "bkhd"
+            scores = jnp.einsum(f"bqhd,{kv_dims}->bhqk", q, k)
             if position_bias is not None:
                 scores = scores + position_bias
             if mask is not None:
@@ -391,12 +420,13 @@ class Attention(nn.Module):
             probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
             if not deterministic and cfg.dropout_rate > 0:
                 probs = nn.Dropout(cfg.dropout_rate)(probs, deterministic=False)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
-        return nn.DenseGeneral(
+            ctx = jnp.einsum(f"bhqk,{kv_dims}->bqhd", probs, v)
+        out = nn.DenseGeneral(
             features=cfg.d_model, axis=(-2, -1), use_bias=False, dtype=dtype,
             kernel_init=nn.initializers.normal(stddev=(cfg.num_heads * cfg.d_kv) ** -0.5),
             name="o",
         )(ctx)
+        return out if appended is None else (out, appended)
 
 
 class FeedForward(nn.Module):
@@ -447,14 +477,20 @@ class DecoderLayer(nn.Module):
     def __call__(
         self, x, enc, position_bias, self_mask=None, self_kv_mask=None,
         self_causal=False, cross_kv_mask=None,
-        decode=False, deterministic=True,
+        decode=False, appending=None, deterministic=True,
     ):
+        """With ``appending`` (see ``Attention``) returns ``(x, rows)``."""
         cfg = self.config
         h = RMSNorm(cfg.layer_norm_epsilon, _dtype(cfg), name="ln_self")(x)
-        x = x + Attention(cfg, name="self_attn")(
+        attn = Attention(cfg, name="self_attn")(
             h, h, self_mask, position_bias, kv_mask=self_kv_mask,
-            causal=self_causal, decode=decode, deterministic=deterministic,
+            causal=self_causal, decode=decode, appending=appending,
+            deterministic=deterministic,
         )
+        rows = None
+        if appending is not None:
+            attn, rows = attn
+        x = x + attn
         h = RMSNorm(cfg.layer_norm_epsilon, _dtype(cfg), name="ln_cross")(x)
         x = x + Attention(cfg, name="cross_attn")(
             h, enc, None, None, kv_mask=cross_kv_mask, cross_decode=decode,
@@ -462,7 +498,7 @@ class DecoderLayer(nn.Module):
         )
         h = RMSNorm(cfg.layer_norm_epsilon, _dtype(cfg), name="ln_mlp")(x)
         x = x + FeedForward(cfg, name="mlp")(h, deterministic=deterministic)
-        return x
+        return x if appending is None else (x, rows)
 
 
 class Encoder(nn.Module):
@@ -502,18 +538,41 @@ class Decoder(nn.Module):
             pos = self.variable(
                 "cache", "decoder_pos", lambda: jnp.array(0, dtype=jnp.int32)
             )
-            # klen equals the cache length, which equals qlen at init time and
-            # is carried by the attention cache afterwards; the caller passes
-            # the same max_len via embeds at init, so derive klen from the
-            # layer-0 cache when present.
-            is_init = not self.has_variable("cache", "decoder_max_len")
-            if is_init:
-                klen = qlen
-            else:
-                klen = int(self.get_variable("cache", "decoder_max_len").shape[0])
-            self.variable(
-                "cache", "decoder_max_len", jnp.zeros, (klen,), jnp.int8
-            )
+            # The self-attention slabs of ALL layers live here, one array a
+            # kind and position-major: ``self_keys`` / ``self_values``
+            # [layers, L, b, h*d] (int8 caches: and their per-position
+            # scales [layers, L, b, h]).  A layer reads its slice as it was
+            # before the step and hands back the step's rows; they are
+            # appended below, in one update a kind.  One array, and not one
+            # a layer: an array the chip's fast memory can hold is moved
+            # there for the step, appended to there and written back to HBM
+            # whole, every step (PERF.md, PR 34); these cannot be, so a step
+            # reads each slab once and writes one row of it.  Created by
+            # init_cache (eval_shape) at klen = the cache's length, which is
+            # qlen there; afterwards the slabs carry it.
+            # A caller may hand the same slabs in as a tuple of the layers'
+            # own arrays (``generate.per_layer_slabs``), and gets them back
+            # so.  A program that is one step and no loop takes its cache as
+            # parameters, and the compiler copies every slice it reads of a
+            # parameter out before use; a layer's own [L, b, h*d] parameter
+            # it leaves in HBM, because a position is a whole block of it.
+            is_init = not self.has_variable("cache", "self_keys")
+            cache_int8 = getattr(cfg, "decode_cache_int8", False)
+            nl, bsz = cfg.num_decoder_layers, embeds.shape[0]
+            klen = (qlen if is_init else
+                    self.get_variable("cache", "self_keys")[0].shape[0])
+            slab = (nl, klen, bsz, cfg.num_heads * cfg.d_kv)
+            slabs = [
+                self.variable("cache", name, jnp.zeros, slab,
+                              jnp.int8 if cache_int8 else dtype)
+                for name in ("self_keys", "self_values")
+            ]
+            if cache_int8:
+                slabs += [
+                    self.variable("cache", name, jnp.zeros,
+                                  (nl, klen, bsz, cfg.num_heads), jnp.float32)
+                    for name in ("self_key_scales", "self_value_scales")
+                ]
             query_positions = pos.value + jnp.arange(qlen)
             key_positions = jnp.arange(klen)
             bias = RelativePositionBias(cfg, bidirectional=False, name="rel_bias")(
@@ -524,14 +583,32 @@ class Decoder(nn.Module):
             ).astype(jnp.float32)
             self_mask = ((1.0 - causal[None, None]) * NEG_INF).astype(dtype)
             x = embeds
-            for i in range(cfg.num_decoder_layers):
-                x = DecoderLayer(cfg, name=f"layer_{i}")(
-                    x, enc, bias, self_mask=self_mask, cross_kv_mask=enc_mask,
-                    decode=True, deterministic=deterministic,
-                )
+            rows = []
+            kwargs = dict(self_mask=self_mask, cross_kv_mask=enc_mask,
+                          decode=True, deterministic=deterministic)
+            for i in range(nl):
+                layer = DecoderLayer(cfg, name=f"layer_{i}")
+                if is_init:
+                    x = layer(x, enc, bias, **kwargs)
+                    continue
+                own = [s.value[i] for s in slabs]
+                own += [None] * (4 - len(own))       # no scales
+                x, new = layer(x, enc, bias, **kwargs,
+                               appending=(*own, pos.value))
+                rows.append(new)
             if not is_init:
                 # the cache-init pass (a real apply now, so cross K/V get
-                # computed) is not a decoding step — position stays 0
+                # computed) is not a decoding step — nothing is appended
+                # and the position stays 0
+                for s, new in zip(slabs, zip(*rows)):
+                    if isinstance(s.value, tuple):   # one array a layer
+                        s.value = tuple(
+                            jax.lax.dynamic_update_slice(
+                                own, row, (pos.value, 0, 0))
+                            for own, row in zip(s.value, new))
+                    else:
+                        s.value = jax.lax.dynamic_update_slice(
+                            s.value, jnp.stack(new), (0, pos.value, 0, 0))
                 pos.value = pos.value + qlen
             return RMSNorm(cfg.layer_norm_epsilon, dtype, name="final_ln")(x)
 

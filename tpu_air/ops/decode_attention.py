@@ -1,23 +1,48 @@
-"""Single-token decode attention over FLAT K/V cache slabs ``[b, L, h*d]``.
+"""Single-token decode attention over cached K/V slabs.  Which read a slab
+gets follows from how the slab is WRITTEN, and from nothing else:
 
-Every cached single-token step in the repo (T5 self and cross, the LM's
-offline and paged steps) attends through :func:`flat_decode_attention`:
-pure XLA, all heads in one batched MXU matmul per contraction via a
-block-diagonal selector, the slab streamed once in its unpadded storage
-layout and never viewed as ``[b, L, h, d]`` (whose last two dims TPU pads
-to (16, 128), 2.67x the bytes).  On the v5e that is 72.5 % of the HBM
-roofline under the while-loop ``predict`` runs, where the dense path over a
-4-D view read 37.0 % (ledger, PR 25, ``t5base-batchgen``: ``gen_seq_s``
-x 1.80).  PERF.md §6 has the candidates that lost.
+=====================  ==========================  ================================
+slab                   stored                      read by
+=====================  ==========================  ================================
+T5 cross-attention:    LENGTH-MINOR                :func:`length_minor_decode_attention`
+written once at cache  ``[b, h, d, Lp]``, scales
+init, only read after  ``[b, h, d, 1]``
+T5 self-attention:     FLAT, position-major        :func:`flat_append_decode_attention`
+grows a position a     ``[L, b, h*d]``, scales
+step                   ``[L, b, h]``
+the LM's K/V: pages    FLAT ``[b, L, h*d]``,       :func:`flat_decode_attention`
+gathered for the step  scales ``[b, L, h]`` or
+                       ``[b, 1, h*d]``
+=====================  ==========================  ================================
+
+FLAT: all heads ride one batched MXU matmul per contraction via a
+block-diagonal selector, the slab streamed in its unpadded storage layout and
+never viewed as ``[b, L, h, d]`` (whose last two dims TPU pads to (16, 128),
+2.67x the bytes; PERF.md, PR 25).  A slab the step appends to is read AS IT
+WAS BEFORE the step, the step's own row apart
+(:func:`flat_append_decode_attention`): while the read depends on the append,
+the v5e's compiler keeps the slab in fast memory through the step and writes
+it back to HBM whole, every step (PERF.md, PR 34: 23 of FLAN-T5-base's 24
+slabs under ``generate``'s ``while``, a sixth of the step's traffic and none
+of it counted by the roofline).  Whoever holds the slabs appends the rows
+apart from the read (``models/t5/modeling.py``, ``Decoder``).
+
+LENGTH-MINOR: ``(d, Lp)`` are whole tiles as stored, so each head's slab is
+contracted directly, with no selector and none of its ``num_heads`` x
+multiply-accumulates (bare, 739 GB/s on the v5e where the flat read gives 685:
+ISSUE 34's record of PR 33's chip runs).  Only a slab that is never appended
+to can be stored so: a new position would be one lane of every tile.
+:func:`length_minor` makes it, padding ``L`` up to whole lanes; the caller
+masks the padding (:func:`pad_keys`).
 
 Quantisation: int8 slabs carry scales that fold into the math, per channel
-(cross, ``[b, 1, h*d]``) into q and the context, per position (self,
-``[b, L, h]``) into the scores and probabilities; no dequantised slab is
-ever materialised.  Masking: ``bias`` is additive f32 ``[h, L]`` and already
-holds causal masking, ``kv_mask`` ``[b, L]`` is per-row key validity.  A
-fully-masked row gives the plain mean of V (uniform softmax): finite, never
-zero, and to be treated as undefined by a caller that can produce one.
-Scores and softmax in f32, matmul operands in the model dtype.
+(cross) into q and the context, per position (self, the LM's) into the scores
+and probabilities; no dequantised slab is ever materialised.  Masking:
+``bias`` is additive f32 ``[h, L]`` and already holds causal masking,
+``kv_mask`` ``[b, L]`` is per-row key validity.  A fully-masked row gives the
+plain mean of V (uniform softmax): finite, never zero, and to be treated as
+undefined by a caller that can produce one.  Scores and softmax in f32,
+matmul operands in the model dtype.
 """
 
 from __future__ import annotations
@@ -27,6 +52,7 @@ import jax.numpy as jnp
 
 _MASK_FLOOR = -1e20
 _NEG_INF_DENSE = -1e9
+_LANES = 128    # the minor dimension of a TPU tile
 
 
 def decode_attention_reference(q, k, v, *, bias=None, kv_mask=None,
@@ -114,4 +140,106 @@ def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
     ctx = jnp.sum(jnp.where(sel.T[None], ctx2, 0.0), axis=1)  # [b, hd]
     if v_chan:
         ctx = ctx * v_scale[:, 0, :]
+    return ctx.reshape(b, 1, h, d).astype(dtype)
+
+
+def flat_append_decode_attention(q, kf, vf, k_row, v_row, cur, bias_hl,
+                                 kv_mask, k_scale, v_scale, num_heads, dtype):
+    """Single-token attention over POSITION-MAJOR flat slabs ``[L, b, h*d]``
+    that the step is about to append to: ``kf``/``vf`` as they were BEFORE
+    the step, and the step's own row ``k_row``/``v_row`` ``[1, b, h*d]``
+    (what position ``cur`` will hold; same dtype as the slab) apart from
+    them.  The formulation is :func:`flat_decode_attention`'s (block-diagonal
+    selector, one MXU matmul a contraction, no per-head view) and so is the
+    result over the appended slab; the difference is what the program around
+    it has to keep.  Reading the appended slab makes the append a producer of
+    the read: the v5e's compiler then holds the whole slab in fast memory
+    through the step and writes all of it back to HBM for the next one
+    (PERF.md, PR 34).  Read this way the slab is only read, and the append
+    is one ``[1, b, h*d]`` block written in place, by whoever holds the
+    slab: whole tiles where the program keeps this order (the engine's step
+    does; a loop's carry the compiler lays out as it likes), where a row of
+    a ``[b, L, h*d]`` slab is one sublane of ``b`` tiles.
+
+    The row's scores are written into the small ``[b, L, h]`` score array at
+    ``cur`` and its probability is taken out again for the row's own value,
+    so position ``cur`` of ``kf``/``vf`` is never used.  bias_hl additive
+    f32 [h, L] (carries causal masking); kv_mask [b, L]; ``k_scale``/
+    ``v_scale`` None or per-position ``[L, b, h]`` AFTER the step (they are
+    small).  q and the result [b, 1, h, d]."""
+    L, b, hd = kf.shape
+    h, d = num_heads, hd // num_heads
+    qv = q.reshape(b, hd).astype(jnp.float32)
+    sel = jnp.arange(hd)[:, None] // d == jnp.arange(h)[None, :]  # [hd, h]
+    qexp = jnp.where(sel[None], qv[:, :, None], 0.0).astype(dtype)
+
+    # the transposes are the contractions' dimension numbers, not copies
+    # (XLA folds them into the matmuls; its CPU backend has no bf16 product
+    # with the batch dimension second)
+    def scores(slab):                                     # -> [b, L, h]
+        return jnp.einsum("blf,bfh->blh", jnp.swapaxes(slab, 0, 1).astype(dtype),
+                          qexp, preferred_element_type=jnp.float32)
+
+    def context(p, slab):                                 # -> [b, hd]
+        ctx2 = jnp.einsum("blh,blf->bhf", p.astype(dtype),
+                          jnp.swapaxes(slab, 0, 1).astype(dtype),
+                          preferred_element_type=jnp.float32)
+        return jnp.sum(jnp.where(sel.T[None], ctx2, 0.0), axis=1)
+
+    s = jax.lax.dynamic_update_slice(scores(kf), scores(k_row), (0, cur, 0))
+    if k_scale is not None:
+        s = s * jnp.swapaxes(k_scale, 0, 1)
+    if bias_hl is not None:
+        s = s + bias_hl.T[None]
+    if kv_mask is not None:
+        s = s + jnp.where(kv_mask > 0, 0.0, _NEG_INF_DENSE)[:, :, None]
+    p = jax.nn.softmax(s, axis=1)
+    if v_scale is not None:
+        p = p * jnp.swapaxes(v_scale, 0, 1)
+    p_row = jax.lax.dynamic_slice_in_dim(p, cur, 1, axis=1)
+    p = jax.lax.dynamic_update_slice(p, jnp.zeros_like(p_row), (0, cur, 0))
+    ctx = context(p, vf) + context(p_row, v_row)
+    return ctx.reshape(b, 1, h, d).astype(dtype)
+
+
+def length_minor(x: jax.Array) -> jax.Array:
+    """``[b, L, h, d]`` -> the stored slab ``[b, h, d, Lp]``, ``Lp`` the next
+    multiple of 128 lanes over ``L``, zeros past ``L`` (the tile padding,
+    made visible)."""
+    L = x.shape[1]
+    return pad_keys(jnp.transpose(x, (0, 2, 3, 1)), L + -L % _LANES)
+
+
+def pad_keys(x: jax.Array, length: int) -> jax.Array:
+    """Zero-pad the last (key) dimension up to ``length``: a key mask so
+    padded hides the positions :func:`length_minor` added, an additive bias
+    so padded leaves them to the mask."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, length - x.shape[-1])])
+
+
+def length_minor_decode_attention(q, k, v, bias_hl, kv_mask, k_scale, v_scale,
+                                  dtype):
+    """Single-token attention over LENGTH-MINOR cache slabs ``[b, h, d, L]``:
+    the plain per-head contraction, scores ``bhd,bhdl->bhl`` and context
+    ``bhl,bhdl->bhd``, each slab streamed once as stored.  int8 scales are
+    per channel and fold into q and the context.
+
+    q [b, 1, h, d]; bias_hl additive f32 [h, L]; kv_mask [b, L];
+    k_scale/v_scale None or [b, h, d, 1].  Returns [b, 1, h, d] in model
+    dtype."""
+    b, h, d, _ = k.shape
+    qv = q.reshape(b, h, d)
+    if k_scale is not None:
+        qv = qv.astype(jnp.float32) * k_scale[..., 0]
+    s = jnp.einsum("bhd,bhdl->bhl", qv.astype(dtype), k.astype(dtype),
+                   preferred_element_type=jnp.float32)
+    if bias_hl is not None:
+        s = s + bias_hl[None]
+    if kv_mask is not None:
+        s = s + jnp.where(kv_mask > 0, 0.0, _NEG_INF_DENSE)[:, None, :]
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bhl,bhdl->bhd", p.astype(dtype), v.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    if v_scale is not None:
+        ctx = ctx * v_scale[..., 0]
     return ctx.reshape(b, 1, h, d).astype(dtype)
