@@ -9,9 +9,11 @@ the Mosaic kernel is really in the program (``tpu_custom_call``), so a path
 that quietly took interpret mode fails.  A compile that passes is not a chip
 run: numbers and results come from ``chip_smoke.py`` and ``-m tpu``.
 
-The last two tests compile the flat decode attention, ``generate`` at the W3
+The next two tests compile the flat decode attention, ``generate`` at the W3
 shape and the engine's step at the serving shape, and read what the compiler
-made of the decode cache's layout (no kernel in them).
+made of the decode cache's layout (no kernel in them).  The last compile
+``T5Trainer``'s train step at the fine-tune cells' shapes and count what a
+dropout mask element costs in random bits, on one chip and on ``data=4``.
 """
 
 import os
@@ -313,3 +315,99 @@ def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
         assert _slabs_made_anew(text, slab) == written_back
     else:
         assert written_back == 0, written_back
+
+
+# -- the train step's dropout masks (PR 37) ----------------------------------
+
+def _mask_draws(jaxpr):
+    """(generator, bits an element, shape) of every array of random bits the
+    program draws, sub-programs included.  A Threefry key spends one 20-round
+    block on each element of a draw of up to 32 bits (the partitionable form
+    JAX 0.9 defaults to); an ``rbg`` key asks the chip's own generator."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "random_bits":
+            out.append((eqn.invars[0].aval.dtype._impl.name,
+                        eqn.params["bit_width"], tuple(eqn.params["shape"])))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out.extend(_mask_draws(sub))
+    return out
+
+
+def test_mask_draw_counter_sees_what_the_parent_step_drew():
+    """The counter is not vacuous: the parent's draw (``flax.linen.Dropout``
+    under the default key, at one encoder layer's probabilities) reads as one
+    32-bit Threefry draw of every element."""
+    from flax import linen as nn
+
+    shape = (32, 12, 512, 512)
+    jaxpr = jax.make_jaxpr(lambda key, x: nn.Dropout(0.1).apply(
+        {}, x, deterministic=False, rngs={"dropout": key}))(
+            jax.random.key(0), jax.ShapeDtypeStruct(shape, jnp.bfloat16))
+    assert _mask_draws(jaxpr.jaxpr) == [("threefry2x32", 32, shape)]
+
+
+def _train_step_w1(devs, chips, monkeypatch):
+    """``T5Trainer``'s step as ``fit`` builds it (``value_and_grad`` + clipped
+    AdamW, fp32 parameters, bf16 compute, dropout 0.1) at FLAN-T5-base widths,
+    2 + 2 layers, 32 rows a chip, encoder 512, decoder 128, on a
+    ``(data=chips, model=1)`` mesh of the described host."""
+    from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
+    from tpu_air.train import t5_trainer
+
+    cfg = T5Config.flan_t5_base()
+    cfg.dtype, cfg.num_layers, cfg.num_decoder_layers = "bfloat16", 2, 2
+    model = T5ForConditionalGeneration(cfg)
+    tx = t5_trainer._make_optimizer(t5_trainer.TrainingArguments(), 100)
+    mesh = Mesh(np.array(devs[:chips]).reshape(chips, 1), ("data", "model"))
+    rep, rows = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+
+    def on(tree, sharding):
+        return jax.tree_util.tree_map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    one = jnp.ones((1, 8), jnp.int32)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), one, one, one[:, :4])["params"])
+    b = 32 * chips
+    batch = {k: jax.ShapeDtypeStruct((b, n), jnp.int32, sharding=rows)
+             for k, n in (("input_ids", 512), ("attention_mask", 512),
+                          ("labels", 128))}
+    args = (on(params, rep), on(jax.eval_shape(tx.init, params), rep), batch,
+            on(jax.eval_shape(lambda: t5_trainer.dropout_key(0)), rep))
+    # the step asks the platform which compiler it will meet; the described
+    # chip is not the backend, so the test says so
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    return t5_trainer.make_train_step(model, tx), args
+
+
+def _site_shapes(b):
+    """The ten dropout sites of a 2 + 2-layer step at ``b`` rows: a layer's
+    probabilities (encoder self, decoder self, cross) and feed-forward hidden."""
+    enc = [(b, H, 512, 512), (b, 512, 2048)]
+    dec = [(b, H, 128, 128), (b, H, 128, 512), (b, 128, 2048)]
+    return sorted(2 * (enc + dec))
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_train_step_draws_each_mask_once_from_the_chips_generator(
+        v5e, monkeypatch, chips):
+    """What the fine-tune step pays for its masks.  The parent spent a Threefry
+    block on every element, three times a step where the backward fusions made
+    the mask again (the test above holds that count); this step draws each
+    site's mask once, 16 bits an element, from the chip's generator, and
+    no Threefry draw is left.  Under ``data=4`` every chip generates its own
+    32 rows: no ``rng-bit-generator`` of the global 128.  The compiler uses the
+    bits as they are generated: it copies none into another layout (it did,
+    0.6 ms a layer, where a self-attention mask was drawn key-minor)."""
+    step, args = _train_step_w1(v5e, chips, monkeypatch)
+    draws = _mask_draws(step.trace(*args).jaxpr.jaxpr)
+    assert sorted(shape for _, _, shape in draws) == _site_shapes(32 * chips)
+    assert {(g, bits) for g, bits, _ in draws} == {("rbg", 16)}
+
+    text = step.lower(*args).compile().as_text()
+    made = re.findall(r"= u(\d+)\[([\d,]+)\]\S* rng-bit-generator\(", text)
+    assert sorted(tuple(map(int, dims.split(","))) for _, dims in made) \
+        == _site_shapes(32), made
+    assert {bits for bits, _ in made} == {"16"}
+    assert not re.findall(r"= u16\[[\d,]+\]\S* copy\(", text)

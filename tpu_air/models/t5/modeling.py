@@ -40,6 +40,30 @@ Array = jax.Array
 NEG_INF = -1e9
 
 
+def _dropout(x: Array, rate: float, key, transposed: bool = False) -> Array:
+    """Inverted dropout from 16 random bits an element: kept where the
+    element's ``uint16`` draw is under ``round((1 - rate) * 2**16)`` (rate 0.1:
+    58,982 of 65,536, keep probability 0.899994), scaled by ``1 / (1 - rate)``.
+
+    Both live sites (attention probabilities, feed-forward hidden) draw here,
+    and what a mask element costs in random bits is most of what live dropout
+    costs the train step (PERF.md, PR 37).  Under the trainer's ``rbg`` key a
+    draw is one pass of the chip's bit generator, written once and read by the
+    forward and the backward fusions; 16 bits halve those bytes against the
+    32-bit uniform of ``flax.linen.Dropout``, and 8 cannot state a tenth.
+    ``transposed`` draws the bits with the last two axes exchanged, for a site
+    whose array the compiler lays out that way: the mask is as good, and the
+    bits need no copy into the layout."""
+    keep = min(int(round((1.0 - rate) * 2**16)), 2**16 - 1)
+    if transposed:
+        *lead, rows, cols = x.shape
+        bits = jnp.swapaxes(
+            jax.random.bits(key, (*lead, cols, rows), jnp.uint16), -1, -2)
+    else:
+        bits = jax.random.bits(key, x.shape, jnp.uint16)
+    return jnp.where(bits < keep, x / (1.0 - rate), jnp.zeros_like(x))
+
+
 def _dtype(config: T5Config):
     return jnp.dtype(config.dtype)
 
@@ -419,7 +443,13 @@ class Attention(nn.Module):
                 scores = scores + mask
             probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
             if not deterministic and cfg.dropout_rate > 0:
-                probs = nn.Dropout(cfg.dropout_rate)(probs, deterministic=False)
+                # where queries and keys are as many (self-attention) the
+                # chip's compiler lays the probabilities out query-minor, and
+                # would copy bits drawn key-minor: 0.6 ms a layer at
+                # [32, 12, 512, 512] (tests/test_chip_compile.py counts none)
+                probs = _dropout(probs, cfg.dropout_rate,
+                                 self.make_rng("dropout"),
+                                 transposed=qlen == klen)
             ctx = jnp.einsum(f"bhqk,{kv_dims}->bqhd", probs, v)
         out = nn.DenseGeneral(
             features=cfg.d_model, axis=(-2, -1), use_bias=False, dtype=dtype,
@@ -447,8 +477,8 @@ class FeedForward(nn.Module):
         else:
             h = act(nn.Dense(cfg.d_ff, use_bias=False, dtype=dtype, kernel_init=init,
                              name="wi")(x))
-        if cfg.dropout_rate > 0:
-            h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
+        if not deterministic and cfg.dropout_rate > 0:
+            h = _dropout(h, cfg.dropout_rate, self.make_rng("dropout"))
         return nn.Dense(
             cfg.d_model, use_bias=False, dtype=dtype,
             kernel_init=nn.initializers.normal(stddev=cfg.d_ff**-0.5), name="wo",

@@ -45,6 +45,8 @@ class TrainingArguments:
     max_grad_norm: float = 1.0
     optimizer: str = "adamw"  # or "adafactor"
     lr_scheduler_type: str = "constant"  # or "linear" / "cosine" decay to 0
+    # model.init takes PRNGKey(seed); the dropout masks come from their own
+    # stream, t5_trainer.dropout_key(seed)
     seed: int = 42
     evaluation_strategy: str = "epoch"
     save_strategy: str = "epoch"
@@ -105,18 +107,82 @@ def _make_optimizer(args: TrainingArguments, total_steps: int):
     return tx
 
 
+def dropout_key(seed: int):
+    """The key of the train step's dropout masks: a typed ``rbg`` key seeded
+    with ``seed + 1``, so a mask is drawn by the chip's own bit generator
+    (``rng-bit-generator``) and not by a software Threefry block an element,
+    which took 46 % of the fine-tune step and was run again in the backward
+    fusions (PERF.md, PR 37).  Only the dropout stream uses it: ``model.init``
+    keeps ``PRNGKey(seed)``, as every other random stream keeps its key.  Masks
+    therefore differ from those of versions that drew them from the default
+    key at the same seed, and, as ``rbg`` bits are not invariant under
+    sharding, from one mesh shape to another."""
+    import jax
+
+    return jax.random.key(seed + 1, impl="rbg")
+
+
+def _loss_from_batch(model, p, batch, dropout_rng):
+    import jax.numpy as jnp
+
+    from tpu_air.models.t5 import cross_entropy_loss, shift_right
+
+    cfg = model.config
+    labels = batch["labels"]
+    dec_in = shift_right(labels, cfg.decoder_start_token_id, cfg.pad_token_id)
+    dec_mask = (dec_in != cfg.pad_token_id).astype(jnp.int32).at[:, 0].set(1)
+    logits = model.apply(
+        {"params": p},
+        batch["input_ids"],
+        batch["attention_mask"],
+        dec_in,
+        decoder_attention_mask=dec_mask,
+        deterministic=dropout_rng is None,
+        rngs=None if dropout_rng is None else {"dropout": dropout_rng},
+    )
+    return cross_entropy_loss(logits, labels, cfg.pad_token_id)
+
+
+def make_train_step(model, tx):
+    """``(params, opt_state, batch, key) -> (params, opt_state, loss, key)``,
+    jitted with the first two donated: the loss with live dropout under a
+    split of ``key`` (a ``dropout_key``), its gradient, one ``tx`` update.
+
+    For a TPU the step is compiled with
+    ``xla_tpu_spmd_rng_bit_generator_unsafe``: each shard of a mask, over
+    ``data`` and ``model`` alike, is then generated on the chip that holds
+    it, from the key offset by the chip's place in the mesh.  Without it every
+    chip generates every shard's bits and keeps its slice (four times the
+    work on ``data=4``).  Other compilers do not know the option, and the
+    platform is all that is looked at."""
+    import jax
+    import optax
+
+    options = ({"xla_tpu_spmd_rng_bit_generator_unsafe": True}
+               if jax.default_backend() == "tpu" else None)
+
+    def train_step(p, o, batch, rng):
+        rng, sub = jax.random.split(rng)
+
+        def lf(pp):
+            loss, _ = _loss_from_batch(model, pp, batch, sub)
+            return loss
+
+        loss, grads = jax.value_and_grad(lf)(p)
+        updates, o = tx.update(grads, o, p)
+        p = optax.apply_updates(p, updates)
+        return p, o, loss, rng
+
+    return jax.jit(train_step, donate_argnums=(0, 1), compiler_options=options)
+
+
 def t5_train_loop(config: Dict[str, Any]) -> None:
     """The SPMD training function (runs inside the trial actor, on its chip
     lease). Uses the session API for data/report."""
     import jax
     import jax.numpy as jnp
 
-    from tpu_air.models.t5 import (
-        T5Config,
-        T5ForConditionalGeneration,
-        cross_entropy_loss,
-        shift_right,
-    )
+    from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
     from tpu_air.parallel import make_mesh, visible_devices
     from tpu_air.parallel.sharding import shard_params
     from tpu_air.train import session
@@ -163,7 +229,6 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
 
     model = T5ForConditionalGeneration(model_config)
     pad_id = model_config.pad_token_id
-    start_id = model_config.decoder_start_token_id
 
     # -- data ---------------------------------------------------------------
     train_ds = session.get_dataset_shard("train")
@@ -223,42 +288,11 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
     param_devices = len({d for x in leaves for d in x.sharding.device_set})
 
     # -- steps --------------------------------------------------------------
-    def loss_from_batch(p, batch, dropout_rng):
-        labels = batch["labels"]
-        dec_in = shift_right(labels, start_id, pad_id)
-        dec_mask = (dec_in != pad_id).astype(jnp.int32).at[:, 0].set(1)
-        logits = model.apply(
-            {"params": p},
-            batch["input_ids"],
-            batch["attention_mask"],
-            dec_in,
-            decoder_attention_mask=dec_mask,
-            deterministic=dropout_rng is None,
-            rngs=None if dropout_rng is None else {"dropout": dropout_rng},
-        )
-        return cross_entropy_loss(logits, labels, pad_id)
-
-    from functools import partial
-
-    import optax
-
-    @partial(jax.jit, donate_argnums=(0, 1))
-    def train_step(p, o, batch, rng):
-        rng, sub = jax.random.split(rng)
-
-        def lf(pp):
-            loss, _ = loss_from_batch(pp, batch, sub)
-            return loss
-
-        loss, grads = jax.value_and_grad(lf)(p)
-        updates, o = tx.update(grads, o, p)
-        p = optax.apply_updates(p, updates)
-        return p, o, loss, rng
+    train_step = make_train_step(model, tx)
 
     @jax.jit
     def eval_step(p, batch):
-        loss, ntok = loss_from_batch(p, batch, None)
-        return loss, ntok
+        return _loss_from_batch(model, p, batch, None)
 
     multihost = jax.process_count() > 1
 
@@ -280,12 +314,14 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
         # a host-local key is committed to a local device and may not mix
         # with global-mesh arrays in one jit — build a replicated global key
         # (identical bits on every host: same seed)
-        key_np = np.asarray(jax.random.PRNGKey(args.seed + 1))
-        rng = jax.make_array_from_callback(
-            key_np.shape, rep, lambda idx: key_np[idx]
-        )
+        local = dropout_key(args.seed)
+        key_np = np.asarray(jax.random.key_data(local))
+        rng = jax.random.wrap_key_data(
+            jax.make_array_from_callback(
+                key_np.shape, rep, lambda idx: key_np[idx]),
+            impl=jax.random.key_impl(local))
     else:
-        rng = jax.device_put(jax.random.PRNGKey(args.seed + 1), rep)
+        rng = jax.device_put(dropout_key(args.seed), rep)
 
     # -- epochs -------------------------------------------------------------
     for epoch in range(int(args.num_train_epochs)):
@@ -377,7 +413,14 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
 
 class T5Trainer(BaseTrainer):
     """Drop-in for the reference's HuggingFaceTrainer-on-T5 configuration
-    (Model_finetuning…ipynb:cc-40; flan-t5-batch-inference.py:96-111)."""
+    (Model_finetuning…ipynb:cc-40; flan-t5-batch-inference.py:96-111).
+
+    Randomness: ``TrainingArguments.seed`` seeds the parameters
+    (``PRNGKey(seed)``, unless pretrained or resumed) and, apart from them,
+    the dropout masks (``dropout_key``: the chip's bit generator, 16 bits an
+    element).  Equal seeds on an equal mesh give equal runs; the masks are not
+    those of versions before PR 37 at the same seed, nor the same from one
+    mesh shape to another."""
 
     _name_prefix = "T5Trainer"
 
