@@ -95,8 +95,13 @@ def make_lm_generate_fn(model: CausalLM, max_new_tokens: int,
             decode=True, return_hidden=True, mutable=["cache"],
         )
         head_w = head_weight(params, cfg).astype(jnp.float32)
+
+        def head(h):    # outside every module: scoped (docs/OBSERVABILITY.md)
+            with jax.named_scope("lm_head"):
+                return h @ head_w
+
         rng, sub = jax.random.split(rng)
-        tok = pick(hidden[:, -1].astype(jnp.float32) @ head_w, sub)
+        tok = pick(head(hidden[:, -1].astype(jnp.float32)), sub)
         if eos_token_id is not None:
             # filler rows (declared by the caller's live_mask) are born
             # finished: they emit pure pad and never hold the while_loop
@@ -115,7 +120,7 @@ def make_lm_generate_fn(model: CausalLM, max_new_tokens: int,
                 return_hidden=True, mutable=["cache"],
             )
             rng, sub = jax.random.split(rng)
-            nxt = pick(hidden[:, -1].astype(jnp.float32) @ head_w, sub)
+            nxt = pick(head(hidden[:, -1].astype(jnp.float32)), sub)
             if done is not None:
                 nxt = jnp.where(done, pad, nxt)
                 done = done | (nxt == eos_token_id)
@@ -271,7 +276,9 @@ def make_paged_decode_logits_body(model: CausalLM, slot_len: int):
             lambda _: block_table.astype(jnp.int32))
         cache, hidden, rows = apply(params, cache, tok[:, None], pos[:, None])
         h = hidden[:, -1].astype(jnp.float32)
-        return cache, h, h @ head_weight(params, cfg).astype(jnp.float32), rows
+        with jax.named_scope("lm_head"):
+            logits = h @ head_weight(params, cfg).astype(jnp.float32)
+        return cache, h, logits, rows
 
     return logits_step
 
@@ -399,8 +406,9 @@ def make_prefill_chunk_logits_body(model: CausalLM, page_len: int,
         positions = (p0 + jnp.arange(page_len, dtype=jnp.int32))[None]
         cache, hidden, _ = apply(params, cache, ids, positions)
         h_last = hidden[0, last_local.astype(jnp.int32)].astype(jnp.float32)
-        return cache, h_last, h_last @ head_weight(params, cfg).astype(
-            jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = h_last @ head_weight(params, cfg).astype(jnp.float32)
+        return cache, h_last, logits
 
     return logits_chunk
 

@@ -58,14 +58,19 @@ def rope(x: Array, positions: Array, theta: float) -> Array:
 
 def _dense_causal_attention(q, k, v, scale, q_offset=0):
     """(B,H,L,D) einsum attention with causal mask; baseline path."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32))
-    s = s * scale
-    lq, lk = q.shape[2], k.shape[2]
-    qi = q_offset + jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 0)
-    kj = jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 1)
-    s = jnp.where(qi >= kj, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32)).astype(q.dtype)
+    with jax.named_scope("attn_scores"):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                       k.astype(jnp.float32))
+        s = s * scale
+        lq, lk = q.shape[2], k.shape[2]
+        qi = q_offset + jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 0)
+        kj = jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 1)
+        s = jnp.where(qi >= kj, s, NEG_INF)
+    with jax.named_scope("attn_softmax"):
+        p = jax.nn.softmax(s, axis=-1)
+    with jax.named_scope("attn_context"):
+        return jnp.einsum("bhqk,bhkd->bhqd", p,
+                          v.astype(jnp.float32)).astype(q.dtype)
 
 
 class CausalSelfAttention(nn.Module):
@@ -140,10 +145,11 @@ class CausalSelfAttention(nn.Module):
                     rows = jnp.arange(b)
                     page = table[rows, i // C]
                     off = i % C
-                    ck.value = ck.value.at[page, off].set(
-                        kflat[:, 0].astype(dtype))
-                    cv.value = cv.value.at[page, off].set(
-                        vflat[:, 0].astype(dtype))
+                    with jax.named_scope("kv_append"):
+                        ck.value = ck.value.at[page, off].set(
+                            kflat[:, 0].astype(dtype))
+                        cv.value = cv.value.at[page, off].set(
+                            vflat[:, 0].astype(dtype))
                     idx.value = i + 1
                     kvm = jnp.arange(lg)[None, :] <= i[:, None]
                     o4 = flat_decode_attention(
@@ -166,10 +172,11 @@ class CausalSelfAttention(nn.Module):
                     )
                 p0 = i[0]
                 page = table[0, p0 // C]
-                ck.value = jax.lax.dynamic_update_slice(
-                    ck.value, kflat.astype(dtype), (page, 0, 0))
-                cv.value = jax.lax.dynamic_update_slice(
-                    cv.value, vflat.astype(dtype), (page, 0, 0))
+                with jax.named_scope("kv_append"):
+                    ck.value = jax.lax.dynamic_update_slice(
+                        ck.value, kflat.astype(dtype), (page, 0, 0))
+                    cv.value = jax.lax.dynamic_update_slice(
+                        cv.value, vflat.astype(dtype), (page, 0, 0))
                 idx.value = i + l
                 kg = gather_pages(ck.value, table[:1])
                 vg = gather_pages(cv.value, table[:1])
@@ -178,10 +185,11 @@ class CausalSelfAttention(nn.Module):
                 o = _dense_causal_attention(q, k4, v4, scale, q_offset=p0)
                 o = o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
                 return proj("o", cfg.d_model)(o)
-            ck.value = jax.lax.dynamic_update_slice(
-                ck.value, kflat.astype(dtype), (0, i, 0))
-            cv.value = jax.lax.dynamic_update_slice(
-                cv.value, vflat.astype(dtype), (0, i, 0))
+            with jax.named_scope("kv_append"):
+                ck.value = jax.lax.dynamic_update_slice(
+                    ck.value, kflat.astype(dtype), (0, i, 0))
+                cv.value = jax.lax.dynamic_update_slice(
+                    cv.value, vflat.astype(dtype), (0, i, 0))
             idx.value = i + l
             if l == 1:
                 # future cache slots are zeros; the kv_mask hides them
@@ -283,12 +291,13 @@ class SparseExperts(nn.Module):
         up = self.param("up", init, (e, d, f), jnp.float32)
         down = self.param("down", init, (e, f, d), jnp.float32)
         t = x.reshape(-1, d)
-        logits = jnp.dot(t.astype(jnp.float32), router.astype(jnp.float32),
-                         precision=jax.lax.Precision.HIGHEST)
-        probs, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        self.sow("intermediates", "expert_rows",
-                 jnp.zeros((t.shape[0], e), jnp.int32).at[
-                     jnp.arange(t.shape[0])[:, None], chosen].set(1))
+        with jax.named_scope("moe_router"):
+            logits = jnp.dot(t.astype(jnp.float32), router.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            probs, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            self.sow("intermediates", "expert_rows",
+                     jnp.zeros((t.shape[0], e), jnp.int32).at[
+                         jnp.arange(t.shape[0])[:, None], chosen].set(1))
         y = expert_ffn(t.astype(dtype), chosen, probs, gate.astype(dtype),
                        up.astype(dtype), down.astype(dtype))
         return y.astype(dtype).reshape(x.shape)
@@ -362,7 +371,9 @@ class CausalLM(nn.Module):
             # attention; at L=8k, V=50k that tensor alone is GBs)
             return x
         if cfg.tie_embeddings:
-            logits = x.astype(jnp.float32) @ embed.T
+            # the untied head is a module of this name
+            with jax.named_scope("lm_head"):
+                logits = x.astype(jnp.float32) @ embed.T
         else:
             logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=jnp.float32,
                               name="lm_head")(x.astype(jnp.float32))

@@ -40,6 +40,7 @@ Array = jax.Array
 NEG_INF = -1e9
 
 
+@jax.named_scope("dropout")
 def _dropout(x: Array, rate: float, key, transposed: bool = False) -> Array:
     """Inverted dropout from 16 random bits an element: kept where the
     element's ``uint16`` draw is under ``round((1 - rate) * 2**16)`` (rate 0.1:
@@ -436,12 +437,15 @@ class Attention(nn.Module):
                 mask = mask.astype(dtype)
             # a cached cross slab is [b, h, d, k]; anything else [b, k, h, d]
             kv_dims = "bhdk" if cross_cached else "bkhd"
-            scores = jnp.einsum(f"bqhd,{kv_dims}->bhqk", q, k)
-            if position_bias is not None:
-                scores = scores + position_bias
-            if mask is not None:
-                scores = scores + mask
-            probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
+            with jax.named_scope("attn_scores"):
+                scores = jnp.einsum(f"bqhd,{kv_dims}->bhqk", q, k)
+                if position_bias is not None:
+                    scores = scores + position_bias
+                if mask is not None:
+                    scores = scores + mask
+            with jax.named_scope("attn_softmax"):
+                probs = jax.nn.softmax(
+                    scores.astype(jnp.float32), axis=-1).astype(dtype)
             if not deterministic and cfg.dropout_rate > 0:
                 # where queries and keys are as many (self-attention) the
                 # chip's compiler lays the probabilities out query-minor, and
@@ -450,7 +454,8 @@ class Attention(nn.Module):
                 probs = _dropout(probs, cfg.dropout_rate,
                                  self.make_rng("dropout"),
                                  transposed=qlen == klen)
-            ctx = jnp.einsum(f"bhqk,{kv_dims}->bqhd", probs, v)
+            with jax.named_scope("attn_context"):
+                ctx = jnp.einsum(f"bhqk,{kv_dims}->bqhd", probs, v)
         out = nn.DenseGeneral(
             features=cfg.d_model, axis=(-2, -1), use_bias=False, dtype=dtype,
             kernel_init=nn.initializers.normal(stddev=(cfg.num_heads * cfg.d_kv) ** -0.5),
@@ -630,15 +635,18 @@ class Decoder(nn.Module):
                 # the cache-init pass (a real apply now, so cross K/V get
                 # computed) is not a decoding step — nothing is appended
                 # and the position stays 0
-                for s, new in zip(slabs, zip(*rows)):
-                    if isinstance(s.value, tuple):   # one array a layer
-                        s.value = tuple(
-                            jax.lax.dynamic_update_slice(
-                                own, row, (pos.value, 0, 0))
-                            for own, row in zip(s.value, new))
-                    else:
-                        s.value = jax.lax.dynamic_update_slice(
-                            s.value, jnp.stack(new), (0, pos.value, 0, 0))
+                # no layer's module is around the append: the scope says
+                # whose rows they are (docs/OBSERVABILITY.md)
+                with jax.named_scope("self_attn/kv_append"):
+                    for s, new in zip(slabs, zip(*rows)):
+                        if isinstance(s.value, tuple):   # one array a layer
+                            s.value = tuple(
+                                jax.lax.dynamic_update_slice(
+                                    own, row, (pos.value, 0, 0))
+                                for own, row in zip(s.value, new))
+                        else:
+                            s.value = jax.lax.dynamic_update_slice(
+                                s.value, jnp.stack(new), (0, pos.value, 0, 0))
                 pos.value = pos.value + qlen
             return RMSNorm(cfg.layer_norm_epsilon, dtype, name="final_ln")(x)
 
@@ -684,8 +692,10 @@ class T5ForConditionalGeneration(nn.Module):
     def _head(self, hidden):
         cfg = self.config
         if cfg.tie_word_embeddings:
-            hidden = hidden * (cfg.d_model**-0.5)
-            return hidden @ self.shared.embedding.T.astype(hidden.dtype)
+            # the untied head is a module of this name
+            with jax.named_scope("lm_head"):
+                hidden = hidden * (cfg.d_model**-0.5)
+                return hidden @ self.shared.embedding.T.astype(hidden.dtype)
         return self.lm_head(hidden)
 
     def init_decode_cache(self, decoder_input_ids, encoder_hidden, encoder_mask):

@@ -80,6 +80,7 @@ def decode_attention_reference(q, k, v, *, bias=None, kv_mask=None,
     return out[:, None] if squeeze else out
 
 
+@jax.named_scope("kv_gather")
 def gather_pages(pool: jax.Array, block_table: jax.Array) -> jax.Array:
     """Assemble per-slot flat K/V slabs from a paged pool.
 
@@ -101,6 +102,7 @@ def gather_pages(pool: jax.Array, block_table: jax.Array) -> jax.Array:
     return pool[block_table].reshape(s, npg * page_len, hd)
 
 
+@jax.named_scope("decode_attention")
 def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
                            num_heads, dtype):
     """Single-token attention over FLAT cache slabs ``[b, L, h*d]``.
@@ -143,6 +145,7 @@ def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
     return ctx.reshape(b, 1, h, d).astype(dtype)
 
 
+@jax.named_scope("decode_attention")
 def flat_append_decode_attention(q, kf, vf, k_row, v_row, cur, bias_hl,
                                  kv_mask, k_scale, v_scale, num_heads, dtype):
     """Single-token attention over POSITION-MAJOR flat slabs ``[L, b, h*d]``
@@ -217,6 +220,7 @@ def pad_keys(x: jax.Array, length: int) -> jax.Array:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, length - x.shape[-1])])
 
 
+@jax.named_scope("decode_attention")
 def length_minor_decode_attention(q, k, v, bias_hl, kv_mask, k_scale, v_scale,
                                   dtype):
     """Single-token attention over LENGTH-MINOR cache slabs ``[b, h, d, L]``:

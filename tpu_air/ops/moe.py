@@ -52,12 +52,16 @@ def expert_ffn(x: Array, chosen: Array, weights: Array, gate: Array,
     float32."""
     t, k = chosen.shape
     e = gate.shape[0]
-    flat = chosen.reshape(-1)
-    order = jnp.argsort(flat, stable=True)           # assignments by expert
-    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
-    xs = x[order // k]                               # [t*k, d]
-    h = (jax.nn.silu(_grouped(xs, gate, sizes))
-         * _grouped(xs, up, sizes)).astype(x.dtype)
-    y = _grouped(h, down, sizes) * weights.reshape(-1)[order][:, None]
-    back = jnp.zeros((t * k, y.shape[-1]), jnp.float32).at[order].set(y)
-    return back.reshape(t, k, -1).sum(1)
+    with jax.named_scope("moe_sort"):
+        flat = chosen.reshape(-1)
+        order = jnp.argsort(flat, stable=True)       # assignments by expert
+        sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+        xs = x[order // k]                           # [t*k, d]
+    with jax.named_scope("moe_experts"):
+        h = (jax.nn.silu(_grouped(xs, gate, sizes))
+             * _grouped(xs, up, sizes)).astype(x.dtype)
+        y = _grouped(h, down, sizes)
+    with jax.named_scope("moe_combine"):
+        y = y * weights.reshape(-1)[order][:, None]
+        back = jnp.zeros((t * k, y.shape[-1]), jnp.float32).at[order].set(y)
+        return back.reshape(t, k, -1).sum(1)

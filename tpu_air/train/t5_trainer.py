@@ -19,7 +19,6 @@ cc-29) with one jit-compiled SPMD train step over a ``(data, model)`` mesh:
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
@@ -123,14 +122,19 @@ def dropout_key(seed: int):
 
 
 def _loss_from_batch(model, p, batch, dropout_rng):
+    import jax
     import jax.numpy as jnp
 
     from tpu_air.models.t5 import cross_entropy_loss, shift_right
 
     cfg = model.config
     labels = batch["labels"]
-    dec_in = shift_right(labels, cfg.decoder_start_token_id, cfg.pad_token_id)
-    dec_mask = (dec_in != cfg.pad_token_id).astype(jnp.int32).at[:, 0].set(1)
+    # the model's own parts are named by its modules; what is outside them
+    # gets a scope (docs/OBSERVABILITY.md, "Model parts on the device rows")
+    with jax.named_scope("loss"):
+        dec_in = shift_right(labels, cfg.decoder_start_token_id,
+                             cfg.pad_token_id)
+        dec_mask = (dec_in != cfg.pad_token_id).astype(jnp.int32).at[:, 0].set(1)
     logits = model.apply(
         {"params": p},
         batch["input_ids"],
@@ -140,7 +144,8 @@ def _loss_from_batch(model, p, batch, dropout_rng):
         deterministic=dropout_rng is None,
         rngs=None if dropout_rng is None else {"dropout": dropout_rng},
     )
-    return cross_entropy_loss(logits, labels, cfg.pad_token_id)
+    with jax.named_scope("loss"):
+        return cross_entropy_loss(logits, labels, cfg.pad_token_id)
 
 
 def make_train_step(model, tx):
@@ -169,8 +174,9 @@ def make_train_step(model, tx):
             return loss
 
         loss, grads = jax.value_and_grad(lf)(p)
-        updates, o = tx.update(grads, o, p)
-        p = optax.apply_updates(p, updates)
+        with jax.named_scope("optimizer"):    # the clip is part of ``tx``
+            updates, o = tx.update(grads, o, p)
+            p = optax.apply_updates(p, updates)
         return p, o, loss, rng
 
     return jax.jit(train_step, donate_argnums=(0, 1), compiler_options=options)
@@ -225,7 +231,6 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
         )
     dp = max(1, len(devs) // tp)
     mesh = make_mesh(("data", "model"), (dp, tp), devices=devs[: dp * tp])
-    ndev = dp * tp
 
     model = T5ForConditionalGeneration(model_config)
     pad_id = model_config.pad_token_id
@@ -244,7 +249,6 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
     sample = next(train_ds.iter_batches(batch_size=2, batch_format="pandas"))
     sample_batch = collate(sample, keys)
     seq_lens = {k: v.shape[1] for k, v in sample_batch.items()}
-    seq_len = seq_lens["input_ids"]
 
     resume_dir = config.get("resume_from_checkpoint")
     pretrained = config.get("pretrained_params")
@@ -325,8 +329,6 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
 
     # -- epochs -------------------------------------------------------------
     for epoch in range(int(args.num_train_epochs)):
-        t0 = time.time()
-        tokens = 0
         losses = []
         nsteps = 0
         batches = train_ds.iter_batches(
@@ -349,19 +351,15 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
             with phase("train.dispatch", step=nsteps):
                 params, opt_state, loss, rng = train_step(params, opt_state, batch, rng)
             losses.append(loss)
-            tokens += global_bs * seq_len
             nsteps += 1
             if args.max_steps_per_epoch and nsteps >= args.max_steps_per_epoch:
                 break
         with phase("train.epoch_sync", epoch=epoch + 1):
             train_loss = float(jnp.mean(jnp.stack(losses))) if losses else float("nan")
-        dt = time.time() - t0
         metrics: Dict[str, Any] = {
             "epoch": epoch + 1,
             "loss": train_loss,
             "steps": nsteps,
-            "train_tokens_per_sec": tokens / dt if dt > 0 else 0.0,
-            "train_tokens_per_sec_per_chip": (tokens / dt / ndev) if dt > 0 else 0.0,
             "mesh_data": dp,
             "mesh_model": tp,
             # how many PROCESSES the mesh spans — the cross-host proof for
