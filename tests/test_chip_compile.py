@@ -109,8 +109,30 @@ def _struct(shape, dtype, devs):
         shape, dtype, sharding=SingleDeviceSharding(devs[0]))
 
 
+def _flash_t5_train(devs, grad: bool):
+    """The fine-tune step's encoder self-attention (PR 39): bias, key mask
+    and live dropout drawn in the kernel, operands ``[b, L, h·d]`` as the
+    projections write them; forward, or forward and the one-kernel backward
+    with ``dbias``."""
+    b, L = 4, 512
+    qkv = _struct((b, L, H * D), jnp.bfloat16, devs)
+    bias = _struct((1, H, L, L), jnp.float32, devs)
+    mask = _struct((b, L), jnp.int32, devs)
+    seed = _struct((2,), jnp.int32, devs)
+    fwd = lambda q, k, v, bias, mask, seed: flash_attention(  # noqa: E731
+        q, k, v, bias=bias, kv_mask=mask, scale=1.0, interpret=False,
+        dropout_rate=0.1, dropout_seed=seed, num_heads=H)
+    if grad:
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2, 3)), (qkv, qkv, qkv, bias, mask, seed)
+    return fwd, (qkv, qkv, qkv, bias, mask, seed)
+
+
 CASES = {
     "flash_fwd_t5_bias_mask": _flash_t5,
+    "flash_fwd_t5_bias_mask_dropout": lambda d: _flash_t5_train(d, grad=False),
+    "flash_bwd_t5_bias_mask_dropout": lambda d: _flash_t5_train(d, grad=True),
     "flash_fwd_causal_2048": lambda d: _flash_causal(d, grad=False),
     "flash_bwd_causal_2048": lambda d: _flash_causal(d, grad=True),
     "ring_causal_4_chips": _ring,
@@ -378,13 +400,15 @@ def _train_step_w1(devs, chips, monkeypatch):
     # the step asks the platform which compiler it will meet; the described
     # chip is not the backend, so the test says so
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    return t5_trainer.make_train_step(model, tx), args
+    return t5_trainer.make_train_step(model, tx, mesh), args
 
 
-def _site_shapes(b):
-    """The ten dropout sites of a 2 + 2-layer step at ``b`` rows: a layer's
-    probabilities (encoder self, decoder self, cross) and feed-forward hidden."""
-    enc = [(b, H, 512, 512), (b, 512, 2048)]
+def _xla_site_shapes(b):
+    """The dropout sites of a 2 + 2-layer step at ``b`` rows whose mask XLA
+    still draws: the feed-forward hidden, and the decoder's two small
+    attentions, which stay dense (128 x 128 and 128 x 512 are under the
+    training dispatch's crossover)."""
+    enc = [(b, 512, 2048)]
     dec = [(b, H, 128, 128), (b, H, 128, 512), (b, 128, 2048)]
     return sorted(2 * (enc + dec))
 
@@ -392,22 +416,40 @@ def _site_shapes(b):
 @pytest.mark.parametrize("chips", [1, 4])
 def test_train_step_draws_each_mask_once_from_the_chips_generator(
         v5e, monkeypatch, chips):
-    """What the fine-tune step pays for its masks.  The parent spent a Threefry
-    block on every element, three times a step where the backward fusions made
-    the mask again (the test above holds that count); this step draws each
-    site's mask once, 16 bits an element, from the chip's generator, and
-    no Threefry draw is left.  Under ``data=4`` every chip generates its own
-    32 rows: no ``rng-bit-generator`` of the global 128.  The compiler uses the
-    bits as they are generated: it copies none into another layout (it did,
-    0.6 ms a layer, where a self-attention mask was drawn key-minor)."""
+    """What the fine-tune step pays for its masks, and for its attention.  The
+    encoder's self-attention sites (512 x 512) are the fused Pallas kernels,
+    one forward and one backward each: they draw their masks where they use
+    them, so no array of a site's probabilities ``[rows, 12, 512, 512]``, of
+    any type, is in the compiled step, and none of the projections' results
+    ``[rows, 512, 768]`` is copied into another layout for them.  What XLA
+    still draws (the feed-forward hidden, the decoder's small attentions) it
+    draws once a site, 16 bits an element, from the chip's generator, no
+    Threefry draw (PR 37; the test above holds the parent's count), and uses
+    as generated: no copy of bits.  Under ``data=4`` every chip runs the
+    kernels on, and generates the bits of, its own 32 rows: no
+    ``rng-bit-generator`` of the global 128, no all-gather in the step, and
+    one all-reduce of the encoder's position-bias gradient."""
     step, args = _train_step_w1(v5e, chips, monkeypatch)
     draws = _mask_draws(step.trace(*args).jaxpr.jaxpr)
-    assert sorted(shape for _, _, shape in draws) == _site_shapes(32 * chips)
+    assert sorted(shape for _, _, shape in draws) == _xla_site_shapes(32 * chips)
     assert {(g, bits) for g, bits, _ in draws} == {("rbg", 16)}
+    assert step.attention_sites == {"fused": 2, "dense": 4}
 
     text = step.lower(*args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2 * 2
     made = re.findall(r"= u(\d+)\[([\d,]+)\]\S* rng-bit-generator\(", text)
     assert sorted(tuple(map(int, dims.split(","))) for _, dims in made) \
-        == _site_shapes(32), made
+        == _xla_site_shapes(32), made
     assert {bits for bits, _ in made} == {"16"}
     assert not re.findall(r"= u16\[[\d,]+\]\S* copy\(", text)
+    for rows in (32, 32 * chips):
+        assert f"[{rows},{H},512,512]" not in text
+        assert not re.findall(rf"= \w+\[{rows},512,768\]\S* copy\(", text)
+    assert "all-gather" not in text
+    # the position bias's gradient crosses the chips once a stack (every
+    # layer's ``dbias`` added on its own chip first), not once a layer
+    reduced = [dims for line in text.splitlines() if " all-reduce(" in line
+               for dims in re.findall(r"\w\[([\d,]+)\]",
+                                      line.split(" all-reduce(")[0])]
+    assert sum(sorted(dims.split(",")) == ["12", "512", "512"]
+               for dims in reduced) == (chips > 1), reduced

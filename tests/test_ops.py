@@ -699,3 +699,171 @@ def test_t5_cached_window_matches_single_steps(int8):
         np.testing.assert_allclose(
             np.asarray(a, np.float32), np.asarray(b, np.float32),
             atol=1 if a.dtype == jnp.int8 else 1e-5, rtol=0)
+
+
+# -- the fused training attention (PR 39): bias, key mask, dropout, dbias ------
+
+FUSED_SHAPES = {          # (Lq, Lk, causal, bias): the fine-tune cell's three
+    "self_256x256": (256, 256, False, True),       # attention kinds, smaller
+    "cross_128x256": (128, 256, False, False),
+    "causal_128x128": (128, 128, True, True),
+}
+
+
+def _fused_case(shape, rate, seed=0):
+    """(q, k, v, bias, kv_mask, keep) at B·H = 2·3, head width 64: the bias
+    per head and batch-shared, as T5's, the key mask with padded rows."""
+    lq, lk, causal, with_bias = FUSED_SHAPES[shape]
+    b, h, d = 2, 3, 64
+    rng = np.random.default_rng(seed)
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    q, k, v = mk(b * h, lq, d), mk(b * h, lk, d), mk(b * h, lk, d)
+    bias = mk(h, lq, lk) if with_bias else None
+    kv_mask = jnp.ones((b, lk), jnp.int32).at[1, lk - 40:].set(0)
+    keep = None
+    if rate:
+        keep = jnp.asarray(rng.random((b * h, lq, lk)) < 1.0 - rate, jnp.int8)
+    return q, k, v, bias, kv_mask, keep, causal
+
+
+@pytest.mark.parametrize("layout", ["head_major", "token_major"])
+@pytest.mark.parametrize("tiles", ["one_tile", "tiled"])
+@pytest.mark.parametrize("rate", [0.1, 0.0])
+@pytest.mark.parametrize("shape", sorted(FUSED_SHAPES))
+def test_fused_attention_matches_dense_with_the_mask_supplied(
+        shape, rate, tiles, layout):
+    """``out``, ``dq``, ``dk``, ``dv`` and ``dbias`` of the kernels against the
+    dense reference under the same keep mask: one tile a (q, k) pair (the
+    one-kernel backward, the cell's case) and 64-wide tiles (the two-pass
+    backward, whose dq pass carries the dbias rows); operands head-major, and
+    token-major ``[b, L, h·d]`` as T5's projections hand them over (the
+    kernels read a head's lanes in place)."""
+    q, k, v, bias, kv_mask, keep, causal = _fused_case(shape, rate)
+    block = None if tiles == "one_tile" else 64
+    addmask = (1.0 - kv_mask.astype(jnp.float32)) * -1e30
+    w = jnp.asarray(np.random.default_rng(9).normal(size=q.shape), jnp.float32)
+    b, h = 2, 3
+
+    def as_given(x):          # (b·h, L, d) -> (b, L, h·d) where token-major
+        if layout == "head_major":
+            return x
+        x = x.reshape(b, h, *x.shape[1:]).transpose(0, 2, 1, 3)
+        return x.reshape(b, x.shape[1], -1)
+
+    def f_flash(q, k, v, bias):
+        out = flash_attention(
+            as_given(q), as_given(k), as_given(v), bias, kv_mask=kv_mask,
+            scale=1.0, causal=causal, block_q=block, block_k=block,
+            dropout_rate=rate, dropout_keep=keep,
+            num_heads=None if layout == "head_major" else h)
+        if layout == "token_major":
+            out = out.reshape(b, -1, h, q.shape[-1]).transpose(0, 2, 1, 3)
+        return (w * out.reshape(q.shape)).sum()
+
+    def f_ref(q, k, v, bias):
+        return (w * _reference_pair(q, k, v, bias, addmask, 1.0, causal,
+                                    keep=keep, rate=rate)[0]).sum()
+
+    argnums = (0, 1, 2, 3) if bias is not None else (0, 1, 2)
+    got = jax.value_and_grad(f_flash, argnums)(q, k, v, bias)
+    want = jax.value_and_grad(f_ref, argnums)(q, k, v, bias)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for name, a, b in zip(("dq", "dk", "dv", "dbias"), got[1], want[1]):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=1e-3, err_msg=name)
+
+
+@pytest.mark.parametrize("case", ["same_seed", "other_seed", "other_shard"])
+def test_fused_attention_meets_its_forward_mask_in_the_backward(case):
+    """A seed is all the backward has of the forward's mask.  In interpret
+    mode (no generator) ``keep_from_seed`` stands in for the kernels' draw:
+    the same seed gives the same ``out`` and the gradients of the dense
+    reference under that very mask; another seed, or another shard's offset
+    of the same seed (``flash_attention_on_mesh`` adds a shard's place to the
+    second word), gives another mask."""
+    from tpu_air.ops.flash_attention import keep_from_seed
+
+    rate = 0.1
+    q, k, v, bias, kv_mask, _, causal = _fused_case("self_256x256", 0.0)
+    addmask = (1.0 - kv_mask.astype(jnp.float32)) * -1e30
+    seed = jnp.asarray([1234, 77], jnp.int32)
+    other = {"same_seed": seed, "other_seed": seed.at[0].add(1),
+             "other_shard": seed.at[1].add(1)}[case]
+    shape = (q.shape[0], q.shape[1], k.shape[1])
+    keep = keep_from_seed(seed, shape, rate)
+    same = bool(jnp.all(keep == keep_from_seed(other, shape, rate)))
+    assert same == (case == "same_seed")
+
+    def f_flash(q, k, v, bias, seed):
+        return flash_attention(q, k, v, bias, kv_mask=kv_mask, scale=1.0,
+                               dropout_rate=rate, dropout_seed=seed).sum()
+
+    def f_ref(q, k, v, bias):
+        return _reference_pair(q, k, v, bias, addmask, 1.0, causal,
+                               keep=keep, rate=rate)[0].sum()
+
+    got = jax.value_and_grad(f_flash, (0, 1, 2, 3))(q, k, v, bias, other)
+    want = jax.value_and_grad(f_ref, (0, 1, 2, 3))(q, k, v, bias)
+    close = all(
+        np.allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=1e-3)
+        for a, b in zip((got[0], *got[1]), (want[0], *want[1])))
+    assert close == (case == "same_seed")
+
+
+@pytest.mark.parametrize("rate", [0.1, 0.5])
+def test_fused_attention_keeps_its_share(rate):
+    """Kept share within 3 sigma of ``1 - rate`` (the 16-bit threshold's own
+    rounding is under a thousandth of a sigma at this size)."""
+    from tpu_air.ops.flash_attention import keep_from_seed, keep_threshold
+
+    n = 6 * 256 * 256
+    keep = keep_from_seed(jnp.asarray([5, 6], jnp.int32), (6, 256, 256), rate)
+    sigma = (rate * (1 - rate) / n) ** 0.5
+    assert abs(float(keep.mean()) - (1 - rate)) < 3 * sigma
+    assert abs(keep_threshold(rate) / 2**16 - (1 - rate)) < 2**-16
+
+
+@pytest.mark.parametrize("layout", ["head_major", "token_major",
+                                    "token_major_bias_copied_by_the_caller"])
+def test_fused_attention_on_a_mesh_matches_one_device(layout):
+    """``flash_attention_on_mesh`` over ``data=2 x model=2``: every shard runs
+    the kernels on its own rows and heads — (B, H, L, D) operands, and the
+    projections' (B, L, H·D) split along their last axis; the result and every
+    gradient, the batch-shared bias's summed over the batch shards, are the
+    one-device call's — also where the caller made the bias's copy a batch
+    shard itself (``bias_per_batch_shard``: T5 does, once a stack)."""
+    from jax.sharding import Mesh
+
+    from tpu_air.ops.flash_attention import (
+        bias_per_batch_shard, flash_attention_on_mesh, kernel_mesh)
+
+    b, h, lq, lk, d = 4, 4, 128, 128, 64
+    rng = np.random.default_rng(3)
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    if layout == "head_major":
+        q, k, v = mk(b, h, lq, d), mk(b, h, lk, d), mk(b, h, lk, d)
+        heads = {}
+    else:
+        q, k, v = mk(b, lq, h * d), mk(b, lk, h * d), mk(b, lk, h * d)
+        heads = {"num_heads": h}
+    bias = mk(1, h, lq, lk)
+    kv_mask = jnp.ones((b, lk), jnp.int32).at[3, 100:].set(0)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+
+    def loss(q, k, v, bias, on_mesh):
+        fn = flash_attention_on_mesh if on_mesh else flash_attention
+        if on_mesh and layout.endswith("by_the_caller"):
+            bias = bias_per_batch_shard(bias)
+            assert bias.shape[0] == 2
+        return (fn(q, k, v, bias, kv_mask=kv_mask, scale=1.0, **heads)
+                ** 2).sum()
+
+    want = jax.value_and_grad(loss, (0, 1, 2, 3))(q, k, v, bias, False)
+    with kernel_mesh(mesh):
+        got = jax.jit(jax.value_and_grad(loss, (0, 1, 2, 3)),
+                      static_argnums=4)(q, k, v, bias, True)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b_ in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=1e-4,
+                                   rtol=1e-4)

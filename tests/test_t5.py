@@ -662,3 +662,71 @@ def test_saved_config_with_removed_keys_still_loads():
     assert cfg == dataclasses.replace(T5Config.tiny(), d_ff=96)
     assert not hasattr(cfg, "decode_attention_impl")
     assert T5Config.from_json(json.dumps(saved)) == cfg
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.1])
+def test_training_pass_takes_the_fused_attention_where_the_shape_has_tiles(
+        monkeypatch, rate):
+    """A training pass under ``attention_impl="flash"`` (interpret mode on
+    this host; under ``"auto"`` the backend gate keeps every CPU run dense):
+    the site counter says which path each attention took, and loss and
+    gradients are the dense path's.  Rate 0.0: no dropout is live, every
+    eligible site is the deterministic flash call, whose biased backward is
+    the kernels' (``dbias`` reaches the position-bias table).  Rate 0.1: the
+    encoder's 128 x 128 self-attention has tiles and takes the fused training
+    kernels, projections flat; the decoder's 32-long queries have none and
+    stay dense; the dense model is given the kernels' own masks (in interpret
+    mode ``keep_from_seed`` of the site's seed words)."""
+    import dataclasses
+
+    from tpu_air.models.t5 import modeling
+    from tpu_air.ops.flash_attention import keep_from_seed
+
+    dropout = modeling._dropout
+
+    def dropout_as_the_kernels_draw(x, rate, key, transposed=False):
+        if x.ndim != 4:                      # the feed-forward hidden
+            return dropout(x, rate, key, transposed)
+        b, h, q, k = x.shape
+        keep = keep_from_seed(modeling._seed_words(key), (b * h, q, k), rate)
+        return jnp.where(keep.reshape(x.shape) != 0, x / (1.0 - rate), 0.0)
+
+    monkeypatch.setattr(modeling, "_dropout", dropout_as_the_kernels_draw)
+    cfg = dataclasses.replace(T5Config.tiny(), dropout_rate=rate)
+    dense = T5ForConditionalGeneration(
+        dataclasses.replace(cfg, attention_impl="einsum"))
+    flash = T5ForConditionalGeneration(
+        dataclasses.replace(cfg, attention_impl="flash"))
+    rng = jax.random.PRNGKey(0)
+    b, le, ld = 2, 128, 32
+    ii = jax.random.randint(rng, (b, le), 2, cfg.vocab_size, jnp.int32)
+    am = jnp.ones((b, le), jnp.int32).at[1, 90:].set(0)
+    labels = jax.random.randint(jax.random.PRNGKey(1), (b, ld), 2,
+                                cfg.vocab_size, jnp.int32)
+    dec_in = shift_right(labels, cfg.decoder_start_token_id, cfg.pad_token_id)
+    params = dense.init(rng, ii[:1, :8], am[:1, :8], dec_in[:1, :4])["params"]
+
+    def loss(model, p):
+        logits = model.apply(
+            {"params": p}, ii, am, dec_in, deterministic=False,
+            rngs={"dropout": jax.random.PRNGKey(7)})
+        return cross_entropy_loss(logits, labels, cfg.pad_token_id)[0]
+
+    counts = {}
+    for name, model in (("dense", dense), ("flash", flash)):
+        with modeling.count_attention_sites() as sites:
+            counts[name] = (jax.value_and_grad(lambda p: loss(model, p))(params),
+                            dict(sites))
+    assert counts["dense"][1] == {"dense": 6}
+    # rate 0.0: encoder self, decoder self and cross are all eligible for the
+    # deterministic call; 0.1: only the encoder's two sites have tiles
+    assert counts["flash"][1] == (
+        {"fused": 6} if rate == 0.0 else {"fused": 2, "dense": 4})
+    (l0, g0), (l1, g1) = counts["dense"][0], counts["flash"][0]
+    np.testing.assert_allclose(l1, l0, rtol=2e-5)
+    flat0 = jax.tree_util.tree_leaves_with_path(g0)
+    flat1 = dict(jax.tree_util.tree_leaves_with_path(g1))
+    for path, a in flat0:
+        np.testing.assert_allclose(
+            np.asarray(flat1[path]), np.asarray(a), atol=2e-5, rtol=2e-3,
+            err_msg=jax.tree_util.keystr(path))

@@ -136,6 +136,111 @@ def test_flash_backward_compiled_on_chip():
     assert "FLASH_BWD_TPU_OK" in out
 
 
+_FUSED_TRAIN_SCRIPT = """
+import jax, jax.numpy as jnp
+assert jax.devices()[0].platform == "tpu", jax.devices()
+from tpu_air.ops.flash_attention import (
+    flash_attention, keep_threshold, _reference_pair)
+
+# the fine-tune step's encoder self-attention, fewer rows: token-major
+# operands, T5's bias, a key mask with padding, the mask drawn in the kernel
+B, H, L, D, RATE = 2, 12, 512, 64, 0.1
+f32 = jnp.float32
+
+
+def fused(q, k, v, bias, kv_mask, seed):
+    return flash_attention(
+        q, k, v, bias, kv_mask=kv_mask, scale=1.0, interpret=False,
+        dropout_rate=RATE, dropout_seed=seed, num_heads=H)
+
+
+def mask_of(seed):
+    # The keep mask the kernels draw for ``seed``, read off the chip: with
+    # zero q and k every probability is 1 / L, and a v of unit rows hands 64
+    # columns of the dropped probabilities out a call.
+    z = jnp.zeros((B, L, H * D), jnp.bfloat16)
+    cols = []
+    for c in range(L // D):
+        unit = jnp.zeros((L, D), f32).at[c * D + jnp.arange(D),
+                                        jnp.arange(D)].set(1.0)
+        v = jnp.tile(unit[None, :, None, :], (B, 1, H, 1))
+        out = fused(z, z, v.reshape(B, L, H * D).astype(jnp.bfloat16), None,
+                    None, seed)
+        cols.append(out.reshape(B, L, H, D))
+    pd = jnp.concatenate(cols, axis=-1)                  # [B, Lq, H, Lk]
+    return (pd > 0).transpose(0, 2, 1, 3).reshape(B * H, L, L)
+
+
+def head_major(x):                       # [B, L, H*D] -> (B*H, L, D)
+    return x.reshape(B, L, H, D).transpose(0, 2, 1, 3).reshape(B * H, L, D)
+
+
+seed = jnp.asarray([1234567, 89], jnp.int32)
+keep = mask_of(seed)
+assert bool(jnp.all(keep == mask_of(seed))), "one seed, two masks"
+n = keep.size
+share = float(keep.mean())
+want = keep_threshold(RATE) / 2**16
+z = (share - want) / (want * (1 - want) / n) ** 0.5
+print(f"kept share {share:.6f} (law {want:.6f}), z = {z:.2f}")
+assert abs(z) < 4, z
+for name, other in (("other seed", seed.at[0].add(1)),
+                    ("other shard", seed.at[1].add(1))):
+    differ = float((keep != mask_of(other)).mean())
+    print(f"{name}: {differ:.4f} of the mask differs")
+    assert differ > 0.1, (name, differ)
+
+ks = jax.random.split(jax.random.PRNGKey(3), 6)
+q, k, v = (jax.random.normal(kk, (B, L, H * D), jnp.bfloat16) for kk in ks[:3])
+bias = jax.random.normal(ks[3], (H, L, L), f32)
+w = jax.random.normal(ks[4], (B, L, H * D), f32)
+kv_mask = jnp.ones((B, L), jnp.int32).at[1, 400:].set(0)
+addmask = (1.0 - kv_mask.astype(f32)) * -1e30
+
+
+def ref_out(q, k, v, bias, keep):
+    return _reference_pair(head_major(q), head_major(k), head_major(v), bias,
+                           addmask, 1.0, False, keep=keep, rate=RATE)[0]
+
+
+def fused_out(q, k, v, bias):
+    return head_major(fused(q, k, v, bias, kv_mask, seed))
+
+
+def grads(out_fn, *more):
+    loss = lambda q, k, v, bias: (                                # noqa: E731
+        head_major(w) * out_fn(q, k, v, bias, *more).astype(f32)).sum()
+    return jax.jit(jax.grad(loss, (0, 1, 2, 3)))(q, k, v, bias)
+
+
+other = mask_of(seed.at[0].add(1))
+got = (fused_out(q, k, v, bias), *grads(fused_out))
+want = (ref_out(q, k, v, bias, keep), *grads(ref_out, keep))
+wrong = (ref_out(q, k, v, bias, other), *grads(ref_out, other))
+for name, a, b, c in zip(("out", "dq", "dk", "dv", "dbias"), got, want, wrong):
+    scale = float(jnp.max(jnp.abs(b.astype(f32)))) + 1e-9
+    rel = float(jnp.max(jnp.abs(a.astype(f32) - b.astype(f32)))) / scale
+    off = float(jnp.max(jnp.abs(a.astype(f32) - c.astype(f32)))) / scale
+    print(f"{name}: rel_err={rel:.5f} (under another mask {off:.5f})")
+    # the backward drew the forward's mask: under any other the gradients
+    # are off by as much as they are large
+    assert rel < 3e-2 and off > 5 * rel, (name, rel, off)
+print("FUSED_TRAIN_TPU_OK")
+"""
+
+
+def test_fused_training_attention_on_chip():
+    """The fused training attention (PR 39) COMPILED on the chip with the
+    mask drawn by the chip's generator: the mask is read off the forward, is a
+    function of the seed alone (the same twice; another for another seed or
+    another shard's offset), keeps its share, and forward and backward —
+    ``dq``, ``dk``, ``dv``, ``dbias`` — are the dense reference's under that
+    very mask, so the backward met the forward's mask with none of it
+    stored."""
+    out = _run_on_tpu(_FUSED_TRAIN_SCRIPT)
+    assert "FUSED_TRAIN_TPU_OK" in out
+
+
 _RING_SCRIPT = """
 import jax, jax.numpy as jnp
 assert jax.devices()[0].platform == "tpu", jax.devices()

@@ -37,13 +37,19 @@ class T5Config:
     # Model_finetuning…ipynb:cc-64), fp32 params.
     dtype: str = "float32"
     # Attention dispatch.  ``attention_impl`` picks per-call at TRACE time:
-    # * "auto"   — einsum below ``flash_min_seq_len``, Pallas flash at or
-    #   above it (the measured v5e crossover: dense wins at 512, flash is
-    #   3.5-5x at >=2048 — docs/KERNELS.md); no user flag needed.
+    # * "auto"   — a deterministic pass: einsum below ``flash_min_seq_len``,
+    #   Pallas flash at or above it (the measured v5e crossover for a forward
+    #   without dropout: dense wins at 512, flash is 3.5-5x at >=2048 —
+    #   docs/KERNELS.md).  A training pass with live attention dropout: the
+    #   fused kernels (mask drawn in the kernel, no probabilities in HBM)
+    #   from the shape alone, ``ops.flash_attention.train_dispatch_ok`` —
+    #   512 x 512 and up, whatever ``flash_min_seq_len`` says.  No user flag
+    #   needed.
     # * "einsum" — always the XLA dense path.
-    # * "flash"  — always the Pallas kernel where eligible.
+    # * "flash"  — always the Pallas kernel where eligible (a training pass:
+    #   where the shape has real tiles).
     # Flash is only eligible off the cached-decode path with structured
-    # masks and inactive attention dropout (see modeling.Attention).
+    # masks (see modeling.Attention).
     attention_impl: str = "auto"
     flash_min_seq_len: int = 1024
     # Opt-in int8 cross-attention K/V cache for cached decode: the cross

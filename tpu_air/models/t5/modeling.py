@@ -20,6 +20,10 @@ attention score scaling, gated-GELU feed-forward, untied lm_head.
 
 from __future__ import annotations
 
+import collections
+import contextlib
+import importlib
+import math
 from typing import Any, Optional
 
 import jax
@@ -34,6 +38,10 @@ from tpu_air.ops.decode_attention import (
 )
 
 from .config import T5Config
+
+# the module: ``tpu_air.ops.flash_attention`` as an attribute is the function
+# of that name, which ``ops/__init__`` re-exports over it
+fa = importlib.import_module("tpu_air.ops.flash_attention")
 
 Array = jax.Array
 
@@ -55,7 +63,7 @@ def _dropout(x: Array, rate: float, key, transposed: bool = False) -> Array:
     ``transposed`` draws the bits with the last two axes exchanged, for a site
     whose array the compiler lays out that way: the mask is as good, and the
     bits need no copy into the layout."""
-    keep = min(int(round((1.0 - rate) * 2**16)), 2**16 - 1)
+    keep = fa.keep_threshold(rate)
     if transposed:
         *lead, rows, cols = x.shape
         bits = jnp.swapaxes(
@@ -63,6 +71,63 @@ def _dropout(x: Array, rate: float, key, transposed: bool = False) -> Array:
     else:
         bits = jax.random.bits(key, x.shape, jnp.uint16)
     return jnp.where(bits < keep, x / (1.0 - rate), jnp.zeros_like(x))
+
+
+def _flat_dot_general(x, kernel, dimension_numbers, precision=None,
+                      preferred_element_type=None):
+    """The product ``DenseGeneral`` asks for with the head and width axes it
+    would split (q, k, v: the result then ``[b, L, h*d]``) or contract (o)
+    kept as one axis — the same parameters, one plain matrix product."""
+    contract = len(dimension_numbers[0][0])        # trailing axes of x
+    x = x.reshape(*x.shape[:x.ndim - contract], -1)
+    kernel = kernel.reshape(math.prod(kernel.shape[:contract]), -1)
+    return jax.lax.dot_general(
+        x, kernel, (((x.ndim - 1,), (0,)), ((), ())), precision=precision,
+        preferred_element_type=preferred_element_type)
+
+
+def _train_fused(cfg: T5Config, deterministic: bool, batch: int, qlen: int,
+                 klen: int) -> bool:
+    """Does a plain (no cache, structured mask) attention of these lengths
+    take the fused training kernels?  Live dropout, and a shape the dispatch
+    sends there — ``ops.flash_attention.train_dispatch_ok`` under ``"auto"``
+    (chosen at trace time from the shape, no knob), any shape with tiles
+    under ``"flash"`` — that the kernels' mesh can split."""
+    if deterministic or cfg.dropout_rate <= 0 or qlen <= 1:
+        return False
+    if cfg.attention_impl == "auto":
+        ok = fa.train_dispatch_ok(qlen, klen, cfg.d_kv)
+    else:
+        ok = cfg.attention_impl == "flash" and fa.has_tiles(qlen, klen)
+    return ok and fa.mesh_divides(batch, cfg.num_heads)
+
+
+def _seed_words(key) -> Array:
+    """Two 32-bit words of a call site's dropout key, the fused attention's
+    seed: the site's own key as ``make_rng`` folded it, so no generator runs
+    for them."""
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    return jax.lax.bitcast_convert_type(key.reshape(-1)[:2], jnp.int32)
+
+
+# While a program is traced under ``count_attention_sites`` every ``Attention``
+# call outside the cached decode step counts itself here, by the path it took.
+_site_counts: Optional[collections.Counter] = None
+
+
+@contextlib.contextmanager
+def count_attention_sites():
+    """Counts, by ``"fused"`` (the Pallas kernels) and ``"dense"`` (einsum),
+    the attention call sites of what is traced under it.
+    ``T5Trainer`` reports its train step's (``fused_attention_sites``,
+    ``dense_attention_sites``)."""
+    global _site_counts
+    was, _site_counts = _site_counts, collections.Counter()
+    try:
+        yield _site_counts
+    finally:
+        _site_counts = was
 
 
 def _dtype(config: T5Config):
@@ -167,13 +232,32 @@ class Attention(nn.Module):
         dtype = _dtype(cfg)
         init = nn.initializers.normal(stddev=cfg.d_model**-0.5)
 
+        # A training pass with live attention dropout, no cache in play and
+        # the structured mask form: the attention between the projections is
+        # the fused Pallas kernels wherever the shape is worth a kernel
+        # (``_train_fused``).  The dense path there writes, keeps and reads
+        # the [b, h, q, k] probabilities and their mask words in HBM, a third
+        # of the fine-tune step (PERF.md, PR 39); the kernels draw the mask
+        # where they use it, forward and backward, and keep neither.  Known
+        # before the projections, because these then keep heads and widths
+        # one axis, [b, L, h*d]: the kernels read a head's lanes where they
+        # lie, and no array is copied into another layout on the way.
+        live_dropout = not deterministic and cfg.dropout_rate > 0
+        train_fused = (
+            not decode and not cross_decode and appending is None
+            and mask is None
+            and _train_fused(cfg, deterministic, hidden.shape[0],
+                             hidden.shape[1], kv_hidden.shape[1]))
+        flat = _flat_dot_general if train_fused else None
+
         def dense(name):
             return nn.DenseGeneral(
                 features=(cfg.num_heads, cfg.d_kv),
                 axis=-1, use_bias=False, dtype=dtype, kernel_init=init, name=name,
+                dot_general=flat,
             )
 
-        q = dense("q")(hidden)           # [b, q, h, d]
+        q = dense("q")(hidden)           # [b, q, h, d]; train_fused: [b, q, h*d]
         cache_int8 = getattr(cfg, "decode_cache_int8", False)
 
         def _quant(x):
@@ -305,30 +389,31 @@ class Attention(nn.Module):
         # cached slabs: cross [b, h, d, Lp], self [L, b, h*d]
         qlen = q.shape[1]
         klen = k.shape[-1 if cross_cached else 0 if cached_step else 1]
-        # Pallas blockwise path: eligible when callers passed the structured
-        # mask form (causal flag + key-padding row — never a dense (q, k)
-        # tensor), we're not in cached decode (qlen == 1 per-token launches
-        # are a perf cliff; XLA's einsum path wins there), and attention
-        # dropout is inactive (flash streams probabilities — there is no
-        # materialized matrix to drop out of).  Dispatch among eligible
-        # paths is by SHAPE at trace time (config.attention_impl="auto"):
-        # einsum below the measured crossover, flash at/above it.
+        # Pallas blockwise path for a DETERMINISTIC pass: eligible when
+        # callers passed the structured mask form (causal flag + key-padding
+        # row — never a dense (q, k) tensor) and we're not in cached decode
+        # (qlen == 1 per-token launches are a perf cliff; XLA's einsum path
+        # wins there).  Dispatch among eligible paths is by SHAPE at trace
+        # time (config.attention_impl="auto"): einsum below the measured
+        # crossover, flash at/above it.  (Live dropout below the training
+        # dispatch's own crossover stays dense: ``train_fused`` above.)
         eligible = (
             not decode
             and not cross_cached
             and qlen > 1
             and mask is None
-            and (deterministic or cfg.dropout_rate == 0)
+            and not live_dropout
+            and fa.mesh_divides(q.shape[0], cfg.num_heads)
         )
         if cfg.attention_impl == "auto":
-            from tpu_air.ops.flash_attention import auto_dispatch_ok
-
             use_flash = eligible and (
                 max(qlen, klen) >= cfg.flash_min_seq_len
-                and auto_dispatch_ok(qlen, klen)
+                and fa.auto_dispatch_ok(qlen, klen)
             )
         else:
             use_flash = eligible and cfg.attention_impl == "flash"
+        if _site_counts is not None and not cached_step:
+            _site_counts["fused" if use_flash or train_fused else "dense"] += 1
         if cached_step:
             # Single-token step over cache slabs.  Structured-mask
             # contract: mask here is batch-shared (decode causal row) or
@@ -409,22 +494,39 @@ class Attention(nn.Module):
             ctx = None
         if ctx is not None:
             pass
-        elif use_flash:
-            from tpu_air.ops import flash_attention
-
+        elif train_fused or use_flash:
             # position_bias stays (1, H, q, k) — the kernel's BlockSpec
             # replays the head tile per batch element; no HBM broadcast.
             # Block sizes: None → the kernel's measured-on-TPU auto tiling
             # (512/1024 caps; 128-capped tiles ran the MXU at ~1/8 rate).
-            ctx = flash_attention(
-                q.transpose(0, 2, 1, 3),
-                k.transpose(0, 2, 1, 3),
-                v.transpose(0, 2, 1, 3),
-                None if position_bias is None else position_bias.astype(jnp.float32),
-                kv_mask=kv_mask,
-                causal=causal,
-                scale=1.0,  # T5: unscaled scores
-            ).transpose(0, 2, 1, 3)
+            # A training pass hands q, k, v over as the projections wrote
+            # them, [b, L, h*d], with its rate and the site's seed; a
+            # deterministic one heads first, [b, h, L, d].  Under the
+            # trainer's mesh each shard runs the kernels on its own rows and
+            # heads.  One scope word for the call, forward and backward: the
+            # scores, the softmax and the mask are inside it.
+            if train_fused:
+                operands = (q, k, v)
+                training = dict(
+                    num_heads=cfg.num_heads, dropout_rate=cfg.dropout_rate,
+                    dropout_seed=_seed_words(self.make_rng("dropout")))
+            else:
+                operands = [x.transpose(0, 2, 1, 3) for x in (q, k, v)]
+                training = {}
+            with jax.named_scope("attn_context"):
+                ctx = fa.flash_attention_on_mesh(
+                    *operands,
+                    None if position_bias is None
+                    else position_bias.astype(jnp.float32),
+                    kv_mask=kv_mask,
+                    causal=causal,
+                    scale=1.0,  # T5: unscaled scores
+                    **training,
+                )
+            # [b, q, h, d], as ``o`` counts its axes (a training pass: its
+            # product folds them again, no copy)
+            ctx = (ctx.reshape(*ctx.shape[:2], cfg.num_heads, cfg.d_kv)
+                   if train_fused else ctx.transpose(0, 2, 1, 3))
         else:
             if mask is None and (kv_mask is not None or causal):
                 # densify the structured mask for the einsum path
@@ -459,7 +561,7 @@ class Attention(nn.Module):
         out = nn.DenseGeneral(
             features=cfg.d_model, axis=(-2, -1), use_bias=False, dtype=dtype,
             kernel_init=nn.initializers.normal(stddev=(cfg.num_heads * cfg.d_kv) ** -0.5),
-            name="o",
+            name="o", dot_general=flat,
         )(ctx)
         return out if appended is None else (out, appended)
 
@@ -547,6 +649,11 @@ class Encoder(nn.Module):
         bias = RelativePositionBias(cfg, bidirectional=True, name="rel_bias")(
             positions, positions
         )
+        if _train_fused(cfg, deterministic, embeds.shape[0], L, L):
+            # every layer's kernels take the bias: a copy a batch shard of
+            # the trainer's mesh, made once for the stack, so its gradient is
+            # reduced over the shards once and not once a layer
+            bias = fa.bias_per_batch_shard(bias)
         x = embeds
         for i in range(cfg.num_layers):
             x = EncoderLayer(cfg, name=f"layer_{i}")(
@@ -654,6 +761,8 @@ class Decoder(nn.Module):
         bias = RelativePositionBias(cfg, bidirectional=False, name="rel_bias")(
             positions, positions
         )
+        if _train_fused(cfg, deterministic, embeds.shape[0], qlen, qlen):
+            bias = fa.bias_per_batch_shard(bias)     # as in the encoder
         x = embeds
         for i in range(cfg.num_decoder_layers):
             x = DecoderLayer(cfg, name=f"layer_{i}")(

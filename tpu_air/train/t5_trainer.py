@@ -148,10 +148,17 @@ def _loss_from_batch(model, p, batch, dropout_rng):
         return cross_entropy_loss(logits, labels, cfg.pad_token_id)
 
 
-def make_train_step(model, tx):
+def make_train_step(model, tx, mesh=None):
     """``(params, opt_state, batch, key) -> (params, opt_state, loss, key)``,
     jitted with the first two donated: the loss with live dropout under a
     split of ``key`` (a ``dropout_key``), its gradient, one ``tx`` update.
+
+    ``mesh``: the ``(data, model)`` mesh the arguments are sharded over.  XLA
+    partitions the step but cannot partition a Pallas kernel, so the fused
+    attention (``models/t5/modeling.Attention``, a training pass) is mapped
+    over it: each chip runs the kernels on its own rows and heads.
+    ``step.attention_sites`` holds, once the step has been traced,
+    ``{"fused": n, "dense": n}``: the attention call sites by the path taken.
 
     For a TPU the step is compiled with
     ``xla_tpu_spmd_rng_bit_generator_unsafe``: each shard of a mask, over
@@ -163,8 +170,12 @@ def make_train_step(model, tx):
     import jax
     import optax
 
+    from tpu_air.models.t5.modeling import count_attention_sites
+    from tpu_air.ops.flash_attention import kernel_mesh
+
     options = ({"xla_tpu_spmd_rng_bit_generator_unsafe": True}
                if jax.default_backend() == "tpu" else None)
+    sites = {}
 
     def train_step(p, o, batch, rng):
         rng, sub = jax.random.split(rng)
@@ -173,13 +184,17 @@ def make_train_step(model, tx):
             loss, _ = _loss_from_batch(model, pp, batch, sub)
             return loss
 
-        loss, grads = jax.value_and_grad(lf)(p)
+        with kernel_mesh(mesh), count_attention_sites() as counted:
+            loss, grads = jax.value_and_grad(lf)(p)
+        sites.update(fused=counted["fused"], dense=counted["dense"])
         with jax.named_scope("optimizer"):    # the clip is part of ``tx``
             updates, o = tx.update(grads, o, p)
             p = optax.apply_updates(p, updates)
         return p, o, loss, rng
 
-    return jax.jit(train_step, donate_argnums=(0, 1), compiler_options=options)
+    step = jax.jit(train_step, donate_argnums=(0, 1), compiler_options=options)
+    step.attention_sites = sites
+    return step
 
 
 def t5_train_loop(config: Dict[str, Any]) -> None:
@@ -268,9 +283,26 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
     tx = _make_optimizer(args, steps_per_epoch * args.num_train_epochs)
 
     params = shard_params(params, mesh)
-    opt_state = tx.init(params)
     batch_sharding = NamedSharding(mesh, P("data"))
     rep = NamedSharding(mesh, P())
+    multihost = jax.process_count() > 1
+
+    def replicated(x):
+        """``x`` (host-local, the same on every host) on every device of
+        the mesh.  device_put rejects shardings with non-addressable
+        devices, so across hosts each one hands its devices their copy."""
+        if not multihost:
+            return jax.device_put(x, rep)
+        xa = np.asarray(x)
+        return jax.make_array_from_callback(
+            xa.shape, rep, lambda idx: xa[idx])
+
+    # the moments take their parameters' shardings; the step counters come
+    # out of ``tx.init`` on one device, uncommitted, and would come back from
+    # the first step on the mesh: another signature, so the step was traced
+    # and compiled twice (PERF.md, PR 39).  On the mesh from the start.
+    opt_state = jax.tree_util.tree_map(
+        lambda x: replicated(x) if x.ndim == 0 else x, tx.init(params))
 
     # Per-device param residency: with tp>1 the model-sharded leaves occupy
     # 1/tp of their bytes on each chip — the property that lets T5-XL fit
@@ -292,13 +324,11 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
     param_devices = len({d for x in leaves for d in x.sharding.device_set})
 
     # -- steps --------------------------------------------------------------
-    train_step = make_train_step(model, tx)
+    train_step = make_train_step(model, tx, mesh)
 
     @jax.jit
     def eval_step(p, batch):
         return _loss_from_batch(model, p, batch, None)
-
-    multihost = jax.process_count() > 1
 
     def put_batch(b):
         if multihost:
@@ -314,18 +344,12 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
             return out
         return {k: jax.device_put(jnp.asarray(v), batch_sharding) for k, v in b.items()}
 
-    if multihost:
-        # a host-local key is committed to a local device and may not mix
-        # with global-mesh arrays in one jit — build a replicated global key
-        # (identical bits on every host: same seed)
-        local = dropout_key(args.seed)
-        key_np = np.asarray(jax.random.key_data(local))
-        rng = jax.random.wrap_key_data(
-            jax.make_array_from_callback(
-                key_np.shape, rep, lambda idx: key_np[idx]),
-            impl=jax.random.key_impl(local))
-    else:
-        rng = jax.device_put(dropout_key(args.seed), rep)
+    # a host-local key is committed to a local device and may not mix with
+    # global-mesh arrays in one jit (identical bits on every host: same seed)
+    local = dropout_key(args.seed)
+    rng = jax.random.wrap_key_data(
+        replicated(jax.random.key_data(local)),
+        impl=jax.random.key_impl(local))
 
     # -- epochs -------------------------------------------------------------
     for epoch in range(int(args.num_train_epochs)):
@@ -362,6 +386,10 @@ def t5_train_loop(config: Dict[str, Any]) -> None:
             "steps": nsteps,
             "mesh_data": dp,
             "mesh_model": tp,
+            # the train step's attention call sites by the path they took
+            # (modeling.Attention): the Pallas kernels, or the dense einsum
+            "fused_attention_sites": train_step.attention_sites.get("fused", 0),
+            "dense_attention_sites": train_step.attention_sites.get("dense", 0),
             # how many PROCESSES the mesh spans — the cross-host proof for
             # the SPMD-multihost path (1 on a single host)
             "mesh_num_hosts": len(
