@@ -453,3 +453,42 @@ def test_train_step_draws_each_mask_once_from_the_chips_generator(
                                       line.split(" all-reduce(")[0])]
     assert sum(sorted(dims.split(",")) == ["12", "512", "512"]
                for dims in reduced) == (chips > 1), reduced
+
+
+def test_hybrid_decode_step_keeps_its_state_in_place_on_v5e(v5e):
+    """The paged decode step of a hybrid model at Jamba2-3B's widths (three
+    layers: Mamba, attention with one K/V head, Mamba) over 128 slots: the
+    chip's compiler takes it, updates the per-slot state where it lies (the
+    donated cache comes back aliased, nothing the size of a state is held
+    beside it) and pads neither the state-major ``[128, 16, 5120]`` state
+    nor the flat ``[128, 15360]`` convolution tail."""
+    from tpu_air.models.lm import CausalLM, LMConfig
+    from tpu_air.models.lm.generate import (init_paged_cache,
+                                            make_paged_decode_body)
+
+    cfg = LMConfig(vocab_size=65536, d_model=2560, n_layers=3, n_heads=20,
+                   n_kv_heads=1, head_dim=128, d_ff=8192, max_seq_len=2048,
+                   rope_theta=None, attn_layer_period=3, attn_layer_offset=1,
+                   mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=160,
+                   dtype="bfloat16")
+    model = CausalLM(cfg)
+    slots, slot_len, page = 128, 2048, 128
+    npg = slot_len // page
+    on = lambda tree, dtype=None: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _struct(s.shape, dtype or s.dtype, v5e), tree)
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        jnp.bfloat16)
+    cache = on(jax.eval_shape(
+        lambda: init_paged_cache(model, slots, slots * npg + 1, page, npg)))
+    i32 = lambda *shape: _struct(shape, jnp.int32, v5e)  # noqa: E731
+    compiled = jax.jit(make_paged_decode_body(model, slot_len),
+                       donate_argnums=(1,)).lower(
+        params, cache, i32(slots), i32(slots), i32(slots, npg)).compile()
+    mem = compiled.memory_analysis()
+    state = 2 * slots * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert mem.alias_size_in_bytes >= state          # updated in place
+    assert mem.temp_size_in_bytes < state // 2       # no second copy of it
+    text = compiled.as_text()
+    assert "f32[128,5120,16]" not in text            # never channel-major
+    assert not re.search(r"= \w+\[128,3,5120\]\S* copy\(", text)

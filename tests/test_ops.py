@@ -501,6 +501,46 @@ def test_length_minor_pads_to_whole_lanes_behind_the_mask(L):
                                atol=2e-6, rtol=2e-6)
 
 
+@pytest.mark.parametrize("kv_heads", [1, 2])
+@pytest.mark.parametrize("kind", ["none", "pos", "chan"])
+def test_flat_decode_attention_grouped_kv_takes_bias_and_scales(kind,
+                                                                kv_heads):
+    """One formulation for every number of K/V heads: with ``g`` K/V heads
+    serving ``h / g`` query heads each, the additive bias (a row a QUERY
+    head) and both kinds of int8 scales (a K/V head's, per position or per
+    channel) fold in as at ``g == h``, against the explicit reference over
+    K/V repeated a query head."""
+    from tpu_air.ops.decode_attention import (
+        decode_attention_reference, flat_decode_attention,
+    )
+
+    b, L, h, d = 3, 96, 4, 16
+    g, rep = kv_heads, 4 // kv_heads
+    rng = np.random.default_rng(3 + kv_heads)
+    q, _, _, bias, mask = _dk_inputs()
+    ks = vs = ks4 = vs4 = None
+    if kind == "none":
+        k = jnp.asarray(rng.standard_normal((b, L, g * d)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((b, L, g * d)), jnp.float32)
+    else:
+        k = jnp.asarray(rng.integers(-127, 128, (b, L, g * d)), jnp.int8)
+        v = jnp.asarray(rng.integers(-127, 128, (b, L, g * d)), jnp.int8)
+        shape = (b, L, g) if kind == "pos" else (b, 1, g * d)
+        ks = jnp.asarray(rng.uniform(0.001, 0.02, shape), jnp.float32)
+        vs = jnp.asarray(rng.uniform(0.001, 0.02, shape), jnp.float32)
+        ref_shape = (b, L, g, 1) if kind == "pos" else (b, 1, g, d)
+        ks4 = jnp.repeat(ks.reshape(ref_shape), rep, axis=2)
+        vs4 = jnp.repeat(vs.reshape(ref_shape), rep, axis=2)
+    got = flat_decode_attention(q, k, v, bias, mask, ks, vs, h, jnp.float32,
+                                g)
+    want = decode_attention_reference(
+        q, jnp.repeat(_heads(k, g), rep, axis=2),
+        jnp.repeat(_heads(v, g), rep, axis=2), bias=bias, kv_mask=mask,
+        k_scale=ks4, v_scale=vs4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.parametrize("kind", ["pos", "chan"])
 def test_flat_decode_attention_int8_scale_folding(kind):
     """int8 slabs never materialize a dequantized copy: scales fold into

@@ -11,6 +11,11 @@
 //
 // Usage: tpu_air_gcs <port> [dead_after_ms]
 //   prints "LISTENING <port>" on stdout once accepting (port 0 = ephemeral).
+//
+// The daemon belongs to the process that started it (a driver's runtime, a
+// local cluster's launcher) and goes when that process does, however it
+// went: a killed driver runs no atexit hook, so the daemon watches its own
+// parent pid, which changes the moment the parent is gone (PR 41).
 
 #include <arpa/inet.h>
 #include <netinet/in.h>
@@ -222,6 +227,14 @@ int main(int argc, char** argv) {
   ::getsockname(srv, reinterpret_cast<sockaddr*>(&addr), &alen);
   std::printf("LISTENING %d\n", ntohs(addr.sin_port));
   std::fflush(stdout);
+
+  const pid_t parent = ::getppid();
+  if (parent > 1) {
+    std::thread([parent] {
+      while (::getppid() == parent) ::usleep(250000);
+      ::_exit(0);
+    }).detach();
+  }
 
   for (;;) {
     int fd = ::accept(srv, nullptr, nullptr);

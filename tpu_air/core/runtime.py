@@ -35,6 +35,7 @@ from __future__ import annotations
 import itertools
 import os
 import secrets
+import signal
 import sys
 import tempfile
 import threading
@@ -318,7 +319,129 @@ def _resolve_args(store: ObjectStore, args, kwargs):
     return args, kwargs
 
 
+# -- no process outlives a run --------------------------------------------------
+#
+# Three ways a process was left behind a run that had printed its result:
+# a worker whose driver was killed noticed only at its next ``conn.recv()``,
+# not while it computed; a stop gave up on a process 11 s after asking, with
+# the process alive (a worker holding gigabytes on a chip can take longer to
+# tear down); nothing stopped what a worker itself had started.  So: a worker
+# dies with its driver (`_die_with_driver`), a stop waits until the process is
+# gone (`_stop_process`), and a worker's descendants go with it
+# (`_kill_descendants`, from the worker on its way out and from the driver
+# once the worker is gone).
+
+_PR_SET_PDEATHSIG = 1
+
+
+def _proc_stat(pid: int) -> Optional[Tuple[int, str, str]]:
+    """``(parent pid, state, start time)`` of a process from ``/proc``; None:
+    no such process (or no ``/proc``)."""
+    try:
+        with open(f"/proc/{pid}/stat") as f:
+            fields = f.read().rsplit(")", 1)[1].split()
+    except (OSError, IndexError):
+        return None
+    return int(fields[1]), fields[0], fields[19]
+
+
+def _descendants(pid: int) -> List[Tuple[int, str]]:
+    """Every live (non-zombie) process below ``pid`` as ``(pid, start
+    time)``, children before grandchildren.  The start time makes a pid that
+    was reused since tell itself apart."""
+    children: Dict[int, List[Tuple[int, str]]] = {}
+    try:
+        pids = [int(p) for p in os.listdir("/proc") if p.isdigit()]
+    except OSError:
+        return []
+    for p in pids:
+        st = _proc_stat(p)
+        if st is not None and st[1] != "Z":
+            children.setdefault(st[0], []).append((p, st[2]))
+    out, frontier = [], [pid]
+    while frontier:
+        nxt = [c for p in frontier for c in children.get(p, [])]
+        out.extend(nxt)
+        frontier = [p for p, _ in nxt]
+    return out
+
+
+def _kill_descendants(found: List[Tuple[int, str]], wait: float = 5.0) -> None:
+    """SIGKILL the processes of a :func:`_descendants` listing that are still
+    the ones listed, and wait (briefly) until none is left running."""
+    def alive(p, started):
+        st = _proc_stat(p)
+        return st is not None and st[1] != "Z" and st[2] == started
+
+    for p, started in found:
+        if alive(p, started):
+            try:
+                os.kill(p, signal.SIGKILL)
+            except (OSError, ProcessLookupError):
+                pass
+    deadline = time.monotonic() + wait
+    while time.monotonic() < deadline:
+        for p, _ in found:
+            try:        # ours to reap if it is our child; else init's
+                os.waitpid(p, os.WNOHANG)
+            except (ChildProcessError, OSError):
+                pass
+        if not any(alive(p, s) for p, s in found):
+            return
+        time.sleep(0.02)
+
+
+def _die_with_driver(driver_pid: int) -> None:
+    """Arrange for this worker to die when its driver does, whatever it is
+    doing then.  The parent-death signal where the platform has it and the
+    parent is one that lives as long as the driver (the forkserver's single
+    thread: the signal is tied to the THREAD that forked, and a plain fork
+    may come from a thread that ends long before its process).  And, always, a
+    watcher that looks for the driver's pid twice a second: it takes what the
+    worker started down with it."""
+    if driver_pid <= 0:
+        return
+    if os.getppid() != driver_pid:
+        try:
+            import ctypes
+
+            ctypes.CDLL(None, use_errno=True).prctl(
+                _PR_SET_PDEATHSIG, int(signal.SIGKILL), 0, 0, 0)
+        except (OSError, AttributeError):
+            pass
+    seen = _proc_stat(driver_pid)
+    if seen is None:        # no /proc here: nothing to watch by
+        return
+    started = seen[2]
+
+    def watch() -> None:
+        while True:
+            st = _proc_stat(driver_pid)
+            if st is None or st[1] == "Z" or st[2] != started:
+                _kill_descendants(_descendants(os.getpid()), wait=1.0)
+                os._exit(1)
+            time.sleep(0.5)
+
+    threading.Thread(target=watch, name="tpu_air-driver-watch",
+                     daemon=True).start()
+
+
 def _worker_main(
+    worker_id: int,
+    store_root: str,
+    conn: mpc.Connection,
+    driver_env: Optional[Dict[str, str]] = None,
+    driver_pid: int = 0,
+):
+    try:
+        _die_with_driver(driver_pid)
+        _worker_loop(worker_id, store_root, conn, driver_env)
+    finally:
+        # whatever this worker started ends with it
+        _kill_descendants(_descendants(os.getpid()), wait=2.0)
+
+
+def _worker_loop(
     worker_id: int,
     store_root: str,
     conn: mpc.Connection,
@@ -454,19 +577,30 @@ def _kill_quietly(proc) -> None:
         pass
 
 
+#: how long a stop waits for a process that was sent SIGKILL before it says
+#: so: a worker tearing down gigabytes on a chip is slow, not immortal
+_GONE_WAIT_S = 120.0
+
+
 def _stop_process(proc, grace: float) -> bool:
     """Give ``proc`` ``grace`` seconds to exit by itself, then SIGTERM, then
-    SIGKILL.  True once it is gone — only then may a chip it held go to
-    another process.  One thread per process: the caller owns the reap."""
+    SIGKILL, and wait until it is GONE (SIGKILL again every few seconds, up
+    to ``_GONE_WAIT_S``); then stop whatever it had started itself.  True
+    once it is gone — only then may a chip it held go to another process.
+    One thread per process: the caller owns the reap."""
+    below = _descendants(proc.pid) if proc.pid else []
     proc.join(timeout=grace)
-    for stop in (proc.terminate, proc.kill):
-        if not proc.is_alive():
-            break
+    if proc.is_alive():
         try:
-            stop()
+            proc.terminate()
         except (OSError, ProcessLookupError):
             pass
         proc.join(timeout=5)
+    deadline = time.monotonic() + _GONE_WAIT_S
+    while proc.is_alive() and time.monotonic() < deadline:
+        _kill_quietly(proc)
+        proc.join(timeout=5)
+    _kill_descendants(below)
     return not proc.is_alive()
 
 
@@ -753,7 +887,8 @@ class Runtime:
         # initializes any backend.
         proc = self._pick_ctx().Process(
             target=_worker_main,
-            args=(wid, self.store_root, child, dict(os.environ)),
+            args=(wid, self.store_root, child, dict(os.environ),
+                  os.getpid()),
             daemon=True,
             name=f"tpu_air-worker-{wid}",
         )

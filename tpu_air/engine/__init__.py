@@ -28,6 +28,7 @@ from .types import (
     EngineConfig,
     EngineOverloadedError,
     Request,
+    RecurrentStateUnsupported,
     RequestValidationError,
     ResponseStream,
 )
@@ -48,6 +49,7 @@ __all__ = [
     "PrefixCache",
     "PrefixMatch",
     "Request",
+    "RecurrentStateUnsupported",
     "RequestValidationError",
     "ShardedPagedPool",
     "ResponseStream",

@@ -40,7 +40,7 @@ from tpu_air.parallel.sharding import lm_param_shardings, lm_param_spec, \
     shard_params
 
 from ..engine import InferenceEngine
-from ..types import EngineConfig
+from ..types import EngineConfig, RecurrentStateUnsupported
 from .pool import ShardedPagedPool
 from .sharded import (
     make_sharded_page_copy_fn,
@@ -138,6 +138,10 @@ class MeshEngine(InferenceEngine):
 
     def _build_paged_state(self) -> None:
         cfg = self.config
+        if self._recurrent:
+            raise RecurrentStateUnsupported(
+                "MeshEngine shards page pools over data and has no sharding "
+                "for per-slot recurrent state (ROADMAP.md M6)")
         ppr = self._pages_per_replica()
         self.pool = ShardedPagedPool(
             self._dp, ppr, cfg.page_len, cfg.num_slots,

@@ -66,6 +66,12 @@ class PrefillWorker:
 
         self.model, self.params = self._checkpoint.get_model(
             dtype=self._dtype)
+        if getattr(self.model.config, "has_recurrent_layers", False):
+            from tpu_air.engine.types import RecurrentStateUnsupported
+
+            raise RecurrentStateUnsupported(
+                "a PrefillWorker ships K/V pages only; this model keeps "
+                "per-slot recurrent state beside them (ROADMAP.md M6)")
         self.pool = PagedKVPool(self.num_pages, self.page_len, 1,
                                 self.pages_per_slot)
         self.cache = init_paged_cache(

@@ -50,6 +50,20 @@ the prompts that just finished.  What follows from reading one step late:
   weight swap and rollback, adapter load and unload) first settles the step
   in flight: reads it and emits it (``_settle``).
 
+RECURRENT LAYERS.  A model with Mamba layers keeps, beside the pages of its
+attention layers, a row of state a slot that no block table reaches (the
+cache description: models/lm/generate.py).  A slot owns its pages by table
+and its state row by index, and three things pages gave for free are done by
+hand: the decode step holds the state of every row it does not decode (a row
+mid-prefill rides every step issued between its chunks; the program takes
+``pos > 0`` as the row being live, which is exactly the rows ``_issue``
+leaves in the table); a prompt's first chunk starts the row from zeros; a
+last chunk's padding never enters it.  The row that ends on EOS and rides
+step N+1 advances a state nobody reads again: the next tenant's first chunk
+zeroes it.  Prefix sharing is off for such a model (a page hit without the
+state at that boundary is wrong), seen in the model and not set by an option,
+and whatever ships pages alone is refused (``RecurrentStateUnsupported``).
+
 Depth is one and fixed; no option selects the behaviour.
 
 Correctness anchor: with greedy decoding the engine's emitted tokens are
@@ -70,6 +84,7 @@ import jax.numpy as jnp
 
 from tpu_air.models.lm.generate import (
     init_paged_cache,
+    recurrent_state_bytes,
     make_lm_paged_decode_step_fn,
     make_lm_prefill_chunk_fn,
     make_lm_step_feed_fns,
@@ -91,6 +106,7 @@ from .types import (
     EngineConfig,
     EngineDrainingError,
     EngineOverloadedError,
+    RecurrentStateUnsupported,
     Request,
     RequestValidationError,
     ResponseStream,
@@ -138,6 +154,9 @@ class InferenceEngine:
                 f"{model.config.max_seq_len}"
             )
         self.adapters_enabled = cfg.adapter_slots > 0
+        # per-slot state that is not pages (module doc, RECURRENT LAYERS)
+        self._recurrent = bool(
+            getattr(model.config, "has_recurrent_layers", False))
 
         # device side: the persistent donated KV pool + compiled phases
         # (MeshEngine overrides the builder: a sharded pool/cache and
@@ -168,6 +187,10 @@ class InferenceEngine:
         self.scheduler = Scheduler(cfg)
         self.slots = SlotManager(cfg.num_slots)
         self.metrics = EngineMetrics(name=name, num_slots=cfg.num_slots)
+        if self._recurrent:
+            self.metrics.set_recurrent_state(
+                self._state_bytes,
+                prefix_cache_disabled=bool(cfg.prefix_cache))
         # airscope: analytic flops/bytes per compiled program, fed into the
         # metrics ledger with each program's measured wall time.  The
         # decode-step cost is a CONSTANT — the fixed-shape step attends the
@@ -217,12 +240,14 @@ class InferenceEngine:
         cfg = self.config
         self.pool = PagedKVPool(
             cfg.pool_pages(), cfg.page_len, cfg.num_slots,
-            cfg.pages_per_slot(), prefix_cache=cfg.prefix_cache,
+            cfg.pages_per_slot(),
+            prefix_cache=cfg.prefix_cache and not self._recurrent,
         )
         self.cache = init_paged_cache(
             self.model, cfg.num_slots, cfg.pool_pages(), cfg.page_len,
             cfg.pages_per_slot(),
         )
+        self._state_bytes = recurrent_state_bytes(self.cache)
         self._decode_step = make_lm_paged_decode_step_fn(
             self.model, cfg.slot_len, adapters=self.adapters_enabled)
         self._chunk_fn = make_lm_prefill_chunk_fn(
@@ -357,6 +382,7 @@ class InferenceEngine:
         handoff instead of dropping it."""
         # a handoff rides through a drain: the router admitted it before the
         # drain started and its prefill already ran on another replica
+        self._refuse_pages_only("submit_prefilled")
         req = self._make_request(prompt, max_new_tokens, stream, priority,
                                  admit_while_draining=True,
                                  deadline_ms=deadline_ms)
@@ -452,6 +478,13 @@ class InferenceEngine:
         # airlint: disable=CC001 — GIL-atomic monotonic bool read
         return self._preempting
 
+    def _refuse_pages_only(self, what: str) -> None:
+        if self._recurrent:
+            raise RecurrentStateUnsupported(
+                f"{what} ships K/V pages only and this model keeps "
+                f"{self._state_bytes} bytes of recurrent state a pool beside "
+                "them (ROADMAP.md M6)")
+
     def migrate_out(self) -> List[Dict[str, Any]]:
         """Preemption drain: freeze the loop, settle the step in flight
         (read and emit it, so every cursor below is the device's) and pull
@@ -469,6 +502,7 @@ class InferenceEngine:
         owns the stream's future); their source streams are abandoned
         unfinished, and the proxy re-pins pollers at the destination.
         """
+        self._refuse_pages_only("migrate_out")
         self.preempt()
         from .dist.kv_transfer import extract_kv_pages  # lazy: avoids cycle
 
@@ -521,6 +555,7 @@ class InferenceEngine:
         having moved."""
         from .dist.kv_transfer import validate_kv_payload  # lazy: no cycle
 
+        self._refuse_pages_only("submit_migrated")
         prompt = [int(t) for t in payload["prompt"]]
         streamed = [int(t) for t in payload["streamed"]]
         pos = int(payload["pos"])
@@ -775,18 +810,18 @@ class InferenceEngine:
         last_local = (n - 1 - p0) if is_last else (C - 1)
         row = self.pool.chunk_row(slot.index, p0, plan.null_target)
         t0 = time.monotonic()
+        args = [self.params, self.cache, jnp.asarray(ids), jnp.int32(p0),
+                jnp.int32(last_local), jnp.asarray(row)]
         if self.adapters_enabled:
-            self.cache, tok = self._chunk_fn(
-                self.params, self.cache, jnp.asarray(ids), jnp.int32(p0),
-                jnp.int32(last_local), jnp.asarray(row),
-                self._adapter_a, self._adapter_b,
-                jnp.int32(req.adapter_row),
-            )
-        else:
-            self.cache, tok = self._chunk_fn(
-                self.params, self.cache, jnp.asarray(ids), jnp.int32(p0),
-                jnp.int32(last_local), jnp.asarray(row),
-            )
+            args += [self._adapter_a, self._adapter_b,
+                     jnp.int32(req.adapter_row)]
+        # the slot's state row goes with its table row; the chunk at position
+        # 0 starts it from zeros (the last tenant left its state behind) and
+        # positions past last_local are masked out of it
+        state = {"slot": jnp.int32(slot.index)} if self._recurrent else {}
+        self.cache, tok = self._chunk_fn(*args, **state)
+        if self._recurrent and p0 == 0:
+            self.metrics.record_state_reset()
         if self._cost_model is not None:
             # dispatch-time measurement: no chunk is host-synced here (the
             # final chunk's token is read after the next step went out), so
@@ -1116,6 +1151,9 @@ class InferenceEngine:
             self._tok_dev, self._pos_dev, out)
         self._inflight = _IssuedStep(out, [(s, s.request) for s in rows])
         self.metrics.record_issue(ahead)
+        if self._recurrent:
+            # every row not in the step rode it with its state held
+            self.metrics.record_rows_held(self.config.num_slots - len(rows))
         if not ahead:
             self._mark = time.monotonic()
 

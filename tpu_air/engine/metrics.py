@@ -111,6 +111,14 @@ class EngineMetrics:
         self.steps_issued = 0
         self.steps_ahead = 0
         self.steps_dropped = 0
+        # per-slot recurrent state (absent for a model without such layers):
+        # its bytes, first chunks run (each zeroes a slot's state), rows x
+        # steps whose state a decode step held by its mask, and whether the
+        # engine turned prefix sharing off because of it
+        self.ssm_state_bytes = 0
+        self.ssm_state_resets = 0
+        self.ssm_rows_held = 0
+        self.prefix_cache_disabled_by_model = False
         register(self)
 
     def set_topology(self, **kw: Any) -> None:
@@ -251,6 +259,20 @@ class EngineMetrics:
             self.steps_issued += 1
             self.steps_ahead += bool(ahead)
 
+    def set_recurrent_state(self, nbytes: int,
+                            prefix_cache_disabled: bool) -> None:
+        with self._lock:
+            self.ssm_state_bytes = int(nbytes)
+            self.prefix_cache_disabled_by_model = bool(prefix_cache_disabled)
+
+    def record_state_reset(self) -> None:
+        with self._lock:
+            self.ssm_state_resets += 1
+
+    def record_rows_held(self, rows: int) -> None:
+        with self._lock:
+            self.ssm_rows_held += rows
+
     def record_dropped_step(self) -> None:
         """An issued step nobody read: its window closed (every live row
         ended on EOS one step earlier) or the engine did."""
@@ -375,6 +397,12 @@ class EngineMetrics:
                 out["moe_expert_load"] = self.moe_expert_load.tolist()
                 out["moe_experts_streamed"] = self.moe_experts_streamed
                 out["moe_steps"] = self.moe_steps
+            if self.ssm_state_bytes:
+                out["ssm_state_bytes"] = self.ssm_state_bytes
+                out["ssm_state_resets"] = self.ssm_state_resets
+                out["ssm_rows_held"] = self.ssm_rows_held
+                out["prefix_cache_disabled_by_model"] = (
+                    self.prefix_cache_disabled_by_model)
             if self.steps_issued:
                 out["steps_issued"] = self.steps_issued
                 out["steps_ahead"] = self.steps_ahead

@@ -42,6 +42,13 @@ class EngineClosedError(TpuAirError):
     """The engine was shut down with this request still queued/in flight."""
 
 
+class RecurrentStateUnsupported(NotImplementedError, TpuAirError):
+    """The model keeps per-slot recurrent state (Mamba layers) and the
+    operation moves or shares K/V PAGES only: pages without the state at
+    that boundary are a wrong answer, so it is refused by name (preemption
+    migration, disaggregated prefill, the mesh engine; ROADMAP.md M6)."""
+
+
 class RequestValidationError(ValueError, TpuAirError):
     """The request itself is malformed (unknown ``adapter_id``): the
     client's fault, not the server's.  A ValueError subclass so local

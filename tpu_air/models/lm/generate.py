@@ -181,6 +181,17 @@ def make_lm_generate_fn(model: CausalLM, max_new_tokens: int,
 # HOST state (engine/kvpool/pool.py) pushed into the cache dict at every call
 # via leaf mappers, so the donated device cache never round-trips.  Prefill
 # is page-sized CHUNKS: one compiled program for every prompt length.
+#
+# ONE cache description for both kinds of per-sequence state: an attention
+# layer keeps pages, which the table reaches; a Mamba layer keeps a ROW A
+# SLOT of convolution tail and state-space state, which no table reaches and
+# the slot's index does (modeling.MambaMixer).  Its host-side facts are
+# pushed in the same way: ``valid_len`` (how many of the call's positions
+# are real for each row: in a decode step 1 for a decoding row and 0 for a
+# row that rides along, so the step holds that row's state; in a chunk the
+# real tokens, so padding never enters the state) and ``state_row`` (the slot
+# a chunk works for).  A model without such layers has no such leaves, and
+# its programs are what they were.
 # ---------------------------------------------------------------------------
 
 
@@ -204,11 +215,14 @@ def _map_cache_index(cache, fn):
 
 def init_paged_cache(model: CausalLM, num_slots: int, num_pages: int,
                      page_len: int, pages_per_slot: int):
-    """Zero paged KV cache: every attention layer gets page pools
-    ``[num_pages, page_len, h*d]`` (page 0 = the pinned null page), a
-    per-slot index vector ``[S]`` and a block table ``[S, pages_per_slot]``
-    of page ids (0 = unreached/null).  This is the persistent donated cache
-    of the engine."""
+    """Zero paged cache, asked of each layer by its kind: an attention
+    layer gets page pools ``[num_pages, page_len, kv_heads*d]`` (page 0 = the
+    pinned null page), a per-slot index vector ``[S]`` and a block table
+    ``[S, pages_per_slot]`` of page ids (0 = unreached/null); a Mamba layer
+    gets per-slot ``conv_state [S, (d_conv-1)*d_inner]`` and ``ssm_state [S,
+    d_state, d_inner]`` (as its module lays them out), the index vector, and
+    ``state_row`` / ``valid_len`` ``[S]``.  This is the persistent donated
+    cache of the engine."""
     dmodel = CausalLM(LMConfig.from_dict(
         {**model.config.to_dict(), "max_seq_len": page_len}))
     base = init_cache(dmodel, num_slots)
@@ -228,11 +242,30 @@ def init_paged_cache(model: CausalLM, num_slots: int, num_pages: int,
                     "block_table": jnp.zeros(
                         (num_slots, pages_per_slot), jnp.int32),
                 }
+            elif "ssm_state" in v:
+                rows = jnp.zeros((num_slots,), jnp.int32)
+                out[k] = {
+                    "conv_state": v["conv_state"],
+                    "ssm_state": v["ssm_state"],
+                    "cache_index": rows, "state_row": rows, "valid_len": rows,
+                }
             else:
                 out[k] = rebuild(v)
         return out
 
     return rebuild(base)
+
+
+def recurrent_state_bytes(cache) -> int:
+    """Bytes of per-slot state the cache holds that is not pages (the Mamba
+    layers' convolution tails and states)."""
+    total = 0
+    for k, v in cache.items():
+        if isinstance(v, dict):
+            total += recurrent_state_bytes(v)
+        elif k in ("conv_state", "ssm_state"):
+            total += v.size * v.dtype.itemsize
+    return total
 
 
 def _apply_paged(model: CausalLM, slot_len: int):
@@ -274,6 +307,11 @@ def make_paged_decode_logits_body(model: CausalLM, slot_len: int):
         cache = _map_cache_leaf(
             cache, "block_table",
             lambda _: block_table.astype(jnp.int32))
+        # a row that decodes is past its prompt; every other row (free, or
+        # mid-prefill with its chunks building its state) sits at position 0
+        # and the step must hold whatever state it has
+        cache = _map_cache_leaf(
+            cache, "valid_len", lambda _: (pos > 0).astype(jnp.int32))
         cache, hidden, rows = apply(params, cache, tok[:, None], pos[:, None])
         h = hidden[:, -1].astype(jnp.float32)
         with jax.named_scope("lm_head"):
@@ -385,16 +423,32 @@ def make_lm_step_feed_fns():
 
 def make_prefill_chunk_logits_body(model: CausalLM, page_len: int,
                                    slot_len: int):
-    """``fn(params, cache, ids, p0, last_local, table_row) -> (cache',
-    h_last, logits)``: one prefill chunk up to the head — ``h_last [D]`` the
-    float32 hidden state at ``last_local``, ``logits [V]`` float32 there.
-    :func:`make_prefill_chunk_body` samples from it (see
-    :func:`make_paged_decode_logits_body`)."""
+    """``fn(params, cache, ids, p0, last_local, table_row, slot=None) ->
+    (cache', h_last, logits)``: one prefill chunk up to the head — ``h_last
+    [D]`` the float32 hidden state at ``last_local``, ``logits [V]`` float32
+    there.  :func:`make_prefill_chunk_body` samples from it (see
+    :func:`make_paged_decode_logits_body`).  ``slot``: the row of the
+    per-slot state the chunk works for; a model with recurrent layers needs
+    it, any other ignores it."""
     cfg = model.config
     apply = _apply_paged(model, slot_len)
 
-    def logits_chunk(params, cache, ids, p0, last_local, table_row):
+    def logits_chunk(params, cache, ids, p0, last_local, table_row,
+                     slot=None):
         p0 = p0.astype(jnp.int32)
+        if cfg.has_recurrent_layers:
+            if slot is None:
+                raise ValueError(
+                    "a model with recurrent layers keeps state a slot: the "
+                    "chunk program needs slot=")
+            # the real positions of a chunk end at last_local (a full
+            # chunk's is its last): padding past it must not enter the state
+            cache = _map_cache_leaf(
+                cache, "valid_len", lambda v: jnp.full(
+                    v.shape, last_local.astype(jnp.int32) + 1, jnp.int32))
+            cache = _map_cache_leaf(
+                cache, "state_row", lambda v: jnp.full(
+                    v.shape, jnp.asarray(slot).astype(jnp.int32), jnp.int32))
         # leaf shapes must stay [S]/[S, npg] across chunk and decode calls
         # (shape-stable donation); only row 0 is consulted at b=1
         cache = _map_cache_index(
@@ -429,18 +483,19 @@ def make_prefill_chunk_body(model: CausalLM, page_len: int, slot_len: int,
     logits_chunk = make_prefill_chunk_logits_body(model, page_len, slot_len)
 
     def prefill_chunk(params, cache, ids, p0, last_local, table_row,
-                      bank_a=None, bank_b=None, adapter_id=None):
+                      bank_a=None, bank_b=None, adapter_id=None, slot=None):
         cache, h_last, logits = logits_chunk(params, cache, ids, p0,
-                                             last_local, table_row)
+                                             last_local, table_row, slot)
         if adapters:
             logits = logits + (h_last @ bank_a[adapter_id]) @ bank_b[adapter_id]
         tok = jnp.argmax(logits).astype(jnp.int32)
         return cache, tok
 
     if not adapters:
-        def base_chunk(params, cache, ids, p0, last_local, table_row):
+        def base_chunk(params, cache, ids, p0, last_local, table_row,
+                       slot=None):
             return prefill_chunk(params, cache, ids, p0, last_local,
-                                 table_row)
+                                 table_row, slot=slot)
         return base_chunk
     return prefill_chunk
 
@@ -474,7 +529,8 @@ def make_lm_prefill_chunk_fn(model: CausalLM, page_len: int, slot_len: int,
 
 def page_copy_body(cache, dst, src):
     """The UNJITTED copy-on-write body: copy page ``src`` onto page ``dst``
-    in every layer's K and V pools; index and table leaves pass through.
+    in every attention layer's K and V pools; index and table leaves, and a
+    Mamba layer's per-slot state, pass through.
     Wrapped by :func:`make_page_copy_fn` (single chip) and the sharded
     factory (engine/dist/sharded.py)."""
     dst = dst.astype(jnp.int32) if hasattr(dst, "astype") else dst

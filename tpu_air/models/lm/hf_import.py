@@ -1,5 +1,5 @@
-"""Published (Hugging Face) OLMoE configuration and weights -> ``LMConfig``
-and this framework's ``CausalLM`` parameter tree.
+"""Published (Hugging Face) OLMoE and Jamba configurations and weights ->
+``LMConfig`` and this framework's ``CausalLM`` parameter tree.
 
 Beside T5's importer (models/t5/hf_import.py).  Pure numpy: the converter
 only transposes, permutes and stacks, so it works on any element type (the
@@ -54,15 +54,65 @@ HF_FIXED = {
 }
 
 
-def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
-                      **overrides: Any) -> LMConfig:
-    """``LMConfig`` of a published ``olmoe`` ``config.json`` (a dict).
-    Refuses a configuration whose layer this framework does not compute."""
-    for key, want in HF_FIXED.items():
+#: ``model_type: jamba`` (AI21-Jamba2-3B): the keys mapped, and the values
+#: that must hold.  No rope key exists in this family: attention layers take
+#: no position encoding (``rope_theta`` None), the Mamba layers carry order.
+JAMBA_KEYS = {
+    "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "intermediate_size": "d_ff",
+    "vocab_size": "vocab_size",
+    "rms_norm_eps": "rmsnorm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+    "attn_layer_period": "attn_layer_period",
+    "attn_layer_offset": "attn_layer_offset",
+    "mamba_expand": "mamba_expand",
+    "mamba_d_state": "mamba_d_state",
+    "mamba_d_conv": "mamba_d_conv",
+    "mamba_dt_rank": "mamba_dt_rank",
+}
+JAMBA_FIXED = {
+    "num_experts": 1,           # every feed-forward is the one SwiGLU
+    "sliding_window": None,
+    "mamba_conv_bias": True,
+    "mamba_proj_bias": False,
+    "hidden_act": "silu",
+}
+
+
+def _check_fixed(hf: Dict[str, Any], fixed: Dict[str, Any]) -> None:
+    for key, want in fixed.items():
         if hf.get(key, want) != want:
             raise ValueError(
                 f"published {key}={hf.get(key)!r}: only {want!r} is "
                 "implemented (models/lm/modeling.py)")
+
+
+def _jamba_config_from_hf(hf: Dict[str, Any], dtype: str,
+                          **overrides: Any) -> LMConfig:
+    _check_fixed(hf, JAMBA_FIXED)
+    fields = {ours: hf[theirs] for theirs, ours in JAMBA_KEYS.items()}
+    fields.update(
+        head_dim=hf["hidden_size"] // hf["num_attention_heads"],
+        rope_theta=None,
+        max_seq_len=hf.get("max_position_embeddings", 2048),
+        pad_token_id=hf.get("pad_token_id") or 0,
+        eos_token_id=hf.get("eos_token_id"),
+        dtype=dtype)
+    fields.update(overrides)
+    return LMConfig(**fields)
+
+
+def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
+                      **overrides: Any) -> LMConfig:
+    """``LMConfig`` of a published ``olmoe`` or ``jamba`` ``config.json`` (a
+    dict).  Refuses a configuration whose layer this framework does not
+    compute."""
+    if hf.get("model_type") == "jamba":
+        return _jamba_config_from_hf(hf, dtype, **overrides)
+    _check_fixed(hf, HF_FIXED)
     kv = hf.get("num_key_value_heads", hf["num_attention_heads"])
     if kv != hf["num_attention_heads"]:
         raise ValueError(
@@ -135,4 +185,58 @@ def convert_olmoe_state_dict(get: Callable[[str], Any],
         params["lm_head"] = {"kernel": _t(get("lm_head.weight"))}
     for i in range(config.n_layers):
         params[f"layer_{i}"] = convert_olmoe_layer(get, i, config)
+    return params
+
+
+def convert_jamba_layer(get: Callable[[str], Any], i: int,
+                        config: LMConfig) -> Dict[str, Any]:
+    """Layer ``i`` of the tree from the published ``jamba`` names: pure
+    renaming and transposition (there is no rope, so no column order to
+    keep); the depthwise convolution ``[channels, 1, width]`` becomes
+    ``[width, channels]``."""
+    pre = f"model.layers.{i}."
+    kernel = lambda name: {"kernel": _t(get(pre + name + ".weight"))}  # noqa: E731
+    weight = lambda name: {"weight": np.asarray(  # noqa: E731
+        get(pre + name + ".weight"))}
+    layer = {
+        "mlp_norm": weight("pre_ff_layernorm"),
+        "mlp": {w: kernel(f"feed_forward.{w}_proj")
+                for w in ("gate", "up", "down")},
+    }
+    if config.layer_kinds()[i] == "attention":
+        layer["attn_norm"] = weight("input_layernorm")
+        layer["attn"] = {w: kernel(f"self_attn.{w}_proj") for w in "qkvo"}
+        return layer
+    layer["mamba_norm"] = weight("input_layernorm")
+    layer["mamba"] = {
+        "in_proj": kernel("mamba.in_proj"),
+        "conv": {"kernel": _t(np.asarray(
+            get(pre + "mamba.conv1d.weight"))[:, 0, :]),
+            "bias": np.asarray(get(pre + "mamba.conv1d.bias"))},
+        "x_proj": kernel("mamba.x_proj"),
+        "dt_proj": {**kernel("mamba.dt_proj"),
+                    "bias": np.asarray(get(pre + "mamba.dt_proj.bias"))},
+        "A_log": np.asarray(get(pre + "mamba.A_log")),
+        "D": np.asarray(get(pre + "mamba.D")),
+        "out_proj": kernel("mamba.out_proj"),
+        "dt_norm": weight("mamba.dt_layernorm"),
+        "b_norm": weight("mamba.b_layernorm"),
+        "c_norm": weight("mamba.c_layernorm"),
+    }
+    return layer
+
+
+def convert_jamba_state_dict(get: Callable[[str], Any],
+                             config: LMConfig) -> Dict[str, Any]:
+    """The whole ``CausalLM`` parameter tree from a published ``jamba`` state
+    dict (tied embeddings: no head tensor), given as ``get(name)``."""
+    params: Dict[str, Any] = {
+        "embedding": np.asarray(get("model.embed_tokens.weight")),
+        "final_norm": {"weight": np.asarray(
+            get("model.final_layernorm.weight"))},
+    }
+    if not config.tie_embeddings:
+        params["lm_head"] = {"kernel": _t(get("lm_head.weight"))}
+    for i in range(config.n_layers):
+        params[f"layer_{i}"] = convert_jamba_layer(get, i, config)
     return params

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from tpu_air.ops import ssm
 from tpu_air.ops.decode_attention import flat_decode_attention, gather_pages
 
 from .config import LMConfig
@@ -56,8 +57,17 @@ def rope(x: Array, positions: Array, theta: float) -> Array:
     return jnp.stack([y1, y2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def _repeat_kv(x: Array, n_heads: int) -> Array:
+    """(B,G,L,D) K or V with fewer heads than the queries, each repeated for
+    the query heads it serves (a no-op where every head has its own)."""
+    g = x.shape[1]
+    return x if g == n_heads else jnp.repeat(x, n_heads // g, axis=1)
+
+
 def _dense_causal_attention(q, k, v, scale, q_offset=0):
-    """(B,H,L,D) einsum attention with causal mask; baseline path."""
+    """(B,H,L,D) einsum attention with causal mask; baseline path.  k, v may
+    carry fewer heads than q (grouped K/V)."""
+    k, v = _repeat_kv(k, q.shape[1]), _repeat_kv(v, q.shape[1])
     with jax.named_scope("attn_scores"):
         s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                        k.astype(jnp.float32))
@@ -81,22 +91,24 @@ class CausalSelfAttention(nn.Module):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         b, l, _ = x.shape
-        h, d = cfg.n_heads, cfg.head_dim
+        h, g, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
         def proj(name, out):
             return nn.Dense(out, use_bias=False, dtype=dtype,
                             kernel_init=nn.initializers.normal(0.02), name=name)
 
-        q, k = proj("q", h * d)(x), proj("k", h * d)(x)
+        q, k = proj("q", h * d)(x), proj("k", g * d)(x)
         if cfg.qk_norm:
             # over the whole h*d projection, before the split into heads
             q = RMSNorm(cfg.rmsnorm_eps, dtype, name="q_norm")(q)
             k = RMSNorm(cfg.rmsnorm_eps, dtype, name="k_norm")(k)
         q = q.reshape(b, l, h, d).transpose(0, 2, 1, 3)
-        k = k.reshape(b, l, h, d).transpose(0, 2, 1, 3)
-        v = proj("v", h * d)(x).reshape(b, l, h, d).transpose(0, 2, 1, 3)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        k = k.reshape(b, l, g, d).transpose(0, 2, 1, 3)
+        v = proj("v", g * d)(x).reshape(b, l, g, d).transpose(0, 2, 1, 3)
+        if cfg.rope_theta is not None:
+            # None: the family has no position encoding (LMConfig)
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         scale = 1.0 / (d ** 0.5)
 
         if decode:
@@ -114,15 +126,15 @@ class CausalSelfAttention(nn.Module):
             max_len = cfg.max_seq_len
             ck = self.variable(
                 "cache", "cached_key",
-                lambda: jnp.zeros((b, max_len, h * d), dtype))
+                lambda: jnp.zeros((b, max_len, g * d), dtype))
             cv = self.variable(
                 "cache", "cached_value",
-                lambda: jnp.zeros((b, max_len, h * d), dtype))
+                lambda: jnp.zeros((b, max_len, g * d), dtype))
             idx = self.variable(
                 "cache", "cache_index", lambda: jnp.array(0, jnp.int32))
             i = idx.value
-            kflat = k.transpose(0, 2, 1, 3).reshape(b, l, h * d)
-            vflat = v.transpose(0, 2, 1, 3).reshape(b, l, h * d)
+            kflat = k.transpose(0, 2, 1, 3).reshape(b, l, g * d)
+            vflat = v.transpose(0, 2, 1, 3).reshape(b, l, g * d)
             if self.has_variable("cache", "block_table"):
                 # PAGED engine cache (engine/kvpool/): cached_key/value are
                 # page POOLS [P, page_len, h*d] shared by every slot, and
@@ -156,7 +168,7 @@ class CausalSelfAttention(nn.Module):
                         q.transpose(0, 2, 1, 3) * scale,
                         gather_pages(ck.value, table),
                         gather_pages(cv.value, table),
-                        None, kvm, None, None, h, dtype)
+                        None, kvm, None, None, h, dtype, g)
                     return proj("o", cfg.d_model)(o4.reshape(b, 1, h * d))
                 # chunked prefill: ONE slot (b == 1) processes one page-
                 # aligned chunk of its prompt at positions p0 .. p0+l-1.
@@ -180,8 +192,8 @@ class CausalSelfAttention(nn.Module):
                 idx.value = i + l
                 kg = gather_pages(ck.value, table[:1])
                 vg = gather_pages(cv.value, table[:1])
-                k4 = kg.reshape(1, lg, h, d).transpose(0, 2, 1, 3)
-                v4 = vg.reshape(1, lg, h, d).transpose(0, 2, 1, 3)
+                k4 = kg.reshape(1, lg, g, d).transpose(0, 2, 1, 3)
+                v4 = vg.reshape(1, lg, g, d).transpose(0, 2, 1, 3)
                 o = _dense_causal_attention(q, k4, v4, scale, q_offset=p0)
                 o = o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
                 return proj("o", cfg.d_model)(o)
@@ -197,18 +209,19 @@ class CausalSelfAttention(nn.Module):
                     (jnp.arange(max_len) <= i)[None], (b, max_len))
                 o4 = flat_decode_attention(
                     q.transpose(0, 2, 1, 3) * scale, ck.value, cv.value,
-                    None, kvm, None, None, h, dtype)
+                    None, kvm, None, None, h, dtype, g)
                 return proj("o", cfg.d_model)(o4.reshape(b, 1, h * d))
             # prefill (and any multi-token window): dense attention over
             # the cache with the query offset at the index — a one-time
             # 4-D view per generate call.  Future slots are zeros but
             # kj > qi masks them out.
-            ck4 = ck.value.reshape(b, max_len, h, d).transpose(0, 2, 1, 3)
-            cv4 = cv.value.reshape(b, max_len, h, d).transpose(0, 2, 1, 3)
+            ck4 = ck.value.reshape(b, max_len, g, d).transpose(0, 2, 1, 3)
+            cv4 = cv.value.reshape(b, max_len, g, d).transpose(0, 2, 1, 3)
             o = _dense_causal_attention(q, ck4, cv4, scale, q_offset=i)
             o = o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
             return proj("o", cfg.d_model)(o)
 
+        k, v = _repeat_kv(k, h), _repeat_kv(v, h)  # the kernels want h heads
         impl = cfg.attention
         if impl == "auto":
             # trace-time shape dispatch: the einsum path wins short
@@ -312,8 +325,160 @@ def expert_assignments(intermediates) -> Array:
         if any(getattr(p, "key", None) == "expert_rows" for p in path)])
 
 
+class DepthwiseConv(nn.Module):
+    """The Mamba mixer's causal depthwise convolution over positions:
+    ``kernel [width, channels]`` (the last tap multiplies the current
+    position) and ``bias``; the caller carries ``tail [b, (width-1) *
+    channels]``, the ``width - 1`` inputs before the call, flat as stored
+    (``ops/ssm.py``).  Returns the outputs and the tail after the call's
+    ``valid_len [b]`` real positions."""
+
+    width: int
+
+    @nn.compact
+    def __call__(self, x: Array, tail: Array, valid_len: Array):
+        b, l, c = x.shape
+        # fan-in of a channel is the width (the published Conv1d default)
+        bound = self.width ** -0.5
+        uniform = lambda key, shape, dt: jax.random.uniform(  # noqa: E731
+            key, shape, dt, -bound, bound)
+        kernel = self.param("kernel", uniform, (self.width, c), jnp.float32)
+        bias = self.param("bias", uniform, (c,), jnp.float32)
+        with jax.named_scope("ssm_conv"):
+            if l == 1:
+                y, tail = ssm.causal_conv_step(x[:, 0], tail, kernel, bias,
+                                               valid_len > 0)
+                return y[:, None], tail
+            y, tail = ssm.causal_conv_chunk(
+                x, tail.reshape(b, self.width - 1, c), kernel, bias, valid_len)
+            return y, tail.reshape(b, -1)
+
+
+def _init_dt_bias(key, shape, dtype=jnp.float32):
+    """Mamba's own: ``softplus(bias)`` log-uniform in [1e-3, 1e-1]."""
+    dt0 = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
+                  * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
+    return (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype)
+
+
+class MambaMixer(nn.Module):
+    """Mamba-1 selective state-space mixer, with Jamba's three inner norms
+    (over the step size's low-rank input and over B and C)::
+
+        [u, z] = in_proj(h);  u = silu(conv(u))
+        [dt, B, C] = x_proj(u);  dt, B, C = dt_norm(dt), b_norm(B), c_norm(C)
+        dt = softplus(dt_proj(dt));  A = -exp(A_log)
+        s_t = exp(dt_t A) s_{t-1} + dt_t B_t u_t;  y_t = C_t . s_t + D u_t
+        out = out_proj(y * silu(z))
+
+    What it keeps between calls is a convolution tail (the last ``d_conv -
+    1`` inputs of the convolution) and the float32 state, a row a sequence:
+    ``conv_state [S, (d_conv-1) * d_inner]`` (flat: whole tiles on the chip)
+    and ``ssm_state [S, d_state, d_inner]`` (state-major, ``ops/ssm.py``) in
+    the ``cache`` collection.  Three callers, told apart by what the cache
+    holds:
+
+    * no cache (``decode=False``): the whole sequence from a zero state;
+    * a plain cache (``generate``): row ``b`` of the state is sequence ``b``;
+      a multi-token call is the prompt, a one-token call a decode step;
+    * the engine's cache (``state_row`` present; models/lm/generate.py):
+      ``valid_len [S]`` says how many of the call's positions are real for
+      each row and ``cache_index [S]`` where the call starts.  A one-token
+      call is the pool's decode step over ALL rows, and a row with
+      ``valid_len`` 0 (free, or mid-prefill: its chunks are building its
+      state) keeps its state bit for bit.  A longer call is one chunk of the
+      prompt of row ``state_row[0]``: it starts from zeros when it is the
+      prompt's first (``cache_index`` 0: a slot's last tenant left its state
+      behind), and its padded positions neither advance the state nor enter
+      the tail."""
+
+    config: LMConfig
+
+    @nn.compact
+    def __call__(self, x: Array, decode: bool = False) -> Array:
+        cfg = self.config
+        dtype = jnp.dtype(cfg.dtype)
+        b, l, _ = x.shape
+        c, n, k, r = (cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv,
+                      cfg.mamba_dt_rank)
+        dense = lambda name, out, **kw: nn.Dense(  # noqa: E731
+            out, use_bias=False, dtype=dtype,
+            kernel_init=nn.initializers.normal(0.02), name=name, **kw)
+        a_log = self.param(
+            "A_log", lambda key, shape, dt: jnp.log(jnp.broadcast_to(
+                jnp.arange(1, n + 1, dtype=dt), shape)), (c, n), jnp.float32)
+        skip = self.param("D", nn.initializers.ones, (c,), jnp.float32)
+        A = -jnp.exp(a_log.astype(jnp.float32)).T                  # [n, c]
+
+        tail = jnp.zeros((b, (k - 1) * c), dtype)
+        state = jnp.zeros((b, n, c), jnp.float32)
+        valid = jnp.full((b,), l, jnp.int32)
+        engine = decode and self.has_variable("cache", "state_row")
+        if decode:
+            cs = self.variable("cache", "conv_state",
+                               lambda: jnp.zeros((b, (k - 1) * c), dtype))
+            ss = self.variable("cache", "ssm_state",
+                               lambda: jnp.zeros((b, n, c), jnp.float32))
+            tail, state = cs.value, ss.value
+        if engine:
+            index = self.get_variable("cache", "cache_index")
+            valid = self.get_variable("cache", "valid_len")
+            if l > 1:
+                # one chunk of one row's prompt (b == 1)
+                row = self.get_variable("cache", "state_row")[0]
+                fresh = index[0] == 0
+                tail = jnp.where(fresh, 0, jax.lax.dynamic_slice_in_dim(
+                    tail, row, 1, axis=0))
+                state = jnp.where(fresh, 0, jax.lax.dynamic_slice_in_dim(
+                    state, row, 1, axis=0))
+                valid = valid[:1]
+
+        uz = dense("in_proj", 2 * c)(x)
+        u, z = uz[..., :c], uz[..., c:]
+        u, new_tail = DepthwiseConv(k, name="conv")(u, tail, valid)
+        u = nn.silu(u).astype(dtype)
+        dbc = dense("x_proj", r + 2 * n)(u)
+        dt = RMSNorm(cfg.rmsnorm_eps, dtype, name="dt_norm")(dbc[..., :r])
+        B = RMSNorm(cfg.rmsnorm_eps, dtype, name="b_norm")(dbc[..., r:r + n])
+        C = RMSNorm(cfg.rmsnorm_eps, dtype, name="c_norm")(dbc[..., r + n:])
+        dt = nn.softplus(nn.Dense(
+            c, use_bias=True, dtype=jnp.float32, name="dt_proj",
+            kernel_init=lambda key, shape, dt_: jax.random.uniform(
+                key, shape, dt_, -r ** -0.5, r ** -0.5),
+            bias_init=_init_dt_bias)(dt.astype(jnp.float32)))
+        if l == 1 and decode:
+            with jax.named_scope("ssm_state_update"):
+                y, new_state = ssm.selective_state_update(
+                    u[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], skip, state,
+                    valid > 0)
+                y = y[:, None]
+        else:
+            with jax.named_scope("ssm_scan"):
+                # bounded pieces: the scan makes [b, piece, n, c] float32
+                # terms for all of a piece's positions at once
+                ys, new_state, piece = [], state, 256
+                for p0 in range(0, l, piece):
+                    sl = slice(p0, p0 + piece)
+                    y, new_state = ssm.selective_scan_chunk(
+                        u[:, sl], dt[:, sl], A, B[:, sl], C[:, sl], skip,
+                        new_state, jnp.clip(valid - p0, 0, piece))
+                    ys.append(y)
+                y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+        if decode:
+            if engine and l > 1:
+                cs.value = jax.lax.dynamic_update_slice_in_dim(
+                    cs.value, new_tail, row, axis=0)
+                ss.value = jax.lax.dynamic_update_slice_in_dim(
+                    ss.value, new_state, row, axis=0)
+            else:
+                cs.value, ss.value = new_tail, new_state
+        return dense("out_proj", cfg.d_model)(
+            (y * nn.silu(z.astype(jnp.float32))).astype(dtype))
+
+
 class Block(nn.Module):
     config: LMConfig
+    kind: str = "attention"   # LMConfig.layer_kinds()
 
     @nn.compact
     def __call__(self, x: Array, positions: Array, deterministic: bool = True,
@@ -321,10 +486,15 @@ class Block(nn.Module):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
-        x = x + drop(CausalSelfAttention(cfg, name="attn")(
-            RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(x), positions,
-            decode=decode,
-        ))
+        if self.kind == "mamba":
+            x = x + drop(MambaMixer(cfg, name="mamba")(
+                RMSNorm(cfg.rmsnorm_eps, dtype, name="mamba_norm")(x),
+                decode=decode))
+        else:
+            x = x + drop(CausalSelfAttention(cfg, name="attn")(
+                RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(x),
+                positions, decode=decode,
+            ))
         # the feed-forward kind follows from the configuration's numbers
         ff = (SparseExperts(cfg, name="moe") if cfg.num_experts
               else SwiGLU(cfg, name="mlp"))
@@ -360,9 +530,9 @@ class CausalLM(nn.Module):
             jnp.float32,
         )
         x = embed[input_ids].astype(dtype)
-        for i in range(cfg.n_layers):
-            x = Block(cfg, name=f"layer_{i}")(x, positions, deterministic,
-                                              decode=decode)
+        for i, kind in enumerate(cfg.layer_kinds()):
+            x = Block(cfg, kind, name=f"layer_{i}")(
+                x, positions, deterministic, decode=decode)
         x = RMSNorm(cfg.rmsnorm_eps, dtype, name="final_norm")(x)
         if return_hidden:
             # pre-head hidden states: pair with head_weight() +

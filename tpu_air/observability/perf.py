@@ -379,6 +379,15 @@ class LMCostModel:
     routing, ``E * (1 - (1 - 1/E)**n)`` experts a layer (63.98 of 64 at a
     128-token chunk of top-8), an upper estimate when routing is skewed.
 
+    Layer kinds (``config.layer_kinds()``): only the ``A`` attention layers
+    have q, k, v, o (k and v at ``G = n_kv_heads`` heads: ``2*D*H*Dh +
+    2*D*G*Dh``), K/V bytes and attention flops; each of the ``M`` Mamba
+    layers has its four projections instead (``D*2c + c*(r + 2n) + r*c +
+    c*D`` at ``c = d_inner``, ``n`` states, ``r`` the step size's rank) and
+    keeps ``c*n*4 + (k-1)*c*b`` bytes of state a row, which a decode step
+    reads and writes for EVERY row and a chunk for one.  The recurrence's own
+    arithmetic (``~7*c*n`` a token a layer) is counted; it is vector work.
+
     Norms, rotary embeddings and softmax are omitted (≪1% of the matmul
     budget at any real geometry); the model is deliberately closed-form so
     identical claims can be recomputed anywhere (arXiv:2204.06514 §4).
@@ -397,11 +406,35 @@ class LMCostModel:
             getattr(config, "num_experts_per_tok", 0) or 0)
         self.dtype_bytes = _DTYPE_BYTES.get(
             str(getattr(config, "dtype", "float32")), 4)
+        self.n_kv_heads = int(getattr(config, "n_kv_heads", None)
+                              or self.n_heads)
+        kinds = (config.layer_kinds() if hasattr(config, "layer_kinds")
+                 else ["attention"] * self.n_layers)
+        self.n_mamba_layers = kinds.count("mamba")
+        self.n_attn_layers = self.n_layers - self.n_mamba_layers
+        self.d_inner = int(getattr(config, "mamba_d_inner", 0) or 0)
+        self.d_state = int(getattr(config, "mamba_d_state", 0) or 0)
+        self.d_conv = int(getattr(config, "mamba_d_conv", 0) or 0)
+        self.dt_rank = int(getattr(config, "mamba_dt_rank", 0) or 0)
 
     # -- derived geometry ----------------------------------------------------
     @property
     def _attn_params(self) -> int:
-        return 4 * self.d_model * self.n_heads * self.head_dim
+        return 2 * self.d_model * self.head_dim * (
+            self.n_heads + self.n_kv_heads)
+
+    @property
+    def _mamba_params(self) -> int:
+        c, n, r = self.d_inner, self.d_state, self.dt_rank
+        return (self.d_model * 2 * c + c * (r + 2 * n) + r * c
+                + c * self.d_model)
+
+    @property
+    def state_bytes_per_row(self) -> int:
+        """Recurrent state one sequence keeps over the Mamba layers: the
+        float32 state and the convolution tail in the model's dtype."""
+        return self.n_mamba_layers * self.d_inner * (
+            self.d_state * 4 + (self.d_conv - 1) * self.dtype_bytes)
 
     @property
     def _expert_params(self) -> int:
@@ -415,7 +448,9 @@ class LMCostModel:
         ff = self._expert_params
         if self.num_experts:
             ff = experts * ff + self.d_model * self.num_experts
-        return self.n_layers * (self._attn_params + ff)
+        return (self.n_attn_layers * self._attn_params
+                + self.n_mamba_layers * self._mamba_params
+                + self.n_layers * ff)
 
     @property
     def matmul_params(self) -> int:
@@ -459,17 +494,18 @@ class LMCostModel:
 
     @property
     def linear_flops_per_token(self) -> float:
-        return 2.0 * (self.active_matmul_params
-                      + self.d_model * self.vocab_size)
+        return (2.0 * (self.active_matmul_params
+                       + self.d_model * self.vocab_size)
+                + self.n_mamba_layers * 7.0 * self.d_inner * self.d_state)
 
     @property
     def kv_bytes_per_position(self) -> float:
-        return self.n_layers * 2 * self.n_heads * self.head_dim \
+        return self.n_attn_layers * 2 * self.n_kv_heads * self.head_dim \
             * self.dtype_bytes
 
     def attention_flops(self, attended_positions: float) -> float:
         """Per ONE token attending over ``attended_positions``."""
-        return self.n_layers * 4.0 * self.n_heads * self.head_dim \
+        return self.n_attn_layers * 4.0 * self.n_heads * self.head_dim \
             * attended_positions
 
     # -- program costs -------------------------------------------------------
@@ -482,7 +518,8 @@ class LMCostModel:
                         + self.attention_flops(attended))
         hbm = (self.streamed_param_bytes(rows)
                + rows * attended * self.kv_bytes_per_position   # KV read
-               + rows * self.kv_bytes_per_position)             # KV write
+               + rows * self.kv_bytes_per_position              # KV write
+               + 2 * rows * self.state_bytes_per_row)           # state r+w
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=rows)
 
     def prefill_chunk_cost(self, chunk_len: int,
@@ -497,7 +534,8 @@ class LMCostModel:
                  + self.attention_flops(attended_sum))
         hbm = (self.streamed_param_bytes(c)
                + (start_pos + c) * self.kv_bytes_per_position   # prefix read
-               + c * self.kv_bytes_per_position)                # KV write
+               + c * self.kv_bytes_per_position                 # KV write
+               + 2 * self.state_bytes_per_row)                  # one row
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=c)
 
     def train_step_cost(self, batch: int, seq_len: int) -> ProgramCost:

@@ -104,44 +104,58 @@ def gather_pages(pool: jax.Array, block_table: jax.Array) -> jax.Array:
 
 @jax.named_scope("decode_attention")
 def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
-                           num_heads, dtype):
-    """Single-token attention over FLAT cache slabs ``[b, L, h*d]``.
-    All heads ride ONE batched MXU matmul per contraction via block-
-    diagonal expansion (selector ``E``), so the slab streams from HBM
-    exactly once in its unpadded storage layout, under any loop or program
-    boundary (the module docstring has the chip's numbers).  int8 scales
-    fold into the math (cross per-channel -> q / context; self
-    per-position -> scores / probs) — the dequantized slab is never
-    materialized.
+                           num_heads, dtype, num_kv_heads=None):
+    """Single-token attention over FLAT cache slabs ``[b, L, g*d]``, ``g =
+    num_kv_heads`` K/V heads (default ``num_heads``) serving ``h / g`` query
+    heads each.  All heads ride ONE batched MXU matmul per contraction via
+    a selector that lets a query head see the features of ITS K/V head
+    (block-diagonal at ``g == h``; dense with one K/V head: every query
+    head reads the whole row and none of the products is wasted on zeros),
+    so the slab streams from HBM exactly once in its unpadded storage
+    layout, under any loop or program boundary (the module docstring has
+    the chip's numbers).  int8 scales fold into the math (per-channel -> q /
+    context; per-position -> scores / probs) — the dequantized slab is
+    never materialized.
 
     q [b, 1, h, d]; bias_hl additive f32 [h, L] (carries causal masking);
-    kv_mask [b, L]; k_scale/v_scale None or [b, 1, h*d] (per-channel) or
-    [b, L, h] (per-position).  Returns [b, 1, h, d] in model dtype."""
-    b, L, hd = kf.shape
-    h, d = num_heads, hd // num_heads
-    qv = q.reshape(b, hd).astype(jnp.float32)
+    kv_mask [b, L]; k_scale/v_scale None or [b, 1, g*d] (per-channel) or
+    [b, L, g] (per-position).  Returns [b, 1, h, d] in model dtype."""
+    b, L, gd = kf.shape
+    h = num_heads
+    g = num_kv_heads or h
+    d, r = gd // g, h // g
     k_chan = k_scale is not None and k_scale.shape[1] == 1
     v_chan = v_scale is not None and v_scale.shape[1] == 1
+    # sel[f, hq]: feature f of the slab belongs to query head hq's K/V head
+    sel = jnp.arange(gd)[:, None] // d == jnp.arange(h)[None, :] // r
+    # qexp[b, f, hq] = q[b, hq, f % d] where sel: a K/V head's features, in
+    # the slab's order, against the r query heads it serves
+    qt = jnp.swapaxes(q.reshape(b, g, r, d).astype(jnp.float32), 2, 3)
+    qt = qt.reshape(b, gd, 1, r)
     if k_chan:
-        qv = qv * k_scale[:, 0, :]
-    sel = jnp.arange(hd)[:, None] // d == jnp.arange(h)[None, :]  # [hd, h]
-    qexp = jnp.where(sel[None], qv[:, :, None], 0.0).astype(dtype)
+        qt = qt * k_scale[:, 0, :, None, None]
+    qexp = jnp.where(
+        sel[None], jnp.broadcast_to(qt, (b, gd, g, r)).reshape(b, gd, h),
+        0.0).astype(dtype)
     s = jnp.einsum("blf,bfh->blh", kf.astype(dtype), qexp,
                    preferred_element_type=jnp.float32)
     if k_scale is not None and not k_chan:
-        s = s * k_scale
+        s = s * jnp.repeat(k_scale, r, axis=2)
     if bias_hl is not None:
         s = s + bias_hl.T[None]
     if kv_mask is not None:
         s = s + jnp.where(kv_mask > 0, 0.0, _NEG_INF_DENSE)[:, :, None]
     p = jax.nn.softmax(s, axis=1)
     if v_scale is not None and not v_chan:
-        p = p * v_scale
+        p = p * jnp.repeat(v_scale, r, axis=2)
     ctx2 = jnp.einsum("blh,blf->bhf", p.astype(dtype), vf.astype(dtype),
-                      preferred_element_type=jnp.float32)
-    ctx = jnp.sum(jnp.where(sel.T[None], ctx2, 0.0), axis=1)  # [b, hd]
+                      preferred_element_type=jnp.float32)      # [b, h, g*d]
+    # a query head keeps its own K/V head's features: summed over the K/V
+    # heads' axis (the major one), what is left is [b, r, g*d]
+    ctx = jnp.where(sel.T[None], ctx2, 0.0).reshape(b, g, r, gd).sum(1)
     if v_chan:
-        ctx = ctx * v_scale[:, 0, :]
+        ctx = ctx * v_scale[:, 0, None, :]
+    ctx = jnp.swapaxes(ctx.reshape(b, r, g, d), 1, 2)          # [b, g, r, d]
     return ctx.reshape(b, 1, h, d).astype(dtype)
 
 
