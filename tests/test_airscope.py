@@ -179,6 +179,16 @@ def test_prefill_chunk_cost_hand_computed():
     assert c.tokens == 4
 
 
+def test_mixed_step_cost_streams_the_weights_once():
+    """A decode step and a prefill chunk in one program: the operations and
+    the per-sequence bytes of both, the parameters once."""
+    m = LMCostModel(_GEOM)
+    c = m.mixed_step_cost(rows=3, attended=10, chunk_len=4, start_pos=8)
+    assert c.flops == 11136 + 14976
+    assert c.hbm_bytes == 10368 + 8192 - 6144
+    assert c.tokens == 7
+
+
 def test_train_step_cost_hand_computed():
     m = LMCostModel(_GEOM)
     c = m.train_step_cost(batch=2, seq_len=3)

@@ -492,3 +492,153 @@ def test_hybrid_decode_step_keeps_its_state_in_place_on_v5e(v5e):
     text = compiled.as_text()
     assert "f32[128,5120,16]" not in text            # never channel-major
     assert not re.search(r"= \w+\[128,3,5120\]\S* copy\(", text)
+
+
+# -- the engine's mixed step (PR 42) ------------------------------------------
+
+def _weight_consumers(text):
+    """For every weight matrix among the compiled program's parameters (a
+    ``params`` leaf of two or more dimensions that a product streams: not the
+    Mamba mixer's ``A_log`` and convolution taps, which are elementwise
+    operands made once), the operations of the entry computation that read
+    it, followed through what only moves it: the compiler's prefetches of a
+    weight into fast memory in slices (``slice-start`` / ``slice-done`` /
+    ``ConcatBitcast``) and copies."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}\n")]
+    moving = {"copy", "copy-start", "copy-done", "bitcast", "slice-start",
+              "slice-done", "get-tuple-element", "ConcatBitcast"}
+    ops, users, weights = {}, {}, []
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*)", line)
+        call = m and re.search(r" ([a-z][\w\-]*)\((?=%|\d|\))", " " + m[2])
+        if not call:
+            continue
+        name, rest = m[1], m[2]
+        ops[name] = ("ConcatBitcast" if '"ConcatBitcast"' in rest
+                     else call[1])
+        if ops[name] == "parameter" and name.startswith("params__") \
+                and re.match(r"\w+\[\d+,", rest) \
+                and not re.search("A_log|conv____kernel", name):
+            weights.append(name)
+        args = re.split(r"\), \w+=", rest[call.end() - 1:])[0]
+        for operand in re.findall(r"%([\w\.\-]+)", args):
+            users.setdefault(operand, []).append(name)
+    out = {}
+    for w in weights:
+        found, todo = set(), [w]
+        while todo:
+            for u in users.get(todo.pop(), []):
+                (todo.append if ops[u] in moving else found.add)(u)
+        out[w] = found
+    return out
+
+
+def _serving_pool(model, slots, slot_len, page, devs):
+    """(params in bf16, paged cache, a struct maker) of a serving pool on
+    the described chip, as shapes."""
+    from tpu_air.models.lm.generate import init_paged_cache
+
+    npg = slot_len // page
+    on = lambda tree, dtype=None: jax.tree_util.tree_map(  # noqa: E731
+        lambda s: _struct(s.shape, dtype or s.dtype, devs), tree)
+    params = on(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        jnp.bfloat16)
+    cache = on(jax.eval_shape(
+        lambda: init_paged_cache(model, slots, slots * npg + 1, page, npg)))
+    return params, cache, lambda *shape: _struct(shape, jnp.int32, devs)
+
+
+def _hybrid(n_layers, period, offset):
+    from tpu_air.models.lm import LMConfig
+
+    return LMConfig(vocab_size=65536, d_model=2560, n_layers=n_layers,
+                    n_heads=20, n_kv_heads=1, head_dim=128, d_ff=8192,
+                    max_seq_len=2048, rope_theta=None,
+                    attn_layer_period=period, attn_layer_offset=offset,
+                    mamba_d_state=16, mamba_d_conv=4, mamba_dt_rank=160,
+                    dtype="bfloat16")
+
+
+@pytest.mark.parametrize("family", ["hybrid", "sparse_experts"])
+def test_mixed_step_streams_each_weight_once_on_v5e(v5e, monkeypatch, family):
+    """The engine's mixed step (a decode step over every slot and one slot's
+    prefill chunk in one program) at the two serving cells' widths and
+    geometry: the chip's compiler takes it; every weight matrix is read by
+    ONE operation (the point of the program: XLA does not merge two products
+    that share a weight, and the two bodies traced into one program read each
+    weight twice, which the counter sees); the tied embedding is read by the
+    token gather and the head product.
+
+    The hybrid is Jamba2-3B WHOLE (28 layers, attention at 7 and 21 on one
+    K/V head; 128 slots and 128 chunk positions), because what goes wrong
+    shows only at depth: with the chunk's state row read from the pool of
+    states beside the step's update of it, the compiler copied 22 of the 26
+    layers' ``[128, 16, 5120]`` states whole, every step (2.7 ms of a 22 ms
+    program on the chip: PERF.md, PR 42), and at 3 or 12 layers none.  Read
+    from what the update leaves, the state is updated where it lies: the
+    donated cache comes back aliased and no state is copied.  The
+    sparse-expert model (OLMoE's widths, 2 layers, 64 slots) runs its three
+    grouped products a layer as the kernel, once over the step's rows and
+    the chunk's together."""
+    from tpu_air.models.lm import CausalLM, LMConfig
+    from tpu_air.models.lm.generate import (
+        make_paged_decode_body, make_paged_mixed_body,
+        make_prefill_chunk_body)
+    from tpu_air.ops import moe
+
+    if family == "hybrid":
+        cfg = _hybrid(28, 14, 7)
+        slots, slot_len, page = 128, 2048, 128
+    else:
+        cfg = LMConfig(vocab_size=50304, d_model=2048, n_layers=2, n_heads=16,
+                       n_kv_heads=16, head_dim=128, d_ff=1024,
+                       max_seq_len=1024, num_experts=64,
+                       num_experts_per_tok=8, qk_norm=True,
+                       tie_embeddings=False, dtype="bfloat16")
+        slots, slot_len, page = 64, 1024, 128
+        monkeypatch.setattr(moe.jax, "default_backend", lambda: "tpu")
+
+    def lowered(body, cfg):
+        model = CausalLM(cfg)
+        params, cache, i32 = _serving_pool(model, slots, slot_len, page, v5e)
+        slot = {"slot": i32()} if cfg.has_recurrent_layers else {}
+        return jax.jit(body(model), donate_argnums=(1,)).lower(
+            params, cache, i32(slots), i32(slots),
+            i32(slots, slot_len // page), i32(1, page), i32(), i32(),
+            i32(slot_len // page), **slot)
+
+    compiled = lowered(
+        lambda m: make_paged_mixed_body(m, page, slot_len), cfg).compile()
+    text = compiled.as_text()
+    readers = _weight_consumers(text)
+    tied = {w for w in readers if "embedding" in w and cfg.tie_embeddings}
+    assert len(readers) >= 4 * cfg.n_layers
+    assert {w: len(r) for w, r in readers.items()} == {
+        w: 2 if w in tied else 1 for w in readers}
+    if family == "sparse_experts":
+        assert text.count("tpu_custom_call") == 3 * cfg.n_layers
+        return
+    mem = compiled.memory_analysis()
+    state = 26 * slots * (16 * 5120 * 4 + 3 * 5120 * 2)
+    assert mem.alias_size_in_bytes >= state          # updated in place
+    assert mem.temp_size_in_bytes < state // 4       # no second copy of it
+    assert not re.search(r"= f32\[128,16,5120\]\S* copy\(", text)
+    assert "f32[128,5120,16]" not in text            # never channel-major
+    assert not re.search(r"= \w+\[128,3,5120\]\S* copy\(", text)
+
+    # the counter is not vacuous: the two bodies, one after the other in one
+    # program (three layers of it), read every weight matrix twice
+    def both(model):
+        chunk = make_prefill_chunk_body(model, page, slot_len)
+        step = make_paged_decode_body(model, slot_len)
+
+        def body(params, cache, tok, pos, table, *chunk_args, slot):
+            cache, first = chunk(params, cache, *chunk_args, slot=slot)
+            return step(params, cache, tok, pos, table) + (first,)
+        return body
+
+    twice = _weight_consumers(
+        lowered(both, _hybrid(3, 3, 1)).compile().as_text())
+    assert {len(r) for w, r in twice.items() if "embedding" not in w} == {2}

@@ -236,6 +236,17 @@ def _jamba_prefill_chunk():
                       slot=i32()).compile())
 
 
+def _jamba_mixed_step():
+    from tpu_air.models.lm.generate import make_lm_paged_mixed_step_fn
+
+    return _jamba(lambda model, params, cache, i32, slots, slot_len, page,
+                  npg: make_lm_paged_mixed_step_fn(
+                      model, page, slot_len).lower(
+                      params, cache, i32(slots), i32(slots), i32(slots, npg),
+                      i32(1, page), i32(), i32(), i32(npg),
+                      slot=i32()).compile())
+
+
 # the words that came after benchmark/scopes.py wrote its list down (PR 41:
 # lower-case words, which its reader takes for parts of a model as they are)
 LATER_WORDS = {"ssm_conv", "ssm_scan", "ssm_state_update"}
@@ -249,6 +260,11 @@ PROGRAMS = {
     "jamba_prefill_chunk": (_jamba_prefill_chunk, {
         "ssm_conv": "mamba", "ssm_scan": "mamba", "attn_scores": "attn",
         "kv_append": "attn", "lm_head": None}),
+    # the mixed step holds the step's scopes and the chunk's, side by side
+    "jamba_mixed_step": (_jamba_mixed_step, {
+        "ssm_conv": "mamba", "ssm_state_update": "mamba", "ssm_scan": "mamba",
+        "kv_gather": "attn", "decode_attention": "attn",
+        "attn_scores": "attn", "kv_append": "attn", "lm_head": None}),
     "t5_train_step": (_t5_train_step, {
         "attn_scores": "self_attn", "attn_softmax": "cross_attn",
         "attn_context": "self_attn", "dropout": "mlp", "loss": None,

@@ -34,6 +34,8 @@ from tpu_air.engine import (
 from tpu_air.models.lm import CausalLM, LMConfig
 from tpu_air.models.lm.generate import generate as lm_generate
 
+import _mixed_step_cases
+
 PORT = 8127
 
 
@@ -221,6 +223,19 @@ def test_token_parity_with_eos_retirement(lm):
             retired_early += 1
     assert retired_early > 0, "EOS never triggered — test exercises nothing"
     engine.close()
+
+
+@pytest.mark.parametrize("case", sorted(_mixed_step_cases.CASES))
+def test_mixed_step(lm, case):
+    """One program for an iteration's prefill chunk and its decode step
+    (tests/_mixed_step_cases.py), on the dense model against offline
+    ``generate``."""
+    cfg, model, params = lm
+
+    def check(prompt, tokens):
+        assert tokens == _offline(model, params, prompt, len(tokens), None)
+
+    _mixed_step_cases.CASES[case](model, params, check)
 
 
 def test_slot_reuse_burst_deeper_than_pool(lm):

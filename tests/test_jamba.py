@@ -15,6 +15,8 @@ from tpu_air.models.lm import hf_import, reference_jamba
 from tpu_air.models.lm.config import LMConfig
 from tpu_air.models.lm.modeling import CausalLM
 
+import _mixed_step_cases
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 TINY = {
@@ -291,10 +293,11 @@ def test_a_reused_slot_gives_the_logits_of_a_fresh_engine(tiny):
 
 
 def test_a_row_mid_prefill_keeps_its_state_while_others_decode(tiny):
-    """A long prompt's chunks run one an iteration between decode steps of
-    the rows already streaming: those steps must hold its state (the live
-    mask), and ``engine.step``'s live rows are the rows whose state the step
-    advances."""
+    """A long prompt's chunks go out one an iteration, each riding the decode
+    step of the rows already streaming (the mixed program): the step's half
+    must hold the prompt's state (the live mask) while the chunk's half
+    advances it, and ``engine.step``'s live rows are the rows whose state the
+    step's half advances."""
     rng = np.random.default_rng(8)
     short = [rng.integers(2, 384, 6).tolist() for _ in range(2)]
     long_ = rng.integers(2, 384, 90).tolist()       # six chunks of 16
@@ -320,13 +323,36 @@ def test_a_row_mid_prefill_keeps_its_state_while_others_decode(tiny):
                         == 4 - live)
     assert steps_with_prefilling_row >= 4
     got = late.result(5)
+    snap = eng.metrics.snapshot()
     eng.close()
+    # the first short prompt met no step; the second rode the first's, and
+    # each of the long one's six chunks a step of both
+    assert (snap["chunks_alone"], snap["chunks_fused"],
+            snap["mixed_steps"]) == (1, 7, 7)
     alone = _engine(tiny)
     want = alone.generate([long_], 6)[0]
     alone.close()
     assert got == want
     assert [s.result(5) for s in streams][0][:3]  # the others streamed on
     assert held0 >= 0 and issued0 > 0
+
+
+@pytest.mark.parametrize("case", sorted(_mixed_step_cases.CASES))
+def test_mixed_step(tiny, case):
+    """One program for an iteration's prefill chunk and its decode step
+    (tests/_mixed_step_cases.py): the chunk's slot rides the step's half
+    held and ends with the chunk's state; streams against offline
+    ``generate``."""
+    from tpu_air.models.lm.generate import generate
+
+    _, config, model, params = tiny
+
+    def check(prompt, tokens):
+        want = generate(model, params, np.asarray([prompt]),
+                        max_new_tokens=len(tokens))
+        assert np.asarray(want)[0].tolist() == tokens
+
+    _mixed_step_cases.CASES[case](model, params, check)
 
 
 def test_live_mask_holds_a_state_bit_for_bit():

@@ -653,9 +653,10 @@ def test_t5_window_learns_of_eos_one_step_late_and_emits_nothing_past_it(
 
 
 @pytest.mark.parametrize("other_budget, issued, ahead, dropped", [
-    # the other row joins one iteration later and runs on to its budget of
-    # 8: steps 1..8, each one read, all but the first issued ahead
-    (8, 8, 7, 0),
+    # the other row's one chunk rides step 2 (the mixed program), so it joins
+    # step 3 and runs on to its budget of 8: steps 1..9, each one read, all
+    # but the first issued ahead
+    (8, 9, 8, 0),
     # the other row ends on its budget of 3 (host state: it is in no step
     # past its last), so the row that ends on EOS at its fifth token is the
     # last: the sixth token's step is out when the host learns of it

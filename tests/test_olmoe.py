@@ -21,6 +21,8 @@ from tpu_air.models.lm import CausalLM, hf_import, reference
 from tpu_air.models.lm.modeling import rope
 from tpu_air.observability.perf import LMCostModel
 
+import _mixed_step_cases
+
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 2e-5
 
@@ -123,6 +125,25 @@ def test_engine_streams_the_references_tokens_with_unequal_prompts():
     assert 0 < snap["moe_experts_streamed"] <= snap["moe_steps"] * 2 * 8
 
 
+@pytest.mark.parametrize("case", sorted(_mixed_step_cases.CASES))
+def test_mixed_step(case):
+    """One program for an iteration's prefill chunk and its decode step
+    (tests/_mixed_step_cases.py): the grouped expert product over the step's
+    rows and the chunk's together, every streamed token held to the float32
+    reference."""
+    sd = published()
+    model, params = build(sd)
+
+    def check(prompt, tokens):
+        ids = prompt + tokens[:-1]
+        want = reference.forward(sd.__getitem__, HF, ids,
+                                 rows=range(len(prompt) - 1, len(ids)))["logits"]
+        assert (want.max(-1)
+                - want[np.arange(len(tokens)), tokens]).max() < TOL
+
+    _mixed_step_cases.CASES[case](model, params, check)
+
+
 def test_a_skewed_router_overloads_one_expert_and_drops_nothing():
     sd = published(seed=4)
     # every token carries a common component (added to every embedding row)
@@ -209,6 +230,12 @@ def test_cost_model_prices_experts_stored_and_computed_apart():
         m.streamed_param_bytes(3) + 3 * 64 * kv + 3 * kv)
     assert step.flops == pytest.approx(
         3 * (m.linear_flops_per_token + layers * 4.0 * hd * 64))
+    # the mixed step streams the experts its 3 + 8 tokens touch, once
+    mixed = m.mixed_step_cost(3, 64, 8, 0)
+    chunk = m.prefill_chunk_cost(8, 0)
+    assert mixed.flops == pytest.approx(step.flops + chunk.flops)
+    assert mixed.hbm_bytes == pytest.approx(
+        m.streamed_param_bytes(11) + 3 * 64 * kv + 3 * kv + 8 * kv + 8 * kv)
     # a dense model is priced as it always was
     from tpu_air.models.lm import LMConfig
 

@@ -225,8 +225,30 @@ def paged_phases(tmp_path_factory):
     after = engine.metrics.snapshot()
     engine.close()
     counted = {k: after[k] - before[k] for k in (
-        "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped")}
+        "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped",
+        "chunks_fused", "chunks_alone", "mixed_steps")}
     return _phases(trace_dir), tokens, counted
+
+
+def test_paged_phases_say_which_chunks_rode_a_step(paged_phases):
+    """``engine.prefill`` counts ``chunks`` 1 and ``fused`` 0/1, the
+    ``engine.step`` that carries the chunk ``chunk`` 1: the sums are the
+    engine's ``chunks_fused`` / ``chunks_alone`` / ``mixed_steps``, and a
+    fused chunk is made ready right before the step it goes out in."""
+    phases, _, counted = paged_phases
+    prefills = [p for p in phases if p[0] == "engine.prefill"]
+    steps = [p for p in phases if p[0] == "engine.step"]
+    assert all(p[3]["chunks"] == 1 for p in prefills)
+    fused = [p for p in prefills if p[3]["fused"]]
+    # the first prompt finds no step to ride, nor does one admitted when the
+    # other slot's budget has just ended
+    assert len(fused) == counted["chunks_fused"] == 3
+    assert len(prefills) - len(fused) == counted["chunks_alone"] == 2
+    carrying = [st for st in steps if st[3]["chunk"]]
+    assert len(carrying) == counted["mixed_steps"] == len(fused)
+    for p, st in zip(fused, carrying):
+        between = [q for q in prefills + steps if p[2] <= q[1] < st[1]]
+        assert p[2] <= st[1] and not between
 
 
 def test_paged_step_holds_dispatch_readback_emit_in_order(paged_phases):

@@ -331,6 +331,9 @@ def test_adapter_parity_mixed_tenants_vs_offline(lm):
     # or the gather proves nothing
     assert streams[1].result(0.1) != offline_greedy(
         model, params, prompts[1], max_new)
+    # both tenants' prompts rode a decode step of the base request: their
+    # first tokens came from the mixed program's head, under their adapters
+    assert engine.metrics.snapshot()["chunks_fused"] == 2
     engine.close()
 
 

@@ -16,7 +16,7 @@ decode step the device is computing (see "one step in flight" below):
 1. **admission** — FIFO from the scheduler queue, gated on KV-page
    capacity with a bounded reorder window so a big blocked head can't
    starve small requests behind it.
-2. **prefill** — up to ``prefill_chunks_per_step`` chunk calls per engine
+2. **prefill** — up to ``prefill_chunks_per_step`` chunks per engine
    step, shortest-remaining-prompt first, so short-request TTFT stays flat
    while long prompts stream in.
 3. **decode + retirement** — one fixed-shape step over all ``S`` rows;
@@ -24,16 +24,43 @@ decode step the device is computing (see "one step in flight" below):
    the next host visit (its private pages return to the free list; its
    prompt's pages stay resident in the prefix cache for future hits).
 
+THREE PROGRAMS, chosen by what the iteration holds and by nothing else (no
+option, no model's name).  A chunk alone and a decode step alone are each a
+whole pass over the model and stream every weight from HBM; a decode step at
+``S`` rows is far under the chip's ridge, so the chunk's ``page_len`` rows
+cost its weight products next to nothing when they ride the same pass:
+
+* a chunk to run AND rows to decode: ONE program, the MIXED STEP
+  (``lm_paged_mixed_step``: models/lm/generate.py): every weight matrix is
+  applied once to the ``S`` decoding rows and the chunk's ``page_len`` rows
+  together, and only the sequence mixers take the two parts apart (paged
+  single-token attention / state update for the step's rows, dense causal
+  attention over the slot's pages / the scan from its carried state for the
+  chunk's).  It carries the chunk the prefill quantum picks first; with
+  ``prefill_chunks_per_step`` > 1 the others (of other prompts: a prompt's
+  next chunk waits for the one that rides) run alone, ahead of it;
+* a chunk and no row to decode (a cold start, a drain's last prompts): the
+  chunk program (``lm_prefill_chunk``), alone;
+* rows and no chunk: the decode step (``lm_paged_decode_step``).
+
+The mixed step is compiled when the engine is built (``_compile_mixed_step``:
+no single request reaches it).  ``stats()`` counts ``chunks_fused``,
+``chunks_alone`` (their sum is every chunk run) and ``mixed_steps``.
+
 ONE STEP IN FLIGHT.  The loop runs one decode step ahead of its host: step
 N+1 is issued from step N's tokens as they lie on the device, and only then
 is step N read back, emitted and retired from.  The step's ``tok`` and
 ``pos`` live on the device (``advance_rows_body`` moves them on behind every
 step, ``set_row_body`` sets the row that joins or leaves), the masked block
 table is uploaded again only when a row joined or left, and a prompt's last
-chunk hands its first token to the step on the device and is read
-afterwards.  So an iteration is: admit; issue the chunk(s) behind the step in
-flight; issue step N+1; read step N and emit it; read the first tokens of
-the prompts that just finished.  What follows from reading one step late:
+chunk hands its first token to a step on the device and is read afterwards:
+issued alone it joins the step of the same iteration; riding step N+1 it
+joins step N+2 (its token does not exist before N+1 ends) and is read with
+N+1.  So an iteration is: admit; make the first chunk ready and issue the
+others alone, behind the step in flight; issue step N+1 (mixed, if a chunk
+rides); read step N and emit it; read the first tokens of the prompts that
+just finished (last chunk issued alone this iteration, or riding step N).
+What follows from reading one step late:
 
 * budgets are host state, so a row whose budget ends with step N is not in
   step N+1, and a step is issued only if some row has a token left after
@@ -45,7 +72,8 @@ the prompts that just finished.  What follows from reading one step late:
   them next runs behind N+1 on the device (the cache is donated through
   every program).  Its output in N+1 is discarded, never emitted or counted;
 * a step whose rows all ended before it was read is dropped unread
-  (``steps_dropped``), as is the step in flight at ``close``;
+  (``steps_dropped``), as is the step in flight at ``close``; the first
+  token of a prompt whose last chunk rode a dropped step is still read;
 * whatever reads or changes slot state from outside the loop (migration,
   weight swap and rollback, adapter load and unload) first settles the step
   in flight: reads it and emits it (``_settle``).
@@ -58,7 +86,11 @@ hand: the decode step holds the state of every row it does not decode (a row
 mid-prefill rides every step issued between its chunks; the program takes
 ``pos > 0`` as the row being live, which is exactly the rows ``_issue``
 leaves in the table); a prompt's first chunk starts the row from zeros; a
-last chunk's padding never enters it.  The row that ends on EOS and rides
+last chunk's padding never enters it.  In a mixed step the chunk's slot is
+such a held row of the step's half AND the row the chunk's half advances:
+the chunk starts from the state the slot holds as the program begins, and
+its write of the row is the one that lands (as its page write lands beside
+the step's scatter, which for that row goes to the null page).  The row that ends on EOS and rides
 step N+1 advances a state nobody reads again: the next tenant's first chunk
 zeroes it.  Prefix sharing is off for such a model (a page hit without the
 state at that boundary is wrong), seen in the model and not set by an option,
@@ -86,6 +118,7 @@ from tpu_air.models.lm.generate import (
     init_paged_cache,
     recurrent_state_bytes,
     make_lm_paged_decode_step_fn,
+    make_lm_paged_mixed_step_fn,
     make_lm_prefill_chunk_fn,
     make_lm_step_feed_fns,
     make_page_copy_fn,
@@ -113,12 +146,28 @@ from .types import (
 )
 
 
+class _Chunk(NamedTuple):
+    """One prefill chunk made ready on the host: the slot, where the chunk
+    starts, and the chunk program's arguments behind the cache (``ids``,
+    ``p0``, ``last_local``, ``table_row``) as they go to the device."""
+
+    slot: Slot
+    start: int
+    args: Tuple[Any, ...]
+    adapter_row: int = 0
+
+
 class _IssuedStep(NamedTuple):
     """A decode step handed to the device and not read yet: its device
-    output, and the rows it decodes with the request each held then."""
+    output, the rows it decodes with the request each held then, where the
+    chunk that rode it starts (None: a plain decode step), and, where that
+    chunk was its prompt's last, the prompt's slot and first token (on the
+    device until the step is read)."""
 
     out: Any
     rows: List[Tuple[Slot, Request]]
+    chunk_start: Optional[int] = None
+    first: Optional[Tuple[Slot, Any]] = None
 
     def alive(self) -> List[Slot]:
         """The rows that still hold the request the step decoded for them
@@ -160,7 +209,9 @@ class InferenceEngine:
 
         # device side: the persistent donated KV pool + compiled phases
         # (MeshEngine overrides the builder: a sharded pool/cache and
-        # pjit-wrapped step fns, same host loop)
+        # pjit-wrapped step fns, same host loop; it builds no mixed step,
+        # and an engine without one issues every chunk alone)
+        self._mixed_step = None
         self._build_paged_state()
 
         # the step's inputs as they lie on the device between steps (see
@@ -182,7 +233,10 @@ class InferenceEngine:
         self._inflight: Optional[_IssuedStep] = None
         self._mark = 0.0
         self._round_reserved = 0   # pages promised during one admission round
-        self._chunks_run = 0       # prefill chunk calls, engine lifetime
+        self._chunks_run = 0       # prefill chunks issued, engine lifetime
+        # the chunk this iteration's decode step carries (set by the prefill
+        # quantum, taken by the issue that follows it in the same iteration)
+        self._chunk_riding: Optional[_Chunk] = None
 
         self.scheduler = Scheduler(cfg)
         self.slots = SlotManager(cfg.num_slots)
@@ -232,6 +286,8 @@ class InferenceEngine:
         self._preempting = False
         self._round_admits = 0  # slots taken during one admission round
         self._thread: Optional[threading.Thread] = None
+        if self._mixed_step is not None:
+            self._compile_mixed_step()
         if auto_start:
             self.start()
 
@@ -253,6 +309,9 @@ class InferenceEngine:
         self._chunk_fn = make_lm_prefill_chunk_fn(
             self.model, cfg.page_len, cfg.slot_len,
             adapters=self.adapters_enabled)
+        self._mixed_step = make_lm_paged_mixed_step_fn(
+            self.model, cfg.page_len, cfg.slot_len,
+            adapters=self.adapters_enabled)
         self._copy_fn = make_page_copy_fn()
         self._advance, self._set_row = make_lm_step_feed_fns()
         if self.adapters_enabled:
@@ -263,6 +322,28 @@ class InferenceEngine:
             self._adapter_a = jnp.zeros((A + 1, mc.d_model, r), jnp.float32)
             self._adapter_b = jnp.zeros((A + 1, r, mc.vocab_size),
                                         jnp.float32)
+
+    def _compile_mixed_step(self) -> None:
+        """Run the mixed step once on the empty pool, so that it is compiled
+        when the engine is built.  The other two programs compile when the
+        first request meets them; this one takes a prompt that arrives while
+        another request decodes, which a warm-up of one request never is,
+        and the first such iteration under load would stall every stream for
+        the compile.  Every row rides idle at the null page (the table as an
+        empty engine's is); the chunk is slot 0's at position 0 with no real
+        position (``last_local`` -1), so its K/V goes to the null page and
+        row 0's state is set to the zeros it holds."""
+        cfg = self.config
+        ids = np.full((1, cfg.page_len), self.model.config.pad_token_id,
+                      np.int32)
+        null = np.array([[self._null_entry(i)] * cfg.pages_per_slot()
+                         for i in range(cfg.num_slots)], np.int32)
+        self._table_dev = jnp.asarray(null)
+        if self.adapters_enabled:
+            self._adapter_ids_dev = jnp.asarray(self._adapter_ids_host.copy())
+        self._launch_step(_Chunk(self.slots.slots[0], 0, (
+            jnp.asarray(ids), jnp.int32(0), jnp.int32(-1),
+            jnp.asarray(null[0]))))
 
     # -- submission (any thread) ---------------------------------------------
     def _make_request(self, prompt, max_new_tokens, stream,
@@ -773,55 +854,79 @@ class InferenceEngine:
         return insert_kv_pages(cache, page_ids, payload)
 
     def _prefill_quantum(self) -> bool:
-        """Issue up to ``prefill_chunks_per_step`` prefill chunk calls,
+        """Take up to ``prefill_chunks_per_step`` prefill chunks,
         SHORTEST-REMAINING-PROMPT first (ties: request id = arrival order).
-        They queue behind the decode step in flight and ahead of the next
-        one, so the device starts them the moment it is free.  Bounding the
-        per-step quantum keeps any single long prompt from stalling
-        in-flight decodes; preferring short remainders keeps short-request
-        TTFT flat while a long prompt streams in."""
+        Where this iteration will issue a decode step, the first chunk rides
+        it (``_chunk_riding``: one program for both, the weights streamed
+        once); it is made ready here and goes out with the step.  Every other
+        chunk is issued here, alone: behind the decode step in flight and
+        ahead of the next one, so the device starts it the moment it is free.
+        Bounding the per-step quantum keeps any single long prompt from
+        stalling in-flight decodes; preferring short remainders keeps
+        short-request TTFT flat while a long prompt streams in.  True if a
+        chunk was issued alone."""
+        ride = None   # will this iteration issue a step?  asked on first need
         ran = False
         for _ in range(max(1, self.config.prefill_chunks_per_step)):
-            pending = [s for s in self.slots.active_slots() if s.prefilling]
+            # the riding chunk goes out after the ones issued here: its
+            # prompt's next chunk waits for the next iteration
+            pending = [s for s in self.slots.active_slots() if s.prefilling
+                       and (self._chunk_riding is None
+                            or s is not self._chunk_riding.slot)]
             if not pending:
                 break
             slot = min(
                 pending,
                 key=lambda s: (s.plan.chunks_left, s.request.request_id),
             )
+            if ride is None:
+                # the rows _token_step will find: a chunk issued here can
+                # only add to them (a prompt that finishes joins the step)
+                ride = self._mixed_step is not None and bool(self._step_rows(
+                    self._inflight.alive() if self._inflight else []))
+            fused = ride and self._chunk_riding is None
             with phase("engine.prefill", tokens=self.config.page_len,
                        start=slot.plan.next_start,
-                       queued=self.scheduler.depth()):
-                self._run_chunk(slot)
-            ran = True
+                       queued=self.scheduler.depth(), chunks=1,
+                       fused=int(fused)):
+                chunk = self._ready_chunk(slot)
+                if fused:
+                    self._chunk_riding = chunk
+                else:
+                    self._run_chunk(chunk)
+                    ran = True
         return ran
 
-    def _run_chunk(self, slot: Slot) -> None:
+    def _ready_chunk(self, slot: Slot) -> _Chunk:
+        """The slot's next chunk, its inputs on their way to the device."""
         plan = slot.plan
         req = slot.request
-        cfg = self.config
-        C = cfg.page_len
+        C = self.config.page_len
         p0 = plan.next_start
-        n = plan.prompt_len
         ids = np.full((1, C), self.model.config.pad_token_id, np.int32)
         chunk_toks = req.prompt[p0:p0 + C]
         ids[0, :len(chunk_toks)] = chunk_toks
         is_last = plan.chunks_done == len(plan.chunk_starts) - 1
-        last_local = (n - 1 - p0) if is_last else (C - 1)
+        last_local = (plan.prompt_len - 1 - p0) if is_last else (C - 1)
         row = self.pool.chunk_row(slot.index, p0, plan.null_target)
-        t0 = time.monotonic()
-        args = [self.params, self.cache, jnp.asarray(ids), jnp.int32(p0),
-                jnp.int32(last_local), jnp.asarray(row)]
-        if self.adapters_enabled:
-            args += [self._adapter_a, self._adapter_b,
-                     jnp.int32(req.adapter_row)]
+        return _Chunk(slot, p0, (jnp.asarray(ids), jnp.int32(p0),
+                                 jnp.int32(last_local), jnp.asarray(row)),
+                      req.adapter_row)
+
+    def _state_row(self, slot: Slot) -> Dict[str, Any]:
         # the slot's state row goes with its table row; the chunk at position
         # 0 starts it from zeros (the last tenant left its state behind) and
         # positions past last_local are masked out of it
-        state = {"slot": jnp.int32(slot.index)} if self._recurrent else {}
-        self.cache, tok = self._chunk_fn(*args, **state)
-        if self._recurrent and p0 == 0:
-            self.metrics.record_state_reset()
+        return {"slot": jnp.int32(slot.index)} if self._recurrent else {}
+
+    def _run_chunk(self, chunk: _Chunk) -> None:
+        """Issue one chunk alone, with the chunk program."""
+        t0 = time.monotonic()
+        args = (self.params, self.cache) + chunk.args
+        if self.adapters_enabled:
+            args += (self._adapter_a, self._adapter_b,
+                     jnp.int32(chunk.adapter_row))
+        self.cache, tok = self._chunk_fn(*args, **self._state_row(chunk.slot))
         if self._cost_model is not None:
             # dispatch-time measurement: no chunk is host-synced here (the
             # final chunk's token is read after the next step went out), so
@@ -830,12 +935,27 @@ class InferenceEngine:
             # item 5's lane)
             self.metrics.record_program(
                 "prefill_chunk",
-                self._cost_model.prefill_chunk_cost(C, p0),
+                self._cost_model.prefill_chunk_cost(self.config.page_len,
+                                                    chunk.start),
                 time.monotonic() - t0)
+        if self._chunk_issued(chunk, tok, fused=False):
+            self._firsts.append((chunk.slot, tok))
+
+    def _chunk_issued(self, chunk: _Chunk, tok, fused: bool) -> bool:
+        """Book a chunk that went to the device, alone or in a mixed step;
+        ``tok`` is the program's token for it, on the device.  True when it
+        was its prompt's last: the slot is handed over to decode, joins the
+        next issued step with ``tok``, and the caller sees to the read of
+        ``tok`` as the prompt's first token."""
+        slot = chunk.slot
+        plan, req = slot.plan, slot.request
+        if self._recurrent and chunk.start == 0:
+            self.metrics.record_state_reset()
         plan.chunks_done += 1
         self._chunks_run += 1
+        self.metrics.record_chunk(fused)
         if not plan.done:
-            return
+            return False
         # final chunk: publication, CoW, hand over to decode.  The first
         # token stays on the device: the row joins the next issued step with
         # it, and the host reads it after that step went out (_read_firsts)
@@ -846,17 +966,18 @@ class InferenceEngine:
             self.cache = self._copy_fn(
                 self.cache, jnp.int32(dst), jnp.int32(src))
         slot.prefilling = False
-        slot.pos = n
+        slot.pos = plan.prompt_len
         slot.budget_left = req.max_new_tokens - 1
-        self._firsts.append((slot, tok))
         if slot.budget_left:
-            self._joins[slot.index] = (tok, n)
+            self._joins[slot.index] = (tok, plan.prompt_len)
+        return True
 
     def _read_firsts(self) -> None:
         """Read and emit the first tokens of the prompts whose last chunk
-        was issued this iteration (TTFT is stamped here, at the read).  A
-        row whose budget was one token, or whose first token is EOS, retires
-        here; the latter rode the step that went out before this read."""
+        was issued alone this iteration, or rode the step just read (TTFT is
+        stamped here, at the read).  A row whose budget was one token, or
+        whose first token is EOS, retires here; the latter rode the step
+        that went out before this read."""
         firsts, self._firsts = self._firsts, []
         with phase("engine.readback", first=len(firsts)):
             # airlint: disable=JX004 — one read a finished prompt, after the
@@ -1066,24 +1187,28 @@ class InferenceEngine:
 
     def _token_step(self, issue: bool = True) -> bool:
         """One token step: issue the next decode step (``issue``, and a row
-        has budget for it), then read back and emit the step before it and
-        the first tokens of the prompts that just finished.  False when
-        there was nothing to issue and nothing to read."""
+        has budget for it), carrying the chunk the prefill quantum left to
+        ride it; then read back and emit the step before it and the first
+        tokens of the prompts that just finished.  False when there was
+        nothing to issue and nothing to read."""
         unread, self._inflight = self._inflight, None
+        chunk, self._chunk_riding = self._chunk_riding, None
         reading = unread.alive() if unread else []
         rows = self._step_rows(reading) if issue else []
+        assert chunk is None or rows, "a chunk rides only a step that goes out"
         if unread is None and not rows and not self._firsts:
             return False
         ahead = bool(rows) and unread is not None
         with phase("engine.step", live=len(reading if unread else rows),
-                   batch=self.config.num_slots, ahead=int(ahead)):
+                   batch=self.config.num_slots, ahead=int(ahead),
+                   chunk=int(chunk is not None)):
             if rows:
                 # out before step N is read: the device runs it while the
                 # host reads, emits, retires and admits
                 with phase("engine.dispatch"):
-                    self._issue(rows, ahead)
+                    self._issue(rows, ahead, chunk)
             if unread is not None:
-                self._read(unread.out, reading)
+                self._read(unread, reading)
             if self._firsts:
                 self._read_firsts()
             if self._inflight is not None and not self._inflight.alive():
@@ -1102,6 +1227,9 @@ class InferenceEngine:
 
     def _drop_step(self) -> None:
         if self._inflight is not None:
+            if self._inflight.first is not None:
+                # the prompt whose last chunk rode it still wants its token
+                self._firsts.append(self._inflight.first)
             self._inflight = None
             self.metrics.record_dropped_step()
 
@@ -1115,9 +1243,11 @@ class InferenceEngine:
         return [s for s in self.slots.active_slots() if not s.prefilling
                 and s.budget_left - (s.index in held) >= 1]
 
-    def _issue(self, rows: List[Slot], ahead: bool) -> None:
+    def _issue(self, rows: List[Slot], ahead: bool,
+               chunk: Optional[_Chunk] = None) -> None:
         """Issue one pool decode step over ``rows`` from the inputs on the
-        device, and move those inputs on behind it."""
+        device, and move those inputs on behind it.  With ``chunk`` the step
+        is the mixed program and the chunk goes out in it."""
         wanted = {s.index for s in rows}
         leaving = self._riding - wanted
         for i in leaving:
@@ -1142,37 +1272,69 @@ class InferenceEngine:
                     self._adapter_ids_host.copy())
         self._riding = wanted
         self._joins = {}
-        args = (self.params, self.cache, self._tok_dev, self._pos_dev,
-                self._table_dev)
-        if self.adapters_enabled:
-            args += (self._adapter_a, self._adapter_b, self._adapter_ids_dev)
-        self.cache, out = self._decode_step(*args)
+        out, tok = self._launch_step(chunk)
         self._tok_dev, self._pos_dev = self._advance(
             self._tok_dev, self._pos_dev, out)
-        self._inflight = _IssuedStep(out, [(s, s.request) for s in rows])
-        self.metrics.record_issue(ahead)
+        start = first = None
+        if chunk is not None:
+            start = chunk.start
+            # after the joins were taken: a prompt that ends here joins the
+            # NEXT step, with a token this program has yet to compute
+            if self._chunk_issued(chunk, tok, fused=True):
+                first = (chunk.slot, tok)
+        self._inflight = _IssuedStep(
+            out, [(s, s.request) for s in rows], start, first)
+        self.metrics.record_issue(ahead, mixed=chunk is not None)
         if self._recurrent:
             # every row not in the step rode it with its state held
             self.metrics.record_rows_held(self.config.num_slots - len(rows))
         if not ahead:
             self._mark = time.monotonic()
 
+    def _launch_step(self, chunk: Optional[_Chunk] = None):
+        """Hand the device the decode step over its own inputs, or, with
+        ``chunk``, the mixed step that carries it.  Returns the step's
+        output and the chunk's token (None without one), both on the
+        device."""
+        args = (self.params, self.cache, self._tok_dev, self._pos_dev,
+                self._table_dev)
+        lora = ((self._adapter_a, self._adapter_b, self._adapter_ids_dev)
+                if self.adapters_enabled else ())
+        if chunk is None:
+            self.cache, out = self._decode_step(*args, *lora)
+            return out, None
+        if lora:
+            lora += (jnp.int32(chunk.adapter_row),)
+        self.cache, out, tok = self._mixed_step(
+            *args, *chunk.args, *lora, **self._state_row(chunk.slot))
+        return out, tok
+
     def _set_dev_row(self, row: int, tok, p: int) -> None:
         self._tok_dev, self._pos_dev = self._set_row(
             self._tok_dev, self._pos_dev, np.int32(row),
             tok if hasattr(tok, "dtype") else np.int32(tok), np.int32(p))
 
-    def _read(self, out, reading: List[Slot]) -> None:
+    def _read(self, step: _IssuedStep, reading: List[Slot]) -> None:
         """Read one issued step back and emit it to ``reading``, the rows
-        it decoded that are still the requests it decoded them for."""
+        it decoded that are still the requests it decoded them for.  The
+        first token of a prompt whose last chunk rode the step is ready with
+        it: queued for ``_read_firsts``."""
         with phase("engine.readback"):
-            nxt = np.asarray(out)
+            nxt = np.asarray(step.out)
+        if step.first is not None:
+            self._firsts.append(step.first)
         # what this token step cost the stream: read-back to read-back, a
-        # chunk that ran between the two steps included
+        # chunk that ran between the two steps (or in this one) included
         now = time.monotonic()
         dt, self._mark = now - self._mark, now
-        if self._decode_cost is not None:
-            self.metrics.record_program("decode_step", self._decode_cost, dt)
+        if self._cost_model is not None:
+            kind, cost = "decode_step", self._decode_cost
+            if step.chunk_start is not None:
+                cfg = self.config
+                kind, cost = "mixed_step", self._cost_model.mixed_step_cost(
+                    cfg.num_slots, cfg.slot_len, cfg.page_len,
+                    step.chunk_start)
+            self.metrics.record_program(kind, cost, dt)
         if len(nxt) > self.config.num_slots:
             # sparse experts: the step's routing counters ride behind
             # the tokens (make_paged_decode_body)

@@ -111,6 +111,12 @@ class EngineMetrics:
         self.steps_issued = 0
         self.steps_ahead = 0
         self.steps_dropped = 0
+        # InferenceEngine: the issued steps that carried a prefill chunk in
+        # their one program, the chunks that went out so, and those issued
+        # alone with the chunk program
+        self.mixed_steps = 0
+        self.chunks_fused = 0
+        self.chunks_alone = 0
         # per-slot recurrent state (absent for a model without such layers):
         # its bytes, first chunks run (each zeroes a slot's state), rows x
         # steps whose state a decode step held by its mask, and whether the
@@ -252,12 +258,23 @@ class EngineMetrics:
             self._token_stamps.append((now, tokens))
             self._trim_stamps(now)
 
-    def record_issue(self, ahead: bool) -> None:
+    def record_issue(self, ahead: bool, mixed: bool = False) -> None:
         """One decode step handed to the device; ``ahead`` when the step
-        before it had not been read back yet."""
+        before it had not been read back yet, ``mixed`` when it carried a
+        prefill chunk."""
         with self._lock:
             self.steps_issued += 1
             self.steps_ahead += bool(ahead)
+            self.mixed_steps += bool(mixed)
+
+    def record_chunk(self, fused: bool) -> None:
+        """One prefill chunk handed to the device: ``fused`` into a decode
+        step's program, or alone."""
+        with self._lock:
+            if fused:
+                self.chunks_fused += 1
+            else:
+                self.chunks_alone += 1
 
     def set_recurrent_state(self, nbytes: int,
                             prefix_cache_disabled: bool) -> None:
@@ -383,6 +400,9 @@ class EngineMetrics:
                 out["kvpool"] = dict(self.kvpool)
                 out["reordered_admits"] = self.reordered_admits
                 out["prefill_chunks"] = self.prefill_chunks
+                out["chunks_fused"] = self.chunks_fused
+                out["chunks_alone"] = self.chunks_alone
+                out["mixed_steps"] = self.mixed_steps
             if self.topology:
                 out["topology"] = dict(self.topology)
             if self.weights:
@@ -465,7 +485,8 @@ def merge_snapshots(snapshots: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
                                        for e in entries]),
         }
     out["priority"] = prio
-    for key in ("steps_issued", "steps_ahead", "steps_dropped"):
+    for key in ("steps_issued", "steps_ahead", "steps_dropped",
+                "chunks_fused", "chunks_alone", "mixed_steps"):
         if any(key in s for s in snaps):
             out[key] = sum(int(s.get(key, 0)) for s in snaps)
     perfs = [s.get("perf") for s in snaps if s.get("perf")]
@@ -550,6 +571,12 @@ _FAMILIES = [
      "admissions taken out of FIFO order"),
     ("tpu_air_engine_prefill_chunks", "counter",
      "prefill chunk programs executed"),
+    ("tpu_air_engine_chunks_fused", "counter",
+     "prefill chunks that went out inside a decode step's program"),
+    ("tpu_air_engine_chunks_alone", "counter",
+     "prefill chunks issued alone, with the chunk program"),
+    ("tpu_air_engine_mixed_steps", "counter",
+     "decode steps that carried a prefill chunk in their one program"),
     ("tpu_air_engine_steps_issued", "counter",
      "decode steps handed to the device (one-step-ahead engines)"),
     ("tpu_air_engine_steps_ahead", "counter",
@@ -647,7 +674,8 @@ def prometheus_lines(snapshots: Dict[str, Dict[str, Any]] = None) -> list:
                 b.declare(fam, "gauge", f"paged KV pool: {key}")
                 kvpool_declared.add(fam)
             b.raw(fam, f"{fam}{tag} {val:g}")
-        for key in ("reordered_admits", "prefill_chunks", "steps_issued",
+        for key in ("reordered_admits", "prefill_chunks", "chunks_fused",
+                    "chunks_alone", "mixed_steps", "steps_issued",
                     "steps_ahead", "steps_dropped"):
             if key in snap:
                 b.raw(f"tpu_air_engine_{key}",

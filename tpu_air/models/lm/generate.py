@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from tpu_air.models.sampling import sample_token
 
 from .config import LMConfig
-from .modeling import CausalLM, expert_assignments, head_weight
+from .modeling import (CausalLM, ChunkRows, expert_assignments,
+                       head_weight)
 
 
 def init_cache(model: CausalLM, batch_size: int):
@@ -269,25 +270,59 @@ def recurrent_state_bytes(cache) -> int:
 
 
 def _apply_paged(model: CausalLM, slot_len: int):
-    """``fn(params, cache, ids, positions) -> (cache', hidden, rows)``: the
-    model applied once over a paged cache, the way both engine bodies do.
-    ``rows`` is ``[layers, tokens, E]`` expert assignments for a
+    """``fn(params, cache, ids, positions, chunk=None) -> (cache', hidden,
+    rows)``: the model applied once over a paged cache, the way every engine
+    body does.  ``rows`` is ``[layers, tokens, E]`` expert assignments for a
     sparse-expert model (``modeling.expert_assignments``), else None."""
     cfg = model.config
     dmodel = CausalLM(LMConfig.from_dict(
         {**cfg.to_dict(), "max_seq_len": slot_len}))
     mutable = ["cache", "intermediates"] if cfg.num_experts else ["cache"]
 
-    def apply(params, cache, ids, positions):
+    def apply(params, cache, ids, positions, chunk=None):
         hidden, vars_ = dmodel.apply(
             {"params": params, "cache": cache}, ids, positions,
-            decode=True, return_hidden=True, mutable=mutable,
+            decode=True, return_hidden=True, mutable=mutable, chunk=chunk,
         )
         rows = (expert_assignments(vars_["intermediates"])
                 if cfg.num_experts else None)
         return vars_["cache"], hidden, rows
 
     return apply
+
+
+def _push_step_leaves(cache, pos, block_table):
+    """The cache with a decode step's host-side facts pushed in: every
+    row's position, the (masked) block table, and which rows are live."""
+    cache = _map_cache_index(cache, lambda _: pos)
+    cache = _map_cache_leaf(
+        cache, "block_table",
+        lambda _: block_table.astype(jnp.int32))
+    # a row that decodes is past its prompt; every other row (free, or
+    # mid-prefill with its chunks building its state) sits at position 0
+    # and the step must hold whatever state it has
+    return _map_cache_leaf(
+        cache, "valid_len", lambda _: (pos > 0).astype(jnp.int32))
+
+
+def _with_routing(nxt, rows, live):
+    """``nxt`` with a sparse-expert step's routing counters behind it
+    (:func:`make_paged_decode_body`): assignments to each expert over the
+    rows ``live [tokens]`` marks, then the experts the step streamed."""
+    if rows is None:
+        return nxt
+    return jnp.concatenate([
+        nxt, (rows * live.astype(jnp.int32)[None, :, None]).sum((0, 1)),
+        (rows.sum(1) > 0).sum(dtype=jnp.int32)[None]])
+
+
+def _lora_head_delta(h, bank_a, bank_b, ids):
+    """Each row's LoRA head delta ``(h @ bank_a[id]) @ bank_b[id]``: ``h
+    [rows, d]``, ``ids [rows]`` into the banks, gathered the way the block
+    table gathers pages (row 0 of a bank is the exact-zero adapter)."""
+    a = bank_a[ids]                                  # [rows, d, r]
+    b = bank_b[ids]                                  # [rows, r, V]
+    return jnp.einsum("sr,srv->sv", jnp.einsum("sd,sdr->sr", h, a), b)
 
 
 def make_paged_decode_logits_body(model: CausalLM, slot_len: int):
@@ -303,15 +338,7 @@ def make_paged_decode_logits_body(model: CausalLM, slot_len: int):
 
     def logits_step(params, cache, tok, pos, block_table):
         pos = pos.astype(jnp.int32)
-        cache = _map_cache_index(cache, lambda _: pos)
-        cache = _map_cache_leaf(
-            cache, "block_table",
-            lambda _: block_table.astype(jnp.int32))
-        # a row that decodes is past its prompt; every other row (free, or
-        # mid-prefill with its chunks building its state) sits at position 0
-        # and the step must hold whatever state it has
-        cache = _map_cache_leaf(
-            cache, "valid_len", lambda _: (pos > 0).astype(jnp.int32))
+        cache = _push_step_leaves(cache, pos, block_table)
         cache, hidden, rows = apply(params, cache, tok[:, None], pos[:, None])
         h = hidden[:, -1].astype(jnp.float32)
         with jax.named_scope("lm_head"):
@@ -353,17 +380,9 @@ def make_paged_decode_body(model: CausalLM, slot_len: int,
         cache, h, logits, rows = logits_step(params, cache, tok, pos,
                                              block_table)
         if adapters:
-            a = bank_a[adapter_ids]                      # [S, d, r]
-            b = bank_b[adapter_ids]                      # [S, r, V]
-            logits = logits + jnp.einsum(
-                "sr,srv->sv", jnp.einsum("sd,sdr->sr", h, a), b)
+            logits = logits + _lora_head_delta(h, bank_a, bank_b, adapter_ids)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        if rows is not None:
-            live = (pos > 0).astype(jnp.int32)[None, :, None]
-            nxt = jnp.concatenate([
-                nxt, (rows * live).sum((0, 1)),
-                (rows.sum(1) > 0).sum(dtype=jnp.int32)[None]])
-        return cache, nxt
+        return cache, _with_routing(nxt, rows, pos > 0)
 
     if not adapters:
         def base_step(params, cache, tok, pos, block_table):
@@ -524,6 +543,106 @@ def make_lm_prefill_chunk_fn(model: CausalLM, page_len: int, slot_len: int,
     stream in without stalling in-flight decodes."""
     body = make_prefill_chunk_body(model, page_len, slot_len, adapters)
     body.__name__ = "lm_prefill_chunk"
+    return jax.jit(body, donate_argnums=(1,))
+
+
+def make_paged_mixed_logits_body(model: CausalLM, page_len: int,
+                                 slot_len: int):
+    """``fn(params, cache, tok, pos, block_table, ids, p0, last_local,
+    table_row, slot=None) -> (cache', h, logits, rows)``: a paged decode
+    step (:func:`make_paged_decode_logits_body`'s first five arguments) and
+    one prefill chunk (:func:`make_prefill_chunk_logits_body`'s others) in
+    ONE pass over the model.  ``h [S + 1, D]`` / ``logits [S + 1, V]``
+    float32: the ``S`` decoding rows, then the chunk's position
+    ``last_local``; ``rows [layers, S + page_len, E]``, the step's rows
+    first.
+
+    The model sees ``S + page_len`` rows of one token each, so every weight
+    matrix (projections, feed-forward, the grouped expert product, the head)
+    is applied once to all of them; the sequence mixers alone take the step's
+    rows and the chunk's apart (``modeling.ChunkRows``), each as its own
+    program does.  The chunk's slot is not a decoding row: the engine keeps
+    it at position 0 with the null table row until its prompt is in, so the
+    step's half scatters its K/V to the null page and holds its state, and
+    the chunk's half writes its page and its state row after it."""
+    cfg = model.config
+    apply = _apply_paged(model, slot_len)
+
+    def logits_mixed(params, cache, tok, pos, block_table, ids, p0,
+                     last_local, table_row, slot=None):
+        if cfg.has_recurrent_layers and slot is None:
+            raise ValueError(
+                "a model with recurrent layers keeps state a slot: the "
+                "chunk needs slot=")
+        s = tok.shape[0]
+        pos, p0 = pos.astype(jnp.int32), p0.astype(jnp.int32)
+        last = last_local.astype(jnp.int32)
+        cache = _push_step_leaves(cache, pos, block_table)
+        chunk = ChunkRows(
+            start=p0, valid=last + 1, table_row=table_row.astype(jnp.int32),
+            slot=jnp.asarray(0 if slot is None else slot).astype(jnp.int32))
+        positions = jnp.concatenate(
+            [pos, p0 + jnp.arange(page_len, dtype=jnp.int32)])
+        cache, hidden, rows = apply(
+            params, cache, jnp.concatenate([tok, ids[0]])[:, None],
+            positions[:, None], chunk)
+        h = jnp.concatenate([hidden[:s, 0], hidden[s + last]]).astype(
+            jnp.float32)
+        with jax.named_scope("lm_head"):
+            logits = h @ head_weight(params, cfg).astype(jnp.float32)
+        return cache, h, logits, rows
+
+    return logits_mixed
+
+
+def make_paged_mixed_body(model: CausalLM, page_len: int, slot_len: int,
+                          adapters: bool = False):
+    """The UNJITTED mixed step: ``fn(params, cache, tok, pos, block_table,
+    ids, p0, last_local, table_row, slot=None) -> (cache', next_tok,
+    chunk_tok)``: ``next_tok`` as :func:`make_paged_decode_body` returns it
+    (a sparse-expert model's routing counters behind the tokens: the load
+    over the DECODING rows as there, the experts streamed over all the
+    pass's rows) and ``chunk_tok`` the greedy token at ``last_local`` as
+    :func:`make_prefill_chunk_body` returns it.
+
+    With ``adapters=True`` the two programs' LoRA arguments follow
+    ``table_row`` (``bank_a``, ``bank_b``, ``adapter_ids [S]``, then the
+    chunk's scalar ``adapter_id``), and the head delta is gathered for the
+    ``S + 1`` rows the way the decode body gathers it for ``S``."""
+    logits_mixed = make_paged_mixed_logits_body(model, page_len, slot_len)
+
+    def mixed(params, cache, tok, pos, block_table, ids, p0, last_local,
+              table_row, bank_a=None, bank_b=None, adapter_ids=None,
+              adapter_id=None, slot=None):
+        cache, h, logits, rows = logits_mixed(
+            params, cache, tok, pos, block_table, ids, p0, last_local,
+            table_row, slot)
+        if adapters:
+            logits = logits + _lora_head_delta(
+                h, bank_a, bank_b,
+                jnp.concatenate([adapter_ids, adapter_id[None]]))
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        s = tok.shape[0]
+        live = jnp.concatenate([pos > 0, jnp.zeros((page_len,), bool)])
+        return cache, _with_routing(nxt[:s], rows, live), nxt[s]
+
+    if not adapters:
+        def base_mixed(params, cache, tok, pos, block_table, ids, p0,
+                       last_local, table_row, slot=None):
+            return mixed(params, cache, tok, pos, block_table, ids, p0,
+                         last_local, table_row, slot=slot)
+        return base_mixed
+    return mixed
+
+
+def make_lm_paged_mixed_step_fn(model: CausalLM, page_len: int,
+                                slot_len: int, adapters: bool = False):
+    """The engine's third unit, jitted, cache donated: one decode step and
+    one prefill chunk in one program (:func:`make_paged_mixed_body`), for
+    the iteration that holds both.  Arguments and shapes are the two
+    programs' own."""
+    body = make_paged_mixed_body(model, page_len, slot_len, adapters)
+    body.__name__ = "lm_paged_mixed_step"
     return jax.jit(body, donate_argnums=(1,))
 
 

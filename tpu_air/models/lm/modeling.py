@@ -18,7 +18,7 @@ from any reference code).
 
 from __future__ import annotations
 
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,21 @@ from .config import LMConfig
 
 Array = jax.Array
 NEG_INF = -1e30
+
+
+class ChunkRows(NamedTuple):
+    """The prefill chunk that rides a paged decode step (the engine's mixed
+    step, models/lm/generate.py): the call's ``x`` is ``[S + C, 1, D]``, the
+    pool's ``S`` decoding rows and then the ``C = page_len`` positions of ONE
+    slot's chunk.  Everything that works on a token alone (norms,
+    projections, feed-forward, experts) sees ``S + C`` rows in one product a
+    weight matrix; only the sequence mixers tell the parts apart, and they
+    take from here what the chunk program takes from the cache's leaves."""
+
+    start: Array      # int32 scalar: the chunk's first position (page-aligned)
+    valid: Array      # int32 scalar: how many of its positions are real
+    table_row: Array  # int32 [pages_per_slot]: the slot's block-table row
+    slot: Array       # int32 scalar: the slot (its row of recurrent state)
 
 
 class RMSNorm(nn.Module):
@@ -83,11 +98,62 @@ def _dense_causal_attention(q, k, v, scale, q_offset=0):
                           v.astype(jnp.float32)).astype(q.dtype)
 
 
+# The paged cache's two writes and two reads (engine/kvpool/): one new
+# position of every slot (a decode step), or one whole page of one slot (a
+# prefill chunk).  The step and the chunk program each make their write, then
+# their read; the mixed step makes both writes, then both reads.
+
+def _paged_append_rows(ck, cv, table, i, k, v):
+    """Scatter row ``s``'s new ``k``/``v`` ``[S, 1, g*d]`` to its current
+    ``(table[s, i // C], i % C)``; returns the pools."""
+    C = ck.shape[1]
+    rows = jnp.arange(table.shape[0])
+    page = table[rows, i // C]
+    off = i % C
+    with jax.named_scope("kv_append"):
+        return (ck.at[page, off].set(k[:, 0].astype(ck.dtype)),
+                cv.at[page, off].set(v[:, 0].astype(cv.dtype)))
+
+
+def _paged_attend_rows(q, ck, cv, table, i, scale, h, g, dtype):
+    """``q [S, h, 1, d]``, each row over its own gathered pages up to its
+    position ``i [S]`` -> ``[S, 1, h*d]``: the flat r5 formulation over
+    pool-resident pages."""
+    kvm = jnp.arange(table.shape[1] * ck.shape[1])[None, :] <= i[:, None]
+    o4 = flat_decode_attention(
+        q.transpose(0, 2, 1, 3) * scale, gather_pages(ck, table),
+        gather_pages(cv, table), None, kvm, None, None, h, dtype, g)
+    return o4.reshape(q.shape[0], 1, -1)
+
+
+def _paged_append_chunk(ck, cv, page, k, v):
+    """Write one slot's chunk ``k``/``v`` ``[1, C, g*d]`` over ``page``."""
+    with jax.named_scope("kv_append"):
+        return (jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                             (page, 0, 0)),
+                jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                             (page, 0, 0)))
+
+
+def _paged_attend_chunk(q, ck, cv, table, p0, scale, g):
+    """``q [1, h, C, d]`` at positions ``p0 ..`` dense and causal over the
+    pages of ``table[:1]`` (earlier chunks and prefix-shared pages supply
+    ``0 .. p0-1``) -> ``[1, C, h*d]``."""
+    kg = gather_pages(ck, table[:1])
+    vg = gather_pages(cv, table[:1])
+    lg, d = kg.shape[1], q.shape[-1]
+    k4 = kg.reshape(1, lg, g, d).transpose(0, 2, 1, 3)
+    v4 = vg.reshape(1, lg, g, d).transpose(0, 2, 1, 3)
+    o = _dense_causal_attention(q, k4, v4, scale, q_offset=p0)
+    return o.transpose(0, 2, 1, 3).reshape(1, q.shape[2], -1)
+
+
 class CausalSelfAttention(nn.Module):
     config: LMConfig
 
     @nn.compact
-    def __call__(self, x: Array, positions: Array, decode: bool = False) -> Array:
+    def __call__(self, x: Array, positions: Array, decode: bool = False,
+                 chunk: Optional[ChunkRows] = None) -> Array:
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         b, l, _ = x.shape
@@ -147,29 +213,43 @@ class CausalSelfAttention(nn.Module):
                     "cache", "block_table",
                     lambda: jnp.zeros((b, 1), jnp.int32))
                 table = bt.value
-                npg = table.shape[1]
                 C = ck.value.shape[1]
-                lg = npg * C
+                if chunk is not None:
+                    # mixed step: rows [:S] are the pool's decode step, rows
+                    # [S:] one slot's chunk.  Both writes, then both reads:
+                    # a row that rides along scatters to the null page, which
+                    # is also where a chunk whose real pages are all shared
+                    # writes (PagedKVPool.chunk_row); the chunk's write is
+                    # the later, so its own read finds what it wrote.
+                    S = table.shape[0]
+                    if l != 1 or b != S + C:
+                        raise ValueError(
+                            f"mixed step wants {S} + {C} rows of one token; "
+                            f"got b={b}, l={l}")
+                    row = chunk.table_row[None]
+                    ck.value, cv.value = _paged_append_rows(
+                        ck.value, cv.value, table, i, kflat[:S], vflat[:S])
+                    ck.value, cv.value = _paged_append_chunk(
+                        ck.value, cv.value, row[0, chunk.start // C],
+                        kflat[S:, 0][None], vflat[S:, 0][None])
+                    idx.value = i + 1
+                    o = jnp.concatenate([
+                        _paged_attend_rows(q[:S], ck.value, cv.value, table,
+                                           i, scale, h, g, dtype),
+                        _paged_attend_chunk(
+                            q[S:].transpose(2, 1, 0, 3), ck.value, cv.value,
+                            row, chunk.start, scale, g).reshape(C, 1, h * d),
+                    ])
+                    return proj("o", cfg.d_model)(o)
                 if l == 1:
                     # paged decode step: scatter each slot's new K/V to its
                     # current (page, offset), then attend over the gathered
                     # flat slab — same r5 formulation, pool-resident pages.
-                    rows = jnp.arange(b)
-                    page = table[rows, i // C]
-                    off = i % C
-                    with jax.named_scope("kv_append"):
-                        ck.value = ck.value.at[page, off].set(
-                            kflat[:, 0].astype(dtype))
-                        cv.value = cv.value.at[page, off].set(
-                            vflat[:, 0].astype(dtype))
+                    ck.value, cv.value = _paged_append_rows(
+                        ck.value, cv.value, table, i, kflat, vflat)
                     idx.value = i + 1
-                    kvm = jnp.arange(lg)[None, :] <= i[:, None]
-                    o4 = flat_decode_attention(
-                        q.transpose(0, 2, 1, 3) * scale,
-                        gather_pages(ck.value, table),
-                        gather_pages(cv.value, table),
-                        None, kvm, None, None, h, dtype, g)
-                    return proj("o", cfg.d_model)(o4.reshape(b, 1, h * d))
+                    return proj("o", cfg.d_model)(_paged_attend_rows(
+                        q, ck.value, cv.value, table, i, scale, h, g, dtype))
                 # chunked prefill: ONE slot (b == 1) processes one page-
                 # aligned chunk of its prompt at positions p0 .. p0+l-1.
                 # The whole chunk writes its page in one dynamic_update_
@@ -183,20 +263,11 @@ class CausalSelfAttention(nn.Module):
                         f"got b={b}, l={l}"
                     )
                 p0 = i[0]
-                page = table[0, p0 // C]
-                with jax.named_scope("kv_append"):
-                    ck.value = jax.lax.dynamic_update_slice(
-                        ck.value, kflat.astype(dtype), (page, 0, 0))
-                    cv.value = jax.lax.dynamic_update_slice(
-                        cv.value, vflat.astype(dtype), (page, 0, 0))
+                ck.value, cv.value = _paged_append_chunk(
+                    ck.value, cv.value, table[0, p0 // C], kflat, vflat)
                 idx.value = i + l
-                kg = gather_pages(ck.value, table[:1])
-                vg = gather_pages(cv.value, table[:1])
-                k4 = kg.reshape(1, lg, g, d).transpose(0, 2, 1, 3)
-                v4 = vg.reshape(1, lg, g, d).transpose(0, 2, 1, 3)
-                o = _dense_causal_attention(q, k4, v4, scale, q_offset=p0)
-                o = o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
-                return proj("o", cfg.d_model)(o)
+                return proj("o", cfg.d_model)(_paged_attend_chunk(
+                    q, ck.value, cv.value, table, p0, scale, g))
             with jax.named_scope("kv_append"):
                 ck.value = jax.lax.dynamic_update_slice(
                     ck.value, kflat.astype(dtype), (0, i, 0))
@@ -331,12 +402,13 @@ class DepthwiseConv(nn.Module):
     position) and ``bias``; the caller carries ``tail [b, (width-1) *
     channels]``, the ``width - 1`` inputs before the call, flat as stored
     (``ops/ssm.py``).  Returns the outputs and the tail after the call's
-    ``valid_len [b]`` real positions."""
+    ``valid_len [b]`` real positions (with ``ride``, a mixed step's two
+    tails)."""
 
     width: int
 
     @nn.compact
-    def __call__(self, x: Array, tail: Array, valid_len: Array):
+    def __call__(self, x: Array, tail: Array, valid_len: Array, ride=None):
         b, l, c = x.shape
         # fan-in of a channel is the width (the published Conv1d default)
         bound = self.width ** -0.5
@@ -344,14 +416,29 @@ class DepthwiseConv(nn.Module):
             key, shape, dt, -bound, bound)
         kernel = self.param("kernel", uniform, (self.width, c), jnp.float32)
         bias = self.param("bias", uniform, (c,), jnp.float32)
+
+        def chunk(x, tail, valid_len):
+            y, tail = ssm.causal_conv_chunk(
+                x, tail.reshape(-1, self.width - 1, c), kernel, bias,
+                valid_len)
+            return y, tail.reshape(tail.shape[0], -1)
+
         with jax.named_scope("ssm_conv"):
+            if ride is not None:
+                # mixed step (ChunkRows): one position of the ``s`` rows
+                # whose tails came in, then one row's chunk from ``ride``,
+                # its own ``(tail [1, ..], valid_len [1])``
+                s = tail.shape[0]
+                y, tail = ssm.causal_conv_step(x[:s, 0], tail, kernel, bias,
+                                               valid_len > 0)
+                y_c, tail_c = chunk(x[s:, 0][None], *ride)
+                return (jnp.concatenate([y, y_c[0]])[:, None],
+                        (tail, tail_c))
             if l == 1:
                 y, tail = ssm.causal_conv_step(x[:, 0], tail, kernel, bias,
                                                valid_len > 0)
                 return y[:, None], tail
-            y, tail = ssm.causal_conv_chunk(
-                x, tail.reshape(b, self.width - 1, c), kernel, bias, valid_len)
-            return y, tail.reshape(b, -1)
+            return chunk(x, tail, valid_len)
 
 
 def _init_dt_bias(key, shape, dtype=jnp.float32):
@@ -390,12 +477,17 @@ class MambaMixer(nn.Module):
       prompt of row ``state_row[0]``: it starts from zeros when it is the
       prompt's first (``cache_index`` 0: a slot's last tenant left its state
       behind), and its padded positions neither advance the state nor enter
-      the tail."""
+      the tail.  With ``chunk`` (:class:`ChunkRows`) the call is both at
+      once, the engine's mixed step: the projections see the decode step's
+      rows and the chunk's positions together, the recurrence takes each
+      part as above, and the chunk's slot, which the step holds, ends with
+      the chunk's state."""
 
     config: LMConfig
 
     @nn.compact
-    def __call__(self, x: Array, decode: bool = False) -> Array:
+    def __call__(self, x: Array, decode: bool = False,
+                 chunk: Optional[ChunkRows] = None) -> Array:
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         b, l, _ = x.shape
@@ -420,22 +512,55 @@ class MambaMixer(nn.Module):
             ss = self.variable("cache", "ssm_state",
                                lambda: jnp.zeros((b, n, c), jnp.float32))
             tail, state = cs.value, ss.value
+
+        def row_of(rows, slot, fresh):
+            """One slot's row of ``rows`` as a chunk starts from it: zeros
+            where the chunk is its prompt's first."""
+            return jnp.where(fresh, 0, jax.lax.dynamic_slice_in_dim(
+                rows, slot, 1, axis=0))
+
+        ride = None
         if engine:
             index = self.get_variable("cache", "cache_index")
             valid = self.get_variable("cache", "valid_len")
-            if l > 1:
+            if chunk is not None:
+                # mixed step: every slot's row takes one token (or is held)
+                # and rows [S:] are the chunk of ``chunk.slot``, from what
+                # that slot holds as the step begins
+                row, fresh = chunk.slot, chunk.start == 0
+                ride = (row_of(tail, row, fresh), chunk.valid[None])
+            elif l > 1:
                 # one chunk of one row's prompt (b == 1)
-                row = self.get_variable("cache", "state_row")[0]
-                fresh = index[0] == 0
-                tail = jnp.where(fresh, 0, jax.lax.dynamic_slice_in_dim(
-                    tail, row, 1, axis=0))
-                state = jnp.where(fresh, 0, jax.lax.dynamic_slice_in_dim(
-                    state, row, 1, axis=0))
+                row, fresh = self.get_variable("cache", "state_row")[0], \
+                    index[0] == 0
+                tail, state = row_of(tail, row, fresh), row_of(state, row,
+                                                               fresh)
                 valid = valid[:1]
+
+        def scan(u, dt, B, C, state, valid):
+            with jax.named_scope("ssm_scan"):
+                # bounded pieces: the scan makes [b, piece, n, c] float32
+                # terms for all of a piece's positions at once
+                ys, piece = [], 256
+                for p0 in range(0, u.shape[1], piece):
+                    sl = slice(p0, p0 + piece)
+                    y, state = ssm.selective_scan_chunk(
+                        u[:, sl], dt[:, sl], A, B[:, sl], C[:, sl], skip,
+                        state, jnp.clip(valid - p0, 0, piece))
+                    ys.append(y)
+                y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+            return y, state
+
+        def update(u, dt, B, C, state, valid):
+            with jax.named_scope("ssm_state_update"):
+                y, state = ssm.selective_state_update(
+                    u[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], skip, state,
+                    valid > 0)
+                return y[:, None], state
 
         uz = dense("in_proj", 2 * c)(x)
         u, z = uz[..., :c], uz[..., c:]
-        u, new_tail = DepthwiseConv(k, name="conv")(u, tail, valid)
+        u, new_tail = DepthwiseConv(k, name="conv")(u, tail, valid, ride)
         u = nn.silu(u).astype(dtype)
         dbc = dense("x_proj", r + 2 * n)(u)
         dt = RMSNorm(cfg.rmsnorm_eps, dtype, name="dt_norm")(dbc[..., :r])
@@ -446,32 +571,38 @@ class MambaMixer(nn.Module):
             kernel_init=lambda key, shape, dt_: jax.random.uniform(
                 key, shape, dt_, -r ** -0.5, r ** -0.5),
             bias_init=_init_dt_bias)(dt.astype(jnp.float32)))
-        if l == 1 and decode:
-            with jax.named_scope("ssm_state_update"):
-                y, new_state = ssm.selective_state_update(
-                    u[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], skip, state,
-                    valid > 0)
-                y = y[:, None]
+        if ride is not None:
+            S = state.shape[0]
+            y, new_state = update(u[:S], dt[:S], B[:S], C[:S], state, valid)
+            # the chunk's row is read from what the step's half left: it
+            # held that row, so this is the state the slot had, and the pool
+            # of states has ONE reader at a time (read beside the update,
+            # the chip's compiler copies every layer's 42 MB of state first
+            # at full depth: PERF.md, PR 42).  The chunk's positions as one
+            # row: [C, 1, ..] -> [1, C, ..]
+            y_c, state_c = scan(*(jnp.swapaxes(a[S:], 0, 1)
+                                  for a in (u, dt, B, C)),
+                                row_of(new_state, row, fresh), ride[1])
+            y = jnp.concatenate([y, jnp.swapaxes(y_c, 0, 1)])
+            new_tail, tail_c = new_tail
+        elif l == 1 and decode:
+            y, new_state = update(u, dt, B, C, state, valid)
         else:
-            with jax.named_scope("ssm_scan"):
-                # bounded pieces: the scan makes [b, piece, n, c] float32
-                # terms for all of a piece's positions at once
-                ys, new_state, piece = [], state, 256
-                for p0 in range(0, l, piece):
-                    sl = slice(p0, p0 + piece)
-                    y, new_state = ssm.selective_scan_chunk(
-                        u[:, sl], dt[:, sl], A, B[:, sl], C[:, sl], skip,
-                        new_state, jnp.clip(valid - p0, 0, piece))
-                    ys.append(y)
-                y = ys[0] if len(ys) == 1 else jnp.concatenate(ys, axis=1)
+            y, new_state = scan(u, dt, B, C, state, valid)
         if decode:
-            if engine and l > 1:
-                cs.value = jax.lax.dynamic_update_slice_in_dim(
+            if ride is not None:
+                # the step held the chunk's row (it rides at position 0); the
+                # chunk's own state is written over it, and is what lands
+                new_tail = jax.lax.dynamic_update_slice_in_dim(
+                    new_tail, tail_c, row, axis=0)
+                new_state = jax.lax.dynamic_update_slice_in_dim(
+                    new_state, state_c, row, axis=0)
+            elif engine and l > 1:
+                new_tail = jax.lax.dynamic_update_slice_in_dim(
                     cs.value, new_tail, row, axis=0)
-                ss.value = jax.lax.dynamic_update_slice_in_dim(
+                new_state = jax.lax.dynamic_update_slice_in_dim(
                     ss.value, new_state, row, axis=0)
-            else:
-                cs.value, ss.value = new_tail, new_state
+            cs.value, ss.value = new_tail, new_state
         return dense("out_proj", cfg.d_model)(
             (y * nn.silu(z.astype(jnp.float32))).astype(dtype))
 
@@ -482,18 +613,21 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x: Array, positions: Array, deterministic: bool = True,
-                 decode: bool = False) -> Array:
+                 decode: bool = False,
+                 chunk: Optional[ChunkRows] = None) -> Array:
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
+        # ``chunk``: only the sequence mixer tells a mixed step's two parts
+        # apart; the norms and the feed-forward below take its rows as rows
         if self.kind == "mamba":
             x = x + drop(MambaMixer(cfg, name="mamba")(
                 RMSNorm(cfg.rmsnorm_eps, dtype, name="mamba_norm")(x),
-                decode=decode))
+                decode=decode, chunk=chunk))
         else:
             x = x + drop(CausalSelfAttention(cfg, name="attn")(
                 RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(x),
-                positions, decode=decode,
+                positions, decode=decode, chunk=chunk,
             ))
         # the feed-forward kind follows from the configuration's numbers
         ff = (SparseExperts(cfg, name="moe") if cfg.num_experts
@@ -507,7 +641,8 @@ class CausalLM(nn.Module):
 
     ``positions``: (B, L) global positions; defaults to 0..L-1.  Sequence-
     parallel callers pass ``shard_offset + arange(L_local)`` so RoPE and the
-    ring causal mask see global coordinates.
+    ring causal mask see global coordinates.  ``chunk``: :class:`ChunkRows`,
+    over the engine's paged cache alone.
     """
 
     config: LMConfig
@@ -515,7 +650,8 @@ class CausalLM(nn.Module):
     @nn.compact
     def __call__(self, input_ids: Array, positions: Optional[Array] = None,
                  deterministic: bool = True, return_hidden: bool = False,
-                 decode: bool = False) -> Array:
+                 decode: bool = False,
+                 chunk: Optional[ChunkRows] = None) -> Array:
         cfg = self.config
         b, l = input_ids.shape
         if l > cfg.max_seq_len:
@@ -532,7 +668,7 @@ class CausalLM(nn.Module):
         x = embed[input_ids].astype(dtype)
         for i, kind in enumerate(cfg.layer_kinds()):
             x = Block(cfg, kind, name=f"layer_{i}")(
-                x, positions, deterministic, decode=decode)
+                x, positions, deterministic, decode=decode, chunk=chunk)
         x = RMSNorm(cfg.rmsnorm_eps, dtype, name="final_norm")(x)
         if return_hidden:
             # pre-head hidden states: pair with head_weight() +

@@ -538,6 +538,20 @@ class LMCostModel:
                + 2 * self.state_bytes_per_row)                  # one row
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=c)
 
+    def mixed_step_cost(self, rows: int, attended: int, chunk_len: int,
+                        start_pos: int) -> ProgramCost:
+        """One decode step and one prefill chunk in one pass over the model:
+        each part's operations and per-sequence bytes as above, the
+        parameters streamed ONCE for the ``rows + chunk_len`` tokens."""
+        step = self.decode_step_cost(rows, attended)
+        chunk = self.prefill_chunk_cost(chunk_len, start_pos)
+        hbm = (step.hbm_bytes + chunk.hbm_bytes
+               - self.streamed_param_bytes(rows)
+               - self.streamed_param_bytes(chunk_len)
+               + self.streamed_param_bytes(rows + chunk_len))
+        return ProgramCost(flops=step.flops + chunk.flops, hbm_bytes=hbm,
+                           tokens=step.tokens + chunk.tokens)
+
     def train_step_cost(self, batch: int, seq_len: int) -> ProgramCost:
         """One train step over ``[batch, seq_len]``: backward ≈ 2× forward
         (the standard 3× multiplier), bytes ≈ 3 weight-sized streams
