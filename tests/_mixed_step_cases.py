@@ -115,7 +115,11 @@ def the_mixed_body_computes_what_the_two_bodies_compute(model, params, check):
                                  _per_sequence_leaves(one)):
         np.testing.assert_allclose(a, b, atol=2e-6, err_msg=name)
     if model.config.num_experts:
-        assert rows.shape[1:] == (S + C, model.config.num_experts)
+        # the experts the tree holds; one more column (assignments sent
+        # elsewhere) where that is a share of those routed over
+        cfg = model.config
+        assert rows.shape[1:] == (
+            S + C, cfg.experts_held + (not cfg.holds_all_experts))
 
 
 def the_chunks_slot_keeps_the_chunks_state_and_pages(model, params, check):

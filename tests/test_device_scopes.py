@@ -247,9 +247,58 @@ def _jamba_mixed_step():
                       slot=i32()).compile())
 
 
-# the words that came after benchmark/scopes.py wrote its list down (PR 41:
-# lower-case words, which its reader takes for parts of a model as they are)
-LATER_WORDS = {"ssm_conv", "ssm_scan", "ssm_state_update"}
+def _gigachat(build):
+    """A tiny latent-attention engine program: a dense layer, then a sparse
+    one that holds half of its router's experts, with a shared expert."""
+    from tpu_air.models.lm import CausalLM, LMConfig
+    from tpu_air.models.lm.generate import init_paged_cache
+
+    cfg = LMConfig(vocab_size=96, d_model=32, n_layers=2, n_heads=2,
+                   head_dim=12, d_ff=16, max_seq_len=32, tie_embeddings=False,
+                   num_experts=8, num_experts_per_tok=2, q_lora_rank=16,
+                   kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
+                   v_head_dim=8, rope_factor=4.0, rope_original_len=8,
+                   rope_mscale_all_dim=1.0, first_dense_layers=1,
+                   dense_d_ff=48, num_shared_experts=1,
+                   router="sigmoid_groups", router_groups=4,
+                   router_topk_groups=2, router_scale=2.5, experts_first=4,
+                   experts_held=4)
+    model = CausalLM(cfg)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32)))["params"]
+    slots, slot_len, page = 3, 32, 8
+    npg = slot_len // page
+    cache = jax.eval_shape(
+        lambda: init_paged_cache(model, slots, 1 + slots * npg, page, npg))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    return build(model, params, cache, i32, slots, slot_len, page, npg)
+
+
+def _gigachat_paged_step():
+    from tpu_air.models.lm.generate import make_lm_paged_decode_step_fn
+
+    return _gigachat(lambda model, params, cache, i32, slots, slot_len, page,
+                     npg: make_lm_paged_decode_step_fn(model, slot_len).lower(
+                         params, cache, i32(slots), i32(slots),
+                         i32(slots, npg)).compile())
+
+
+def _gigachat_mixed_step():
+    from tpu_air.models.lm.generate import make_lm_paged_mixed_step_fn
+
+    return _gigachat(lambda model, params, cache, i32, slots, slot_len, page,
+                     npg: make_lm_paged_mixed_step_fn(
+                         model, page, slot_len).lower(
+                         params, cache, i32(slots), i32(slots),
+                         i32(slots, npg), i32(1, page), i32(), i32(),
+                         i32(npg)).compile())
+
+
+# the words that came after benchmark/scopes.py wrote its list down (PR 41,
+# PR 43: lower-case words, which its reader takes for parts of a model as
+# they are)
+LATER_WORDS = {"ssm_conv", "ssm_scan", "ssm_state_update",
+               "mla_q", "mla_latent", "mla_out", "moe_shared"}
 WORDS = set(scopes.VOCABULARY) | LATER_WORDS
 
 # program -> the words it must carry, and for some the module around them
@@ -265,6 +314,18 @@ PROGRAMS = {
         "ssm_conv": "mamba", "ssm_state_update": "mamba", "ssm_scan": "mamba",
         "kv_gather": "attn", "decode_attention": "attn",
         "attn_scores": "attn", "kv_append": "attn", "lm_head": None}),
+    # latent attention: the absorbed read of the step and, in the mixed
+    # step, the chunk's expanded attention beside it, all under ``attn``
+    "gigachat_paged_step": (_gigachat_paged_step, {
+        "mla_q": "attn", "mla_latent": "attn", "mla_out": "attn",
+        "kv_gather": "attn", "decode_attention": "attn", "kv_append": "attn",
+        "moe_router": "moe", "moe_experts": "moe", "moe_shared": "shared",
+        "lm_head": None}),
+    "gigachat_mixed_step": (_gigachat_mixed_step, {
+        "mla_q": "attn", "mla_latent": "attn", "mla_out": "attn",
+        "kv_gather": "attn", "decode_attention": "attn",
+        "attn_scores": "attn", "attn_context": "attn",
+        "moe_shared": "shared", "lm_head": None}),
     "t5_train_step": (_t5_train_step, {
         "attn_scores": "self_attn", "attn_softmax": "cross_attn",
         "attn_context": "self_attn", "dropout": "mlp", "loss": None,
@@ -537,10 +598,16 @@ NEW = {
     "lm_attention_share": ["olmoe-serve-decode", "jamba2-serve-reason"],
     "lm_expert_share": ["olmoe-serve-decode"],
     "engine_unscoped_share": ["t5large-serve", "olmoe-serve-decode",
-                              "jamba2-serve-reason"],
+                              "jamba2-serve-reason",
+                              "gigachat-serve-docchat"],
     # PR 41: the hybrid's decode step (a flax module's name and a scope word)
     "ssm_mixer_share": ["jamba2-serve-reason"],
     "ssm_state_share": ["jamba2-serve-reason"],
+    # PR 43: latent attention's own matrices, and the shared expert (over
+    # every program of the capture: nearly every iteration of the cell is the
+    # mixed step, so the readers of the decode program alone do not list it)
+    "mla_latent_share": ["gigachat-serve-docchat"],
+    "moe_shared_share": ["gigachat-serve-docchat"],
 }
 
 

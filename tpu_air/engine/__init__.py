@@ -28,6 +28,7 @@ from .types import (
     EngineConfig,
     EngineOverloadedError,
     Request,
+    ExpertExchangeUnsupported,
     RecurrentStateUnsupported,
     RequestValidationError,
     ResponseStream,
@@ -49,6 +50,7 @@ __all__ = [
     "PrefixCache",
     "PrefixMatch",
     "Request",
+    "ExpertExchangeUnsupported",
     "RecurrentStateUnsupported",
     "RequestValidationError",
     "ShardedPagedPool",

@@ -27,13 +27,23 @@ class KVTransferError(ValueError):
     of decoding from silently-corrupted pages."""
 
 
+def _pools(layer) -> Dict[str, str]:
+    """``{short name: leaf}`` of the page pools a layer's cache dict holds
+    (``k``/``v`` for K and V pages, ``c`` for a latent-attention layer's one
+    pool: ``generate.PAGE_POOL_LEAVES``)."""
+    from tpu_air.models.lm.generate import PAGE_POOL_LEAVES
+
+    return {short: leaf for leaf, short in PAGE_POOL_LEAVES.items()
+            if leaf in layer}
+
+
 def _kv_layers(cache, path=()):
     """Yield ``('/'.join(path), layer_dict)`` for every attention-layer
-    cache dict (the ones holding cached_key/cached_value pools)."""
+    cache dict (the ones holding page pools)."""
     for k, v in cache.items():
         if not isinstance(v, dict):
             continue
-        if "cached_key" in v:
+        if _pools(v):
             yield "/".join(path + (k,)), v
         else:
             yield from _kv_layers(v, path + (k,))
@@ -41,16 +51,15 @@ def _kv_layers(cache, path=()):
 
 def extract_kv_pages(cache, page_ids) -> Dict[str, Dict[str, np.ndarray]]:
     """Pull pages ``page_ids`` (in prompt order) out of a paged cache as
-    host arrays: ``{layer_path: {"k": [n, page_len, h*d], "v": ...}}``."""
+    host arrays: ``{layer_path: {"k": [n, page_len, h*d], "v": ...}}`` (a
+    latent-attention layer: ``{"c": [n, page_len, latent_width]}``)."""
     if _faults.enabled():
         _faults.perturb("kv.transfer", key=str(len(page_ids)))
     ids = np.asarray(page_ids, np.int32)
     out = {}
     for path, layer in _kv_layers(cache):
-        out[path] = {
-            "k": np.asarray(layer["cached_key"][ids]),
-            "v": np.asarray(layer["cached_value"][ids]),
-        }
+        out[path] = {short: np.asarray(layer[leaf][ids])
+                     for short, leaf in _pools(layer).items()}
     return out
 
 
@@ -76,7 +85,7 @@ def validate_kv_payload(cache, page_ids, payload) -> None:
             raise KVTransferError(
                 f"kv payload missing layer {path!r} "
                 f"(shipped layers: {sorted(payload)})")
-        for name, key in (("k", "cached_key"), ("v", "cached_value")):
+        for name, key in _pools(layer).items():
             if name not in pages:
                 raise KVTransferError(
                     f"kv payload at {path!r} missing {name!r} pages")
@@ -117,13 +126,12 @@ def insert_kv_pages(cache, page_ids, payload: Dict[str, Dict[str, np.ndarray]]):
         out = {}
         for k, v in d.items():
             if isinstance(v, dict):
-                if "cached_key" in v:
+                if _pools(v):
                     pages = payload["/".join(path + (k,))]
                     out[k] = dict(v)
-                    out[k]["cached_key"] = v["cached_key"].at[ids].set(
-                        jnp.asarray(pages["k"]).astype(v["cached_key"].dtype))
-                    out[k]["cached_value"] = v["cached_value"].at[ids].set(
-                        jnp.asarray(pages["v"]).astype(v["cached_value"].dtype))
+                    for short, leaf in _pools(v).items():
+                        out[k][leaf] = v[leaf].at[ids].set(
+                            jnp.asarray(pages[short]).astype(v[leaf].dtype))
                 else:
                     out[k] = walk(v, path + (k,))
             else:
@@ -134,11 +142,11 @@ def insert_kv_pages(cache, page_ids, payload: Dict[str, Dict[str, np.ndarray]]):
 
 
 def payload_nbytes(payload: Dict[str, Dict[str, np.ndarray]]) -> int:
-    """Total K+V bytes in a handoff payload (the kv_transfer span attr)."""
+    """Total page bytes (K+V, or latent) in a handoff payload (the kv_transfer span attr)."""
     return sum(arr.nbytes for layer in payload.values()
                for arr in layer.values())
 
 
 def payload_pages(payload: Dict[str, Dict[str, np.ndarray]]) -> int:
     first = next(iter(payload.values()), None)
-    return int(first["k"].shape[0]) if first else 0
+    return int(next(iter(first.values())).shape[0]) if first else 0

@@ -40,7 +40,8 @@ from tpu_air.parallel.sharding import lm_param_shardings, lm_param_spec, \
     shard_params
 
 from ..engine import InferenceEngine
-from ..types import EngineConfig, RecurrentStateUnsupported
+from ..types import (EngineConfig, ExpertExchangeUnsupported,
+                     RecurrentStateUnsupported)
 from .pool import ShardedPagedPool
 from .sharded import (
     make_sharded_page_copy_fn,
@@ -142,6 +143,13 @@ class MeshEngine(InferenceEngine):
             raise RecurrentStateUnsupported(
                 "MeshEngine shards page pools over data and has no sharding "
                 "for per-slot recurrent state (ROADMAP.md M6)")
+        mc = self.model.config
+        if not getattr(mc, "holds_all_experts", True):
+            raise ExpertExchangeUnsupported(
+                f"the model holds experts {mc.experts_first}..+"
+                f"{mc.experts_held} of the {mc.num_experts} its router "
+                "scores; the exchange of the other assignments between "
+                "ranks is not built (engine/dist/sharded.py, ROADMAP.md M1)")
         ppr = self._pages_per_replica()
         self.pool = ShardedPagedPool(
             self._dp, ppr, cfg.page_len, cfg.num_slots,

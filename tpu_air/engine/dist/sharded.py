@@ -15,7 +15,8 @@ laid out so every slot's pages live in that slot's own data shard
 
 Layout over a ``(dp, tp)`` mesh:
 
-* ``cached_key`` / ``cached_value`` ``[P, page_len, h*d]`` →
+* ``cached_key`` / ``cached_value`` ``[P, page_len, h*d]`` (or the one
+  ``cached_latent`` pool: ``generate.PAGE_POOL_LEAVES``) →
   ``P("data", None, None)`` — pages split across dp replicas;
 * ``cache_index`` ``[S]`` → ``P("data")``; ``block_table``
   ``[S, pages_per_slot]`` → ``P("data", None)`` — slots follow pages;
@@ -29,6 +30,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpu_air.models.lm.generate import (
+    PAGE_POOL_LEAVES,
     advance_rows_body,
     make_paged_decode_body,
     make_prefill_chunk_body,
@@ -47,7 +49,7 @@ def paged_cache_shardings(cache, mesh):
         for k, v in d.items():
             if isinstance(v, dict):
                 out[k] = walk(v)
-            elif k in ("cached_key", "cached_value"):
+            elif k in PAGE_POOL_LEAVES:
                 out[k] = NamedSharding(mesh, P("data", None, None))
             elif k == "cache_index":
                 out[k] = NamedSharding(mesh, P("data"))

@@ -96,6 +96,18 @@ zeroes it.  Prefix sharing is off for such a model (a page hit without the
 state at that boundary is wrong), seen in the model and not set by an option,
 and whatever ships pages alone is refused (``RecurrentStateUnsupported``).
 
+LATENT ATTENTION, HELD EXPERTS.  A latent-attention layer keeps ONE page
+pool (the normalised latent and the shared roped key a position:
+models/lm/modeling.LatentAttention) where an attention layer keeps K and V
+pools; table, null page, prefix sharing, copy-on-write and migration work on
+it by page as they do on those (``generate.PAGE_POOL_LEAVES``).  ``stats()``
+counts the positions the decode steps had live (``latent_positions_live``:
+the sum of the decoding rows' lengths as each step is read) beside what a
+step's gather writes out (``latent_positions_pool``).  A model that holds a
+share of the experts its router scores (``LMConfig.experts_held``) returns,
+behind the assignments to its held experts, those it sent elsewhere; both
+are counted, only the first are computed (``moe_assignments_elsewhere``).
+
 Depth is one and fixed; no option selects the behaviour.
 
 Correctness anchor: with greedy decoding the engine's emitted tokens are
@@ -206,6 +218,8 @@ class InferenceEngine:
         # per-slot state that is not pages (module doc, RECURRENT LAYERS)
         self._recurrent = bool(
             getattr(model.config, "has_recurrent_layers", False))
+        # latent attention: one pool of latent pages (models/lm/modeling.py)
+        self._latent = bool(getattr(model.config, "kv_lora_rank", 0))
 
         # device side: the persistent donated KV pool + compiled phases
         # (MeshEngine overrides the builder: a sharded pool/cache and
@@ -241,6 +255,9 @@ class InferenceEngine:
         self.scheduler = Scheduler(cfg)
         self.slots = SlotManager(cfg.num_slots)
         self.metrics = EngineMetrics(name=name, num_slots=cfg.num_slots)
+        if self._latent:
+            # the positions a decode step's gather writes out, live or not
+            self.metrics.set_latent_pool(cfg.num_slots * cfg.slot_len)
         if self._recurrent:
             self.metrics.set_recurrent_state(
                 self._state_bytes,
@@ -1337,9 +1354,19 @@ class InferenceEngine:
             self.metrics.record_program(kind, cost, dt)
         if len(nxt) > self.config.num_slots:
             # sparse experts: the step's routing counters ride behind
-            # the tokens (make_paged_decode_body)
-            self.metrics.record_routing(
-                nxt[self.config.num_slots:-1], int(nxt[-1]))
+            # the tokens (make_paged_decode_body); a model that holds a
+            # share of its experts counts the assignments sent elsewhere
+            # behind those to the held ones
+            counts, elsewhere = nxt[self.config.num_slots:-1], None
+            if len(counts) > self.model.config.experts_held:
+                counts, elsewhere = counts[:-1], int(counts[-1])
+            self.metrics.record_routing(counts, int(nxt[-1]), elsewhere,
+                                        chunk=step.chunk_start is not None)
+        if self._latent:
+            # what the step's absorbed read had live: each decoding row's
+            # positions up to the one this token was computed at
+            self.metrics.record_latent_live(
+                sum(slot.pos + 1 for slot in reading))
         # one phase around the walk over the rows, none per row
         with phase("engine.emit", emitted=len(reading)):
             for slot in reading:

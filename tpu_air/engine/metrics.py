@@ -105,6 +105,16 @@ class EngineMetrics:
         self.moe_expert_load = None   # int64 [E] once a step reported
         self.moe_experts_streamed = 0
         self.moe_steps = 0
+        # the same two over the steps that carried no prefill chunk (the
+        # decode program alone: a chunk's rows touch experts of their own)
+        self.moe_experts_streamed_alone = 0
+        self.moe_steps_alone = 0
+        # assignments to experts held elsewhere (a model that holds a share)
+        self.moe_assignments_elsewhere = None
+        # latent attention: positions the pool holds for a step to gather,
+        # and the positions the decoding rows of the steps read had live
+        self.latent_positions_pool = 0
+        self.latent_positions_live = 0
         # one-step-ahead decode (T5Engine; absent for engines that issue
         # and read a step in turn): decode steps issued, those issued
         # before the step before was read back, those never read
@@ -296,16 +306,38 @@ class EngineMetrics:
         with self._lock:
             self.steps_dropped += 1
 
-    def record_routing(self, counts, streamed: int) -> None:
+    def record_routing(self, counts, streamed: int,
+                       elsewhere: Optional[int] = None,
+                       chunk: bool = False) -> None:
         """One decode step's routing, as read back with its tokens: the
-        assignments to each expert ``[E]`` (decoding rows only, summed over
-        layers) and the experts the step streamed (summed over layers)."""
+        assignments to each expert the model holds ``[E]`` (decoding rows
+        only, summed over layers), the held experts the step streamed
+        (summed over layers) and, for a model that holds a share of its
+        experts, the decoding rows' assignments to the others.  ``chunk``:
+        the step carried a prefill chunk (the mixed program), whose rows
+        are in ``streamed`` too."""
         with self._lock:
             self.moe_experts_streamed += streamed
             self.moe_steps += 1
+            if not chunk:
+                self.moe_experts_streamed_alone += streamed
+                self.moe_steps_alone += 1
             if self.moe_expert_load is None:
                 self.moe_expert_load = np.zeros(len(counts), np.int64)
             self.moe_expert_load += counts
+            if elsewhere is not None:
+                self.moe_assignments_elsewhere = (
+                    self.moe_assignments_elsewhere or 0) + int(elsewhere)
+
+    def set_latent_pool(self, positions: int) -> None:
+        with self._lock:
+            self.latent_positions_pool = int(positions)
+
+    def record_latent_live(self, positions: int) -> None:
+        """One decode step read: the positions its decoding rows had live
+        (the sum of their lengths), of ``latent_positions_pool``."""
+        with self._lock:
+            self.latent_positions_live += int(positions)
 
     def record_program(self, kind: str, cost: ProgramCost,
                        seconds: float) -> None:
@@ -417,6 +449,15 @@ class EngineMetrics:
                 out["moe_expert_load"] = self.moe_expert_load.tolist()
                 out["moe_experts_streamed"] = self.moe_experts_streamed
                 out["moe_steps"] = self.moe_steps
+                out["moe_experts_streamed_alone"] = (
+                    self.moe_experts_streamed_alone)
+                out["moe_steps_alone"] = self.moe_steps_alone
+                if self.moe_assignments_elsewhere is not None:
+                    out["moe_assignments_elsewhere"] = (
+                        self.moe_assignments_elsewhere)
+            if self.latent_positions_pool:
+                out["latent_positions_pool"] = self.latent_positions_pool
+                out["latent_positions_live"] = self.latent_positions_live
             if self.ssm_state_bytes:
                 out["ssm_state_bytes"] = self.ssm_state_bytes
                 out["ssm_state_resets"] = self.ssm_state_resets

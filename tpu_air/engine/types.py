@@ -49,6 +49,14 @@ class RecurrentStateUnsupported(NotImplementedError, TpuAirError):
     migration, disaggregated prefill, the mesh engine; ROADMAP.md M6)."""
 
 
+class ExpertExchangeUnsupported(NotImplementedError, TpuAirError):
+    """The model holds a SHARE of the experts its router scores (one
+    expert-parallel rank: ``LMConfig.experts_held < num_experts``) and the
+    operation would have to send a token's other assignments to the ranks
+    that hold them.  That exchange is not built (ROADMAP.md M1): the mesh
+    engine refuses by name instead of serving partial sums as whole ones."""
+
+
 class RequestValidationError(ValueError, TpuAirError):
     """The request itself is malformed (unknown ``adapter_id``): the
     client's fault, not the server's.  A ValueError subclass so local

@@ -33,6 +33,32 @@ class LMConfig:
     ``n_kv_heads`` K/V heads are shared by ``n_heads / n_kv_heads`` query
     heads each (default: one a query head).  ``rope_theta`` None: no position
     encoding at all (what Jamba publishes: its Mamba layers carry order).
+
+    LATENT ATTENTION (``kv_lora_rank > 0``; what ``deepseek_v3`` publishes):
+    an attention layer is ``modeling.LatentAttention`` (:meth:`layer_kinds`
+    says ``"latent"``): queries through a low-rank ``q_lora_rank`` pair, K and
+    V expanded from ONE joint latent of ``kv_lora_rank`` numbers a position
+    plus one rotary key of ``qk_rope_head_dim`` shared by all heads, score
+    heads of ``qk_nope_head_dim + qk_rope_head_dim`` (= ``head_dim``) beside
+    value heads of ``v_head_dim``.  What is cached a position a layer is the
+    latent and the roped key (:attr:`latent_width` numbers).  ``rope_factor >
+    1`` stretches the rotary frequencies the yarn way
+    (``modeling.yarn_inv_freq``) over ``rope_original_len`` with the ramp
+    between ``rope_beta_fast`` and ``rope_beta_slow``; ``rope_mscale`` /
+    ``rope_mscale_all_dim`` scale cos/sin and the softmax as published.
+
+    FEED-FORWARD BY LAYER (:meth:`ff_kinds`): with ``num_experts > 0`` the
+    first ``first_dense_layers`` layers keep one dense SwiGLU of width
+    ``dense_d_ff`` and the others route; ``num_shared_experts`` adds a SwiGLU
+    of ``num_shared_experts * d_ff`` every token takes beside its routed ones.
+    ``router`` names the routing rule: ``"softmax"`` (OLMoE: top-k of the
+    softmax, not renormalised) or ``"sigmoid_groups"`` (sigmoid scores, a
+    selection bias, the best ``router_topk_groups`` of ``router_groups``
+    groups of consecutive experts ranked by their two largest, top-k among
+    those, renormalised and times ``router_scale``).  ``experts_held`` of the
+    ``num_experts`` routed over, from id ``experts_first``, are the ones this
+    parameter tree HOLDS and computes (an expert-parallel rank; default all):
+    a token's assignments to the others are counted and left to their ranks.
     """
 
     vocab_size: int = 32000
@@ -69,6 +95,26 @@ class LMConfig:
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
     mamba_dt_rank: Optional[int] = None  # default ceil(d_model / 16)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0           # > 0: latent attention
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_factor: float = 1.0        # > 1: yarn
+    rope_original_len: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    first_dense_layers: int = 0
+    dense_d_ff: Optional[int] = None    # default d_ff
+    num_shared_experts: int = 0
+    router: str = "softmax"         # softmax | sigmoid_groups
+    router_groups: int = 1
+    router_topk_groups: int = 1
+    router_scale: float = 1.0
+    experts_first: int = 0
+    experts_held: Optional[int] = None  # default num_experts
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -92,12 +138,71 @@ class LMConfig:
             raise ValueError(
                 f"num_experts_per_tok {self.num_experts_per_tok} is not in "
                 f"1..num_experts ({self.num_experts})")
+        if self.dense_d_ff is None:
+            self.dense_d_ff = self.d_ff
+        if self.experts_held is None:
+            self.experts_held = self.num_experts
+        if not (0 <= self.experts_first
+                and self.experts_first + self.experts_held
+                <= self.num_experts):
+            raise ValueError(
+                f"experts {self.experts_first}..+{self.experts_held} are not "
+                f"among the {self.num_experts} routed over")
+        if self.router not in ("softmax", "sigmoid_groups"):
+            raise ValueError(f"router {self.router!r}")
+        if self.router == "sigmoid_groups" and (
+                self.num_experts % self.router_groups
+                or not 1 <= self.router_topk_groups <= self.router_groups
+                or self.num_experts // self.router_groups < 2
+                or self.num_experts_per_tok > self.router_topk_groups
+                * (self.num_experts // self.router_groups)):
+            raise ValueError(
+                f"{self.num_experts} experts do not make "
+                f"{self.router_groups} groups of two or more of which "
+                f"{self.router_topk_groups} hold "
+                f"{self.num_experts_per_tok} a token")
+        if self.kv_lora_rank and (
+                self.head_dim
+                != self.qk_nope_head_dim + self.qk_rope_head_dim
+                or not self.v_head_dim or not self.q_lora_rank
+                or self.rope_theta is None):
+            raise ValueError(
+                "latent attention wants q_lora_rank, v_head_dim, rope_theta "
+                "and head_dim = qk_nope_head_dim + qk_rope_head_dim")
 
     def layer_kinds(self) -> List[str]:
-        """``"attention"`` or ``"mamba"`` for each layer, in order."""
-        return ["attention" if i % self.attn_layer_period
+        """The sequence mixer of each layer, in order: ``"attention"``
+        (``"latent"`` where the configuration has a latent) or ``"mamba"``."""
+        attention = "latent" if self.kv_lora_rank else "attention"
+        return [attention if i % self.attn_layer_period
                 == self.attn_layer_offset else "mamba"
                 for i in range(self.n_layers)]
+
+    def ff_kinds(self) -> List[str]:
+        """The feed-forward of each layer, in order: ``"dense"`` (one SwiGLU)
+        or ``"sparse"`` (routed experts, and the shared one)."""
+        return ["sparse" if self.num_experts and i >= self.first_dense_layers
+                else "dense" for i in range(self.n_layers)]
+
+    @property
+    def latent_width(self) -> int:
+        """Numbers a latent-attention layer caches a position."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row_width(self) -> int:
+        """The width of a cached row: :attr:`latent_width` in whole lanes of
+        128, zeros behind the numbers.  A TPU tile pads a row to whole lanes
+        in memory whatever its declared width; declared so, the chip's
+        compiler appends to the page pool in place and gathers pages as they
+        lie, where at 576 of 640 it copies the pool into another layout and
+        back every step (8.97 against 5.06 ms a layer at 128 slots x 4096:
+        PERF.md, PR 43)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def holds_all_experts(self) -> bool:
+        return self.experts_held == self.num_experts
 
     @property
     def has_recurrent_layers(self) -> bool:

@@ -196,6 +196,14 @@ def make_lm_generate_fn(model: CausalLM, max_new_tokens: int,
 # ---------------------------------------------------------------------------
 
 
+#: the leaves of a paged cache that are page POOLS ``[num_pages, page_len,
+#: width]``, and the short name each travels under when pages are shipped
+#: (engine/dist/kv_transfer.py): an attention layer's K and V, a latent-
+#: attention layer's one latent pool
+PAGE_POOL_LEAVES = {"cached_key": "k", "cached_value": "v",
+                    "cached_latent": "c"}
+
+
 def _map_cache_leaf(cache, leaf, fn):
     """Rebuild a flax cache dict with ``fn`` applied to every ``leaf``-named
     entry (everything else passes through untouched)."""
@@ -219,7 +227,9 @@ def init_paged_cache(model: CausalLM, num_slots: int, num_pages: int,
     """Zero paged cache, asked of each layer by its kind: an attention
     layer gets page pools ``[num_pages, page_len, kv_heads*d]`` (page 0 = the
     pinned null page), a per-slot index vector ``[S]`` and a block table
-    ``[S, pages_per_slot]`` of page ids (0 = unreached/null); a Mamba layer
+    ``[S, pages_per_slot]`` of page ids (0 = unreached/null); a latent-
+    attention layer the same with ONE pool ``[num_pages, page_len,
+    latent_row_width]`` (``cached_latent``) in place of the two; a Mamba layer
     gets per-slot ``conv_state [S, (d_conv-1)*d_inner]`` and ``ssm_state [S,
     d_state, d_inner]`` (as its module lays them out), the index vector, and
     ``state_row`` / ``valid_len`` ``[S]``.  This is the persistent donated
@@ -239,6 +249,15 @@ def init_paged_cache(model: CausalLM, num_slots: int, num_pages: int,
                 out[k] = {
                     "cached_key": jnp.zeros((num_pages, page_len, hd), dt),
                     "cached_value": jnp.zeros((num_pages, page_len, hd), dt),
+                    "cache_index": jnp.zeros((num_slots,), jnp.int32),
+                    "block_table": jnp.zeros(
+                        (num_slots, pages_per_slot), jnp.int32),
+                }
+            elif "cached_latent" in v:
+                lat = v["cached_latent"]
+                out[k] = {
+                    "cached_latent": jnp.zeros(
+                        (num_pages, page_len, lat.shape[-1]), lat.dtype),
                     "cache_index": jnp.zeros((num_slots,), jnp.int32),
                     "block_table": jnp.zeros(
                         (num_slots, pages_per_slot), jnp.int32),
@@ -305,15 +324,17 @@ def _push_step_leaves(cache, pos, block_table):
         cache, "valid_len", lambda _: (pos > 0).astype(jnp.int32))
 
 
-def _with_routing(nxt, rows, live):
+def _with_routing(nxt, rows, live, held):
     """``nxt`` with a sparse-expert step's routing counters behind it
-    (:func:`make_paged_decode_body`): assignments to each expert over the
-    rows ``live [tokens]`` marks, then the experts the step streamed."""
+    (:func:`make_paged_decode_body`): assignments to each of the ``held``
+    experts over the rows ``live [tokens]`` marks (and, where only a share of
+    the experts is held, those rows' assignments sent elsewhere: ``rows`` has
+    that column), then the held experts the step streamed."""
     if rows is None:
         return nxt
     return jnp.concatenate([
         nxt, (rows * live.astype(jnp.int32)[None, :, None]).sum((0, 1)),
-        (rows.sum(1) > 0).sum(dtype=jnp.int32)[None]])
+        (rows[..., :held].sum(1) > 0).sum(dtype=jnp.int32)[None]])
 
 
 def _lora_head_delta(h, bank_a, bank_b, ids):
@@ -366,7 +387,10 @@ def make_paged_decode_body(model: CausalLM, slot_len: int,
     an exact-zero delta and stay bit-identical to the base model.
 
     For a sparse-expert model (``config.num_experts``) ``next_tok`` is
-    ``[S + E + 1]``: the ``S`` tokens; then the step's assignments to each
+    ``[S + E + 1]``, ``E`` the experts the tree holds (``[S + E + 2]`` where
+    that is a share of those routed over: the decoding rows' assignments to
+    experts held elsewhere follow the ``E``): the ``S`` tokens; then the
+    step's assignments to each
     of the ``E`` experts summed over layers and over the DECODING rows
     (``pos > 0``: the engine keeps a free or prefilling row at position 0,
     and a decoding row is past its prompt); then the number of experts the
@@ -382,7 +406,8 @@ def make_paged_decode_body(model: CausalLM, slot_len: int,
         if adapters:
             logits = logits + _lora_head_delta(h, bank_a, bank_b, adapter_ids)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return cache, _with_routing(nxt, rows, pos > 0)
+        return cache, _with_routing(nxt, rows, pos > 0,
+                                    model.config.experts_held)
 
     if not adapters:
         def base_step(params, cache, tok, pos, block_table):
@@ -624,7 +649,8 @@ def make_paged_mixed_body(model: CausalLM, page_len: int, slot_len: int,
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         s = tok.shape[0]
         live = jnp.concatenate([pos > 0, jnp.zeros((page_len,), bool)])
-        return cache, _with_routing(nxt[:s], rows, live), nxt[s]
+        return cache, _with_routing(nxt[:s], rows, live,
+                                    model.config.experts_held), nxt[s]
 
     if not adapters:
         def base_mixed(params, cache, tok, pos, block_table, ids, p0,
@@ -648,8 +674,9 @@ def make_lm_paged_mixed_step_fn(model: CausalLM, page_len: int,
 
 def page_copy_body(cache, dst, src):
     """The UNJITTED copy-on-write body: copy page ``src`` onto page ``dst``
-    in every attention layer's K and V pools; index and table leaves, and a
-    Mamba layer's per-slot state, pass through.
+    in every attention layer's page pools (K and V, or the one latent pool:
+    ``PAGE_POOL_LEAVES``); index and table leaves, and a Mamba layer's
+    per-slot state, pass through.
     Wrapped by :func:`make_page_copy_fn` (single chip) and the sharded
     factory (engine/dist/sharded.py)."""
     dst = dst.astype(jnp.int32) if hasattr(dst, "astype") else dst
@@ -660,7 +687,7 @@ def page_copy_body(cache, dst, src):
         for k, v in d.items():
             if isinstance(v, dict):
                 out[k] = walk(v)
-            elif k in ("cached_key", "cached_value"):
+            elif k in PAGE_POOL_LEAVES:
                 page = jax.lax.dynamic_slice(
                     v, (src, 0, 0), (1,) + v.shape[1:])
                 out[k] = jax.lax.dynamic_update_slice(

@@ -1,5 +1,5 @@
-"""Published (Hugging Face) OLMoE and Jamba configurations and weights ->
-``LMConfig`` and this framework's ``CausalLM`` parameter tree.
+"""Published (Hugging Face) OLMoE, Jamba and DeepSeek-V3 configurations and
+weights -> ``LMConfig`` and this framework's ``CausalLM`` parameter tree.
 
 Beside T5's importer (models/t5/hf_import.py).  Pure numpy: the converter
 only transposes, permutes and stacks, so it works on any element type (the
@@ -16,6 +16,16 @@ program is the published column ``i``, column ``2i + 1`` the published
 elementwise over the whole ``h*d`` vector and divide by a mean no ordering
 changes, so their weights are reordered the same way.  v, o and everything
 else are untouched.
+
+``model_type: deepseek_v3`` needs no such reordering: its published rotary
+embedding takes a head's rope dimensions in PAIRS ``(2j, 2j + 1)`` (the
+modeling code de-interleaves them before its rotate-half), which is
+``modeling.rope``'s own pairing, so the rope columns of ``q_b_proj`` and
+``kv_a_proj_with_mqa`` go in as published.  What is not a renaming there:
+``kv_b_proj`` is cut into its two halves a head (``k_up``, ``v_up``:
+``modeling.LatentAttention`` uses them apart), and the tree may hold a SHARE
+of the model (an expert-parallel rank): a range of the routed experts and a
+range of the vocabulary's rows.
 """
 
 from __future__ import annotations
@@ -82,6 +92,50 @@ JAMBA_FIXED = {
 }
 
 
+#: ``model_type: deepseek_v3`` (GigaChat3.1-702B-A36B): the keys mapped, and
+#: the values that must hold.  ``rope_scaling`` is null or yarn.
+DEEPSEEK_KEYS = {
+    "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads",
+    "moe_intermediate_size": "d_ff",       # one routed or shared expert
+    "intermediate_size": "dense_d_ff",     # the leading dense layers
+    "n_routed_experts": "num_experts",     # what the router scores
+    "num_experts_per_tok": "num_experts_per_tok",
+    "n_shared_experts": "num_shared_experts",
+    "first_k_dense_replace": "first_dense_layers",
+    "n_group": "router_groups",
+    "topk_group": "router_topk_groups",
+    "routed_scaling_factor": "router_scale",
+    "q_lora_rank": "q_lora_rank",
+    "kv_lora_rank": "kv_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim",
+    "qk_rope_head_dim": "qk_rope_head_dim",
+    "v_head_dim": "v_head_dim",
+    "vocab_size": "vocab_size",
+    "rope_theta": "rope_theta",
+    "rms_norm_eps": "rmsnorm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+DEEPSEEK_FIXED = {
+    "moe_layer_freq": 1,
+    "topk_method": "noaux_tc",
+    "scoring_func": "sigmoid",
+    "norm_topk_prob": True,
+    "attention_bias": False,
+    "hidden_act": "silu",
+}
+#: published ``rope_scaling`` keys (``rope_type: yarn``) -> ``LMConfig``
+YARN_KEYS = {
+    "factor": "rope_factor",
+    "original_max_position_embeddings": "rope_original_len",
+    "beta_fast": "rope_beta_fast",
+    "beta_slow": "rope_beta_slow",
+    "mscale": "rope_mscale",
+    "mscale_all_dim": "rope_mscale_all_dim",
+}
+
+
 def _check_fixed(hf: Dict[str, Any], fixed: Dict[str, Any]) -> None:
     for key, want in fixed.items():
         if hf.get(key, want) != want:
@@ -105,13 +159,49 @@ def _jamba_config_from_hf(hf: Dict[str, Any], dtype: str,
     return LMConfig(**fields)
 
 
+def _deepseek_config_from_hf(hf: Dict[str, Any], dtype: str,
+                             **overrides: Any) -> LMConfig:
+    _check_fixed(hf, DEEPSEEK_FIXED)
+    kv = hf.get("num_key_value_heads", hf["num_attention_heads"])
+    if kv != hf["num_attention_heads"]:
+        raise ValueError(
+            f"num_key_value_heads {kv} != num_attention_heads: every head of "
+            "latent attention expands its own K and V")
+    fields = {ours: hf[theirs] for theirs, ours in DEEPSEEK_KEYS.items()}
+    scaling = hf.get("rope_scaling")
+    if scaling is not None:
+        kind = scaling.get("rope_type", scaling.get("type"))
+        if kind != "yarn":
+            raise ValueError(f"rope_scaling {kind!r}: only yarn is "
+                             "implemented (models/lm/modeling.py)")
+        fields.update({ours: scaling[theirs]
+                       for theirs, ours in YARN_KEYS.items()
+                       if theirs in scaling})
+    fields.update(
+        head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
+        router="sigmoid_groups",
+        max_seq_len=hf.get("max_position_embeddings", 2048),
+        pad_token_id=hf.get("pad_token_id") or 0,
+        eos_token_id=hf.get("eos_token_id"),
+        dtype=dtype)
+    fields.update(overrides)
+    return LMConfig(**fields)
+
+
 def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
                       **overrides: Any) -> LMConfig:
-    """``LMConfig`` of a published ``olmoe`` or ``jamba`` ``config.json`` (a
-    dict).  Refuses a configuration whose layer this framework does not
-    compute."""
+    """``LMConfig`` of a published ``olmoe``, ``jamba`` or ``deepseek_v3``
+    ``config.json`` (a dict).  Refuses a configuration whose layer this
+    framework does not compute.  A tree that holds a share of a
+    ``deepseek_v3`` model says so in ``overrides``: ``experts_first`` /
+    ``experts_held`` (of the ``n_routed_experts`` the router scores) and the
+    ``vocab_size`` of its slice.  The multi-token module
+    (``num_nextn_predict_layers``) is an extra block the next-token logits do
+    not depend on; it is neither made nor run."""
     if hf.get("model_type") == "jamba":
         return _jamba_config_from_hf(hf, dtype, **overrides)
+    if hf.get("model_type") == "deepseek_v3":
+        return _deepseek_config_from_hf(hf, dtype, **overrides)
     _check_fixed(hf, HF_FIXED)
     kv = hf.get("num_key_value_heads", hf["num_attention_heads"])
     if kv != hf["num_attention_heads"]:
@@ -239,4 +329,78 @@ def convert_jamba_state_dict(get: Callable[[str], Any],
         params["lm_head"] = {"kernel": _t(get("lm_head.weight"))}
     for i in range(config.n_layers):
         params[f"layer_{i}"] = convert_jamba_layer(get, i, config)
+    return params
+
+
+def convert_deepseek_v3_layer(get: Callable[[str], Any], i: int,
+                              config: LMConfig) -> Dict[str, Any]:
+    """Layer ``i`` of the tree from the published ``deepseek_v3`` names.
+    Only the experts the configuration holds are asked for."""
+    pre = f"model.layers.{i}."
+    kernel = lambda name: {"kernel": _t(get(pre + name + ".weight"))}  # noqa: E731
+    weight = lambda name: {"weight": np.asarray(  # noqa: E731
+        get(pre + name + ".weight"))}
+    h, dn, r = config.n_heads, config.qk_nope_head_dim, config.kv_lora_rank
+    # [h * (dn + dv), r]: a head's k rows, then its v rows
+    kv_b = np.asarray(get(pre + "self_attn.kv_b_proj.weight")).reshape(
+        h, dn + config.v_head_dim, r)
+    layer = {
+        "attn_norm": weight("input_layernorm"),
+        "mlp_norm": weight("post_attention_layernorm"),
+        "attn": {
+            "q_a": kernel("self_attn.q_a_proj"),
+            "q_a_norm": weight("self_attn.q_a_layernorm"),
+            "q_b": kernel("self_attn.q_b_proj"),
+            "kv_a": kernel("self_attn.kv_a_proj_with_mqa"),
+            "kv_a_norm": weight("self_attn.kv_a_layernorm"),
+            "k_up": np.ascontiguousarray(kv_b[:, :dn].transpose(2, 0, 1)),
+            "v_up": np.ascontiguousarray(kv_b[:, dn:].transpose(2, 0, 1)),
+            "o": kernel("self_attn.o_proj"),
+        },
+    }
+    if config.ff_kinds()[i] == "dense":
+        layer["mlp"] = {w: kernel(f"mlp.{w}_proj")
+                        for w in ("gate", "up", "down")}
+        return layer
+    experts = range(config.experts_first,
+                    config.experts_first + config.experts_held)
+    stack = lambda which: np.stack([  # noqa: E731
+        _t(get(f"{pre}mlp.experts.{e}.{which}_proj.weight")) for e in experts])
+    layer["moe"] = {
+        "router": _t(get(pre + "mlp.gate.weight")),
+        "router_bias": np.asarray(
+            get(pre + "mlp.gate.e_score_correction_bias")),
+        "gate": stack("gate"), "up": stack("up"), "down": stack("down")}
+    if config.num_shared_experts:
+        layer["shared"] = {w: kernel(f"mlp.shared_experts.{w}_proj")
+                           for w in ("gate", "up", "down")}
+    return layer
+
+
+def convert_deepseek_v3_state_dict(get: Callable[[str], Any],
+                                   config: LMConfig,
+                                   vocab_rows: Any = None) -> Dict[str, Any]:
+    """The ``CausalLM`` parameter tree from a published ``deepseek_v3`` state
+    dict, given as ``get(name)``: the first ``config.n_layers`` layers, the
+    routed experts ``config.experts_first .. + config.experts_held`` of each
+    sparse layer, and the rows ``vocab_rows`` (a ``range`` or slice; default
+    all) of the embedding and of the head (``config.vocab_size`` of them).
+    The multi-token module's tensors (``model.layers.<num_hidden_layers>.*``)
+    are never asked for."""
+    rows = slice(None) if vocab_rows is None else vocab_rows
+    if isinstance(rows, range):
+        rows = slice(rows.start, rows.stop, rows.step)
+    take = lambda name: np.asarray(get(name))[rows]  # noqa: E731
+    params: Dict[str, Any] = {
+        "embedding": take("model.embed_tokens.weight"),
+        "final_norm": {"weight": np.asarray(get("model.norm.weight"))},
+    }
+    if not config.tie_embeddings:
+        params["lm_head"] = {"kernel": _t(take("lm_head.weight"))}
+    if params["embedding"].shape[0] != config.vocab_size:
+        raise ValueError(
+            f"{params['embedding'].shape[0]} vocabulary rows for a "
+            f"configuration of {config.vocab_size}")
+    for i in range(config.n_layers):
+        params[f"layer_{i}"] = convert_deepseek_v3_layer(get, i, config)
     return params

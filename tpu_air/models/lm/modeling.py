@@ -25,7 +25,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from tpu_air.ops import ssm
-from tpu_air.ops.decode_attention import flat_decode_attention, gather_pages
+from tpu_air.ops.decode_attention import (flat_decode_attention, gather_pages,
+                                          latent_decode_attention)
 
 from .config import LMConfig
 
@@ -59,11 +60,47 @@ class RMSNorm(nn.Module):
         return (w * x.astype(jnp.float32) * jax.lax.rsqrt(var + self.eps)).astype(self.dtype)
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float) -> Array:
+    """The ``dim / 2`` rotary frequencies stretched the yarn way (DeepSeek-V3's
+    ``yarn_find_correction_range`` and linear ramp): pair ``j`` keeps
+    ``theta**(-2j/dim)`` where it turns more than ``beta_fast`` times over
+    ``original_len`` positions, takes that over ``factor`` where it turns
+    fewer than ``beta_slow`` times, and a linear blend by pair index between
+    the two correction dimensions."""
+    import math
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_len / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001     # as published: no division by zero
+    j = jnp.arange(dim // 2, dtype=jnp.float32)
+    plain = theta ** (-2.0 * j / dim)
+    keep = 1.0 - jnp.clip((j - low) / (high - low), 0.0, 1.0)
+    return plain / factor * (1.0 - keep) + plain * keep
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V3's ``yarn_get_mscale``: ``0.1 * mscale * ln(factor) + 1``
+    (1 without stretching)."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope(x: Array, positions: Array, theta: float,
+         inv_freq: Optional[Array] = None) -> Array:
     """Rotary embedding.  x: (B, H, L, D), positions: (B, L) global token
-    positions (sequence-sharded models pass shard-offset positions)."""
+    positions (sequence-sharded models pass shard-offset positions).
+    ``inv_freq`` ``[D/2]``: the frequencies, where they are not the plain
+    ``theta**(-2j/D)`` (:func:`yarn_inv_freq`)."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = positions[:, None, :, None].astype(jnp.float32) * inv_freq  # (B,1,L,D/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x1, x2 = x[..., 0::2], x[..., 1::2]
@@ -335,29 +372,271 @@ class CausalSelfAttention(nn.Module):
         return proj("o", cfg.d_model)(o)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (``deepseek_v3``), per position ``t``::
+
+        c_q = RMSNorm(W_DQ h);  [q_n, q_r] = W_UQ c_q        h heads x (dn + dr)
+        [c, k_r] = W_DKV h;  c = RMSNorm(c);  q_r, k_r = rope(q_r), rope(k_r)
+        [k_n, v] = W_UKV c                                   h heads x (dn + dv)
+        score = sigma (q_n . k_n + q_r . k_r);  o = softmax(score) v;  out = W_O o
+
+    ``k_r`` is ONE vector every head shares, and what a position leaves
+    behind is ``[c, k_r]`` (``config.latent_width`` numbers): the cache is one
+    slab, or one page pool (``cached_latent``), whose rows are those numbers
+    in whole lanes (``config.latent_row_width``, zeros behind them).  ``W_UKV`` is
+    held as its two halves a head, ``k_up [r, h, dn]`` and ``v_up [r, h,
+    dv]``, because the two forms below use them apart.
+
+    Two forms of the same mathematics, picked from the call's shape at trace
+    time, no option.  EXPANDED (the whole sequence, a prefill chunk, a
+    prompt over the plain cache): K and V are made from the latent rows and
+    attention is the dense causal one.  ABSORBED (one query position a row:
+    a decode step): ``q~ = q_n W_UK^T`` scores the latent directly, the
+    context is taken in latent space and ``W_UV`` applied after
+    (``ops.decode_attention.latent_decode_attention``): the slab streams
+    once for all heads and no K or V is ever made.  The paged cache's writes
+    and reads, the plain cache and the mixed step are
+    :class:`CausalSelfAttention`'s, over the one pool.
+
+    Scopes (docs/OBSERVABILITY.md): ``mla_q`` the query's path (in a decode
+    step the fold of ``W_UK`` too), ``mla_latent`` the latent's (down, norm,
+    rope of ``k_r``, the append; in the expanded form ``W_UKV``),
+    ``kv_gather`` and ``decode_attention`` the latent gather and the absorbed
+    read, ``mla_out`` ``W_UV`` and ``W_O``."""
+
+    config: LMConfig
+
+    @nn.compact
+    def __call__(self, x: Array, positions: Array, decode: bool = False,
+                 chunk: Optional[ChunkRows] = None) -> Array:
+        cfg = self.config
+        dtype = jnp.dtype(cfg.dtype)
+        b, l, _ = x.shape
+        h, r = cfg.n_heads, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        if cfg.attention not in ("auto", "dense"):
+            raise ValueError(
+                f"latent attention is dense; attention={cfg.attention!r}")
+        init = nn.initializers.normal(0.02)
+
+        def proj(name, out):
+            return nn.Dense(out, use_bias=False, dtype=dtype,
+                            kernel_init=init, name=name)
+
+        inv_freq = None
+        if cfg.rope_factor > 1:
+            inv_freq = yarn_inv_freq(
+                dr, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_len,
+                cfg.rope_beta_fast, cfg.rope_beta_slow)
+        # as published: cos and sin times mscale / mscale_all_dim (1 where
+        # the two are equal), the softmax scale times mscale_all_dim's square
+        all_dim = yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+        wave = yarn_mscale(cfg.rope_factor, cfg.rope_mscale) / all_dim
+        scale = (dn + dr) ** -0.5 * all_dim ** 2
+
+        def turned(v):
+            v = rope(v, positions, cfg.rope_theta, inv_freq)
+            return v if wave == 1.0 else (v * wave).astype(v.dtype)
+
+        with jax.named_scope("mla_q"):
+            cq = RMSNorm(cfg.rmsnorm_eps, dtype, name="q_a_norm")(
+                proj("q_a", cfg.q_lora_rank)(x))
+            q = proj("q_b", h * (dn + dr))(cq)
+            q = q.reshape(b, l, h, dn + dr).transpose(0, 2, 1, 3)
+            q_n, q_r = q[..., :dn], turned(q[..., dn:])
+        with jax.named_scope("mla_latent"):
+            ckr = proj("kv_a", r + dr)(x)
+            c = RMSNorm(cfg.rmsnorm_eps, dtype, name="kv_a_norm")(ckr[..., :r])
+            k_r = turned(ckr[..., r:][:, None])[:, 0]
+            # a cached row: [c, k_r] and zeros up to whole lanes
+            w = cfg.latent_row_width
+            latent = jnp.concatenate(
+                [c, k_r, jnp.zeros((b, l, w - r - dr), c.dtype)],
+                -1).astype(dtype)                               # [b, l, w]
+        k_up = self.param("k_up", init, (r, h, dn), jnp.float32).astype(dtype)
+        v_up = self.param("v_up", init, (r, h, dv), jnp.float32).astype(dtype)
+        f32 = dict(preferred_element_type=jnp.float32)
+
+        def expanded(q_n, q_r, lat, q_offset):
+            """``q_* [B, h, Lq, .]`` over the latent rows ``lat [B, Lk, w]``,
+            causal with the first query at ``q_offset`` -> ``[B, Lq, h*dv]``."""
+            with jax.named_scope("mla_latent"):
+                k_n = jnp.einsum("bkr,rhn->bhkn", lat[..., :r], k_up)
+                v = jnp.einsum("bkr,rhv->bhkv", lat[..., :r], v_up)
+            with jax.named_scope("attn_scores"):
+                s = (jnp.einsum("bhqn,bhkn->bhqk", q_n, k_n, **f32)
+                     + jnp.einsum("bhqd,bkd->bhqk", q_r,
+                                  lat[..., r:r + dr], **f32)) * scale
+                lq, lk = q_n.shape[2], lat.shape[1]
+                qi = q_offset + jax.lax.broadcasted_iota(
+                    jnp.int32, (lq, lk), 0)
+                kj = jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 1)
+                s = jnp.where(qi >= kj, s, NEG_INF)
+            with jax.named_scope("attn_softmax"):
+                p = jax.nn.softmax(s, axis=-1)
+            with jax.named_scope("attn_context"):
+                o = jnp.einsum("bhqk,bhkv->bhqv", p.astype(dtype), v, **f32)
+            return o.astype(dtype).transpose(0, 2, 1, 3).reshape(
+                q_n.shape[0], q_n.shape[2], h * dv)
+
+        def absorbed(q_n, q_r, lat, kvm):
+            """``q_* [S, h, 1, .]``, each row over its own latent rows ``lat
+            [S, L, w]`` where ``kvm [S, L]`` -> ``[S, 1, h*dv]``."""
+            with jax.named_scope("mla_q"):
+                qt = jnp.einsum("shn,rhn->shr", q_n[:, :, 0], k_up, **f32)
+                qc = jnp.concatenate(
+                    [qt, q_r[:, :, 0].astype(jnp.float32)], -1) * scale
+                # against the row's trailing zeros
+                qc = jnp.pad(qc, ((0, 0), (0, 0), (0, lat.shape[-1] - r - dr)))
+            o_lat = latent_decode_attention(qc, lat, kvm, r, dtype)
+            with jax.named_scope("mla_out"):
+                o = jnp.einsum("shr,rhv->shv", o_lat, v_up, **f32)
+            return o.astype(dtype).reshape(q_n.shape[0], 1, h * dv)
+
+        def out(o):
+            with jax.named_scope("mla_out"):
+                return proj("o", cfg.d_model)(o)
+
+        if not decode:
+            return out(expanded(q_n, q_r, latent, 0))
+
+        max_len = cfg.max_seq_len
+        cl = self.variable("cache", "cached_latent", lambda: jnp.zeros(
+            (b, max_len, w), dtype))
+        idx = self.variable(
+            "cache", "cache_index", lambda: jnp.array(0, jnp.int32))
+        i = idx.value
+
+        def append_rows(pool, table, lat):
+            """Row ``s``'s new latent ``[S, 1, w]`` to its current ``(table[s,
+            i // C], i % C)``."""
+            C = pool.shape[1]
+            page = table[jnp.arange(table.shape[0]), i // C]
+            with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
+                return pool.at[page, i % C].set(lat[:, 0])
+
+        def append_chunk(pool, page, lat):
+            """One slot's chunk ``[1, C, w]`` over ``page``."""
+            with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
+                return jax.lax.dynamic_update_slice(pool, lat, (page, 0, 0))
+
+        def attend_rows(q_n, q_r, pool, table):
+            kvm = jnp.arange(table.shape[1] * pool.shape[1])[None, :] \
+                <= i[:, None]
+            return absorbed(q_n, q_r, gather_pages(pool, table), kvm)
+
+        if self.has_variable("cache", "block_table"):
+            # the engine's paged cache: ``cached_latent`` is ONE page pool
+            # [P, page_len, w]; table, null page and the three callers as
+            # CausalSelfAttention has them
+            table = self.variable(
+                "cache", "block_table",
+                lambda: jnp.zeros((b, 1), jnp.int32)).value
+            C = cl.value.shape[1]
+            if chunk is not None:
+                S = table.shape[0]
+                if l != 1 or b != S + C:
+                    raise ValueError(
+                        f"mixed step wants {S} + {C} rows of one token; "
+                        f"got b={b}, l={l}")
+                row = chunk.table_row[None]
+                pool = append_rows(cl.value, table, latent[:S])
+                pool = append_chunk(pool, row[0, chunk.start // C],
+                                    latent[S:, 0][None])
+                cl.value = pool
+                idx.value = i + 1
+                return out(jnp.concatenate([
+                    attend_rows(q_n[:S], q_r[:S], pool, table),
+                    expanded(q_n[S:].transpose(2, 1, 0, 3),
+                             q_r[S:].transpose(2, 1, 0, 3),
+                             gather_pages(pool, row), chunk.start
+                             ).reshape(C, 1, h * dv)]))
+            if l == 1:
+                cl.value = append_rows(cl.value, table, latent)
+                idx.value = i + 1
+                return out(attend_rows(q_n, q_r, cl.value, table))
+            if b != 1 or l != C:
+                raise ValueError(
+                    f"paged chunk prefill wants b=1, l=page_len ({C}); "
+                    f"got b={b}, l={l}")
+            p0 = i[0]
+            cl.value = append_chunk(cl.value, table[0, p0 // C], latent)
+            idx.value = i + l
+            return out(expanded(q_n, q_r, gather_pages(cl.value, table[:1]),
+                                p0))
+        with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
+            cl.value = jax.lax.dynamic_update_slice(cl.value, latent,
+                                                    (0, i, 0))
+        idx.value = i + l
+        if l == 1:
+            kvm = jnp.broadcast_to((jnp.arange(max_len) <= i)[None],
+                                   (b, max_len))
+            return out(absorbed(q_n, q_r, cl.value, kvm))
+        # future cache rows are zeros and kj > qi masks them out
+        return out(expanded(q_n, q_r, cl.value, i))
+
+
 class SwiGLU(nn.Module):
     config: LMConfig
+    width: Optional[int] = None     # default config.d_ff
 
     @nn.compact
     def __call__(self, x: Array) -> Array:
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
+        width = self.width or cfg.d_ff
         dense = lambda name, out: nn.Dense(  # noqa: E731
             out, use_bias=False, dtype=dtype,
             kernel_init=nn.initializers.normal(0.02), name=name)
-        gate = nn.silu(dense("gate", cfg.d_ff)(x))
-        up = dense("up", cfg.d_ff)(x)
+        gate = nn.silu(dense("gate", width)(x))
+        up = dense("up", width)(x)
         return dense("down", cfg.d_model)(gate * up)
 
 
+def grouped_sigmoid_routing(logits: Array, bias: Array, k: int, groups: int,
+                            topk_groups: int, scale: float):
+    """``deepseek_v3``'s ``noaux_tc`` routing over ``logits [t, E]`` float32:
+    ``s = sigmoid(logits)``; the selection scores are ``s + bias``; the
+    ``groups`` groups of ``E / groups`` consecutive experts are ranked by the
+    sum of their two largest selection scores and the best ``topk_groups``
+    stay; the token's experts are the ``k`` largest selection scores among
+    those groups' experts; their weights are ``scale * s_e / (sum of the
+    chosen s + 1e-20)``: the bias selects, it does not weigh.  Ties go to the
+    lower index (``lax.top_k``).  Returns ``(weights [t, k] float32, chosen
+    [t, k] int32)``."""
+    t, e = logits.shape
+    s = jax.nn.sigmoid(logits)
+    pick = s + bias
+    by_group = pick.reshape(t, groups, e // groups)
+    rank = jax.lax.top_k(by_group, 2)[0].sum(-1)              # [t, groups]
+    best = jax.lax.top_k(rank, topk_groups)[1]
+    stays = jnp.zeros((t, groups), bool).at[
+        jnp.arange(t)[:, None], best].set(True)
+    among = jnp.where(stays[:, :, None], by_group, -jnp.inf).reshape(t, e)
+    chosen = jax.lax.top_k(among, k)[1]
+    w = jnp.take_along_axis(s, chosen, axis=-1)
+    return scale * w / (w.sum(-1, keepdims=True) + 1e-20), chosen
+
+
 class SparseExperts(nn.Module):
-    """Routed feed-forward: ``y[t] = sum over the top-k experts e of p[t, e]
-    * down_e(silu(gate_e x[t]) * up_e x[t])`` with ``p = softmax(x @ router)``
-    in float32 and NOT renormalised over the chosen k.  Every assignment is
-    computed (``ops.moe.expert_ffn``: no capacity, nothing dropped or
-    re-routed, whatever the load).  Sows ``expert_rows`` ``[tokens, E]``
-    int32 (1 where the token went to the expert) into ``intermediates`` for
-    callers that make it mutable: the engine's routing counters."""
+    """Routed feed-forward: ``y[t] = sum over the token's top-k experts e of
+    w[t, e] * down_e(silu(gate_e x[t]) * up_e x[t])``.  The router is float32
+    and follows ``config.router``: ``"softmax"``: ``w = softmax(x @ router)``
+    NOT renormalised over the chosen k (OLMoE); ``"sigmoid_groups"``:
+    :func:`grouped_sigmoid_routing` with the learned selection bias
+    ``router_bias`` (``deepseek_v3``).  Every assignment to an expert this
+    tree HOLDS is computed (``ops.moe.expert_ffn``: no capacity, nothing
+    dropped or re-routed, whatever the load).  The tree holds
+    ``config.experts_held`` experts from id ``config.experts_first``; the
+    router scores all ``config.num_experts``, and an assignment to an expert
+    held elsewhere is counted and contributes nothing here (its rank adds
+    it; the sum of all ranks' outputs is the uncut layer's).
+
+    Sows ``expert_rows`` int32 into ``intermediates`` for callers that make
+    it mutable (the engine's routing counters): ``[tokens, E]``, 1 where the
+    token went to the expert; where only a share is held ``[tokens, held +
+    1]``, the held experts' columns and then HOW MANY of the token's
+    assignments went elsewhere."""
 
     config: LMConfig
 
@@ -369,28 +648,46 @@ class SparseExperts(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         e, k, d, f = (cfg.num_experts, cfg.num_experts_per_tok, cfg.d_model,
                       cfg.d_ff)
+        held, whole = cfg.experts_held, cfg.holds_all_experts
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (d, e), jnp.float32)
-        gate = self.param("gate", init, (e, d, f), jnp.float32)
-        up = self.param("up", init, (e, d, f), jnp.float32)
-        down = self.param("down", init, (e, f, d), jnp.float32)
+        gate = self.param("gate", init, (held, d, f), jnp.float32)
+        up = self.param("up", init, (held, d, f), jnp.float32)
+        down = self.param("down", init, (held, f, d), jnp.float32)
         t = x.reshape(-1, d)
         with jax.named_scope("moe_router"):
             logits = jnp.dot(t.astype(jnp.float32), router.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            probs, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-            self.sow("intermediates", "expert_rows",
-                     jnp.zeros((t.shape[0], e), jnp.int32).at[
-                         jnp.arange(t.shape[0])[:, None], chosen].set(1))
+            if cfg.router == "softmax":
+                probs, chosen = jax.lax.top_k(
+                    jax.nn.softmax(logits, axis=-1), k)
+            else:
+                bias = self.param("router_bias", nn.initializers.zeros, (e,),
+                                  jnp.float32)
+                probs, chosen = grouped_sigmoid_routing(
+                    logits, bias.astype(jnp.float32), k, cfg.router_groups,
+                    cfg.router_topk_groups, cfg.router_scale)
+            if not whole:
+                # ids among the held ones; ``held`` itself: held elsewhere
+                local = chosen - cfg.experts_first
+                chosen = jnp.where((local >= 0) & (local < held), local, held)
+            rows = jnp.zeros((t.shape[0], held), jnp.int32).at[
+                jnp.arange(t.shape[0])[:, None], chosen].set(1, mode="drop")
+            if not whole:
+                rows = jnp.concatenate(
+                    [rows, (chosen == held).sum(-1, dtype=jnp.int32)[:, None]],
+                    axis=-1)
+            self.sow("intermediates", "expert_rows", rows)
         y = expert_ffn(t.astype(dtype), chosen, probs, gate.astype(dtype),
-                       up.astype(dtype), down.astype(dtype))
+                       up.astype(dtype), down.astype(dtype), partial=not whole)
         return y.astype(dtype).reshape(x.shape)
 
 
 def expert_assignments(intermediates) -> Array:
-    """``[layers, tokens, E]`` int32: what every ``SparseExperts`` layer
-    sowed as ``expert_rows`` (callers sum or count over the layers: their
-    order here is the tree's, not the model's)."""
+    """``[layers, tokens, E]`` int32 (``E``: the experts held, and one more
+    column where a share is held): what every ``SparseExperts`` layer sowed
+    as ``expert_rows`` (callers sum or count over the layers: their order
+    here is the tree's, not the model's)."""
     return jnp.stack([
         v for path, v in jax.tree_util.tree_flatten_with_path(intermediates)[0]
         if any(getattr(p, "key", None) == "expert_rows" for p in path)])
@@ -610,6 +907,7 @@ class MambaMixer(nn.Module):
 class Block(nn.Module):
     config: LMConfig
     kind: str = "attention"   # LMConfig.layer_kinds()
+    ff: str = "dense"         # LMConfig.ff_kinds()
 
     @nn.compact
     def __call__(self, x: Array, positions: Array, deterministic: bool = True,
@@ -625,15 +923,23 @@ class Block(nn.Module):
                 RMSNorm(cfg.rmsnorm_eps, dtype, name="mamba_norm")(x),
                 decode=decode, chunk=chunk))
         else:
-            x = x + drop(CausalSelfAttention(cfg, name="attn")(
+            mixer = (LatentAttention if self.kind == "latent"
+                     else CausalSelfAttention)
+            x = x + drop(mixer(cfg, name="attn")(
                 RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(x),
                 positions, decode=decode, chunk=chunk,
             ))
         # the feed-forward kind follows from the configuration's numbers
-        ff = (SparseExperts(cfg, name="moe") if cfg.num_experts
-              else SwiGLU(cfg, name="mlp"))
-        x = x + drop(ff(RMSNorm(cfg.rmsnorm_eps, dtype, name="mlp_norm")(x)))
-        return x
+        h = RMSNorm(cfg.rmsnorm_eps, dtype, name="mlp_norm")(x)
+        if self.ff != "sparse":
+            return x + drop(SwiGLU(cfg, cfg.dense_d_ff, name="mlp")(h))
+        y = SparseExperts(cfg, name="moe")(h)
+        if cfg.num_shared_experts:
+            # every token's, beside its routed ones
+            with jax.named_scope("moe_shared"):
+                y = y + SwiGLU(cfg, cfg.num_shared_experts * cfg.d_ff,
+                               name="shared")(h)
+        return x + drop(y)
 
 
 class CausalLM(nn.Module):
@@ -666,8 +972,9 @@ class CausalLM(nn.Module):
             jnp.float32,
         )
         x = embed[input_ids].astype(dtype)
-        for i, kind in enumerate(cfg.layer_kinds()):
-            x = Block(cfg, kind, name=f"layer_{i}")(
+        for i, (kind, ff) in enumerate(zip(cfg.layer_kinds(),
+                                           cfg.ff_kinds())):
+            x = Block(cfg, kind, ff, name=f"layer_{i}")(
                 x, positions, deterministic, decode=decode, chunk=chunk)
         x = RMSNorm(cfg.rmsnorm_eps, dtype, name="final_norm")(x)
         if return_hidden:

@@ -388,6 +388,17 @@ class LMCostModel:
     reads and writes for EVERY row and a chunk for one.  The recurrence's own
     arithmetic (``~7*c*n`` a token a layer) is counted; it is vector work.
 
+    Feed-forward by layer (``config.ff_kinds()``) and held experts: the
+    ``S`` sparse layers store ``held * 3*D*F`` (``config.experts_held`` of the
+    ``E`` routed over), the ``D*E`` router and ``shared * 3*D*F``; a token
+    computes with ``k * held/E`` held experts in expectation, plus the
+    shared one; the other layers keep one SwiGLU of ``dense_d_ff``.  Latent
+    attention (``config.kv_lora_rank = r``): the layer's matrices are ``D*rq
+    + rq*H*(dn+dr) + D*(r+dr) + r*H*(dn+dv) + H*dv*D``, a position keeps
+    ``(r+dr) * b`` bytes a layer, and a token attending ``P`` positions in
+    the absorbed form costs ``4*H*(r+dr)*P`` (both contractions run over the
+    slab's whole width).
+
     Norms, rotary embeddings and softmax are omitted (≪1% of the matmul
     budget at any real geometry); the model is deliberately closed-form so
     identical claims can be recomputed anywhere (arXiv:2204.06514 §4).
@@ -416,10 +427,32 @@ class LMCostModel:
         self.d_state = int(getattr(config, "mamba_d_state", 0) or 0)
         self.d_conv = int(getattr(config, "mamba_d_conv", 0) or 0)
         self.dt_rank = int(getattr(config, "mamba_dt_rank", 0) or 0)
+        ff = (config.ff_kinds() if hasattr(config, "ff_kinds")
+              else ["sparse" if self.num_experts else "dense"]
+              * self.n_layers)
+        self.n_sparse_layers = ff.count("sparse")
+        self.dense_d_ff = int(getattr(config, "dense_d_ff", None)
+                              or self.d_ff)
+        self.experts_held = int(getattr(config, "experts_held", None)
+                                or self.num_experts)
+        self.shared_experts = int(
+            getattr(config, "num_shared_experts", 0) or 0)
+        self.kv_lora_rank = int(getattr(config, "kv_lora_rank", 0) or 0)
+        self.q_lora_rank = int(getattr(config, "q_lora_rank", 0) or 0)
+        self.qk_nope = int(getattr(config, "qk_nope_head_dim", 0) or 0)
+        self.qk_rope = int(getattr(config, "qk_rope_head_dim", 0) or 0)
+        self.v_head_dim = int(getattr(config, "v_head_dim", 0) or 0)
 
     # -- derived geometry ----------------------------------------------------
     @property
     def _attn_params(self) -> int:
+        if self.kv_lora_rank:
+            d, h, r = self.d_model, self.n_heads, self.kv_lora_rank
+            return (d * self.q_lora_rank
+                    + self.q_lora_rank * h * (self.qk_nope + self.qk_rope)
+                    + d * (r + self.qk_rope)
+                    + r * h * (self.qk_nope + self.v_head_dim)
+                    + h * self.v_head_dim * d)
         return 2 * self.d_model * self.head_dim * (
             self.n_heads + self.n_kv_heads)
 
@@ -442,25 +475,31 @@ class LMCostModel:
         expert."""
         return 3 * self.d_model * self.d_ff
 
-    def _layer_params(self, experts: int) -> int:
-        """Matrix parameters of the layers with ``experts`` experts counted
-        in each (a dense model has the one feed-forward and no router)."""
-        ff = self._expert_params
-        if self.num_experts:
-            ff = experts * ff + self.d_model * self.num_experts
+    def _layer_params(self, experts: float) -> float:
+        """Matrix parameters of the layers with ``experts`` routed experts
+        counted in each sparse one (beside its router and shared expert; a
+        dense layer has the one feed-forward and no router)."""
+        sparse = ((experts + self.shared_experts) * self._expert_params
+                  + self.d_model * self.num_experts)
+        dense = 3 * self.d_model * self.dense_d_ff
         return (self.n_attn_layers * self._attn_params
                 + self.n_mamba_layers * self._mamba_params
-                + self.n_layers * ff)
+                + self.n_sparse_layers * sparse
+                + (self.n_layers - self.n_sparse_layers) * dense)
 
     @property
     def matmul_params(self) -> int:
         """Matrix parameters STORED in the layers."""
-        return self._layer_params(self.num_experts)
+        return self._layer_params(self.experts_held)
 
     @property
-    def active_matmul_params(self) -> int:
-        """Matrix parameters one token COMPUTES with in the layers."""
-        return self._layer_params(self.experts_per_tok)
+    def active_matmul_params(self) -> float:
+        """Matrix parameters one token COMPUTES with in the layers (of its
+        ``k`` experts the share this tree holds, in expectation)."""
+        if not self.num_experts:
+            return self._layer_params(0)
+        return self._layer_params(
+            self.experts_per_tok * self.experts_held / self.num_experts)
 
     @property
     def param_count(self) -> int:
@@ -479,7 +518,8 @@ class LMCostModel:
         if not self.num_experts:
             return 0.0
         e = self.num_experts
-        return e * (1.0 - (1.0 - 1.0 / e) ** (tokens * self.experts_per_tok))
+        return self.experts_held * (
+            1.0 - (1.0 - 1.0 / e) ** (tokens * self.experts_per_tok))
 
     def streamed_param_bytes(self, tokens: int) -> float:
         """Parameter bytes a program over ``tokens`` tokens reads: all of
@@ -488,8 +528,8 @@ class LMCostModel:
         touched."""
         if not self.num_experts:
             return float(self.param_bytes)
-        idle = self.num_experts - self.experts_touched(tokens)
-        return (self.param_count - self.n_layers * idle
+        idle = self.experts_held - self.experts_touched(tokens)
+        return (self.param_count - self.n_sparse_layers * idle
                 * self._expert_params) * self.dtype_bytes
 
     @property
@@ -500,12 +540,17 @@ class LMCostModel:
 
     @property
     def kv_bytes_per_position(self) -> float:
+        if self.kv_lora_rank:
+            return self.n_attn_layers * (self.kv_lora_rank + self.qk_rope) \
+                * self.dtype_bytes
         return self.n_attn_layers * 2 * self.n_kv_heads * self.head_dim \
             * self.dtype_bytes
 
     def attention_flops(self, attended_positions: float) -> float:
         """Per ONE token attending over ``attended_positions``."""
-        return self.n_attn_layers * 4.0 * self.n_heads * self.head_dim \
+        width = (self.kv_lora_rank + self.qk_rope if self.kv_lora_rank
+                 else self.head_dim)
+        return self.n_attn_layers * 4.0 * self.n_heads * width \
             * attended_positions
 
     # -- program costs -------------------------------------------------------

@@ -13,6 +13,9 @@ step                   ``[L, b, h]``
 the LM's K/V: pages    FLAT ``[b, L, h*d]``,       :func:`flat_decode_attention`
 gathered for the step  scales ``[b, L, h]`` or
                        ``[b, 1, h*d]``
+the LM's latent:       ONE slab ``[b, L, w]``       :func:`latent_decode_attention`
+pages gathered for     (latent ``r``, roped key
+the step               ``dr``, zeros), no scales
 =====================  ==========================  ================================
 
 FLAT: all heads ride one batched MXU matmul per contraction via a
@@ -157,6 +160,29 @@ def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
         ctx = ctx * v_scale[:, 0, None, :]
     ctx = jnp.swapaxes(ctx.reshape(b, r, g, d), 1, 2)          # [b, g, r, d]
     return ctx.reshape(b, 1, h, d).astype(dtype)
+
+
+@jax.named_scope("decode_attention")
+def latent_decode_attention(q, latent, kv_mask, rank, dtype):
+    """Single-token ABSORBED attention over a latent slab ``[b, L, w]``
+    (``r = rank`` numbers of joint K/V latent, then the ``dr`` of the one
+    roped key every head shares, then zeros up to whole lanes; ``q`` is as
+    wide): every head's query, already folded into latent space
+    (``q [b, h, r + dr]``: ``q_nope W_UK`` then the roped part, the softmax
+    scale folded in), scores the SAME row, so the ``h`` heads are the rows of
+    one matmul a sequence and the slab streams once for all of them; the
+    context is taken in latent space (``[b, h, r]``: the caller applies
+    ``W_UV``).  The second contraction runs over the slab's whole width and
+    the roped key's ``dr`` columns of the result are dropped: slicing them
+    off the slab first would copy it.  ``kv_mask [b, L]`` is per-row key
+    validity; scores and softmax in f32, operands in the model dtype."""
+    s = jnp.einsum("bhc,blc->bhl", q.astype(dtype), latent.astype(dtype),
+                   preferred_element_type=jnp.float32)
+    s = s + jnp.where(kv_mask > 0, 0.0, _NEG_INF_DENSE)[:, None, :]
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bhl,blc->bhc", p.astype(dtype), latent.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    return ctx[..., :rank].astype(dtype)
 
 
 @jax.named_scope("decode_attention")

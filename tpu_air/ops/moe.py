@@ -6,6 +6,13 @@ over the ``k``: exactly the published sum.  There is no capacity and no
 fixed group size, so no assignment is dropped or re-routed however uneven
 the load.  Inputs in the model's dtype, accumulation in float32.
 
+The caller may hold only SOME of the experts the router scores (an
+expert-parallel rank: ``partial``): an assignment to an expert held elsewhere
+comes in with the id one past the last held expert, sorts behind every held
+group, belongs to no group, and no product touches its row; the rows past the
+last group come out as zeros.  Shapes stay static: such rows cost the sort
+and nothing else.
+
 One formulation, no switch: rows are sorted by expert and the three
 products run as grouped matmuls over the uneven groups (the Pallas
 ``megablox`` kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere — the same
@@ -26,30 +33,50 @@ Array = jax.Array
 # 2048 x 1024 (PERF.md, PR 27).  A device trace names the kernel's calls
 # ``gmm``, ``gmm.1``, ...
 GMM_TILING = (128, 2048, 1024)
+# where GMM_TILING does not divide the shapes (a contraction of 7168 = 7 x
+# 1024): the fastest of twelve tried on the v5e (tools/gmm_tilings.py) at 16
+# groups of 7168 x 2048, 64 to 1024 held rows among 1024 to 3072 sorted:
+# 0.67 to 0.80 ms a product where the 470 MB of weights take 0.57 at the HBM
+# peak; (128, 1024, 1024) reads 0.71 to 0.83, (128, 3584, 512) 0.85 to 0.97,
+# and a contraction tile of 1792 or more beside 2048 columns does not fit
+# VMEM.  The down product (2048 x 7168) keeps GMM_TILING, the fastest of ten
+# there (0.64 to 0.71 ms).  PERF.md, PR 43.
+GMM_TILING_K1024 = (128, 1024, 2048)
+
+
+def gmm_tiling(m: int, kdim: int, n: int):
+    """The kernel tile for ``[m, kdim] x [g, kdim, n]``: the first of the
+    measured tilings whose every side divides the shapes and is whole lanes,
+    or None (no kernel: the shapes go to ``ragged_dot``)."""
+    for tiling in (GMM_TILING, GMM_TILING_K1024):
+        tm, tk, tn = tiling[0], min(tiling[1], kdim), min(tiling[2], n)
+        if (m % tm == 0 and kdim % tk == 0 and n % tn == 0
+                and tk % 128 == 0 and tn % 128 == 0):
+            return tm, tk, tn
+    return None
 
 
 def _grouped(lhs: Array, rhs: Array, sizes: Array) -> Array:
     """``lhs [m, k]`` (rows sorted by group) times ``rhs [g, k, n]`` with
-    ``sizes [g]`` rows a group -> ``[m, n]`` float32."""
+    ``sizes [g]`` rows a group -> ``[m, n]`` float32.  Rows past the last
+    group are undefined (the kernel never visits them)."""
     m, kdim = lhs.shape
-    n = rhs.shape[-1]
-    tm, tk, tn = GMM_TILING[0], min(GMM_TILING[1], kdim), min(GMM_TILING[2], n)
-    if (jax.default_backend() == "tpu" and m % tm == 0
-            and kdim % tk == 0 and n % tn == 0 and tk % 128 == 0
-            and tn % 128 == 0):
+    tiling = gmm_tiling(m, kdim, rhs.shape[-1])
+    if jax.default_backend() == "tpu" and tiling is not None:
         from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
         return gmm(lhs, rhs, sizes, preferred_element_type=jnp.float32,
-                   tiling=(tm, tk, tn))
+                   tiling=tiling)
     return jax.lax.ragged_dot(lhs, rhs, sizes,
                               preferred_element_type=jnp.float32)
 
 
 def expert_ffn(x: Array, chosen: Array, weights: Array, gate: Array,
-               up: Array, down: Array) -> Array:
+               up: Array, down: Array, partial: bool = False) -> Array:
     """``x [t, d]``; ``chosen [t, k]`` int expert ids; ``weights [t, k]``
     float32; ``gate``/``up`` ``[e, d, f]``, ``down [e, f, d]`` -> ``[t, d]``
-    float32."""
+    float32.  ``partial``: ``chosen`` may hold the id ``e``, an expert held
+    elsewhere (module doc)."""
     t, k = chosen.shape
     e = gate.shape[0]
     with jax.named_scope("moe_sort"):
@@ -63,5 +90,8 @@ def expert_ffn(x: Array, chosen: Array, weights: Array, gate: Array,
         y = _grouped(h, down, sizes)
     with jax.named_scope("moe_combine"):
         y = y * weights.reshape(-1)[order][:, None]
+        if partial:
+            # rows of no group hold whatever the kernel's buffer held
+            y = jnp.where((flat[order] < e)[:, None], y, 0.0)
         back = jnp.zeros((t * k, y.shape[-1]), jnp.float32).at[order].set(y)
         return back.reshape(t, k, -1).sum(1)
