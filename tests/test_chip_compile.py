@@ -642,3 +642,77 @@ def test_mixed_step_streams_each_weight_once_on_v5e(v5e, monkeypatch, family):
     twice = _weight_consumers(
         lowered(both, _hybrid(3, 3, 1)).compile().as_text())
     assert {len(r) for w, r in twice.items() if "embedding" not in w} == {2}
+
+
+# -- the latent page pool read in place (PR 44) --------------------------------
+
+_LATENT_SLAB = r"bf16\[128,4096,640\]"           # every slot's pages, gathered
+_LATENT_POOL_MOVED = r"= bf16\[2049,256,640\]\S* (copy|transpose)\("
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_latent_decode_rows_read_their_pages_in_place_on_v5e(
+        v5e, monkeypatch, program):
+    """The latent family's two programs that decode rows, at
+    ``gigachat-serve-docchat``'s widths, depth and geometry (128 slots x 4096,
+    pages of 256 x 640): the chip's compiler takes them with the paged kernel
+    in every layer beside the twelve grouped products; nothing of the
+    gathered slab's shape ``[128, 4096, 640]`` is made (the gathered read
+    makes it: the counter sees it there), no pool is copied around the
+    kernel's call or laid out anew for it, and the donated pools come back
+    aliased.  The mixed step keeps the chunk's gather of its ONE slot."""
+    import json
+
+    from benchmark import weights_mla
+    from tpu_air.models.lm import CausalLM
+    from tpu_air.models.lm.generate import (make_paged_decode_body,
+                                            make_paged_mixed_body)
+    from tpu_air.ops import decode_attention as da
+
+    slots, slot_len, page = 128, 4096, 256
+    npg = slot_len // page
+    if program == "decode":
+        # the counter is not vacuous: the gathered read alone holds the slab
+        def gathered(q, pool, table, pos):
+            kvm = jnp.arange(slot_len)[None, :] <= pos[:, None]
+            return da.latent_decode_attention(
+                q, da.gather_pages(pool, table), kvm, 512, jnp.bfloat16)
+
+        text = jax.jit(gathered).lower(
+            _struct((slots, 64, 640), jnp.bfloat16, v5e),
+            _struct((slots * npg + 1, page, 640), jnp.bfloat16, v5e),
+            _struct((slots, npg), jnp.int32, v5e),
+            _struct((slots,), jnp.int32, v5e)).compile().as_text()
+        assert re.search(_LATENT_SLAB, text)
+
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "gigachat3.1-702b-a36b.json")) as f:
+        cfg = weights_mla.lm_config(json.load(f), "bfloat16", slot_len)
+    model = CausalLM(cfg)
+    params, cache, i32 = _serving_pool(model, slots, slot_len, page, v5e)
+    assert cache["layer_0"]["attn"]["cached_latent"].shape == (
+        slots * npg + 1, page, 640)
+    step = (params, cache, i32(slots), i32(slots), i32(slots, npg))
+    if program == "decode":
+        lowered = jax.jit(make_paged_decode_body(model, slot_len),
+                          donate_argnums=(1,)).lower(*step)
+    else:
+        lowered = jax.jit(make_paged_mixed_body(model, page, slot_len),
+                          donate_argnums=(1,)).lower(
+            *step, i32(1, page), i32(), i32(), i32(npg))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers + 3 * 4
+    assert sum("tpu_custom_call" in line
+               and "paged_latent_decode_attention" in line
+               for line in text.splitlines()) == cfg.n_layers
+    assert not re.search(_LATENT_SLAB, text)
+    assert not re.search(_LATENT_POOL_MOVED, text)
+    pools = cfg.n_layers * (slots * npg + 1) * page * 640 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools           # appended to in place
+    assert mem.temp_size_in_bytes < pools // 4        # and no second pool
+    if program == "mixed":
+        assert re.search(r"bf16\[1,4096,640\]", text)  # the chunk's own slot

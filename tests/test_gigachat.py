@@ -547,12 +547,36 @@ def _engine(tiny, **kw):
                            auto_start=False)
 
 
+@pytest.fixture(params=["gathered", "in_place"])
+def read(request, monkeypatch):
+    """How the step's rows read the latent pool.  ``gathered``: what the rule
+    picks off a TPU.  ``in_place``: the rule's answer on a TPU at a geometry
+    of whole tiles (the engine's here: float32 pages of 16 or 8 x 128), so
+    the kernel runs, in interpret mode, inside the engine's own decode and
+    mixed programs."""
+    from tpu_air.ops import decode_attention as da
+
+    traced = []
+
+    def kernel(*args, _fn=da.paged_latent_decode_attention, **kw):
+        traced.append(args[1].shape)
+        return _fn(*args, **kw)
+
+    monkeypatch.setattr(da, "paged_latent_decode_attention", kernel)
+    if request.param == "in_place":
+        monkeypatch.setattr(da, "latent_pages_read_in_place",
+                            da.pages_are_whole_tiles)
+    yield request.param
+    # a fallback cannot pass silently, nor the kernel slip into a CPU run
+    assert bool(traced) == (request.param == "in_place")
+
+
 def _reference_rows(sd, prompt, answer):
     ids = list(prompt) + list(answer[:-1])
     return _ref(sd, ids, range(len(prompt) - 1, len(ids)))["logits"]
 
 
-def test_chunked_prefill_then_paged_decode_matches_the_reference(tiny):
+def test_chunked_prefill_then_paged_decode_matches_the_reference(tiny, read):
     """Logits, not tokens: prompts that cross a chunk boundary and end in a
     padded chunk, one that fills its last chunk and one shorter than a chunk,
     through the engine's chunk (expanded) and decode (absorbed) programs over
@@ -587,9 +611,15 @@ def test_chunked_prefill_then_paged_decode_matches_the_reference(tiny):
     assert snap["latent_positions_pool"] == 4 * 256
     want_live = 2 * sum(len(p) + j + 1 for p in prompts for j in range(5))
     assert snap["latent_positions_live"] == want_live
+    # and the pages they span (page 16): a token computed at position q has
+    # pages 0 .. q // 16 live
+    assert snap["latent_pages_read"] == 2 * sum(
+        (len(p) + j) // 16 + 1 for p in prompts for j in range(5))
+    assert snap["latent_pages_read"] == 2 * (
+        5 * 3 + 5 * 3 + 5 * 1 + 5 * 4)
 
 
-def test_engine_streams_the_tokens_of_offline_generate(tiny):
+def test_engine_streams_the_tokens_of_offline_generate(tiny, read):
     from tpu_air.models.lm.generate import generate
 
     _, config, model, params = tiny
@@ -603,7 +633,7 @@ def test_engine_streams_the_tokens_of_offline_generate(tiny):
         assert np.asarray(want)[0].tolist() == g
 
 
-def test_a_row_mid_prefill_rides_the_mixed_step(tiny):
+def test_a_row_mid_prefill_rides_the_mixed_step(tiny, read):
     """A long prompt's chunks go out one an iteration inside the decode step
     of the rows already streaming: the chunk's half (expanded, over the
     slot's pages) and the step's half (absorbed) in one program."""
@@ -632,7 +662,7 @@ def test_a_row_mid_prefill_rides_the_mixed_step(tiny):
 
 
 @pytest.mark.parametrize("case", sorted(_mixed_step_cases.CASES))
-def test_mixed_step(tiny, case):
+def test_mixed_step(tiny, case, read):
     from tpu_air.models.lm.generate import generate
 
     _, config, model, params = tiny
@@ -645,7 +675,7 @@ def test_mixed_step(tiny, case):
     _mixed_step_cases.CASES[case](model, params, check)
 
 
-def test_prefix_sharing_and_copy_on_write_work_on_latent_pages(tiny):
+def test_prefix_sharing_and_copy_on_write_work_on_latent_pages(tiny, read):
     """A latent page is addressed by position like a K/V page: a second
     request with the same first pages hits the prefix cache, and its answer
     is the one a cold engine gives."""
@@ -679,7 +709,7 @@ def test_prefix_sharing_and_copy_on_write_work_on_latent_pages(tiny):
     cold.close()
 
 
-def test_latent_pages_migrate(tiny):
+def test_latent_pages_migrate(tiny, read):
     """``migrate_out`` / ``submit_migrated`` ship a latent layer's ONE pool
     under ``c``; the stream goes on at the destination token for token."""
     rng = np.random.default_rng(12)
@@ -719,6 +749,47 @@ def test_mesh_engine_refuses_held_experts_by_name(tiny):
                    EngineConfig(num_slots=4, slot_len=64, page_len=16,
                                 max_new_tokens=4),
                    dp=2, tp=1, auto_start=False)
+
+
+def test_the_mesh_engines_step_is_traced_for_its_mesh(monkeypatch):
+    """What picks the paged kernel sees a program the partitioner will split
+    (``engine/dist/sharded.py`` traces the decode step under
+    ``kernel_mesh``) and keeps the gather there; the single-chip engine's
+    programs are traced under none.  A latent-attention model with dense
+    feed-forwards (``MeshEngine`` takes no routed experts) streams the single
+    engine's tokens."""
+    import dataclasses
+
+    from tpu_air.engine import EngineConfig, InferenceEngine
+    from tpu_air.engine.dist import MeshEngine
+    from tpu_air.ops import decode_attention as da
+    from tpu_air.ops.flash_attention import traced_for_mesh
+
+    config = dataclasses.replace(
+        hf_import.lm_config_from_hf(TINY, max_seq_len=256), num_experts=0,
+        experts_held=0, num_experts_per_tok=0, num_shared_experts=0,
+        router="softmax")
+    assert config.layer_kinds() == ["latent"] * 3
+    model = CausalLM(config)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    prompts = [np.random.default_rng(14).integers(2, 384, k).tolist()
+               for k in (19, 7)]
+    cfg = EngineConfig(num_slots=4, slot_len=64, page_len=16,
+                       max_new_tokens=4, eos_token_id=None)
+    rule, asked = da.latent_pages_read_in_place, []
+    monkeypatch.setattr(
+        da, "latent_pages_read_in_place",
+        lambda pool: asked.append(traced_for_mesh()) or rule(pool))
+    mesh = MeshEngine(model, params, cfg, dp=2, tp=1, auto_start=False)
+    on_mesh = mesh.generate(prompts, 4)
+    mesh.close()
+    assert asked and all(asked)
+    del asked[:]
+    single = InferenceEngine(model, params, cfg, auto_start=False)
+    assert single.generate(prompts, 4) == on_mesh
+    single.close()
+    assert asked and not any(asked)
 
 
 # -- costs and the configuration file -----------------------------------------

@@ -591,6 +591,88 @@ def test_length_minor_decode_attention_int8_scale_folding():
                                atol=2e-5, rtol=2e-5)
 
 
+# the paged latent read: rows x (table, position); page 16, 4 pages a slot.
+# A table names the slot's pages in position order; 0 is the null page.
+_PAGED_LATENT = {
+    "length_1": ([[5, 0, 0, 0]], [0]),
+    "length_page_less_one": ([[5, 0, 0, 0]], [14]),
+    "length_page": ([[5, 0, 0, 0]], [15]),
+    "length_page_plus_one": ([[5, 9, 0, 0]], [16]),
+    "length_full_slot": ([[5, 9, 2, 7]], [63]),
+    # rows at position 0 (free, or mid-prefill: table at the null page)
+    # before, between and behind live rows
+    "idle_rows_between_live_rows": (
+        [[0, 0, 0, 0], [3, 4, 0, 0], [0, 0, 0, 0], [0, 0, 0, 0],
+         [6, 1, 8, 0], [0, 0, 0, 0]], [0, 20, 0, 0, 40, 0]),
+    "shuffled_physical_pages": (
+        [[12, 3, 10, 1], [2, 11, 4, 9], [8, 5, 7, 6]], [63, 50, 33]),
+    "rows_sharing_prefix_pages": (
+        [[4, 5, 6, 0], [4, 5, 7, 0], [4, 8, 0, 0]], [37, 45, 16]),
+    "null_page_in_the_tables_tail": (
+        [[3, 0, 0, 0], [4, 5, 0, 0], [6, 7, 8, 0]], [9, 31, 32]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_PAGED_LATENT))
+def test_paged_latent_read_is_the_gathered_read(case, dtype):
+    """``paged_latent_decode_attention`` (the kernel, interpret mode) reads
+    each row's live pages where they lie and gives what
+    ``latent_decode_attention`` gives over ``gather_pages``, the order of the
+    sums apart.  Every page no row has live (a table's tail, a page of no
+    table) holds NaN for the kernel and numbers for the gathered read: a
+    visit to one would show, in that row and, through the copy a row's last
+    page starts for the next row, in the one behind it."""
+    from tpu_air.ops.decode_attention import (
+        gather_pages, latent_decode_attention, paged_latent_decode_attention,
+    )
+
+    table, pos = (np.asarray(a, np.int32) for a in _PAGED_LATENT[case])
+    h, w, rank, C = 4, 128, 16, 16
+    rng = np.random.default_rng(7)
+    pool = rng.standard_normal((14, C, w)).astype(np.float32)
+    pool[..., 24:] = 0.0                 # a row's zeros behind [c, k_r]
+    q = jnp.asarray(rng.standard_normal((len(pos), h, w)), dtype)
+    live = {0} | {int(p) for row, at in zip(table, pos)
+                  for p in row[:at // C + 1]}
+    poisoned = pool.copy()
+    poisoned[[p for p in range(len(pool)) if p not in live]] = np.nan
+    kvm = jnp.arange(table.shape[1] * C)[None, :] <= pos[:, None]
+    want = latent_decode_attention(
+        q, gather_pages(jnp.asarray(pool, dtype), jnp.asarray(table)), kvm,
+        rank, dtype)
+    got = paged_latent_decode_attention(
+        q, jnp.asarray(poisoned, dtype), jnp.asarray(table), jnp.asarray(pos),
+        rank, dtype, interpret=True)
+    assert got.shape == (len(pos), h, rank) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), atol=tol,
+                               rtol=tol)
+
+
+def test_latent_pages_are_read_in_place_on_a_tpu_in_whole_tiles_off_a_mesh(
+        monkeypatch):
+    """The rule that picks the kernel sees the backend, the page's tiles
+    and whether the program is traced for a mesh, and nothing else."""
+    from tpu_air.ops import decode_attention as da
+    from tpu_air.ops.flash_attention import kernel_mesh
+
+    pool = lambda page, w, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        (9, page, w), jnp.dtype(dt))
+    assert not da.latent_pages_read_in_place(pool(256, 640, "bfloat16"))
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
+    assert da.latent_pages_read_in_place(pool(256, 640, "bfloat16"))
+    assert da.latent_pages_read_in_place(pool(8, 128, "float32"))
+    assert not da.latent_pages_read_in_place(pool(8, 128, "bfloat16"))
+    assert not da.latent_pages_read_in_place(pool(256, 576, "bfloat16"))
+    with kernel_mesh(object()):
+        assert not da.latent_pages_read_in_place(pool(256, 640, "bfloat16"))
+    assert da.latent_pages_read_in_place(pool(256, 640, "bfloat16"))
+
+
 def _read(layout, q, k, v, mask, h):
     from tpu_air.ops.decode_attention import (
         flat_decode_attention, length_minor_decode_attention,

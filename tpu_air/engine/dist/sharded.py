@@ -26,8 +26,12 @@ Layout over a ``(dp, tp)`` mesh:
 
 from __future__ import annotations
 
+import functools
+
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
+
+from tpu_air.ops.flash_attention import kernel_mesh
 
 from tpu_air.models.lm.generate import (
     PAGE_POOL_LEAVES,
@@ -62,6 +66,18 @@ def paged_cache_shardings(cache, mesh):
     return walk(cache)
 
 
+def _traced_for(mesh, body):
+    """``body``, traced under ``kernel_mesh(mesh)``: what picks a kernel at
+    trace time (``ops.decode_attention.latent_pages_read_in_place``) sees
+    that the partitioner will split this program's arrays, and keeps the
+    path the partitioner can split."""
+    @functools.wraps(body)
+    def traced(*args):
+        with kernel_mesh(mesh):
+            return body(*args)
+    return traced
+
+
 def make_sharded_paged_decode_step_fn(model, slot_len: int, mesh,
                                       param_shardings, cache_shardings):
     """The MeshEngine decode step: same body and donate contract as
@@ -69,7 +85,7 @@ def make_sharded_paged_decode_step_fn(model, slot_len: int, mesh,
     batch = NamedSharding(mesh, P("data"))
     table = NamedSharding(mesh, P("data", None))
     return jax.jit(
-        make_paged_decode_body(model, slot_len),
+        _traced_for(mesh, make_paged_decode_body(model, slot_len)),
         donate_argnums=(1,),
         in_shardings=(param_shardings, cache_shardings, batch, batch, table),
         out_shardings=(cache_shardings, batch),

@@ -102,8 +102,11 @@ models/lm/modeling.LatentAttention) where an attention layer keeps K and V
 pools; table, null page, prefix sharing, copy-on-write and migration work on
 it by page as they do on those (``generate.PAGE_POOL_LEAVES``).  ``stats()``
 counts the positions the decode steps had live (``latent_positions_live``:
-the sum of the decoding rows' lengths as each step is read) beside what a
-step's gather writes out (``latent_positions_pool``).  A model that holds a
+the sum of the decoding rows' lengths as each step is read) and the pages
+those lengths span (``latent_pages_read``: what the absorbed read visits a
+layer where it reads the pool in place, ops/decode_attention.py) beside what
+the slots could hold (``latent_positions_pool``: ``num_slots x slot_len``,
+what a step's gather writes out where the pool is gathered).  A model that holds a
 share of the experts its router scores (``LMConfig.experts_held``) returns,
 behind the assignments to its held experts, those it sent elsewhere; both
 are counted, only the first are computed (``moe_assignments_elsewhere``).
@@ -1364,9 +1367,12 @@ class InferenceEngine:
                                         chunk=step.chunk_start is not None)
         if self._latent:
             # what the step's absorbed read had live: each decoding row's
-            # positions up to the one this token was computed at
+            # positions up to the one this token was computed at, and the
+            # pages they span
+            page = self.config.page_len
             self.metrics.record_latent_live(
-                sum(slot.pos + 1 for slot in reading))
+                sum(slot.pos + 1 for slot in reading),
+                sum(slot.pos // page + 1 for slot in reading))
         # one phase around the walk over the rows, none per row
         with phase("engine.emit", emitted=len(reading)):
             for slot in reading:

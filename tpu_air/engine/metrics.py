@@ -115,6 +115,7 @@ class EngineMetrics:
         # and the positions the decoding rows of the steps read had live
         self.latent_positions_pool = 0
         self.latent_positions_live = 0
+        self.latent_pages_read = 0
         # one-step-ahead decode (T5Engine; absent for engines that issue
         # and read a step in turn): decode steps issued, those issued
         # before the step before was read back, those never read
@@ -333,11 +334,13 @@ class EngineMetrics:
         with self._lock:
             self.latent_positions_pool = int(positions)
 
-    def record_latent_live(self, positions: int) -> None:
+    def record_latent_live(self, positions: int, pages: int) -> None:
         """One decode step read: the positions its decoding rows had live
-        (the sum of their lengths), of ``latent_positions_pool``."""
+        (the sum of their lengths), of ``latent_positions_pool``, and the
+        pages those lengths span (what a read in place visits a layer)."""
         with self._lock:
             self.latent_positions_live += int(positions)
+            self.latent_pages_read += int(pages)
 
     def record_program(self, kind: str, cost: ProgramCost,
                        seconds: float) -> None:
@@ -458,6 +461,7 @@ class EngineMetrics:
             if self.latent_positions_pool:
                 out["latent_positions_pool"] = self.latent_positions_pool
                 out["latent_positions_live"] = self.latent_positions_live
+                out["latent_pages_read"] = self.latent_pages_read
             if self.ssm_state_bytes:
                 out["ssm_state_bytes"] = self.ssm_state_bytes
                 out["ssm_state_resets"] = self.ssm_state_resets

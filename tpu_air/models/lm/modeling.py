@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from tpu_air.ops import ssm
+from tpu_air.ops import decode_attention, ssm
 from tpu_air.ops.decode_attention import (flat_decode_attention, gather_pages,
                                           latent_decode_attention)
 
@@ -394,15 +394,23 @@ class LatentAttention(nn.Module):
     a decode step): ``q~ = q_n W_UK^T`` scores the latent directly, the
     context is taken in latent space and ``W_UV`` applied after
     (``ops.decode_attention.latent_decode_attention``): the slab streams
-    once for all heads and no K or V is ever made.  The paged cache's writes
-    and reads, the plain cache and the mixed step are
-    :class:`CausalSelfAttention`'s, over the one pool.
+    once for all heads and no K or V is ever made.  The paged cache's writes,
+    the plain cache and the mixed step are :class:`CausalSelfAttention`'s,
+    over the one pool; the read of a step's rows (``attend_rows``: the decode
+    step, the step's half of the mixed step) is this page kind's own: on a
+    TPU each row's live pages where they lie in the pool
+    (``ops.decode_attention.paged_latent_decode_attention``, a Pallas
+    kernel), elsewhere every slot's pages gathered at ``slot_len`` first
+    (``ops.decode_attention.latent_pages_read_in_place`` is the rule: what
+    the trace can see, no option).  A chunk's expanded attention gathers its
+    one slot either way.
 
     Scopes (docs/OBSERVABILITY.md): ``mla_q`` the query's path (in a decode
     step the fold of ``W_UK`` too), ``mla_latent`` the latent's (down, norm,
     rope of ``k_r``, the append; in the expanded form ``W_UKV``),
-    ``kv_gather`` and ``decode_attention`` the latent gather and the absorbed
-    read, ``mla_out`` ``W_UV`` and ``W_O``."""
+    ``kv_gather`` and ``decode_attention`` the latent gather (where the
+    rows' read is in place: the chunk's slot alone) and the absorbed read,
+    kernel or not, ``mla_out`` ``W_UV`` and ``W_O``."""
 
     config: LMConfig
 
@@ -479,19 +487,23 @@ class LatentAttention(nn.Module):
             return o.astype(dtype).transpose(0, 2, 1, 3).reshape(
                 q_n.shape[0], q_n.shape[2], h * dv)
 
-        def absorbed(q_n, q_r, lat, kvm):
-            """``q_* [S, h, 1, .]``, each row over its own latent rows ``lat
-            [S, L, w]`` where ``kvm [S, L]`` -> ``[S, 1, h*dv]``."""
+        def fold(q_n, q_r):
+            """The ABSORBED form's queries: ``q_* [S, h, 1, .]`` -> ``[S, h,
+            w]`` in latent space (``q_n W_UK``, then the roped part, the
+            softmax scale folded in, zeros against the row's trailing
+            zeros)."""
             with jax.named_scope("mla_q"):
                 qt = jnp.einsum("shn,rhn->shr", q_n[:, :, 0], k_up, **f32)
                 qc = jnp.concatenate(
                     [qt, q_r[:, :, 0].astype(jnp.float32)], -1) * scale
-                # against the row's trailing zeros
-                qc = jnp.pad(qc, ((0, 0), (0, 0), (0, lat.shape[-1] - r - dr)))
-            o_lat = latent_decode_attention(qc, lat, kvm, r, dtype)
+                return jnp.pad(qc, ((0, 0), (0, 0), (0, w - r - dr)))
+
+        def lift(o_lat):
+            """The absorbed form's context, taken in latent space ``[S, h,
+            r]`` -> ``[S, 1, h*dv]``."""
             with jax.named_scope("mla_out"):
                 o = jnp.einsum("shr,rhv->shv", o_lat, v_up, **f32)
-            return o.astype(dtype).reshape(q_n.shape[0], 1, h * dv)
+            return o.astype(dtype).reshape(o_lat.shape[0], 1, h * dv)
 
         def out(o):
             with jax.named_scope("mla_out"):
@@ -521,9 +533,18 @@ class LatentAttention(nn.Module):
                 return jax.lax.dynamic_update_slice(pool, lat, (page, 0, 0))
 
         def attend_rows(q_n, q_r, pool, table):
+            """The step's rows, each over positions ``0 .. i`` of its slot:
+            its live pages read where they lie, or every slot's pages
+            gathered at ``slot_len`` first (``ops.decode_attention`` has the
+            rule)."""
+            if decode_attention.latent_pages_read_in_place(pool):
+                return lift(decode_attention.paged_latent_decode_attention(
+                    fold(q_n, q_r), pool, table, i, r, dtype))
             kvm = jnp.arange(table.shape[1] * pool.shape[1])[None, :] \
                 <= i[:, None]
-            return absorbed(q_n, q_r, gather_pages(pool, table), kvm)
+            lat = gather_pages(pool, table)
+            return lift(latent_decode_attention(
+                fold(q_n, q_r), lat, kvm, r, dtype))
 
         if self.has_variable("cache", "block_table"):
             # the engine's paged cache: ``cached_latent`` is ONE page pool
@@ -571,7 +592,8 @@ class LatentAttention(nn.Module):
         if l == 1:
             kvm = jnp.broadcast_to((jnp.arange(max_len) <= i)[None],
                                    (b, max_len))
-            return out(absorbed(q_n, q_r, cl.value, kvm))
+            return out(lift(latent_decode_attention(
+                fold(q_n, q_r), cl.value, kvm, r, dtype)))
         # future cache rows are zeros and kj > qi masks them out
         return out(expanded(q_n, q_r, cl.value, i))
 

@@ -13,9 +13,13 @@ step                   ``[L, b, h]``
 the LM's K/V: pages    FLAT ``[b, L, h*d]``,       :func:`flat_decode_attention`
 gathered for the step  scales ``[b, L, h]`` or
                        ``[b, 1, h*d]``
-the LM's latent:       ONE slab ``[b, L, w]``       :func:`latent_decode_attention`
-pages gathered for     (latent ``r``, roped key
-the step               ``dr``, zeros), no scales
+the LM's latent,       ONE slab ``[b, L, w]``       :func:`latent_decode_attention`
+plain cache or pages   (latent ``r``, roped key
+gathered for the step  ``dr``, zeros), no scales
+the LM's latent page   the pool itself ``[P,        :func:`paged_latent_decode_attention`
+pool on a TPU: a       page_len, w]``, rows as      (Pallas: a row's live pages
+row's live pages read  above, whole tiles a page    walked through its block table)
+in place
 =====================  ==========================  ================================
 
 FLAT: all heads ride one batched MXU matmul per contraction via a
@@ -38,6 +42,18 @@ to can be stored so: a new position would be one lane of every tile.
 :func:`length_minor` makes it, padding ``L`` up to whole lanes; the caller
 masks the padding (:func:`pad_keys`).
 
+IN PLACE (PR 44): a gather writes every slot's pages out at ``slot_len`` and
+the read passes over that slab twice, live or not; with a twelfth of the
+positions live (``gigachat-serve-docchat``: 128 slots of 4096) that was 20.8 ms
+of a 55.1 ms step.  :func:`paged_latent_decode_attention` copies only the
+pages a row's length spans, HBM to fast memory, one while the one before is
+computed on (1.0 ms of the same step; docs/KERNELS.md has its budget).
+:func:`latent_pages_read_in_place` is the rule that picks it: a TPU, pages of
+whole tiles, no mesh.  The K/V page kind (:func:`flat_decode_attention` over
+:func:`gather_pages`) stays gathered: its roofline counts K/V at ``slot_len``
+(PERF.md section 7, harness edit 7), and a read of live pages would read over
+100 % of it.
+
 Quantisation: int8 slabs carry scales that fold into the math, per channel
 (cross) into q and the context, per position (self, the LM's) into the scores
 and probabilities; no dequantised slab is ever materialised.  Masking:
@@ -50,8 +66,14 @@ matmul operands in the model dtype.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import traced_for_mesh
 
 _MASK_FLOOR = -1e20
 _NEG_INF_DENSE = -1e9
@@ -183,6 +205,139 @@ def latent_decode_attention(q, latent, kv_mask, rank, dtype):
     ctx = jnp.einsum("bhl,blc->bhc", p.astype(dtype), latent.astype(dtype),
                      preferred_element_type=jnp.float32)
     return ctx[..., :rank].astype(dtype)
+
+
+def pages_are_whole_tiles(pool: jax.Array) -> bool:
+    """A page ``[page_len, w]`` of the pool is whole tiles of its dtype."""
+    _, page_len, w = pool.shape
+    return page_len % (32 // pool.dtype.itemsize) == 0 and w % _LANES == 0
+
+
+def latent_pages_read_in_place(pool: jax.Array) -> bool:
+    """Does a decode step read this latent page pool ``[P, page_len, w]``
+    where it lies (:func:`paged_latent_decode_attention`) or gather it
+    first?  Decided at trace time from what the call can observe, no knob:
+    the backend is a TPU (interpret mode is for tests), a page is whole
+    tiles, and the program is not traced for a mesh
+    (``flash_attention.kernel_mesh``: XLA cannot partition a Mosaic call,
+    and left to the partitioner the kernel would run on the gathered
+    pool)."""
+    return (jax.default_backend() == "tpu" and not traced_for_mesh()
+            and pages_are_whole_tiles(pool))
+
+
+def _paged_latent_kernel(table_ref, pos_ref, q_ref, pool_ref, o_ref, buf,
+                         sems, first_ref, m_ref, l_ref, acc_ref, *, rank, npg):
+    """One grid step a row ``s``: a loop over its live pages, each copied
+    from the pool in HBM into one of two buffers while the page before it is
+    computed on.  The row's last page starts the copy of the NEXT row's
+    first, so only row 0 waits for a copy it has just started; which buffer
+    a row's first page is in rides ``first_ref`` from row to row (the grid
+    is sequential)."""
+    s, rows = pl.program_id(0), pl.num_programs(0)
+    page_len = buf.shape[1]
+    # a position past the slot would walk the table on into the next row's
+    pos = jnp.minimum(pos_ref[s], npg * page_len - 1)
+    n = pos // page_len + 1
+
+    def fetch(row, j, slot):
+        return pltpu.make_async_copy(
+            pool_ref.at[table_ref[row * npg + j]], buf.at[slot],
+            sems.at[slot])
+
+    @pl.when(s == 0)
+    def _():
+        first_ref[0] = 0
+        fetch(0, 0, 0).start()
+
+    first = first_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[0]                                             # [h, w]
+
+    def page(j, carry):
+        slot = (first + j) % 2
+
+        @pl.when(j + 1 < n)
+        def _():
+            fetch(s, j + 1, 1 - slot).start()
+
+        @pl.when(jnp.logical_and(j + 1 == n, s + 1 < rows))
+        def _():
+            fetch(s + 1, 0, 1 - slot).start()
+
+        fetch(s, j, slot).wait()
+        lat = buf[slot].astype(q.dtype)                      # [page_len, w]
+        sc = jax.lax.dot_general(q, lat, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        at = j * page_len + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(at <= pos, sc, _NEG_INF_DENSE)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, sc.max(-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(sc - m_new)
+        l_ref[...] = alpha * l_ref[...] + p.sum(-1, keepdims=True)
+        acc_ref[...] = alpha * acc_ref[...] + jnp.dot(
+            p.astype(q.dtype), lat, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n, page, None)
+    first_ref[0] = (first + n) % 2
+    o_ref[0] = (acc_ref[:, :rank] / l_ref[...]).astype(o_ref.dtype)
+
+
+@jax.named_scope("decode_attention")
+def paged_latent_decode_attention(q, pool, block_table, pos, rank, dtype,
+                                  interpret=None):
+    """:func:`latent_decode_attention` over the pages of the pool, IN PLACE:
+    ``q [S, h, w]`` as that one takes it; ``pool [P, page_len, w]`` the
+    latent page pool, left in HBM; ``block_table [S, pages_per_slot]`` int32
+    and ``pos [S]`` int32, row ``s`` attending positions ``0 .. pos[s]`` of
+    its slot (both ride to the kernel as scalar-prefetch arguments).  Row
+    ``s`` visits pages ``table[s, 0 .. pos[s] // page_len]`` and no other:
+    on each the scores ``[h, page_len]`` in f32, the mask ``<= pos[s]`` (the
+    last page's tail), an online softmax and the context accumulated in f32
+    over the page's whole width; the first ``rank`` columns over the sum at
+    the end -> ``[S, h, rank]`` in ``dtype``.  Operands in ``dtype``: the
+    precision of the gathered read, the order of the sums apart.  A row at
+    ``pos == 0`` (the engine's idle rows, their table at the null page)
+    reads position 0 of its first page, as the gathered read has it.
+
+    VMEM (the cell: 64 heads, page 256 x 640 bf16): two page buffers 0.66
+    MB, q and the output block twice 0.29 MB, the f32 accumulator 0.16 MB.
+    ``interpret`` None: interpret mode off a TPU (tests)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    rows, h, w = q.shape
+    _, page_len, _ = pool.shape
+    npg = block_table.shape[1]
+    return pl.pallas_call(
+        functools.partial(_paged_latent_kernel, rank=rank, npg=npg),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows,),
+            in_specs=[
+                pl.BlockSpec((1, h, w), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, rank), lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, page_len, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, 1), jnp.float32),
+                pltpu.VMEM((h, w), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((rows, h, rank), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=bool(interpret),
+        name="paged_latent_decode_attention",
+    )(block_table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
+      q.astype(dtype), pool)
 
 
 @jax.named_scope("decode_attention")

@@ -1017,6 +1017,11 @@ def kernel_mesh(mesh):
         _kernel_mesh = was
 
 
+def traced_for_mesh() -> bool:
+    """Is the program being traced under a :func:`kernel_mesh`?"""
+    return _kernel_mesh is not None
+
+
 def mesh_divides(batch: int, heads: int, batch_axis: str = "data",
                  head_axis: str = "model") -> bool:
     """Can ``flash_attention_on_mesh`` split these rows and heads evenly over
