@@ -269,6 +269,35 @@ def of_two_chunks_an_iteration_the_first_rides_and_the_other_runs_alone(
         == snap["prefill_chunks"] == 1 + 1 + 1 + 3
 
 
+def stats_count_the_pages_each_chunk_has_reached(model, params, check):
+    """``chunk_pages_read`` is the sum over the chunks run of ``start //
+    page_len + 1`` (what a chunk's attention has to visit) and
+    ``chunk_pages_slot`` the chunks times the pages a slot holds (what it
+    visits where it gathers the slot), whichever program carried the chunk."""
+    lengths = (19, 5, 27, 40)
+    prompts = _prompts(model, 16, lengths)
+    eng = _engine(model, params)
+    streams = [eng.submit(prompts[0], 8)]
+    _drain(eng)                           # a cold start: its chunks run alone
+    alone = eng.metrics.snapshot()
+    assert (alone["chunks_alone"], alone["chunk_pages_read"],
+            alone["chunk_pages_slot"]) == (3, 1 + 2 + 3, 3 * SLOT_LEN // C)
+    streams.append(eng.submit(prompts[1], 24))
+    eng.step()
+    eng.step()
+    streams += [eng.submit(p, 4) for p in prompts[2:]]   # these ride
+    _drain(eng)
+    snap = eng.metrics.snapshot()
+    eng.close()
+    for p, s in zip(prompts, streams):
+        check(p, s.result(5))
+    assert snap["chunks_fused"] >= 4 + 5
+    chunks = [-(-n // C) for n in lengths]
+    assert snap["prefill_chunks"] == sum(chunks)
+    assert snap["chunk_pages_read"] == sum(n * (n + 1) // 2 for n in chunks)
+    assert snap["chunk_pages_slot"] == sum(chunks) * (SLOT_LEN // C)
+
+
 CASES = {fn.__name__: fn for fn in (
     the_mixed_body_computes_what_the_two_bodies_compute,
     the_chunks_slot_keeps_the_chunks_state_and_pages,
@@ -277,4 +306,5 @@ CASES = {fn.__name__: fn for fn in (
     a_chunk_with_no_row_to_decode_runs_alone,
     rows_with_no_chunk_take_the_decode_step,
     of_two_chunks_an_iteration_the_first_rides_and_the_other_runs_alone,
+    stats_count_the_pages_each_chunk_has_reached,
 )}

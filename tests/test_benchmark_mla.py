@@ -58,11 +58,13 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
                  "lm_expert_share", "engine_host_ms_p50"):
         assert name not in layer, name
     # the new metrics are this cell's alone, and at the end of the list
-    new = [m["name"] for m in bench.doc["per_layer"][-6:]]
+    new = [m["name"] for m in bench.doc["per_layer"][-8:]]
     assert new == ["mla_decode_roofline", "mla_attention_roofline",
                    "moe_held_expert_roofline", "mla_latent_share",
-                   "moe_shared_share", "latent_live_share"]
-    assert all(m["workloads"] == [CELL] for m in bench.doc["per_layer"][-6:])
+                   "moe_shared_share", "latent_live_share",
+                   # PR 45: the chunk's walk, and what the traffic leaves it
+                   "mla_chunk_attention_share", "chunk_page_visit_share"]
+    assert all(m["workloads"] == [CELL] for m in bench.doc["per_layer"][-8:])
     assert [w["name"] for w in bench.doc["workloads"][:7]] == [
         "t5base-finetune", "t5base-finetune-dp4", "t5base-batchgen",
         "t5large-serve", "t5large-batchgen", "olmoe-serve-decode",

@@ -644,29 +644,36 @@ def test_mixed_step_streams_each_weight_once_on_v5e(v5e, monkeypatch, family):
     assert {len(r) for w, r in twice.items() if "embedding" not in w} == {2}
 
 
-# -- the latent page pool read in place (PR 44) --------------------------------
+# -- the latent page pool read in place (PR 44, PR 45) --------------------------
 
 _LATENT_SLAB = r"bf16\[128,4096,640\]"           # every slot's pages, gathered
 _LATENT_POOL_MOVED = r"= bf16\[2049,256,640\]\S* (copy|transpose)\("
+_CHUNK_SLOT = r"bf16\[1,4096,640\]"              # a chunk's one slot, gathered
+_CHUNK_SCORES = r"\[(1,)?64,256,4096\]"           # its scores over slot_len
 
 
-@pytest.mark.parametrize("program", ["decode", "mixed"])
+@pytest.mark.parametrize("program", ["decode", "mixed", "chunk"])
 def test_latent_decode_rows_read_their_pages_in_place_on_v5e(
         v5e, monkeypatch, program):
-    """The latent family's two programs that decode rows, at
+    """The latent family's three engine programs, at
     ``gigachat-serve-docchat``'s widths, depth and geometry (128 slots x 4096,
     pages of 256 x 640): the chip's compiler takes them with the paged kernel
-    in every layer beside the twelve grouped products; nothing of the
-    gathered slab's shape ``[128, 4096, 640]`` is made (the gathered read
-    makes it: the counter sees it there), no pool is copied around the
-    kernel's call or laid out anew for it, and the donated pools come back
-    aliased.  The mixed step keeps the chunk's gather of its ONE slot."""
+    of the rows' read in every layer of the two programs that decode rows,
+    the kernel of the chunk's walk in every layer of the two that carry a
+    chunk, beside the twelve grouped products; nothing of the gathered
+    slab's shape ``[128, 4096, 640]`` is made (the gathered read makes it:
+    the counter sees it there), nothing of a chunk's gathered slot ``[1,
+    4096, 640]`` nor of its scores over ``slot_len`` ``[64, 256, 4096]`` (the
+    dense form over the gathered slot makes both: seen there too), no pool
+    is copied around a kernel's call or laid out anew for it, and the
+    donated pools come back aliased."""
     import json
 
     from benchmark import weights_mla
     from tpu_air.models.lm import CausalLM
     from tpu_air.models.lm.generate import (make_paged_decode_body,
-                                            make_paged_mixed_body)
+                                            make_paged_mixed_body,
+                                            make_prefill_chunk_body)
     from tpu_air.ops import decode_attention as da
 
     slots, slot_len, page = 128, 4096, 256
@@ -685,7 +692,6 @@ def test_latent_decode_rows_read_their_pages_in_place_on_v5e(
             _struct((slots,), jnp.int32, v5e)).compile().as_text()
         assert re.search(_LATENT_SLAB, text)
 
-    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmark", "configs",
             "gigachat3.1-702b-a36b.json")) as f:
@@ -695,24 +701,42 @@ def test_latent_decode_rows_read_their_pages_in_place_on_v5e(
     assert cache["layer_0"]["attn"]["cached_latent"].shape == (
         slots * npg + 1, page, 640)
     step = (params, cache, i32(slots), i32(slots), i32(slots, npg))
-    if program == "decode":
-        lowered = jax.jit(make_paged_decode_body(model, slot_len),
-                          donate_argnums=(1,)).lower(*step)
-    else:
-        lowered = jax.jit(make_paged_mixed_body(model, page, slot_len),
-                          donate_argnums=(1,)).lower(
-            *step, i32(1, page), i32(), i32(), i32(npg))
-    compiled = lowered.compile()
+    a_chunk = (i32(1, page), i32(), i32(), i32(npg))
+
+    def lowered():
+        if program == "decode":
+            return jax.jit(make_paged_decode_body(model, slot_len),
+                           donate_argnums=(1,)).lower(*step)
+        if program == "mixed":
+            return jax.jit(make_paged_mixed_body(model, page, slot_len),
+                           donate_argnums=(1,)).lower(*step, *a_chunk)
+        return jax.jit(make_prefill_chunk_body(model, page, slot_len),
+                       donate_argnums=(1,)).lower(params, cache, *a_chunk)
+
+    if program == "chunk":
+        # not vacuous either: off a TPU the chunk gathers its slot and
+        # scores all of it (one layer is enough to see it)
+        dense = lowered().compile().as_text()
+        assert re.search(_CHUNK_SLOT, dense)
+        assert re.search(_CHUNK_SCORES, dense)
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
+    compiled = lowered().compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == cfg.n_layers + 3 * 4
-    assert sum("tpu_custom_call" in line
-               and "paged_latent_decode_attention" in line
-               for line in text.splitlines()) == cfg.n_layers
+    kernels = {name: sum("tpu_custom_call" in line and name in line
+                         for line in text.splitlines())
+               for name in ("paged_latent_decode_attention",
+                            "paged_latent_chunk_attention")}
+    assert kernels == {
+        "paged_latent_decode_attention":
+            cfg.n_layers if program != "chunk" else 0,
+        "paged_latent_chunk_attention":
+            cfg.n_layers if program != "decode" else 0}
+    assert text.count("tpu_custom_call") == sum(kernels.values()) + 3 * 4
     assert not re.search(_LATENT_SLAB, text)
     assert not re.search(_LATENT_POOL_MOVED, text)
+    assert not re.search(_CHUNK_SLOT, text)
+    assert not re.search(_CHUNK_SCORES, text)
     pools = cfg.n_layers * (slots * npg + 1) * page * 640 * 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pools           # appended to in place
     assert mem.temp_size_in_bytes < pools // 4        # and no second pool
-    if program == "mixed":
-        assert re.search(r"bf16\[1,4096,640\]", text)  # the chunk's own slot

@@ -608,6 +608,9 @@ NEW = {
     # mixed step, so the readers of the decode program alone do not list it)
     "mla_latent_share": ["gigachat-serve-docchat"],
     "moe_shared_share": ["gigachat-serve-docchat"],
+    # PR 45: a chunk's attention over its slot's latent pages, of the mixed
+    # step (the dense form's three words; the walk's kernel is under the last)
+    "mla_chunk_attention_share": ["gigachat-serve-docchat"],
 }
 
 

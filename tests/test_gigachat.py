@@ -556,19 +556,99 @@ def read(request, monkeypatch):
     mixed programs."""
     from tpu_air.ops import decode_attention as da
 
-    traced = []
+    traced = {"paged_latent_decode_attention": [],
+              "paged_latent_chunk_attention": []}
 
-    def kernel(*args, _fn=da.paged_latent_decode_attention, **kw):
-        traced.append(args[1].shape)
-        return _fn(*args, **kw)
+    def counted(name):
+        def kernel(*args, _fn=getattr(da, name), **kw):
+            traced[name].append(args[0].shape)
+            return _fn(*args, **kw)
+        return kernel
 
-    monkeypatch.setattr(da, "paged_latent_decode_attention", kernel)
+    for name in traced:
+        monkeypatch.setattr(da, name, counted(name))
     if request.param == "in_place":
         monkeypatch.setattr(da, "latent_pages_read_in_place",
                             da.pages_are_whole_tiles)
     yield request.param
-    # a fallback cannot pass silently, nor the kernel slip into a CPU run
-    assert bool(traced) == (request.param == "in_place")
+    # a fallback cannot pass silently, nor a kernel slip into a CPU run:
+    # the rows' read and the chunk's walk (PR 45) go by the one rule
+    for name, shapes in traced.items():
+        assert bool(shapes) == (request.param == "in_place"), name
+
+
+_WALK_CASES = {
+    # name: (page the chunk sits on, the slot's table row); pool pages 1..8
+    # hold latent rows, page 0 is the null page
+    "first_page": (0, [3, 5, 1, 7]),
+    "middle_page": (1, [3, 5, 1, 7]),
+    "last_page": (3, [3, 5, 1, 7]),
+    # page 2 is another slot's first page too (a shared prefix), and its
+    # second is the one this chunk writes
+    "prefix_shared_first_page": (1, [2, 6, 4, 8]),
+    # what the prompt has not reached is the null page
+    "unreached_are_the_null_page": (2, [3, 5, 1, 0]),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_WALK_CASES))
+def test_a_chunks_walk_is_expanded_over_the_gathered_slot(tiny, monkeypatch,
+                                                          case, dtype):
+    """One latent layer's chunk call over a pool other chunks have filled:
+    the pages its prompt has reached walked in place (the kernel, interpret
+    mode) against the dense form over the slot's pages gathered at
+    ``slot_len``; the layer's output to one step of the dtype, the pool
+    written alike.  The null page holds large numbers: the gathered form
+    masks them, the walk never reads them."""
+    import dataclasses
+
+    from tpu_air.models.lm.modeling import LatentAttention
+    from tpu_air.ops import decode_attention as da
+
+    at, row = _WALK_CASES[case]
+    C, npg = 16, 4
+    config = dataclasses.replace(tiny[1], dtype=dtype)
+    layer = LatentAttention(config)
+    params = jax.tree_util.tree_map(
+        lambda a: a * 4,            # scores that spread: a softmax with a peak
+        tiny[3]["layer_1"]["attn"])
+    rng = np.random.default_rng(3)
+    w = config.latent_row_width
+    pool = rng.standard_normal((9, C, w)).astype(np.float32)
+    pool[..., config.latent_width:] = 0
+    pool[0] = 1e4
+    cache = {
+        "cached_latent": jnp.asarray(pool, dtype),
+        "cache_index": jnp.full((2,), at * C, jnp.int32),
+        "block_table": jnp.broadcast_to(jnp.asarray(row, jnp.int32), (2, npg)),
+    }
+    x = jnp.asarray(rng.standard_normal((1, C, config.d_model)), dtype)
+    positions = (at * C + jnp.arange(C, dtype=jnp.int32))[None]
+    walked = []
+
+    def run(rule):
+        monkeypatch.setattr(da, "latent_pages_read_in_place", rule)
+        with jax.default_matmul_precision("highest"):
+            y, v = layer.apply({"params": params, "cache": cache}, x,
+                               positions, decode=True, mutable=["cache"])
+        return np.asarray(y, np.float32), v["cache"]
+
+    monkeypatch.setattr(
+        da, "paged_latent_chunk_attention",
+        lambda *a, _fn=da.paged_latent_chunk_attention, **k:
+            walked.append(a[0].shape) or _fn(*a, **k))
+    want, c_want = run(lambda pool: False)
+    assert not walked
+    got, c_got = run(da.pages_are_whole_tiles)
+    assert walked == [(4, C, 8)]
+    assert np.isfinite(got).all()
+    step = 2.0 ** -7 if dtype == "bfloat16" else 1e-5
+    assert np.abs(got - want).max() <= step * np.abs(want).max()
+    assert np.abs(want).max() > 0.05
+    for k in cache:
+        np.testing.assert_array_equal(np.asarray(c_got[k], np.float32),
+                                      np.asarray(c_want[k], np.float32))
 
 
 def _reference_rows(sd, prompt, answer):

@@ -653,6 +653,65 @@ def test_paged_latent_read_is_the_gathered_read(case, dtype):
                                rtol=tol)
 
 
+# the chunk's walk: (start, the slot's table row); page 16, 4 pages a slot,
+# h = 5 heads (a tile of one) or 4 (one tile), so a tile's last page starts
+# the copy of the next tile's first
+_CHUNK_WALK = {
+    "first_page": (0, [5, 0, 0, 0], 5),
+    "second_page": (16, [5, 9, 0, 0], 5),
+    "last_page_of_the_slot": (48, [12, 3, 10, 1], 4),
+    "third_page_behind_two_others": (32, [7, 2, 11, 0], 4),
+    "shared_first_pages": (32, [4, 5, 6, 0], 6),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_CHUNK_WALK))
+def test_paged_latent_chunk_walk_is_the_dense_form(case, dtype):
+    """``paged_latent_chunk_attention`` (the kernel, interpret mode) visits
+    pages ``table_row[0 .. start // page_len]`` and gives what the dense
+    causal form gives over the slot's gathered pages (K and V from ``W_UKV``
+    over all of them, a softmax over ``slot_len``), the order of the sums
+    apart.  Every other page of the pool, the null page too, holds NaN for
+    the kernel: a visit to one would show."""
+    from tpu_air.ops.decode_attention import (gather_pages,
+                                              paged_latent_chunk_attention)
+
+    start, row, h = _CHUNK_WALK[case]
+    C, w, r, dn, dr, dv = 16, 128, 16, 8, 8, 12
+    rng = np.random.default_rng(9)
+    pool = rng.standard_normal((14, C, w)).astype(np.float32)
+    pool[..., r + dr:] = 0.0
+    q_n = jnp.asarray(rng.standard_normal((h, C, dn)), dtype)
+    q_r = jnp.asarray(rng.standard_normal((h, C, dr)), dtype)
+    k_up = jnp.asarray(rng.standard_normal((h, r, dn)) * 0.5, dtype)
+    v_up = jnp.asarray(rng.standard_normal((h, r, dv)) * 0.5, dtype)
+    scale = (dn + dr) ** -0.5
+    poisoned = pool.copy()
+    poisoned[[p for p in range(len(pool))
+              if p not in row[:start // C + 1]]] = np.nan
+    f32 = dict(preferred_element_type=jnp.float32)
+    lat = gather_pages(jnp.asarray(pool, dtype), jnp.asarray([row]))[0]
+    k_n = jnp.einsum("kr,hrn->hkn", lat[:, :r], k_up)
+    v = jnp.einsum("kr,hrv->hkv", lat[:, :r], v_up)
+    sc = (jnp.einsum("hqn,hkn->hqk", q_n, k_n, **f32)
+          + jnp.einsum("hqd,kd->hqk", q_r, lat[:, r:r + dr], **f32)) * scale
+    keep = (start + jnp.arange(C))[:, None] >= jnp.arange(lat.shape[0])[None]
+    prob = jax.nn.softmax(jnp.where(keep, sc, -1e30), axis=-1)
+    want = jnp.einsum("hqk,hkv->hqv", prob.astype(dtype), v, **f32)
+    got = paged_latent_chunk_attention(
+        q_n, q_r, k_up, v_up, jnp.asarray(poisoned, dtype),
+        jnp.asarray(row, jnp.int32), jnp.int32(start), scale, dtype,
+        interpret=True)
+    assert got.shape == (h, C, dv) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got, np.asarray(want, np.float32), atol=tol,
+                               rtol=tol)
+
+
 def test_latent_pages_are_read_in_place_on_a_tpu_in_whole_tiles_off_a_mesh(
         monkeypatch):
     """The rule that picks the kernel sees the backend, the page's tiles

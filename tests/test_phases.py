@@ -226,8 +226,26 @@ def paged_phases(tmp_path_factory):
     engine.close()
     counted = {k: after[k] - before[k] for k in (
         "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped",
-        "chunks_fused", "chunks_alone", "mixed_steps")}
+        "chunks_fused", "chunks_alone", "mixed_steps", "chunk_pages_read",
+        "chunk_pages_slot")}
     return _phases(trace_dir), tokens, counted
+
+
+def test_paged_prefill_phases_count_the_pages_a_chunk_has_reached(
+        paged_phases):
+    """``engine.prefill`` counts ``pages`` = ``start // page_len + 1`` and
+    ``slot_pages``, the pages a slot holds: the sums are the engine's
+    ``chunk_pages_read`` / ``chunk_pages_slot`` (``chunk_page_visit_share``
+    is their ratio over a traced run)."""
+    phases, _, counted = paged_phases
+    prefills = [p[3] for p in phases if p[0] == "engine.prefill"]
+    assert prefills
+    assert all(p["pages"] == p["start"] // p["tokens"] + 1 for p in prefills)
+    assert len({p["slot_pages"] for p in prefills}) == 1
+    assert all(1 <= p["pages"] <= p["slot_pages"] for p in prefills)
+    assert sum(p["pages"] for p in prefills) == counted["chunk_pages_read"]
+    assert (sum(p["slot_pages"] for p in prefills)
+            == counted["chunk_pages_slot"])
 
 
 def test_paged_phases_say_which_chunks_rode_a_step(paged_phases):

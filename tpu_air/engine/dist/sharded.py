@@ -68,7 +68,8 @@ def paged_cache_shardings(cache, mesh):
 
 def _traced_for(mesh, body):
     """``body``, traced under ``kernel_mesh(mesh)``: what picks a kernel at
-    trace time (``ops.decode_attention.latent_pages_read_in_place``) sees
+    trace time (``ops.decode_attention.latent_pages_read_in_place``: the
+    step's rows and, since PR 45, a chunk's walk) sees
     that the partitioner will split this program's arrays, and keeps the
     path the partitioner can split."""
     @functools.wraps(body)
@@ -107,7 +108,7 @@ def make_sharded_prefill_chunk_fn(model, page_len: int, slot_len: int, mesh,
     chunk is broadcast work; only its page writes land in a data shard)."""
     repl = NamedSharding(mesh, P())
     return jax.jit(
-        make_prefill_chunk_body(model, page_len, slot_len),
+        _traced_for(mesh, make_prefill_chunk_body(model, page_len, slot_len)),
         donate_argnums=(1,),
         in_shardings=(param_shardings, cache_shardings, repl, repl, repl,
                       repl),

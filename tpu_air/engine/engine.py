@@ -908,7 +908,8 @@ class InferenceEngine:
             with phase("engine.prefill", tokens=self.config.page_len,
                        start=slot.plan.next_start,
                        queued=self.scheduler.depth(), chunks=1,
-                       fused=int(fused)):
+                       fused=int(fused),
+                       **self._chunk_pages(slot.plan.next_start)):
                 chunk = self._ready_chunk(slot)
                 if fused:
                     self._chunk_riding = chunk
@@ -916,6 +917,13 @@ class InferenceEngine:
                     self._run_chunk(chunk)
                     ran = True
         return ran
+
+    def _chunk_pages(self, start: int) -> Dict[str, int]:
+        """What a chunk at ``start`` counts: the ``pages`` of its slot its
+        prompt has reached with it (what its attention has to visit) and
+        the ``slot_pages`` a slot holds (what a gather of the slot visits)."""
+        return {"pages": start // self.config.page_len + 1,
+                "slot_pages": self.config.pages_per_slot()}
 
     def _ready_chunk(self, slot: Slot) -> _Chunk:
         """The slot's next chunk, its inputs on their way to the device."""
@@ -973,7 +981,7 @@ class InferenceEngine:
             self.metrics.record_state_reset()
         plan.chunks_done += 1
         self._chunks_run += 1
-        self.metrics.record_chunk(fused)
+        self.metrics.record_chunk(fused, **self._chunk_pages(chunk.start))
         if not plan.done:
             return False
         # final chunk: publication, CoW, hand over to decode.  The first

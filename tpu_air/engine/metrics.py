@@ -128,6 +128,11 @@ class EngineMetrics:
         self.mixed_steps = 0
         self.chunks_fused = 0
         self.chunks_alone = 0
+        # the pages the chunks' prompts had reached (``start // page_len +
+        # 1`` a chunk: what a chunk's attention has to visit) and the pages
+        # their slots hold (what it visits where it gathers the slot)
+        self.chunk_pages_read = 0
+        self.chunk_pages_slot = 0
         # per-slot recurrent state (absent for a model without such layers):
         # its bytes, first chunks run (each zeroes a slot's state), rows x
         # steps whose state a decode step held by its mask, and whether the
@@ -278,14 +283,17 @@ class EngineMetrics:
             self.steps_ahead += bool(ahead)
             self.mixed_steps += bool(mixed)
 
-    def record_chunk(self, fused: bool) -> None:
+    def record_chunk(self, fused: bool, pages: int, slot_pages: int) -> None:
         """One prefill chunk handed to the device: ``fused`` into a decode
-        step's program, or alone."""
+        step's program, or alone; its prompt had reached ``pages`` of its
+        slot's ``slot_pages``."""
         with self._lock:
             if fused:
                 self.chunks_fused += 1
             else:
                 self.chunks_alone += 1
+            self.chunk_pages_read += int(pages)
+            self.chunk_pages_slot += int(slot_pages)
 
     def set_recurrent_state(self, nbytes: int,
                             prefix_cache_disabled: bool) -> None:
@@ -438,6 +446,8 @@ class EngineMetrics:
                 out["chunks_fused"] = self.chunks_fused
                 out["chunks_alone"] = self.chunks_alone
                 out["mixed_steps"] = self.mixed_steps
+                out["chunk_pages_read"] = self.chunk_pages_read
+                out["chunk_pages_slot"] = self.chunk_pages_slot
             if self.topology:
                 out["topology"] = dict(self.topology)
             if self.weights:
@@ -531,7 +541,8 @@ def merge_snapshots(snapshots: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
         }
     out["priority"] = prio
     for key in ("steps_issued", "steps_ahead", "steps_dropped",
-                "chunks_fused", "chunks_alone", "mixed_steps"):
+                "chunks_fused", "chunks_alone", "mixed_steps",
+                "chunk_pages_read", "chunk_pages_slot"):
         if any(key in s for s in snaps):
             out[key] = sum(int(s.get(key, 0)) for s in snaps)
     perfs = [s.get("perf") for s in snaps if s.get("perf")]

@@ -396,21 +396,29 @@ class LatentAttention(nn.Module):
     (``ops.decode_attention.latent_decode_attention``): the slab streams
     once for all heads and no K or V is ever made.  The paged cache's writes,
     the plain cache and the mixed step are :class:`CausalSelfAttention`'s,
-    over the one pool; the read of a step's rows (``attend_rows``: the decode
-    step, the step's half of the mixed step) is this page kind's own: on a
-    TPU each row's live pages where they lie in the pool
+    over the one pool; the two reads of it are this page kind's own, and one
+    rule picks both (``ops.decode_attention.latent_pages_read_in_place``:
+    what the trace can see, no option).  A step's rows (``attend_rows``: the
+    decode step, the step's half of the mixed step): on a TPU each row's live
+    pages where they lie in the pool
     (``ops.decode_attention.paged_latent_decode_attention``, a Pallas
-    kernel), elsewhere every slot's pages gathered at ``slot_len`` first
-    (``ops.decode_attention.latent_pages_read_in_place`` is the rule: what
-    the trace can see, no option).  A chunk's expanded attention gathers its
-    one slot either way.
+    kernel), elsewhere every slot's pages gathered at ``slot_len`` first.  A
+    chunk's rows (``attend_chunk``: the chunk program, the chunk's half of
+    the mixed step): on a TPU the pages its prompt has reached,
+    ``table_row[0 .. start // page_len]``, walked where they lie, K and V
+    made for the page in hand and the softmax a running one
+    (``ops.decode_attention.paged_latent_chunk_attention``), elsewhere the
+    slot's pages gathered at ``slot_len`` and ``expanded`` over them.
 
     Scopes (docs/OBSERVABILITY.md): ``mla_q`` the query's path (in a decode
     step the fold of ``W_UK`` too), ``mla_latent`` the latent's (down, norm,
     rope of ``k_r``, the append; in the expanded form ``W_UKV``),
     ``kv_gather`` and ``decode_attention`` the latent gather (where the
-    rows' read is in place: the chunk's slot alone) and the absorbed read,
-    kernel or not, ``mla_out`` ``W_UV`` and ``W_O``."""
+    reads are in place: nothing) and the absorbed read, kernel or not,
+    ``attn_scores`` ``attn_softmax`` ``attn_context`` the expanded form's
+    attention (the chunk's walk is one kernel call under the last; the two
+    transposes of ``W_UKV`` it wants under ``mla_latent``), ``mla_out``
+    ``W_UV`` and ``W_O``."""
 
     config: LMConfig
 
@@ -532,6 +540,21 @@ class LatentAttention(nn.Module):
             with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
                 return jax.lax.dynamic_update_slice(pool, lat, (page, 0, 0))
 
+        def attend_chunk(q_n, q_r, pool, table_row, start):
+            """A chunk's rows ``q_* [h, C, .]`` at positions ``start .. start
+            + C - 1`` of the slot ``table_row`` names -> ``[C, h*dv]``: the
+            pages the prompt has reached walked where they lie, or the
+            slot's pages gathered at ``slot_len`` and attended densely (the
+            rule is ``attend_rows``'s)."""
+            if decode_attention.latent_pages_read_in_place(pool):
+                with jax.named_scope("mla_latent"):
+                    ku, vu = k_up.transpose(1, 0, 2), v_up.transpose(1, 0, 2)
+                o = decode_attention.paged_latent_chunk_attention(
+                    q_n, q_r, ku, vu, pool, table_row, start, scale, dtype)
+                return o.transpose(1, 0, 2).reshape(q_n.shape[1], h * dv)
+            return expanded(q_n[None], q_r[None],
+                            gather_pages(pool, table_row[None]), start)[0]
+
         def attend_rows(q_n, q_r, pool, table):
             """The step's rows, each over positions ``0 .. i`` of its slot:
             its live pages read where they lie, or every slot's pages
@@ -560,18 +583,17 @@ class LatentAttention(nn.Module):
                     raise ValueError(
                         f"mixed step wants {S} + {C} rows of one token; "
                         f"got b={b}, l={l}")
-                row = chunk.table_row[None]
+                row = chunk.table_row
                 pool = append_rows(cl.value, table, latent[:S])
-                pool = append_chunk(pool, row[0, chunk.start // C],
+                pool = append_chunk(pool, row[chunk.start // C],
                                     latent[S:, 0][None])
                 cl.value = pool
                 idx.value = i + 1
                 return out(jnp.concatenate([
                     attend_rows(q_n[:S], q_r[:S], pool, table),
-                    expanded(q_n[S:].transpose(2, 1, 0, 3),
-                             q_r[S:].transpose(2, 1, 0, 3),
-                             gather_pages(pool, row), chunk.start
-                             ).reshape(C, 1, h * dv)]))
+                    attend_chunk(q_n[S:, :, 0].transpose(1, 0, 2),
+                                 q_r[S:, :, 0].transpose(1, 0, 2),
+                                 pool, row, chunk.start)[:, None]]))
             if l == 1:
                 cl.value = append_rows(cl.value, table, latent)
                 idx.value = i + 1
@@ -583,8 +605,8 @@ class LatentAttention(nn.Module):
             p0 = i[0]
             cl.value = append_chunk(cl.value, table[0, p0 // C], latent)
             idx.value = i + l
-            return out(expanded(q_n, q_r, gather_pages(cl.value, table[:1]),
-                                p0))
+            return out(attend_chunk(q_n[0], q_r[0], cl.value, table[0],
+                                    p0)[None])
         with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
             cl.value = jax.lax.dynamic_update_slice(cl.value, latent,
                                                     (0, i, 0))

@@ -22,6 +22,12 @@ row's live pages read  above, whole tiles a page    walked through its block tab
 in place
 =====================  ==========================  ================================
 
+One read is not a single token's: a prefill CHUNK's attention over the same
+latent pool on a TPU, :func:`paged_latent_chunk_attention` (Pallas: the pages
+the chunk's prompt has reached walked through the slot's table row, K and V
+made for the page in hand, a running softmax).  It lives here because it
+shares the pool, the rule and the walk with the rows' read.
+
 FLAT: all heads ride one batched MXU matmul per contraction via a
 block-diagonal selector, the slab streamed in its unpadded storage layout and
 never viewed as ``[b, L, h, d]`` (whose last two dims TPU pads to (16, 128),
@@ -49,7 +55,11 @@ of a 55.1 ms step.  :func:`paged_latent_decode_attention` copies only the
 pages a row's length spans, HBM to fast memory, one while the one before is
 computed on (1.0 ms of the same step; docs/KERNELS.md has its budget).
 :func:`latent_pages_read_in_place` is the rule that picks it: a TPU, pages of
-whole tiles, no mesh.  The K/V page kind (:func:`flat_decode_attention` over
+whole tiles, no mesh.  PR 45 took the chunk's half of the same disease (its
+dense attention over the slot's 4096 gathered positions, three quarters of
+them masked, with a ``[64, 256, 4096]`` f32 score array through HBM: 14.0 ms
+of a 35 ms mixed step) the same way, by the same rule.
+The K/V page kind (:func:`flat_decode_attention` over
 :func:`gather_pages`) stays gathered: its roofline counts K/V at ``slot_len``
 (PERF.md section 7, harness edit 7), and a read of live pages would read over
 100 % of it.
@@ -338,6 +348,151 @@ def paged_latent_decode_attention(q, pool, block_table, pos, rank, dtype,
         name="paged_latent_decode_attention",
     )(block_table.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),
       q.astype(dtype), pool)
+
+
+def _paged_latent_chunk_kernel(table_ref, start_ref, qn_ref, qr_ref, kup_ref,
+                               vup_ref, pool_ref, o_ref, buf, sems, first_ref,
+                               m_ref, l_ref, acc_ref, *, rank, scale, npg):
+    """One grid step a tile of heads: a loop over the pages the chunk's
+    prompt has reached, each copied from the pool in HBM into one of two
+    buffers while the page before it is computed on (the tile's last page
+    starts the copy of the NEXT tile's first, as the rows' kernel has it).
+    On a page, a head at a time: K and V of the page's rows from ``W_UKV``,
+    the scores, the causal mask (it bites on the last page alone), and the
+    running maximum, sum and context in f32."""
+    t, tiles = pl.program_id(0), pl.num_programs(0)
+    heads, chunk, _ = qn_ref.shape
+    rope = qr_ref.shape[2]
+    page_len = buf.shape[1]
+    dtype = qn_ref.dtype
+    # a start past the slot would walk the table off its end
+    start = jnp.minimum(start_ref[0], (npg - 1) * page_len)
+    n = start // page_len + 1
+
+    def fetch(j, slot):
+        return pltpu.make_async_copy(
+            pool_ref.at[table_ref[j]], buf.at[slot], sems.at[slot])
+
+    @pl.when(t == 0)
+    def _():
+        first_ref[0] = 0
+        fetch(0, 0).start()
+
+    first = first_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    ends = (((1,), (1,)), ((), ()))             # contract the minor of both
+
+    def page(j, carry):
+        slot = (first + j) % 2
+
+        @pl.when(j + 1 < n)
+        def _():
+            fetch(j + 1, 1 - slot).start()
+
+        @pl.when(jnp.logical_and(j + 1 == n, t + 1 < tiles))
+        def _():
+            fetch(0, 1 - slot).start()
+
+        fetch(j, slot).wait()
+        c = buf[slot, :, :rank].astype(dtype)                # [page_len, r]
+        k_r = buf[slot, :, rank:rank + rope].astype(dtype)   # [page_len, dr]
+        at = (chunk, page_len)
+        live = (start + jax.lax.broadcasted_iota(jnp.int32, at, 0)
+                >= j * page_len + jax.lax.broadcasted_iota(jnp.int32, at, 1))
+        for hd in range(heads):
+            k_n = jnp.dot(c, kup_ref[hd],
+                          preferred_element_type=jnp.float32).astype(dtype)
+            v = jnp.dot(c, vup_ref[hd],
+                        preferred_element_type=jnp.float32).astype(dtype)
+            sc = (jax.lax.dot_general(qn_ref[hd], k_n, ends,
+                                      preferred_element_type=jnp.float32)
+                  + jax.lax.dot_general(qr_ref[hd], k_r, ends,
+                                        preferred_element_type=jnp.float32)
+                  ) * scale
+            sc = jnp.where(live, sc, _NEG_INF_DENSE)
+            m = m_ref[hd]
+            m_new = jnp.maximum(m, sc.max(-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l_ref[hd] = alpha * l_ref[hd] + p.sum(-1, keepdims=True)
+            acc_ref[hd] = alpha * acc_ref[hd] + jnp.dot(
+                p.astype(dtype), v, preferred_element_type=jnp.float32)
+            m_ref[hd] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n, page, None)
+    first_ref[0] = (first + n) % 2
+    o_ref[...] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+_CHUNK_HEAD_TILE = 4    # heads a grid step: 7 MB of VMEM at the cell's widths
+
+
+@jax.named_scope("attn_context")
+def paged_latent_chunk_attention(q_n, q_r, k_up, v_up, pool, table_row,
+                                 start, scale, dtype, interpret=None):
+    """A prefill CHUNK's expanded attention over the pages its prompt has
+    reached, IN PLACE: ``q_n [h, C, dn]`` and ``q_r [h, C, dr]`` the queries
+    of positions ``start .. start + C - 1`` of one slot; ``k_up [h, r, dn]``
+    and ``v_up [h, r, dv]`` the two halves of ``W_UKV`` a head; ``pool [P,
+    page_len, w]`` the latent page pool, left in HBM; ``table_row
+    [pages_per_slot]`` int32 the slot's pages and ``start`` int32, a chunk
+    being one page: ``C == page_len`` and ``start`` a multiple of it (both
+    ride to the kernel as scalar-prefetch arguments).  Visits pages
+    ``table_row[0 .. start // page_len]`` and no other, the trip count taken
+    from ``start`` at run time: on each, K and V of the page's rows (in
+    ``dtype``), the scores ``[C, page_len]`` a head in f32, the mask ``start
+    + q >= j * page_len + k``, an online softmax and the context in f32,
+    divided at the end -> ``[h, C, dv]`` in ``dtype``.  The precision of the dense form
+    over the gathered slot, the order of the sums apart; no score array over
+    ``slot_len`` and no K or V beyond the page in hand ever exist.
+
+    VMEM (the cell: tiles of 4 of 64 heads, chunk and page 256, r 512, dn
+    128, dr 64, dv 192, bf16): the queries, ``W_UKV`` and the output block
+    twice 4.1 MB, two page buffers 0.66 MB, the f32 context, maximum and sum
+    1.8 MB.  ``interpret`` None: interpret mode off a TPU (tests)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    h, chunk, dn = q_n.shape
+    dr, dv = q_r.shape[2], v_up.shape[2]
+    _, page_len, w = pool.shape
+    if chunk != page_len:
+        raise ValueError(f"a chunk is one page: {chunk} rows, page {page_len}")
+    rank = k_up.shape[1]
+    heads = next(t for t in (_CHUNK_HEAD_TILE, 2, 1) if h % t == 0)
+
+    def tile(minor):
+        return pl.BlockSpec((heads,) + minor, lambda t, *_: (t, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(_paged_latent_chunk_kernel, rank=rank,
+                          scale=float(scale), npg=table_row.shape[0]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(h // heads,),
+            in_specs=[
+                tile((chunk, dn)), tile((chunk, dr)),
+                tile((rank, dn)), tile((rank, dv)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=tile((chunk, dv)),
+            scratch_shapes=[
+                pltpu.VMEM((2, page_len, w), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((heads, chunk, 1), jnp.float32),
+                pltpu.VMEM((heads, chunk, 1), jnp.float32),
+                pltpu.VMEM((heads, chunk, dv), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((h, chunk, dv), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=bool(interpret),
+        name="paged_latent_chunk_attention",
+    )(table_row.astype(jnp.int32), jnp.reshape(start, (1,)).astype(jnp.int32),
+      *(a.astype(dtype) for a in (q_n, q_r, k_up, v_up)), pool)
 
 
 @jax.named_scope("decode_attention")
