@@ -433,8 +433,8 @@ def test_grouped_decode_attention_matches_the_per_head_reference(kv_heads):
     v = jnp.asarray(rng.standard_normal((b, L, kv_heads, d)), jnp.float32)
     mask = jnp.asarray(np.arange(L)[None] < np.array([[5], [24], [13]]))
     got = flat_decode_attention(
-        q, k.reshape(b, L, -1), v.reshape(b, L, -1), None, mask, None, None,
-        h, jnp.float32, kv_heads)
+        q, k.reshape(b, L, -1), v.reshape(b, L, -1), mask, h, jnp.float32,
+        kv_heads)
     rep = h // kv_heads
     want = decode_attention_reference(
         q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2),

@@ -45,6 +45,21 @@ def test_forward_shapes(batch):
     assert np.isfinite(float(s)) and float(c) > 0
 
 
+def test_a_config_written_with_the_old_attention_options_still_loads():
+    """``LMConfig`` lost ``attention``, ``flash_min_seq_len``, ``block_q`` and
+    ``block_k`` (no caller set them): a checkpoint's ``model_config.json``
+    written while it had them loads, the keys dropped."""
+    import json
+
+    gone = {"attention": "flash", "flash_min_seq_len": 1024, "block_q": 512,
+            "block_k": None}
+    written = json.dumps({**tiny_cfg().to_dict(), **gone,
+                          "model_type": "causal_lm"})
+    cfg = LMConfig.from_dict(json.loads(written))
+    assert cfg == tiny_cfg()
+    assert not set(gone) & set(cfg.to_dict())
+
+
 def test_causality(batch):
     """Future tokens must not influence past logits."""
     cfg = tiny_cfg()
@@ -70,7 +85,7 @@ def test_ring_forward_matches_dense(batch):
     dense = model.apply({"params": params}, batch)
 
     mesh = make_sp_mesh(8, dp=2, sp=4)
-    ring_cfg = tiny_cfg(attention="ring", sequence_axis="sequence")
+    ring_cfg = tiny_cfg(sequence_axis="sequence")
     ring_model = CausalLM(ring_cfg)
 
     def local_fwd(p, ids):

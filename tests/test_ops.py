@@ -329,12 +329,11 @@ def test_attention_auto_dispatch_by_seq_len(monkeypatch):
     # qlen=8 but klen=64 — dispatch is max(qlen, klen), so both are flash
     assert calls and max(calls) == 64, calls
 
-    # LM family: same rule through LMConfig.attention="auto"
-    from tpu_air.models.lm import CausalLM, LMConfig
+    # LM family: the same rule, its crossover a module constant
+    from tpu_air.models.lm import CausalLM, LMConfig, modeling
 
     lcfg = LMConfig.tiny()
-    lcfg.flash_min_seq_len = 32
-    assert lcfg.attention == "auto"
+    monkeypatch.setattr(modeling, "FLASH_MIN_SEQ_LEN", 32)
     lm = CausalLM(lcfg)
     ids16 = jax.random.randint(rng, (1, 16), 2, lcfg.vocab_size, jnp.int32)
     ids64 = jax.random.randint(rng, (1, 64), 2, lcfg.vocab_size, jnp.int32)
@@ -374,27 +373,27 @@ def _heads(x, h):
 _DK_TOL = {"float32": 2e-5, "bfloat16": 2e-2}
 
 
-@pytest.mark.parametrize("with_bias", [False, True], ids=["nobias", "bias"])
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["own_kv", "shared_kv"])
 @pytest.mark.parametrize("with_mask", [False, True], ids=["nomask", "mask"])
 @pytest.mark.parametrize("L", [96, 129])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-def test_flat_decode_attention_matches_reference(with_bias, with_mask, L, dtype):
-    """The single-token step every decode cell runs == the dense reference
-    over the same slab, with the T5 decode operand shapes (additive [h, L]
-    bias that carries the causal mask; per-row key mask), at a cache length
-    that is a multiple of 8 and one that is not."""
+def test_flat_decode_attention_matches_reference(kv_heads, with_mask, L,
+                                                 dtype):
+    """The LM's single-token step == the dense reference over the same slab
+    (per-row key mask or none; a K/V head a query head or one for two), at a
+    cache length that is a multiple of 8 and one that is not."""
     from tpu_air.ops.decode_attention import (
         decode_attention_reference, flat_decode_attention,
     )
 
-    h = 4
-    q, k, v, bias, mask = _dk_inputs(L=L, h=h, dtype=jnp.dtype(dtype))
-    bias = bias if with_bias else None
+    h, g = 4, kv_heads
+    q, k, v, _, mask = _dk_inputs(L=L, h=h, dtype=jnp.dtype(dtype))
+    k, v = k[..., :g * 16], v[..., :g * 16]
     mask = mask if with_mask else None
-    got = flat_decode_attention(q, k, v, bias, mask, None, None, h,
-                                jnp.dtype(dtype))
-    want = decode_attention_reference(q, _heads(k, h), _heads(v, h),
-                                      bias=bias, kv_mask=mask)
+    got = flat_decode_attention(q, k, v, mask, h, jnp.dtype(dtype), g)
+    want = decode_attention_reference(
+        q, jnp.repeat(_heads(k, g), h // g, axis=2),
+        jnp.repeat(_heads(v, g), h // g, axis=2), kv_mask=mask)
     assert got.shape == q.shape and got.dtype == q.dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
@@ -406,14 +405,13 @@ def test_flat_decode_attention_matches_reference(with_bias, with_mask, L, dtype)
 def test_flat_append_decode_attention_equals_flat_over_the_appended_slab(
         cache, cur):
     """The read of a slab a step is about to append to (position-major
-    ``[L, b, h*d]`` as it was, the step's row apart) ==
-    ``flat_decode_attention`` over the ``[b, L, h*d]`` slab with the row in
-    place, at the first, a middle and the last position, with the causal bias
-    a decode step carries and a key mask; whatever the slab held at ``cur``
-    before is never used.  int8: per-position scales, read as after the
+    ``[L, b, h*d]`` as it was, the step's row apart) == the dense reference
+    over the ``[b, L, h, d]`` slab with the row in place, at the first, a
+    middle and the last position, with the causal bias a decode step carries
+    and a key mask; whatever the slab held at ``cur`` before is never used.  int8: per-position scales, read as after the
     step."""
     from tpu_air.ops.decode_attention import (
-        flat_append_decode_attention, flat_decode_attention,
+        decode_attention_reference, flat_append_decode_attention,
     )
 
     b, L, h, d = 3, 96, 4, 16
@@ -427,7 +425,10 @@ def test_flat_append_decode_attention_equals_flat_over_the_appended_slab(
         v = jnp.asarray(rng.integers(-127, 128, (b, L, h * d)), jnp.int8)
         ks = jnp.asarray(rng.uniform(0.001, 0.02, (b, L, h)), jnp.float32)
         vs = jnp.asarray(rng.uniform(0.001, 0.02, (b, L, h)), jnp.float32)
-    want = flat_decode_attention(q, k, v, bias, mask, ks, vs, h, dtype)
+    per_pos = lambda x: None if x is None else x[..., None]  # noqa: E731
+    want = decode_attention_reference(
+        q, _heads(k, h), _heads(v, h), bias=bias, kv_mask=mask,
+        k_scale=per_pos(ks), v_scale=per_pos(vs))
     # position-major, and junk where the step's row will go
     pm = lambda x: None if x is None else jnp.swapaxes(x, 0, 1)  # noqa: E731
     junk = jnp.full((1, b, h * d), 99, k.dtype)
@@ -502,14 +503,10 @@ def test_length_minor_pads_to_whole_lanes_behind_the_mask(L):
 
 
 @pytest.mark.parametrize("kv_heads", [1, 2])
-@pytest.mark.parametrize("kind", ["none", "pos", "chan"])
-def test_flat_decode_attention_grouped_kv_takes_bias_and_scales(kind,
-                                                                kv_heads):
-    """One formulation for every number of K/V heads: with ``g`` K/V heads
-    serving ``h / g`` query heads each, the additive bias (a row a QUERY
-    head) and both kinds of int8 scales (a K/V head's, per position or per
-    channel) fold in as at ``g == h``, against the explicit reference over
-    K/V repeated a query head."""
+def test_flat_decode_attention_grouped_kv_heads(kv_heads):
+    """One formulation for every number of K/V heads: ``g`` K/V heads serving
+    ``h / g`` query heads each, against the explicit reference over K/V
+    repeated a query head."""
     from tpu_air.ops.decode_attention import (
         decode_attention_reference, flat_decode_attention,
     )
@@ -517,53 +514,13 @@ def test_flat_decode_attention_grouped_kv_takes_bias_and_scales(kind,
     b, L, h, d = 3, 96, 4, 16
     g, rep = kv_heads, 4 // kv_heads
     rng = np.random.default_rng(3 + kv_heads)
-    q, _, _, bias, mask = _dk_inputs()
-    ks = vs = ks4 = vs4 = None
-    if kind == "none":
-        k = jnp.asarray(rng.standard_normal((b, L, g * d)), jnp.float32)
-        v = jnp.asarray(rng.standard_normal((b, L, g * d)), jnp.float32)
-    else:
-        k = jnp.asarray(rng.integers(-127, 128, (b, L, g * d)), jnp.int8)
-        v = jnp.asarray(rng.integers(-127, 128, (b, L, g * d)), jnp.int8)
-        shape = (b, L, g) if kind == "pos" else (b, 1, g * d)
-        ks = jnp.asarray(rng.uniform(0.001, 0.02, shape), jnp.float32)
-        vs = jnp.asarray(rng.uniform(0.001, 0.02, shape), jnp.float32)
-        ref_shape = (b, L, g, 1) if kind == "pos" else (b, 1, g, d)
-        ks4 = jnp.repeat(ks.reshape(ref_shape), rep, axis=2)
-        vs4 = jnp.repeat(vs.reshape(ref_shape), rep, axis=2)
-    got = flat_decode_attention(q, k, v, bias, mask, ks, vs, h, jnp.float32,
-                                g)
+    q, _, _, _, mask = _dk_inputs()
+    k = jnp.asarray(rng.standard_normal((b, L, g * d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, L, g * d)), jnp.float32)
+    got = flat_decode_attention(q, k, v, mask, h, jnp.float32, g)
     want = decode_attention_reference(
         q, jnp.repeat(_heads(k, g), rep, axis=2),
-        jnp.repeat(_heads(v, g), rep, axis=2), bias=bias, kv_mask=mask,
-        k_scale=ks4, v_scale=vs4)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5, rtol=2e-5)
-
-
-@pytest.mark.parametrize("kind", ["pos", "chan"])
-def test_flat_decode_attention_int8_scale_folding(kind):
-    """int8 slabs never materialize a dequantized copy: scales fold into
-    the math (per-position [b, L, h] -> scores/probs; per-channel
-    [b, 1, h*d] -> q/context) and must match the explicit-dequant
-    reference."""
-    from tpu_air.ops.decode_attention import (
-        decode_attention_reference, flat_decode_attention,
-    )
-
-    b, L, h, d = 3, 96, 4, 16
-    rng = np.random.default_rng(1)
-    q, _, _, bias, mask = _dk_inputs()
-    k8 = jnp.asarray(rng.integers(-127, 128, (b, L, h * d)), jnp.int8)
-    v8 = jnp.asarray(rng.integers(-127, 128, (b, L, h * d)), jnp.int8)
-    shape = (b, L, h) if kind == "pos" else (b, 1, h * d)
-    ks = jnp.asarray(rng.uniform(0.001, 0.02, shape), jnp.float32)
-    vs = jnp.asarray(rng.uniform(0.001, 0.02, shape), jnp.float32)
-    got = flat_decode_attention(q, k8, v8, bias, mask, ks, vs, h, jnp.float32)
-    ref_shape = (b, L, h, 1) if kind == "pos" else (b, 1, h, d)
-    want = decode_attention_reference(
-        q, _heads(k8, h), _heads(v8, h), bias=bias, kv_mask=mask,
-        k_scale=ks.reshape(ref_shape), v_scale=vs.reshape(ref_shape))
+        jnp.repeat(_heads(v, g), rep, axis=2), kv_mask=mask)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -738,8 +695,7 @@ def _read(layout, q, k, v, mask, h):
     )
 
     if layout == "flat":
-        return flat_decode_attention(q, k, v, None, mask, None, None, h,
-                                     jnp.float32)
+        return flat_decode_attention(q, k, v, mask, h, jnp.float32)
     return length_minor_decode_attention(q, _minor(k, h), _minor(v, h), None,
                                          mask, None, None, jnp.float32)
 
@@ -790,8 +746,7 @@ def test_paged_decode_equals_flat_over_gathered_slab(lens):
     valid = jnp.asarray(np.arange(npg * C)[None] < np.asarray(lens)[:, None])
     got = np.asarray(flat_decode_attention(
         q, gather_pages(kpool, jnp.asarray(table)),
-        gather_pages(vpool, jnp.asarray(table)), None, valid, None, None, h,
-        jnp.float32))
+        gather_pages(vpool, jnp.asarray(table)), valid, h, jnp.float32))
     for s, n in enumerate(lens):
         own = lambda pool: jnp.concatenate(  # noqa: E731
             [pool[table[s, p]] for p in range(npg)])[None, :n]

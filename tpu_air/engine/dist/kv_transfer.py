@@ -12,11 +12,12 @@ CPU-rig test surface.
 
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict
 
 import numpy as np
 
 from tpu_air.faults import plan as _faults
+from tpu_air.models.lm.paged_cache import map_pools, page_pools
 
 
 class KVTransferError(ValueError):
@@ -27,40 +28,17 @@ class KVTransferError(ValueError):
     of decoding from silently-corrupted pages."""
 
 
-def _pools(layer) -> Dict[str, str]:
-    """``{short name: leaf}`` of the page pools a layer's cache dict holds
-    (``k``/``v`` for K and V pages, ``c`` for a latent-attention layer's one
-    pool: ``generate.PAGE_POOL_LEAVES``)."""
-    from tpu_air.models.lm.generate import PAGE_POOL_LEAVES
-
-    return {short: leaf for leaf, short in PAGE_POOL_LEAVES.items()
-            if leaf in layer}
-
-
-def _kv_layers(cache, path=()):
-    """Yield ``('/'.join(path), layer_dict)`` for every attention-layer
-    cache dict (the ones holding page pools)."""
-    for k, v in cache.items():
-        if not isinstance(v, dict):
-            continue
-        if _pools(v):
-            yield "/".join(path + (k,)), v
-        else:
-            yield from _kv_layers(v, path + (k,))
-
-
 def extract_kv_pages(cache, page_ids) -> Dict[str, Dict[str, np.ndarray]]:
     """Pull pages ``page_ids`` (in prompt order) out of a paged cache as
-    host arrays: ``{layer_path: {"k": [n, page_len, h*d], "v": ...}}`` (a
-    latent-attention layer: ``{"c": [n, page_len, latent_width]}``)."""
+    host arrays, every pool under its short name (models/lm/paged_cache.py):
+    ``{layer_path: {"k": [n, page_len, h*d], "v": ...}}`` (a latent-attention
+    layer: ``{"c": [n, page_len, latent_width]}``)."""
     if _faults.enabled():
         _faults.perturb("kv.transfer", key=str(len(page_ids)))
     ids = np.asarray(page_ids, np.int32)
-    out = {}
-    for path, layer in _kv_layers(cache):
-        out[path] = {short: np.asarray(layer[leaf][ids])
-                     for short, leaf in _pools(layer).items()}
-    return out
+    return {path: {short: np.asarray(pool[ids])
+                   for short, pool in pools.items()}
+            for path, pools in page_pools(cache).items()}
 
 
 def _lossless_cast(src: np.dtype, dst: np.dtype) -> bool:
@@ -79,18 +57,17 @@ def validate_kv_payload(cache, page_ids, payload) -> None:
     wrong page geometry, missing layers, or lossy dtype narrowing.  Runs
     before any write so a bad payload corrupts nothing."""
     n = len(page_ids)
-    for path, layer in _kv_layers(cache):
+    for path, pools in page_pools(cache).items():
         pages = payload.get(path)
         if pages is None:
             raise KVTransferError(
                 f"kv payload missing layer {path!r} "
                 f"(shipped layers: {sorted(payload)})")
-        for name, key in _pools(layer).items():
+        for name, dst in pools.items():
             if name not in pages:
                 raise KVTransferError(
                     f"kv payload at {path!r} missing {name!r} pages")
             arr = np.asarray(pages[name])
-            dst = layer[key]
             if arr.ndim != dst.ndim or arr.shape[0] != n:
                 raise KVTransferError(
                     f"truncated kv payload at {path}/{name}: shipped "
@@ -122,23 +99,8 @@ def insert_kv_pages(cache, page_ids, payload: Dict[str, Dict[str, np.ndarray]]):
 
     ids = jnp.asarray(np.asarray(page_ids, np.int32))
 
-    def walk(d, path=()):
-        out = {}
-        for k, v in d.items():
-            if isinstance(v, dict):
-                if _pools(v):
-                    pages = payload["/".join(path + (k,))]
-                    out[k] = dict(v)
-                    for short, leaf in _pools(v).items():
-                        out[k][leaf] = v[leaf].at[ids].set(
-                            jnp.asarray(pages[short]).astype(v[leaf].dtype))
-                else:
-                    out[k] = walk(v, path + (k,))
-            else:
-                out[k] = v
-        return out
-
-    return walk(cache)
+    return map_pools(cache, lambda path, short, pool: pool.at[ids].set(
+        jnp.asarray(payload[path][short]).astype(pool.dtype)))
 
 
 def payload_nbytes(payload: Dict[str, Dict[str, np.ndarray]]) -> int:

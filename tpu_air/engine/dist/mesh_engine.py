@@ -41,7 +41,7 @@ from tpu_air.parallel.sharding import lm_param_shardings, lm_param_spec, \
 
 from ..engine import InferenceEngine
 from ..types import (EngineConfig, ExpertExchangeUnsupported,
-                     RecurrentStateUnsupported)
+                     refuse_pages_only)
 from .pool import ShardedPagedPool
 from .sharded import (
     make_sharded_page_copy_fn,
@@ -139,10 +139,9 @@ class MeshEngine(InferenceEngine):
 
     def _build_paged_state(self) -> None:
         cfg = self.config
-        if self._recurrent:
-            raise RecurrentStateUnsupported(
-                "MeshEngine shards page pools over data and has no sharding "
-                "for per-slot recurrent state (ROADMAP.md M6)")
+        refuse_pages_only(
+            self.model, "MeshEngine shards page pools over data and has no "
+            "sharding for per-slot recurrent state")
         mc = self.model.config
         if not getattr(mc, "holds_all_experts", True):
             raise ExpertExchangeUnsupported(

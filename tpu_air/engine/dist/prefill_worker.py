@@ -59,6 +59,7 @@ class PrefillWorker:
         if self._built:
             return
         from tpu_air.engine.kvpool import PagedKVPool
+        from tpu_air.engine.types import refuse_pages_only
         from tpu_air.models.lm.generate import (
             init_paged_cache,
             make_lm_prefill_chunk_fn,
@@ -66,12 +67,9 @@ class PrefillWorker:
 
         self.model, self.params = self._checkpoint.get_model(
             dtype=self._dtype)
-        if getattr(self.model.config, "has_recurrent_layers", False):
-            from tpu_air.engine.types import RecurrentStateUnsupported
-
-            raise RecurrentStateUnsupported(
-                "a PrefillWorker ships K/V pages only; this model keeps "
-                "per-slot recurrent state beside them (ROADMAP.md M6)")
+        refuse_pages_only(
+            self.model, "a PrefillWorker ships K/V pages only; this model "
+            "keeps per-slot recurrent state beside them")
         self.pool = PagedKVPool(self.num_pages, self.page_len, 1,
                                 self.pages_per_slot)
         self.cache = init_paged_cache(

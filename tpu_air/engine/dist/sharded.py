@@ -3,8 +3,8 @@
 
 The sharded engine runs the SAME step bodies as the single-chip engine
 (models/lm/generate.py ``make_paged_decode_body`` /
-``make_prefill_chunk_body`` / ``page_copy_body``) — only the jit options
-differ: explicit ``in_shardings``/``out_shardings`` place the KV page
+``make_prefill_chunk_body``, models/lm/paged_cache.py ``copy_page``) — only
+the jit options differ: explicit ``in_shardings``/``out_shardings`` place the KV page
 pools, per-slot indices and block tables over the ``data`` axis and the
 q/k/v/gate/up/o/down kernels over ``model`` (parallel/sharding.py
 ``lm_param_spec``), and XLA's SPMD partitioner inserts the tensor-parallel
@@ -15,11 +15,9 @@ laid out so every slot's pages live in that slot's own data shard
 
 Layout over a ``(dp, tp)`` mesh:
 
-* ``cached_key`` / ``cached_value`` ``[P, page_len, h*d]`` (or the one
-  ``cached_latent`` pool: ``generate.PAGE_POOL_LEAVES``) →
-  ``P("data", None, None)`` — pages split across dp replicas;
-* ``cache_index`` ``[S]`` → ``P("data")``; ``block_table``
-  ``[S, pages_per_slot]`` → ``P("data", None)`` — slots follow pages;
+* the cache's leaves as ``paged_cache.SHARD_AXES`` says: page pools split by
+  page across dp replicas, the per-slot index and the block table by slot
+  (slots follow pages), the rest replicated;
 * decode ``tok``/``pos`` ``[S]`` → ``P("data")``; prefill chunk args
   (b=1 work) and CoW page ids → replicated.
 """
@@ -34,36 +32,19 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from tpu_air.ops.flash_attention import kernel_mesh
 
 from tpu_air.models.lm.generate import (
-    PAGE_POOL_LEAVES,
     advance_rows_body,
     make_paged_decode_body,
     make_prefill_chunk_body,
-    page_copy_body,
     set_row_body,
 )
+from tpu_air.models.lm.paged_cache import SHARD_AXES, copy_page, map_layers
 
 
 def paged_cache_shardings(cache, mesh):
-    """NamedSharding tree matching an ``init_paged_cache`` result: page
-    pools and slot-indexed leaves over ``data``, everything else
-    replicated."""
-
-    def walk(d):
-        out = {}
-        for k, v in d.items():
-            if isinstance(v, dict):
-                out[k] = walk(v)
-            elif k in PAGE_POOL_LEAVES:
-                out[k] = NamedSharding(mesh, P("data", None, None))
-            elif k == "cache_index":
-                out[k] = NamedSharding(mesh, P("data"))
-            elif k == "block_table":
-                out[k] = NamedSharding(mesh, P("data", None))
-            else:
-                out[k] = NamedSharding(mesh, P())
-        return out
-
-    return walk(cache)
+    """NamedSharding tree matching an ``init_paged_cache`` result, every
+    leaf over the mesh axes ``paged_cache.SHARD_AXES`` names for it."""
+    return map_layers(cache, lambda _, layer: {
+        leaf: NamedSharding(mesh, P(*SHARD_AXES[leaf])) for leaf in layer})
 
 
 def _traced_for(mesh, body):
@@ -121,7 +102,7 @@ def make_sharded_page_copy_fn(mesh, cache_shardings):
     within one replica's page range, so the copy never crosses shards."""
     repl = NamedSharding(mesh, P())
     return jax.jit(
-        page_copy_body,
+        copy_page,
         donate_argnums=(0,),
         in_shardings=(cache_shardings, repl, repl),
         out_shardings=cache_shardings,

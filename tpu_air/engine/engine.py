@@ -80,7 +80,7 @@ What follows from reading one step late:
 
 RECURRENT LAYERS.  A model with Mamba layers keeps, beside the pages of its
 attention layers, a row of state a slot that no block table reaches (the
-cache description: models/lm/generate.py).  A slot owns its pages by table
+cache's format: models/lm/paged_cache.py).  A slot owns its pages by table
 and its state row by index, and three things pages gave for free are done by
 hand: the decode step holds the state of every row it does not decode (a row
 mid-prefill rides every step issued between its chunks; the program takes
@@ -100,7 +100,8 @@ LATENT ATTENTION, HELD EXPERTS.  A latent-attention layer keeps ONE page
 pool (the normalised latent and the shared roped key a position:
 models/lm/modeling.LatentAttention) where an attention layer keeps K and V
 pools; table, null page, prefix sharing, copy-on-write and migration work on
-it by page as they do on those (``generate.PAGE_POOL_LEAVES``).  ``stats()``
+it by page as they do on those (models/lm/paged_cache.py has each kind's
+pools).  ``stats()``
 counts the positions the decode steps had live (``latent_positions_live``:
 the sum of the decoding rows' lengths as each step is read) and the pages
 those lengths span (``latent_pages_read``: what the absorbed read visits a
@@ -131,13 +132,13 @@ import jax.numpy as jnp
 
 from tpu_air.models.lm.generate import (
     init_paged_cache,
-    recurrent_state_bytes,
     make_lm_paged_decode_step_fn,
     make_lm_paged_mixed_step_fn,
     make_lm_prefill_chunk_fn,
     make_lm_step_feed_fns,
     make_page_copy_fn,
 )
+from tpu_air.models.lm.paged_cache import recurrent_state_bytes
 
 from tpu_air.faults import plan as _faults
 from tpu_air.observability import tracing as _tracing
@@ -154,10 +155,11 @@ from .types import (
     EngineConfig,
     EngineDrainingError,
     EngineOverloadedError,
-    RecurrentStateUnsupported,
     Request,
     RequestValidationError,
     ResponseStream,
+    keeps_slot_state,
+    refuse_pages_only,
 )
 
 
@@ -219,8 +221,7 @@ class InferenceEngine:
             )
         self.adapters_enabled = cfg.adapter_slots > 0
         # per-slot state that is not pages (module doc, RECURRENT LAYERS)
-        self._recurrent = bool(
-            getattr(model.config, "has_recurrent_layers", False))
+        self._recurrent = keeps_slot_state(model)
         # latent attention: one pool of latent pages (models/lm/modeling.py)
         self._latent = bool(getattr(model.config, "kv_lora_rank", 0))
 
@@ -580,11 +581,10 @@ class InferenceEngine:
         return self._preempting
 
     def _refuse_pages_only(self, what: str) -> None:
-        if self._recurrent:
-            raise RecurrentStateUnsupported(
-                f"{what} ships K/V pages only and this model keeps "
-                f"{self._state_bytes} bytes of recurrent state a pool beside "
-                "them (ROADMAP.md M6)")
+        refuse_pages_only(
+            self.model, f"{what} ships K/V pages only and this model keeps "
+            f"{self._state_bytes} bytes of recurrent state a pool beside "
+            "them")
 
     def migrate_out(self) -> List[Dict[str, Any]]:
         """Preemption drain: freeze the loop, settle the step in flight

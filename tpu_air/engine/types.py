@@ -49,6 +49,20 @@ class RecurrentStateUnsupported(NotImplementedError, TpuAirError):
     migration, disaggregated prefill, the mesh engine; ROADMAP.md M6)."""
 
 
+def keeps_slot_state(model) -> bool:
+    """The model keeps, beside its pages, a row of state a slot (the one
+    place the engines ask: models/lm/paged_cache.py has what it keeps)."""
+    return bool(model.config.has_recurrent_layers)
+
+
+def refuse_pages_only(model, why: str) -> None:
+    """Refuse, by name, what ships or shards PAGES alone for a model that
+    keeps per-slot state beside them; ``why`` says what was asked and why it
+    cannot be given."""
+    if keeps_slot_state(model):
+        raise RecurrentStateUnsupported(f"{why} (ROADMAP.md M6)")
+
+
 class ExpertExchangeUnsupported(NotImplementedError, TpuAirError):
     """The model holds a SHARE of the experts its router scores (one
     expert-parallel rank: ``LMConfig.experts_held < num_experts``) and the

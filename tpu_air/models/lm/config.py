@@ -9,13 +9,13 @@ from typing import List, Optional
 @dataclass
 class LMConfig:
     """Decoder-only transformer (RoPE + SwiGLU, pre-RMSNorm) — the
-    framework's long-context flagship.  ``attention`` picks the kernel:
-
-    * ``"dense"`` — XLA einsum softmax (baseline, any backend);
-    * ``"flash"`` — the Pallas blockwise kernel (ops/flash_attention.py);
-    * ``"ring"``  — ring attention over the ``sequence_axis`` mesh axis
-      (ops/ring_attention.py): each device holds L/P of the sequence and
-      K/V shards rotate over ICI, so context length scales with the mesh.
+    framework's long-context flagship.  No option picks the attention of a
+    full-sequence pass: with ``sequence_axis`` naming a mesh axis it is ring
+    attention over that axis (ops/ring_attention.py: each device holds L/P of
+    the sequence and K/V shards rotate over ICI, so context length scales
+    with the mesh); otherwise the trace's sequence length picks the Pallas
+    blockwise kernel (ops/flash_attention.py, its own tiling) or the XLA
+    einsum softmax (``modeling.CausalSelfAttention``).
 
     ``num_experts > 0`` makes every block's feed-forward a routed expert
     layer (``modeling.SparseExperts``): ``num_experts`` SwiGLU experts of
@@ -74,16 +74,7 @@ class LMConfig:
     dropout_rate: float = 0.0
     dtype: str = "float32"
     tie_embeddings: bool = True
-    # "auto" picks per-trace by sequence length: dense below
-    # flash_min_seq_len, the Pallas flash kernel at/above it (measured v5e
-    # crossover — docs/KERNELS.md).  "ring" stays explicit: it
-    # needs a sequence mesh axis.
-    attention: str = "auto"           # auto | dense | flash | ring
-    flash_min_seq_len: int = 1024
-    sequence_axis: Optional[str] = None  # mesh axis for ring attention
-    # None -> kernel's measured-on-TPU auto tiling (512/1024 caps)
-    block_q: Optional[int] = None
-    block_k: Optional[int] = None
+    sequence_axis: Optional[str] = None  # mesh axis: ring attention over it
     pad_token_id: int = 0
     eos_token_id: Optional[int] = None  # None: generation never early-stops
     num_experts: int = 0          # 0: one dense SwiGLU a block
@@ -225,6 +216,8 @@ class LMConfig:
 
     @classmethod
     def from_dict(cls, d: dict) -> "LMConfig":
+        # keys the class does not have are dropped: a checkpoint written when
+        # it had more options (``attention``, ``block_q`` ...) still loads
         return cls(**{k: v for k, v in d.items() if k in cls.__dataclass_fields__})
 
     @classmethod

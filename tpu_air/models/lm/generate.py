@@ -27,6 +27,10 @@ from tpu_air.models.sampling import sample_token
 from .config import LMConfig
 from .modeling import (CausalLM, ChunkRows, expert_assignments,
                        head_weight)
+# init_paged_cache is exported from here too: the engines, the benchmark's
+# hooks and the tests import it beside the make_* factories
+from .paged_cache import (copy_page, init_paged_cache,  # noqa: F401
+                          push_chunk, push_step)
 
 
 def init_cache(model: CausalLM, batch_size: int):
@@ -174,118 +178,13 @@ def make_lm_generate_fn(model: CausalLM, max_new_tokens: int,
 # make_lm_generate_fn keeps prefill and the per-token step private inside one
 # jitted program — right for offline batches, useless for an engine that must
 # admit/retire requests BETWEEN steps.  These expose the same two phases as
-# standalone compiled units over the engine's paged cache layout
-# (tpu_air.engine.kvpool): per-layer page POOLS [num_pages, page_len, h*d]
-# shared by all slots, a PER-ROW cache index (each slot sits at its own
-# position) and a block_table leaf [S, pages_per_slot] mapping each slot's
-# logical positions onto physical pages.  The table and per-slot indices are
-# HOST state (engine/kvpool/pool.py) pushed into the cache dict at every call
-# via leaf mappers, so the donated device cache never round-trips.  Prefill
-# is page-sized CHUNKS: one compiled program for every prompt length.
-#
-# ONE cache description for both kinds of per-sequence state: an attention
-# layer keeps pages, which the table reaches; a Mamba layer keeps a ROW A
-# SLOT of convolution tail and state-space state, which no table reaches and
-# the slot's index does (modeling.MambaMixer).  Its host-side facts are
-# pushed in the same way: ``valid_len`` (how many of the call's positions
-# are real for each row: in a decode step 1 for a decoding row and 0 for a
-# row that rides along, so the step holds that row's state; in a chunk the
-# real tokens, so padding never enters the state) and ``state_row`` (the slot
-# a chunk works for).  A model without such layers has no such leaves, and
-# its programs are what they were.
+# standalone compiled units over the engine's paged cache: what each layer
+# keeps there (page pools, a row of state a slot) and the host state pushed
+# into it at every call is models/lm/paged_cache.py's to say, and nothing
+# here names a leaf.  Prefill is page-sized CHUNKS: one compiled program for
+# every prompt length.  A model without recurrent layers has no per-slot
+# state leaves, and its programs are what they were.
 # ---------------------------------------------------------------------------
-
-
-#: the leaves of a paged cache that are page POOLS ``[num_pages, page_len,
-#: width]``, and the short name each travels under when pages are shipped
-#: (engine/dist/kv_transfer.py): an attention layer's K and V, a latent-
-#: attention layer's one latent pool
-PAGE_POOL_LEAVES = {"cached_key": "k", "cached_value": "v",
-                    "cached_latent": "c"}
-
-
-def _map_cache_leaf(cache, leaf, fn):
-    """Rebuild a flax cache dict with ``fn`` applied to every ``leaf``-named
-    entry (everything else passes through untouched)."""
-    out = {}
-    for k, v in cache.items():
-        if isinstance(v, dict):
-            out[k] = _map_cache_leaf(v, leaf, fn)
-        elif k == leaf:
-            out[k] = fn(v)
-        else:
-            out[k] = v
-    return out
-
-
-def _map_cache_index(cache, fn):
-    return _map_cache_leaf(cache, "cache_index", fn)
-
-
-def init_paged_cache(model: CausalLM, num_slots: int, num_pages: int,
-                     page_len: int, pages_per_slot: int):
-    """Zero paged cache, asked of each layer by its kind: an attention
-    layer gets page pools ``[num_pages, page_len, kv_heads*d]`` (page 0 = the
-    pinned null page), a per-slot index vector ``[S]`` and a block table
-    ``[S, pages_per_slot]`` of page ids (0 = unreached/null); a latent-
-    attention layer the same with ONE pool ``[num_pages, page_len,
-    latent_row_width]`` (``cached_latent``) in place of the two; a Mamba layer
-    gets per-slot ``conv_state [S, (d_conv-1)*d_inner]`` and ``ssm_state [S,
-    d_state, d_inner]`` (as its module lays them out), the index vector, and
-    ``state_row`` / ``valid_len`` ``[S]``.  This is the persistent donated
-    cache of the engine."""
-    dmodel = CausalLM(LMConfig.from_dict(
-        {**model.config.to_dict(), "max_seq_len": page_len}))
-    base = init_cache(dmodel, num_slots)
-
-    def rebuild(d):
-        out = {}
-        for k, v in d.items():
-            if not isinstance(v, dict):
-                out[k] = v
-            elif "cached_key" in v:
-                hd = v["cached_key"].shape[-1]
-                dt = v["cached_key"].dtype
-                out[k] = {
-                    "cached_key": jnp.zeros((num_pages, page_len, hd), dt),
-                    "cached_value": jnp.zeros((num_pages, page_len, hd), dt),
-                    "cache_index": jnp.zeros((num_slots,), jnp.int32),
-                    "block_table": jnp.zeros(
-                        (num_slots, pages_per_slot), jnp.int32),
-                }
-            elif "cached_latent" in v:
-                lat = v["cached_latent"]
-                out[k] = {
-                    "cached_latent": jnp.zeros(
-                        (num_pages, page_len, lat.shape[-1]), lat.dtype),
-                    "cache_index": jnp.zeros((num_slots,), jnp.int32),
-                    "block_table": jnp.zeros(
-                        (num_slots, pages_per_slot), jnp.int32),
-                }
-            elif "ssm_state" in v:
-                rows = jnp.zeros((num_slots,), jnp.int32)
-                out[k] = {
-                    "conv_state": v["conv_state"],
-                    "ssm_state": v["ssm_state"],
-                    "cache_index": rows, "state_row": rows, "valid_len": rows,
-                }
-            else:
-                out[k] = rebuild(v)
-        return out
-
-    return rebuild(base)
-
-
-def recurrent_state_bytes(cache) -> int:
-    """Bytes of per-slot state the cache holds that is not pages (the Mamba
-    layers' convolution tails and states)."""
-    total = 0
-    for k, v in cache.items():
-        if isinstance(v, dict):
-            total += recurrent_state_bytes(v)
-        elif k in ("conv_state", "ssm_state"):
-            total += v.size * v.dtype.itemsize
-    return total
 
 
 def _apply_paged(model: CausalLM, slot_len: int):
@@ -308,20 +207,6 @@ def _apply_paged(model: CausalLM, slot_len: int):
         return vars_["cache"], hidden, rows
 
     return apply
-
-
-def _push_step_leaves(cache, pos, block_table):
-    """The cache with a decode step's host-side facts pushed in: every
-    row's position, the (masked) block table, and which rows are live."""
-    cache = _map_cache_index(cache, lambda _: pos)
-    cache = _map_cache_leaf(
-        cache, "block_table",
-        lambda _: block_table.astype(jnp.int32))
-    # a row that decodes is past its prompt; every other row (free, or
-    # mid-prefill with its chunks building its state) sits at position 0
-    # and the step must hold whatever state it has
-    return _map_cache_leaf(
-        cache, "valid_len", lambda _: (pos > 0).astype(jnp.int32))
 
 
 def _with_routing(nxt, rows, live, held):
@@ -359,7 +244,7 @@ def make_paged_decode_logits_body(model: CausalLM, slot_len: int):
 
     def logits_step(params, cache, tok, pos, block_table):
         pos = pos.astype(jnp.int32)
-        cache = _push_step_leaves(cache, pos, block_table)
+        cache = push_step(cache, pos, block_table)
         cache, hidden, rows = apply(params, cache, tok[:, None], pos[:, None])
         h = hidden[:, -1].astype(jnp.float32)
         with jax.named_scope("lm_head"):
@@ -480,27 +365,7 @@ def make_prefill_chunk_logits_body(model: CausalLM, page_len: int,
     def logits_chunk(params, cache, ids, p0, last_local, table_row,
                      slot=None):
         p0 = p0.astype(jnp.int32)
-        if cfg.has_recurrent_layers:
-            if slot is None:
-                raise ValueError(
-                    "a model with recurrent layers keeps state a slot: the "
-                    "chunk program needs slot=")
-            # the real positions of a chunk end at last_local (a full
-            # chunk's is its last): padding past it must not enter the state
-            cache = _map_cache_leaf(
-                cache, "valid_len", lambda v: jnp.full(
-                    v.shape, last_local.astype(jnp.int32) + 1, jnp.int32))
-            cache = _map_cache_leaf(
-                cache, "state_row", lambda v: jnp.full(
-                    v.shape, jnp.asarray(slot).astype(jnp.int32), jnp.int32))
-        # leaf shapes must stay [S]/[S, npg] across chunk and decode calls
-        # (shape-stable donation); only row 0 is consulted at b=1
-        cache = _map_cache_index(
-            cache, lambda v: jnp.full(v.shape, p0, jnp.int32))
-        cache = _map_cache_leaf(
-            cache, "block_table",
-            lambda v: jnp.broadcast_to(
-                table_row.astype(jnp.int32)[None], v.shape))
+        cache = push_chunk(cache, p0, last_local, table_row, slot)
         positions = (p0 + jnp.arange(page_len, dtype=jnp.int32))[None]
         cache, hidden, _ = apply(params, cache, ids, positions)
         h_last = hidden[0, last_local.astype(jnp.int32)].astype(jnp.float32)
@@ -602,7 +467,7 @@ def make_paged_mixed_logits_body(model: CausalLM, page_len: int,
         s = tok.shape[0]
         pos, p0 = pos.astype(jnp.int32), p0.astype(jnp.int32)
         last = last_local.astype(jnp.int32)
-        cache = _push_step_leaves(cache, pos, block_table)
+        cache = push_step(cache, pos, block_table)
         chunk = ChunkRows(
             start=p0, valid=last + 1, table_row=table_row.astype(jnp.int32),
             slot=jnp.asarray(0 if slot is None else slot).astype(jnp.int32))
@@ -672,39 +537,12 @@ def make_lm_paged_mixed_step_fn(model: CausalLM, page_len: int,
     return jax.jit(body, donate_argnums=(1,))
 
 
-def page_copy_body(cache, dst, src):
-    """The UNJITTED copy-on-write body: copy page ``src`` onto page ``dst``
-    in every attention layer's page pools (K and V, or the one latent pool:
-    ``PAGE_POOL_LEAVES``); index and table leaves, and a Mamba layer's
-    per-slot state, pass through.
-    Wrapped by :func:`make_page_copy_fn` (single chip) and the sharded
-    factory (engine/dist/sharded.py)."""
-    dst = dst.astype(jnp.int32) if hasattr(dst, "astype") else dst
-    src = src.astype(jnp.int32) if hasattr(src, "astype") else src
-
-    def walk(d):
-        out = {}
-        for k, v in d.items():
-            if isinstance(v, dict):
-                out[k] = walk(v)
-            elif k in PAGE_POOL_LEAVES:
-                page = jax.lax.dynamic_slice(
-                    v, (src, 0, 0), (1,) + v.shape[1:])
-                out[k] = jax.lax.dynamic_update_slice(
-                    v, page, (dst, 0, 0))
-            else:
-                out[k] = v
-        return out
-
-    return walk(cache)
-
-
 def make_page_copy_fn():
     """Build the copy-on-write primitive: a jitted ``fn(cache, dst, src) ->
     cache'`` (cache donated) copying page ``src`` onto page ``dst`` in every
-    layer's K and V pools.  Run once when a slot's first decode append would
+    page pool.  Run once when a slot's first decode append would
     land in a prefix-shared tail page (PagedKVPool.resolve_cow)."""
-    return jax.jit(page_copy_body, donate_argnums=(0,))
+    return jax.jit(copy_page, donate_argnums=(0,))
 
 
 _GEN_CACHE: Dict[Tuple, Any] = {}

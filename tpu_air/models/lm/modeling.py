@@ -1,9 +1,8 @@
 """Long-context causal LM — Flax decoder-only transformer, TPU-first.
 
-First-class sequence parallelism: with ``config.attention="ring"`` and
-``config.sequence_axis`` naming a mesh axis, the model runs INSIDE shard_map
-with activations sequence-sharded — each device holds L/P tokens, RoPE uses
-global positions (shard offset from ``lax.axis_index``), and attention is
+First-class sequence parallelism: with ``config.sequence_axis`` naming a mesh
+axis, the model runs INSIDE shard_map with activations sequence-sharded —
+each device holds L/P tokens, RoPE uses global positions (shard offset from ``lax.axis_index``), and attention is
 ring attention (ops/ring_attention.py): K/V shards rotate over ICI while the
 blockwise-softmax state folds in each incoming block.  Context length then
 scales linearly with the ``sequence`` mesh axis — the long-context design
@@ -18,7 +17,7 @@ from any reference code).
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,9 +28,13 @@ from tpu_air.ops.decode_attention import (flat_decode_attention, gather_pages,
                                           latent_decode_attention)
 
 from .config import LMConfig
+from .paged_cache import BLOCK_TABLE, CACHE_INDEX, STATE_ROW, VALID_LEN
 
 Array = jax.Array
 NEG_INF = -1e30
+# the full-sequence pass takes the Pallas flash kernel from this many tokens
+# (the measured v5e crossover: docs/KERNELS.md), the einsum path below
+FLASH_MIN_SEQ_LEN = 1024
 
 
 class ChunkRows(NamedTuple):
@@ -135,21 +138,126 @@ def _dense_causal_attention(q, k, v, scale, q_offset=0):
                           v.astype(jnp.float32)).astype(q.dtype)
 
 
-# The paged cache's two writes and two reads (engine/kvpool/): one new
-# position of every slot (a decode step), or one whole page of one slot (a
-# prefill chunk).  The step and the chunk program each make their write, then
-# their read; the mixed step makes both writes, then both reads.
+class CacheKind(NamedTuple):
+    """What an attention mixer hands :func:`_cached_attention`: its writes to
+    and reads of what it keeps a position.  ``pools`` is a tuple of arrays
+    (K and V, or the one latent): page pools ``[P, C, w]`` over the engine's
+    paged cache, slabs ``[b, L, w]`` over the plain one; ``new`` the rows the
+    call's positions leave behind, an array a pool; ``q`` a tuple of the
+    call's query arrays ``[b, h, l, .]``; ``i`` the cache index.  Every read
+    returns the attention's output rows as the call lays them, ``[b, l,
+    h*dv]``, for the mixer's output projection."""
 
-def _paged_append_rows(ck, cv, table, i, k, v):
+    # paged: one new position of every slot (a decode step's rows) ...
+    append_rows: Callable    # (pools, table, i, new [S, 1, w]) -> pools
+    attend_rows: Callable    # (q [S, h, 1, .], pools, table, i) -> [S, 1, .]
+    # ... or one whole page of one slot (a prefill chunk).  ``where``: the
+    # slot's table row, or a table whose first row it is; ``ahead``: None in
+    # the chunk program (``q [1, h, C, .]``), in a mixed step the number of
+    # step rows ``q`` holds ahead of the chunk's ``C`` rows of one token
+    append_chunk: Callable   # (pools, page, new [1, C, w]) -> pools
+    attend_chunk: Callable   # (q, pools, where, start, ahead) -> chunk rows
+    # plain (``generate``): the call's positions land at ``i``; a one-token
+    # call reads the slab up to ``i``, a longer one (the prompt) attends
+    # causally with its first query at ``i``
+    append_plain: Callable   # (pools, i, new [b, l, w]) -> pools
+    attend_token: Callable   # (q, pools, i) -> [b, 1, .]
+    attend_prompt: Callable  # (q, pools, i) -> [b, l, .]
+
+
+def _slot_page(where, start, page_len):
+    """The page id position ``start`` of a slot lies in: ``where`` is the
+    slot's table row, or a table whose first row it is."""
+    return where[(0,) * (where.ndim - 1) + (start // page_len,)]
+
+
+def _cached_attention(mod: nn.Module, kind: CacheKind, q, new, pools, idx,
+                      chunk):
+    """The cached (``decode=True``) call of an attention mixer, whatever it
+    keeps a position: ``pools`` and ``idx`` are ``mod``'s cache variables,
+    ``kind`` its writes and reads (:class:`CacheKind`).  The SAME call serves
+    five callers, told apart by what the cache holds and the call's shape:
+
+    * the engine's PAGED cache (a ``block_table`` leaf: engine/kvpool/,
+      models/lm/paged_cache.py): the pools are page pools shared by every
+      slot and ``block_table [S, pages_per_slot]`` maps each slot's logical
+      positions onto physical pages: position p of slot s lives at
+      ``(table[s, p // C], p % C)``.  Prefix-shared pages appear in several
+      rows at once; the null page (id 0) absorbs writes/reads of masked rows
+      and unreached entries.  A DECODE STEP (``l == 1``) scatters each
+      slot's new row to its current (page, offset), then reads.  A PREFILL
+      CHUNK (``b == 1``, ``l == page_len``) is one page-aligned piece of one
+      slot's prompt at positions p0 .. p0+l-1: the whole chunk writes its
+      page at once and attends with the query offset at p0 (earlier chunks /
+      prefix-shared pages supply 0 .. p0-1), so one compiled program serves
+      EVERY prompt length.  A MIXED STEP (``chunk``: :class:`ChunkRows`)
+      is both: rows [:S] are the pool's decode step, rows [S:] one slot's
+      chunk.  Both writes, then both reads: a row that rides along scatters
+      to the null page, which is also where a chunk whose real pages are all
+      shared writes (PagedKVPool.chunk_row); the chunk's write is the later,
+      so its own read finds what it wrote.
+    * the PLAIN cache (``generate``): slabs ``[b, max_len, w]``, new rows
+      land at the running index; the same call handles the multi-token
+      prefill and the 1-token decode steps (positions are global: the caller
+      derives them from the index)."""
+    b, l = new[0].shape[:2]
+    i = idx.value
+    held = tuple(p.value for p in pools)
+
+    def keep(held, step):
+        for p, v in zip(pools, held):
+            p.value = v
+        idx.value = i + step
+
+    if not mod.has_variable("cache", BLOCK_TABLE):
+        held = kind.append_plain(held, i, new)
+        keep(held, l)
+        if l == 1:
+            return kind.attend_token(q, held, i)
+        return kind.attend_prompt(q, held, i)
+    table = mod.variable("cache", BLOCK_TABLE,
+                         lambda: jnp.zeros((b, 1), jnp.int32)).value
+    C = held[0].shape[1]
+    if chunk is not None:
+        S = table.shape[0]
+        if l != 1 or b != S + C:
+            raise ValueError(
+                f"mixed step wants {S} + {C} rows of one token; "
+                f"got b={b}, l={l}")
+        held = kind.append_rows(held, table, i, tuple(x[:S] for x in new))
+        held = kind.append_chunk(
+            held, _slot_page(chunk.table_row, chunk.start, C),
+            tuple(x[S:, 0][None] for x in new))
+        keep(held, 1)
+        return jnp.concatenate([
+            kind.attend_rows(tuple(x[:S] for x in q), held, table, i),
+            kind.attend_chunk(q, held, chunk.table_row, chunk.start, S)])
+    if l == 1:
+        held = kind.append_rows(held, table, i, new)
+        keep(held, 1)
+        return kind.attend_rows(q, held, table, i)
+    if b != 1 or l != C:
+        raise ValueError(
+            f"paged chunk prefill wants b=1, l=page_len ({C}); "
+            f"got b={b}, l={l}")
+    p0 = i[0]
+    held = kind.append_chunk(held, _slot_page(table, p0, C), new)
+    keep(held, l)
+    return kind.attend_chunk(q, held, table, p0, None)
+
+
+# CausalSelfAttention's paged writes and reads (its CacheKind).
+
+def _paged_append_rows(pools, table, i, new):
     """Scatter row ``s``'s new ``k``/``v`` ``[S, 1, g*d]`` to its current
     ``(table[s, i // C], i % C)``; returns the pools."""
-    C = ck.shape[1]
+    C = pools[0].shape[1]
     rows = jnp.arange(table.shape[0])
     page = table[rows, i // C]
     off = i % C
     with jax.named_scope("kv_append"):
-        return (ck.at[page, off].set(k[:, 0].astype(ck.dtype)),
-                cv.at[page, off].set(v[:, 0].astype(cv.dtype)))
+        return tuple(p.at[page, off].set(x[:, 0].astype(p.dtype))
+                     for p, x in zip(pools, new))
 
 
 def _paged_attend_rows(q, ck, cv, table, i, scale, h, g, dtype):
@@ -159,17 +267,15 @@ def _paged_attend_rows(q, ck, cv, table, i, scale, h, g, dtype):
     kvm = jnp.arange(table.shape[1] * ck.shape[1])[None, :] <= i[:, None]
     o4 = flat_decode_attention(
         q.transpose(0, 2, 1, 3) * scale, gather_pages(ck, table),
-        gather_pages(cv, table), None, kvm, None, None, h, dtype, g)
+        gather_pages(cv, table), kvm, h, dtype, g)
     return o4.reshape(q.shape[0], 1, -1)
 
 
-def _paged_append_chunk(ck, cv, page, k, v):
+def _paged_append_chunk(pools, page, new):
     """Write one slot's chunk ``k``/``v`` ``[1, C, g*d]`` over ``page``."""
     with jax.named_scope("kv_append"):
-        return (jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                             (page, 0, 0)),
-                jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                             (page, 0, 0)))
+        return tuple(jax.lax.dynamic_update_slice(
+            p, x.astype(p.dtype), (page, 0, 0)) for p, x in zip(pools, new))
 
 
 def _paged_attend_chunk(q, ck, cv, table, p0, scale, g):
@@ -216,16 +322,14 @@ class CausalSelfAttention(nn.Module):
 
         if decode:
             # KV-cache path (autoregressive generate, SURVEY.md §7
-            # hard-part 2): keys/values land at the running cache index via
-            # dynamic_update_slice; the SAME call handles both the
-            # multi-token prefill and 1-token decode steps.  Cached k is
-            # already RoPE'd (positions are global — the caller derives them
-            # from the cache index).  Slabs are stored FLAT [b, L, h*d]:
-            # the r5 T5 profile measured the [.., L, d=64] layout at 2x
-            # physical HBM bytes from (8, 128) tile padding; h*d is
-            # unpadded, and the 1-token step attends via the flat block-
-            # diagonal formulation (ops/decode_attention.py) that streams
-            # the slab once in storage layout.
+            # hard-part 2; the engine's paged cache): _cached_attention has
+            # the callers.  Cached k is already RoPE'd.  Slabs and pages are
+            # stored FLAT [.., L, h*d]: the r5 T5 profile measured the
+            # [.., L, d=64] layout at 2x physical HBM bytes from (8, 128)
+            # tile padding; h*d is unpadded, and the 1-token step attends
+            # via the flat block-diagonal formulation
+            # (ops/decode_attention.py) that streams the slab once in
+            # storage layout, over the plain slab or the gathered pages.
             max_len = cfg.max_seq_len
             ck = self.variable(
                 "cache", "cached_key",
@@ -234,120 +338,60 @@ class CausalSelfAttention(nn.Module):
                 "cache", "cached_value",
                 lambda: jnp.zeros((b, max_len, g * d), dtype))
             idx = self.variable(
-                "cache", "cache_index", lambda: jnp.array(0, jnp.int32))
-            i = idx.value
+                "cache", CACHE_INDEX, lambda: jnp.array(0, jnp.int32))
             kflat = k.transpose(0, 2, 1, 3).reshape(b, l, g * d)
             vflat = v.transpose(0, 2, 1, 3).reshape(b, l, g * d)
-            if self.has_variable("cache", "block_table"):
-                # PAGED engine cache (engine/kvpool/): cached_key/value are
-                # page POOLS [P, page_len, h*d] shared by every slot, and
-                # block_table [S, pages_per_slot] maps each slot's logical
-                # positions onto physical pages — position p of slot s lives
-                # at (table[s, p // C], p % C).  Prefix-shared pages appear
-                # in several rows at once; the null page (id 0) absorbs
-                # writes/reads of masked rows and unreached entries.
-                bt = self.variable(
-                    "cache", "block_table",
-                    lambda: jnp.zeros((b, 1), jnp.int32))
-                table = bt.value
-                C = ck.value.shape[1]
-                if chunk is not None:
-                    # mixed step: rows [:S] are the pool's decode step, rows
-                    # [S:] one slot's chunk.  Both writes, then both reads:
-                    # a row that rides along scatters to the null page, which
-                    # is also where a chunk whose real pages are all shared
-                    # writes (PagedKVPool.chunk_row); the chunk's write is
-                    # the later, so its own read finds what it wrote.
-                    S = table.shape[0]
-                    if l != 1 or b != S + C:
-                        raise ValueError(
-                            f"mixed step wants {S} + {C} rows of one token; "
-                            f"got b={b}, l={l}")
-                    row = chunk.table_row[None]
-                    ck.value, cv.value = _paged_append_rows(
-                        ck.value, cv.value, table, i, kflat[:S], vflat[:S])
-                    ck.value, cv.value = _paged_append_chunk(
-                        ck.value, cv.value, row[0, chunk.start // C],
-                        kflat[S:, 0][None], vflat[S:, 0][None])
-                    idx.value = i + 1
-                    o = jnp.concatenate([
-                        _paged_attend_rows(q[:S], ck.value, cv.value, table,
-                                           i, scale, h, g, dtype),
-                        _paged_attend_chunk(
-                            q[S:].transpose(2, 1, 0, 3), ck.value, cv.value,
-                            row, chunk.start, scale, g).reshape(C, 1, h * d),
-                    ])
-                    return proj("o", cfg.d_model)(o)
-                if l == 1:
-                    # paged decode step: scatter each slot's new K/V to its
-                    # current (page, offset), then attend over the gathered
-                    # flat slab — same r5 formulation, pool-resident pages.
-                    ck.value, cv.value = _paged_append_rows(
-                        ck.value, cv.value, table, i, kflat, vflat)
-                    idx.value = i + 1
-                    return proj("o", cfg.d_model)(_paged_attend_rows(
-                        q, ck.value, cv.value, table, i, scale, h, g, dtype))
-                # chunked prefill: ONE slot (b == 1) processes one page-
-                # aligned chunk of its prompt at positions p0 .. p0+l-1.
-                # The whole chunk writes its page in one dynamic_update_
-                # slice; attention runs dense over the gathered pages with
-                # the query offset at p0 (earlier chunks / prefix-shared
-                # pages supply 0 .. p0-1).  One compiled program serves
-                # EVERY prompt length — no per-bucket prefill compiles.
-                if b != 1 or l != C:
-                    raise ValueError(
-                        f"paged chunk prefill wants b=1, l=page_len ({C}); "
-                        f"got b={b}, l={l}"
-                    )
-                p0 = i[0]
-                ck.value, cv.value = _paged_append_chunk(
-                    ck.value, cv.value, table[0, p0 // C], kflat, vflat)
-                idx.value = i + l
-                return proj("o", cfg.d_model)(_paged_attend_chunk(
-                    q, ck.value, cv.value, table, p0, scale, g))
-            with jax.named_scope("kv_append"):
-                ck.value = jax.lax.dynamic_update_slice(
-                    ck.value, kflat.astype(dtype), (0, i, 0))
-                cv.value = jax.lax.dynamic_update_slice(
-                    cv.value, vflat.astype(dtype), (0, i, 0))
-            idx.value = i + l
-            if l == 1:
+            if chunk is not None:
+                # the chunk's reads take the slot's row as a table of one
+                chunk = chunk._replace(table_row=chunk.table_row[None])
+
+            def append_plain(pools, i, new):
+                with jax.named_scope("kv_append"):
+                    return tuple(jax.lax.dynamic_update_slice(
+                        p, x.astype(dtype), (0, i, 0))
+                        for p, x in zip(pools, new))
+
+            def attend_chunk(q, pools, table, start, ahead):
+                (q,) = q
+                if ahead is not None:   # C rows of one token: one row of C
+                    q = q[ahead:].transpose(2, 1, 0, 3)
+                o = _paged_attend_chunk(q, *pools, table, start, scale, g)
+                return o if ahead is None else o.reshape(-1, 1, h * d)
+
+            def attend_token(q, pools, i):
                 # future cache slots are zeros; the kv_mask hides them
                 kvm = jnp.broadcast_to(
                     (jnp.arange(max_len) <= i)[None], (b, max_len))
                 o4 = flat_decode_attention(
-                    q.transpose(0, 2, 1, 3) * scale, ck.value, cv.value,
-                    None, kvm, None, None, h, dtype, g)
-                return proj("o", cfg.d_model)(o4.reshape(b, 1, h * d))
-            # prefill (and any multi-token window): dense attention over
-            # the cache with the query offset at the index — a one-time
-            # 4-D view per generate call.  Future slots are zeros but
-            # kj > qi masks them out.
-            ck4 = ck.value.reshape(b, max_len, g, d).transpose(0, 2, 1, 3)
-            cv4 = cv.value.reshape(b, max_len, g, d).transpose(0, 2, 1, 3)
-            o = _dense_causal_attention(q, ck4, cv4, scale, q_offset=i)
-            o = o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
+                    q[0].transpose(0, 2, 1, 3) * scale, *pools, kvm, h,
+                    dtype, g)
+                return o4.reshape(b, 1, h * d)
+
+            def attend_prompt(q, pools, i):
+                # dense attention over the cache with the query offset at
+                # the index: a one-time 4-D view per generate call.  Future
+                # slots are zeros but kj > qi masks them out.
+                ck4, cv4 = (p.reshape(b, max_len, g, d).transpose(0, 2, 1, 3)
+                            for p in pools)
+                o = _dense_causal_attention(q[0], ck4, cv4, scale, q_offset=i)
+                return o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
+
+            o = _cached_attention(self, CacheKind(
+                append_rows=_paged_append_rows,
+                attend_rows=lambda q, pools, table, i: _paged_attend_rows(
+                    q[0], *pools, table, i, scale, h, g, dtype),
+                append_chunk=_paged_append_chunk,
+                attend_chunk=attend_chunk, append_plain=append_plain,
+                attend_token=attend_token, attend_prompt=attend_prompt,
+            ), (q,), (kflat, vflat), (ck, cv), idx, chunk)
             return proj("o", cfg.d_model)(o)
 
         k, v = _repeat_kv(k, h), _repeat_kv(v, h)  # the kernels want h heads
-        impl = cfg.attention
-        if impl == "auto":
-            # trace-time shape dispatch: the einsum path wins short
-            # sequences, the Pallas kernel wins at/above the measured
-            # crossover (no user flag); off-TPU and
-            # tile-degenerate shapes stay dense (interpret-mode flash and
-            # 1-wide tiles are both perf cliffs)
-            from tpu_air.ops.flash_attention import auto_dispatch_ok
+        # imported here: a test replaces auto_dispatch_ok on its module
+        from tpu_air.ops.flash_attention import (auto_dispatch_ok,
+                                                 flash_attention)
 
-            impl = (
-                "flash"
-                if l >= getattr(cfg, "flash_min_seq_len", 1024)
-                and auto_dispatch_ok(l, l)
-                else "dense"
-            )
-        if impl == "ring":
-            if cfg.sequence_axis is None:
-                raise ValueError('attention="ring" requires sequence_axis')
+        if cfg.sequence_axis is not None:
             from tpu_air.ops.ring_attention import ring_attention
 
             # fold heads into batch: ring expects (B·H, L_local, D)
@@ -355,15 +399,16 @@ class CausalSelfAttention(nn.Module):
                 q.reshape(b * h, l, d), k.reshape(b * h, l, d),
                 v.reshape(b * h, l, d), axis_name=cfg.sequence_axis,
                 scale=scale, causal=True,
-                block_q=cfg.block_q, block_k=cfg.block_k,
             ).reshape(b, h, l, d)
-        elif impl == "flash":
-            from tpu_air.ops.flash_attention import flash_attention
-
+        elif l >= FLASH_MIN_SEQ_LEN and auto_dispatch_ok(l, l):
+            # trace-time shape dispatch, no user flag: the einsum path wins
+            # short sequences, the Pallas kernel (its own measured tiling)
+            # wins at/above the crossover; off-TPU and tile-degenerate shapes
+            # stay dense (interpret-mode flash and 1-wide tiles are both perf
+            # cliffs)
             o = flash_attention(
                 q.reshape(b * h, l, d), k.reshape(b * h, l, d),
                 v.reshape(b * h, l, d), scale=scale, causal=True,
-                block_q=cfg.block_q, block_k=cfg.block_k,
             ).reshape(b, h, l, d)
         else:
             o = _dense_causal_attention(q, k, v, scale)
@@ -394,9 +439,10 @@ class LatentAttention(nn.Module):
     a decode step): ``q~ = q_n W_UK^T`` scores the latent directly, the
     context is taken in latent space and ``W_UV`` applied after
     (``ops.decode_attention.latent_decode_attention``): the slab streams
-    once for all heads and no K or V is ever made.  The paged cache's writes,
-    the plain cache and the mixed step are :class:`CausalSelfAttention`'s,
-    over the one pool; the two reads of it are this page kind's own, and one
+    once for all heads and no K or V is ever made.  The callers over a cache
+    (the paged step, chunk and mixed step, the plain cache) are
+    :func:`_cached_attention`'s, as for :class:`CausalSelfAttention`, over
+    the one pool; the two reads of it are this page kind's own, and one
     rule picks both (``ops.decode_attention.latent_pages_read_in_place``:
     what the trace can see, no option).  A step's rows (``attend_rows``: the
     decode step, the step's half of the mixed step): on a TPU each row's live
@@ -430,9 +476,10 @@ class LatentAttention(nn.Module):
         b, l, _ = x.shape
         h, r = cfg.n_heads, cfg.kv_lora_rank
         dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-        if cfg.attention not in ("auto", "dense"):
+        if cfg.sequence_axis is not None:
             raise ValueError(
-                f"latent attention is dense; attention={cfg.attention!r}")
+                "latent attention is dense; sequence_axis="
+                f"{cfg.sequence_axis!r} asks for the ring")
         init = nn.initializers.normal(0.02)
 
         def proj(name, out):
@@ -524,100 +571,83 @@ class LatentAttention(nn.Module):
         cl = self.variable("cache", "cached_latent", lambda: jnp.zeros(
             (b, max_len, w), dtype))
         idx = self.variable(
-            "cache", "cache_index", lambda: jnp.array(0, jnp.int32))
-        i = idx.value
+            "cache", CACHE_INDEX, lambda: jnp.array(0, jnp.int32))
 
-        def append_rows(pool, table, lat):
+        def append_rows(pools, table, i, new):
             """Row ``s``'s new latent ``[S, 1, w]`` to its current ``(table[s,
             i // C], i % C)``."""
+            (pool,), (lat,) = pools, new
             C = pool.shape[1]
             page = table[jnp.arange(table.shape[0]), i // C]
             with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
-                return pool.at[page, i % C].set(lat[:, 0])
+                return (pool.at[page, i % C].set(lat[:, 0]),)
 
-        def append_chunk(pool, page, lat):
+        def append_chunk(pools, page, new):
             """One slot's chunk ``[1, C, w]`` over ``page``."""
             with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
-                return jax.lax.dynamic_update_slice(pool, lat, (page, 0, 0))
+                return (jax.lax.dynamic_update_slice(
+                    pools[0], new[0], (page, 0, 0)),)
 
-        def attend_chunk(q_n, q_r, pool, table_row, start):
-            """A chunk's rows ``q_* [h, C, .]`` at positions ``start .. start
-            + C - 1`` of the slot ``table_row`` names -> ``[C, h*dv]``: the
-            pages the prompt has reached walked where they lie, or the
-            slot's pages gathered at ``slot_len`` and attended densely (the
-            rule is ``attend_rows``'s)."""
+        def append_plain(pools, i, new):
+            with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
+                return (jax.lax.dynamic_update_slice(
+                    pools[0], new[0], (0, i, 0)),)
+
+        def attend_chunk(q, pools, where, start, ahead):
+            """A chunk's rows at positions ``start .. start + C - 1`` of the
+            slot whose table row ``where`` is (or holds first): the pages the
+            prompt has reached walked where they lie, or the slot's pages
+            gathered at ``slot_len`` and attended densely (the rule is
+            ``attend_rows``'s)."""
+            (pool,) = pools
+            if ahead is None:
+                q_n, q_r, table_row = q[0][0], q[1][0], where[0]
+            else:    # C rows of one token
+                q_n, q_r = (x[ahead:, :, 0].transpose(1, 0, 2) for x in q)
+                table_row = where
+            # q_* [h, C, .] -> o [C, h*dv]
             if decode_attention.latent_pages_read_in_place(pool):
                 with jax.named_scope("mla_latent"):
                     ku, vu = k_up.transpose(1, 0, 2), v_up.transpose(1, 0, 2)
                 o = decode_attention.paged_latent_chunk_attention(
                     q_n, q_r, ku, vu, pool, table_row, start, scale, dtype)
-                return o.transpose(1, 0, 2).reshape(q_n.shape[1], h * dv)
-            return expanded(q_n[None], q_r[None],
-                            gather_pages(pool, table_row[None]), start)[0]
+                o = o.transpose(1, 0, 2).reshape(q_n.shape[1], h * dv)
+            else:
+                o = expanded(q_n[None], q_r[None],
+                             gather_pages(pool, table_row[None]), start)[0]
+            return o[None] if ahead is None else o[:, None]
 
-        def attend_rows(q_n, q_r, pool, table):
+        def attend_rows(q, pools, table, i):
             """The step's rows, each over positions ``0 .. i`` of its slot:
             its live pages read where they lie, or every slot's pages
             gathered at ``slot_len`` first (``ops.decode_attention`` has the
             rule)."""
+            (pool,) = pools
             if decode_attention.latent_pages_read_in_place(pool):
                 return lift(decode_attention.paged_latent_decode_attention(
-                    fold(q_n, q_r), pool, table, i, r, dtype))
+                    fold(*q), pool, table, i, r, dtype))
             kvm = jnp.arange(table.shape[1] * pool.shape[1])[None, :] \
                 <= i[:, None]
             lat = gather_pages(pool, table)
             return lift(latent_decode_attention(
-                fold(q_n, q_r), lat, kvm, r, dtype))
+                fold(*q), lat, kvm, r, dtype))
 
-        if self.has_variable("cache", "block_table"):
-            # the engine's paged cache: ``cached_latent`` is ONE page pool
-            # [P, page_len, w]; table, null page and the three callers as
-            # CausalSelfAttention has them
-            table = self.variable(
-                "cache", "block_table",
-                lambda: jnp.zeros((b, 1), jnp.int32)).value
-            C = cl.value.shape[1]
-            if chunk is not None:
-                S = table.shape[0]
-                if l != 1 or b != S + C:
-                    raise ValueError(
-                        f"mixed step wants {S} + {C} rows of one token; "
-                        f"got b={b}, l={l}")
-                row = chunk.table_row
-                pool = append_rows(cl.value, table, latent[:S])
-                pool = append_chunk(pool, row[chunk.start // C],
-                                    latent[S:, 0][None])
-                cl.value = pool
-                idx.value = i + 1
-                return out(jnp.concatenate([
-                    attend_rows(q_n[:S], q_r[:S], pool, table),
-                    attend_chunk(q_n[S:, :, 0].transpose(1, 0, 2),
-                                 q_r[S:, :, 0].transpose(1, 0, 2),
-                                 pool, row, chunk.start)[:, None]]))
-            if l == 1:
-                cl.value = append_rows(cl.value, table, latent)
-                idx.value = i + 1
-                return out(attend_rows(q_n, q_r, cl.value, table))
-            if b != 1 or l != C:
-                raise ValueError(
-                    f"paged chunk prefill wants b=1, l=page_len ({C}); "
-                    f"got b={b}, l={l}")
-            p0 = i[0]
-            cl.value = append_chunk(cl.value, table[0, p0 // C], latent)
-            idx.value = i + l
-            return out(attend_chunk(q_n[0], q_r[0], cl.value, table[0],
-                                    p0)[None])
-        with jax.named_scope("mla_latent"), jax.named_scope("kv_append"):
-            cl.value = jax.lax.dynamic_update_slice(cl.value, latent,
-                                                    (0, i, 0))
-        idx.value = i + l
-        if l == 1:
+        def attend_token(q, pools, i):
             kvm = jnp.broadcast_to((jnp.arange(max_len) <= i)[None],
                                    (b, max_len))
-            return out(lift(latent_decode_attention(
-                fold(q_n, q_r), cl.value, kvm, r, dtype)))
-        # future cache rows are zeros and kj > qi masks them out
-        return out(expanded(q_n, q_r, cl.value, i))
+            return lift(latent_decode_attention(
+                fold(*q), pools[0], kvm, r, dtype))
+
+        # the engine's paged cache: ``cached_latent`` is ONE page pool
+        # [P, page_len, w]; table, null page and the callers are
+        # _cached_attention's.  A prompt over the plain cache: future cache
+        # rows are zeros and kj > qi masks them out
+        return out(_cached_attention(self, CacheKind(
+            append_rows=append_rows, attend_rows=attend_rows,
+            append_chunk=append_chunk, attend_chunk=attend_chunk,
+            append_plain=append_plain, attend_token=attend_token,
+            attend_prompt=lambda q, pools, i: expanded(*q, pools[0], i),
+        ), (q_n, q_r), (latent,), (cl,), idx, chunk))
 
 
 class SwiGLU(nn.Module):
@@ -809,7 +839,7 @@ class MambaMixer(nn.Module):
     * no cache (``decode=False``): the whole sequence from a zero state;
     * a plain cache (``generate``): row ``b`` of the state is sequence ``b``;
       a multi-token call is the prompt, a one-token call a decode step;
-    * the engine's cache (``state_row`` present; models/lm/generate.py):
+    * the engine's cache (``state_row`` present; models/lm/paged_cache.py):
       ``valid_len [S]`` says how many of the call's positions are real for
       each row and ``cache_index [S]`` where the call starts.  A one-token
       call is the pool's decode step over ALL rows, and a row with
@@ -846,7 +876,9 @@ class MambaMixer(nn.Module):
         tail = jnp.zeros((b, (k - 1) * c), dtype)
         state = jnp.zeros((b, n, c), jnp.float32)
         valid = jnp.full((b,), l, jnp.int32)
-        engine = decode and self.has_variable("cache", "state_row")
+        # what the host pushes in (models/lm/paged_cache.py) tells the engine's
+        # cache from the plain one
+        engine = decode and self.has_variable("cache", STATE_ROW)
         if decode:
             cs = self.variable("cache", "conv_state",
                                lambda: jnp.zeros((b, (k - 1) * c), dtype))
@@ -862,8 +894,8 @@ class MambaMixer(nn.Module):
 
         ride = None
         if engine:
-            index = self.get_variable("cache", "cache_index")
-            valid = self.get_variable("cache", "valid_len")
+            index = self.get_variable("cache", CACHE_INDEX)
+            valid = self.get_variable("cache", VALID_LEN)
             if chunk is not None:
                 # mixed step: every slot's row takes one token (or is held)
                 # and rows [S:] are the chunk of ``chunk.slot``, from what
@@ -872,7 +904,7 @@ class MambaMixer(nn.Module):
                 ride = (row_of(tail, row, fresh), chunk.valid[None])
             elif l > 1:
                 # one chunk of one row's prompt (b == 1)
-                row, fresh = self.get_variable("cache", "state_row")[0], \
+                row, fresh = self.get_variable("cache", STATE_ROW)[0], \
                     index[0] == 0
                 tail, state = row_of(tail, row, fresh), row_of(state, row,
                                                                fresh)

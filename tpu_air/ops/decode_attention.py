@@ -11,8 +11,7 @@ T5 self-attention:     FLAT, position-major        :func:`flat_append_decode_att
 grows a position a     ``[L, b, h*d]``, scales
 step                   ``[L, b, h]``
 the LM's K/V: pages    FLAT ``[b, L, h*d]``,       :func:`flat_decode_attention`
-gathered for the step  scales ``[b, L, h]`` or
-                       ``[b, 1, h*d]``
+gathered for the step  no scales
 the LM's latent,       ONE slab ``[b, L, w]``       :func:`latent_decode_attention`
 plain cache or pages   (latent ``r``, roped key
 gathered for the step  ``dr``, zeros), no scales
@@ -64,10 +63,12 @@ The K/V page kind (:func:`flat_decode_attention` over
 (PERF.md section 7, harness edit 7), and a read of live pages would read over
 100 % of it.
 
-Quantisation: int8 slabs carry scales that fold into the math, per channel
-(cross) into q and the context, per position (self, the LM's) into the scores
-and probabilities; no dequantised slab is ever materialised.  Masking:
-``bias`` is additive f32 ``[h, L]`` and already holds causal masking,
+Quantisation (the T5 reads alone: the LM's slabs and pages are never int8):
+int8 slabs carry scales that fold into the math, per channel (cross,
+:func:`length_minor_decode_attention`) into q and the context, per position
+(self, :func:`flat_append_decode_attention`) into the scores and
+probabilities; no dequantised slab is ever materialised.  Masking: ``bias``
+(the T5 reads) is additive f32 ``[h, L]`` and already holds causal masking,
 ``kv_mask`` ``[b, L]`` is per-row key validity.  A fully-masked row gives the
 plain mean of V (uniform softmax): finite, never zero, and to be treated as
 undefined by a caller that can produce one.  Scores and softmax in f32,
@@ -138,8 +139,8 @@ def gather_pages(pool: jax.Array, block_table: jax.Array) -> jax.Array:
 
 
 @jax.named_scope("decode_attention")
-def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
-                           num_heads, dtype, num_kv_heads=None):
+def flat_decode_attention(q, kf, vf, kv_mask, num_heads, dtype,
+                          num_kv_heads=None):
     """Single-token attention over FLAT cache slabs ``[b, L, g*d]``, ``g =
     num_kv_heads`` K/V heads (default ``num_heads``) serving ``h / g`` query
     heads each.  All heads ride ONE batched MXU matmul per contraction via
@@ -148,48 +149,34 @@ def flat_decode_attention(q, kf, vf, bias_hl, kv_mask, k_scale, v_scale,
     head reads the whole row and none of the products is wasted on zeros),
     so the slab streams from HBM exactly once in its unpadded storage
     layout, under any loop or program boundary (the module docstring has
-    the chip's numbers).  int8 scales fold into the math (per-channel -> q /
-    context; per-position -> scores / probs) — the dequantized slab is
-    never materialized.
+    the chip's numbers).
 
-    q [b, 1, h, d]; bias_hl additive f32 [h, L] (carries causal masking);
-    kv_mask [b, L]; k_scale/v_scale None or [b, 1, g*d] (per-channel) or
-    [b, L, g] (per-position).  Returns [b, 1, h, d] in model dtype."""
+    q [b, 1, h, d]; kv_mask [b, L] per-row key validity (what hides the
+    positions a row has not reached), or None.  Returns [b, 1, h, d] in
+    model dtype."""
     b, L, gd = kf.shape
     h = num_heads
     g = num_kv_heads or h
     d, r = gd // g, h // g
-    k_chan = k_scale is not None and k_scale.shape[1] == 1
-    v_chan = v_scale is not None and v_scale.shape[1] == 1
     # sel[f, hq]: feature f of the slab belongs to query head hq's K/V head
     sel = jnp.arange(gd)[:, None] // d == jnp.arange(h)[None, :] // r
     # qexp[b, f, hq] = q[b, hq, f % d] where sel: a K/V head's features, in
     # the slab's order, against the r query heads it serves
     qt = jnp.swapaxes(q.reshape(b, g, r, d).astype(jnp.float32), 2, 3)
     qt = qt.reshape(b, gd, 1, r)
-    if k_chan:
-        qt = qt * k_scale[:, 0, :, None, None]
     qexp = jnp.where(
         sel[None], jnp.broadcast_to(qt, (b, gd, g, r)).reshape(b, gd, h),
         0.0).astype(dtype)
     s = jnp.einsum("blf,bfh->blh", kf.astype(dtype), qexp,
                    preferred_element_type=jnp.float32)
-    if k_scale is not None and not k_chan:
-        s = s * jnp.repeat(k_scale, r, axis=2)
-    if bias_hl is not None:
-        s = s + bias_hl.T[None]
     if kv_mask is not None:
         s = s + jnp.where(kv_mask > 0, 0.0, _NEG_INF_DENSE)[:, :, None]
     p = jax.nn.softmax(s, axis=1)
-    if v_scale is not None and not v_chan:
-        p = p * jnp.repeat(v_scale, r, axis=2)
     ctx2 = jnp.einsum("blh,blf->bhf", p.astype(dtype), vf.astype(dtype),
                       preferred_element_type=jnp.float32)      # [b, h, g*d]
     # a query head keeps its own K/V head's features: summed over the K/V
     # heads' axis (the major one), what is left is [b, r, g*d]
     ctx = jnp.where(sel.T[None], ctx2, 0.0).reshape(b, g, r, gd).sum(1)
-    if v_chan:
-        ctx = ctx * v_scale[:, 0, None, :]
     ctx = jnp.swapaxes(ctx.reshape(b, r, g, d), 1, 2)          # [b, g, r, d]
     return ctx.reshape(b, 1, h, d).astype(dtype)
 

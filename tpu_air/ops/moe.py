@@ -16,9 +16,8 @@ and nothing else.
 One formulation, no switch: rows are sorted by expert and the three
 products run as grouped matmuls over the uneven groups (the Pallas
 ``megablox`` kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere — the same
-mathematics; the kernel exists only for the TPU).  tools/moe_candidates.py
-holds the other formulations that were measured against it on the v5e
-(PERF.md, PR 27).
+mathematics; the kernel exists only for the TPU).  The other formulations
+that were measured against it on the v5e are in PERF.md (PR 27).
 """
 
 from __future__ import annotations
@@ -34,7 +33,7 @@ Array = jax.Array
 # ``gmm``, ``gmm.1``, ...
 GMM_TILING = (128, 2048, 1024)
 # where GMM_TILING does not divide the shapes (a contraction of 7168 = 7 x
-# 1024): the fastest of twelve tried on the v5e (tools/gmm_tilings.py) at 16
+# 1024): the fastest of twelve tried on the v5e (PERF.md, PR 43) at 16
 # groups of 7168 x 2048, 64 to 1024 held rows among 1024 to 3072 sorted:
 # 0.67 to 0.80 ms a product where the 470 MB of weights take 0.57 at the HBM
 # peak; (128, 1024, 1024) reads 0.71 to 0.83, (128, 3584, 512) 0.85 to 0.97,

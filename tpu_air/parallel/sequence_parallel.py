@@ -92,8 +92,7 @@ def make_sp_train_step(
     """Returns (jitted_step, model).  ``jitted_step(params, opt_state,
     input_ids, targets) -> (params, opt_state, loss)`` with input_ids /
     targets sharded P(data, sequence) and params/opt_state replicated."""
-    cfg = LMConfig.from_dict({**config.to_dict(),
-                              "attention": "ring", "sequence_axis": seq_axis})
+    cfg = LMConfig.from_dict({**config.to_dict(), "sequence_axis": seq_axis})
     model = CausalLM(cfg)
 
     def local_step(params, opt_state, input_ids, targets):
@@ -126,7 +125,7 @@ def make_sp_train_step(
 
 def init_sp_params(config: LMConfig, mesh: Mesh, seed: int = 0):
     """Replicated param init (single-device trace; placed replicated)."""
-    model = CausalLM(LMConfig.from_dict({**config.to_dict(), "attention": "dense",
+    model = CausalLM(LMConfig.from_dict({**config.to_dict(),
                                          "sequence_axis": None}))
     rng = jax.random.PRNGKey(seed)
     params = model.init(rng, jnp.ones((1, 8), jnp.int32))["params"]
