@@ -29,9 +29,9 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
     manifest.validate(bench.doc)
     cell = bench.cell(CELL)
     assert (cell["config"], cell["chips"]) == (CONFIG, 1)
-    assert bench.doc["workloads"][-1] is cell       # appended, not inserted
-    assert bench.doc["configs"][-1]["name"] == CONFIG
-    assert bench.doc["configs"][-1]["reduced"] == [
+    assert bench.doc["workloads"][7] is cell        # appended, not inserted
+    assert bench.doc["configs"][4]["name"] == CONFIG
+    assert bench.doc["configs"][4]["reduced"] == [
         "num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
         "vocab_size"]
     assert bench.traffic(cell)["kind"] == "mlaserve"
@@ -57,14 +57,18 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
                  "lm_kv_gather_share", "lm_attention_share",
                  "lm_expert_share", "engine_host_ms_p50"):
         assert name not in layer, name
-    # the new metrics are this cell's alone, and at the end of the list
-    new = [m["name"] for m in bench.doc["per_layer"][-8:]]
+    # the new metrics came in together, this cell the first to report them
+    # (PR 47 appended its own behind them, and its cell to moe_shared_share)
+    names = [m["name"] for m in bench.doc["per_layer"]]
+    first = names.index("mla_decode_roofline")
+    mine = bench.doc["per_layer"][first:first + 8]
+    new = [m["name"] for m in mine]
     assert new == ["mla_decode_roofline", "mla_attention_roofline",
                    "moe_held_expert_roofline", "mla_latent_share",
                    "moe_shared_share", "latent_live_share",
                    # PR 45: the chunk's walk, and what the traffic leaves it
                    "mla_chunk_attention_share", "chunk_page_visit_share"]
-    assert all(m["workloads"] == [CELL] for m in bench.doc["per_layer"][-8:])
+    assert all(m["workloads"][0] == CELL for m in mine)
     assert [w["name"] for w in bench.doc["workloads"][:7]] == [
         "t5base-finetune", "t5base-finetune-dp4", "t5base-batchgen",
         "t5large-serve", "t5large-batchgen", "olmoe-serve-decode",

@@ -733,3 +733,55 @@ def test_latent_decode_rows_read_their_pages_in_place_on_v5e(
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pools           # appended to in place
     assert mem.temp_size_in_bytes < pools // 4        # and no second pool
+
+
+# -- a layer that is one thing: Mamba-2 rows beside held latent experts (PR 47)
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "chunk"])
+def test_mamba2_state_pool_stays_in_place_at_full_depth_on_v5e(
+        v5e, monkeypatch, program):
+    """``nemotron3-serve-agent``'s three programs at its widths, depth (all
+    11 layers: PR 42 met its copy only at full depth) and geometry (128 slots
+    x 4096, pages of 256): the chip's compiler takes each, the donated cache
+    comes back aliased, nothing the size of the ``[128, 128, 64, 128]``
+    float32 state pool (537 MB a layer) is held or copied beside it, the ten
+    expert products are the grouped-matmul kernel with the whole-matrix tile,
+    and the program fits the chip beside its 12.6 GB of arguments."""
+    import json
+    import os
+
+    from benchmark import weights_nemotron
+    from tpu_air.models.lm import CausalLM
+    from tpu_air.models.lm.generate import (make_paged_decode_body,
+                                            make_paged_mixed_body,
+                                            make_prefill_chunk_body)
+    from tpu_air.ops import moe
+
+    monkeypatch.setattr(moe.jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "configs", "nemotron3-super-120b-a12b.json")) as f:
+        cfg = weights_nemotron.lm_config(json.load(f), "bfloat16", 4096)
+    model = CausalLM(cfg)
+    slots, slot_len, page = 128, 4096, 256
+    npg = slot_len // page
+    params, cache, i32 = _serving_pool(model, slots, slot_len, page, v5e)
+    step = (i32(slots), i32(slots), i32(slots, npg))
+    chunk = (i32(1, page), i32(), i32(), i32(npg))
+    body, args = {
+        "decode": (make_paged_decode_body(model, slot_len), step),
+        "chunk": (make_prefill_chunk_body(model, page, slot_len), chunk),
+        "mixed": (make_paged_mixed_body(model, page, slot_len), step + chunk),
+    }[program]
+    kw = {} if program == "decode" else {"slot": i32()}
+    compiled = jax.jit(body, donate_argnums=(1,)).lower(
+        params, cache, *args, **kw).compile()
+    mem = compiled.memory_analysis()
+    pool = slots * 128 * 64 * 128 * 4                 # one layer's states
+    assert mem.alias_size_in_bytes >= 5 * pool        # updated in place
+    assert mem.temp_size_in_bytes < pool // 2 + (1 << 28)   # no second copy
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    text = compiled.as_text()
+    assert not re.search(r"= f32\[128,128,64,128\]\S* copy\(", text)
+    assert text.count("tpu_custom_call") == 10

@@ -247,6 +247,62 @@ def _jamba_mixed_step():
                       slot=i32()).compile())
 
 
+def _nemotron(build):
+    """A tiny engine program of layers that are ONE thing: Mamba-2, experts
+    in a latent (half of the router's held, a shared one), attention."""
+    from tpu_air.models.lm import CausalLM, LMConfig
+    from tpu_air.models.lm.generate import init_paged_cache
+
+    cfg = LMConfig(vocab_size=96, d_model=32, n_layers=3, n_heads=2,
+                   n_kv_heads=1, head_dim=16, d_ff=24, max_seq_len=32,
+                   rope_theta=None, tie_embeddings=False, num_experts=8,
+                   num_experts_per_tok=3, num_shared_experts=1,
+                   shared_d_ff=40, router="sigmoid_groups", router_scale=5.0,
+                   experts_first=4, experts_held=4, layer_pattern="ME*",
+                   mamba_n_heads=4, mamba_head_dim=8, mamba_n_groups=2,
+                   mamba_d_state=8, mamba_chunk_size=4, ff_act="relu2",
+                   moe_latent_size=16)
+    model = CausalLM(cfg)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32)))["params"]
+    slots, slot_len, page = 3, 32, 8
+    npg = slot_len // page
+    cache = jax.eval_shape(
+        lambda: init_paged_cache(model, slots, 1 + slots * npg, page, npg))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    return build(model, params, cache, i32, slots, slot_len, page, npg)
+
+
+def _nemotron_paged_step():
+    from tpu_air.models.lm.generate import make_lm_paged_decode_step_fn
+
+    return _nemotron(lambda model, params, cache, i32, slots, slot_len, page,
+                     npg: make_lm_paged_decode_step_fn(model, slot_len).lower(
+                         params, cache, i32(slots), i32(slots),
+                         i32(slots, npg)).compile())
+
+
+def _nemotron_prefill_chunk():
+    from tpu_air.models.lm.generate import make_lm_prefill_chunk_fn
+
+    return _nemotron(lambda model, params, cache, i32, slots, slot_len, page,
+                     npg: make_lm_prefill_chunk_fn(
+                         model, page, slot_len).lower(
+                         params, cache, i32(1, page), i32(), i32(), i32(npg),
+                         slot=i32()).compile())
+
+
+def _nemotron_mixed_step():
+    from tpu_air.models.lm.generate import make_lm_paged_mixed_step_fn
+
+    return _nemotron(lambda model, params, cache, i32, slots, slot_len, page,
+                     npg: make_lm_paged_mixed_step_fn(
+                         model, page, slot_len).lower(
+                         params, cache, i32(slots), i32(slots),
+                         i32(slots, npg), i32(1, page), i32(), i32(),
+                         i32(npg), slot=i32()).compile())
+
+
 def _gigachat(build):
     """A tiny latent-attention engine program: a dense layer, then a sparse
     one that holds half of its router's experts, with a shared expert."""
@@ -295,10 +351,12 @@ def _gigachat_mixed_step():
 
 
 # the words that came after benchmark/scopes.py wrote its list down (PR 41,
-# PR 43: lower-case words, which its reader takes for parts of a model as
-# they are)
+# PR 43, PR 47: lower-case words, which its reader takes for parts of a model
+# as they are)
 LATER_WORDS = {"ssm_conv", "ssm_scan", "ssm_state_update",
-               "mla_q", "mla_latent", "mla_out", "moe_shared"}
+               "mla_q", "mla_latent", "mla_out", "moe_shared",
+               "ssd_conv", "ssd_scan", "ssd_state_update", "ssd_gate_norm",
+               "moe_latent_down", "moe_latent_up"}
 WORDS = set(scopes.VOCABULARY) | LATER_WORDS
 
 # program -> the words it must carry, and for some the module around them
@@ -326,6 +384,23 @@ PROGRAMS = {
         "kv_gather": "attn", "decode_attention": "attn",
         "attn_scores": "attn", "attn_context": "attn",
         "moe_shared": "shared", "lm_head": None}),
+    # a layer that is one thing (PR 47): Mamba-2's scopes under ``mamba``,
+    # the latent pair around the routed experts under ``moe``
+    "nemotron_paged_step": (_nemotron_paged_step, {
+        "ssd_conv": "mamba", "ssd_state_update": "mamba",
+        "ssd_gate_norm": "mamba", "moe_latent_down": "moe",
+        "moe_latent_up": "moe", "moe_router": "moe", "moe_experts": "moe",
+        "moe_shared": "shared", "decode_attention": "attn",
+        "lm_head": None}),
+    "nemotron_prefill_chunk": (_nemotron_prefill_chunk, {
+        "ssd_conv": "mamba", "ssd_scan": "mamba", "ssd_gate_norm": "mamba",
+        "moe_latent_down": "moe", "moe_latent_up": "moe",
+        "attn_scores": "attn", "lm_head": None}),
+    "nemotron_mixed_step": (_nemotron_mixed_step, {
+        "ssd_conv": "mamba", "ssd_state_update": "mamba",
+        "ssd_scan": "mamba", "ssd_gate_norm": "mamba",
+        "moe_latent_down": "moe", "moe_latent_up": "moe",
+        "moe_experts": "moe", "moe_shared": "shared", "lm_head": None}),
     "t5_train_step": (_t5_train_step, {
         "attn_scores": "self_attn", "attn_softmax": "cross_attn",
         "attn_context": "self_attn", "dropout": "mlp", "loss": None,
@@ -599,7 +674,8 @@ NEW = {
     "lm_expert_share": ["olmoe-serve-decode"],
     "engine_unscoped_share": ["t5large-serve", "olmoe-serve-decode",
                               "jamba2-serve-reason",
-                              "gigachat-serve-docchat"],
+                              "gigachat-serve-docchat",
+                              "nemotron3-serve-agent"],
     # PR 41: the hybrid's decode step (a flax module's name and a scope word)
     "ssm_mixer_share": ["jamba2-serve-reason"],
     "ssm_state_share": ["jamba2-serve-reason"],
@@ -607,10 +683,14 @@ NEW = {
     # every program of the capture: nearly every iteration of the cell is the
     # mixed step, so the readers of the decode program alone do not list it)
     "mla_latent_share": ["gigachat-serve-docchat"],
-    "moe_shared_share": ["gigachat-serve-docchat"],
+    "moe_shared_share": ["gigachat-serve-docchat", "nemotron3-serve-agent"],
     # PR 45: a chunk's attention over its slot's latent pages, of the mixed
     # step (the dense form's three words; the walk's kernel is under the last)
     "mla_chunk_attention_share": ["gigachat-serve-docchat"],
+    # PR 47: the Mamba-2 state's pass, a chunk's block form, the latent pair
+    "ssd_state_share": ["nemotron3-serve-agent"],
+    "ssd_scan_share": ["nemotron3-serve-agent"],
+    "latent_proj_share": ["nemotron3-serve-agent"],
 }
 
 

@@ -1314,8 +1314,11 @@ class InferenceEngine:
             out, [(s, s.request) for s in rows], start, first)
         self.metrics.record_issue(ahead, mixed=chunk is not None)
         if self._recurrent:
-            # every row not in the step rode it with its state held
-            self.metrics.record_rows_held(self.config.num_slots - len(rows))
+            # every row not in the step rode it with its state held; the
+            # others' state the step advances, over the positions they hold
+            self.metrics.record_rows_held(
+                self.config.num_slots - len(rows), live=len(rows),
+                positions=sum(s.pos + 1 for s in rows))
         if not ahead:
             self._mark = time.monotonic()
 

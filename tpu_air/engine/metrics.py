@@ -140,6 +140,11 @@ class EngineMetrics:
         self.ssm_state_bytes = 0
         self.ssm_state_resets = 0
         self.ssm_rows_held = 0
+        # ... and rows x steps whose state a step ADVANCED, with the
+        # positions those rows held as it was issued (what its attention
+        # layers read): the bytes a step of such a model must move
+        self.ssd_rows_live = 0
+        self.ssd_positions_live = 0
         self.prefix_cache_disabled_by_model = False
         register(self)
 
@@ -305,9 +310,12 @@ class EngineMetrics:
         with self._lock:
             self.ssm_state_resets += 1
 
-    def record_rows_held(self, rows: int) -> None:
+    def record_rows_held(self, rows: int, live: int = 0,
+                         positions: int = 0) -> None:
         with self._lock:
             self.ssm_rows_held += rows
+            self.ssd_rows_live += live
+            self.ssd_positions_live += positions
 
     def record_dropped_step(self) -> None:
         """An issued step nobody read: its window closed (every live row
@@ -476,6 +484,8 @@ class EngineMetrics:
                 out["ssm_state_bytes"] = self.ssm_state_bytes
                 out["ssm_state_resets"] = self.ssm_state_resets
                 out["ssm_rows_held"] = self.ssm_rows_held
+                out["ssd_rows_live"] = self.ssd_rows_live
+                out["ssd_positions_live"] = self.ssd_positions_live
                 out["prefix_cache_disabled_by_model"] = (
                     self.prefix_cache_disabled_by_model)
             if self.steps_issued:

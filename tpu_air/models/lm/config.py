@@ -59,6 +59,23 @@ class LMConfig:
     ``num_experts`` routed over, from id ``experts_first``, are the ones this
     parameter tree HOLDS and computes (an expert-parallel rank; default all):
     a token's assignments to the others are counted and left to their ranks.
+
+    A LAYER THAT IS ONE THING (``layer_pattern``; what ``nemotron_h``
+    publishes as ``hybrid_override_pattern``): a string of one character a
+    layer, ``M`` a Mamba-2 mixer (``modeling.Mamba2Mixer``), ``*`` attention,
+    ``E`` routed experts; layer ``i`` is ``x + f_i(RMSNorm(x))`` and has no
+    second half, so :meth:`layer_kinds` says ``"none"`` for an ``E`` layer
+    and :meth:`ff_kinds` ``"none"`` for the two others.  The period and the
+    leading dense layers above do not apply then.  Mamba-2's widths:
+    ``mamba_n_heads`` heads of ``mamba_head_dim`` channels (``d_inner`` their
+    product), ``mamba_n_groups`` groups of heads that share B and C,
+    ``mamba_d_state`` states a channel, ``mamba_chunk_size`` the block of the
+    state-space-duality form (``ops/ssm.py``).  ``ff_act`` ``"relu2"`` makes
+    every feed-forward (an expert, the shared one) TWO matrices around a
+    squared ReLU instead of SwiGLU's three; ``moe_latent_size > 0`` puts the
+    routed experts inside a down- and up-projection to that width (the router
+    and the shared expert take the full hidden state); ``shared_d_ff`` is the
+    shared expert's width where it is not ``num_shared_experts * d_ff``.
     """
 
     vocab_size: int = 32000
@@ -106,6 +123,14 @@ class LMConfig:
     router_scale: float = 1.0
     experts_first: int = 0
     experts_held: Optional[int] = None  # default num_experts
+    layer_pattern: Optional[str] = None  # e.g. "MEM*E": one thing a layer
+    mamba_n_heads: int = 0          # > 0 with layer_pattern's M: Mamba-2
+    mamba_head_dim: int = 0
+    mamba_n_groups: int = 1
+    mamba_chunk_size: int = 128
+    ff_act: str = "swiglu"          # swiglu | relu2
+    moe_latent_size: int = 0        # > 0: routed experts work in a latent
+    shared_d_ff: Optional[int] = None   # default num_shared_experts * d_ff
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -152,6 +177,26 @@ class LMConfig:
                 f"{self.router_groups} groups of two or more of which "
                 f"{self.router_topk_groups} hold "
                 f"{self.num_experts_per_tok} a token")
+        if self.ff_act not in ("swiglu", "relu2"):
+            raise ValueError(f"ff_act {self.ff_act!r}")
+        if self.shared_d_ff is None:
+            self.shared_d_ff = self.num_shared_experts * self.d_ff
+        if self.layer_pattern is not None:
+            p = self.layer_pattern
+            if len(p) != self.n_layers or set(p) - set("M*E"):
+                raise ValueError(
+                    f"layer_pattern {p!r} is not {self.n_layers} characters "
+                    "of M (Mamba-2), * (attention) and E (experts)")
+            if "E" in p and not self.num_experts:
+                raise ValueError("layer_pattern has E layers and "
+                                 "num_experts is 0")
+            if "M" in p and (
+                    self.mamba_n_heads < 1 or self.mamba_head_dim < 1
+                    or self.mamba_n_heads % self.mamba_n_groups):
+                raise ValueError(
+                    f"Mamba-2 wants mamba_n_heads ({self.mamba_n_heads}) "
+                    f"heads of mamba_head_dim ({self.mamba_head_dim}) in "
+                    f"mamba_n_groups ({self.mamba_n_groups}) equal groups")
         if self.kv_lora_rank and (
                 self.head_dim
                 != self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -163,15 +208,25 @@ class LMConfig:
 
     def layer_kinds(self) -> List[str]:
         """The sequence mixer of each layer, in order: ``"attention"``
-        (``"latent"`` where the configuration has a latent) or ``"mamba"``."""
+        (``"latent"`` where the configuration has a latent) or ``"mamba"``;
+        under a ``layer_pattern`` ``"mamba2"``, ``"attention"`` or, for a
+        layer that is experts alone, ``"none"``."""
         attention = "latent" if self.kv_lora_rank else "attention"
+        if self.layer_pattern is not None:
+            return [{"M": "mamba2", "*": attention, "E": "none"}[c]
+                    for c in self.layer_pattern]
         return [attention if i % self.attn_layer_period
                 == self.attn_layer_offset else "mamba"
                 for i in range(self.n_layers)]
 
     def ff_kinds(self) -> List[str]:
         """The feed-forward of each layer, in order: ``"dense"`` (one SwiGLU)
-        or ``"sparse"`` (routed experts, and the shared one)."""
+        or ``"sparse"`` (routed experts, and the shared one); under a
+        ``layer_pattern`` ``"sparse"`` for an ``E`` layer and ``"none"`` for
+        a layer that is a mixer alone."""
+        if self.layer_pattern is not None:
+            return ["sparse" if c == "E" else "none"
+                    for c in self.layer_pattern]
         return ["sparse" if self.num_experts and i >= self.first_dense_layers
                 else "dense" for i in range(self.n_layers)]
 
@@ -198,11 +253,19 @@ class LMConfig:
     @property
     def has_recurrent_layers(self) -> bool:
         """Some layer keeps per-sequence state that is not K/V pages."""
-        return "mamba" in self.layer_kinds()
+        return bool({"mamba", "mamba2"} & set(self.layer_kinds()))
 
     @property
     def mamba_d_inner(self) -> int:
+        if self.mamba_n_heads:
+            return self.mamba_n_heads * self.mamba_head_dim
         return self.mamba_expand * self.d_model
+
+    @property
+    def mamba2_conv_dim(self) -> int:
+        """Channels of Mamba-2's one convolution: x, then B and C a group."""
+        return (self.mamba_d_inner
+                + 2 * self.mamba_n_groups * self.mamba_d_state)
 
     def to_dict(self) -> dict:
         return asdict(self)

@@ -1,5 +1,6 @@
-"""Published (Hugging Face) OLMoE, Jamba and DeepSeek-V3 configurations and
-weights -> ``LMConfig`` and this framework's ``CausalLM`` parameter tree.
+"""Published (Hugging Face) OLMoE, Jamba, DeepSeek-V3 and Nemotron-H
+configurations and weights -> ``LMConfig`` and this framework's ``CausalLM``
+parameter tree.
 
 Beside T5's importer (models/t5/hf_import.py).  Pure numpy: the converter
 only transposes, permutes and stacks, so it works on any element type (the
@@ -26,6 +27,12 @@ modeling code de-interleaves them before its rotate-half), which is
 ``modeling.LatentAttention`` uses them apart), and the tree may hold a SHARE
 of the model (an expert-parallel rank): a range of the routed experts and a
 range of the vocabulary's rows.
+
+``model_type: nemotron_h`` is renaming and transposition alone: its
+attention has no position encoding, the layer pattern goes in as published
+(``hybrid_override_pattern``), and the Mamba-2 mixer's ``in_proj`` keeps its
+published column order ``[z | x B C | dt]``.  The tree may hold a share, as
+above.
 """
 
 from __future__ import annotations
@@ -136,6 +143,49 @@ YARN_KEYS = {
 }
 
 
+#: ``model_type: nemotron_h`` (NVIDIA-Nemotron-3-Super-120B-A12B): the keys
+#: mapped, and the values that must hold.  ``rope_theta`` and
+#: ``partial_rotary_factor`` are published and read by nothing: the family's
+#: attention applies no position encoding.
+NEMOTRON_H_KEYS = {
+    "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers",
+    "hybrid_override_pattern": "layer_pattern",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "head_dim": "head_dim",
+    "moe_intermediate_size": "d_ff",       # one routed expert
+    "moe_shared_expert_intermediate_size": "shared_d_ff",
+    "moe_latent_size": "moe_latent_size",
+    "n_routed_experts": "num_experts",     # what the router scores
+    "num_experts_per_tok": "num_experts_per_tok",
+    "n_shared_experts": "num_shared_experts",
+    "n_group": "router_groups",
+    "topk_group": "router_topk_groups",
+    "routed_scaling_factor": "router_scale",
+    "mamba_num_heads": "mamba_n_heads",
+    "mamba_head_dim": "mamba_head_dim",
+    "n_groups": "mamba_n_groups",
+    "ssm_state_size": "mamba_d_state",
+    "conv_kernel": "mamba_d_conv",
+    "chunk_size": "mamba_chunk_size",
+    "vocab_size": "vocab_size",
+    "layer_norm_epsilon": "rmsnorm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+}
+NEMOTRON_H_FIXED = {
+    "attention_bias": False,
+    "mlp_bias": False,
+    "use_bias": False,
+    "mamba_proj_bias": False,
+    "use_conv_bias": True,
+    "mamba_hidden_act": "silu",
+    "mlp_hidden_act": "relu2",
+    "norm_topk_prob": True,
+    "sliding_window": None,
+}
+
+
 def _check_fixed(hf: Dict[str, Any], fixed: Dict[str, Any]) -> None:
     for key, want in fixed.items():
         if hf.get(key, want) != want:
@@ -188,12 +238,28 @@ def _deepseek_config_from_hf(hf: Dict[str, Any], dtype: str,
     return LMConfig(**fields)
 
 
+def _nemotron_h_config_from_hf(hf: Dict[str, Any], dtype: str,
+                               **overrides: Any) -> LMConfig:
+    _check_fixed(hf, NEMOTRON_H_FIXED)
+    fields = {ours: hf[theirs] for theirs, ours in NEMOTRON_H_KEYS.items()}
+    # a tree of fewer layers than the pattern publishes holds its first ones
+    fields["layer_pattern"] = fields["layer_pattern"][:fields["n_layers"]]
+    fields.update(
+        rope_theta=None, router="sigmoid_groups", ff_act="relu2",
+        max_seq_len=hf.get("max_position_embeddings", 2048),
+        pad_token_id=hf.get("pad_token_id") or 0,
+        eos_token_id=hf.get("eos_token_id"),
+        dtype=dtype)
+    fields.update(overrides)
+    return LMConfig(**fields)
+
+
 def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
                       **overrides: Any) -> LMConfig:
-    """``LMConfig`` of a published ``olmoe``, ``jamba`` or ``deepseek_v3``
-    ``config.json`` (a dict).  Refuses a configuration whose layer this
+    """``LMConfig`` of a published ``olmoe``, ``jamba``, ``deepseek_v3`` or
+    ``nemotron_h`` ``config.json`` (a dict).  Refuses a configuration whose layer this
     framework does not compute.  A tree that holds a share of a
-    ``deepseek_v3`` model says so in ``overrides``: ``experts_first`` /
+    ``deepseek_v3`` or ``nemotron_h`` model says so in ``overrides``: ``experts_first`` /
     ``experts_held`` (of the ``n_routed_experts`` the router scores) and the
     ``vocab_size`` of its slice.  The multi-token module
     (``num_nextn_predict_layers``) is an extra block the next-token logits do
@@ -202,6 +268,8 @@ def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
         return _jamba_config_from_hf(hf, dtype, **overrides)
     if hf.get("model_type") == "deepseek_v3":
         return _deepseek_config_from_hf(hf, dtype, **overrides)
+    if hf.get("model_type") == "nemotron_h":
+        return _nemotron_h_config_from_hf(hf, dtype, **overrides)
     _check_fixed(hf, HF_FIXED)
     kv = hf.get("num_key_value_heads", hf["num_attention_heads"])
     if kv != hf["num_attention_heads"]:
@@ -403,4 +471,71 @@ def convert_deepseek_v3_state_dict(get: Callable[[str], Any],
             f"configuration of {config.vocab_size}")
     for i in range(config.n_layers):
         params[f"layer_{i}"] = convert_deepseek_v3_layer(get, i, config)
+    return params
+
+
+def convert_nemotron_h_layer(get: Callable[[str], Any], i: int,
+                             config: LMConfig) -> Dict[str, Any]:
+    """Layer ``i`` of the tree from the published ``nemotron_h`` names
+    (``backbone.layers.i.norm`` and ``.mixer.*``, whatever the layer is):
+    renaming and transposition; the depthwise convolution ``[channels, 1,
+    width]`` becomes ``[width, channels]``.  Only the experts the
+    configuration holds are asked for."""
+    pre = f"backbone.layers.{i}."
+    kernel = lambda name: {"kernel": _t(  # noqa: E731
+        get(f"{pre}mixer.{name}.weight"))}
+    vector = lambda name: np.asarray(get(f"{pre}mixer.{name}"))  # noqa: E731
+    norm = {"weight": np.asarray(get(pre + "norm.weight"))}
+    kind = config.layer_pattern[i]
+    if kind == "M":
+        return {"mamba_norm": norm, "mamba": {
+            "in_proj": kernel("in_proj"),
+            "conv": {"kernel": _t(vector("conv1d.weight")[:, 0, :]),
+                     "bias": vector("conv1d.bias")},
+            "dt_bias": vector("dt_bias"), "A_log": vector("A_log"),
+            "D": vector("D"), "norm": vector("norm.weight"),
+            "out_proj": kernel("out_proj")}}
+    if kind == "*":
+        return {"attn_norm": norm,
+                "attn": {w: kernel(f"{w}_proj") for w in "qkvo"}}
+    experts = range(config.experts_first,
+                    config.experts_first + config.experts_held)
+    stack = lambda which: np.stack([  # noqa: E731
+        _t(get(f"{pre}mixer.experts.{e}.{which}_proj.weight"))
+        for e in experts])
+    return {"mlp_norm": norm,
+            "moe": {"router": _t(get(pre + "mixer.gate.weight")),
+                    "router_bias": vector("gate.e_score_correction_bias"),
+                    "latent_down": kernel("fc1_latent_proj"),
+                    "latent_up": kernel("fc2_latent_proj"),
+                    "up": stack("up"), "down": stack("down")},
+            "shared": {w: kernel(f"shared_experts.{w}_proj")
+                       for w in ("up", "down")}}
+
+
+def convert_nemotron_h_state_dict(get: Callable[[str], Any],
+                                  config: LMConfig,
+                                  vocab_rows: Any = None) -> Dict[str, Any]:
+    """The ``CausalLM`` parameter tree from a published ``nemotron_h`` state
+    dict, given as ``get(name)``: the first ``config.n_layers`` layers, the
+    routed experts ``config.experts_first .. + config.experts_held`` of each
+    ``E`` layer, and the rows ``vocab_rows`` (a ``range`` or slice; default
+    all) of the embedding and of the head.  The multi-token module's tensors
+    (``mtp.*``) are never asked for."""
+    rows = slice(None) if vocab_rows is None else vocab_rows
+    if isinstance(rows, range):
+        rows = slice(rows.start, rows.stop, rows.step)
+    take = lambda name: np.asarray(get(name))[rows]  # noqa: E731
+    params: Dict[str, Any] = {
+        "embedding": take("backbone.embeddings.weight"),
+        "final_norm": {"weight": np.asarray(get("backbone.norm_f.weight"))},
+    }
+    if not config.tie_embeddings:
+        params["lm_head"] = {"kernel": _t(take("lm_head.weight"))}
+    if params["embedding"].shape[0] != config.vocab_size:
+        raise ValueError(
+            f"{params['embedding'].shape[0]} vocabulary rows for a "
+            f"configuration of {config.vocab_size}")
+    for i in range(config.n_layers):
+        params[f"layer_{i}"] = convert_nemotron_h_layer(get, i, config)
     return params

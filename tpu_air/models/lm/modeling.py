@@ -667,6 +667,24 @@ class SwiGLU(nn.Module):
         return dense("down", cfg.d_model)(gate * up)
 
 
+class ReLU2(nn.Module):
+    """``down(relu(up x)^2)``: the two-matrix feed-forward ``nemotron_h``
+    publishes (``mlp_hidden_act: relu2``), no gate and no bias."""
+
+    config: LMConfig
+    width: int
+
+    @nn.compact
+    def __call__(self, x: Array) -> Array:
+        cfg = self.config
+        dtype = jnp.dtype(cfg.dtype)
+        dense = lambda name, out: nn.Dense(  # noqa: E731
+            out, use_bias=False, dtype=dtype,
+            kernel_init=nn.initializers.normal(0.02), name=name)
+        return dense("down", cfg.d_model)(
+            jnp.square(nn.relu(dense("up", self.width)(x))))
+
+
 def grouped_sigmoid_routing(logits: Array, bias: Array, k: int, groups: int,
                             topk_groups: int, scale: float):
     """``deepseek_v3``'s ``noaux_tc`` routing over ``logits [t, E]`` float32:
@@ -706,6 +724,15 @@ class SparseExperts(nn.Module):
     held elsewhere is counted and contributes nothing here (its rank adds
     it; the sum of all ranks' outputs is the uncut layer's).
 
+    ``config.ff_act`` ``"relu2"``: an expert is two matrices, ``down_e(relu(
+    up_e x)^2)``, and the tree has no ``gate``.  ``config.moe_latent_size``
+    (``nemotron_h``'s LatentMoE): the experts work on ``l = latent_down x``,
+    that many numbers wide, and ``latent_up`` takes their weighted sum back
+    to ``d_model``; the router scores the FULL hidden state.  Over a share
+    the down-projection is computed here for every token and ``latent_up`` is
+    applied to the held experts' partial sum: it is linear, so the ranks'
+    parts add as before.
+
     Sows ``expert_rows`` int32 into ``intermediates`` for callers that make
     it mutable (the engine's routing counters): ``[tokens, E]``, 1 where the
     token went to the expert; where only a share is held ``[tokens, held +
@@ -725,9 +752,13 @@ class SparseExperts(nn.Module):
         held, whole = cfg.experts_held, cfg.holds_all_experts
         init = nn.initializers.normal(0.02)
         router = self.param("router", init, (d, e), jnp.float32)
-        gate = self.param("gate", init, (held, d, f), jnp.float32)
-        up = self.param("up", init, (held, d, f), jnp.float32)
-        down = self.param("down", init, (held, f, d), jnp.float32)
+        # the width the experts work in: the model's, or the latent's
+        dl = cfg.moe_latent_size or d
+        gate = None
+        if cfg.ff_act != "relu2":
+            gate = self.param("gate", init, (held, dl, f), jnp.float32)
+        up = self.param("up", init, (held, dl, f), jnp.float32)
+        down = self.param("down", init, (held, f, dl), jnp.float32)
         t = x.reshape(-1, d)
         with jax.named_scope("moe_router"):
             logits = jnp.dot(t.astype(jnp.float32), router.astype(jnp.float32),
@@ -752,9 +783,20 @@ class SparseExperts(nn.Module):
                     [rows, (chosen == held).sum(-1, dtype=jnp.int32)[:, None]],
                     axis=-1)
             self.sow("intermediates", "expert_rows", rows)
-        y = expert_ffn(t.astype(dtype), chosen, probs, gate.astype(dtype),
-                       up.astype(dtype), down.astype(dtype), partial=not whole)
-        return y.astype(dtype).reshape(x.shape)
+        t = t.astype(dtype)
+        if cfg.moe_latent_size:
+            with jax.named_scope("moe_latent_down"):
+                t = nn.Dense(dl, use_bias=False, dtype=dtype,
+                             kernel_init=init, name="latent_down")(t)
+        y = expert_ffn(t, chosen, probs,
+                       None if gate is None else gate.astype(dtype),
+                       up.astype(dtype), down.astype(dtype),
+                       partial=not whole).astype(dtype)
+        if cfg.moe_latent_size:
+            with jax.named_scope("moe_latent_up"):
+                y = nn.Dense(d, use_bias=False, dtype=dtype,
+                             kernel_init=init, name="latent_up")(y)
+        return y.reshape(x.shape)
 
 
 def expert_assignments(intermediates) -> Array:
@@ -777,6 +819,7 @@ class DepthwiseConv(nn.Module):
     tails)."""
 
     width: int
+    ssd: bool = False   # Mamba-2's: the device rows say ssd_conv
 
     @nn.compact
     def __call__(self, x: Array, tail: Array, valid_len: Array, ride=None):
@@ -794,7 +837,8 @@ class DepthwiseConv(nn.Module):
                 valid_len)
             return y, tail.reshape(tail.shape[0], -1)
 
-        with jax.named_scope("ssm_conv"):
+        with (jax.named_scope("ssd_conv") if self.ssd
+              else jax.named_scope("ssm_conv")):
             if ride is not None:
                 # mixed step (ChunkRows): one position of the ``s`` rows
                 # whose tails came in, then one row's chunk from ``ride``,
@@ -817,6 +861,100 @@ def _init_dt_bias(key, shape, dtype=jnp.float32):
     dt0 = jnp.exp(jax.random.uniform(key, shape, jnp.float32)
                   * (jnp.log(0.1) - jnp.log(1e-3)) + jnp.log(1e-3))
     return (dt0 + jnp.log(-jnp.expm1(-dt0))).astype(dtype)
+
+
+class _SlotRows:
+    """What a Mamba mixer keeps a sequence, and how a call reaches it: the
+    convolution tail and the float32 state, a row a sequence, in the
+    ``cache`` collection (``conv_state [S, tail_width]`` in the model's
+    dtype, ``ssm_state [S, *state_shape]``), for :class:`MambaMixer` and
+    :class:`Mamba2Mixer` alike.  Three callers, told apart by what the cache
+    holds (:class:`MambaMixer` has them); ``tail``, ``state``, ``valid`` and
+    ``ride`` are what the call starts from (``ride``: a mixed step's chunk,
+    the ``(tail [1, ..], valid_len [1])`` :class:`DepthwiseConv` wants), and
+    :meth:`run` runs the recurrence for the call's shape and writes back."""
+
+    def __init__(self, mod: nn.Module, decode: bool,
+                 chunk: Optional[ChunkRows], b: int, l: int, tail_width: int,
+                 state_shape, dtype):
+        self.decode, self.one_token = decode, l == 1
+        self.tail = jnp.zeros((b, tail_width), dtype)
+        self.state = jnp.zeros((b,) + state_shape, jnp.float32)
+        self.valid = jnp.full((b,), l, jnp.int32)
+        # what the host pushes in (models/lm/paged_cache.py) tells the engine's
+        # cache from the plain one
+        self.engine = decode and mod.has_variable("cache", STATE_ROW)
+        if decode:
+            self.cs = mod.variable(
+                "cache", "conv_state",
+                lambda: jnp.zeros((b, tail_width), dtype))
+            self.ss = mod.variable(
+                "cache", "ssm_state",
+                lambda: jnp.zeros((b,) + state_shape, jnp.float32))
+            self.tail, self.state = self.cs.value, self.ss.value
+        self.ride = None
+        if self.engine:
+            index = mod.get_variable("cache", CACHE_INDEX)
+            self.valid = mod.get_variable("cache", VALID_LEN)
+            if chunk is not None:
+                # mixed step: every slot's row takes one token (or is held)
+                # and rows [S:] are the chunk of ``chunk.slot``, from what
+                # that slot holds as the step begins
+                self.row, self.fresh = chunk.slot, chunk.start == 0
+                self.ride = (self._row_of(self.tail), chunk.valid[None])
+            elif l > 1:
+                # one chunk of one row's prompt (b == 1)
+                self.row, self.fresh = mod.get_variable(
+                    "cache", STATE_ROW)[0], index[0] == 0
+                self.tail, self.state = (self._row_of(self.tail),
+                                         self._row_of(self.state))
+                self.valid = self.valid[:1]
+
+    def _row_of(self, rows):
+        """The call's slot's row of ``rows`` as a chunk starts from it: zeros
+        where the chunk is its prompt's first."""
+        return jnp.where(self.fresh, 0, jax.lax.dynamic_slice_in_dim(
+            rows, self.row, 1, axis=0))
+
+    def run(self, xs, new_tail, scan, update):
+        """``xs``: the per-position inputs of the recurrence, ``[rows, l,
+        ..]`` each; ``scan(*xs, state, valid)`` a call of several positions,
+        ``update(*xs, state, valid)`` the one-token call over all rows, both
+        ``-> (y, state')``; ``new_tail`` what the convolution left.  Returns
+        ``y`` and keeps tail and state where the cache wants them."""
+        if self.ride is not None:
+            S = self.state.shape[0]
+            y, new_state = update(*(a[:S] for a in xs), self.state,
+                                  self.valid)
+            # the chunk's row is read from what the step's half left: it
+            # held that row, so this is the state the slot had, and the pool
+            # of states has ONE reader at a time (read beside the update,
+            # the chip's compiler copies every layer's 42 MB of state first
+            # at full depth: PERF.md, PR 42).  The chunk's positions as one
+            # row: [C, 1, ..] -> [1, C, ..]
+            y_c, state_c = scan(*(jnp.swapaxes(a[S:], 0, 1) for a in xs),
+                                self._row_of(new_state), self.ride[1])
+            y = jnp.concatenate([y, jnp.swapaxes(y_c, 0, 1)])
+            new_tail, tail_c = new_tail
+        elif self.one_token and self.decode:
+            y, new_state = update(*xs, self.state, self.valid)
+        else:
+            y, new_state = scan(*xs, self.state, self.valid)
+        if self.decode:
+            if self.ride is not None:
+                # the step held the chunk's row (it rides at position 0); the
+                # chunk's own state is written over it, and is what lands
+                new_tail = jax.lax.dynamic_update_slice_in_dim(
+                    new_tail, tail_c, self.row, axis=0)
+                new_state = jax.lax.dynamic_update_slice_in_dim(
+                    new_state, state_c, self.row, axis=0)
+            elif self.engine and not self.one_token:
+                new_tail = jax.lax.dynamic_update_slice_in_dim(
+                    self.cs.value, new_tail, self.row, axis=0)
+                new_state = jax.lax.dynamic_update_slice_in_dim(
+                    self.ss.value, new_state, self.row, axis=0)
+            self.cs.value, self.ss.value = new_tail, new_state
+        return y
 
 
 class MambaMixer(nn.Module):
@@ -873,42 +1011,7 @@ class MambaMixer(nn.Module):
         skip = self.param("D", nn.initializers.ones, (c,), jnp.float32)
         A = -jnp.exp(a_log.astype(jnp.float32)).T                  # [n, c]
 
-        tail = jnp.zeros((b, (k - 1) * c), dtype)
-        state = jnp.zeros((b, n, c), jnp.float32)
-        valid = jnp.full((b,), l, jnp.int32)
-        # what the host pushes in (models/lm/paged_cache.py) tells the engine's
-        # cache from the plain one
-        engine = decode and self.has_variable("cache", STATE_ROW)
-        if decode:
-            cs = self.variable("cache", "conv_state",
-                               lambda: jnp.zeros((b, (k - 1) * c), dtype))
-            ss = self.variable("cache", "ssm_state",
-                               lambda: jnp.zeros((b, n, c), jnp.float32))
-            tail, state = cs.value, ss.value
-
-        def row_of(rows, slot, fresh):
-            """One slot's row of ``rows`` as a chunk starts from it: zeros
-            where the chunk is its prompt's first."""
-            return jnp.where(fresh, 0, jax.lax.dynamic_slice_in_dim(
-                rows, slot, 1, axis=0))
-
-        ride = None
-        if engine:
-            index = self.get_variable("cache", CACHE_INDEX)
-            valid = self.get_variable("cache", VALID_LEN)
-            if chunk is not None:
-                # mixed step: every slot's row takes one token (or is held)
-                # and rows [S:] are the chunk of ``chunk.slot``, from what
-                # that slot holds as the step begins
-                row, fresh = chunk.slot, chunk.start == 0
-                ride = (row_of(tail, row, fresh), chunk.valid[None])
-            elif l > 1:
-                # one chunk of one row's prompt (b == 1)
-                row, fresh = self.get_variable("cache", STATE_ROW)[0], \
-                    index[0] == 0
-                tail, state = row_of(tail, row, fresh), row_of(state, row,
-                                                               fresh)
-                valid = valid[:1]
+        rows = _SlotRows(self, decode, chunk, b, l, (k - 1) * c, (n, c), dtype)
 
         def scan(u, dt, B, C, state, valid):
             with jax.named_scope("ssm_scan"):
@@ -933,7 +1036,8 @@ class MambaMixer(nn.Module):
 
         uz = dense("in_proj", 2 * c)(x)
         u, z = uz[..., :c], uz[..., c:]
-        u, new_tail = DepthwiseConv(k, name="conv")(u, tail, valid, ride)
+        u, new_tail = DepthwiseConv(k, name="conv")(
+            u, rows.tail, rows.valid, rows.ride)
         u = nn.silu(u).astype(dtype)
         dbc = dense("x_proj", r + 2 * n)(u)
         dt = RMSNorm(cfg.rmsnorm_eps, dtype, name="dt_norm")(dbc[..., :r])
@@ -944,43 +1048,105 @@ class MambaMixer(nn.Module):
             kernel_init=lambda key, shape, dt_: jax.random.uniform(
                 key, shape, dt_, -r ** -0.5, r ** -0.5),
             bias_init=_init_dt_bias)(dt.astype(jnp.float32)))
-        if ride is not None:
-            S = state.shape[0]
-            y, new_state = update(u[:S], dt[:S], B[:S], C[:S], state, valid)
-            # the chunk's row is read from what the step's half left: it
-            # held that row, so this is the state the slot had, and the pool
-            # of states has ONE reader at a time (read beside the update,
-            # the chip's compiler copies every layer's 42 MB of state first
-            # at full depth: PERF.md, PR 42).  The chunk's positions as one
-            # row: [C, 1, ..] -> [1, C, ..]
-            y_c, state_c = scan(*(jnp.swapaxes(a[S:], 0, 1)
-                                  for a in (u, dt, B, C)),
-                                row_of(new_state, row, fresh), ride[1])
-            y = jnp.concatenate([y, jnp.swapaxes(y_c, 0, 1)])
-            new_tail, tail_c = new_tail
-        elif l == 1 and decode:
-            y, new_state = update(u, dt, B, C, state, valid)
-        else:
-            y, new_state = scan(u, dt, B, C, state, valid)
-        if decode:
-            if ride is not None:
-                # the step held the chunk's row (it rides at position 0); the
-                # chunk's own state is written over it, and is what lands
-                new_tail = jax.lax.dynamic_update_slice_in_dim(
-                    new_tail, tail_c, row, axis=0)
-                new_state = jax.lax.dynamic_update_slice_in_dim(
-                    new_state, state_c, row, axis=0)
-            elif engine and l > 1:
-                new_tail = jax.lax.dynamic_update_slice_in_dim(
-                    cs.value, new_tail, row, axis=0)
-                new_state = jax.lax.dynamic_update_slice_in_dim(
-                    ss.value, new_state, row, axis=0)
-            cs.value, ss.value = new_tail, new_state
+        y = rows.run((u, dt, B, C), new_tail, scan, update)
         return dense("out_proj", cfg.d_model)(
             (y * nn.silu(z.astype(jnp.float32))).astype(dtype))
 
 
+class Mamba2Mixer(nn.Module):
+    """Mamba-2 mixer as ``nemotron_h`` publishes it: ``H`` heads of ``P``
+    channels, ``G`` groups of heads that share B and C, ONE convolution over
+    x, B and C together, a scalar decay a head, the gate BEFORE a group
+    RMSNorm::
+
+        [z | xBC | dt] = in_proj(h);  xBC = silu(conv(xBC));  [u | B | C] = xBC
+        dt = softplus(dt + dt_bias);  A = -exp(A_log)                 a head
+        S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] u_t[h] (x) B_t[g(h)]
+        y_t[h] = S_t[h] C_t[g(h)] + D[h] u_t[h]
+        out = out_proj(RMSNorm_groups(y * silu(z)) * w)
+
+    (the mean square over each group's ``d_inner / G`` channels).  What it
+    keeps between calls is :class:`MambaMixer`'s, a row a sequence:
+    ``conv_state [S, (d_conv-1) * conv_dim]`` in the model's dtype and float32
+    ``ssm_state [S, H, P, N]``, as published (``ops/ssm.py``: whole tiles).
+    The callers are :class:`MambaMixer`'s three, told apart the same way; a
+    chunk runs the block form (``ssm.ssd_chunk``, blocks of
+    ``config.mamba_chunk_size``), a decode step the one-token update over all
+    rows (``ssm.ssd_state_update``).
+
+    Scopes (docs/OBSERVABILITY.md): ``ssd_conv`` the convolution,
+    ``ssd_scan`` a chunk's block form, ``ssd_state_update`` the step's pass
+    over the state, ``ssd_gate_norm`` the gate and the group norm."""
+
+    config: LMConfig
+
+    @nn.compact
+    def __call__(self, x: Array, decode: bool = False,
+                 chunk: Optional[ChunkRows] = None) -> Array:
+        cfg = self.config
+        dtype = jnp.dtype(cfg.dtype)
+        b, l, _ = x.shape
+        H, P, G, n, k = (cfg.mamba_n_heads, cfg.mamba_head_dim,
+                         cfg.mamba_n_groups, cfg.mamba_d_state,
+                         cfg.mamba_d_conv)
+        c, cd = cfg.mamba_d_inner, cfg.mamba2_conv_dim
+        dense = lambda name, out: nn.Dense(  # noqa: E731
+            out, use_bias=False, dtype=dtype,
+            kernel_init=nn.initializers.normal(0.02), name=name)
+        a_log = self.param(
+            "A_log", lambda key, shape, dt: jnp.log(jax.random.uniform(
+                key, shape, dt, 1.0, 16.0)), (H,), jnp.float32)
+        skip = self.param("D", nn.initializers.ones, (H,), jnp.float32)
+        dt_bias = self.param("dt_bias", _init_dt_bias, (H,), jnp.float32)
+        A = -jnp.exp(a_log.astype(jnp.float32))                     # [H]
+
+        rows = _SlotRows(self, decode, chunk, b, l, (k - 1) * cd, (H, P, n),
+                         dtype)
+
+        def heads(u, B, C):
+            """``[r, l, ..]`` flat as projected -> by head and by group."""
+            r, q = u.shape[:2]
+            return (u.reshape(r, q, H, P), B.reshape(r, q, G, n),
+                    C.reshape(r, q, G, n))
+
+        def scan(u, dt, B, C, state, valid):
+            with jax.named_scope("ssd_scan"):
+                u4, B4, C4 = heads(u, B, C)
+                y, state = ssm.ssd_chunk(u4, dt, A, B4, C4, skip, state,
+                                         valid, cfg.mamba_chunk_size)
+            return y.reshape(y.shape[:2] + (c,)), state
+
+        def update(u, dt, B, C, state, valid):
+            with jax.named_scope("ssd_state_update"):
+                u4, B4, C4 = heads(u, B, C)
+                y, state = ssm.ssd_state_update(
+                    u4[:, 0], dt[:, 0], A, B4[:, 0], C4[:, 0], skip, state,
+                    valid > 0)
+                return y.reshape(y.shape[0], 1, c), state
+
+        zxd = dense("in_proj", 2 * c + 2 * G * n + H)(x)
+        z, xbc, dt = zxd[..., :c], zxd[..., c:c + cd], zxd[..., c + cd:]
+        xbc, new_tail = DepthwiseConv(k, ssd=True, name="conv")(
+            xbc, rows.tail, rows.valid, rows.ride)
+        xbc = nn.silu(xbc).astype(dtype)
+        u, B, C = xbc[..., :c], xbc[..., c:c + G * n], xbc[..., c + G * n:]
+        dt = nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
+        y = rows.run((u, dt, B, C), new_tail, scan, update)
+        with jax.named_scope("ssd_gate_norm"):
+            w = self.param("norm", nn.initializers.ones, (c,), jnp.float32)
+            y = (y * nn.silu(z.astype(jnp.float32))).reshape(
+                y.shape[:2] + (G, c // G))
+            y = y * jax.lax.rsqrt(
+                jnp.mean(jnp.square(y), -1, keepdims=True) + cfg.rmsnorm_eps)
+            y = (y.reshape(y.shape[:2] + (c,)) * w).astype(dtype)
+        return dense("out_proj", cfg.d_model)(y)
+
+
 class Block(nn.Module):
+    """One layer: a sequence mixer (``kind``) and then a feed-forward
+    (``ff``), each ``x + f(RMSNorm(x))``; either may be ``"none"`` (a
+    ``layer_pattern``'s layer is ONE of the two)."""
+
     config: LMConfig
     kind: str = "attention"   # LMConfig.layer_kinds()
     ff: str = "dense"         # LMConfig.ff_kinds()
@@ -994,27 +1160,30 @@ class Block(nn.Module):
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
         # ``chunk``: only the sequence mixer tells a mixed step's two parts
         # apart; the norms and the feed-forward below take its rows as rows
-        if self.kind == "mamba":
-            x = x + drop(MambaMixer(cfg, name="mamba")(
+        if self.kind in ("mamba", "mamba2"):
+            mixer = MambaMixer if self.kind == "mamba" else Mamba2Mixer
+            x = x + drop(mixer(cfg, name="mamba")(
                 RMSNorm(cfg.rmsnorm_eps, dtype, name="mamba_norm")(x),
                 decode=decode, chunk=chunk))
-        else:
+        elif self.kind != "none":
             mixer = (LatentAttention if self.kind == "latent"
                      else CausalSelfAttention)
             x = x + drop(mixer(cfg, name="attn")(
                 RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(x),
                 positions, decode=decode, chunk=chunk,
             ))
+        if self.ff == "none":
+            return x
         # the feed-forward kind follows from the configuration's numbers
+        ffn = ReLU2 if cfg.ff_act == "relu2" else SwiGLU
         h = RMSNorm(cfg.rmsnorm_eps, dtype, name="mlp_norm")(x)
         if self.ff != "sparse":
-            return x + drop(SwiGLU(cfg, cfg.dense_d_ff, name="mlp")(h))
+            return x + drop(ffn(cfg, cfg.dense_d_ff, name="mlp")(h))
         y = SparseExperts(cfg, name="moe")(h)
         if cfg.num_shared_experts:
             # every token's, beside its routed ones
             with jax.named_scope("moe_shared"):
-                y = y + SwiGLU(cfg, cfg.num_shared_experts * cfg.d_ff,
-                               name="shared")(h)
+                y = y + ffn(cfg, cfg.shared_d_ff, name="shared")(h)
         return x + drop(y)
 
 

@@ -12,7 +12,9 @@ there for a sequence follows from its kind (``LMConfig.layer_kinds()``), and
   pool under its short name.
 * **rows** a slot: a Mamba layer's convolution tail ``conv_state [S, (d_conv -
   1) * d_inner]`` and float32 ``ssm_state [S, d_state, d_inner]``, which no
-  table reaches and the slot's index does.
+  table reaches and the slot's index does; a Mamba-2 layer's are the same two
+  leaves at its own shapes (``[S, (d_conv - 1) * conv_dim]`` over x, B and C
+  together, ``[S, heads, head_dim, d_state]`` as published).
 * **pushed leaves**, host state (engine/kvpool/) written into the tree before
   every program so the donated cache never round-trips: ``cache_index [S]``
   (where each row's call starts), ``block_table [S, pages_per_slot]``,
@@ -59,6 +61,10 @@ FORMATS: Dict[str, LayerFormat] = {
                           (CACHE_INDEX, BLOCK_TABLE)),
     "mamba": LayerFormat({}, ("conv_state", "ssm_state"),
                          (CACHE_INDEX, STATE_ROW, VALID_LEN)),
+    # the same leaves at Mamba-2's shapes (the mixer makes them): a layer's
+    # dict reads as "mamba" in layer_kind, whose format is this one
+    "mamba2": LayerFormat({}, ("conv_state", "ssm_state"),
+                          (CACHE_INDEX, STATE_ROW, VALID_LEN)),
 }
 
 _POOLS = {leaf: short for fmt in FORMATS.values()

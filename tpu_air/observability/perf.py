@@ -399,6 +399,15 @@ class LMCostModel:
     the absorbed form costs ``4*H*(r+dr)*P`` (both contractions run over the
     slab's whole width).
 
+    A layer that is one thing (``config.layer_pattern``): the counts above
+    are by kind, so a layer of experts alone has no attention and a mixer
+    layer no feed-forward.  Each of the ``M2`` Mamba-2 layers has ``D*(2c +
+    2*G*n + H) + c*D`` (``H`` heads, ``G`` groups) and keeps ``c*n*4 +
+    (k-1)*(c + 2*G*n)*b`` bytes a row.  ``ff_act`` ``relu2``: a feed-forward
+    is TWO matrices.  ``moe_latent_size = l``: a routed expert is ``2*l*F``
+    and the layer has the pair ``2*D*l`` beside it; the shared expert stays
+    at the model's width, ``shared_d_ff`` wide.
+
     Norms, rotary embeddings and softmax are omitted (≪1% of the matmul
     budget at any real geometry); the model is deliberately closed-form so
     identical claims can be recomputed anywhere (arXiv:2204.06514 §4).
@@ -422,7 +431,11 @@ class LMCostModel:
         kinds = (config.layer_kinds() if hasattr(config, "layer_kinds")
                  else ["attention"] * self.n_layers)
         self.n_mamba_layers = kinds.count("mamba")
-        self.n_attn_layers = self.n_layers - self.n_mamba_layers
+        self.n_mamba2_layers = kinds.count("mamba2")
+        self.n_attn_layers = (kinds.count("attention")
+                              + kinds.count("latent"))
+        self.mamba_n_heads = int(getattr(config, "mamba_n_heads", 0) or 0)
+        self.mamba_n_groups = int(getattr(config, "mamba_n_groups", 1) or 1)
         self.d_inner = int(getattr(config, "mamba_d_inner", 0) or 0)
         self.d_state = int(getattr(config, "mamba_d_state", 0) or 0)
         self.d_conv = int(getattr(config, "mamba_d_conv", 0) or 0)
@@ -431,12 +444,18 @@ class LMCostModel:
               else ["sparse" if self.num_experts else "dense"]
               * self.n_layers)
         self.n_sparse_layers = ff.count("sparse")
+        self.n_dense_layers = ff.count("dense")
+        self.ff_matrices = 2 if getattr(
+            config, "ff_act", "swiglu") == "relu2" else 3
+        self.moe_latent = int(getattr(config, "moe_latent_size", 0) or 0)
         self.dense_d_ff = int(getattr(config, "dense_d_ff", None)
                               or self.d_ff)
         self.experts_held = int(getattr(config, "experts_held", None)
                                 or self.num_experts)
         self.shared_experts = int(
             getattr(config, "num_shared_experts", 0) or 0)
+        self.shared_d_ff = int(getattr(config, "shared_d_ff", None)
+                               or self.shared_experts * self.d_ff)
         self.kv_lora_rank = int(getattr(config, "kv_lora_rank", 0) or 0)
         self.q_lora_rank = int(getattr(config, "q_lora_rank", 0) or 0)
         self.qk_nope = int(getattr(config, "qk_nope_head_dim", 0) or 0)
@@ -463,29 +482,44 @@ class LMCostModel:
                 + c * self.d_model)
 
     @property
+    def _mamba2_conv_dim(self) -> int:
+        return self.d_inner + 2 * self.mamba_n_groups * self.d_state
+
+    @property
+    def _mamba2_params(self) -> int:
+        c = self.d_inner
+        return (self.d_model * (c + self._mamba2_conv_dim
+                                + self.mamba_n_heads) + c * self.d_model)
+
+    @property
     def state_bytes_per_row(self) -> int:
         """Recurrent state one sequence keeps over the Mamba layers: the
         float32 state and the convolution tail in the model's dtype."""
-        return self.n_mamba_layers * self.d_inner * (
-            self.d_state * 4 + (self.d_conv - 1) * self.dtype_bytes)
+        tail = (self.d_conv - 1) * self.dtype_bytes
+        return (self.n_mamba_layers * self.d_inner * (self.d_state * 4 + tail)
+                + self.n_mamba2_layers * (self.d_inner * self.d_state * 4
+                                          + self._mamba2_conv_dim * tail))
 
     @property
     def _expert_params(self) -> int:
-        """One SwiGLU of width ``d_ff``: the dense feed-forward, or one
-        expert."""
-        return 3 * self.d_model * self.d_ff
+        """One routed expert of width ``d_ff``, in the width it works in."""
+        return self.ff_matrices * (self.moe_latent or self.d_model) \
+            * self.d_ff
 
     def _layer_params(self, experts: float) -> float:
         """Matrix parameters of the layers with ``experts`` routed experts
         counted in each sparse one (beside its router and shared expert; a
         dense layer has the one feed-forward and no router)."""
-        sparse = ((experts + self.shared_experts) * self._expert_params
+        sparse = (experts * self._expert_params
+                  + self.ff_matrices * self.d_model * self.shared_d_ff
+                  + 2 * self.d_model * self.moe_latent
                   + self.d_model * self.num_experts)
-        dense = 3 * self.d_model * self.dense_d_ff
+        dense = self.ff_matrices * self.d_model * self.dense_d_ff
         return (self.n_attn_layers * self._attn_params
                 + self.n_mamba_layers * self._mamba_params
+                + self.n_mamba2_layers * self._mamba2_params
                 + self.n_sparse_layers * sparse
-                + (self.n_layers - self.n_sparse_layers) * dense)
+                + self.n_dense_layers * dense)
 
     @property
     def matmul_params(self) -> int:
@@ -536,7 +570,8 @@ class LMCostModel:
     def linear_flops_per_token(self) -> float:
         return (2.0 * (self.active_matmul_params
                        + self.d_model * self.vocab_size)
-                + self.n_mamba_layers * 7.0 * self.d_inner * self.d_state)
+                + (self.n_mamba_layers + self.n_mamba2_layers) * 7.0
+                * self.d_inner * self.d_state)
 
     @property
     def kv_bytes_per_position(self) -> float:

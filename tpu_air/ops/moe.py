@@ -1,8 +1,9 @@
 """The routed expert product of a sparse-expert feed-forward layer.
 
 ``expert_ffn`` computes, for every token ``t`` and each of its ``k`` chosen
-experts ``e``, ``w[t, e] * down_e(silu(gate_e x[t]) * up_e x[t])`` and sums
-over the ``k``: exactly the published sum.  There is no capacity and no
+experts ``e``, ``w[t, e] * down_e(silu(gate_e x[t]) * up_e x[t])`` (without a
+gate matrix: ``w[t, e] * down_e(relu(up_e x[t])^2)``, the two-matrix expert
+``nemotron_h`` publishes) and sums over the ``k``: exactly the published sum.  There is no capacity and no
 fixed group size, so no assignment is dropped or re-routed however uneven
 the load.  Inputs in the model's dtype, accumulation in float32.
 
@@ -21,6 +22,8 @@ that were measured against it on the v5e are in PERF.md (PR 27).
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,13 +44,24 @@ GMM_TILING = (128, 2048, 1024)
 # VMEM.  The down product (2048 x 7168) keeps GMM_TILING, the fastest of ten
 # there (0.64 to 0.71 ms).  PERF.md, PR 43.
 GMM_TILING_K1024 = (128, 1024, 2048)
+# where neither divides them (a side of 2688 = 21 x 128, which 1024 and 2048
+# columns do not divide): the WHOLE matrix a tile, so 128 x 1024 x 2688 for
+# nemotron_h's up product and 128 x 2688 x 1024 for its down product (5.5 MB
+# a tile: two buffers of it fit VMEM beside the rows).  The fastest of six a
+# product tried on the v5e (PERF.md, PR 47) at 128 groups of 1024 x 2688 and
+# of 2688 x 1024, 704 to 2112 held rows among 2816 to 8448 sorted: 1.05 to
+# 1.10 ms (up) and 1.04 to 1.07 ms (down) where the 705 MB of weights take
+# 0.86 at the HBM peak; 896 of the 2688 a tile reads 1.07 to 1.13 (up) and
+# 1.18 to 1.23 (down), 384 of them 1.16 to 1.27, 256 rows a tile 1.15 to
+# 1.36, a tile of 1344 does not lower, and ``ragged_dot`` 2.9 to 5.4.
+GMM_TILING_WHOLE = (128, 2688, 2688)
 
 
 def gmm_tiling(m: int, kdim: int, n: int):
     """The kernel tile for ``[m, kdim] x [g, kdim, n]``: the first of the
     measured tilings whose every side divides the shapes and is whole lanes,
     or None (no kernel: the shapes go to ``ragged_dot``)."""
-    for tiling in (GMM_TILING, GMM_TILING_K1024):
+    for tiling in (GMM_TILING, GMM_TILING_K1024, GMM_TILING_WHOLE):
         tm, tk, tn = tiling[0], min(tiling[1], kdim), min(tiling[2], n)
         if (m % tm == 0 and kdim % tk == 0 and n % tn == 0
                 and tk % 128 == 0 and tn % 128 == 0):
@@ -70,22 +84,28 @@ def _grouped(lhs: Array, rhs: Array, sizes: Array) -> Array:
                               preferred_element_type=jnp.float32)
 
 
-def expert_ffn(x: Array, chosen: Array, weights: Array, gate: Array,
-               up: Array, down: Array, partial: bool = False) -> Array:
+def expert_ffn(x: Array, chosen: Array, weights: Array,
+               gate: Optional[Array], up: Array, down: Array,
+               partial: bool = False) -> Array:
     """``x [t, d]``; ``chosen [t, k]`` int expert ids; ``weights [t, k]``
     float32; ``gate``/``up`` ``[e, d, f]``, ``down [e, f, d]`` -> ``[t, d]``
-    float32.  ``partial``: ``chosen`` may hold the id ``e``, an expert held
-    elsewhere (module doc)."""
+    float32.  ``gate`` None: two-matrix experts around a squared ReLU.
+    ``partial``: ``chosen`` may hold the id ``e``, an expert held elsewhere
+    (module doc)."""
     t, k = chosen.shape
-    e = gate.shape[0]
+    e = up.shape[0]
     with jax.named_scope("moe_sort"):
         flat = chosen.reshape(-1)
         order = jnp.argsort(flat, stable=True)       # assignments by expert
         sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
         xs = x[order // k]                           # [t*k, d]
     with jax.named_scope("moe_experts"):
-        h = (jax.nn.silu(_grouped(xs, gate, sizes))
-             * _grouped(xs, up, sizes)).astype(x.dtype)
+        if gate is None:
+            h = jnp.square(jax.nn.relu(_grouped(xs, up, sizes))).astype(
+                x.dtype)
+        else:
+            h = (jax.nn.silu(_grouped(xs, gate, sizes))
+                 * _grouped(xs, up, sizes)).astype(x.dtype)
         y = _grouped(h, down, sizes)
     with jax.named_scope("moe_combine"):
         y = y * weights.reshape(-1)[order][:, None]

@@ -1,6 +1,7 @@
-"""The Mamba-1 selective state-space recurrence, in the two shapes a serving
-engine needs it: a CHUNK of positions from a carried state (prefill, and the
-whole-sequence forward) and ONE token over every slot (decode).
+"""The selective state-space recurrences (Mamba-1, and Mamba-2 at the end of
+the file), each in the two shapes a serving engine needs it: a CHUNK of
+positions from a carried state (prefill, and the whole-sequence forward) and
+ONE token over every slot (decode).
 
 Per channel ``c`` and state ``n``, with ``A = -exp(A_log)``::
 
@@ -22,6 +23,24 @@ the input state BIT FOR BIT (a ``where``, not a multiplication by one).
 
 Everything is float32 whatever the model computes in: the state integrates
 hundreds of positions.  Plain ``jax.lax``; there is one path.
+
+MAMBA-2 (``ssd_chunk``, ``ssd_state_update``).  A head ``h`` of ``P``
+channels has ONE scalar decay a position, and the heads of a group ``g``
+share ``B`` and ``C``; with ``A[h] < 0``::
+
+    S_t[h] = exp(dt_t[h] A[h]) S_{t-1}[h] + dt_t[h] * u_t[h] (x) B_t[g(h)]     [P, N]
+    y_t[h] = S_t[h] C_t[g(h)] + D[h] u_t[h]
+
+The state is held as published, ``[rows, H, P, N]``: ``N`` (128) minor is
+whole lanes and ``P`` (64) whole sublanes, so no state-major turn is needed.
+A scalar decay a head is what allows the BLOCK form (state-space duality): in
+a block of ``Q`` positions ``y = (C B^T (.) L) (dt u) + exp(cum a) C S_in``
+with ``L[t, s] = exp(sum of a over s+1..t)`` for ``s <= t``, three matrix
+products a head instead of ``Q`` dependent steps over a 4 MB state, and the
+state leaves the block as ``exp(cum a_Q) S_in + sum_s exp(cum a_Q - cum a_s)
+dt_s u_s (x) B_s``.  Padding is ``dt = 0`` there: decay 1 and no drive, so a
+block of padding alone multiplies the state by 1.0 and adds 0.0, bit for bit
+what came in.
 """
 
 from __future__ import annotations
@@ -111,3 +130,84 @@ def selective_state_update(u, dt, A, B, C, D, state, live):
            + (dt * u)[:, None, :] * B.astype(f32)[:, :, None])
     y = jnp.einsum("bn,bnc->bc", C.astype(f32), new) + D.astype(f32) * u
     return y, jnp.where(live[:, None, None], new, state)
+
+
+def ssd_chunk(u, dt, A, B, C, D, state, valid_len, block: int = 128):
+    """``l`` positions of the Mamba-2 recurrence from a carried state, in the
+    block form (module doc).
+
+    ``u [b, l, H, P]``; ``dt [b, l, H]`` (after softplus); ``A [H]``
+    (negative); ``B``, ``C`` ``[b, l, G, N]`` (head ``h`` reads group ``h //
+    (H / G)``); ``D [H]``; ``state [b, H, P, N]`` float32; ``valid_len [b]``.
+    Returns ``(y [b, l, H, P] float32, state')``.  Positions at or past
+    ``valid_len`` leave the state untouched (their ``y`` is don't-care).
+    ``l`` is padded to whole blocks of ``block`` positions; the blocks are a
+    ``lax.scan`` that carries the state.  Every product is float32 at the
+    highest precision: the state sums hundreds of positions."""
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    b, l, H, P = u.shape
+    G, N = B.shape[2], B.shape[3]
+    k = H // G
+    q = min(block, l)
+    nb = -(-l // q)
+    pad = nb * q - l
+    real = jnp.arange(l)[None, :] < valid_len.astype(jnp.int32)[:, None]
+    dt = jnp.where(real[:, :, None], dt.astype(f32), 0.0)
+
+    def blocks(x):
+        x = jnp.pad(x.astype(f32), ((0, 0), (0, pad)) + ((0, 0),) *
+                    (x.ndim - 2))
+        return jnp.swapaxes(x.reshape((b, nb, q) + x.shape[2:]), 0, 1)
+
+    tri = jnp.tril(jnp.ones((q, q), bool))
+
+    def one(s, xs):
+        u_, dt_, B_, C_ = xs                       # [b, q, ..]
+        a = dt_ * A.astype(f32)                    # [b, q, H], <= 0
+        cum = jnp.cumsum(a, axis=1)
+        # L[t, s] = exp(cum_t - cum_s) where s <= t (<= 1: no overflow)
+        diff = cum[:, :, None, :] - cum[:, None, :, :]       # [b, t, s, H]
+        decay = jnp.exp(jnp.where(tri[None, :, :, None], diff, -jnp.inf))
+        cb = jnp.einsum("btgn,bsgn->btsg", C_, B_, precision=hi)
+        m = decay.reshape(b, q, q, G, k) * cb[..., None]     # [b,t,s,G,k]
+        x = (dt_[..., None] * u_).reshape(b, q, G, k, P)     # dt u
+        y = jnp.einsum("btsgk,bsgkp->btgkp", m, x, precision=hi)
+        s5 = s.reshape(b, G, k, P, N)
+        y = y + jnp.exp(cum).reshape(b, q, G, k)[..., None] * jnp.einsum(
+            "btgn,bgkpn->btgkp", C_, s5, precision=hi)
+        last = cum[:, -1]                                    # [b, H]
+        w = jnp.exp(last[:, None] - cum).reshape(b, q, G, k)
+        new = (jnp.exp(last).reshape(b, G, k)[..., None, None] * s5
+               + jnp.einsum("bsgkp,bsgn->bgkpn", w[..., None] * x, B_,
+                            precision=hi))
+        return new.reshape(b, H, P, N), y.reshape(b, q, H, P)
+
+    state, ys = jax.lax.scan(one, state.astype(f32),
+                             (blocks(u), blocks(dt), blocks(B), blocks(C)))
+    y = jnp.swapaxes(ys, 0, 1).reshape(b, nb * q, H, P)[:, :l]
+    return y + D.astype(f32)[:, None] * u.astype(f32), state
+
+
+def ssd_state_update(u, dt, A, B, C, D, state, live):
+    """One token of the Mamba-2 recurrence over every row.
+
+    ``u [b, H, P]``; ``dt [b, H]``; ``A [H]``; ``B``, ``C`` ``[b, G, N]``;
+    ``D [H]``; ``state [b, H, P, N]`` float32; ``live [b]`` bool.  Returns
+    ``(y [b, H, P] float32, state')``; a row with ``live`` false keeps its
+    state bit for bit (its ``y`` is don't-care).  One elementwise pass over
+    the state: read once, written once, ``y`` reduced over the lanes in the
+    same pass."""
+    f32 = jnp.float32
+    b, H, P = u.shape
+    G, N = B.shape[1], B.shape[2]
+    k = H // G
+    u, dt = u.astype(f32), dt.astype(f32)
+    s5 = state.astype(f32).reshape(b, G, k, P, N)
+    decay = jnp.exp(dt * A.astype(f32)).reshape(b, G, k, 1, 1)
+    drive = (dt[..., None] * u).reshape(b, G, k, P, 1)
+    new = decay * s5 + drive * B.astype(f32)[:, :, None, None, :]
+    y = (new * C.astype(f32)[:, :, None, None, :]).sum(-1).reshape(b, H, P)
+    new = new.reshape(b, H, P, N)
+    return (y + D.astype(f32)[:, None] * u,
+            jnp.where(live[:, None, None, None], new, state))
