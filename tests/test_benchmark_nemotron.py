@@ -58,10 +58,18 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
                  "engine_host_ms_p50", "ssm_state_share"):
         assert name not in layer, name
     # the new metrics are this cell's alone, and at the end of the list
-    assert [m["name"] for m in bench.doc["per_layer"][-6:]] == NEW
+    assert [m["name"] for m in bench.doc["per_layer"][-7:-1]] == NEW
     assert all(m["workloads"] == [CELL] and m["unit"] == "%"
                and m["source"] == "device_trace"
-               for m in bench.doc["per_layer"][-6:])
+               for m in bench.doc["per_layer"][-7:-1])
+    # PR 48: how much of the state the pass moves is the live rows'
+    passed = layer["ssd_state_pass_live_share"]
+    assert bench.doc["per_layer"][-1]["name"] == passed["name"]
+    assert (passed["workloads"], passed["source"], passed["layer"]) == (
+        [CELL], "program_span", "kernels")
+    assert (passed["reader"], passed["args"]) == (
+        "span_stat_ratio",
+        {"name": "engine.step", "num": "live", "den": "state_rows"})
     assert [w["name"] for w in bench.doc["workloads"][:8]] == [
         "t5base-finetune", "t5base-finetune-dp4", "t5base-batchgen",
         "t5large-serve", "t5large-batchgen", "olmoe-serve-decode",

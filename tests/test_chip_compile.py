@@ -746,6 +746,9 @@ def test_mamba2_state_pool_stays_in_place_at_full_depth_on_v5e(
     comes back aliased, nothing the size of the ``[128, 128, 64, 128]``
     float32 state pool (537 MB a layer) is held or copied beside it, the ten
     expert products are the grouped-matmul kernel with the whole-matrix tile,
+    a step's five passes over the state are the in-place kernel of the live
+    rows (``ops/ssm.ssd_rows_update``, PR 48: the pool aliased through it, in
+    the mixed step too, where the chunk's row is read from what it left),
     and the program fits the chip beside its 12.6 GB of arguments."""
     import json
     import os
@@ -784,4 +787,11 @@ def test_mamba2_state_pool_stays_in_place_at_full_depth_on_v5e(
             < 15.75 * 2 ** 30)
     text = compiled.as_text()
     assert not re.search(r"= f32\[128,128,64,128\]\S* copy\(", text)
-    assert text.count("tpu_custom_call") == 10
+    # ten expert products; a step's five passes (the chunk program has none)
+    assert text.count("tpu_custom_call") == (10 if program == "chunk" else 15)
+    assert ("ssd_rows_update" in text) == (program != "chunk")
+    # a kernel call that asks for more than the default scoped fast memory
+    # changes how the compiler tiles OTHER fusions: with 16 MB asked the
+    # attention's softmax got a window its cost model could not price (this
+    # number) and ran eleven times as long on the chip (PERF.md, PR 48)
+    assert '"estimated_cycles":"9223372036854775807"' not in text

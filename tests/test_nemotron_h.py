@@ -14,7 +14,8 @@ import pytest
 
 from tpu_air.models.lm import hf_import, reference_nemotron_h as reference
 from tpu_air.models.lm.config import LMConfig
-from tpu_air.models.lm.modeling import CausalLM, grouped_sigmoid_routing
+from tpu_air.models.lm.modeling import (CausalLM, Mamba2Mixer,
+                                        grouped_sigmoid_routing)
 from tpu_air.ops import moe, ssm
 
 import _mixed_step_cases
@@ -414,6 +415,79 @@ def test_ssd_padding_and_held_rows_keep_the_state_bit_for_bit():
                                rtol=1e-5, atol=1e-5)
 
 
+_LIVE_PATTERNS = {
+    "all": [1, 1, 1, 1, 1, 1],
+    "none": [0, 0, 0, 0, 0, 0],
+    "first": [1, 0, 0, 0, 0, 0],
+    "last": [0, 0, 0, 0, 0, 1],
+    "alternating": [1, 0, 1, 0, 1, 0],
+    "prefix": [1, 1, 1, 0, 0, 0],
+    "suffix": [0, 0, 0, 1, 1, 1],
+}
+
+
+@pytest.mark.parametrize("pattern", sorted(_LIVE_PATTERNS))
+def test_ssd_rows_update_moves_the_live_rows_alone(pattern):
+    """The in-place kernel (interpret mode) against the ``jnp`` form and the
+    plain recurrence: two groups of four heads, a tile of two heads (so a
+    row is four grid steps and a group two tiles).  A row that is not live is
+    the input bit for bit and its ``y`` zeros; with none live the whole pool
+    comes back bit for bit."""
+    live = np.asarray(_LIVE_PATTERNS[pattern], bool)
+    rng = np.random.default_rng(sum(map(ord, pattern)))
+    u, dt, A, B, C, D, state = _ssd_inputs(rng, len(live), 1, H=8, P=8, G=2,
+                                           N=128)
+    args = (u[:, 0], dt[:, 0], A, B[:, 0], C[:, 0], D, state)
+    y, new = jax.jit(lambda *a: ssm.ssd_rows_update(*a, head_tile=2))(
+        *args, jnp.asarray(live))
+    y, new = np.asarray(y), np.asarray(new)
+    np.testing.assert_array_equal(new[~live], np.asarray(state)[~live])
+    np.testing.assert_array_equal(y[~live], 0.0)
+    y_jnp, new_jnp = ssm.ssd_state_update(*args, jnp.asarray(live))
+    want_y, want = _plain_recurrence(u, dt, A, B, C, D, state,
+                                     live.astype(int))
+    for got, ref in ((new, np.asarray(new_jnp)), (new, want)):
+        np.testing.assert_allclose(got[live], ref[live], rtol=1e-5, atol=1e-6)
+    for ref in (np.asarray(y_jnp), want_y[:, 0]):
+        np.testing.assert_allclose(y[live], ref[live], rtol=1e-5, atol=1e-5)
+
+
+def test_the_rule_that_moves_state_rows_in_place(monkeypatch):
+    """``state_rows_move_in_place`` is decided from backend, mesh, dtype and
+    tile shape: no on a CPU, under a ``kernel_mesh``, for a bf16 state, for a
+    state that is not whole tiles (or Mamba-1's three dimensions); and where
+    it says no ``Mamba2Mixer`` runs the ``jnp`` form."""
+    from tpu_air.ops.flash_attention import kernel_mesh
+
+    pool = lambda P=64, N=128, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        (4, 8, P, N), dtype)
+    assert not ssm.state_rows_move_in_place(pool())              # a CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ssm.state_rows_move_in_place(pool())
+    assert ssm.state_rows_move_in_place(pool(8, 256))
+    assert not ssm.state_rows_move_in_place(pool(dtype=jnp.bfloat16))
+    assert not ssm.state_rows_move_in_place(pool(N=64))
+    assert not ssm.state_rows_move_in_place(pool(P=4))
+    assert not ssm.state_rows_move_in_place(
+        jax.ShapeDtypeStruct((4, 16, 5120), jnp.float32))
+    with kernel_mesh(object()):
+        assert not ssm.state_rows_move_in_place(pool())
+    assert ssm.state_rows_move_in_place(pool())
+    monkeypatch.undo()
+
+    # the mixer asks the rule: on this CPU no kernel call is traced
+    called = []
+    monkeypatch.setattr(ssm, "ssd_rows_update",
+                        lambda *a, **k: called.append(a))
+    cfg = hf_import.lm_config_from_hf(TINY, max_seq_len=256)
+    mixer = Mamba2Mixer(cfg)
+    x = jnp.zeros((2, 1, cfg.d_model), jnp.dtype(cfg.dtype))
+    variables = mixer.init(jax.random.PRNGKey(0), x, decode=True)
+    text = jax.jit(lambda v, x: mixer.apply(
+        v, x, decode=True, mutable=["cache"])).lower(variables, x).as_text()
+    assert not called and "ssd_rows_update" not in text
+
+
 # -- the engine: chunked prefill, then paged decode, the state a slot --------
 
 def _engine(tiny, **kw):
@@ -535,6 +609,53 @@ def test_mixed_step(tiny, case):
         assert np.asarray(want)[0].tolist() == tokens
 
     _mixed_step_cases.CASES[case](model, params, check)
+
+
+@pytest.mark.parametrize("in_place", [False, True])
+def test_the_engine_counts_the_rows_whose_state_a_step_moves(
+        tiny, monkeypatch, in_place):
+    """``stats()["ssd_state_rows_passed"]`` and ``engine.step``'s
+    ``state_rows``: every slot a step where the pass is the ``jnp`` form's
+    (this CPU), the rows the step advances where the rule says the state
+    moves in place (patched to yes: the kernel in interpret mode, through
+    the engine's decode and mixed programs; the tokens are still offline
+    ``generate``'s)."""
+    import contextlib
+
+    from tpu_air.engine import engine as engine_module
+    from tpu_air.models.lm.generate import generate
+
+    if in_place:
+        monkeypatch.setattr(ssm, "state_rows_move_in_place",
+                            lambda state: True)
+    steps = []
+
+    def phase(name, **counts):
+        if name == "engine.step":
+            steps.append(counts)
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(engine_module, "phase", phase)
+    _, config, model, params = tiny
+    rng = np.random.default_rng(16)
+    prompts = [rng.integers(2, 384, k).tolist() for k in (21, 40, 5)]
+    eng = _engine(tiny)
+    got = eng.generate(prompts, 8)
+    snap = eng.metrics.snapshot()
+    eng.close()
+    assert snap["ssd_rows_live"] > 0 and snap["mixed_steps"] > 0
+    if in_place:
+        assert snap["ssd_state_rows_passed"] == snap["ssd_rows_live"]
+    else:
+        assert (snap["ssd_state_rows_passed"]
+                == eng.config.num_slots * snap["steps_issued"])
+        assert snap["ssd_state_rows_passed"] > snap["ssd_rows_live"]
+    issued = [s for s in steps if "state_rows" in s]
+    assert len(issued) == snap["steps_issued"]
+    assert sum(s["state_rows"] for s in issued) == snap["ssd_state_rows_passed"]
+    for p, g in zip(prompts, got):
+        want = generate(model, params, np.asarray([p]), max_new_tokens=8)
+        assert np.asarray(want)[0].tolist() == g
 
 
 def test_rows_beside_pages_prefix_sharing_off_and_moves_refused(tiny):

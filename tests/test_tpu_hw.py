@@ -270,3 +270,113 @@ def test_ring_attention_step_on_chip():
     """One compiled ring-attention step executes on the chip."""
     out = _run_on_tpu(_RING_SCRIPT)
     assert "RING_TPU_OK" in out
+
+
+# The rehearsal off the chip (``python -c`` of this text with JAX_PLATFORMS=cpu)
+# patches the rule to yes and interprets the kernel at a smaller pool.
+_SSD_ROWS_SCRIPT = r"""
+import numpy as np
+import jax, jax.numpy as jnp
+from tpu_air.ops import ssm
+ON_CHIP = jax.devices()[0].platform == "tpu"
+if not ON_CHIP:                       # the rehearsal: the kernel interpreted
+    ssm.state_rows_move_in_place = lambda state: True
+from tpu_air.engine import EngineConfig, InferenceEngine
+from tpu_air.models.lm import hf_import, paged_cache
+from tpu_air.models.lm.modeling import CausalLM
+
+# 1. the kernel alone, at the cell's head geometry: rows that are not live
+# come back bit for bit (with none live the whole pool) and their y is
+# zeros; live rows are the recurrence's, in float64 on the host
+S, H, P, G, N = (16, 128, 64, 8, 128) if ON_CHIP else (6, 16, 8, 2, 128)
+ks = jax.random.split(jax.random.PRNGKey(48), 7)
+u = jax.random.normal(ks[0], (S, H, P), jnp.float32)
+dt = jax.random.uniform(ks[1], (S, H), jnp.float32, 1e-3, 0.5)
+A = -jax.random.uniform(ks[2], (H,), jnp.float32, 1.0, 16.0)
+B, C = (jax.random.normal(k, (S, G, N), jnp.float32) for k in ks[3:5])
+D = jax.random.normal(ks[5], (H,), jnp.float32)
+state = jax.random.normal(ks[6], (S, H, P, N), jnp.float32)
+assert ssm.state_rows_move_in_place(state)
+u8, dt8, A8, B8, C8, D8, s8 = (np.asarray(a, np.float64)
+                               for a in (u, dt, A, B, C, D, state))
+Bh, Ch = np.repeat(B8, H // G, 1), np.repeat(C8, H // G, 1)
+want = (np.exp(dt8 * A8)[..., None, None] * s8
+        + (dt8[..., None] * u8)[..., None] * Bh[:, :, None, :])
+want_y = (want * Ch[:, :, None, :]).sum(-1) + D8[:, None] * u8
+step = jax.jit(ssm.ssd_state_update)
+for name, live in (("none", np.zeros(S, bool)), ("one", np.arange(S) == S - 1),
+                   ("some", np.arange(S) % 3 != 1), ("all", np.ones(S, bool))):
+    y, new = (np.asarray(a) for a in step(u, dt, A, B, C, D, state,
+                                          jnp.asarray(live)))
+    np.testing.assert_array_equal(new[~live], np.asarray(state)[~live])
+    np.testing.assert_array_equal(y[~live], 0.0)
+    # the chip's float32 exp against the host's float64 one
+    np.testing.assert_allclose(new[live], want[live], rtol=2e-5, atol=1e-5)
+    np.testing.assert_allclose(y[live], want_y[live], rtol=1e-4, atol=1e-4)
+    print("kernel", name, "ok", flush=True)
+
+# 2. through the engine: a model whose Mamba-2 state is whole tiles, a slot
+# whose tenant has left (its state stays behind) beside one that decodes; the
+# pool read back before and after a step
+cfg = hf_import.lm_config_from_hf({
+    "model_type": "nemotron_h", "hidden_size": 128, "num_hidden_layers": 4,
+    "hybrid_override_pattern": "M*ME", "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 32, "intermediate_size": 128,
+    "moe_intermediate_size": 128, "moe_latent_size": 128,
+    "moe_shared_expert_intermediate_size": 128, "n_routed_experts": 4,
+    "n_shared_experts": 1, "num_experts_per_tok": 2, "n_group": 1,
+    "topk_group": 1, "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+    "mamba_num_heads": 16, "mamba_head_dim": 8, "n_groups": 2,
+    "ssm_state_size": 128, "conv_kernel": 4, "chunk_size": 16,
+    "layer_norm_epsilon": 1e-05, "vocab_size": 512,
+    "max_position_embeddings": 512, "tie_word_embeddings": False,
+    "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
+    "use_conv_bias": True, "use_bias": False, "mlp_bias": False,
+    "attention_bias": False, "mamba_proj_bias": False}, max_seq_len=256)
+model = CausalLM(cfg)
+params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+eng = InferenceEngine(model, params, EngineConfig(
+    num_slots=4, slot_len=256, page_len=16, max_new_tokens=24,
+    eos_token_id=None), auto_start=False)
+assert paged_cache.state_rows_move_in_place(eng.cache)
+
+
+def states():
+    return [np.asarray(layer["ssm_state"]) for _, layer in
+            paged_cache.layers(eng.cache) if "ssm_state" in layer]
+
+
+rng = np.random.default_rng(48)
+short = eng.submit(rng.integers(2, 512, 20).tolist(), 3)
+long = eng.submit(rng.integers(2, 512, 37).tolist(), 24)
+while not short.done:
+    eng.step()
+held_and_advanced = 0
+for _ in range(6):
+    riding_before, before = set(eng._riding), states()
+    eng.step()
+    moved_by = riding_before | set(eng._riding)
+    after = states()
+    for a, b in zip(before, after):
+        changed = {i for i in range(4) if not np.array_equal(a[i], b[i])}
+        assert changed <= moved_by, (changed, moved_by)
+        stale = [i for i in range(4) if i not in moved_by and a[i].any()]
+        held_and_advanced += bool(stale) and bool(changed)
+assert held_and_advanced, "no step held a slot's stale state beside a live row"
+while not eng.idle():
+    eng.step()
+snap = eng.metrics.snapshot()
+assert snap["ssd_state_rows_passed"] == snap["ssd_rows_live"] > 0, snap
+eng.close()
+print("engine ok: steps that held a stale row beside a live one:",
+      held_and_advanced, "rows passed", snap["ssd_state_rows_passed"])
+"""
+
+
+def test_ssd_rows_update_holds_the_rows_it_does_not_advance_on_chip():
+    """PR 48: ``ops/ssm.ssd_rows_update`` compiled and run: a pool read back
+    before and after the pass (the kernel alone at the cell's head geometry,
+    then an engine's step with a slot's stale state beside a decoding row):
+    a row that is not live is bit for bit what it was."""
+    out = _run_on_tpu(_SSD_ROWS_SCRIPT)
+    assert "engine ok" in out and out.count("kernel") == 4, out

@@ -1,14 +1,21 @@
 """Did an edit leave the engine's compiled programs alone?  Prints the sha256
 of the lowered text (``jax.jit(body).lower(...).as_text()``, on the CPU, shapes
 only) of every ``InferenceEngine`` program (decode, chunk, mixed) of the tests'
-tiny dense, OLMoE, Jamba and latent-attention models: run it from the root of
-two trees and diff the output.
+tiny dense, OLMoE, Jamba, latent-attention and Nemotron-H models: run it from
+the root of two trees and diff the output.
 
     JAX_PLATFORMS=cpu python tools/lowered_programs.py > /tmp/change.txt
     (cd <parent tree> && JAX_PLATFORMS=cpu python <this file> > /tmp/parent.txt)
 
 A program whose line is the same in both runs the same operations in both
-(PERF.md, PRs 44 and 45: the cells whose model a change does not name)."""
+(PERF.md, PRs 44 and 45: the cells whose model a change does not name).
+
+``--as-tpu`` answers for the programs a TPU traces: ``jax.default_backend`` is
+patched to say so, so the trace-time rules (``latent_pages_read_in_place``,
+``state_rows_move_in_place``) pick what they pick on the chip, and the hash is
+of the traced jaxpr (a Mosaic kernel does not lower for the CPU).  The
+Nemotron-H model is there at a state of whole tiles, which the second rule
+wants (PR 48)."""
 
 import hashlib
 import os
@@ -22,18 +29,25 @@ import jax.numpy as jnp  # noqa: E402
 
 import test_gigachat  # noqa: E402
 import test_jamba  # noqa: E402
+import test_nemotron_h  # noqa: E402
 import test_olmoe  # noqa: E402
 from tpu_air.models.lm import CausalLM, LMConfig, hf_import  # noqa: E402
 from tpu_air.models.lm.generate import (  # noqa: E402
     init_paged_cache, make_paged_decode_body, make_paged_mixed_body,
     make_prefill_chunk_body)
 
+AS_TPU = "--as-tpu" in sys.argv[1:]
+if AS_TPU:
+    jax.default_backend = lambda: "tpu"
 S, C, L = 4, 8, 64
 npg = L // C
+# a Mamba-2 state [heads, 8, 128]: whole float32 tiles
+nemotron = {**test_nemotron_h.TINY, "ssm_state_size": 128} if AS_TPU else test_nemotron_h.TINY
 cfgs = {"dense": LMConfig.tiny(),
         "olmoe": hf_import.lm_config_from_hf(test_olmoe.HF),
         "jamba": hf_import.lm_config_from_hf(test_jamba.TINY, max_seq_len=256),
-        "gigachat": hf_import.lm_config_from_hf(test_gigachat.TINY, max_seq_len=256, experts_first=4, experts_held=8)}
+        "gigachat": hf_import.lm_config_from_hf(test_gigachat.TINY, max_seq_len=256, experts_first=4, experts_held=8),
+        "nemotron": hf_import.lm_config_from_hf(nemotron, max_seq_len=256, experts_first=2, experts_held=4)}
 for name, cfg in cfgs.items():
     model = CausalLM(cfg)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
@@ -46,5 +60,8 @@ for name, cfg in cfgs.items():
         "mixed": (make_paged_mixed_body(model, C, L), (params, cache, i32(S), i32(S), i32(S, npg), i32(1, C), i32(), i32(), i32(npg)), slot),
     }
     for pn, (body, args, kw) in progs.items():
-        text = jax.jit(body, donate_argnums=(1,)).lower(*args, **kw).as_text()
+        if AS_TPU:
+            text = str(jax.make_jaxpr(lambda a, k: body(*a, **k))(args, kw))
+        else:
+            text = jax.jit(body, donate_argnums=(1,)).lower(*args, **kw).as_text()
         print(name, pn, len(text), hashlib.sha256(text.encode()).hexdigest()[:16])

@@ -138,7 +138,8 @@ from tpu_air.models.lm.generate import (
     make_lm_step_feed_fns,
     make_page_copy_fn,
 )
-from tpu_air.models.lm.paged_cache import recurrent_state_bytes
+from tpu_air.models.lm.paged_cache import (recurrent_state_bytes,
+                                            state_rows_move_in_place)
 
 from tpu_air.faults import plan as _faults
 from tpu_air.observability import tracing as _tracing
@@ -325,6 +326,9 @@ class InferenceEngine:
             cfg.pages_per_slot(),
         )
         self._state_bytes = recurrent_state_bytes(self.cache)
+        # does a step's pass move the state of the rows it advances alone
+        # (asked once: the rule reads what does not change under an engine)
+        self._state_in_place = state_rows_move_in_place(self.cache)
         self._decode_step = make_lm_paged_decode_step_fn(
             self.model, cfg.slot_len, adapters=self.adapters_enabled)
         self._chunk_fn = make_lm_prefill_chunk_fn(
@@ -1227,9 +1231,12 @@ class InferenceEngine:
         if unread is None and not rows and not self._firsts:
             return False
         ahead = bool(rows) and unread is not None
+        # the rows whose recurrent state the issued program's pass moves
+        passed = ({"state_rows": self._state_rows_passed(len(rows))}
+                  if self._recurrent and rows else {})
         with phase("engine.step", live=len(reading if unread else rows),
                    batch=self.config.num_slots, ahead=int(ahead),
-                   chunk=int(chunk is not None)):
+                   chunk=int(chunk is not None), **passed):
             if rows:
                 # out before step N is read: the device runs it while the
                 # host reads, emits, retires and admits
@@ -1252,6 +1259,12 @@ class InferenceEngine:
         changes slot state from outside the loop calls this first (under
         ``_step_lock``)."""
         self._token_step(issue=False)
+
+    def _state_rows_passed(self, issued: int) -> int:
+        """The rows whose per-slot state a step that advances ``issued`` rows
+        reads and writes: those rows where the pass moves the live rows
+        alone, every slot's where it passes over the pool."""
+        return issued if self._state_in_place else self.config.num_slots
 
     def _drop_step(self) -> None:
         if self._inflight is not None:
@@ -1318,7 +1331,8 @@ class InferenceEngine:
             # others' state the step advances, over the positions they hold
             self.metrics.record_rows_held(
                 self.config.num_slots - len(rows), live=len(rows),
-                positions=sum(s.pos + 1 for s in rows))
+                positions=sum(s.pos + 1 for s in rows),
+                passed=self._state_rows_passed(len(rows)))
         if not ahead:
             self._mark = time.monotonic()
 

@@ -145,6 +145,10 @@ class EngineMetrics:
         # layers read): the bytes a step of such a model must move
         self.ssd_rows_live = 0
         self.ssd_positions_live = 0
+        # ... and rows x steps whose state the steps' pass MOVED: the live
+        # rows where it moves those alone (ops/ssm.py), every slot where it
+        # passes over the pool
+        self.ssd_state_rows_passed = 0
         self.prefix_cache_disabled_by_model = False
         register(self)
 
@@ -311,11 +315,12 @@ class EngineMetrics:
             self.ssm_state_resets += 1
 
     def record_rows_held(self, rows: int, live: int = 0,
-                         positions: int = 0) -> None:
+                         positions: int = 0, passed: int = 0) -> None:
         with self._lock:
             self.ssm_rows_held += rows
             self.ssd_rows_live += live
             self.ssd_positions_live += positions
+            self.ssd_state_rows_passed += passed
 
     def record_dropped_step(self) -> None:
         """An issued step nobody read: its window closed (every live row
@@ -486,6 +491,7 @@ class EngineMetrics:
                 out["ssm_rows_held"] = self.ssm_rows_held
                 out["ssd_rows_live"] = self.ssd_rows_live
                 out["ssd_positions_live"] = self.ssd_positions_live
+                out["ssd_state_rows_passed"] = self.ssd_state_rows_passed
                 out["prefix_cache_disabled_by_model"] = (
                     self.prefix_cache_disabled_by_model)
             if self.steps_issued:

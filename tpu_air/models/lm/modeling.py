@@ -1071,8 +1071,9 @@ class Mamba2Mixer(nn.Module):
     ``ssm_state [S, H, P, N]``, as published (``ops/ssm.py``: whole tiles).
     The callers are :class:`MambaMixer`'s three, told apart the same way; a
     chunk runs the block form (``ssm.ssd_chunk``, blocks of
-    ``config.mamba_chunk_size``), a decode step the one-token update over all
-    rows (``ssm.ssd_state_update``).
+    ``config.mamba_chunk_size``), a decode step the one-token update
+    (``ssm.ssd_state_update``: over all rows, or on a TPU over the live rows'
+    state alone, in place; the rule is ``ssm.state_rows_move_in_place``).
 
     Scopes (docs/OBSERVABILITY.md): ``ssd_conv`` the convolution,
     ``ssd_scan`` a chunk's block form, ``ssd_state_update`` the step's pass

@@ -37,6 +37,8 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from tpu_air.ops import ssm
+
 from .config import LMConfig
 
 CACHE_INDEX = "cache_index"
@@ -172,6 +174,17 @@ def recurrent_state_bytes(cache) -> int:
     """Bytes of per-slot state the cache holds that is not pages."""
     return sum(v.size * v.dtype.itemsize for _, layer in layers(cache)
                for k, v in layer.items() if k in _ROWS)
+
+
+def state_rows_move_in_place(cache) -> bool:
+    """Does a decode step's pass over the per-slot state move the rows it
+    advances alone (True), or every slot's (False)?  The mixers' own rule
+    (``ops/ssm.state_rows_move_in_place``: a TPU, no mesh, float32 Mamba-2
+    states of whole tiles), asked of every layer's state this cache holds;
+    False for a cache that holds none."""
+    states = [layer["ssm_state"] for _, layer in layers(cache)
+              if "ssm_state" in layer]
+    return bool(states) and all(map(ssm.state_rows_move_in_place, states))
 
 
 def push_step(cache, pos, block_table):

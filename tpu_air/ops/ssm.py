@@ -22,7 +22,10 @@ functions take that as data (``valid_len`` / ``live``), and a held state is
 the input state BIT FOR BIT (a ``where``, not a multiplication by one).
 
 Everything is float32 whatever the model computes in: the state integrates
-hundreds of positions.  Plain ``jax.lax``; there is one path.
+hundreds of positions.  Plain ``jax.lax``, with one exception: on a TPU the
+Mamba-2 one-token update moves the state of the LIVE rows alone, where it
+lies (``ssd_rows_update``, a Pallas kernel; ``state_rows_move_in_place`` is
+the rule, decided at trace time from what the call can observe).
 
 MAMBA-2 (``ssd_chunk``, ``ssd_state_update``).  A head ``h`` of ``P``
 channels has ONE scalar decay a position, and the heads of a group ``g``
@@ -45,8 +48,21 @@ what came in.
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import traced_for_mesh
+
+_LANES = 128
+# the tile of one live row a grid step moves, [1, heads, P, N] float32, and the
+# heads one step of its loop takes: chosen on the chip (PERF.md, PR 48)
+_STATE_TILE_BYTES = 2 << 20
+_HEADS_A_LOOP_STEP = 4
 
 
 def causal_conv_chunk(x, tail, weight, bias, valid_len):
@@ -189,15 +205,168 @@ def ssd_chunk(u, dt, A, B, C, D, state, valid_len, block: int = 128):
     return y + D.astype(f32)[:, None] * u.astype(f32), state
 
 
+def state_rows_move_in_place(state: jax.Array) -> bool:
+    """Does :func:`ssd_state_update` move the state of the live rows alone,
+    where it lies in the pool ``[rows, H, P, N]`` (:func:`ssd_rows_update`),
+    or pass over every row?  Decided at trace time from what the call can
+    observe, no knob (``decode_attention.latent_pages_read_in_place`` is the
+    same rule for the latent pages): the backend is a TPU (interpret mode is
+    for tests), the program is not traced for a mesh (XLA cannot partition a
+    Mosaic call), and a head's state ``[P, N]`` is whole float32 tiles."""
+    return (jax.default_backend() == "tpu" and not traced_for_mesh()
+            and state.ndim == 4 and state.dtype == jnp.float32
+            and state.shape[2] % 8 == 0 and state.shape[3] % _LANES == 0)
+
+
+def _head_tile(H: int, P: int, N: int) -> int:
+    """Heads a grid step moves: all of a row's where they fit
+    ``_STATE_TILE_BYTES``, else the most that divide ``H`` into blocks
+    ``[heads, P]`` of whole sublanes and fit (a row's all where none does)."""
+    most = _STATE_TILE_BYTES // (P * N * 4)
+    if most >= H:
+        return H
+    return max((h for h in range(8, most + 1, 8) if H % h == 0), default=H)
+
+
+def _ssd_rows_kernel(rows_ref, count_ref, decay_ref, drive_ref, b_ref, c_ref,
+                     s_ref, y_ref, o_ref, *, per_group):
+    """Grid step ``(i, j)``: tile ``j`` (``hb`` heads) of the ``i``-th live
+    row, a head at a time.  The blocks were chosen by the index maps from
+    the prefetched row list; a step past the live count sits on the last
+    live tile (nothing moves for it) with its body off."""
+    i, j, n = pl.program_id(0), pl.program_id(1), count_ref[0]
+    hb, P, _ = s_ref.shape[1:]
+    H = decay_ref.shape[2]
+
+    @pl.when(i < n)
+    def _():
+        # a [1, P] row as a [P, 1] column and back: through the diagonal, so
+        # the head index stays a loop variable (sublanes) and nothing is
+        # sliced along lanes
+        eye = (jax.lax.broadcasted_iota(jnp.int32, (P, P), 0)
+               == jax.lax.broadcasted_iota(jnp.int32, (P, P), 1))
+        head_of_lane = jax.lax.broadcasted_iota(jnp.int32, (1, H), 1)
+        decay = decay_ref[0]                                     # [1, H]
+
+        def head(t):
+            h = j * hb + t
+            g = h // per_group
+            a = jnp.sum(jnp.where(head_of_lane == h, decay, 0.0), axis=1,
+                        keepdims=True)                           # [1, 1]
+            x = jnp.sum(jnp.where(eye, drive_ref[0, pl.ds(t, 1), :], 0.0),
+                        axis=1, keepdims=True)                   # [P, 1]
+            new = a * s_ref[0, t] + x * b_ref[0, pl.ds(g, 1), :]
+            o_ref[0, t] = new
+            y = jnp.sum(new * c_ref[0, pl.ds(g, 1), :], axis=1,
+                        keepdims=True)                           # [P, 1]
+            y_ref[0, pl.ds(t, 1), :] = jnp.sum(
+                jnp.where(eye, y, 0.0), axis=0, keepdims=True)   # [1, P]
+
+        # a head is one chain of dependent operations, two reductions over
+        # lanes in it, and only the heads of one loop step overlap: one at a
+        # time the pass is bound by that chain at twice the copies' time
+        step = math.gcd(hb, _HEADS_A_LOOP_STEP)
+
+        def heads(q, carry):
+            for r in range(step):
+                head(q * step + r)
+            return carry
+
+        jax.lax.fori_loop(0, hb // step, heads, None)
+
+    # no live row: the one tile the steps sit on goes back as it came (an
+    # output block nobody wrote would be written back as it is)
+    @pl.when((n == 0) & (i == 0) & (j == 0))
+    def _():
+        o_ref[...] = s_ref[...]
+
+
+def ssd_rows_update(u, dt, A, B, C, D, state, live, head_tile=None,
+                    interpret=None):
+    """:func:`ssd_state_update` over the LIVE rows alone, IN PLACE: the pool
+    ``state [b, H, P, N]`` float32 goes in and comes out as one buffer
+    (``input_output_aliases``) and a grid step moves one tile ``[1, hb, P,
+    N]`` of one live row through fast memory: read once, written once where
+    it lay.  The live rows' indices (live ones first, in slot order) and
+    their count are made here from ``live`` and ride as scalar-prefetch
+    arguments; the block specs index by them.  A row that is not live is
+    never touched; with no live row one tile is written back as it was read.
+    The formula and its order are the ``jnp`` form's, in float32; ``y`` of a
+    row that is not live is zeros.
+
+    VMEM (the cell: ``hb`` = 64 heads of 64 x 128 float32): the state tile in
+    and out, two buffers each, 8 MB.  ``head_tile`` None:
+    :func:`_head_tile`; ``interpret`` None: interpret mode off a TPU
+    (tests)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    f32 = jnp.float32
+    b, H, P = u.shape
+    G, N = B.shape[1], B.shape[2]
+    hb = head_tile or _head_tile(H, P, N)
+    tiles = H // hb
+    u, dt = u.astype(f32), dt.astype(f32)
+    rows = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
+    count = jnp.sum(live, dtype=jnp.int32)[None]
+
+    def at(i, j, rows_ref, count_ref):
+        """(row, tile) of grid step ``(i, j)``; past the live count, the last
+        live row's last tile (with none live, a tile of row ``rows[0]``)."""
+        n = count_ref[0]
+        return (rows_ref[jnp.minimum(i, jnp.maximum(n - 1, 0))],
+                jnp.where(i < n, j, tiles - 1))
+
+    a_row = lambda *s: (at(*s)[0], 0, 0)                         # noqa: E731
+    a_tile = lambda *s: at(*s) + (0,)                            # noqa: E731
+    a_state = lambda *s: at(*s) + (0, 0)                         # noqa: E731
+    y, state = pl.pallas_call(
+        functools.partial(_ssd_rows_kernel, per_group=H // G),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, tiles),
+            in_specs=[
+                pl.BlockSpec((1, 1, H), a_row),
+                pl.BlockSpec((1, hb, P), a_tile),
+                pl.BlockSpec((1, G, N), a_row),
+                pl.BlockSpec((1, G, N), a_row),
+                pl.BlockSpec((1, hb, P, N), a_state),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, hb, P), a_tile),
+                pl.BlockSpec((1, hb, P, N), a_state),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct((b, H, P), f32),
+                   jax.ShapeDtypeStruct(state.shape, f32)],
+        # operands count the two prefetched scalars
+        input_output_aliases={6: 1},
+        # no ``vmem_limit_bytes``: the tile is sized to the default scoped
+        # limit, and a call that asks for more changes how the compiler tiles
+        # OTHER fusions of the program (PERF.md, PR 48: an attention softmax
+        # eleven times slower)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=bool(interpret),
+        name="ssd_rows_update",
+    )(rows, count, jnp.exp(dt * A.astype(f32))[:, None, :], dt[..., None] * u,
+      B.astype(f32), C.astype(f32), state)
+    y = y + D.astype(f32)[:, None] * u
+    return jnp.where(live[:, None, None], y, 0.0), state
+
+
 def ssd_state_update(u, dt, A, B, C, D, state, live):
     """One token of the Mamba-2 recurrence over every row.
 
     ``u [b, H, P]``; ``dt [b, H]``; ``A [H]``; ``B``, ``C`` ``[b, G, N]``;
     ``D [H]``; ``state [b, H, P, N]`` float32; ``live [b]`` bool.  Returns
     ``(y [b, H, P] float32, state')``; a row with ``live`` false keeps its
-    state bit for bit (its ``y`` is don't-care).  One elementwise pass over
-    the state: read once, written once, ``y`` reduced over the lanes in the
-    same pass."""
+    state bit for bit.  Where :func:`state_rows_move_in_place` says so (a
+    TPU) the live rows' state alone is read and written, where it lies
+    (:func:`ssd_rows_update`), and ``y`` of a row that is not live is ZEROS;
+    elsewhere one elementwise pass over every row's state, read once and
+    written once, ``y`` reduced over the lanes in the same pass, and ``y`` of
+    a row that is not live is don't-care."""
+    if state_rows_move_in_place(state):
+        return ssd_rows_update(u, dt, A, B, C, D, state, live)
     f32 = jnp.float32
     b, H, P = u.shape
     G, N = B.shape[1], B.shape[2]
