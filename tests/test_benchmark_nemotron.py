@@ -57,16 +57,31 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
                  "mla_decode_roofline", "moe_held_expert_roofline",
                  "engine_host_ms_p50", "ssm_state_share"):
         assert name not in layer, name
-    # the new metrics are this cell's alone, and at the end of the list
-    assert [m["name"] for m in bench.doc["per_layer"][-7:-1]] == NEW
+    # the new metrics are this cell's alone, appended in one piece (found by
+    # name: later PRs append behind them)
+    names = [m["name"] for m in bench.doc["per_layer"]]
+    at = names.index(NEW[0])
+    assert names[at:at + 6] == NEW
     assert all(m["workloads"] == [CELL] and m["unit"] == "%"
                and m["source"] == "device_trace"
-               for m in bench.doc["per_layer"][-7:-1])
+               for m in bench.doc["per_layer"][at:at + 6])
     # PR 48: how much of the state the pass moves is the live rows'
     passed = layer["ssd_state_pass_live_share"]
-    assert bench.doc["per_layer"][-1]["name"] == passed["name"]
+    assert names[at + 6] == passed["name"]
     assert (passed["workloads"], passed["source"], passed["layer"]) == (
         [CELL], "program_span", "kernels")
+    # PR 49: the sum over a token's choices, in the three sparse cells, by
+    # the reader and over the programs ``moe_shared_share`` reads
+    combine = layer["moe_combine_share"]
+    assert names[at + 7] == combine["name"]
+    assert combine["workloads"] == [
+        "gigachat-serve-docchat", CELL, "olmoe-serve-decode"]
+    assert (combine["unit"], combine["better"], combine["source"],
+            combine["layer"], combine["moves"]) == (
+        "%", "lower", "device_trace", "kernels", "serve_tpot_p50_ms")
+    assert (combine["reader"], combine["args"]) == (
+        "scope_share", {"scope": "^moe_combine$"})
+    assert combine["args"].keys() == layer["moe_shared_share"]["args"].keys()
     assert (passed["reader"], passed["args"]) == (
         "span_stat_ratio",
         {"name": "engine.step", "num": "live", "den": "state_rows"})

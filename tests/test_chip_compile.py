@@ -151,8 +151,10 @@ def test_expert_product_compiles_with_its_kernel_for_v5e(v5e, monkeypatch):
     """The routed expert product (ops/moe.py) at OLMoE's widths and the
     serving cell's two shapes, 64 rows (a decode step) and 128 (a prefill
     chunk) of top-8 over 64 experts: three calls of the grouped-matmul
-    kernel each.  ``expert_ffn`` takes the kernel where the backend is a TPU;
-    the described chip is not the backend, so the test says so."""
+    kernel each and one of the sum over a token's choices
+    (``held_rows_sum``, PR 49).  ``expert_ffn`` takes the kernels where the
+    backend is a TPU; the described chip is not the backend, so the test
+    says so."""
     from tpu_air.ops import moe
 
     monkeypatch.setattr(moe.jax, "default_backend", lambda: "tpu")
@@ -164,7 +166,76 @@ def test_expert_product_compiles_with_its_kernel_for_v5e(v5e, monkeypatch):
                 _struct((64, 2048, 1024), jnp.bfloat16, v5e),
                 _struct((64, 1024, 2048), jnp.bfloat16, v5e))
         text = jax.jit(moe.expert_ffn).lower(*args).compile().as_text()
-        assert text.count("tpu_custom_call") == 3, rows
+        assert text.count("tpu_custom_call") == 4, rows
+        assert sum("tpu_custom_call" in line and "held_rows_sum" in line
+                   for line in text.splitlines()) == 1, rows
+
+
+# -- the sum over a token's choices moves the held rows once (PR 49) -----------
+
+def _rows_written_under_combine(text, m, d):
+    """Float32 arrays ``[m, d]`` (a row a product, ``m = t * k``) the ENTRY
+    computation writes under the scope ``moe_combine``: each is a pass over
+    every assignment's row, held here or not."""
+    entry = text[text.index("\nENTRY "):]
+    return len([line for line in entry.splitlines()
+                if re.match(rf"\s*(?:ROOT )?%\S+ = f32\[{m},{d}\]\S* "
+                            r"(?!bitcast|parameter|get-tuple-element)[\w-]+\(",
+                            line)
+                and re.search(r'op_name="[^"]*/moe_combine/', line)])
+
+
+def test_combine_counter_sees_what_the_parent_program_did():
+    """Not vacuous: the three ``[3072, 7168]`` float32 arrays of the program
+    as it was compiled before PR 49 (the weighted and masked rows, the
+    zeros, the scatter into them), and none for a bitcast or another scope."""
+    made = ('  %{0} = f32[3072,7168]{{1,0:T(8,128)}} fusion(%a, %b), '
+            'kind=kLoop, calls=%c, metadata={{op_name="jit(f)/moe/{1}" '
+            'stack_frame_id=4}}\n')
+    parent = ("\nENTRY %main {\n"
+              + made.format("multiply_select_fusion",
+                            "moe_combine/jit(_where)/select_n")
+              + made.format("fusion.40", "moe_combine/scatter")
+              + made.format("fusion.19", "moe_combine/scatter")
+              + made.format("fusion.7", "moe_experts/jit(gmm)/pallas_call")
+              + '  %bitcast.6 = f32[3072,7168]{1,0:T(8,128)} bitcast(%x), '
+                'metadata={op_name="jit(f)/moe/moe_combine/reshape"}\n'
+              + '  %r = f32[384,7168]{1,0:T(8,128)} fusion(%fusion.19), '
+                'kind=kLoop, calls=%d, metadata={op_name="jit(f)/moe/'
+                'moe_combine/reduce_sum"}\n}')
+    assert _rows_written_under_combine(parent, 3072, 7168) == 3
+
+
+def test_the_sum_over_choices_writes_no_row_of_no_group_on_v5e(
+        v5e, monkeypatch):
+    """``expert_ffn`` at ``gigachat-serve-docchat``'s widths and its mixed
+    step's rows (384 of top-8, 16 experts held, ids held elsewhere among
+    them): under ``moe_combine`` the program writes a float32 ``[t*k, d]``
+    at most once (before PR 49 three times: 88 MB each, fifteen sixteenths
+    of it rows of no group), there is no scatter, the sum is the one kernel
+    beside the three products, and no fusion got a window the compiler's
+    cost model cannot price (PERF.md, PR 48).  Off a TPU the gathered form
+    writes such an array once: the gather, read by the reduction."""
+    from tpu_air.ops import moe
+
+    t, k, d, f, e = 384, 8, 7168, 2048, 16
+    args = (_struct((t, d), jnp.bfloat16, v5e),
+            _struct((t, k), jnp.int32, v5e),
+            _struct((t, k), jnp.float32, v5e),
+            _struct((e, d, f), jnp.bfloat16, v5e),
+            _struct((e, d, f), jnp.bfloat16, v5e),
+            _struct((e, f, d), jnp.bfloat16, v5e))
+    # (a function each: a trace is cached by the function, not the backend)
+    gathered = jax.jit(lambda *a: moe.expert_ffn(*a)).lower(
+        *args).compile().as_text()
+    assert _rows_written_under_combine(gathered, t * k, d) == 1
+    monkeypatch.setattr(moe.jax, "default_backend", lambda: "tpu")
+    text = jax.jit(lambda *a: moe.expert_ffn(*a)).lower(
+        *args).compile().as_text()
+    assert _rows_written_under_combine(text, t * k, d) == 0
+    assert not re.search(r'scatter\([^\n]*op_name="[^"]*/moe_combine/', text)
+    assert text.count("tpu_custom_call") == 4
+    assert '"estimated_cycles":"9223372036854775807"' not in text
 
 
 def test_flat_decode_attention_compiles_for_v5e(v5e):
@@ -574,7 +645,7 @@ def test_mixed_step_streams_each_weight_once_on_v5e(v5e, monkeypatch, family):
     donated cache comes back aliased and no state is copied.  The
     sparse-expert model (OLMoE's widths, 2 layers, 64 slots) runs its three
     grouped products a layer as the kernel, once over the step's rows and
-    the chunk's together."""
+    the chunk's together, and the sum over a token's choices as its own."""
     from tpu_air.models.lm import CausalLM, LMConfig
     from tpu_air.models.lm.generate import (
         make_paged_decode_body, make_paged_mixed_body,
@@ -611,7 +682,8 @@ def test_mixed_step_streams_each_weight_once_on_v5e(v5e, monkeypatch, family):
     assert {w: len(r) for w, r in readers.items()} == {
         w: 2 if w in tied else 1 for w in readers}
     if family == "sparse_experts":
-        assert text.count("tpu_custom_call") == 3 * cfg.n_layers
+        # three products and the sum over a token's choices
+        assert text.count("tpu_custom_call") == 4 * cfg.n_layers
         return
     mem = compiled.memory_analysis()
     state = 26 * slots * (16 * 5120 * 4 + 3 * 5120 * 2)
@@ -653,7 +725,8 @@ def test_latent_decode_rows_read_their_pages_in_place_on_v5e(
     pages of 256 x 640): the chip's compiler takes them with the paged kernel
     of the rows' read in every layer of the two programs that decode rows,
     the kernel of the chunk's walk in every layer of the two that carry a
-    chunk, beside the twelve grouped products; nothing of the gathered
+    chunk, beside the twelve grouped products and the four sums over a
+    token's choices (``held_rows_sum``); nothing of the gathered
     slab's shape ``[128, 4096, 640]`` is made (the gathered read makes it:
     the counter sees it there), nothing of a chunk's gathered slot ``[1,
     4096, 640]`` nor of its scores over ``slot_len`` ``[64, 256, 4096]`` (the
@@ -724,11 +797,13 @@ def test_latent_decode_rows_read_their_pages_in_place_on_v5e(
             cfg.n_layers if program != "chunk" else 0,
         "paged_latent_chunk_attention":
             cfg.n_layers if program != "decode" else 0}
-    assert text.count("tpu_custom_call") == sum(kernels.values()) + 3 * 4
+    assert text.count("tpu_custom_call") == sum(kernels.values()) + 4 * 4
     assert not re.search(_LATENT_SLAB, text)
     assert not re.search(_LATENT_POOL_MOVED, text)
     assert not re.search(_CHUNK_SLOT, text)
     assert not re.search(_CHUNK_SCORES, text)
+    # no fusion with a window the compiler's cost model cannot price
+    assert '"estimated_cycles":"9223372036854775807"' not in text
     pools = cfg.n_layers * (slots * npg + 1) * page * 640 * 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pools           # appended to in place
@@ -745,8 +820,9 @@ def test_mamba2_state_pool_stays_in_place_at_full_depth_on_v5e(
     x 4096, pages of 256): the chip's compiler takes each, the donated cache
     comes back aliased, nothing the size of the ``[128, 128, 64, 128]``
     float32 state pool (537 MB a layer) is held or copied beside it, the ten
-    expert products are the grouped-matmul kernel with the whole-matrix tile,
-    a step's five passes over the state are the in-place kernel of the live
+    expert products are the grouped-matmul kernel with the whole-matrix tile
+    and the five sums over a token's choices one kernel each, a step's five
+    passes over the state are the in-place kernel of the live
     rows (``ops/ssm.ssd_rows_update``, PR 48: the pool aliased through it, in
     the mixed step too, where the chunk's row is read from what it left),
     and the program fits the chip beside its 12.6 GB of arguments."""
@@ -787,8 +863,9 @@ def test_mamba2_state_pool_stays_in_place_at_full_depth_on_v5e(
             < 15.75 * 2 ** 30)
     text = compiled.as_text()
     assert not re.search(r"= f32\[128,128,64,128\]\S* copy\(", text)
-    # ten expert products; a step's five passes (the chunk program has none)
-    assert text.count("tpu_custom_call") == (10 if program == "chunk" else 15)
+    # ten expert products and five sums over the choices; a step's five
+    # passes (the chunk program has none)
+    assert text.count("tpu_custom_call") == (15 if program == "chunk" else 20)
     assert ("ssd_rows_update" in text) == (program != "chunk")
     # a kernel call that asks for more than the default scoped fast memory
     # changes how the compiler tiles OTHER fusions: with 16 MB asked the

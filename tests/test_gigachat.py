@@ -18,6 +18,7 @@ from tpu_air.models.lm.config import LMConfig
 from tpu_air.models.lm.modeling import (CausalLM, grouped_sigmoid_routing,
                                         yarn_inv_freq, yarn_mscale)
 
+import _combine_cases
 import _mixed_step_cases
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -433,7 +434,7 @@ def test_absent_assignments_cost_no_product():
     x, gate, up, down = f(6, 8), f(3, 8, 4), f(3, 8, 4), f(3, 4, 8)
     chosen = jnp.asarray([[0, 3], [3, 3], [2, 1], [3, 0], [1, 3], [3, 2]])
     w = jnp.abs(f(6, 2))
-    got = np.asarray(expert_ffn(x, chosen, w, gate, up, down, partial=True))
+    got = np.asarray(expert_ffn(x, chosen, w, gate, up, down))
     want = np.zeros((6, 8), np.float32)
     for t in range(6):
         for j in range(2):
@@ -443,6 +444,60 @@ def test_absent_assignments_cost_no_product():
                 want[t] += np.asarray(w[t, j] * (hid @ down[e]))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     assert np.all(got[1] == 0)
+
+
+@pytest.mark.parametrize("routing", _combine_cases.PARTIAL)
+def test_gated_expert_ffn_and_its_gradient_with_assignments_elsewhere(
+        routing):
+    """A share of the experts held (tests/_combine_cases.py): some of a
+    token's choices elsewhere, all of every token's, and tokens that send
+    none to all of theirs to this rank."""
+    _combine_cases.against_the_loop(routing, gated=True)
+
+
+@pytest.mark.parametrize("form", sorted(_combine_cases.FORMS))
+@pytest.mark.parametrize("routing", _combine_cases.PARTIAL)
+def test_rows_of_no_group_are_masked_never_multiplied(routing, form):
+    """The sum over a token's choices, both forms, over products whose
+    unvisited rows are NaN."""
+    _combine_cases.rows_of_no_group(routing, form)
+
+
+@pytest.mark.parametrize("routing", _combine_cases.PARTIAL)
+def test_the_sums_kernel_is_differentiated_as_the_gathered_form(routing):
+    _combine_cases.kernel_gradient(routing)
+
+
+def test_the_rule_that_picks_the_sums_kernel(monkeypatch):
+    """``expert_ffn`` sums through ``held_rows_sum`` on a TPU where the
+    sorted rows and the width are whole tiles and the tokens fit one tile of
+    the result; every CPU run gathers.  The cells' shapes (a decode step, a
+    chunk, a mixed step of the three sparse configurations) all have a tile."""
+    from tpu_air.ops import moe
+
+    for t, k, d in [(128, 8, 7168), (256, 8, 7168), (384, 8, 7168),
+                    (128, 22, 1024), (256, 22, 1024), (384, 22, 1024)]:
+        assert moe.combine_tile(t, t * k, d) == 1024
+    for t in (64, 128, 192):
+        assert moe.combine_tile(t, t * 8, 2048) == 2048
+    assert moe.combine_tile(1024, 8192, 2048) == 512
+    assert moe.combine_tile(2048, 16384, 2048) is None      # too many tokens
+    assert moe.combine_tile(19, 57, 16) is None             # no whole tiles
+    assert moe.combine_tile(32, 128, 192) is None
+
+    taken = []
+    monkeypatch.setattr(moe, "held_rows_sum",
+                        lambda *a: taken.append(a) or moe.gathered_sum(*a))
+    f = lambda *s: jnp.ones(s, jnp.float32)  # noqa: E731
+    args = (f(32, 128), jnp.zeros((32, 4), jnp.int32), f(32, 4),
+            f(2, 128, 8), f(2, 128, 8), f(2, 8, 128))
+    moe.expert_ffn(*args)
+    assert not taken                                        # a CPU
+    monkeypatch.setattr(moe, "_grouped", lambda lhs, rhs, sizes:
+                        jax.lax.ragged_dot(lhs, rhs, sizes))
+    monkeypatch.setattr(moe.jax, "default_backend", lambda: "tpu")
+    moe.expert_ffn(*args)
+    assert len(taken) == 1
 
 
 def test_the_kernel_is_taken_for_this_models_shapes():

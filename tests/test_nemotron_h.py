@@ -18,6 +18,7 @@ from tpu_air.models.lm.modeling import (CausalLM, Mamba2Mixer,
                                         grouped_sigmoid_routing)
 from tpu_air.ops import moe, ssm
 
+import _combine_cases
 import _mixed_step_cases
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -294,8 +295,7 @@ def test_two_matrix_relu2_expert_ffn_against_a_loop_over_experts(partial):
     chosen = np.stack([rng.permutation(e + partial)[:k] for _ in range(t)])
     w = jnp.asarray(rng.uniform(0.1, 1, (t, k)), jnp.float32)
     with jax.default_matmul_precision("highest"):
-        got = moe.expert_ffn(x, jnp.asarray(chosen), w, None, up, down,
-                             partial=partial)
+        got = moe.expert_ffn(x, jnp.asarray(chosen), w, None, up, down)
         want = np.zeros((t, d), np.float32)
         for i in range(t):
             for j in range(k):
@@ -305,6 +305,13 @@ def test_two_matrix_relu2_expert_ffn_against_a_loop_over_experts(partial):
                     want[i] += float(w[i, j]) * np.asarray(
                         hid @ down[chosen[i, j]])
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("routing", sorted(_combine_cases.ROUTINGS))
+def test_two_matrix_expert_ffn_and_its_gradient_against_the_loop(routing):
+    """Two-matrix experts through the same sum over a token's choices
+    (tests/_combine_cases.py), every routing of the other two files."""
+    _combine_cases.against_the_loop(routing, gated=False)
 
 
 def test_every_benchmark_configurations_products_keep_their_tile():

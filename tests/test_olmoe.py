@@ -21,6 +21,7 @@ from tpu_air.models.lm import CausalLM, hf_import, reference
 from tpu_air.models.lm.modeling import rope
 from tpu_air.observability.perf import LMCostModel
 
+import _combine_cases
 import _mixed_step_cases
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -183,6 +184,23 @@ def test_expert_product_with_every_row_on_one_expert():
             a, b = x[i] @ g[c], x[i] @ u[c]
             want[i] += w[i, k] * ((a / (1 + np.exp(-a)) * b) @ dn[c])
     assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("routing", _combine_cases.WHOLE)
+def test_gated_expert_ffn_and_its_gradient_against_a_loop_over_experts(
+        routing):
+    """Every expert held: the case in which every product row is held
+    (tests/_combine_cases.py)."""
+    _combine_cases.against_the_loop(routing, gated=True)
+
+
+@pytest.mark.parametrize("form", sorted(_combine_cases.FORMS))
+def test_the_sum_over_a_tokens_choices_with_every_row_held(form):
+    _combine_cases.rows_of_no_group("none_elsewhere", form)
+
+
+def test_the_sums_kernel_is_differentiated_as_the_gathered_form():
+    _combine_cases.kernel_gradient("none_elsewhere")
 
 
 def test_rope_columns_turn_rotate_half_into_the_programs_pairing():

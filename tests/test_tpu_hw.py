@@ -380,3 +380,48 @@ def test_ssd_rows_update_holds_the_rows_it_does_not_advance_on_chip():
     a row that is not live is bit for bit what it was."""
     out = _run_on_tpu(_SSD_ROWS_SCRIPT)
     assert "engine ok" in out and out.count("kernel") == 4, out
+
+
+_HELD_ROWS_SCRIPT = """
+import jax, jax.numpy as jnp, numpy as np
+assert jax.devices()[0].platform == "tpu", jax.devices()
+from tpu_air.ops import moe
+
+# the three sparse cells' mixed-step shapes: tokens, top-k, experts routed
+# over, experts held, width
+for name, (t, k, routed, e, d) in {
+        "gigachat": (384, 8, 256, 16, 7168), "nemotron": (384, 22, 512, 128, 1024),
+        "olmoe": (192, 8, 64, 64, 2048), "none_held": (128, 8, 256, 0, 1024)}.items():
+    rng = np.random.default_rng(49)
+    chosen = np.stack([rng.permutation(routed)[:k] for _ in range(t)])
+    if e == 0:
+        e, chosen = 16, np.full((t, k), 16)
+    flat = np.minimum(chosen, e).reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    n = int((flat < e).sum())
+    y = rng.standard_normal((t * k, d)).astype(np.float32)
+    y[n:] = np.nan                       # what the product never wrote
+    w = rng.uniform(0.1, 1, (t, k)).astype(np.float32)
+    want = np.zeros((t, d))
+    np.add.at(want, order[:n] // k,
+              (w.reshape(-1)[order[:n], None] * y[:n]).astype(np.float64))
+    args = (jnp.asarray(y), jnp.asarray(flat, jnp.int32),
+            jnp.asarray(order, jnp.int32), jnp.asarray(w))
+    assert moe.combine_tile(t, t * k, d)
+    for form in (moe.held_rows_sum, moe.gathered_sum):
+        got = np.asarray(jax.jit(lambda *a: form(*a, e))(*args))
+        assert np.isfinite(got).all(), (name, form.__name__)
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-6)
+        if n == 0:
+            assert not got.any()
+    print("held rows", name, n, "ok", flush=True)
+"""
+
+
+def test_held_rows_sum_is_the_float32_sum_on_chip():
+    """PR 49: ``ops/moe.held_rows_sum`` compiled and run at the sparse cells'
+    mixed-step shapes over products whose unwritten rows are NaN: finite,
+    and the float64 sum of the float32 products to float32 rounding (nothing
+    went through the MXU at bfloat16's 8 bits), as the gathered form is."""
+    out = _run_on_tpu(_HELD_ROWS_SCRIPT)
+    assert out.count("held rows") == 4, out

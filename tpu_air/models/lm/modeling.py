@@ -790,8 +790,7 @@ class SparseExperts(nn.Module):
                              kernel_init=init, name="latent_down")(t)
         y = expert_ffn(t, chosen, probs,
                        None if gate is None else gate.astype(dtype),
-                       up.astype(dtype), down.astype(dtype),
-                       partial=not whole).astype(dtype)
+                       up.astype(dtype), down.astype(dtype)).astype(dtype)
         if cfg.moe_latent_size:
             with jax.named_scope("moe_latent_up"):
                 y = nn.Dense(d, use_bias=False, dtype=dtype,

@@ -8,25 +8,37 @@ fixed group size, so no assignment is dropped or re-routed however uneven
 the load.  Inputs in the model's dtype, accumulation in float32.
 
 The caller may hold only SOME of the experts the router scores (an
-expert-parallel rank: ``partial``): an assignment to an expert held elsewhere
-comes in with the id one past the last held expert, sorts behind every held
-group, belongs to no group, and no product touches its row; the rows past the
-last group come out as zeros.  Shapes stay static: such rows cost the sort
-and nothing else.
+expert-parallel rank): an assignment to an expert held elsewhere comes in
+with the id one past the last held expert, sorts behind every held group,
+belongs to no group, no product touches its row and the sum takes exactly
+0.0 for it.  Shapes stay static: such rows cost the sort and nothing else.
 
 One formulation, no switch: rows are sorted by expert and the three
 products run as grouped matmuls over the uneven groups (the Pallas
 ``megablox`` kernel on a TPU, ``jax.lax.ragged_dot`` elsewhere — the same
 mathematics; the kernel exists only for the TPU).  The other formulations
 that were measured against it on the v5e are in PERF.md (PR 27).
+
+The sum over the ``k`` (the scope ``moe_combine``) reads each product row at
+most once.  A caller that holds every expert is the case in which every row
+is held: one form for both.  On a TPU it is :func:`held_rows_sum`, a Pallas
+kernel over the held prefix of the sorted rows that writes the ``[t, d]``
+result alone; elsewhere, under ``jax.grad`` and where the shapes are not
+whole tiles, :func:`gathered_sum`, each token's rows gathered into the
+reduction (PERF.md, PR 49, has both against the scatter that was there).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import traced_for_mesh
 
 Array = jax.Array
 
@@ -84,14 +96,142 @@ def _grouped(lhs: Array, rhs: Array, sizes: Array) -> Array:
                               preferred_element_type=jnp.float32)
 
 
+# sorted rows a grid step of the combine's kernel takes: the grouped product's
+# own row tile, so the same shapes are whole tiles for both
+_COMBINE_ROWS = 128
+# the most a tile ``[t, columns]`` float32 of the combine's result may take:
+# two buffers of it, two of the rows' tile and the products' temporaries stay
+# inside the default scoped VMEM (16 MB; no ``vmem_limit_bytes``: PERF.md,
+# PR 48)
+_COMBINE_TILE_BYTES = 2 << 20
+
+
+def combine_tile(t: int, m: int, d: int) -> Optional[int]:
+    """Columns a grid step of :func:`held_rows_sum` takes for ``m`` sorted
+    rows of ``d`` numbers summed into ``t`` tokens: the widest of 2048, 1024,
+    512 (or, of a narrower ``d``, all of it) that divides ``d`` into whole
+    lanes and keeps the result's tile within ``_COMBINE_TILE_BYTES``, or None
+    (no whole tiles, or more tokens than one tile holds: 1024 at 512
+    columns): :func:`gathered_sum`."""
+    if m % _COMBINE_ROWS or t % 8:
+        return None
+    return next((c for c in (2048, 1024, 512, d)
+                 if d % c == 0 and c % 128 == 0
+                 and t * c * 4 <= _COMBINE_TILE_BYTES), None)
+
+
+def gathered_sum(y: Array, flat: Array, order: Array, weights: Array,
+                 e: int) -> Array:
+    """``y [t*k, d]`` float32, the products sorted by expert; ``flat [t*k]``
+    the ids in token order, ``order`` the sort's permutation, ``weights
+    [t, k]`` -> ``[t, d]``: token ``i``'s ``sum_j weights[i, j] * y[row of
+    (i, j)]`` in choice order.  The inverse permutation is ``t*k`` integers;
+    the rows travel from ``y`` to the sum through one gather.  A row of no
+    group (``flat == e``) holds whatever the kernel's buffer held: it is
+    masked before anything multiplies it, and its index points at row 0, so
+    it costs no bytes of its own."""
+    t, k = weights.shape
+    held = flat < e
+    at = jnp.zeros(t * k, jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    rows = jnp.where(held[:, None], y[jnp.where(held, at, 0)], 0.0)
+    return (rows.reshape(t, k, -1) * weights[:, :, None]).sum(1)
+
+
+def _held_rows_kernel(n_ref, y_ref, tok_ref, w_ref, out_ref):
+    """Grid step ``(j, i)``: tile ``i`` of the sorted rows into column tile
+    ``j`` of the result, which stays in fast memory over the ``i``.  A step
+    past the held rows sits on the last held tile (nothing moves for it)
+    with its body off."""
+    i, n = pl.program_id(1), n_ref[0]
+    t, rows = out_ref.shape[0], y_ref.shape[0]
+
+    @pl.when(i == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i * rows < n)
+    def _():
+        row = i * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        yw = jnp.where(row < n, y_ref[...] * w_ref[...], 0.0)
+        # out[tok[r]] += yw[r] as a product with a 0/1 matrix on the MXU.
+        # float32 through it EXACTLY: three bfloat16 pieces of 8 bits hold
+        # its 24, each times 1.0 and added in float32
+        hot = (jax.lax.broadcasted_iota(jnp.int32, (t, rows), 0)
+               == tok_ref[0]).astype(jnp.bfloat16)
+        for _ in range(3):
+            piece = yw.astype(jnp.bfloat16)
+            yw = yw - piece.astype(jnp.float32)
+            out_ref[...] += jnp.dot(hot, piece,
+                                    preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def held_rows_sum(y: Array, flat: Array, order: Array, weights: Array,
+                  e: int, interpret: Optional[bool] = None) -> Array:
+    """:func:`gathered_sum` (same arguments) as one pass over the HELD prefix
+    of the sorted rows: they lie first in ``y``, ``n`` of them, and a grid
+    step takes a tile of 128 with their tokens and weights, masks the rows
+    from ``n`` on, and adds each row into its token's row of the ``[t,
+    columns]`` result tile in fast memory.  ``n`` rides as a scalar-prefetch
+    argument and the block specs index by it: the tiles past the held rows
+    are never read.  Float32 throughout; a token's rows are added in sorted
+    order, not choice order, so the result is :func:`gathered_sum`'s to
+    float32 rounding, not to the bit.  ``interpret`` None: interpret mode
+    off a TPU (tests).  Differentiated as :func:`gathered_sum`."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    t, k = weights.shape
+    m, d = y.shape
+    rows, cols = _COMBINE_ROWS, combine_tile(t, m, d)
+
+    def tile(j, i, n_ref):
+        return jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0) // rows)
+
+    return pl.pallas_call(
+        _held_rows_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(d // cols, m // rows),
+            in_specs=[
+                pl.BlockSpec((rows, cols), lambda j, i, n: (tile(j, i, n), j)),
+                pl.BlockSpec((1, 1, rows),
+                             lambda j, i, n: (tile(j, i, n), 0, 0)),
+                pl.BlockSpec((rows, 1), lambda j, i, n: (tile(j, i, n), 0)),
+            ],
+            out_specs=pl.BlockSpec((t, cols), lambda j, i, n: (0, j))),
+        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=bool(interpret),
+        name="held_rows_sum",
+    )(jnp.sum(flat < e, dtype=jnp.int32)[None], y,
+      (order // k).astype(jnp.int32).reshape(m // rows, 1, rows),
+      weights.reshape(-1)[order][:, None])
+
+
+def _held_rows_sum_fwd(y, flat, order, weights, e, interpret):
+    return (held_rows_sum(y, flat, order, weights, e, interpret),
+            (y, flat, order, weights))
+
+
+def _held_rows_sum_bwd(e, interpret, saved, g):
+    y, flat, order, weights = saved
+    dy, dw = jax.vjp(lambda y, w: gathered_sum(y, flat, order, w, e),
+                     y, weights)[1](g)
+    return dy, None, None, dw
+
+
+held_rows_sum.defvjp(_held_rows_sum_fwd, _held_rows_sum_bwd)
+
+
 def expert_ffn(x: Array, chosen: Array, weights: Array,
-               gate: Optional[Array], up: Array, down: Array,
-               partial: bool = False) -> Array:
+               gate: Optional[Array], up: Array, down: Array) -> Array:
     """``x [t, d]``; ``chosen [t, k]`` int expert ids; ``weights [t, k]``
     float32; ``gate``/``up`` ``[e, d, f]``, ``down [e, f, d]`` -> ``[t, d]``
     float32.  ``gate`` None: two-matrix experts around a squared ReLU.
-    ``partial``: ``chosen`` may hold the id ``e``, an expert held elsewhere
-    (module doc)."""
+    ``chosen`` may hold the id ``e``, an expert held elsewhere (module
+    doc)."""
     t, k = chosen.shape
     e = up.shape[0]
     with jax.named_scope("moe_sort"):
@@ -108,9 +248,7 @@ def expert_ffn(x: Array, chosen: Array, weights: Array,
                  * _grouped(xs, up, sizes)).astype(x.dtype)
         y = _grouped(h, down, sizes)
     with jax.named_scope("moe_combine"):
-        y = y * weights.reshape(-1)[order][:, None]
-        if partial:
-            # rows of no group hold whatever the kernel's buffer held
-            y = jnp.where((flat[order] < e)[:, None], y, 0.0)
-        back = jnp.zeros((t * k, y.shape[-1]), jnp.float32).at[order].set(y)
-        return back.reshape(t, k, -1).sum(1)
+        if (jax.default_backend() == "tpu" and not traced_for_mesh()
+                and combine_tile(t, *y.shape) is not None):
+            return held_rows_sum(y, flat, order, weights, e)
+        return gathered_sum(y, flat, order, weights, e)
