@@ -548,7 +548,7 @@ def test_metrics_exposition_parses_line_by_line(monkeypatch):
                          reordered_admits=1, prefill_chunks=7)
         m.record_submit("interactive")
         for i in range(50):
-            m.record_ttft(0.01 + i * 0.002, priority="interactive",
+            m.record_ttft(0.0, 0.01 + i * 0.002, priority="interactive",
                           trace_id="ab" * 16)
         m.record_step(0.004, tokens=8)
         m.record_program("decode_step", ProgramCost(1e6, 1e5, tokens=8),
@@ -608,7 +608,7 @@ def test_exemplar_resolves_to_trace_over_http():
             with tracing.span("engine.prefill"):
                 pass
             trace_id = sp.trace_id
-        m.record_ttft(2.5, priority="interactive", trace_id=trace_id)
+        m.record_ttft(0.0, 2.5, priority="interactive", trace_id=trace_id)
 
         with urllib.request.urlopen(f"{url}/metrics", timeout=10) as r:
             text = r.read().decode()
@@ -709,7 +709,7 @@ def test_postmortem_captures_live_engine_and_trace(tmp_path):
     try:
         with tracing.span("doomed.task") as sp:
             trace_id = sp.trace_id
-        m.record_ttft(0.1)
+        m.record_ttft(0.0, 0.1)
         path = postmortem.dump("crash", {"trace_ids": [trace_id]},
                                directory=str(tmp_path))
         data = postmortem.load(path)

@@ -486,6 +486,161 @@ def test_metrics_and_dashboard_export(lm):
     assert "engine-test-metrics" not in engine_stats()  # unregistered
 
 
+def _tiny_engine(kind, lm, name):
+    """A manual-step engine over two rows: ``InferenceEngine`` on the tiny
+    LM, or ``T5Engine`` on the tiny T5."""
+    if kind == "paged":
+        _, model, params = lm
+        return InferenceEngine(
+            model, params,
+            EngineConfig(num_slots=2, slot_len=64, max_new_tokens=4,
+                         eos_token_id=None),
+            auto_start=False, name=name)
+    from tpu_air.engine import T5Engine, T5EngineConfig
+    from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
+
+    model = T5ForConditionalGeneration(T5Config.tiny())
+    ones = jnp.ones((1, 8), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ones, ones,
+                        ones[:, :4])["params"]
+    return T5Engine(model, params,
+                    T5EngineConfig(max_batch=2, max_input_len=12,
+                                   max_new_tokens=4),
+                    auto_start=False, name=name)
+
+
+def _watch(engine):
+    """The requests the engine admits, in admission order, and the parts
+    its ``record_ttft`` is given, as they happen."""
+    admitted, parts = [], []
+    pop, record = engine.scheduler.pop_admissible, engine.metrics.record_ttft
+
+    def spy_pop(*a, **kw):
+        out = pop(*a, **kw)
+        admitted.extend(out)
+        return out
+
+    def spy_record(queue_wait_s, prefill_s, *a, **kw):
+        parts.append((queue_wait_s, prefill_s))
+        return record(queue_wait_s, prefill_s, *a, **kw)
+
+    engine.scheduler.pop_admissible = spy_pop
+    engine.metrics.record_ttft = spy_record
+    return admitted, parts
+
+
+@pytest.mark.parametrize("kind", ["paged", "t5"])
+def test_one_set_of_stamps_splits_ttft_into_queue_wait_and_prefill(lm, kind):
+    """Both engines admit through ``Scheduler.pop_admissible``, which stamps
+    ``admitted_at`` on every request it hands out, with or without a
+    profiler session or airtrace: ``stats()`` holds one ``queue_wait_s`` and
+    one ``prefill_s`` sample a request, and the two are the request's TTFT."""
+    engine = _tiny_engine(kind, lm, f"engine-test-stamps-{kind}")
+    admitted, parts = _watch(engine)
+    engine.generate(_prompts(seed=31, n=5), max_new_tokens=3)
+    snap = engine.metrics.snapshot()
+    engine.close()
+    assert len(admitted) == len(parts) == 5
+    for key in ("ttft_s", "queue_wait_s", "prefill_s"):
+        assert snap[key]["count"] == 5
+    assert snap["priority"]["interactive"]["queue_wait_s"]["count"] == 5
+    assert snap["priority"]["batch"]["queue_wait_s"]["count"] == 0
+    for req in admitted:
+        assert req.submitted_at <= req.admitted_at < req.first_token_at
+        assert not hasattr(req, "t_submit_ns")
+        assert req.trace_ctx is None          # airtrace is off
+    # request by request: the parts recorded are the stamps' differences and
+    # sum to the request's TTFT
+    want = sorted((r.admitted_at - r.submitted_at,
+                   r.first_token_at - r.admitted_at) for r in admitted)
+    assert sorted(parts) == want
+    for (queue_wait, prefill), r in zip(sorted(parts), sorted(
+            admitted, key=lambda r: r.admitted_at - r.submitted_at)):
+        assert queue_wait + prefill == pytest.approx(
+            r.first_token_at - r.submitted_at, abs=1e-9)
+    assert snap["queue_wait_s"]["sum"] + snap["prefill_s"]["sum"] == (
+        pytest.approx(snap["ttft_s"]["sum"], abs=1e-9))
+    # five requests over two rows: the later ones waited for a row
+    assert snap["queue_wait_s"]["max"] > snap["queue_wait_s"]["min"] >= 0
+    # one reading a round: requests admitted together share the stamp
+    rounds = {r.admitted_at for r in admitted}
+    assert len(rounds) < 5
+
+
+@pytest.mark.parametrize("kind", ["paged", "t5"])
+def test_step_latency_is_split_by_the_program_read(lm, kind):
+    """``step_latency_by_program_s`` holds every ``step_latency_s`` sample
+    once, under the program the step READ was: ``T5Engine`` has one program,
+    ``InferenceEngine``'s mixed steps are as many as it issued and read."""
+    engine = _tiny_engine(kind, lm, f"engine-test-programs-{kind}")
+    engine.generate(_prompts(seed=32, n=5), max_new_tokens=4)
+    snap = engine.metrics.snapshot()
+    engine.close()
+    by_program = snap["step_latency_by_program_s"]
+    assert sorted(by_program) == (["decode", "mixed"] if kind == "paged"
+                                  else ["decode"])
+    assert sum(h["count"] for h in by_program.values()) == (
+        snap["step_latency_s"]["count"])
+    assert sum(h.get("sum", 0.0) for h in by_program.values()) == (
+        pytest.approx(snap["step_latency_s"]["sum"], abs=1e-9))
+    if kind == "paged":
+        # budgets end these streams: every issued step was read
+        assert snap["steps_dropped"] == 0
+        assert by_program["mixed"]["count"] == snap["mixed_steps"] >= 1
+        assert snap["step_latency_s"]["count"] == snap["steps_issued"]
+
+
+def test_reset_window_clears_the_new_histograms_too(lm):
+    engine = _tiny_engine("paged", lm, "engine-test-reset")
+    engine.generate(_prompts(seed=33, n=3), max_new_tokens=3)
+    engine.metrics.reset_window()
+    snap = engine.metrics.snapshot()
+    engine.close()
+    assert snap["queue_wait_s"]["count"] == snap["prefill_s"]["count"] == 0
+    assert all(h["count"] == 0
+               for h in snap["step_latency_by_program_s"].values())
+    assert snap["priority"]["interactive"]["queue_wait_s"]["count"] == 0
+    assert snap["requests_completed"] == 3       # counters stay
+
+
+def test_prefilled_admission_reads_a_prefill_of_zero(lm):
+    """A request whose prefill ran elsewhere (``submit_prefilled``) is
+    stamped like any other; its first token is emitted at its admission, so
+    its ``prefill_s`` sample is 0 and its TTFT here is its queue wait."""
+    from tpu_air.engine.dist.kv_transfer import extract_kv_pages
+
+    cfg, model, params = lm
+    ecfg = EngineConfig(num_slots=2, slot_len=64, max_new_tokens=4,
+                        page_len=8, eos_token_id=None)
+    src = InferenceEngine(model, params, ecfg, auto_start=False,
+                          name="engine-test-prefill-src")
+    dst = InferenceEngine(model, params, ecfg, auto_start=False,
+                          name="engine-test-prefill-dst")
+    prompt = _prompts(seed=34, n=1, lo=9, hi=12)[0]
+    stream = src.submit(prompt)
+    while not stream.tokens_so_far():
+        src.step()
+    slot = src.slots.active_slots()[0]
+    pages = extract_kv_pages(
+        src.cache, src.pool.prompt_page_ids(slot.index, len(prompt)))
+    first = stream.tokens_so_far()[0]
+    admitted, parts = _watch(dst)
+    got = dst.submit_prefilled(prompt, first, pages, 4)
+    while not dst.idle():
+        dst.step()
+    assert got.result(5.0) == _offline(model, params, prompt, 4, None)
+    snap = dst.metrics.snapshot()
+    src.close()
+    dst.close()
+    (req,) = admitted
+    assert req.first_token_at == req.admitted_at >= req.submitted_at
+    assert parts == [(req.admitted_at - req.submitted_at, 0.0)]
+    assert snap["prefill_s"]["count"] == snap["queue_wait_s"]["count"] == 1
+    assert snap["prefill_s"]["max"] == 0.0
+    assert snap["ttft_s"]["sum"] == pytest.approx(
+        snap["queue_wait_s"]["sum"], abs=1e-12)
+
+
 def test_engine_emits_connected_trace(lm):
     """A traced request through the engine yields a connected span tree at
     retirement: engine.request → queue_wait / prefill / decode, parented
@@ -538,8 +693,8 @@ def test_engine_emits_connected_trace(lm):
 
 
 def test_engine_untraced_requests_cost_nothing(lm):
-    """With tracing off, requests carry zero-valued stamps and the recorder
-    stays empty (the zero-cost-when-off contract)."""
+    """With tracing off, requests carry no carrier and the recorder stays
+    empty (the zero-cost-when-off contract)."""
     cfg, model, params = lm
     from tpu_air.observability import tracing
 

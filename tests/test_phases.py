@@ -45,6 +45,21 @@ def _inside(phases, parent):
             if p is not parent and parent[1] <= p[1] and p[2] <= parent[2]]
 
 
+def _histograms(before, after):
+    """What ``stats()``' latency histograms gained between two snapshots:
+    ``(count, sum in seconds)`` by name, the steps' by program too."""
+    def gained(a, b):
+        return (a["count"] - b.get("count", 0),
+                a.get("sum", 0.0) - b.get("sum", 0.0))
+
+    out = {k: gained(after[k], before[k]) for k in (
+        "ttft_s", "queue_wait_s", "prefill_s", "step_latency_s")}
+    out["by_program"] = {
+        program: gained(h, before["step_latency_by_program_s"][program])
+        for program, h in after["step_latency_by_program_s"].items()}
+    return out
+
+
 def test_phase_in_a_session_lands_on_the_host_plane_with_its_counts(tmp_path):
     import jax
 
@@ -128,6 +143,7 @@ def engine_phases(tmp_path_factory):
     engine.close()
     counted = {k: after[k] - before[k] for k in (
         "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped")}
+    counted.update(_histograms(before, after), trace_dir=trace_dir)
     return _phases(trace_dir), tokens, counted
 
 
@@ -135,8 +151,9 @@ def test_engine_step_holds_dispatch_readback_emit_in_order(engine_phases):
     phases, _, _ = engine_phases
     steps = [p for p in phases if p[0] == "engine.step"]
     assert len(steps) >= 5
+    closes = [p for p in phases if p[0] == "engine.window_close"]
     for st in steps:
-        kids = _inside(phases, st)
+        kids = [k for k in _inside(phases, st) if k not in closes]
         # the next step goes out (at most once, and first), then the step
         # before it is read back, then emitted
         assert [k[0] for k in kids] == (
@@ -145,16 +162,20 @@ def test_engine_step_holds_dispatch_readback_emit_in_order(engine_phases):
         assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
         assert 1 <= st[3]["live"] <= st[3]["batch"] == 2
         assert kids[-1][3] == {"emitted": st[3]["live"]}
+        # a window closes where its last rows retire: inside that emit
+        assert _inside(closes, st) == _inside(closes, kids[-1])
     # budgets 2, 3 | 4, 5 | 6: each window's last token step has nothing to
     # issue ahead of it (the window's first went out at its opening)
     ahead = [st[3]["ahead"] for st in steps]
     assert ahead == [1, 0] + [1, 1, 1, 0] + [1, 1, 1, 1, 0]
     # no phase per row or per token: a read-back and an emit a step, a
-    # dispatch where one was issued, one prefill a window, nothing else
+    # dispatch where one was issued, one prefill and one close a window, one
+    # first token a request, nothing else
     assert {p[0] for p in phases} == {
         "engine.prefill", "engine.step", "engine.dispatch",
-        "engine.readback", "engine.emit"}
-    assert len(phases) == 3 * len(steps) + sum(ahead) + 3
+        "engine.readback", "engine.emit", "engine.first_token",
+        "engine.window_close"}
+    assert len(phases) == 3 * len(steps) + sum(ahead) + 3 + 5 + 3
 
 
 def test_engine_prefill_counts_and_emitted_sum_to_the_engines_tokens(
@@ -165,7 +186,9 @@ def test_engine_prefill_counts_and_emitted_sum_to_the_engines_tokens(
     # five requests, two rows a window; what each window left in the queue
     assert [(p[3]["rows"], p[3]["batch"], p[3]["queued"])
             for p in prefills] == [(2, 2, 3), (2, 2, 1), (1, 2, 0)]
-    assert not any(_inside(phases, p) for p in prefills)
+    # nothing is read inside it; it holds its rows' first tokens alone
+    assert all([k[0] for k in _inside(phases, p)]
+               == ["engine.first_token"] * p[3]["rows"] for p in prefills)
     first = sum(p[3]["rows"] for p in prefills)
     later = sum(p[3]["emitted"] for p in phases if p[0] == "engine.emit")
     assert first + later == emitted == tokens
@@ -183,6 +206,73 @@ def test_engine_step_ahead_counts_are_the_engines_step_counters(
         st[3]["ahead"] for st in steps)
     assert counted["steps_issued"] == len(steps) == len(dispatches) + 3
     assert counted["steps_dropped"] == 0
+
+
+def _first_token_counts_hold(phases, counted, chunks):
+    """One ``engine.first_token`` a request submitted inside the session (the
+    warm-up's two, outside it, left none), each with its four counts; their
+    waits are the samples ``stats()`` took, to the microsecond a request the
+    counts are cut to."""
+    firsts = [p[3] for p in phases if p[0] == "engine.first_token"]
+    assert len(firsts) == 5
+    assert all(set(f) == {"queue_us", "prefill_us", "prompt", "chunks"}
+               for f in firsts)
+    assert sorted(f["prompt"] for f in firsts) == [3, 4, 5, 6, 8]
+    assert all(f["chunks"] == chunks and f["queue_us"] >= 0
+               and f["prefill_us"] > 0 for f in firsts)
+    for part, hist in (("queue_us", "queue_wait_s"),
+                       ("prefill_us", "prefill_s")):
+        n, seconds = counted[hist]
+        assert n == 5
+        assert 0 <= 1e6 * seconds - sum(f[part] for f in firsts) < 5 + 1e-3
+    # the two parts are the whole of it, request by request: one set of stamps
+    assert counted["ttft_s"][0] == 5
+    assert counted["ttft_s"][1] == pytest.approx(
+        counted["queue_wait_s"][1] + counted["prefill_s"][1], abs=1e-9)
+
+
+def test_engine_first_token_is_one_event_a_request_with_its_waits(
+        engine_phases):
+    phases, _, counted = engine_phases
+    _first_token_counts_hold(phases, counted, chunks=1)
+    # a window's rows were admitted by one round and emitted at one reading
+    for pre in (p for p in phases if p[0] == "engine.prefill"):
+        rows = [k[3] for k in _inside(phases, pre)]
+        assert len({r["prefill_us"] for r in rows}) == 1
+    # the window engine has one token-step program
+    assert counted["by_program"] == {"decode": counted["step_latency_s"]}
+
+
+def test_engine_window_close_counts_the_whole_window(engine_phases):
+    """One ``engine.window_close`` a window: ``live_row_steps`` is the rows
+    its prefill emitted for plus ``live`` of each of its ``engine.step``
+    phases (the tokens it emitted), ``row_steps`` its ``steps`` x ``batch``."""
+    phases, tokens, _ = engine_phases
+    prefills = [p for p in phases if p[0] == "engine.prefill"]
+    closes = [p for p in phases if p[0] == "engine.window_close"]
+    steps = [p for p in phases if p[0] == "engine.step"]
+    assert len(closes) == len(prefills) == 3
+    for k, (pre, close) in enumerate(zip(prefills, closes)):
+        c = close[3]
+        assert set(c) == {"steps", "rows", "batch", "live_row_steps",
+                          "row_steps", "us", "queued"}
+        mine = [st for st in steps if pre[2] <= st[1] and st[2] <= close[2]
+                or _inside([close], st)]
+        assert c["steps"] == len(mine) + 1
+        assert (c["rows"], c["batch"]) == (pre[3]["rows"], 2)
+        assert c["live_row_steps"] == pre[3]["rows"] + sum(
+            st[3]["live"] for st in mine)
+        assert c["row_steps"] == c["steps"] * c["batch"]
+        # open to close on the engine's clock is the phases' own span
+        assert abs(c["us"] * 1000 - (close[1] - pre[1])) < 1_000_000
+        # what it leaves waiting is what the next window finds, less its rows
+        if k + 1 < len(prefills):
+            nxt = prefills[k + 1][3]
+            assert c["queued"] == nxt["queued"] + nxt["rows"]
+    assert sum(c[3]["live_row_steps"] for c in closes) == tokens
+    # budgets 2, 3 | 4, 5 | 6 over two rows: 5 of 6, 9 of 10, 6 of 12
+    assert [(c[3]["live_row_steps"], c[3]["row_steps"]) for c in closes] == [
+        (5, 6), (9, 10), (6, 12)]
 
 
 @pytest.fixture(scope="module")
@@ -228,6 +318,7 @@ def paged_phases(tmp_path_factory):
         "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped",
         "chunks_fused", "chunks_alone", "mixed_steps", "chunk_pages_read",
         "chunk_pages_slot")}
+    counted.update(_histograms(before, after), trace_dir=trace_dir)
     return _phases(trace_dir), tokens, counted
 
 
@@ -273,8 +364,9 @@ def test_paged_step_holds_dispatch_readback_emit_in_order(paged_phases):
     phases, _, _ = paged_phases
     steps = [p for p in phases if p[0] == "engine.step"]
     assert len(steps) >= 8
+    tokens = [p for p in phases if p[0] == "engine.first_token"]
     for st in steps:
-        kids = _inside(phases, st)
+        kids = [k for k in _inside(phases, st) if k not in tokens]
         names = [k[0] for k in kids]
         issued = names[:1] == ["engine.dispatch"]
         reads = [k for k in kids if k[0] == "engine.readback"]
@@ -293,13 +385,17 @@ def test_paged_step_holds_dispatch_readback_emit_in_order(paged_phases):
             assert kids[issued + 1][3] == {"emitted": st[3]["live"]}
         if firsts:
             assert kids[-1][3] == {"emitted": firsts[0][3]["first"]}
+        # the first tokens are said where they are emitted, and nowhere else
+        assert len(_inside(tokens, st)) == sum(r[3]["first"] for r in firsts)
+        assert _inside(tokens, st) == (_inside(tokens, kids[-1])
+                                       if firsts else [])
     # budgets 2..6 over two slots: only the very first step finds nothing
     # in flight, and the last read has nothing left to issue
     ahead = [st[3]["ahead"] for st in steps]
     assert ahead == [0] + [1] * (len(steps) - 2) + [0]
     assert {p[0] for p in phases} == {
         "engine.prefill", "engine.step", "engine.dispatch",
-        "engine.readback", "engine.emit"}
+        "engine.readback", "engine.emit", "engine.first_token"}
 
 
 def test_paged_prefill_is_one_phase_a_chunk_and_holds_no_read(paged_phases):
@@ -328,6 +424,102 @@ def test_paged_step_ahead_counts_are_the_engines_step_counters(paged_phases):
     assert counted["steps_ahead"] == sum(st[3]["ahead"] for st in steps) == (
         len(dispatches) - 1)
     assert counted["steps_dropped"] == 0
+
+
+def test_paged_first_token_is_one_event_a_request_with_its_waits(
+        paged_phases):
+    phases, _, counted = paged_phases
+    _first_token_counts_hold(phases, counted, chunks=1)
+
+
+def test_paged_steps_say_which_program_was_issued_and_which_read(
+        paged_phases):
+    """``engine.step`` counts ``steps`` 1 where the iteration issued a step
+    (0 where it only read): sum(chunk) / sum(steps) is the engine's
+    ``mixed_steps`` / ``steps_issued``.  Every token-step ``engine.readback``
+    counts ``mixed``, the program of the step it READS (issued an iteration
+    earlier), and ``stats()`` splits the step samples the same way."""
+    phases, _, counted = paged_phases
+    steps = [p for p in phases if p[0] == "engine.step"]
+    assert all(st[3]["steps"] in (0, 1) and st[3]["chunk"] <= st[3]["steps"]
+               for st in steps)
+    assert sum(st[3]["steps"] for st in steps) == counted["steps_issued"]
+    assert sum(st[3]["chunk"] for st in steps) == counted["mixed_steps"] == 3
+    for st in steps:
+        issued = [k for k in _inside(phases, st) if k[0] == "engine.dispatch"]
+        assert st[3]["steps"] == len(issued)
+    reads = [p for p in phases if p[0] == "engine.readback"]
+    token_steps = [r for r in reads if "first" not in r[3]]
+    assert all(set(r[3]) == {"mixed"} and r[3]["mixed"] in (0, 1)
+               for r in token_steps)
+    assert all("mixed" not in r[3] for r in reads if "first" in r[3])
+    # a step is read in the iteration after the one that issued it
+    issued_mixed = [st[3]["chunk"] for st in steps if st[3]["steps"]]
+    assert [r[3]["mixed"] for r in token_steps] == issued_mixed
+    by_program = counted["by_program"]
+    assert by_program["mixed"][0] == sum(r[3]["mixed"] for r in token_steps)
+    assert by_program["decode"][0] == len(token_steps) - by_program["mixed"][0]
+    assert (by_program["decode"][0] + by_program["mixed"][0]
+            == counted["step_latency_s"][0])
+    assert by_program["decode"][1] + by_program["mixed"][1] == pytest.approx(
+        counted["step_latency_s"][1], abs=1e-9)
+
+
+def _read_metric(monkeypatch, tmp_path, trace_dir, name):
+    """A per-layer metric of ``BENCHMARK.json`` read from a real capture,
+    laid where the benchmark's traced run leaves its own."""
+    import shutil
+    from types import SimpleNamespace
+
+    from benchmark import harness, manifest
+
+    bench = manifest.Benchmark()
+    monkeypatch.setattr(manifest, "REPO", str(tmp_path))
+    shutil.copytree(trace_dir, os.path.join(
+        str(tmp_path), harness.TRACE_DIR, "cell"), dirs_exist_ok=True)
+    how = bench.load_json("layer_metrics", name + ".json")
+    # a CPU capture has no device plane to reduce: these readers ask only
+    # whether the run was traced
+    return bench.module("readers", how["reader"]).read(
+        SimpleNamespace(trace=object(), facts={}), **how["args"])
+
+
+def test_the_benchmarks_readers_read_the_window_engines_events(
+        engine_phases, monkeypatch, tmp_path):
+    phases, _, counted = engine_phases
+    firsts = [p[3] for p in phases if p[0] == "engine.first_token"]
+    def read(name):
+        return _read_metric(monkeypatch, tmp_path, counted["trace_dir"], name)
+
+    assert read("engine_window_live_row_share") == pytest.approx(
+        100.0 * 20 / 28)
+    waits = sorted(f["queue_us"] for f in firsts)
+    assert read("engine_queue_wait_ms_p50") == pytest.approx(waits[2] / 1000.0)
+    assert waits[3] / 1000.0 <= read("engine_queue_wait_ms_p95") <= (
+        waits[4] / 1000.0)
+    assert read("engine_first_prefill_ms_p50") == pytest.approx(
+        sorted(f["prefill_us"] for f in firsts)[2] / 1000.0)
+    # it issues no mixed step and marks no program: those read nothing here
+    assert read("engine_mixed_step_share") is None
+    assert read("engine_mixed_step_ms_p50") is None
+
+
+def test_the_benchmarks_readers_read_the_paged_engines_events(
+        paged_phases, monkeypatch, tmp_path):
+    phases, _, counted = paged_phases
+    def read(name):
+        return _read_metric(monkeypatch, tmp_path, counted["trace_dir"], name)
+
+    assert read("engine_mixed_step_share") == pytest.approx(
+        100.0 * counted["mixed_steps"] / counted["steps_issued"])
+    ends = [(p[2], p[3]["mixed"]) for p in phases
+            if p[0] == "engine.readback" and "mixed" in p[3]]
+    for mixed, name in ((0, "engine_decode_step_ms_p50"),
+                        (1, "engine_mixed_step_ms_p50")):
+        gaps = sorted(b[0] - a[0] for a, b in zip(ends, ends[1:])
+                      if b[1] == mixed)
+        assert gaps[0] / 1e6 <= read(name) <= gaps[-1] / 1e6
+    assert read("engine_window_live_row_share") is None
 
 
 def test_train_loop_phases_per_step_and_epoch(air, tmp_path):

@@ -370,3 +370,151 @@ def test_proxy_opens_root_span_without_inbound_header(air):
         assert roots and roots[0].parent_id is None  # fresh root
     finally:
         serve.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the engine's span tree: built at retirement from the request's one set of
+# monotonic stamps (submitted_at, admitted_at, first_token_at)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_lm():
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_air.models.lm import CausalLM, LMConfig
+
+    model = CausalLM(LMConfig.tiny())
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.ones((1, 8), jnp.int32))["params"]
+    return model, params
+
+
+def _traced_engine_run(tiny_lm, name, under_span):
+    """Three requests over two slots of a manual-step engine with airtrace
+    enabled; returns the admitted requests and the recorder's spans."""
+    from tpu_air.engine import EngineConfig, InferenceEngine
+
+    model, params = tiny_lm
+    tracing.enable()
+    engine = InferenceEngine(
+        model, params,
+        EngineConfig(num_slots=2, slot_len=64, max_new_tokens=4,
+                     eos_token_id=None),
+        auto_start=False, name=name)
+    admitted = []
+    pop = engine.scheduler.pop_admissible
+
+    def spy(*a, **kw):
+        out = pop(*a, **kw)
+        admitted.extend(out)
+        return out
+
+    engine.scheduler.pop_admissible = spy
+    prompts = [[5, 6, 7], [8, 9, 10, 11], [12, 13]]
+    root = None
+    if under_span:
+        with tracing.span("client.generate") as root:
+            engine.generate(prompts)
+    else:
+        engine.generate(prompts)
+    engine.close()
+    return admitted, tracing.recorder().recent(limit=0), root
+
+
+def _tree(spans, request_id):
+    """The span tree of one request: its root and the children by name."""
+    root = next(s for s in spans if s.name == "engine.request"
+                and s.attrs["request_id"] == request_id)
+    kids = {s.name: s for s in spans if s.parent_id == root.span_id}
+    return root, kids
+
+
+def test_engine_span_tree_names_parents_and_order(tiny_lm):
+    admitted, spans, client = _traced_engine_run(
+        tiny_lm, "trace-tree", under_span=True)
+    assert len(admitted) == 3
+    assert sorted(s.name for s in spans if s.name.startswith("engine.")) == (
+        sorted(["engine.request", "engine.queue_wait", "engine.prefill",
+                "engine.decode"] * 3))
+    for req in admitted:
+        assert req.trace_ctx["trace_id"] == client.trace_id
+        root, kids = _tree(spans, req.request_id)
+        assert root.trace_id == client.trace_id
+        assert root.parent_id == client.span_id
+        assert root.attrs == {"engine": "trace-tree",
+                              "request_id": req.request_id}
+        assert sorted(kids) == ["engine.decode", "engine.prefill",
+                                "engine.queue_wait"]
+        wait, prefill, decode = (kids["engine.queue_wait"],
+                                 kids["engine.prefill"], kids["engine.decode"])
+        assert all(k.trace_id == client.trace_id for k in kids.values())
+        # one timeline: the wait opens the request, prefill follows it to the
+        # first token, decode runs from there to retirement
+        assert root.start_ns == wait.start_ns <= wait.end_ns
+        assert wait.end_ns == prefill.start_ns < prefill.end_ns
+        assert prefill.end_ns == decode.start_ns <= decode.end_ns
+        assert decode.end_ns == root.end_ns
+        assert wait.attrs == {}
+        assert prefill.attrs == {
+            "slot": prefill.attrs["slot"], "prompt_len": len(req.prompt),
+            "chunks": 1, "prefix_hit": False, "prefix_tokens": 0}
+        assert decode.attrs["tokens"] == 4
+        assert decode.attrs["slot"] == prefill.attrs["slot"] in (0, 1)
+        assert decode.attrs["occupancy"] >= 1
+
+
+def test_engine_span_tree_is_the_requests_one_set_of_stamps(tiny_lm):
+    """The spans' durations are the differences of the request's monotonic
+    stamps (what ``stats()`` and ``engine.first_token`` read), moved onto the
+    wall clock by one offset: to the nanosecond a float of seconds keeps."""
+    import time
+
+    admitted, spans, _ = _traced_engine_run(
+        tiny_lm, "trace-stamps", under_span=True)
+    now_wall, now_mono = time.time_ns(), time.monotonic()
+    for req in admitted:
+        root, kids = _tree(spans, req.request_id)
+        wait, prefill = kids["engine.queue_wait"], kids["engine.prefill"]
+        assert wait.end_ns - wait.start_ns == pytest.approx(
+            1e9 * (req.admitted_at - req.submitted_at), abs=2)
+        assert prefill.end_ns - prefill.start_ns == pytest.approx(
+            1e9 * (req.first_token_at - req.admitted_at), abs=2)
+        # and on the wall clock they lie where the stamps were taken (the
+        # two clocks drift by far less than this over a test)
+        assert root.start_ns == pytest.approx(
+            now_wall - 1e9 * (now_mono - req.submitted_at), abs=5e7)
+
+
+def test_enabled_airtrace_with_no_carrier_still_emits_the_tree(tiny_lm):
+    """No active span at submit: the carrier is empty, not None, and the
+    request is traced all the same, as a fresh root trace of its own."""
+    admitted, spans, _ = _traced_engine_run(
+        tiny_lm, "trace-no-carrier", under_span=False)
+    assert [req.trace_ctx for req in admitted] == [{}] * 3
+    roots = [s for s in spans if s.name == "engine.request"]
+    assert len(roots) == 3 and all(r.parent_id is None for r in roots)
+    assert len({r.trace_id for r in roots}) == 3
+    for req in admitted:
+        root, kids = _tree(spans, req.request_id)
+        assert sorted(kids) == ["engine.decode", "engine.prefill",
+                                "engine.queue_wait"]
+        assert all(k.trace_id == root.trace_id for k in kids.values())
+
+
+def test_disabled_airtrace_leaves_stamps_and_no_carrier(tiny_lm):
+    from tpu_air.engine import Request, ResponseStream, Scheduler
+    from tpu_air.engine import EngineConfig
+
+    assert not tracing.enabled()
+    s = Scheduler(EngineConfig(num_slots=2))
+    req = Request(request_id=0, prompt=[1, 2], max_new_tokens=2,
+                  stream=ResponseStream(0))
+    s.submit(req)
+    assert req.trace_ctx is None and req.admitted_at is None
+    (out,) = s.pop_admissible(1)
+    assert out is req and req.admitted_at >= req.submitted_at
+    assert not any(hasattr(req, k)
+                   for k in ("t_submit_ns", "t_admit_ns", "t_first_ns"))
+    assert len(tracing.recorder()) == 0

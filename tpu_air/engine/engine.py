@@ -259,7 +259,8 @@ class InferenceEngine:
 
         self.scheduler = Scheduler(cfg)
         self.slots = SlotManager(cfg.num_slots)
-        self.metrics = EngineMetrics(name=name, num_slots=cfg.num_slots)
+        self.metrics = EngineMetrics(name=name, num_slots=cfg.num_slots,
+                                     programs=("decode", "mixed"))
         if self._latent:
             # the positions a decode step's gather writes out, live or not
             self.metrics.set_latent_pool(cfg.num_slots * cfg.slot_len)
@@ -786,14 +787,12 @@ class InferenceEngine:
             self._fail_admission(slot, req, e)
             return
         first = int(req.prefilled["first_token"])
-        req.first_token_at = time.monotonic()
-        if req.t_submit_ns:
-            # t_first == t_admit: the > guard in _emit_request_spans keeps
-            # the (remote) prefill from double-reporting as a local span
-            req.t_first_ns = req.t_admit_ns
-        self.metrics.record_ttft(req.first_token_at - req.submitted_at,
-                                 req.priority,
-                                 trace_id=(req.trace_ctx or {}).get("trace_id"))
+        # first token at the admission: the prefill ran elsewhere and reads
+        # 0 here (and the > guard in _emit_request_spans keeps the remote
+        # prefill from double-reporting as a local span)
+        self.metrics.record_ttft(
+            *req.first_token(req.admitted_at, chunks=0), req.priority,
+            trace_id=(req.trace_ctx or {}).get("trace_id"))
         req.stream._emit(first)
         self.metrics.record_tokens(1)
         self.pool.register(slot.index, req.prompt)
@@ -843,11 +842,9 @@ class InferenceEngine:
         except ValueError as e:  # KVTransferError: payload does not fit
             self._fail_admission(slot, req, e)
             return
-        req.first_token_at = time.monotonic()
-        if req.t_submit_ns:
-            # t_first == t_admit: the > guard in _emit_request_spans keeps
-            # the (source-replica) prefill from re-reporting here
-            req.t_first_ns = req.t_admit_ns
+        # first token at the admission, as in _admit_prefilled (the source
+        # replica's prefill does not re-report here); TTFT was counted there
+        req.first_token(req.admitted_at, chunks=0)
         streamed = m["streamed"]
         for tok in streamed:
             # already counted and TTFT-stamped on the source — replayed
@@ -1018,11 +1015,10 @@ class InferenceEngine:
         with phase("engine.emit", emitted=len(firsts)):
             for (slot, _), first in zip(firsts, tokens):
                 req = slot.request
-                req.first_token_at = time.monotonic()
-                if req.t_submit_ns:  # traced request: TTFT for span emission
-                    req.t_first_ns = _tracing.now_ns()
                 self.metrics.record_ttft(
-                    req.first_token_at - req.submitted_at, req.priority,
+                    *req.first_token(time.monotonic(),
+                                     chunks=len(slot.plan.chunk_starts)),
+                    req.priority,
                     trace_id=(req.trace_ctx or {}).get("trace_id"))
                 req.stream._emit(first)
                 if slot.budget_left == 0 or (
@@ -1236,7 +1232,8 @@ class InferenceEngine:
                   if self._recurrent and rows else {})
         with phase("engine.step", live=len(reading if unread else rows),
                    batch=self.config.num_slots, ahead=int(ahead),
-                   chunk=int(chunk is not None), **passed):
+                   steps=int(bool(rows)), chunk=int(chunk is not None),
+                   **passed):
             if rows:
                 # out before step N is read: the device runs it while the
                 # host reads, emits, retires and admits
@@ -1364,7 +1361,9 @@ class InferenceEngine:
         it decoded that are still the requests it decoded them for.  The
         first token of a prompt whose last chunk rode the step is ready with
         it: queued for ``_read_firsts``."""
-        with phase("engine.readback"):
+        # the program of the step being READ (issued an iteration earlier)
+        mixed = step.chunk_start is not None
+        with phase("engine.readback", mixed=int(mixed)):
             nxt = np.asarray(step.out)
         if step.first is not None:
             self._firsts.append(step.first)
@@ -1374,7 +1373,7 @@ class InferenceEngine:
         dt, self._mark = now - self._mark, now
         if self._cost_model is not None:
             kind, cost = "decode_step", self._decode_cost
-            if step.chunk_start is not None:
+            if mixed:
                 cfg = self.config
                 kind, cost = "mixed_step", self._cost_model.mixed_step_cost(
                     cfg.num_slots, cfg.slot_len, cfg.page_len,
@@ -1389,7 +1388,7 @@ class InferenceEngine:
             if len(counts) > self.model.config.experts_held:
                 counts, elsewhere = counts[:-1], int(counts[-1])
             self.metrics.record_routing(counts, int(nxt[-1]), elsewhere,
-                                        chunk=step.chunk_start is not None)
+                                        chunk=mixed)
         if self._latent:
             # what the step's absorbed read had live: each decoding row's
             # positions up to the one this token was computed at, and the
@@ -1412,11 +1411,11 @@ class InferenceEngine:
                     and token == self.eos_token_id
                 ):
                     self._retire(slot)
-            self.metrics.record_step(dt, len(reading))
+            self.metrics.record_step(dt, len(reading), mixed=mixed)
 
     # -- retirement ----------------------------------------------------------
     def _retire(self, slot: Slot) -> None:
-        if slot.request.t_submit_ns:
+        if slot.request.trace_ctx is not None:  # submitted under airtrace
             self._emit_request_spans(slot)
         slot.request.stream._finish()
         self.metrics.record_complete()
@@ -1448,28 +1447,34 @@ class InferenceEngine:
     def _emit_request_spans(self, slot: Slot) -> None:
         """Retirement-time airtrace emission: the request's whole span tree
         (queue-wait → prefill → decode residency) is reconstructed here from
-        the wall-clock stamps collected along the way, so the decode hot
-        loop does zero tracing work (and stays JX004-clean)."""
+        the request's one set of monotonic stamps, moved onto the spans'
+        wall clock by one offset taken now, so the decode hot loop does zero
+        tracing work (and stays JX004-clean)."""
         req = slot.request
         end = _tracing.now_ns()
+        wall = end - int(time.monotonic() * 1e9)
+
+        def ns(stamp: float) -> int:
+            return wall + int(stamp * 1e9)
+
         ctx = req.trace_ctx or {}
         root = _tracing.record_span(
             "engine.request",
             trace_id=ctx.get("trace_id"),
             parent_id=ctx.get("span_id"),
-            start_ns=req.t_submit_ns,
+            start_ns=ns(req.submitted_at),
             end_ns=end,
             attrs={"engine": self.name, "request_id": req.request_id},
         )
-        if req.t_admit_ns:
-            _tracing.record_span(
-                "engine.queue_wait",
-                trace_id=root.trace_id, parent_id=root.span_id,
-                start_ns=req.t_submit_ns, end_ns=req.t_admit_ns,
-            )
-        if req.t_admit_ns and req.t_first_ns > req.t_admit_ns:
-            # strictly-after: a disaggregated request lands with t_first ==
-            # t_admit (its prefill span was recorded on the worker replica)
+        _tracing.record_span(
+            "engine.queue_wait",
+            trace_id=root.trace_id, parent_id=root.span_id,
+            start_ns=ns(req.submitted_at), end_ns=ns(req.admitted_at),
+        )
+        if req.first_token_at > req.admitted_at:
+            # strictly-after: a disaggregated request lands with its first
+            # token AT its admission (its prefill span was recorded on the
+            # worker replica)
             attrs = {"slot": slot.index, "prompt_len": len(req.prompt)}
             if slot.plan is not None:
                 attrs["chunks"] = len(slot.plan.chunk_starts)
@@ -1478,20 +1483,19 @@ class InferenceEngine:
             _tracing.record_span(
                 "engine.prefill",
                 trace_id=root.trace_id, parent_id=root.span_id,
-                start_ns=req.t_admit_ns, end_ns=req.t_first_ns,
+                start_ns=ns(req.admitted_at), end_ns=ns(req.first_token_at),
                 attrs=attrs,
             )
-        if req.t_first_ns:
-            _tracing.record_span(
-                "engine.decode",
-                trace_id=root.trace_id, parent_id=root.span_id,
-                start_ns=req.t_first_ns, end_ns=end,
-                attrs={
-                    "slot": slot.index,
-                    "tokens": slot.pos - len(req.prompt) + 1,
-                    "occupancy": self.slots.occupancy(),
-                },
-            )
+        _tracing.record_span(
+            "engine.decode",
+            trace_id=root.trace_id, parent_id=root.span_id,
+            start_ns=ns(req.first_token_at), end_ns=end,
+            attrs={
+                "slot": slot.index,
+                "tokens": slot.pos - len(req.prompt) + 1,
+                "occupancy": self.slots.occupancy(),
+            },
+        )
 
     # -- background loop / lifecycle -----------------------------------------
     def start(self) -> None:

@@ -24,7 +24,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Deque, Dict, Optional
+from typing import Any, Deque, Dict, Optional, Sequence
 
 from collections import deque
 
@@ -49,7 +49,8 @@ _RATE_WINDOW_S = 10.0  # tokens/s horizon
 class EngineMetrics:
     """Thread-safe gauges/counters for one engine instance."""
 
-    def __init__(self, name: str = "engine", num_slots: int = 0):
+    def __init__(self, name: str = "engine", num_slots: int = 0,
+                 programs: Sequence[str] = ("decode",)):
         self.name = name
         self.num_slots = num_slots
         self._lock = threading.Lock()
@@ -80,7 +81,20 @@ class EngineMetrics:
         self.deadline_expired = 0
         # distributions / rates
         self._ttft_h = Histogram()
+        # TTFT's two parts, a sample each a request: submit to admission,
+        # admission to first token (they sum to the TTFT sample exactly)
+        self._queue_wait_h = Histogram()
+        self._prefill_h = Histogram()
+        self._queue_wait_by_class: Dict[str, Histogram] = {
+            p: Histogram() for p in PRIORITIES
+        }
         self._step_h = Histogram()
+        # the same step samples by the program the step read was (the
+        # engine names its token-step ``programs``: InferenceEngine has a
+        # second, the mixed step that carries a prefill chunk)
+        self._step_by_program: Dict[str, Histogram] = {
+            p: Histogram() for p in programs
+        }
         self._token_stamps: Deque[Any] = deque()  # (t, n) for tokens/s
         # roofline + goodput accumulator (engine records program costs)
         self.ledger = PerfLedger(detect_peak())
@@ -257,15 +271,23 @@ class EngineMetrics:
         with self._lock:
             self._tenant(adapter_id)["migrated_pages"] += int(pages)
 
-    def record_ttft(self, seconds: float, priority: str = "interactive",
+    def record_ttft(self, queue_wait_s: float, prefill_s: float,
+                    priority: str = "interactive",
                     trace_id: Optional[str] = None) -> None:
-        """A first-token latency sample.  ``trace_id`` (when the request
-        was traced) becomes the histogram bucket's exemplar — the join key
-        from a dashboard tail-latency number to ``/api/traces?trace_id=``."""
+        """A first-token latency sample, as its two parts: the wait for
+        admission and admission to first token, from the request's stamps
+        (``Request.first_token``); the TTFT sample is their sum.
+        ``trace_id`` (when the request was traced) becomes the TTFT
+        histogram bucket's exemplar — the join key from a dashboard
+        tail-latency number to ``/api/traces?trace_id=``."""
+        seconds = queue_wait_s + prefill_s
         with self._lock:
             self._ttft_h.observe(seconds, trace_id)
+            self._queue_wait_h.observe(queue_wait_s)
+            self._prefill_h.observe(prefill_s)
             if priority in self._ttft_by_class:
                 self._ttft_by_class[priority].observe(seconds, trace_id)
+                self._queue_wait_by_class[priority].observe(queue_wait_s)
 
     def record_tokens(self, tokens: int) -> None:
         """Count emitted tokens outside a pool step (prefill's first token)."""
@@ -275,10 +297,16 @@ class EngineMetrics:
             self._token_stamps.append((now, tokens))
             self._trim_stamps(now)
 
-    def record_step(self, seconds: float, tokens: int) -> None:
+    def record_step(self, seconds: float, tokens: int,
+                    mixed: bool = False) -> None:
+        """One token step read back: what it cost the stream (read-back to
+        read-back) and the tokens it emitted; ``mixed`` when the step READ
+        was a mixed step (the engine keeps the bit with the unread step)."""
         now = time.monotonic()
         with self._lock:
             self._step_h.observe(seconds)
+            self._step_by_program[
+                "mixed" if mixed else "decode"].observe(seconds)
             self.tokens_emitted += tokens
             self._token_stamps.append((now, tokens))
             self._trim_stamps(now)
@@ -407,11 +435,12 @@ class EngineMetrics:
         stay).  For benches that warm jit caches through the engine and
         then measure a clean steady-state window."""
         with self._lock:
-            self._ttft_h.reset()
-            self._step_h.reset()
-            self._token_stamps.clear()
-            for h in self._ttft_by_class.values():
+            for h in (self._ttft_h, self._queue_wait_h, self._prefill_h,
+                      self._step_h, *self._step_by_program.values(),
+                      *self._ttft_by_class.values(),
+                      *self._queue_wait_by_class.values()):
                 h.reset()
+            self._token_stamps.clear()
             self.ledger.reset()
 
     # -- dashboard-side ------------------------------------------------------
@@ -437,7 +466,12 @@ class EngineMetrics:
                 "requests_completed": self.requests_completed,
                 "tokens_emitted": self.tokens_emitted,
                 "ttft_s": self._ttft_h.summary(),
+                "queue_wait_s": self._queue_wait_h.summary(),
+                "prefill_s": self._prefill_h.summary(),
                 "step_latency_s": self._step_h.summary(),
+                "step_latency_by_program_s": {
+                    k: h.summary()
+                    for k, h in self._step_by_program.items()},
                 "draining": self.draining,
                 "deadline_expired": self.deadline_expired,
                 "priority": {
@@ -447,6 +481,8 @@ class EngineMetrics:
                         "quota_shed": self.quota_shed_by_class[p],
                         "queue_depth": self.queue_by_class.get(p, 0),
                         "ttft_s": self._ttft_by_class[p].summary(),
+                        "queue_wait_s":
+                            self._queue_wait_by_class[p].summary(),
                     }
                     for p in PRIORITIES
                 },
@@ -539,9 +575,8 @@ def merge_snapshots(snapshots: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
         out[key] = sum(int(s.get(key, 0)) for s in snaps)
     out["tokens_per_s"] = sum(float(s.get("tokens_per_s", 0.0))
                               for s in snaps)
-    out["ttft_s"] = merge_summaries([s.get("ttft_s") or {} for s in snaps])
-    out["step_latency_s"] = merge_summaries(
-        [s.get("step_latency_s") or {} for s in snaps])
+    for key in ("ttft_s", "queue_wait_s", "prefill_s", "step_latency_s"):
+        out[key] = merge_summaries([s.get(key) or {} for s in snaps])
     prio: Dict[str, Any] = {}
     for p in PRIORITIES:
         entries = [(s.get("priority") or {}).get(p) or {} for s in snaps]

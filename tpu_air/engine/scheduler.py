@@ -78,10 +78,9 @@ class Scheduler:
                 f"(expected one of {PRIORITIES})"
             )
         if _tracing.enabled():
-            # stamp outside the lock: carrier + submit time feed the
-            # retirement-time span emission (engine._emit_request_spans)
-            request.trace_ctx = _tracing.current_propagation()
-            request.t_submit_ns = _tracing.now_ns()
+            # outside the lock: the carrier marks the request as traced for
+            # the retirement-time span emission (engine._emit_request_spans)
+            request.trace_ctx = _tracing.current_propagation() or {}
         with self._lock:
             depth = sum(len(q) for q in self._queues.values())
             cap = self.config.queue_cap(request.priority)
@@ -111,7 +110,8 @@ class Scheduler:
         considered in queue order (head-of-line relief); out-of-order
         takes are counted in :attr:`reordered_admits`.  A class whose
         head stays blocked ends the round — lower classes must not claim
-        the capacity it is waiting for."""
+        the capacity it is waiting for.  Every request handed out is stamped
+        ``admitted_at`` from one ``time.monotonic()`` reading of the round."""
         out: List[Request] = []
         window = getattr(self.config, "reorder_window", 0)
         with self._lock:
@@ -144,11 +144,12 @@ class Scheduler:
                     self._deadlines -= 1
             if not any(self._queues.values()):
                 self._work.clear()
-        if _tracing.enabled() and out:
-            t = _tracing.now_ns()
+        if out:
+            # one clock reading a round: where a request's queue wait ends
+            # and its prefill begins
+            now = time.monotonic()
             for r in out:
-                if r.t_submit_ns:
-                    r.t_admit_ns = t
+                r.admitted_at = now
         return out
 
     def _sweep_expired_locked(self) -> None:

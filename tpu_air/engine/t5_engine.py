@@ -116,10 +116,14 @@ class _Window:
     step the host has not read back yet: the next step's ``tok``, and what
     the next read-back copies.  ``mark`` is when the stream's current token
     step began: the end of the last read-back, or for a window's first step
-    its issue."""
+    its issue.  What the window did, said once as it closes
+    (``engine.window_close``): ``opened_at``, the ``rows`` it admitted, the
+    token ``steps`` it emitted from (its prefill and every step read back)
+    and ``live_row_steps``, the rows live in each of them summed (the
+    tokens it emitted)."""
 
     def __init__(self, requests: List[Optional[Request]], cache, enc,
-                 enc_mask):
+                 enc_mask, opened_at: float):
         self.requests = requests
         self.cache = cache
         self.enc = enc
@@ -127,6 +131,9 @@ class _Window:
         self.unread = None
         self.mark = 0.0
         self.budget_left = np.zeros((len(requests),), np.int64)
+        self.opened_at = opened_at
+        self.rows = self.live_row_steps = len(self.live_rows())
+        self.steps = 1
 
     def live_rows(self):
         return [i for i, r in enumerate(self.requests) if r is not None]
@@ -285,25 +292,28 @@ class T5Engine:
             self.params, jnp.asarray(ids), mask_dev)
         tok = np.asarray(tok_dev)
         rows: List[Optional[Request]] = list(reqs) + [None] * (b - len(reqs))
-        win = _Window(rows, cache, enc, mask_dev)
+        # every row was admitted by one round: one reading opened the window
+        win = _Window(rows, cache, enc, mask_dev, reqs[0].admitted_at)
         now = time.monotonic()
         emitted = 0
         for row, req in enumerate(reqs):
             first = int(tok[row])
-            req.first_token_at = now
-            self.metrics.record_ttft(now - req.submitted_at)
+            self.metrics.record_ttft(*req.first_token(now, chunks=1),
+                                     req.priority)
             req.stream._emit(first)
             emitted += 1
             win.budget_left[row] = req.max_new_tokens - 1
             if win.budget_left[row] == 0 or first == self.eos_token_id:
                 self._retire(win, row)
         self.metrics.record_tokens(emitted)
+        self._window = win
         if win.live_rows():
             # the window's first step, from the prefill's tokens as they
             # lie on the device; nothing is in flight, so it is not ahead
             self._issue(win, tok_dev, ahead=False)
             win.mark = time.monotonic()
-            self._window = win
+        else:
+            self._drop_window()  # every row ended on its first token
 
     def _issue(self, win: _Window, tok, ahead: bool) -> None:
         """Issue one decode step from the device tokens ``tok``.  The cache
@@ -343,6 +353,8 @@ class T5Engine:
                     if win.budget_left[row] == 0 or token == self.eos_token_id:
                         self._retire(win, row)
                 self.metrics.record_step(dt, len(live))
+                win.steps += 1
+                win.live_row_steps += len(live)
                 if not win.live_rows():
                     # window drained: drop its cache, admit the next batch
                     # on the following step
@@ -351,10 +363,20 @@ class T5Engine:
     def _drop_window(self) -> None:
         """Close the window.  A step still unread (its last live rows ended
         on EOS, or the engine is closing) is dropped with the cache: the
-        device finishes it and whatever comes next queues behind."""
-        if self._window.unread is not None:
+        device finishes it and whatever comes next queues behind.  One
+        ``engine.window_close`` says what the whole window did: how many of
+        its ``steps`` x ``batch`` row-steps had a live row, how long it was
+        open and the depth it left waiting."""
+        win, self._window = self._window, None
+        if win.unread is not None:
             self.metrics.record_dropped_step()
-        self._window = None
+        batch = self.config.max_batch
+        with phase("engine.window_close", steps=win.steps, rows=win.rows,
+                   batch=batch, live_row_steps=win.live_row_steps,
+                   row_steps=win.steps * batch,
+                   us=int((time.monotonic() - win.opened_at) * 1e6),
+                   queued=self.scheduler.depth()):
+            pass
 
     def _retire(self, win: _Window, row: int) -> None:
         win.requests[row].stream._finish()

@@ -12,9 +12,10 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Iterator, List, Optional, Sequence, Union
+from typing import Iterator, List, Optional, Sequence, Tuple, Union
 
 from tpu_air.core.runtime import TpuAirError
+from tpu_air.observability.profiler import phase
 
 
 #: SLO priority classes, highest first.  Admission pops classes in this
@@ -236,15 +237,18 @@ class Request:
     # SLO class (one of PRIORITIES): admission pops interactive first each
     # step, and the scheduler sheds the tail classes at lower queue depths
     priority: str = "interactive"
+    # the request's one set of stamps (``time.monotonic()``): made here,
+    # handed out by the scheduler (``pop_admissible`` stamps every request
+    # of a round with one reading), first token emitted.  ``stats()``'
+    # ``queue_wait_s`` / ``prefill_s`` / ``ttft_s``, the ``engine.first_token``
+    # phase and the airtrace span tree are all read from these three.
     submitted_at: float = field(default_factory=time.monotonic)
+    admitted_at: Optional[float] = None
     first_token_at: Optional[float] = None
-    # airtrace: carrier captured at submit + wall-clock stamps (ns) for the
-    # retirement-time span emission (engine.py _emit_request_spans).  All
-    # zero/None when tracing is off — the hot loop never touches them.
+    # airtrace: the carrier captured at submit while tracing is enabled ({}
+    # where no span was active).  Not None says the request is traced: its
+    # span tree is built at retirement (engine.py _emit_request_spans).
     trace_ctx: Optional[dict] = None
-    t_submit_ns: int = 0
-    t_admit_ns: int = 0
-    t_first_ns: int = 0
     # disaggregated serving (engine/dist/): a request whose prefill ran on
     # a PrefillWorker replica arrives with its first token and the prompt's
     # KV pages ({"first_token": int, "pages": {layer_path: {"k", "v"}}});
@@ -279,3 +283,20 @@ class Request:
     # ``adapter_id`` it is never validated (a pure label, not a bank row);
     # billing uses ``tenant or adapter_id``.
     tenant: Optional[str] = None
+
+    def first_token(self, at: float, chunks: int) -> Tuple[float, float]:
+        """The request's first token was emitted at ``at``, after ``chunks``
+        prefill chunks on this engine (0: its prefill ran elsewhere and
+        ``at`` is its ``admitted_at``).  Stamps it, puts one
+        ``engine.first_token`` event on a live profiler capture
+        (docs/OBSERVABILITY.md) and returns the two parts of the request's
+        TTFT in seconds, ``(queue_wait, prefill)``: submit to admission and
+        admission to first token, what ``EngineMetrics.record_ttft`` takes."""
+        self.first_token_at = at
+        queue_s = self.admitted_at - self.submitted_at
+        prefill_s = at - self.admitted_at
+        with phase("engine.first_token", queue_us=int(queue_s * 1e6),
+                   prefill_us=int(prefill_s * 1e6), prompt=len(self.prompt),
+                   chunks=chunks):
+            pass
+        return queue_s, prefill_s
