@@ -10,8 +10,8 @@ that quietly took interpret mode fails.  A compile that passes is not a chip
 run: numbers and results come from ``chip_smoke.py`` and ``-m tpu``.
 
 The next two tests compile the flat decode attention, ``generate`` at the W3
-shape and the engine's step at the serving shape, and read what the compiler
-made of the decode cache's layout (no kernel in them).  The last compile
+shape and the engine's step and admit programs at the serving shape, and read
+what the compiler made of the decode cache's layout (no kernel in them).  The last compile
 ``T5Trainer``'s train step at the fine-tune cells' shapes and count what a
 dropout mask element costs in random bits, on one chip and on ``data=4``.
 """
@@ -277,26 +277,33 @@ def _generate_w3(devs, early_stop):
         params, ids, ids, _struct((2,), jnp.uint32, devs)), (256, 12, 64, 512)
 
 
-def _engine_step(devs):
-    """``T5Engine``'s donated-cache step at the ``t5large-serve`` shape:
-    FLAN-T5-large, a window of 64 x 512, a cache for 128 new tokens."""
+def _engine_program(devs, rows=None, admit=None):
+    """One of ``T5Engine``'s donated-state programs at the ``t5large-serve``
+    shape (FLAN-T5-large, 64 slots x 512, a ring for 128 new tokens): the
+    step over the first ``rows`` slots, or the admit program of length
+    ``admit``."""
     from tpu_air.models.t5.generate import (
-        make_t5_decode_step_fn, make_t5_prefill_fn)
+        init_slot_state, make_t5_admit_fn, make_t5_slot_step_fn)
 
     model, params = _t5_on(devs, "flan_t5_large")
-    ids = _struct((64, 512), jnp.int32, devs)
-    tok, cache, enc = jax.tree_util.tree_map(
+    state, tok = jax.tree_util.tree_map(
         lambda s: _struct(s.shape, s.dtype, devs),
-        jax.eval_shape(make_t5_prefill_fn(model, 129), params, ids, ids))
-    return make_t5_decode_step_fn(model).lower(
-        params, cache, tok, enc, ids), (64, 16, 64, 512)
+        jax.eval_shape(lambda p: init_slot_state(model, p, 64, 129, 512),
+                       params))
+    if admit is None:
+        return make_t5_slot_step_fn(model, rows).lower(
+            params, state, tok), (64, 16, 64, 512)
+    return make_t5_admit_fn(model, admit).lower(
+        params, state, tok, _struct((1, admit + 2), jnp.int32, devs))
 
 
 # program -> (builder, the most its temporaries may take)
 DECODE_PROGRAMS = {
     "while": (lambda d: _generate_w3(d, True), 7.5e9),
     "scan": (lambda d: _generate_w3(d, False), 7.5e9),
-    "engine_step": (_engine_step, 0.15e9),
+    "engine_step16": (lambda d: _engine_program(d, rows=16), 0.15e9),
+    "engine_step32": (lambda d: _engine_program(d, rows=32), 0.15e9),
+    "engine_step64": (lambda d: _engine_program(d, rows=64), 0.15e9),
 }
 
 _HBM = r"\{[\d,]*:T\(8,128\)\(2,1\)\}"       # a tiled layout outside fast memory
@@ -351,9 +358,10 @@ def test_slab_counters_see_what_the_parent_programs_did():
 
 @pytest.mark.parametrize("program", sorted(DECODE_PROGRAMS))
 def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
-    """The three programs that carry the T5 decode cache, as the chip's
-    compiler makes them: ``generate`` at the W3 shape under both loop forms
-    and the engine's donated step at the ``t5large-serve`` shape.
+    """The programs that carry the T5 decode cache, as the chip's compiler
+    makes them: ``generate`` at the W3 shape under both loop forms and the
+    engine's donated step at the ``t5large-serve`` shape, over each prefix of
+    its 64 slots it can be issued for.
 
     Cross slabs: ``bf16[b, h, 64, 512]`` laid out length-minor as stored
     (``{3,2,1,0:T(8,128)(2,1)}``: (64, 512) are whole tiles), nothing copies
@@ -367,7 +375,17 @@ def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
     moved; 10 of large's 48 in the engine's step, where a few still are), and
     the one-step program makes no slab-sized array anew, so it copies no
     slice of a cache parameter out before reading it (34 of 48 where one
-    array held all layers' slabs, 0.58 GB of temporaries).
+    array held all layers' slabs, 0.58 GB of temporaries).  That count
+    hangs on how the step's tokens leave it: as a second result cut from a
+    donated token vector, 47 of the 64-row step's 48 slabs are held and
+    written back (``init_slot_state``); beside the donated state, 2 or 3.
+
+    A step over a PREFIX of the slots reads the cross slabs' first rows in
+    place, inside the fusions that contract them.  The self slabs' first rows
+    it copies out before reading them, one ``[129, rows, 1024]`` slice a slab:
+    48 x 4.2 MB = 203 MB a 16-row step, about 0.4 ms of the chip's 819 GB/s
+    read and written.  That is the next thing to take (PERF.md section 7),
+    and it is counted here so that taking it shows.
 
     The temporaries stay near the cache's own 6 GB; with the dense path under
     the while-loop they were 14.1 GB (PERF.md, PR 25).  tests/test_t5.py
@@ -382,11 +400,14 @@ def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
     name = f"bf16[{b},{h},{d},{L}]"
     layouts = [lay for ln in text.splitlines() if "cross_attn" in ln
                for lay in re.findall(re.escape(name) + r"\{([^}S]*)", ln)]
-    layers = 12 if program != "engine_step" else 24
+    engine = program.startswith("engine_step")
+    rows = int(program[len("engine_step"):]) if engine else b
+    layers = 24 if engine else 12
     assert len(layouts) >= 2 * layers, len(layouts)
     assert set(layouts) == {"3,2,1,0:T(8,128)(2,1)"}, set(layouts)
     dims = "|".join(",".join(map(str, p))
-                    for p in set(itertools.permutations(cross)))
+                    for whole in {cross, (rows,) + cross[1:]}
+                    for p in set(itertools.permutations(whole)))
     moved = re.findall(
         rf"(?:bf16|f32)\[(?:{dims})\]\{{[^}}]*\}} (?:copy|transpose)\(.*", text)
     assert not moved, moved[:4]
@@ -396,11 +417,38 @@ def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
     slab = _self_slab(b, h * d)
     assert re.search(slab, text), "no self slab of the expected shape"
     written_back = _slabs_written_back(text, slab)
-    if program == "engine_step":
+    if engine:
         assert written_back <= 4, written_back
         assert _slabs_made_anew(text, slab) == written_back
+        prefix = rf"= bf16\[129,{rows},{h * d}\]\{{[^}}]*\}} slice-done\("
+        assert len(re.findall(prefix, text)) == (48 if rows < b else 0)
     else:
         assert written_back == 0, written_back
+
+
+@pytest.mark.parametrize("length", [128, 512])
+def test_engine_admit_writes_its_slots_rows_in_place_on_v5e(v5e, length):
+    """``T5Engine``'s admit program at the ``t5large-serve`` shape: the
+    state is donated and the admitted prompt's cross K/V go into its slot's
+    row of the 48 ``[64, 16, 64, 512]`` slabs by ``dynamic-update-slice`` on
+    the parameter itself: no slab-sized array is made anew (a copy of one is
+    67 MB, 3.2 GB for all), no self slab is touched, and the temporaries are
+    the encoder's own."""
+    compiled = _engine_program(v5e, admit=length).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    made = re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = bf16\[64,16,64,512\]\S* ([\w-]+)\(", entry,
+        flags=re.M)
+    anew = [(name, op) for name, op in made
+            if op not in ("parameter", "bitcast", "get-tuple-element")
+            and "dynamic-update-slice" not in name]
+    assert not anew, anew[:4]
+    assert sum("dynamic-update-slice" in name for name, _ in made) == 48
+    assert _slabs_made_anew(text, _self_slab(64, 1024)) == 0
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.2e9
+    assert memory.alias_size_in_bytes > 4.0e9      # the state, in place
 
 
 # -- the train step's dropout masks (PR 37) ----------------------------------

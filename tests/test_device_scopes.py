@@ -144,19 +144,24 @@ def _t5_train_step():
         params, tx.init(params), batch, dropout_key(0)).compile()
 
 
-def _t5_decode_step():
+def _t5_slots(rows=None, admit=None):
+    """One of ``T5Engine``'s programs over four slots: the step over the
+    first ``rows`` of them, or the admit program of length ``admit``."""
     from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
-    from tpu_air.models.t5.generate import (make_t5_decode_step_fn,
-                                            make_t5_prefill_fn)
+    from tpu_air.models.t5.generate import (init_slot_state, make_t5_admit_fn,
+                                            make_t5_slot_step_fn)
 
     model = T5ForConditionalGeneration(T5Config.tiny())
     one = jnp.ones((1, 8), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), one, one, one[:, :4])["params"]
-    ids = jnp.ones((2, 16), jnp.int32)
-    tok, cache, enc = jax.eval_shape(make_t5_prefill_fn(model, 9),
-                                     params, ids, ids)
-    return make_t5_decode_step_fn(model).lower(
-        params, cache, tok, enc, ids).compile()
+    state, tok = jax.eval_shape(
+        lambda p: init_slot_state(model, p, 4, 9, 16), params)
+    if admit is None:
+        return make_t5_slot_step_fn(model, rows).lower(
+            params, state, tok).compile()
+    return make_t5_admit_fn(model, admit).lower(
+        params, state, tok,
+        jax.ShapeDtypeStruct((1, admit + 2), jnp.int32)).compile()
 
 
 def _lm_paged_step():
@@ -405,8 +410,12 @@ PROGRAMS = {
         "attn_scores": "self_attn", "attn_softmax": "cross_attn",
         "attn_context": "self_attn", "dropout": "mlp", "loss": None,
         "optimizer": None, "lm_head": None}),
-    "t5_decode_step": (_t5_decode_step, {
-        "decode_attention": "cross_attn", "kv_append": "self_attn"}),
+    "t5_decode_step": (lambda: _t5_slots(rows=2), {
+        "decode_attention": "cross_attn", "kv_append": "self_attn",
+        "kv_gather": "self_attn"}),
+    "t5_admit": (lambda: _t5_slots(admit=16), {
+        "attn_scores": "self_attn", "attn_softmax": "self_attn",
+        "attn_context": "self_attn"}),
     "lm_paged_step": (_lm_paged_step, {
         "kv_gather": "attn", "decode_attention": "attn", "kv_append": "attn",
         "moe_router": "moe", "moe_sort": "moe", "moe_experts": "moe",

@@ -715,12 +715,15 @@ def test_engine_untraced_requests_cost_nothing(lm):
 # ---------------------------------------------------------------------------
 
 
-def test_t5_prefill_and_step_match_offline_generate():
+def test_t5_admit_and_slot_step_match_offline_generate():
+    """The engine's two programs by hand: two prompts admitted into slots 2
+    and 0 of four, one step apart, then steps over a prefix of the slots."""
     from tpu_air.models.t5 import (
         T5Config,
         T5ForConditionalGeneration,
-        make_t5_decode_step_fn,
-        make_t5_prefill_fn,
+        init_slot_state,
+        make_t5_admit_fn,
+        make_t5_slot_step_fn,
     )
     from tpu_air.models.t5.generate import generate as t5_generate
 
@@ -729,22 +732,27 @@ def test_t5_prefill_and_step_match_offline_generate():
     enc = jnp.ones((2, 8), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), enc, jnp.ones_like(enc),
                         jnp.ones((2, 6), jnp.int32))["params"]
-    ids = jnp.array([[4, 5, 6, 1, 0, 0], [9, 8, 7, 6, 5, 1]], jnp.int32)
-    mask = (ids != cfg.pad_token_id).astype(jnp.int32)
+    ids = np.array([[4, 5, 6, 1, 0, 0], [9, 8, 7, 6, 5, 1]], np.int32)
     max_new = 6
 
-    want = np.asarray(t5_generate(model, params, ids, max_new_tokens=max_new,
-                                  early_stop=False))
+    want = np.asarray(t5_generate(model, params, jnp.asarray(ids),
+                                  max_new_tokens=max_new, early_stop=False))
 
-    prefill = make_t5_prefill_fn(model, max_decode_len=max_new)
-    step = make_t5_decode_step_fn(model)
-    tok, cache, enc_h = prefill(params, ids, mask)
-    got = [np.asarray(tok)]
-    for _ in range(max_new - 1):
-        cache, tok = step(params, cache, tok, enc_h, mask)
-        got.append(np.asarray(tok))
-    got = np.stack(got, axis=1)
-    np.testing.assert_array_equal(got, want)
+    state, tok = init_slot_state(model, params, 4, max_new + 1, 6)
+    admit = make_t5_admit_fn(model, 6)
+    step = make_t5_slot_step_fn(model, 4)
+    got = {2: [], 0: []}
+    for t in range(max_new + 1):
+        if t < 2:       # row t goes to slot (2, 0)[t] before step t
+            slot = (2, 0)[t]
+            n = int((ids[t] != cfg.pad_token_id).sum())
+            state, tok = admit(params, state, tok, jnp.asarray(
+                [[*ids[t], n, slot]], jnp.int32))
+        state, tok = step(params, state, tok)
+        for slot, row in got.items():
+            if t >= (slot == 0) and len(row) < max_new:
+                row.append(int(tok[slot]))
+    np.testing.assert_array_equal([got[2], got[0]], want)
 
 
 # ---------------------------------------------------------------------------

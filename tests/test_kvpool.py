@@ -11,7 +11,8 @@ Layers under test:
     slab engine, prefix hits / CoW end to end, chunked-prefill TTFT
     flatness under a long-prompt arrival, OOM deferral, kvpool gauges in
     the metrics snapshot and prometheus text;
-  * the T5 window engine: parity with offline T5 generate.
+  * the T5 slot engine: parity with offline T5 generate, whenever a
+    request is admitted.
 """
 
 import time
@@ -505,11 +506,11 @@ def test_kvpool_gauges_reach_snapshot_and_prometheus(lm):
 
 
 # ---------------------------------------------------------------------------
-# T5 window engine
+# T5 slot engine
 # ---------------------------------------------------------------------------
 
 
-def test_t5_window_engine_matches_offline_generate():
+def test_t5_slot_engine_matches_offline_generate():
     from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
     from tpu_air.models.t5.generate import generate as t5_generate
 
@@ -524,8 +525,7 @@ def test_t5_window_engine_matches_offline_generate():
     max_new = 6
 
     # offline reference: one padded batch; T5 rows are batch-independent,
-    # so grouping differences between this and the engine's windows can't
-    # change any row's tokens
+    # so which rows the engine decodes together can't change a row's tokens
     li = max(len(p) for p in prompts)
     ids = np.full((len(prompts), li), cfg.pad_token_id, np.int32)
     for r, p in enumerate(prompts):
@@ -540,11 +540,11 @@ def test_t5_window_engine_matches_offline_generate():
             row = row[: row.index(cfg.eos_token_id) + 1]
         want.append(row)
 
-    # 5 prompts through max_batch=2 windows: 3 windows, per-row retirement
+    # 5 prompts through 2 slots: a slot is taken again as its row retires
     engine = T5Engine(
         model, params,
         T5EngineConfig(max_batch=2, max_input_len=8, max_new_tokens=max_new),
-        auto_start=False, name="t5-window-test",
+        auto_start=False, name="t5-slot-test",
     )
     streams = [engine.submit(p) for p in prompts]
     steps = 0
@@ -564,7 +564,8 @@ def test_t5_window_engine_matches_offline_generate():
 @pytest.fixture(scope="module")
 def t5_tiny():
     """Tiny T5 weights, three prompts and their first eight greedy tokens
-    (no early stop): what a window must stream, up to the EOS a test picks."""
+    (no early stop): what the engine must stream, up to the EOS a test
+    picks."""
     from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
     from tpu_air.models.t5.generate import generate as t5_generate
 
@@ -590,9 +591,9 @@ def t5_tiny():
     return cfg, params, prompts, ref, eos
 
 
-def _t5_engine(t5_tiny, name, eos=None, max_new=8, **kw):
-    """A hand-stepped window engine over the fixture's weights whose model
-    ends a row on ``eos`` (the weights do not know which token that is)."""
+def _t5_engine(t5_tiny, name, eos=None, max_new=8, slots=2, **kw):
+    """A slot engine over the fixture's weights whose model ends a row on
+    ``eos`` (the weights do not know which token that is)."""
     import dataclasses
 
     from tpu_air.models.t5 import T5ForConditionalGeneration
@@ -602,7 +603,8 @@ def _t5_engine(t5_tiny, name, eos=None, max_new=8, **kw):
         cfg = dataclasses.replace(cfg, eos_token_id=eos)
     return T5Engine(
         T5ForConditionalGeneration(cfg), params,
-        T5EngineConfig(max_batch=2, max_input_len=8, max_new_tokens=max_new),
+        T5EngineConfig(max_batch=slots, max_input_len=8,
+                       max_new_tokens=max_new),
         name=name, **kw)
 
 
@@ -615,14 +617,15 @@ def _run_dry(engine):
 
 
 @pytest.mark.parametrize("other_budget, issued, ahead, dropped", [
-    # the other row runs on to its budget of 8: 7 steps, each one read
-    (8, 7, 6, 0),
+    # the other row runs on to its budget of 8: 8 steps, each one read (a
+    # row's first token is its first step's)
+    (8, 8, 7, 0),
     # the other row ends on its budget of 3, so the row that ends on EOS at
-    # its fifth token is the window's last: the sixth token's step is out
+    # its fifth token is the last live one: the sixth token's step is out
     # when the host learns of it, and nobody reads it
-    (3, 5, 4, 1),
+    (3, 6, 5, 1),
 ], ids=["another-row-runs-on", "the-last-live-row"])
-def test_t5_window_learns_of_eos_one_step_late_and_emits_nothing_past_it(
+def test_t5_engine_learns_of_eos_one_step_late_and_emits_nothing_past_it(
         t5_tiny, other_budget, issued, ahead, dropped):
     _, _, prompts, ref, eos = t5_tiny
     engine = _t5_engine(t5_tiny, f"t5-eos-{other_budget}", eos=eos,
@@ -636,7 +639,8 @@ def test_t5_window_learns_of_eos_one_step_late_and_emits_nothing_past_it(
     assert (snap["steps_issued"], snap["steps_ahead"],
             snap["steps_dropped"]) == (issued, ahead, dropped)
     assert snap["tokens_emitted"] == 5 + other_budget
-    # the next window opens behind the dropped step and streams as ever
+    assert engine._unread is None
+    # the next request is issued behind the dropped step and streams as ever
     late = engine.submit(prompts[2], 4)
     assert not engine.idle()
     engine.drain()
@@ -645,7 +649,7 @@ def test_t5_window_learns_of_eos_one_step_late_and_emits_nothing_past_it(
     assert late.result(5.0) == ref[2][:4]
     assert engine.idle() and engine.drained()
     snap = engine.metrics.snapshot()
-    assert (snap["steps_issued"], snap["steps_dropped"]) == (issued + 3,
+    assert (snap["steps_issued"], snap["steps_dropped"]) == (issued + 4,
                                                              dropped)
     assert snap["requests_completed"] == 3
     engine.close()
@@ -664,8 +668,8 @@ def test_t5_window_learns_of_eos_one_step_late_and_emits_nothing_past_it(
 ], ids=["another-row-runs-on", "the-last-live-row"])
 def test_paged_engine_learns_of_eos_one_step_late_and_emits_nothing_past_it(
         lm_live, other_budget, issued, ahead, dropped):
-    """``InferenceEngine``'s counters for the same two endings as the window
-    engine's case above."""
+    """``InferenceEngine``'s counters for the same two endings as
+    ``T5Engine``'s case above."""
     cfg, model, params = lm_live
     ending, other, late = _prompts(seed=71, n=3)
     ref = _offline(model, params, ending, 8)
@@ -742,69 +746,257 @@ def test_t5_step_counters_reach_metrics_only_from_an_engine_that_issues():
 
     ahead, plain = EngineMetrics("ahead-test"), EngineMetrics("plain-test")
     try:
-        for is_ahead in (False, True, True):
-            ahead.record_issue(is_ahead)
+        for is_ahead, rows in ((False, 16), (True, 16), (True, 32)):
+            ahead.record_issue(is_ahead, rows=rows)
         ahead.record_dropped_step()
+        ahead.record_admission(1, in_flight=False)
+        ahead.record_admission(3, in_flight=True)
         snaps = {"a": ahead.snapshot(), "p": plain.snapshot()}
     finally:
         unregister("ahead-test")
         unregister("plain-test")
     assert "steps_issued" not in snaps["p"]
+    assert "admissions" not in snaps["p"] and "steps_by_rows" not in snaps["p"]
+    assert snaps["a"]["steps_by_rows"] == {16: 2, 32: 1}
     merged = merge_snapshots(snaps)
     assert (merged["steps_issued"], merged["steps_ahead"],
             merged["steps_dropped"]) == (3, 2, 1)
+    assert (merged["admissions"], merged["rows_admitted"],
+            merged["rows_admitted_in_flight"]) == (2, 4, 3)
     assert "steps_issued" not in merge_snapshots({"p": snaps["p"]})
     lines = prometheus_lines(snaps)
-    for key, n in (("issued", 3), ("ahead", 2), ("dropped", 1)):
-        assert f'tpu_air_engine_steps_{key}{{engine="a"}} {n}' in lines
+    for key, n in (("steps_issued", 3), ("steps_ahead", 2),
+                   ("steps_dropped", 1), ("rows_admitted", 4),
+                   ("rows_admitted_in_flight", 3)):
+        assert f'tpu_air_engine_{key}{{engine="a"}} {n}' in lines
     assert not any('steps_issued{engine="p"}' in ln for ln in lines)
+    assert not any('rows_admitted{engine="p"}' in ln for ln in lines)
 
 
-def test_t5_close_with_a_step_in_flight_fails_live_streams_and_joins(
-        t5_tiny):
+@pytest.mark.parametrize("how", ["a-step-in-flight",
+                                 "a-step-and-an-admission-in-flight"])
+def test_t5_close_with_work_in_flight_fails_live_streams_and_joins(
+        t5_tiny, how):
+    """``close()`` while the device still holds an issued step (and, in the
+    second case, an admit program and the step that would have decoded the
+    admitted row's first token): every live stream fails, nothing hangs, the
+    step nobody will read counts as dropped and the loop's thread is gone."""
     import threading
 
     _, _, prompts, ref, _ = t5_tiny
-    engine = _t5_engine(t5_tiny, "t5-close-test", max_new=512)
+    threaded = how == "a-step-in-flight"
+    engine = _t5_engine(t5_tiny, f"t5-close-{threaded}", max_new=512,
+                        auto_start=threaded)
     stream = engine.submit(prompts[0], 512)
-    deadline = time.monotonic() + 60.0
-    while not stream.tokens_so_far():
-        assert time.monotonic() < deadline, "no first token"
-        time.sleep(0.001)
+    streams = [stream]
+    if threaded:
+        deadline = time.monotonic() + 60.0
+        while not stream.tokens_so_far():
+            assert time.monotonic() < deadline, "no first token"
+            time.sleep(0.001)
+    else:
+        for _ in range(3):
+            engine.step()
+        # admitted and issued inside this iteration, read by none
+        streams.append(engine.submit(prompts[1], 512))
+        engine.step()
+        assert engine.metrics.snapshot()["rows_admitted_in_flight"] == 1
+        assert [req is not None for req in engine._rows] == [True, True]
+        assert len(engine._unread.rows) == 2
     engine.close()
-    with pytest.raises(EngineClosedError):
-        stream.result(5.0)
+    for s in streams:
+        with pytest.raises(EngineClosedError):
+            s.result(5.0)
     got = stream.tokens_so_far()
     assert 1 <= len(got) < 512 and got[:8] == ref[0][:len(got)]
+    assert streams[-1] is stream or streams[-1].tokens_so_far() == []
     snap = engine.metrics.snapshot()
-    # the first token is the prefill's; every issued step was read and
-    # emitted, but the one the close dropped
+    # every issued step was read and emitted, but the one the close dropped
     assert snap["steps_dropped"] == 1
-    assert snap["steps_issued"] == len(got)
-    assert engine._window is None and engine._thread is None
-    assert not any(t.name == "tpu-air-t5-close-test"
+    assert snap["steps_issued"] == len(got) + 1
+    assert engine._unread is None and engine._thread is None
+    assert engine._rows == [None, None]
+    assert not any(t.name == f"tpu-air-t5-close-{threaded}"
                    for t in threading.enumerate())
     with pytest.raises(EngineClosedError):
         engine.submit(prompts[1])
 
 
 def test_t5_decode_steps_upload_nothing(t5_tiny):
-    """The step path takes its tokens, mask, encoder output and cache from
-    the device: with host-to-device transfers disallowed, explicit ones
-    too, every step of an open window runs (the parent uploaded ``cur_tok``
-    and ``enc_mask`` each step and fails here)."""
+    """The step path takes its tokens, masks, ring position and cache from
+    the device: with host-to-device transfers disallowed, explicit ones too,
+    every step between two admissions runs (the admission itself uploads one
+    array: the round's prompts, their lengths and slots)."""
     _, _, prompts, ref, _ = t5_tiny
     engine = _t5_engine(t5_tiny, "t5-upload-test", auto_start=False)
     streams = [engine.submit(p, 8) for p in prompts[:2]]
-    engine.step()  # the window opens: ids and mask go up, once
+    engine.step()  # both are admitted: the prompts go up, once
     assert not engine.idle()
     with jax.transfer_guard_host_to_device("disallow_explicit"):
         with pytest.raises(Exception, match="host-to-device"):
             jnp.asarray(np.zeros(2, np.int32))  # the guard is enforced here
         steps = 0
-        while engine._window is not None:
+        while not engine.idle():
             engine.step()
             steps += 1
-    assert steps == 7
+    assert steps == 8
     assert [s.result(5.0) for s in streams] == ref[:2]
+    engine.close()
+
+
+# -- a slot a request: whenever it is admitted, whatever the others do ---------
+
+
+@pytest.fixture(scope="module")
+def t5_many():
+    """Tiny T5 weights (plain and with an int8 decode cache: the parameters
+    are the same), 24 prompts and each one's first eight greedy tokens from
+    offline ``generate`` under either config."""
+    import dataclasses
+
+    from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration
+    from tpu_air.models.t5.generate import generate as t5_generate
+
+    cfg = T5Config.tiny()
+    enc = jnp.ones((2, 8), jnp.int32)
+    params = T5ForConditionalGeneration(cfg).init(
+        jax.random.PRNGKey(0), enc, jnp.ones_like(enc),
+        jnp.ones((2, 6), jnp.int32))["params"]
+    rng = np.random.RandomState(73)
+    prompts = [list(map(int, rng.randint(2, 384, size=rng.randint(3, 9))))
+               for _ in range(24)]
+    ids = np.full((len(prompts), 8), cfg.pad_token_id, np.int32)
+    for r, p in enumerate(prompts):
+        ids[r, :len(p)] = p
+    mask = (ids != cfg.pad_token_id).astype(np.int32)
+    refs = {}
+    for int8 in (False, True):
+        model = T5ForConditionalGeneration(
+            dataclasses.replace(cfg, decode_cache_int8=int8))
+        refs[int8] = np.asarray(t5_generate(
+            model, params, jnp.asarray(ids), attention_mask=jnp.asarray(mask),
+            max_new_tokens=8, early_stop=False)).tolist()
+    return cfg, params, prompts, refs
+
+
+# scenario -> (slots, max_new_tokens, int8, [(iteration, prompt, budget)]):
+# request ``prompt`` is submitted before engine iteration ``iteration``
+_SLOT_SCENARIOS = {
+    # one request every other iteration into four slots: every row joins
+    # rows of other ages
+    "admitted-at-different-steps": (
+        4, 8, False, [(2 * i, i, 3 + i % 6) for i in range(10)]),
+    # a ring of 5 positions turned four times over by 20 requests
+    "after-the-ring-has-wrapped": (
+        2, 4, False, [(i, i, 1 + i % 4) for i in range(20)]),
+    # seven at once into two slots: the queue waits for a free slot, FIFO
+    "more-requests-than-slots": (
+        2, 8, False, [(0, i, 2 + i) for i in range(7)]),
+    # 20 at once into 32 slots, the high slots' budgets short: the step's
+    # prefix is 32 rows, then 16 again
+    "prefix-grows-and-shrinks": (
+        32, 8, False, [(0, i, 8) for i in range(3)]
+        + [(4, i, 8 if i < 8 else 2) for i in range(3, 23)]),
+    # the int8 decode cache goes through the ring and the slots' rows
+    "int8-cache": (
+        4, 8, True, [(2 * i, i, 3 + i % 6) for i in range(8)]),
+}
+
+
+@pytest.mark.parametrize("scenario", sorted(_SLOT_SCENARIOS))
+def test_t5_slot_engine_streams_what_generate_alone_would(t5_many, scenario):
+    import dataclasses
+
+    from tpu_air.models.t5 import T5ForConditionalGeneration
+
+    cfg, params, prompts, refs = t5_many
+    slots, max_new, int8, plan = _SLOT_SCENARIOS[scenario]
+    model = T5ForConditionalGeneration(
+        dataclasses.replace(cfg, decode_cache_int8=int8, eos_token_id=-1))
+    engine = T5Engine(
+        model, params,
+        T5EngineConfig(max_batch=slots, max_input_len=8,
+                       max_new_tokens=max_new),
+        auto_start=False, name=f"t5-slots-{scenario}")
+    admitted, pop = [], engine.scheduler.pop_admissible
+
+    def spy_pop(*a, **kw):
+        out = pop(*a, **kw)
+        admitted.extend(r.request_id for r in out)
+        return out
+
+    engine.scheduler.pop_admissible = spy_pop
+    streams, batches, taken, it = {}, [], [], 0
+    while len(streams) < len(plan) or not engine.idle():
+        for at, k, budget in plan:
+            if at == it:
+                streams[k] = (engine.submit(prompts[k], budget), budget)
+        engine.step()
+        if engine._unread is not None:
+            batches.append(engine._unread.batch)
+        taken.append(sum(r is not None for r in engine._rows))
+        it += 1
+        assert it < 400, "the engine failed to drain"
+    for k, (stream, budget) in streams.items():
+        assert stream.result(5.0) == refs[int8][k][:budget], k
+    snap = engine.metrics.snapshot()
+    pos = int(engine._state["cache"]["decoder"]["decoder_pos"])
+    engine.close()
+    n = len(plan)
+    assert snap["requests_completed"] == snap["rows_admitted"] == n
+    assert snap["tokens_emitted"] == sum(b for _, b in streams.values())
+    assert sum(snap["steps_by_rows"].values()) == snap["steps_issued"]
+    assert admitted == sorted(admitted)            # FIFO, in every scenario
+    assert max(taken) <= slots
+    # the ring's position: the warm-up's steps and every step issued since
+    ring, warm = max_new + 1, 2 * len(engine._steps)
+    assert pos == (warm + snap["steps_issued"]) % ring
+    if scenario == "admitted-at-different-steps":
+        assert snap["rows_admitted_in_flight"] == n - 1
+        assert snap["admissions"] == n
+    elif scenario == "after-the-ring-has-wrapped":
+        assert snap["steps_issued"] > 4 * ring
+    elif scenario == "more-requests-than-slots":
+        assert max(taken) == slots and snap["admissions"] > 2
+        # all but the first round joined rows that were decoding
+        assert snap["rows_admitted_in_flight"] == n - 2
+    elif scenario == "prefix-grows-and-shrinks":
+        assert set(snap["steps_by_rows"]) == {16, 32}
+        grew = batches.index(32)
+        assert set(batches[:grew]) == {16}
+        shrank = grew + batches[grew:].index(16)
+        assert set(batches[shrank:]) == {16} and len(batches) > shrank + 2
+    # nothing was traced or compiled after the engine was built
+    assert all(f._cache_size() == 1 for f in (*engine._steps.values(),
+                                              *engine._admits.values()))
+
+
+def test_t5_slot_is_taken_again_right_after_an_eos_learnt_one_step_late(
+        t5_tiny):
+    """Two slots, three requests.  The row in slot 0 ends on EOS at its fifth
+    token, which the host learns with the sixth token's step already out and
+    the slot's old row in it.  The waiting request is admitted to slot 0 at
+    the next iteration, behind that step on the device, and none of its
+    tokens is the old row's sixth."""
+    _, _, prompts, ref, eos = t5_tiny
+    engine = _t5_engine(t5_tiny, "t5-slot-reuse", eos=eos, auto_start=False)
+    ending = engine.submit(prompts[0], 8)
+    other = engine.submit(prompts[1], 8)
+    waiting = engine.submit(prompts[2], 8)
+    for _ in range(6):      # admit two; issue steps 1..6, read steps 1..5
+        engine.step()
+    assert ending.done and not other.done and not waiting.tokens_so_far()
+    assert engine._rows[0] is None and engine.scheduler.depth() == 1
+    assert [slot for slot, _ in engine._unread.rows] == [0, 1]
+    engine.step()           # admits to slot 0 behind step 6, which it reads
+    assert engine._rows[0] is not None and engine.scheduler.depth() == 0
+    assert len(other.tokens_so_far()) == 6 and not waiting.tokens_so_far()
+    _run_dry(engine)
+    assert ending.result(5.0) == ref[0][:5]
+    assert other.result(5.0) == ref[1]
+    assert waiting.result(5.0) == ref[2]
+    snap = engine.metrics.snapshot()
+    assert (snap["rows_admitted"], snap["rows_admitted_in_flight"]) == (3, 1)
+    assert snap["steps_dropped"] == 0
+    assert snap["tokens_emitted"] == 5 + 8 + 8
     engine.close()

@@ -105,8 +105,9 @@ def test_phase_without_jax_is_the_shared_noop_and_imports_nothing():
 
 @pytest.fixture(scope="module")
 def engine_phases(tmp_path_factory):
-    """A tiny ``T5Engine`` stepped by hand through three windows (five
-    prompts, two rows a window) inside one session."""
+    """A tiny ``T5Engine`` stepped by hand inside one session: five prompts
+    with budgets 2..6 over two slots, so three of them wait for a slot and
+    join a row that is decoding."""
     import jax
     import jax.numpy as jnp
 
@@ -125,7 +126,9 @@ def engine_phases(tmp_path_factory):
     prompts = [list(map(int, rng.randint(2, cfg.vocab_size, size=n)))
                for n in (3, 8, 5, 4, 6)]
     trace_dir = str(tmp_path_factory.mktemp("engine-trace"))
-    engine.generate(prompts[:2], max_new_tokens=2)  # compile outside the trace
+    # six token steps outside the trace: one whole accounting span, so the
+    # spans inside the trace begin with it
+    engine.generate(prompts[:2], max_new_tokens=6)
     before = engine.metrics.snapshot()
     jax.profiler.start_trace(trace_dir)
     try:
@@ -142,56 +145,80 @@ def engine_phases(tmp_path_factory):
     after = engine.metrics.snapshot()
     engine.close()
     counted = {k: after[k] - before[k] for k in (
-        "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped")}
+        "tokens_emitted", "steps_issued", "steps_ahead", "steps_dropped",
+        "admissions", "rows_admitted", "rows_admitted_in_flight")}
     counted.update(_histograms(before, after), trace_dir=trace_dir)
     return _phases(trace_dir), tokens, counted
+
+
+_EVENTS = ("engine.first_token", "engine.window_close")
 
 
 def test_engine_step_holds_dispatch_readback_emit_in_order(engine_phases):
     phases, _, _ = engine_phases
     steps = [p for p in phases if p[0] == "engine.step"]
     assert len(steps) >= 5
-    closes = [p for p in phases if p[0] == "engine.window_close"]
     for st in steps:
-        kids = [k for k in _inside(phases, st) if k not in closes]
+        kids = [k for k in _inside(phases, st) if k[0] not in _EVENTS]
         # the next step goes out (at most once, and first), then the step
-        # before it is read back, then emitted
+        # before it, if rows of it are live, is read back, then emitted
+        read = ["engine.readback", "engine.emit"] if st[3]["live"] else []
         assert [k[0] for k in kids] == (
-            ["engine.dispatch"] * st[3]["ahead"]
-            + ["engine.readback", "engine.emit"])
+            ["engine.dispatch"] * st[3]["ahead"] + read)
         assert all(a[2] <= b[1] for a, b in zip(kids, kids[1:]))
-        assert 1 <= st[3]["live"] <= st[3]["batch"] == 2
-        assert kids[-1][3] == {"emitted": st[3]["live"]}
-        # a window closes where its last rows retire: inside that emit
-        assert _inside(closes, st) == _inside(closes, kids[-1])
-    # budgets 2, 3 | 4, 5 | 6: each window's last token step has nothing to
-    # issue ahead of it (the window's first went out at its opening)
-    ahead = [st[3]["ahead"] for st in steps]
-    assert ahead == [1, 0] + [1, 1, 1, 0] + [1, 1, 1, 1, 0]
-    # no phase per row or per token: a read-back and an emit a step, a
-    # dispatch where one was issued, one prefill and one close a window, one
-    # first token a request, nothing else
+        # ``live`` and ``batch`` are the step READ: its live rows, the
+        # prefix of the slots it ran over (both 0 where none was unread)
+        assert 0 <= st[3]["live"] <= st[3]["batch"] <= 2
+        assert (st[3]["batch"] == 0) == (st[3]["live"] == 0)
+        if read:
+            assert kids[-1][3] == {"emitted": st[3]["live"]}
+        # first tokens and a span's close are said where rows are emitted
+        events = [k for k in _inside(phases, st) if k[0] in _EVENTS]
+        assert events == [k for k in events if _inside([k], kids[-1])]
+        assert read or not events
+    # nothing is unread when the first step goes out; budgets end these
+    # streams, so only the last token step has nothing to issue ahead of it
+    assert [st[3]["live"] for st in steps][0] == 0
+    assert all(st[3]["live"] for st in steps[1:])
+    assert [st[3]["ahead"] for st in steps] == [1] * (len(steps) - 1) + [0]
+    # no phase per row or per token: a dispatch where a step was issued, a
+    # read-back and an emit where one was read, one prefill an admission
+    # round, one first token a request, one close a span, nothing else
     assert {p[0] for p in phases} == {
         "engine.prefill", "engine.step", "engine.dispatch",
         "engine.readback", "engine.emit", "engine.first_token",
         "engine.window_close"}
-    assert len(phases) == 3 * len(steps) + sum(ahead) + 3 + 5 + 3
+    by_name = {n: sum(p[0] == n for p in phases) for n in {p[0] for p in phases}}
+    assert by_name["engine.dispatch"] == len(steps) - 1
+    assert by_name["engine.readback"] == by_name["engine.emit"] == (
+        len(steps) - 1)
+    assert by_name["engine.first_token"] == 5
+    assert by_name["engine.window_close"] == (len(steps) - 1) // 6
 
 
-def test_engine_prefill_counts_and_emitted_sum_to_the_engines_tokens(
+def test_engine_prefill_is_an_admission_round_and_emits_nothing(
         engine_phases):
     phases, tokens, counted = engine_phases
-    emitted = counted["tokens_emitted"]
     prefills = [p for p in phases if p[0] == "engine.prefill"]
-    # five requests, two rows a window; what each window left in the queue
-    assert [(p[3]["rows"], p[3]["batch"], p[3]["queued"])
-            for p in prefills] == [(2, 2, 3), (2, 2, 1), (1, 2, 0)]
-    # nothing is read inside it; it holds its rows' first tokens alone
-    assert all([k[0] for k in _inside(phases, p)]
-               == ["engine.first_token"] * p[3]["rows"] for p in prefills)
-    first = sum(p[3]["rows"] for p in prefills)
-    later = sum(p[3]["emitted"] for p in phases if p[0] == "engine.emit")
-    assert first + later == emitted == tokens
+    # five requests, two slots: the first round takes two, then one a slot
+    # as it comes free (an admit program a request); ``in_flight`` whether
+    # other rows were decoding
+    assert [p[3] for p in prefills] == [
+        {"rows": 2, "queued": 3, "in_flight": 0},
+        {"rows": 1, "queued": 2, "in_flight": 1},
+        {"rows": 1, "queued": 1, "in_flight": 1},
+        {"rows": 1, "queued": 0, "in_flight": 1}]
+    assert (counted["admissions"], counted["rows_admitted"],
+            counted["rows_admitted_in_flight"]) == (4, 5, 3)
+    # nothing is read or emitted inside it, and it lies in no engine.step:
+    # the admit program goes out and the row's first token is its first
+    # step's, like any other token
+    assert all(not _inside(phases, p) for p in prefills)
+    steps = [p for p in phases if p[0] == "engine.step"]
+    assert not any(_inside(prefills, st) for st in steps)
+    emitted = sum(p[3]["emitted"] for p in phases if p[0] == "engine.emit")
+    assert emitted == counted["tokens_emitted"] == tokens
+    assert emitted == sum(st[3]["live"] for st in steps)
 
 
 def test_engine_step_ahead_counts_are_the_engines_step_counters(
@@ -199,13 +226,14 @@ def test_engine_step_ahead_counts_are_the_engines_step_counters(
     phases, _, counted = engine_phases
     steps = [p for p in phases if p[0] == "engine.step"]
     dispatches = [p for p in phases if p[0] == "engine.dispatch"]
-    # a dispatch phase is a step issued ahead; each window's first step goes
-    # out inside its engine.prefill; budgets end these windows, so every
-    # issued step is read by one engine.step
-    assert counted["steps_ahead"] == len(dispatches) == sum(
+    # a dispatch phase is a step issued; it is ahead where a step was unread
+    assert counted["steps_issued"] == len(dispatches) == sum(
         st[3]["ahead"] for st in steps)
-    assert counted["steps_issued"] == len(steps) == len(dispatches) + 3
+    assert counted["steps_ahead"] == sum(
+        st[3]["ahead"] for st in steps if st[3]["live"])
+    # budgets end these streams: every issued step is read by one engine.step
     assert counted["steps_dropped"] == 0
+    assert counted["step_latency_s"][0] == counted["steps_issued"]
 
 
 def _first_token_counts_hold(phases, counted, chunks):
@@ -235,44 +263,51 @@ def test_engine_first_token_is_one_event_a_request_with_its_waits(
         engine_phases):
     phases, _, counted = engine_phases
     _first_token_counts_hold(phases, counted, chunks=1)
-    # a window's rows were admitted by one round and emitted at one reading
-    for pre in (p for p in phases if p[0] == "engine.prefill"):
-        rows = [k[3] for k in _inside(phases, pre)]
-        assert len({r["prefill_us"] for r in rows}) == 1
-    # the window engine has one token-step program
+    # the first round's two rows were admitted by one reading of the clock
+    # and emitted at one read-back; the other three waited for a slot
+    firsts = [p[3] for p in phases if p[0] == "engine.first_token"]
+    assert firsts[0]["prefill_us"] == firsts[1]["prefill_us"]
+    assert min(f["queue_us"] for f in firsts[2:]) > max(
+        f["queue_us"] for f in firsts[:2])
+    # the slot engine has one kind of token-step program
     assert counted["by_program"] == {"decode": counted["step_latency_s"]}
 
 
-def test_engine_window_close_counts_the_whole_window(engine_phases):
-    """One ``engine.window_close`` a window: ``live_row_steps`` is the rows
-    its prefill emitted for plus ``live`` of each of its ``engine.step``
-    phases (the tokens it emitted), ``row_steps`` its ``steps`` x ``batch``."""
+def test_engine_window_close_counts_a_span_of_token_steps(engine_phases):
+    """One ``engine.window_close`` every ``max_new_tokens`` (6) token steps
+    read: ``live_row_steps`` is Σ ``live`` and ``row_steps`` Σ ``batch`` of
+    those ``engine.step`` phases, ``rows`` the requests admitted meanwhile,
+    ``us`` the first one's start to the last one's read-back."""
     phases, tokens, _ = engine_phases
     prefills = [p for p in phases if p[0] == "engine.prefill"]
     closes = [p for p in phases if p[0] == "engine.window_close"]
-    steps = [p for p in phases if p[0] == "engine.step"]
-    assert len(closes) == len(prefills) == 3
-    for k, (pre, close) in enumerate(zip(prefills, closes)):
+    reads = [p for p in phases if p[0] == "engine.step" and p[3]["live"]]
+    readbacks = [p for p in phases if p[0] == "engine.readback"]
+    dispatches = [p for p in phases if p[0] == "engine.dispatch"]
+    assert len(reads) == 14 and len(closes) == 2
+    began = dispatches[0][2]        # the first step's issue: nothing before it
+    for k, close in enumerate(closes):
         c = close[3]
         assert set(c) == {"steps", "rows", "batch", "live_row_steps",
                           "row_steps", "us", "queued"}
-        mine = [st for st in steps if pre[2] <= st[1] and st[2] <= close[2]
-                or _inside([close], st)]
-        assert c["steps"] == len(mine) + 1
-        assert (c["rows"], c["batch"]) == (pre[3]["rows"], 2)
-        assert c["live_row_steps"] == pre[3]["rows"] + sum(
-            st[3]["live"] for st in mine)
-        assert c["row_steps"] == c["steps"] * c["batch"]
+        mine = reads[6 * k:6 * k + 6]
+        assert _inside([close], mine[-1])
+        assert (c["steps"], c["batch"]) == (6, 2)
+        assert c["live_row_steps"] == sum(st[3]["live"] for st in mine)
+        assert c["row_steps"] == sum(st[3]["batch"] for st in mine) == 12
+        since = closes[k - 1][1] if k else 0
+        assert c["rows"] == sum(p[3]["rows"] for p in prefills
+                                if since < p[1] < close[1])
         # open to close on the engine's clock is the phases' own span
-        assert abs(c["us"] * 1000 - (close[1] - pre[1])) < 1_000_000
-        # what it leaves waiting is what the next window finds, less its rows
-        if k + 1 < len(prefills):
-            nxt = prefills[k + 1][3]
-            assert c["queued"] == nxt["queued"] + nxt["rows"]
-    assert sum(c[3]["live_row_steps"] for c in closes) == tokens
-    # budgets 2, 3 | 4, 5 | 6 over two rows: 5 of 6, 9 of 10, 6 of 12
-    assert [(c[3]["live_row_steps"], c[3]["row_steps"]) for c in closes] == [
-        (5, 6), (9, 10), (6, 12)]
+        ended = readbacks[6 * k + 5][2]
+        assert abs(c["us"] * 1000 - (ended - began)) < 1_000_000
+        began = ended
+    # budgets 2..6 over two slots: 20 tokens in 14 steps, 2 of them after
+    # the second span closed
+    assert [c[3]["live_row_steps"] for c in closes] == [10, 8]
+    assert [c[3]["rows"] for c in closes] == [4, 1]
+    assert [c[3]["queued"] for c in closes] == [1, 0]
+    assert tokens == 20 == 18 + sum(st[3]["live"] for st in reads[12:])
 
 
 @pytest.fixture(scope="module")
@@ -471,28 +506,52 @@ def _read_metric(monkeypatch, tmp_path, trace_dir, name):
     import shutil
     from types import SimpleNamespace
 
-    from benchmark import harness, manifest
+    from jax.profiler import ProfileData
+
+    from benchmark import harness, manifest, xplane
 
     bench = manifest.Benchmark()
     monkeypatch.setattr(manifest, "REPO", str(tmp_path))
     shutil.copytree(trace_dir, os.path.join(
         str(tmp_path), harness.TRACE_DIR, "cell"), dirs_exist_ok=True)
     how = bench.load_json("layer_metrics", name + ".json")
-    # a CPU capture has no device plane to reduce: these readers ask only
-    # whether the run was traced
+    # a CPU capture has no device plane, so the harness's own reduction
+    # gives nothing: the readers get the host events it would have kept (most
+    # ask only whether the run was traced)
+    host = [(ev.name, ev.start_ns * 1e-9, (ev.start_ns + ev.duration_ns) * 1e-9)
+            for plane in ProfileData.from_file(
+                xplane.find_xplane(trace_dir)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events
+            if ev.duration_ns > 0]
+    trace = xplane.TraceSummary({}, host, (0.0, 0.0))
     return bench.module("readers", how["reader"]).read(
-        SimpleNamespace(trace=object(), facts={}), **how["args"])
+        SimpleNamespace(trace=trace, facts={}), **how["args"])
 
 
-def test_the_benchmarks_readers_read_the_window_engines_events(
+def test_the_benchmarks_readers_read_the_slot_engines_events(
         engine_phases, monkeypatch, tmp_path):
+    """Every ``program_span`` metric of ``t5large-serve`` that a CPU capture
+    can carry, read through its own metric file from a real capture of the
+    engine: none returns None.  (``engine_idle_host_ms`` and
+    ``engine_idle_readback_ms`` lay device gaps against these phases and
+    need a device plane: the cell's traced run on the chip holds them.)"""
     phases, _, counted = engine_phases
     firsts = [p[3] for p in phases if p[0] == "engine.first_token"]
+    steps = [p for p in phases if p[0] == "engine.step"]
     def read(name):
         return _read_metric(monkeypatch, tmp_path, counted["trace_dir"], name)
 
     assert read("engine_window_live_row_share") == pytest.approx(
-        100.0 * 20 / 28)
+        100.0 * 18 / 24)
+    assert read("engine_live_row_share") == pytest.approx(100.0 * 20 / 28)
+    readbacks = [p for p in phases if p[0] == "engine.readback"]
+    host = sorted(
+        (b[1] - a[1] - sum(r[2] - r[1] for r in _inside(readbacks, a))) / 1e6
+        for a, b in zip(steps, steps[1:])
+        if not any(a[1] <= p[1] < b[1] for p in phases
+                   if p[0] == "engine.prefill"))
+    assert host[0] <= read("engine_host_ms_p50") <= host[-1]
     waits = sorted(f["queue_us"] for f in firsts)
     assert read("engine_queue_wait_ms_p50") == pytest.approx(waits[2] / 1000.0)
     assert waits[3] / 1000.0 <= read("engine_queue_wait_ms_p95") <= (
@@ -595,3 +654,80 @@ def test_worker_actor_task_one_phase_a_call_with_its_method(air, tmp_path):
     # the session holds the calls in between, whole
     assert [p[3] for p in calls] == [{"method": "poll"}] * 7
     assert all(a[2] <= b[1] for a, b in zip(calls, calls[1:]))
+
+
+class _SlowCapture:
+    """An actor whose worker writes a capture out slowly on a thread of its
+    own, the way the benchmark's replica does: a real session, and a
+    ``stop_trace`` made to take ``seconds`` (on a v5e host it takes a minute
+    for two seconds of a 3 ms decode step; here the lock is held as long),
+    and then to go on for ``after`` seconds more with the ``.xplane.pb``
+    written (there: minutes, on the trace viewer's ``trace.json.gz``)."""
+
+    def capture(self, trace_dir, seconds, linger=False, after=0.0):
+        import threading
+        import time
+
+        import jax
+
+        if linger:
+            # a worker that holds a TPU rarely exits by itself: a thread
+            # that is not a daemon keeps this one from it the same way
+            threading.Thread(target=time.sleep, args=(90.0,)).start()
+
+        def run():
+            jax.profiler.start_trace(trace_dir)
+            state = jax._src.profiler._profile_state
+            stop, held = state.profile_session.stop_and_export, state.lock
+
+            class Slow:
+                def stop_and_export(self, log_dir):
+                    time.sleep(seconds)
+                    stop(log_dir)
+                    time.sleep(after)
+
+            state.profile_session = Slow()
+            started.set()
+            jax.profiler.stop_trace()
+            assert not held.locked()
+
+        started = threading.Event()
+        threading.Thread(target=run, daemon=True).start()
+        return started.wait(30.0)
+
+
+@pytest.mark.parametrize("case", ["writing", "writing-and-lingering",
+                                  "written-and-converting", "idle"])
+def test_a_worker_told_to_exit_lets_its_capture_finish(air, tmp_path, case):
+    """``kill`` of an actor whose worker is inside ``jax.profiler.stop_trace``
+    on another thread (PR 57: the traced run of ``t5large-serve`` lost its
+    capture to the replica's exit, twice): the worker stays until the
+    ``.xplane.pb`` is written and the driver waits for that long and no
+    longer, whatever ``stop_trace`` goes on to, also where the worker
+    would not exit by itself afterwards (it is stopped as ever then); with no
+    capture being written it is gone within the grace it always had."""
+    import glob
+
+    from tpu_air.core.remote import kill
+
+    actor = tpu_air.remote(_SlowCapture).remote()
+    if case == "idle":
+        assert tpu_air.get(actor.capture.remote(str(tmp_path), 0.0))
+        time.sleep(1.0)
+    else:
+        assert tpu_air.get(actor.capture.remote(
+            str(tmp_path), 5.0, linger=case.endswith("lingering"),
+            after=60.0 if case.endswith("converting") else 0.0))
+    t0 = time.monotonic()
+    kill(actor)
+    took = time.monotonic() - t0
+    (written,) = glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    assert took < 3.0 if case == "idle" else 4.0 < took < 30.0
+    # what the wait ends on: the file's top-level fields end where it does
+    from tpu_air.observability.profiler import _whole_proto
+
+    assert _whole_proto(written)
+    cut = tmp_path / "cut.xplane.pb"
+    cut.write_bytes(open(written, "rb").read()[:-7])
+    assert not _whole_proto(str(cut))

@@ -412,10 +412,12 @@ def _uncached_greedy(model, params, ids, mask, steps):
 def test_cached_decode_gives_the_uncached_forwards_greedy_tokens(tiny, kind):
     """float32, ragged prompts: each of the three programs that carry the
     decode cache (``generate``'s while-loop and scan over one stacked array a
-    kind of self slab, the engine's prefill + donated steps over a tuple a
-    layer) emits the argmax chain of the full forward, up to a row's EOS."""
+    kind of self slab, the engine's admit + donated steps over a tuple a
+    layer, here three slots taken at once) emits the argmax chain of the full
+    forward, up to a row's EOS."""
     from tpu_air.models.t5.generate import (
-        make_generate_fn, make_t5_decode_step_fn, make_t5_prefill_fn)
+        init_slot_state, make_generate_fn, make_t5_admit_fn,
+        make_t5_slot_step_fn)
 
     cfg, model, params = tiny
     rng = np.random.default_rng(5)
@@ -425,11 +427,16 @@ def test_cached_decode_gives_the_uncached_forwards_greedy_tokens(tiny, kind):
     ids = ids * mask
     want = _uncached_greedy(model, params, ids, mask, steps)
     if kind == "engine":
-        tok, cache, enc = make_t5_prefill_fn(model, steps + 1)(params, ids, mask)
-        step = make_t5_decode_step_fn(model)
-        got = [np.asarray(tok)]
-        for _ in range(steps - 1):
-            cache, tok = step(params, cache, tok, enc, mask)
+        state, tok = init_slot_state(model, params, 3, steps + 1, 10)
+        prompts = jnp.concatenate(
+            [ids, mask.sum(-1, keepdims=True), jnp.arange(3)[:, None]], axis=1)
+        admit = make_t5_admit_fn(model, 10)
+        for slot in range(3):       # a program a row, as the engine admits
+            state, tok = admit(params, state, tok, prompts[slot:slot + 1])
+        step = make_t5_slot_step_fn(model, 3)
+        got = []
+        for _ in range(steps):
+            state, tok = step(params, state, tok)
             got.append(np.asarray(tok))
         got = np.stack(got, axis=1)
     else:
@@ -535,11 +542,11 @@ def _decode_bodies(kind, int8):
     """The jaxprs of the cached decode step as ``kind`` builds it: the body
     of ``generate``'s loop (``while`` / ``scan``) or the engine's whole step;
     with them ``(b, (encoder length, decoder cache length), h, d)`` and the
-    cache tree's shapes as the engine's prefill builds it."""
+    cache tree's shapes as the engine's state holds it (``b`` slots)."""
     import dataclasses
 
     from tpu_air.models.t5.generate import (
-        make_generate_fn, make_t5_decode_step_fn, make_t5_prefill_fn)
+        init_slot_state, make_generate_fn, make_t5_slot_step_fn)
 
     cfg = dataclasses.replace(T5Config.tiny(), decode_cache_int8=int8)
     model = T5ForConditionalGeneration(cfg)
@@ -548,11 +555,12 @@ def _decode_bodies(kind, int8):
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0), ids, ids, ids[:, :2]))["params"]
     dims = (b, (enc_len, new + 1), cfg.num_heads, cfg.d_kv)
-    tok, cache, enc = jax.eval_shape(
-        make_t5_prefill_fn(model, new + 1), params, ids, ids)
+    state, tok = jax.eval_shape(
+        lambda p: init_slot_state(model, p, b, new + 1, enc_len), params)
+    cache = state["cache"]
     if kind == "step":
-        jaxpr = jax.make_jaxpr(make_t5_decode_step_fn(model))(
-            params, cache, tok, enc, ids)
+        jaxpr = jax.make_jaxpr(make_t5_slot_step_fn(model, b))(
+            params, state, tok)
         return [jaxpr.jaxpr], dims, cache
     fn = make_generate_fn(model, new, early_stop=(kind == "while"))
     jaxpr = jax.make_jaxpr(fn)(params, ids, ids, jax.random.PRNGKey(0))
@@ -579,7 +587,7 @@ def test_cached_step_never_views_a_slab_in_4d(kind, int8, slabs):
     ``[b, h, d, 1]``), and the body only contracts them: two ``dot_general``
     a layer read each as stored, nothing reshapes, transposes or copies one.
     Held for ``generate``'s while-loop and scan and for the engine's
-    donated-cache step, full-width and int8 caches."""
+    donated-state step over all of its slots, full-width and int8 caches."""
     bodies, dims, cache = _decode_bodies(kind, int8)
     assert bodies, f"no {kind} in the program"
     b, (_, dec_len), h, d = dims

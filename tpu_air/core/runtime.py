@@ -53,6 +53,7 @@ from .object_store import ObjectRef, ObjectStore, new_object_id
 # in nothing heavy at import time)
 from tpu_air.faults import plan as _faults
 from tpu_air.observability import tracing as _tracing
+from tpu_air.observability.profiler import finish_capture as _finish_capture
 from tpu_air.observability.profiler import phase as _phase
 
 # --------------------------------------------------------------------------
@@ -224,6 +225,9 @@ class _WorkerState:
     busy_task: Optional[str] = None
     actor_id: Optional[str] = None   # set => dedicated actor worker
     alive: bool = True
+    # set by the worker while, told to exit, it stays to let a profiler
+    # capture finish (``_stop_process``)
+    finishing: Any = None
 
 
 @dataclass
@@ -432,10 +436,16 @@ def _worker_main(
     conn: mpc.Connection,
     driver_env: Optional[Dict[str, str]] = None,
     driver_pid: int = 0,
+    finishing: Any = None,
 ):
     try:
         _die_with_driver(driver_pid)
         _worker_loop(worker_id, store_root, conn, driver_env)
+        # told to exit (or the driver's end of the pipe closed): a capture a
+        # thread of this worker is still writing gets its ``.xplane.pb``
+        # first, and the driver waits while ``finishing`` is set
+        # (``_stop_process``)
+        _finish_capture(_CAPTURE_WAIT_S, finishing)
     finally:
         # whatever this worker started ends with it
         _kill_descendants(_descendants(os.getpid()), wait=2.0)
@@ -580,16 +590,30 @@ def _kill_quietly(proc) -> None:
 #: how long a stop waits for a process that was sent SIGKILL before it says
 #: so: a worker tearing down gigabytes on a chip is slow, not immortal
 _GONE_WAIT_S = 120.0
+#: how long a worker told to exit may stay until a profiler capture a thread
+#: of it is writing has its ``.xplane.pb``: a minute for the largest capture a
+#: cell of the benchmark makes (PERF.md, PR 57), and half as much again
+_CAPTURE_WAIT_S = 90.0
 
 
-def _stop_process(proc, grace: float) -> bool:
-    """Give ``proc`` ``grace`` seconds to exit by itself, then SIGTERM, then
-    SIGKILL, and wait until it is GONE (SIGKILL again every few seconds, up
-    to ``_GONE_WAIT_S``); then stop whatever it had started itself.  True
-    once it is gone — only then may a chip it held go to another process.
-    One thread per process: the caller owns the reap."""
+def _stop_process(proc, grace: float, finishing: Any = None) -> bool:
+    """Give ``proc`` ``grace`` seconds to exit by itself (and for as long as
+    it keeps ``finishing`` set, ``_CAPTURE_WAIT_S`` at most: it got the word
+    and is letting a profiler capture reach its ``.xplane.pb``,
+    ``profiler.finish_capture``; a worker that holds a TPU rarely exits
+    without the SIGTERM, so the wait ends with the event, not with the
+    process), then SIGTERM, then SIGKILL, and wait until it
+    is GONE (SIGKILL again every few seconds, up to ``_GONE_WAIT_S``); then
+    stop whatever it had started itself.  True once it is gone — only then
+    may a chip it held go to another process.  One thread per process: the
+    caller owns the reap."""
     below = _descendants(proc.pid) if proc.pid else []
     proc.join(timeout=grace)
+    if finishing is not None:
+        deadline = time.monotonic() + _CAPTURE_WAIT_S
+        while (proc.is_alive() and finishing.is_set()
+               and time.monotonic() < deadline):
+            proc.join(timeout=0.2)
     if proc.is_alive():
         try:
             proc.terminate()
@@ -881,20 +905,23 @@ class Runtime:
     def _spawn_worker(self, actor_id: Optional[str] = None) -> _WorkerState:
         wid = next(self._next_worker_id)
         parent, child = mp.Pipe(duplex=True)
+        ctx = self._pick_ctx()
+        finishing = ctx.Event()
         # Ship the driver's CURRENT environ: forkserver children inherit the
         # env frozen at server start, so vars set since (JAX_PLATFORMS,
         # multi-host contract, …) must be re-applied in the worker before it
         # initializes any backend.
-        proc = self._pick_ctx().Process(
+        proc = ctx.Process(
             target=_worker_main,
             args=(wid, self.store_root, child, dict(os.environ),
-                  os.getpid()),
+                  os.getpid(), finishing),
             daemon=True,
             name=f"tpu_air-worker-{wid}",
         )
         proc.start()
         child.close()
-        ws = _WorkerState(worker_id=wid, proc=proc, conn=parent, actor_id=actor_id)
+        ws = _WorkerState(worker_id=wid, proc=proc, conn=parent,
+                          actor_id=actor_id, finishing=finishing)
         with self.lock:
             self.workers[wid] = ws
         self._poke_listener()
@@ -1022,7 +1049,7 @@ class Runtime:
             # the pipe closes before the process is gone; the FULL claim
             # (cpu + chip) comes back once it is, exactly like kill_actor
             self._return_claim(
-                claim, _stop_process(worker.proc, grace=2), st)
+                claim, _stop_process(worker.proc, 2, worker.finishing), st)
             self._gcs("mark_actor_dead", dead_actor)
         # flight recorder (outside the lock: dump() scrapes snapshot()/
         # engine_stats(), which re-take it); no-op unless
@@ -1738,7 +1765,8 @@ class Runtime:
             worker.conn.send(("shutdown",))
         except OSError:
             pass
-        self._return_claim(claim, _stop_process(worker.proc, grace=2), st)
+        self._return_claim(
+            claim, _stop_process(worker.proc, 2, worker.finishing), st)
         self._schedule()  # freed chips/cpus may place queued actors
 
     # -- object plane ---------------------------------------------------------
@@ -1807,7 +1835,7 @@ class Runtime:
             except OSError:
                 pass
         for w in workers:
-            _stop_process(w.proc, grace=1)
+            _stop_process(w.proc, 1, w.finishing)
         if self._gcs_heartbeat is not None:
             self._gcs_heartbeat.stop()
         # airlint: disable=CC001 — shutdown-time teardown: _gcs() holds

@@ -4,8 +4,8 @@ A fixed pool of sequence slots over per-layer KV storage — block-table
 PAGED pools with prefix sharing and chunked prefill (``kvpool/``) — one
 persistent compiled decode step, admission/retirement between steps, and
 per-token streaming back to callers.  The T5 family runs through
-:class:`T5Engine`, a window-level variant over the batch-synchronized T5
-decode entry points.  See docs/SERVING.md for the architecture and the
+:class:`T5Engine`: a slot a request over one resident T5 decode state (a
+ring of self-attention positions, a cross-attention row a slot).  See docs/SERVING.md for the architecture and the
 token-parity contract with offline ``generate``.
 """
 

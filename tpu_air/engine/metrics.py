@@ -136,6 +136,13 @@ class EngineMetrics:
         self.steps_issued = 0
         self.steps_ahead = 0
         self.steps_dropped = 0
+        # slot admission (T5Engine): admission rounds, the rows they
+        # admitted, those of them that joined while other rows were
+        # decoding, and the issued steps by the rows each ran over
+        self.admissions = 0
+        self.rows_admitted = 0
+        self.rows_admitted_in_flight = 0
+        self.steps_by_rows: Dict[int, int] = {}
         # InferenceEngine: the issued steps that carried a prefill chunk in
         # their one program, the chunks that went out so, and those issued
         # alone with the chunk program
@@ -311,14 +318,26 @@ class EngineMetrics:
             self._token_stamps.append((now, tokens))
             self._trim_stamps(now)
 
-    def record_issue(self, ahead: bool, mixed: bool = False) -> None:
+    def record_issue(self, ahead: bool, mixed: bool = False,
+                     rows: Optional[int] = None) -> None:
         """One decode step handed to the device; ``ahead`` when the step
         before it had not been read back yet, ``mixed`` when it carried a
-        prefill chunk."""
+        prefill chunk, ``rows`` the slots it ran over where the engine
+        chooses that a step."""
         with self._lock:
             self.steps_issued += 1
             self.steps_ahead += bool(ahead)
             self.mixed_steps += bool(mixed)
+            if rows is not None:
+                self.steps_by_rows[rows] = self.steps_by_rows.get(rows, 0) + 1
+
+    def record_admission(self, rows: int, in_flight: bool) -> None:
+        """One admission round of ``rows`` requests; ``in_flight`` when other
+        rows were decoding as they joined."""
+        with self._lock:
+            self.admissions += 1
+            self.rows_admitted += rows
+            self.rows_admitted_in_flight += rows if in_flight else 0
 
     def record_chunk(self, fused: bool, pages: int, slot_pages: int) -> None:
         """One prefill chunk handed to the device: ``fused`` into a decode
@@ -351,8 +370,8 @@ class EngineMetrics:
             self.ssd_state_rows_passed += passed
 
     def record_dropped_step(self) -> None:
-        """An issued step nobody read: its window closed (every live row
-        ended on EOS one step earlier) or the engine did."""
+        """An issued step nobody read: every row it decoded for had ended
+        on EOS one step earlier, or the engine closed."""
         with self._lock:
             self.steps_dropped += 1
 
@@ -534,6 +553,11 @@ class EngineMetrics:
                 out["steps_issued"] = self.steps_issued
                 out["steps_ahead"] = self.steps_ahead
                 out["steps_dropped"] = self.steps_dropped
+            if self.admissions:
+                out["admissions"] = self.admissions
+                out["rows_admitted"] = self.rows_admitted
+                out["rows_admitted_in_flight"] = self.rows_admitted_in_flight
+                out["steps_by_rows"] = dict(sorted(self.steps_by_rows.items()))
         out["tokens_per_s"] = self.tokens_per_s()
         return out
 
@@ -593,7 +617,8 @@ def merge_snapshots(snapshots: Dict[str, Dict[str, Any]]) -> Dict[str, Any]:
     out["priority"] = prio
     for key in ("steps_issued", "steps_ahead", "steps_dropped",
                 "chunks_fused", "chunks_alone", "mixed_steps",
-                "chunk_pages_read", "chunk_pages_slot"):
+                "chunk_pages_read", "chunk_pages_slot", "admissions",
+                "rows_admitted", "rows_admitted_in_flight"):
         if any(key in s for s in snaps):
             out[key] = sum(int(s.get(key, 0)) for s in snaps)
     perfs = [s.get("perf") for s in snaps if s.get("perf")]
@@ -689,7 +714,11 @@ _FAMILIES = [
     ("tpu_air_engine_steps_ahead", "counter",
      "decode steps issued before the step before was read back"),
     ("tpu_air_engine_steps_dropped", "counter",
-     "decode steps issued and never read (the window closed on EOS)"),
+     "decode steps issued and never read (their rows had all ended on EOS)"),
+    ("tpu_air_engine_rows_admitted", "counter",
+     "requests admitted to a slot (slot-admission engines)"),
+    ("tpu_air_engine_rows_admitted_in_flight", "counter",
+     "requests admitted to a slot while other rows were decoding"),
     ("tpu_air_engine_roofline_fraction", "gauge",
      "achieved fraction of the analytic roofline (perf ledger totals)"),
     ("tpu_air_engine_flops_per_s", "gauge",
@@ -783,7 +812,8 @@ def prometheus_lines(snapshots: Dict[str, Dict[str, Any]] = None) -> list:
             b.raw(fam, f"{fam}{tag} {val:g}")
         for key in ("reordered_admits", "prefill_chunks", "chunks_fused",
                     "chunks_alone", "mixed_steps", "steps_issued",
-                    "steps_ahead", "steps_dropped"):
+                    "steps_ahead", "steps_dropped", "rows_admitted",
+                    "rows_admitted_in_flight"):
             if key in snap:
                 b.raw(f"tpu_air_engine_{key}",
                       f"tpu_air_engine_{key}{tag} {snap[key]}")

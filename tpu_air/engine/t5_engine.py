@@ -1,49 +1,72 @@
-"""Windowed continuous decoding for the T5 family.
+"""Continuous decoding for the T5 family: a slot a request.
 
-The PR 1 engine entry points for T5 (models/t5/generate.py:
-``make_t5_prefill_fn`` / ``make_t5_decode_step_fn``) are BATCH-
-SYNCHRONIZED: the decode cache carries one scalar cache index and the
-whole batch's cross-attention K/V, so rows cannot sit at different decode
-positions the way the causal-LM slot pool allows.  :class:`T5Engine` is
-therefore a WINDOW engine, honest about that boundary:
+A T5 decode step needs, for every row, the self-attention K/V of the tokens
+the row has decoded and the cross-attention K/V of the row's own prompt.
+``generate`` builds both for one batch that starts and ends together, under
+one scalar cache position.  :class:`T5Engine` keeps ONE decode state for its
+life, ``max_batch`` SLOTS of it, and a request is admitted to a free slot at
+the next step boundary, whatever the other slots are doing:
 
 * requests queue through the same :class:`~tpu_air.engine.scheduler.
   Scheduler` (backpressure, FIFO) and stream back per-token on the same
   :class:`~tpu_air.engine.types.ResponseStream`;
-* a *window* is one prefill (encode + cache build + first token) over up
-  to ``max_batch`` queued requests padded to a fixed shape, followed by
-  per-token decode steps driven between host visits — tokens stream out
-  as they are decoded, rows retire individually on EOS (inclusive) or
-  budget;
-* ADMISSION happens only at window boundaries: a window must fully drain
-  before the next batch starts (the cross-attn K/V of a retired row
-  cannot be swapped out under the scalar index).  Early-retired rows ride
-  along as dead weight until the window closes — exactly the cost the
-  causal-LM slot engine exists to avoid; per-slot cross-attn slabs remain
-  the open item before T5 can join the slot pool (ROADMAP).
+* SELF-ATTENTION IS A RING under the one scalar position.  The self slabs
+  stay position-major ``[ring, slots, h*d]`` a layer and a step appends every
+  row's K/V at the scalar ``cur`` in one block, as before; ``cur`` moves on
+  one every issued step, wraps at ``ring = max_new_tokens + 1`` and never
+  resets.  Each slot has ``born``, the ``cur`` its row was admitted at, and
+  a key at ring position ``k`` is the row's own iff its age ``(cur - k) mod
+  ring`` is at most the row's ``(cur - born) mod ring``: a per-row key mask.
+  T5's relative-position bias is a function of that age alone, so it stays
+  one ``[h, ring]`` row for the batch.  A row lives at most
+  ``max_new_tokens`` steps, so it never laps itself
+  (``models/t5/modeling.Decoder``, ``ring_born``);
+* CROSS-ATTENTION IS A ROW A SLOT.  The cross slabs ``[slots, h, d, Lp]``,
+  the encoder key mask, ``born`` and the token each slot feeds next live on
+  the device for the engine's life, donated through every program.  An
+  ADMIT program encodes ONE prompt at a fixed length (the full input length
+  or, where that is whole 128-lane tiles, a quarter of it), projects its
+  cross K/V and writes them into its slot's row in place, with its mask,
+  ``born = cur`` and ``tok = decoder_start``.  It is issued between two
+  steps on the device's queue; the row's first token comes out of the next
+  ordinary step.  Admission takes the LOWEST free slot, FIFO; the requests
+  an iteration finds waiting are one ROUND (one ``admitted_at``), an admit
+  program each.  (One row a program: at a few rows the encoder is bound by
+  its weights and costs the same for one row as for four, but every
+  program more is 1.3 s of a replica's start, traced and lowered even when
+  the compile cache holds it, and two arrivals in one 3 ms step are one
+  round in twenty-five at ``t5large-serve``'s rate.);
+* A STEP RUNS OVER A PREFIX OF THE SLOTS: the smallest of ``_MIN_STEP_ROWS``
+  doubled up to ``max_batch`` that holds every row it decodes for, chosen
+  by the host as it issues.  Rows of finished requests inside the prefix ride
+  along as dead weight (their tokens are dropped on the host); slots past it
+  cost nothing.  Lowest-free admission keeps the taken slots low;
+* every program the engine can issue is compiled, and run, when the engine
+  is built: none compiles later.
 
-ONE STEP IN FLIGHT.  Within a window the decode loop runs one step ahead
-of its host: step N+1 is issued from step N's tokens as they lie on the
-device (the step's ``int32[b]`` output is the next call's ``tok``; the
-encoder mask is uploaded once, when the window opens), and only then is step
-N read back, emitted and retired from — so the host's whole visit (issue,
-read-back, the walk over the rows) runs under a device step instead of
-between two.  What follows from reading one step late:
+ONE STEP IN FLIGHT.  The decode loop runs one step ahead of its host: step
+N+1 is issued from step N's tokens as they lie on the device and only then is step N read back, emitted and retired
+from, so the host's whole visit runs under a device step instead of between
+two.  What follows from reading one step late:
 
-* budgets are host state, so a step is issued only if some row that was live
-  after the last processed read-back still has budget for it: a window whose
-  rows end on their budgets issues exactly the steps it reads;
+* budgets are host state, so a row is in a step only if it has budget for the
+  token: a row that ends on its budget is in exactly the steps it reads;
 * a row that ends on EOS is learnt of one step late.  It rides along for
-  that step the way retired rows ride until the window closes, and its extra
-  token is discarded, never emitted;
-* if the last live rows all end on EOS, the one issued step is dropped with
-  the window's cache (``steps_dropped``); the next window's prefill queues
-  behind it on the device.
+  that step and its extra token is discarded, never emitted.  Its slot is
+  free from the read-back that learnt of it: a request admitted to it goes to
+  the device BEHIND the step that still carried the old row;
+* an issued step whose rows have all ended by the time it would be read is
+  dropped unread (``steps_dropped``); what comes next queues behind it.
 
-Depth is one and fixed.  The step path uploads nothing.
+Depth is one and fixed.  The step path uploads nothing; an admission uploads
+one small array.
 
-Greedy by construction: token streams are identical to offline T5
-``generate`` with ``early_stop=True`` on the same window batch.
+Greedy by construction: a request's token stream is identical to offline T5
+``generate`` of its prompt alone, whenever it was admitted.
+
+ACCOUNTING.  ``engine.window_close`` (docs/OBSERVABILITY.md) is what is left
+of the window this engine used to be: one event every ``max_new_tokens``
+token steps read, with what those steps did.
 """
 
 from __future__ import annotations
@@ -51,15 +74,16 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass
-from typing import List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax.numpy as jnp
 
 from tpu_air.models.t5.generate import (
-    make_t5_decode_step_fn,
-    make_t5_prefill_fn,
+    init_slot_state,
+    make_t5_admit_fn,
+    make_t5_slot_step_fn,
 )
 from tpu_air.observability.profiler import phase
 
@@ -74,17 +98,21 @@ from .types import (
     ResponseStream,
 )
 
+# A step over fewer rows than this reads the same weights and a cache that is
+# small beside them: 16 rows are one bf16 tile of the slabs' row dimension.
+_MIN_STEP_ROWS = 16
+_LANES = 128
+
 
 @dataclass
 class T5EngineConfig:
-    """Dials for the T5 window engine.
+    """Dials for the T5 slot engine.
 
-    * ``max_batch`` — rows per window (the fixed prefill/decode batch
-      shape; short windows pad with dead all-pad rows).
-    * ``max_input_len`` — encoder-side prompt cap; prompts right-pad to
-      this fixed length so one compiled prefill serves every window.
-    * ``max_new_tokens`` — decode budget cap per request (the cache is
-      sized to it).
+    * ``max_batch`` — the slots: rows that can decode at once.
+    * ``max_input_len`` — encoder-side prompt cap; the cross slabs are sized
+      to it.
+    * ``max_new_tokens`` — decode budget cap per request (the self-attention
+      ring is sized to it).
     * ``max_queue`` — queued request cap; beyond it ``submit`` raises
       :class:`EngineOverloadedError`.
     * ``queue_shares`` — per-priority-class fraction of ``max_queue`` at
@@ -96,7 +124,7 @@ class T5EngineConfig:
     max_input_len: int = 64
     max_new_tokens: int = 32
     max_queue: int = 256
-    reorder_window: int = 0  # window admission is FIFO; kept for Scheduler
+    reorder_window: int = 0  # slot admission is FIFO; kept for Scheduler
     queue_shares: Optional[dict] = None
 
     def queue_cap(self, priority: str) -> int:
@@ -108,39 +136,27 @@ class T5EngineConfig:
         return int(self.max_queue * float(shares.get(priority, 1.0)))
 
 
-class _Window:
-    """One in-flight batch: device state + per-row host bookkeeping.
+class _Issued(NamedTuple):
+    """One issued step the host has not read back: its ``int32[slots]``
+    device tokens, the ``(slot, request)`` it decodes for (a request that has
+    ended since is skipped at the read) and the prefix it ran over."""
 
-    ``cache``, ``enc`` and ``enc_mask`` live on the device for the window's
-    life.  ``unread`` is the ``int32[b]`` device output of the one issued
-    step the host has not read back yet: the next step's ``tok``, and what
-    the next read-back copies.  ``mark`` is when the stream's current token
-    step began: the end of the last read-back, or for a window's first step
-    its issue.  What the window did, said once as it closes
-    (``engine.window_close``): ``opened_at``, the ``rows`` it admitted, the
-    token ``steps`` it emitted from (its prefill and every step read back)
-    and ``live_row_steps``, the rows live in each of them summed (the
-    tokens it emitted)."""
+    tokens: object
+    rows: List[Tuple[int, Request]]
+    batch: int
 
-    def __init__(self, requests: List[Optional[Request]], cache, enc,
-                 enc_mask, opened_at: float):
-        self.requests = requests
-        self.cache = cache
-        self.enc = enc
-        self.enc_mask = enc_mask
-        self.unread = None
-        self.mark = 0.0
-        self.budget_left = np.zeros((len(requests),), np.int64)
-        self.opened_at = opened_at
-        self.rows = self.live_row_steps = len(self.live_rows())
-        self.steps = 1
 
-    def live_rows(self):
-        return [i for i, r in enumerate(self.requests) if r is not None]
+def _buckets(smallest: int, largest: int) -> List[int]:
+    """``smallest`` doubled while under ``largest``, then ``largest``."""
+    out, n = [], min(smallest, largest)
+    while n < largest:
+        out.append(n)
+        n *= 2
+    return out + [largest]
 
 
 class T5Engine:
-    """Window-level continuous decoding over a T5 model (see module doc)."""
+    """Slot-level continuous decoding over a T5 model (see module doc)."""
 
     def __init__(self, model, params, config: Optional[T5EngineConfig] = None,
                  *, auto_start: bool = True, name: str = "t5-engine"):
@@ -152,12 +168,28 @@ class T5Engine:
         self.pad_token_id = model.config.pad_token_id
 
         cfg = self.config
-        self._prefill = make_t5_prefill_fn(model, cfg.max_new_tokens + 1)
-        self._decode_step = make_t5_decode_step_fn(model)
-        self._window: Optional[_Window] = None
+        slots, li = cfg.max_batch, cfg.max_input_len
+        self._steps = {rows: make_t5_slot_step_fn(model, rows)
+                       for rows in _buckets(_MIN_STEP_ROWS, slots)}
+        short = li // 4 if li % (4 * _LANES) == 0 else li
+        self._admits = {length: make_t5_admit_fn(model, length)
+                        for length in sorted({short, li})}
+        # the decode state, donated through every program, and the token
+        # each slot feeds next, which is not (see ``init_slot_state``)
+        self._state, self._tok = init_slot_state(
+            model, params, slots, cfg.max_new_tokens + 1, li)
+        # slot -> the request decoding in it, and the tokens it may still emit
+        self._rows: List[Optional[Request]] = [None] * slots
+        self._budget_left = np.zeros((slots,), np.int64)
+        self._unread: Optional[_Issued] = None
+        # when the stream's current token step began: the end of the last
+        # read-back, or the issue of a step with none before it
+        self._mark = 0.0
+        self._span = self._new_span()
+        self._warm()
 
         self.scheduler = Scheduler(cfg)
-        self.metrics = EngineMetrics(name=name, num_slots=cfg.max_batch)
+        self.metrics = EngineMetrics(name=name, num_slots=slots)
 
         self._next_request_id = 0
         self._id_lock = threading.Lock()
@@ -168,14 +200,30 @@ class T5Engine:
         if auto_start:
             self.start()
 
+    def _warm(self) -> None:
+        """Run every program once over the empty state, then once more over
+        a state that is a program's output, as every later call's is:
+        nothing is traced, compiled or loaded after this.  The slots stay
+        free; what the runs left in slot 0 and where the ring stands mean
+        nothing to a row admitted later."""
+        for _ in range(2):
+            for length, admit in self._admits.items():
+                prompts = np.zeros((1, length + 2), np.int32)
+                prompts[:, length] = 1
+                self._state, self._tok = admit(
+                    self.params, self._state, self._tok, jnp.asarray(prompts))
+            for step in self._steps.values():
+                self._state, self._tok = step(
+                    self.params, self._state, self._tok)
+
     # -- submission (any thread) ---------------------------------------------
     def submit(self, input_ids: Sequence[int],
                max_new_tokens: Optional[int] = None, *,
                priority: str = "interactive") -> ResponseStream:
         """Queue one encoder prompt; returns its token stream immediately.
         ``priority`` follows the same SLO-class contract as the causal-LM
-        engine (admission is window-FIFO here, but shed thresholds and
-        per-class gauges still apply)."""
+        engine (admission is FIFO through the classes here; shed thresholds
+        and per-class gauges apply)."""
         if self._closed:
             raise EngineClosedError("engine is shut down")
         if self._draining:
@@ -227,35 +275,30 @@ class T5Engine:
 
     # -- the engine loop -----------------------------------------------------
     def step(self) -> bool:
-        """One engine iteration: open a window if none is in flight (one
-        prefill over the queued batch, and the window's first decode step
-        issued behind it), else one token step: issue the next decode step,
-        then read back and emit the one before.  Returns True if any work
-        happened."""
+        """One engine iteration: admit queued requests to free slots (an
+        admit program each, queued on the device behind the step in
+        flight), then one token step: issue the next decode step, then read
+        back and emit the one before.  Returns True if any work happened."""
         with self._step_lock:
-            worked = False
-            if self._window is None:
-                worked = self._open_window()
-            elif self._window is not None:
-                self._decode_window()
-                worked = True
-            occ = len(self._window.live_rows()) if self._window else 0
+            worked = self._admit()
+            worked = self._token_step() or worked
             self.metrics.observe_gauges(
-                self.scheduler.depth(), occ,
+                self.scheduler.depth(),
+                sum(r is not None for r in self._rows),
                 queue_by_class=self.scheduler.depth_by_class(),
                 draining=self._draining,
             )
             return worked
 
     def idle(self) -> bool:
-        # an issued step lives in its window (``_Window.unread``) and a
-        # window closes when nothing is left to read: no window, no step
-        with self._step_lock:  # _window is step-loop state (see step())
-            return self.scheduler.depth() == 0 and self._window is None
+        # a step is unread only while a row it decodes for is live
+        with self._step_lock:  # _rows is step-loop state (see step())
+            return (self.scheduler.depth() == 0
+                    and all(r is None for r in self._rows))
 
     # -- draining (same contract as InferenceEngine.drain) -------------------
     def drain(self) -> None:
-        """Refuse new submits; queued + in-window work retires normally."""
+        """Refuse new submits; queued + decoding work retires normally."""
         # airlint: disable=CC001 — monotonic GIL-atomic bool, flips
         # False→True once; a racing step() reads either value correctly
         self._draining = True
@@ -267,120 +310,129 @@ class T5Engine:
     def drained(self) -> bool:
         return self._draining and self.idle()
 
-    def _open_window(self) -> bool:
-        reqs = self.scheduler.pop_admissible(self.config.max_batch)
+    def _admit(self) -> bool:
+        """One round of admission: the queued requests the free slots can
+        take, lowest slot first, an admit program each."""
+        if not self.scheduler.depth():
+            return False
+        free = [s for s, r in enumerate(self._rows) if r is None]
+        reqs = self.scheduler.pop_admissible(len(free)) if free else []
         if not reqs:
             return False
+        in_flight = len(free) < len(self._rows)
         with phase("engine.prefill", rows=len(reqs),
-                   batch=self.config.max_batch,
-                   queued=self.scheduler.depth()):
-            self._prefill_window(reqs)
+                   queued=self.scheduler.depth(), in_flight=int(in_flight)):
+            for req, slot in zip(reqs, free):
+                length = min(n for n in self._admits if n >= len(req.prompt))
+                prompt = np.full((1, length + 2), self.pad_token_id, np.int32)
+                prompt[0, :len(req.prompt)] = req.prompt
+                prompt[0, length:] = len(req.prompt), slot
+                self._state, self._tok = self._admits[length](
+                    self.params, self._state, self._tok, jnp.asarray(prompt))
+                self._rows[slot] = req
+                self._budget_left[slot] = req.max_new_tokens
+        self.metrics.record_admission(len(reqs), in_flight)
+        self._span["rows"] += len(reqs)
         return True
 
-    def _prefill_window(self, reqs: List[Request]) -> None:
-        cfg = self.config
-        b, li = cfg.max_batch, cfg.max_input_len
-        ids = np.full((b, li), self.pad_token_id, np.int32)
-        mask = np.zeros((b, li), np.int32)
-        for row, req in enumerate(reqs):
-            ids[row, :len(req.prompt)] = req.prompt
-            mask[row, :len(req.prompt)] = 1
-        # rows past len(reqs) are dead filler: all-pad, zero mask — their
-        # decode outputs are discarded host-side
-        mask_dev = jnp.asarray(mask)
-        tok_dev, cache, enc = self._prefill(
-            self.params, jnp.asarray(ids), mask_dev)
-        tok = np.asarray(tok_dev)
-        rows: List[Optional[Request]] = list(reqs) + [None] * (b - len(reqs))
-        # every row was admitted by one round: one reading opened the window
-        win = _Window(rows, cache, enc, mask_dev, reqs[0].admitted_at)
-        now = time.monotonic()
-        emitted = 0
-        for row, req in enumerate(reqs):
-            first = int(tok[row])
-            self.metrics.record_ttft(*req.first_token(now, chunks=1),
-                                     req.priority)
-            req.stream._emit(first)
-            emitted += 1
-            win.budget_left[row] = req.max_new_tokens - 1
-            if win.budget_left[row] == 0 or first == self.eos_token_id:
-                self._retire(win, row)
-        self.metrics.record_tokens(emitted)
-        self._window = win
-        if win.live_rows():
-            # the window's first step, from the prefill's tokens as they
-            # lie on the device; nothing is in flight, so it is not ahead
-            self._issue(win, tok_dev, ahead=False)
-            win.mark = time.monotonic()
-        else:
-            self._drop_window()  # every row ended on its first token
-
-    def _issue(self, win: _Window, tok, ahead: bool) -> None:
-        """Issue one decode step from the device tokens ``tok``.  The cache
-        is donated; ``tok`` is not, so the host can still read it back."""
-        win.cache, win.unread = self._decode_step(
-            self.params, win.cache, tok, win.enc, win.enc_mask)
-        self.metrics.record_issue(ahead)
-
-    def _decode_window(self) -> None:
-        win = self._window
-        live = win.live_rows()
-        # budgets are host state: step N+1 is worth issuing only if a live
-        # row has a token left after the one step N holds for it
-        ahead = any(win.budget_left[row] >= 2 for row in live)
+    def _token_step(self) -> bool:
+        unread, self._unread = self._unread, None
+        owed = {id(req) for _, req in unread.rows} if unread else ()
+        # budgets are host state: a row is in the step to issue only if it
+        # has a token left after the one the unread step holds for it
+        want = [(slot, req) for slot, req in enumerate(self._rows)
+                if req is not None
+                and self._budget_left[slot] - (id(req) in owed) >= 1]
+        # a row of the unread step that has ended since (on EOS, learnt at
+        # the last read-back) is not read for
+        live = [(slot, req) for slot, req in (unread.rows if unread else ())
+                if self._rows[slot] is req]
+        if not want and not live:
+            return False
         with phase("engine.step", live=len(live),
-                   batch=self.config.max_batch, ahead=int(ahead)):
-            unread, win.unread = win.unread, None
-            if ahead:
-                # out before step N is read: the device runs it while the
-                # host reads, emits and retires below
+                   batch=unread.batch if unread else 0, ahead=int(bool(want))):
+            if want:
+                # out before the step before is read: the device runs it
+                # while the host reads, emits and retires below
                 with phase("engine.dispatch"):
-                    self._issue(win, unread, ahead=True)
-            with phase("engine.readback"):
-                nxt = np.asarray(unread)
-            # what this token step cost the stream: read-back to read-back
-            now = time.monotonic()
-            dt, win.mark = now - win.mark, now
-            # one phase around the walk over the live rows, none per row
-            with phase("engine.emit", emitted=len(live)):
-                for row in live:
-                    # airlint: disable=JX004 — nxt is the np.asarray'd step
-                    # result; the single device sync already happened above
-                    token = int(nxt[row])
-                    req = win.requests[row]
-                    req.stream._emit(token)
-                    win.budget_left[row] -= 1
-                    if win.budget_left[row] == 0 or token == self.eos_token_id:
-                        self._retire(win, row)
-                self.metrics.record_step(dt, len(live))
-                win.steps += 1
-                win.live_row_steps += len(live)
-                if not win.live_rows():
-                    # window drained: drop its cache, admit the next batch
-                    # on the following step
-                    self._drop_window()
+                    self._issue(want, ahead=unread is not None)
+            if live:
+                self._read(unread, live)
+            if self._unread is not None and not any(
+                    self._rows[slot] is req for slot, req in self._unread.rows):
+                # every row it decodes for has ended: nobody will read it
+                self._drop_unread()
+        return True
 
-    def _drop_window(self) -> None:
-        """Close the window.  A step still unread (its last live rows ended
-        on EOS, or the engine is closing) is dropped with the cache: the
-        device finishes it and whatever comes next queues behind.  One
-        ``engine.window_close`` says what the whole window did: how many of
-        its ``steps`` x ``batch`` row-steps had a live row, how long it was
-        open and the depth it left waiting."""
-        win, self._window = self._window, None
-        if win.unread is not None:
-            self.metrics.record_dropped_step()
-        batch = self.config.max_batch
-        with phase("engine.window_close", steps=win.steps, rows=win.rows,
-                   batch=batch, live_row_steps=win.live_row_steps,
-                   row_steps=win.steps * batch,
-                   us=int((time.monotonic() - win.opened_at) * 1e6),
+    def _issue(self, want: List[Tuple[int, Request]], ahead: bool) -> None:
+        """Issue one decode step over the smallest prefix of the slots that
+        holds every row of ``want``.  The state is donated; the tokens are
+        not, so the host can still read them after the next issue."""
+        rows = min(r for r in self._steps if r > want[-1][0])
+        self._state, self._tok = self._steps[rows](
+            self.params, self._state, self._tok)
+        self._unread = _Issued(self._tok, want, rows)
+        self.metrics.record_issue(ahead, rows=rows)
+        if not ahead:
+            self._mark = time.monotonic()
+
+    def _read(self, unread: _Issued, live: List[Tuple[int, Request]]) -> None:
+        with phase("engine.readback"):
+            nxt = np.asarray(unread.tokens)
+        # what this token step cost the stream: read-back to read-back
+        now = time.monotonic()
+        span = self._span
+        if not span["steps"]:
+            span["opened_at"] = self._mark      # where its first step began
+        dt, self._mark = now - self._mark, now
+        # one phase around the walk over the live rows, none per row
+        with phase("engine.emit", emitted=len(live)):
+            for slot, req in live:
+                # airlint: disable=JX004 — nxt is the np.asarray'd step
+                # result; the single device sync already happened above
+                token = int(nxt[slot])
+                if req.first_token_at is None:
+                    self.metrics.record_ttft(*req.first_token(now, chunks=1),
+                                             req.priority)
+                req.stream._emit(token)
+                self._budget_left[slot] -= 1
+                if self._budget_left[slot] == 0 or token == self.eos_token_id:
+                    self._retire(slot)
+            self.metrics.record_step(dt, len(live))
+            span["steps"] += 1
+            span["live_row_steps"] += len(live)
+            span["row_steps"] += unread.batch
+            if span["steps"] == self.config.max_new_tokens:
+                self._close_span()
+
+    def _drop_unread(self) -> None:
+        """Forget the issued step: the device finishes it and whatever comes
+        next queues behind."""
+        self._unread = None
+        self.metrics.record_dropped_step()
+
+    def _new_span(self) -> Dict[str, float]:
+        return {"steps": 0, "rows": 0, "live_row_steps": 0, "row_steps": 0,
+                "opened_at": 0.0}
+
+    def _close_span(self) -> None:
+        """One ``engine.window_close`` says what the last ``max_new_tokens``
+        token steps read did: the ``rows`` admitted meanwhile, how many of the
+        ``row_steps`` they ran over (the prefix of each, summed) had a live
+        row, how long they took (the first one's start to the last one's
+        read-back) and the depth left waiting."""
+        span, self._span = self._span, self._new_span()
+        with phase("engine.window_close", steps=span["steps"],
+                   rows=span["rows"], batch=self.config.max_batch,
+                   live_row_steps=span["live_row_steps"],
+                   row_steps=span["row_steps"],
+                   us=int((self._mark - span["opened_at"]) * 1e6),
                    queued=self.scheduler.depth()):
             pass
 
-    def _retire(self, win: _Window, row: int) -> None:
-        win.requests[row].stream._finish()
-        win.requests[row] = None
+    def _retire(self, slot: int) -> None:
+        self._rows[slot].stream._finish()
+        self._rows[slot] = None
         self.metrics.record_complete()
 
     # -- background loop / lifecycle -----------------------------------------
@@ -411,10 +463,12 @@ class T5Engine:
             err = EngineClosedError("engine shut down")
             for req in self.scheduler.drain():
                 req.stream._finish(err)
-            if self._window is not None:
-                for row in self._window.live_rows():
-                    self._window.requests[row].stream._finish(err)
-                self._drop_window()
+            for slot, req in enumerate(self._rows):
+                if req is not None:
+                    req.stream._finish(err)
+                    self._rows[slot] = None
+            if self._unread is not None:
+                self._drop_unread()
         unregister(self.name)
 
     def __enter__(self) -> "T5Engine":

@@ -3,9 +3,10 @@
 from .config import T5Config
 from .generate import (
     generate,
+    init_slot_state,
     make_generate_fn,
-    make_t5_decode_step_fn,
-    make_t5_prefill_fn,
+    make_t5_admit_fn,
+    make_t5_slot_step_fn,
 )
 from .hf_import import config_from_hf, convert_t5_state_dict, load_t5_from_hf
 from .modeling import (
@@ -21,9 +22,10 @@ __all__ = [
     "convert_t5_state_dict",
     "cross_entropy_loss",
     "generate",
+    "init_slot_state",
     "load_t5_from_hf",
     "make_generate_fn",
-    "make_t5_decode_step_fn",
-    "make_t5_prefill_fn",
+    "make_t5_admit_fn",
+    "make_t5_slot_step_fn",
     "shift_right",
 ]

@@ -57,18 +57,23 @@ def init_cache(model, params, batch_size: int, max_decode_len: int,
         method=model.init_decode_cache,
     )
 
-    def graft(dst, src, under_cross=False):
-        for k, v in src.items():
-            if isinstance(v, dict):
-                graft(dst[k], v, under_cross or k == "cross_attn")
-            elif under_cross:
-                dst[k] = v
-
     from flax.core import unfreeze
 
-    cache = unfreeze(cache)
-    graft(cache, unfreeze(vars1["cache"]))
-    return cache
+    return _graft_cross(unfreeze(cache), unfreeze(vars1["cache"]),
+                        lambda dst, src: src)
+
+
+def _graft_cross(dst, src, leaf, under_cross=False):
+    """The cache tree ``dst`` with ``leaf(dst[k], src[k])`` in place of
+    every array that ``src`` holds under a ``cross_attn`` module."""
+    out = dict(dst)
+    for k, v in src.items():
+        if isinstance(v, dict):
+            out[k] = _graft_cross(dst[k], v, leaf,
+                                  under_cross or k == "cross_attn")
+        elif under_cross:
+            out[k] = leaf(dst[k], v)
+    return out
 
 
 def per_layer_slabs(cache):
@@ -180,62 +185,112 @@ def make_generate_fn(
 
 
 # ---------------------------------------------------------------------------
-# Continuous-batching entry points (tpu_air.engine)
+# Continuous-batching entry points (tpu_air.engine.T5Engine)
 #
-# make_generate_fn keeps the encode+cache-build prefill and the per-token
-# decode private inside one jitted program.  These expose the two phases as
-# standalone compiled units so an online engine can admit/retire between
-# steps.  Encoder-decoder caveat: the decode cache carries the CROSS-
-# attention K/V of the whole batch's encoder output, so these entry points
-# are batch-synchronized (one scalar cache index — every row at the same
-# decode position); per-slot cross-attn slabs are the remaining work before
-# the slot engine (engine/engine.py) can drive the T5 family.
+# make_generate_fn keeps encode, cache build and the per-token decode private
+# inside one jitted program, over one batch that starts and ends together.
+# An online engine's rows come and go, so it keeps ONE decode state for its
+# life, a SLOT a row, and runs two kinds of program over it, each with the
+# state donated: an ADMIT program that encodes a few prompts and writes each
+# into its slot, and a STEP program that decodes one token for the first
+# ``rows`` slots.  What lets rows of different ages share the decoder's one
+# scalar position is in ``modeling.Decoder`` (``ring_born``).
 # ---------------------------------------------------------------------------
 
 
-def make_t5_prefill_fn(model: T5ForConditionalGeneration,
-                       max_decode_len: int):
-    """Build a jitted ``fn(params, input_ids, attention_mask) ->
-    (first_tok, cache, enc_hidden)``: encode the prompts, build the decode
-    cache (self-attn slabs zeroed, cross-attn K/V computed from the encoder
-    output — the prefill-into-segment), and run the first decode step from
-    ``decoder_start_token_id``, returning the first greedy token."""
+def init_slot_state(model: T5ForConditionalGeneration, params, slots: int,
+                    ring_len: int, input_len: int):
+    """``(state, tok)`` for ``slots`` rows, zeroed.  ``state`` is what the
+    programs below take donated: ``cache`` as ``init_cache`` shapes it for a
+    batch of ``slots`` (self slabs a tuple a layer, ``[ring_len, slots,
+    h*d]``: a ring, so a row may live ``ring_len - 1`` steps; cross slabs
+    ``[slots, h, d, Lp]``; ``decoder_pos`` the ring's position), ``enc_mask``
+    int32 ``[slots, input_len]`` and ``born`` int32 ``[slots]`` (the ring
+    position a slot's row was admitted at).  ``tok`` int32 ``[slots]`` is the
+    token each slot's row feeds its next step.  It goes through the programs
+    beside the state and is never donated: a step's ``tok`` is what the
+    caller reads its tokens from, after the state has gone on to the next
+    program.  (As a second result cut from a donated ``tok``, the v5e's
+    compiler holds 47 of FLAN-T5-large's 48 self slabs in fast memory through
+    a 64-row step and writes each back whole; tests/test_chip_compile.py.)"""
+    cfg: T5Config = model.config
+    enc = jax.ShapeDtypeStruct((slots, input_len, cfg.d_model),
+                               jnp.dtype(cfg.dtype))
+    mask = jax.ShapeDtypeStruct((slots, input_len), jnp.int32)
+    shapes = jax.eval_shape(
+        lambda p, e, m: per_layer_slabs(
+            init_cache(model, p, slots, ring_len, e, m)), params, enc, mask)
+
+    def zeros(s):
+        return jnp.zeros(s.shape, s.dtype)
+
+    row = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    return {"cache": jax.tree_util.tree_map(zeros, shapes),
+            "enc_mask": zeros(mask), "born": zeros(row)}, zeros(row)
+
+
+def make_t5_admit_fn(model: T5ForConditionalGeneration, length: int):
+    """Build a jitted ``fn(params, state, tok, prompt) -> (state, tok)``
+    with the state donated: encode ONE prompt padded to ``length`` and write
+    it into its slot in place: its cross-attention K/V (and their int8
+    scales) into the slot's row of every cross slab, its key mask, ``born``
+    = the ring's position and ``tok`` = ``decoder_start_token_id``, so that
+    the next step over the slot decodes the row's first token.  ``prompt``
+    is ONE int32 ``[1, length + 2]`` upload: the prompt's ids, then its
+    length, then its slot.  What a slot's longer earlier prompt left past
+    ``length`` lanes stays there, behind the new mask's zeros."""
     cfg: T5Config = model.config
 
-    @jax.jit
-    def prefill(params, input_ids, attention_mask):
-        batch = input_ids.shape[0]
-        enc = model.apply(
-            {"params": params}, input_ids, attention_mask, method=model.encode
-        )
-        cache = per_layer_slabs(init_cache(
-            model, params, batch, max_decode_len, enc, attention_mask))
-        tok0 = jnp.full((batch, 1), cfg.decoder_start_token_id, jnp.int32)
-        logits, vars_ = model.apply(
-            {"params": params, "cache": cache}, tok0, enc, attention_mask,
-            decode=True, mutable=["cache"], method=model.decode,
-        )
-        tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return tok, vars_["cache"], enc
-
-    return prefill
-
-
-def make_t5_decode_step_fn(model: T5ForConditionalGeneration):
-    """Build a jitted single-token decode step ``fn(params, cache, tok,
-    enc_hidden, enc_mask) -> (cache', next_tok)`` with the cache donated —
-    the per-step unit an online loop re-invokes, greedy (the engine parity
-    anchor)."""
-    from functools import partial
+    def into_slot(dst, src, slot):
+        return jax.lax.dynamic_update_slice(
+            dst, src, (slot[0],) + (0,) * (src.ndim - 1))
 
     @partial(jax.jit, donate_argnums=(1,))
-    def step(params, cache, tok, enc_hidden, enc_mask):
+    def admit(params, state, tok, prompt):
+        ids, n, slot = (prompt[:, :length], prompt[:, length],
+                        prompt[:, length + 1])
+        mask = (jnp.arange(length)[None, :] < n[:, None]).astype(jnp.int32)
+        enc = model.apply({"params": params}, ids, mask, method=model.encode)
+        _, made = model.apply(
+            {"params": params}, jnp.zeros((1, 1), jnp.int32), enc, mask,
+            mutable=["cache"], method=model.init_decode_cache)
+        cache = _graft_cross(state["cache"], made["cache"],
+                             lambda dst, src: into_slot(dst, src, slot))
+        spare = state["enc_mask"].shape[1] - length
+        return {
+            "cache": cache,
+            "enc_mask": into_slot(state["enc_mask"],
+                                  jnp.pad(mask, ((0, 0), (0, spare))), slot),
+            "born": state["born"].at[slot].set(
+                cache["decoder"]["decoder_pos"]),
+        }, tok.at[slot].set(cfg.decoder_start_token_id)
+
+    return admit
+
+
+def make_t5_slot_step_fn(model: T5ForConditionalGeneration, rows: int):
+    """Build a jitted greedy single-token step ``fn(params, state, tok) ->
+    (state, tok)`` over the first ``rows`` slots, the state donated: each
+    feeds its ``tok``, attends to the ring positions written since its
+    ``born`` and to its own cross row, the step's K/V are appended at the
+    ring's position for those rows, the position moves on one (for every
+    slot: the others' rows are not touched and their ``born`` still tells
+    their age), and the new tokens take their slots' places in ``tok``."""
+
+    @partial(jax.jit, donate_argnums=(1,))
+    def step(params, state, tok):
+        mask = state["enc_mask"]
+        # the cached cross-attention asks the encoder output its length alone
+        enc = jnp.zeros((rows, mask.shape[1], 1), jnp.dtype(model.config.dtype))
         logits, vars_ = model.apply(
-            {"params": params, "cache": cache}, tok[:, None], enc_hidden,
-            enc_mask, decode=True, mutable=["cache"], method=model.decode,
+            {"params": params, "cache": state["cache"]},
+            tok[:rows, None], enc, mask[:rows], decode=True,
+            ring_born=state["born"][:rows], mutable=["cache"],
+            method=model.decode,
         )
         nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return vars_["cache"], nxt
+        return ({**state, "cache": vars_["cache"]},
+                jax.lax.dynamic_update_slice(tok, nxt, (0,)))
 
     return step
 

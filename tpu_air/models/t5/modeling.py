@@ -309,6 +309,12 @@ class Attention(nn.Module):
                     self.get_variable("cache", "cached_key_scale"),
                     self.get_variable("cache", "cached_value_scale"),
                 )
+            if k.shape[0] > q.shape[0]:
+                # a cache of more rows than the step has: the step runs over
+                # the first rows of it (``Decoder``, a step over a prefix)
+                k, v = k[:q.shape[0]], v[:q.shape[0]]
+                dk_scales = tuple(
+                    s if s is None else s[:q.shape[0]] for s in dk_scales)
             # the lanes ``length_minor`` added hold no key: masked for
             # every row, whatever the caller's mask says of the real ones
             klp = k.shape[-1]
@@ -668,7 +674,7 @@ class Decoder(nn.Module):
     @nn.compact
     def __call__(
         self, embeds, enc, enc_mask, dec_mask=None,
-        decode=False, deterministic=True,
+        decode=False, deterministic=True, ring_born=None,
     ):
         cfg = self.config
         dtype = _dtype(cfg)
@@ -698,6 +704,20 @@ class Decoder(nn.Module):
             # parameters, and the compiler copies every slice it reads of a
             # parameter out before use; a layer's own [L, b, h*d] parameter
             # it leaves in HBM, because a position is a whole block of it.
+            #
+            # Two things a caller whose rows come and go asks for
+            # (engine/t5_engine.py), both through what is here already.
+            # ``ring_born`` int32 [b]: the slabs are a RING of positions.
+            # ``decoder_pos`` wraps at the slabs' length and never resets,
+            # and a row sees the keys written since the position it was
+            # born at: a key's age is ``(pos - k) mod L``, a row's
+            # ``(pos - born) mod L``, and the key is the row's own iff it is
+            # no older than the row (a per-row key mask in the causal row's
+            # place).  The relative-position bias is a function of a key's
+            # age alone, so it stays one row for the batch.  A row may live
+            # ``L - 1`` steps.  And slabs of MORE ROWS than ``embeds`` has:
+            # the step runs over the first rows of every slab and appends to
+            # those (the cross slabs: ``Attention``).
             is_init = not self.has_variable("cache", "self_keys")
             cache_int8 = getattr(cfg, "decode_cache_int8", False)
             nl, bsz = cfg.num_decoder_layers, embeds.shape[0]
@@ -717,16 +737,25 @@ class Decoder(nn.Module):
                 ]
             query_positions = pos.value + jnp.arange(qlen)
             key_positions = jnp.arange(klen)
-            bias = RelativePositionBias(cfg, bidirectional=False, name="rel_bias")(
-                query_positions, key_positions
-            )
-            causal = (
-                key_positions[None, :] <= query_positions[:, None]
-            ).astype(jnp.float32)
-            self_mask = ((1.0 - causal[None, None]) * NEG_INF).astype(dtype)
+            rel_bias = RelativePositionBias(cfg, bidirectional=False,
+                                            name="rel_bias")
+            if ring_born is None:
+                bias = rel_bias(query_positions, key_positions)
+                causal = (
+                    key_positions[None, :] <= query_positions[:, None]
+                ).astype(jnp.float32)
+                self_masks = dict(
+                    self_mask=((1.0 - causal[None, None]) * NEG_INF
+                               ).astype(dtype))
+            else:
+                age = (pos.value - key_positions) % klen
+                bias = rel_bias(jnp.zeros((1,), jnp.int32), -age)
+                self_masks = dict(self_kv_mask=(
+                    age[None, :] <= ((pos.value - ring_born) % klen)[:, None]
+                ).astype(jnp.float32))
             x = embeds
             rows = []
-            kwargs = dict(self_mask=self_mask, cross_kv_mask=enc_mask,
+            kwargs = dict(**self_masks, cross_kv_mask=enc_mask,
                           decode=True, deterministic=deterministic)
             for i in range(nl):
                 layer = DecoderLayer(cfg, name=f"layer_{i}")
@@ -734,6 +763,9 @@ class Decoder(nn.Module):
                     x = layer(x, enc, bias, **kwargs)
                     continue
                 own = [s.value[i] for s in slabs]
+                if own[0].shape[1] > bsz:            # a step over a prefix
+                    with jax.named_scope("self_attn/kv_gather"):
+                        own = [o[:, :bsz] for o in own]
                 own += [None] * (4 - len(own))       # no scales
                 x, new = layer(x, enc, bias, **kwargs,
                                appending=(*own, pos.value))
@@ -755,6 +787,8 @@ class Decoder(nn.Module):
                             s.value = jax.lax.dynamic_update_slice(
                                 s.value, jnp.stack(new), (0, pos.value, 0, 0))
                 pos.value = pos.value + qlen
+                if ring_born is not None:
+                    pos.value = pos.value % klen
             return RMSNorm(cfg.layer_norm_epsilon, dtype, name="final_ln")(x)
 
         positions = jnp.arange(qlen)
@@ -824,12 +858,12 @@ class T5ForConditionalGeneration(nn.Module):
     def decode(
         self, decoder_input_ids, encoder_hidden, encoder_mask,
         decoder_attention_mask=None, decode: bool = False,
-        deterministic: bool = True,
+        deterministic: bool = True, ring_born=None,
     ):
         hidden = self.decoder(
             self.shared(decoder_input_ids), encoder_hidden, encoder_mask,
             dec_mask=decoder_attention_mask, decode=decode,
-            deterministic=deterministic,
+            deterministic=deterministic, ring_born=ring_born,
         )
         return self._head(hidden)
 

@@ -100,7 +100,7 @@ class _EngineServer:
                     params,
                 )
             # the config type picks the engine family: a T5EngineConfig
-            # gets the window engine (batch-synchronized T5 decode), any
+            # gets the T5 slot engine (engine/t5_engine.py), any
             # EngineConfig (or None) the causal-LM slot/page engine
             if isinstance(self._engine_config, T5EngineConfig):
                 if self._mesh or self._disagg:
@@ -218,7 +218,7 @@ class _EngineServer:
                tenant: Optional[str] = None) -> int:
         # deadline_ms is absolute unix-epoch ms (the proxy converts the
         # client's relative budget at admission).  Passed through only when
-        # set: the T5 window engine doesn't take it, and None means "no
+        # set: the T5 engine doesn't take it, and None means "no
         # deadline" everywhere.  Same for adapter_id (multi-tenant LoRA —
         # paged causal-LM engines only) and tenant (pure cost-attribution
         # label, e.g. the batch lane's ``batch:<job_id>``).
@@ -233,7 +233,7 @@ class _EngineServer:
             kw["adapter_id"] = str(adapter_id)
         if tenant is not None and self._router is None \
                 and hasattr(front, "submit_migrated"):
-            # pure billing label, causal-LM engines only — the T5 window
+            # pure billing label, causal-LM engines only — the T5
             # engine (and the disagg router) take no per-request tenant;
             # dropping the label there degrades attribution, never submits
             kw["tenant"] = str(tenant)
