@@ -21,6 +21,8 @@ NEW = ["engine_queue_wait_ms_p50", "engine_queue_wait_ms_p95",
 T5, PAGED = ["t5large-serve"], ["olmoe-serve-decode", "jamba2-serve-reason",
                                 "gigachat-serve-docchat",
                                 "nemotron3-serve-agent"]
+# PR 58 appended its cell to the three of the engine's programs
+XING = "xing4-serve-longdoc"
 
 # (name, start us, duration us, counts).  Five requests: queue waits 100,
 # 200, 300, 400, 1000 us (median 300; p95 = 400 + 0.8 x 600 = 880), prefill
@@ -175,8 +177,8 @@ def test_the_manifest_appends_the_seven_and_still_validates(bench):
     e2e = {m["name"]: m for m in bench.doc["end_to_end"]}
     for m in bench.doc["per_layer"][at:at + 7]:
         assert (m["source"], m["layer"]) == ("program_span", "engine")
-        assert m["workloads"] == (T5 if m["moves"] == "serve_ttft_p95_ms"
-                                  else PAGED)
+        assert m["workloads"] == (
+            T5 if m["moves"] == "serve_ttft_p95_ms" else PAGED + [XING])
         assert set(m["workloads"]) <= cells
         # a cell that lists the metric reports the metric it moves
         assert set(m["workloads"]) <= set(e2e[m["moves"]]["workloads"])
@@ -188,3 +190,5 @@ def test_the_manifest_appends_the_seven_and_still_validates(bench):
     assert [m["name"] for m in bench.metrics("per_layer", T5[0])][-4:] == NEW[:4]
     for cell in PAGED:
         assert [m["name"] for m in bench.metrics("per_layer", cell)][-3:] == NEW[4:]
+    # PR 58's cell: the same three, its own six behind them
+    assert [m["name"] for m in bench.metrics("per_layer", XING)][-9:-6] == NEW[4:]

@@ -920,3 +920,70 @@ def test_mamba2_state_pool_stays_in_place_at_full_depth_on_v5e(
     # attention's softmax got a window its cost model could not price (this
     # number) and ran eleven times as long on the chip (PERF.md, PR 48)
     assert '"estimated_cycles":"9223372036854775807"' not in text
+
+
+# -- four residual streams around latent attention and held experts (PR 58) ---
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "chunk"])
+def test_residual_streams_programs_fit_the_chip_with_their_kernels_on_v5e(
+        v5e, monkeypatch, program):
+    """``xing4-serve-longdoc``'s three programs at its widths, depth and
+    geometry (64 slots x 8192, pages of 256 x 640): the chip's compiler takes
+    each; both expert products of the four sparse layers are the grouped
+    kernel (a side of 3584: the fourth tile) beside the sum over a token's
+    choices and the latent family's two paged kernels a layer; the donated
+    pools come back aliased; and the program fits the chip beside its 11.5
+    GB of arguments (bfloat16 weights, float32 hyper-connections)."""
+    import json
+    import os
+
+    from benchmark import weights_xing
+    from tpu_air.models.lm import CausalLM
+    from tpu_air.models.lm.generate import (make_paged_decode_body,
+                                            make_paged_mixed_body,
+                                            make_prefill_chunk_body)
+    from tpu_air.ops import decode_attention as da
+
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "configs", "xing4.0-29b-a4b.json")) as f:
+        cfg = weights_xing.lm_config(json.load(f), "bfloat16", 8192)
+    model = CausalLM(cfg)
+    slots, slot_len, page = 64, 8192, 256
+    npg = slot_len // page
+    params, cache, i32 = _serving_pool(model, slots, slot_len, page, v5e)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, s: _struct(
+            s.shape, jnp.float32 if any(
+                str(k.key).endswith("_hc") for k in path) else s.dtype, v5e),
+        params)
+    step = (i32(slots), i32(slots), i32(slots, npg))
+    chunk = (i32(1, page), i32(), i32(), i32(npg))
+    body, args = {
+        "decode": (make_paged_decode_body(model, slot_len), step),
+        "chunk": (make_prefill_chunk_body(model, page, slot_len), chunk),
+        "mixed": (make_paged_mixed_body(model, page, slot_len), step + chunk),
+    }[program]
+    compiled = jax.jit(body, donate_argnums=(1,)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    kernels = {name: sum("tpu_custom_call" in line and name in line
+                         for line in text.splitlines())
+               for name in ("paged_latent_decode_attention",
+                            "paged_latent_chunk_attention", "held_rows_sum")}
+    assert kernels == {
+        "paged_latent_decode_attention": 0 if program == "chunk" else 5,
+        "paged_latent_chunk_attention": 0 if program == "decode" else 5,
+        "held_rows_sum": 4}
+    # what is left: three grouped products a sparse layer, none ragged_dot
+    assert text.count("tpu_custom_call") == sum(kernels.values()) + 4 * 3
+    assert "ragged" not in text
+    assert '"estimated_cycles":"9223372036854775807"' not in text
+    pools = cfg.n_layers * (slots * npg + 1) * page * 640 * 2
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pools           # appended to in place
+    assert mem.temp_size_in_bytes < pools // 4        # and no second pool
+    assert mem.argument_size_in_bytes == pytest.approx(11.46e9, rel=0.01)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)

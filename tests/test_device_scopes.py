@@ -308,13 +308,13 @@ def _nemotron_mixed_step():
                          i32(npg), slot=i32()).compile())
 
 
-def _gigachat(build):
+def _gigachat(build, **more):
     """A tiny latent-attention engine program: a dense layer, then a sparse
     one that holds half of its router's experts, with a shared expert."""
     from tpu_air.models.lm import CausalLM, LMConfig
     from tpu_air.models.lm.generate import init_paged_cache
 
-    cfg = LMConfig(vocab_size=96, d_model=32, n_layers=2, n_heads=2,
+    cfg = LMConfig(**more, vocab_size=96, d_model=32, n_layers=2, n_heads=2,
                    head_dim=12, d_ff=16, max_seq_len=32, tie_embeddings=False,
                    num_experts=8, num_experts_per_tok=2, q_lora_rank=16,
                    kv_lora_rank=8, qk_nope_head_dim=8, qk_rope_head_dim=4,
@@ -344,6 +344,39 @@ def _gigachat_paged_step():
                          i32(slots, npg)).compile())
 
 
+def _xing(build):
+    """The same layers inside four residual streams (PR 58)."""
+    return lambda: _gigachat(build, hc_mult=4)
+
+
+def _lower_step(model, params, cache, i32, slots, slot_len, page, npg):
+    from tpu_air.models.lm.generate import make_lm_paged_decode_step_fn
+
+    return make_lm_paged_decode_step_fn(model, slot_len).lower(
+        params, cache, i32(slots), i32(slots), i32(slots, npg)).compile()
+
+
+def _lower_chunk(model, params, cache, i32, slots, slot_len, page, npg):
+    from tpu_air.models.lm.generate import make_lm_prefill_chunk_fn
+
+    return make_lm_prefill_chunk_fn(model, page, slot_len).lower(
+        params, cache, i32(1, page), i32(), i32(), i32(npg)).compile()
+
+
+def _lower_mixed(model, params, cache, i32, slots, slot_len, page, npg):
+    from tpu_air.models.lm.generate import make_lm_paged_mixed_step_fn
+
+    return make_lm_paged_mixed_step_fn(model, page, slot_len).lower(
+        params, cache, i32(slots), i32(slots), i32(slots, npg), i32(1, page),
+        i32(), i32(), i32(npg)).compile()
+
+
+# the five scopes of the residual streams, in each of the engine's three
+# programs: the maps under their sublayer's module
+_MHC = {"mhc_expand": None, "mhc_pre": "attn_hc", "mhc_sinkhorn": "mlp_hc",
+        "mhc_post": None, "mhc_reduce": None}
+
+
 def _gigachat_mixed_step():
     from tpu_air.models.lm.generate import make_lm_paged_mixed_step_fn
 
@@ -356,12 +389,14 @@ def _gigachat_mixed_step():
 
 
 # the words that came after benchmark/scopes.py wrote its list down (PR 41,
-# PR 43, PR 47: lower-case words, which its reader takes for parts of a model
+# PR 43, PR 47, PR 58: lower-case words, which its reader takes for parts of a model
 # as they are)
 LATER_WORDS = {"ssm_conv", "ssm_scan", "ssm_state_update",
                "mla_q", "mla_latent", "mla_out", "moe_shared",
                "ssd_conv", "ssd_scan", "ssd_state_update", "ssd_gate_norm",
-               "moe_latent_down", "moe_latent_up"}
+               "moe_latent_down", "moe_latent_up",
+               "mhc_expand", "mhc_pre", "mhc_sinkhorn", "mhc_post",
+               "mhc_reduce"}
 WORDS = set(scopes.VOCABULARY) | LATER_WORDS
 
 # program -> the words it must carry, and for some the module around them
@@ -389,6 +424,12 @@ PROGRAMS = {
         "kv_gather": "attn", "decode_attention": "attn",
         "attn_scores": "attn", "attn_context": "attn",
         "moe_shared": "shared", "lm_head": None}),
+    "xing_paged_step": (_xing(_lower_step), {
+        **_MHC, "decode_attention": "attn", "moe_experts": "moe"}),
+    "xing_prefill_chunk": (_xing(_lower_chunk), {
+        **_MHC, "attn_scores": "attn", "moe_experts": "moe"}),
+    "xing_mixed_step": (_xing(_lower_mixed), {
+        **_MHC, "decode_attention": "attn", "attn_scores": "attn"}),
     # a layer that is one thing (PR 47): Mamba-2's scopes under ``mamba``,
     # the latent pair around the routed experts under ``moe``
     "nemotron_paged_step": (_nemotron_paged_step, {
@@ -684,22 +725,27 @@ NEW = {
     "engine_unscoped_share": ["t5large-serve", "olmoe-serve-decode",
                               "jamba2-serve-reason",
                               "gigachat-serve-docchat",
-                              "nemotron3-serve-agent"],
+                              "nemotron3-serve-agent", "xing4-serve-longdoc"],
     # PR 41: the hybrid's decode step (a flax module's name and a scope word)
     "ssm_mixer_share": ["jamba2-serve-reason"],
     "ssm_state_share": ["jamba2-serve-reason"],
     # PR 43: latent attention's own matrices, and the shared expert (over
     # every program of the capture: nearly every iteration of the cell is the
     # mixed step, so the readers of the decode program alone do not list it)
-    "mla_latent_share": ["gigachat-serve-docchat"],
-    "moe_shared_share": ["gigachat-serve-docchat", "nemotron3-serve-agent"],
+    # (PR 58 appended its cell to the three it shares the scopes of)
+    "mla_latent_share": ["gigachat-serve-docchat", "xing4-serve-longdoc"],
+    "moe_shared_share": ["gigachat-serve-docchat", "nemotron3-serve-agent",
+                         "xing4-serve-longdoc"],
     # PR 45: a chunk's attention over its slot's latent pages, of the mixed
     # step (the dense form's three words; the walk's kernel is under the last)
-    "mla_chunk_attention_share": ["gigachat-serve-docchat"],
+    "mla_chunk_attention_share": ["gigachat-serve-docchat", "xing4-serve-longdoc"],
     # PR 47: the Mamba-2 state's pass, a chunk's block form, the latent pair
     "ssd_state_share": ["nemotron3-serve-agent"],
     "ssd_scan_share": ["nemotron3-serve-agent"],
     "latent_proj_share": ["nemotron3-serve-agent"],
+    # PR 58: the residual streams' five scopes, and the Sinkhorn rounds alone
+    "mhc_share": ["xing4-serve-longdoc"],
+    "mhc_sinkhorn_share": ["xing4-serve-longdoc"],
 }
 
 

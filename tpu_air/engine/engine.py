@@ -167,12 +167,14 @@ from .types import (
 class _Chunk(NamedTuple):
     """One prefill chunk made ready on the host: the slot, where the chunk
     starts, and the chunk program's arguments behind the cache (``ids``,
-    ``p0``, ``last_local``, ``table_row``) as they go to the device."""
+    ``p0``, ``last_local``, ``table_row``) as they go to the device;
+    ``tokens``: how many of its positions hold a token of the prompt."""
 
     slot: Slot
     start: int
     args: Tuple[Any, ...]
     adapter_row: int = 0
+    tokens: int = 0
 
 
 class _IssuedStep(NamedTuple):
@@ -264,6 +266,9 @@ class InferenceEngine:
         if self._latent:
             # the positions a decode step's gather writes out, live or not
             self.metrics.set_latent_pool(cfg.num_slots * cfg.slot_len)
+        self._streams = int(getattr(self.model.config, "hc_mult", 1))
+        if self._streams > 1:
+            self.metrics.set_residual_streams(self._streams)
         if self._recurrent:
             self.metrics.set_recurrent_state(
                 self._state_bytes,
@@ -940,7 +945,7 @@ class InferenceEngine:
         row = self.pool.chunk_row(slot.index, p0, plan.null_target)
         return _Chunk(slot, p0, (jnp.asarray(ids), jnp.int32(p0),
                                  jnp.int32(last_local), jnp.asarray(row)),
-                      req.adapter_row)
+                      req.adapter_row, len(chunk_toks))
 
     def _state_row(self, slot: Slot) -> Dict[str, Any]:
         # the slot's state row goes with its table row; the chunk at position
@@ -1323,6 +1328,10 @@ class InferenceEngine:
         self._inflight = _IssuedStep(
             out, [(s, s.request) for s in rows], start, first)
         self.metrics.record_issue(ahead, mixed=chunk is not None)
+        if self._streams > 1:
+            self.metrics.record_stream_rows(
+                len(rows) + (chunk.tokens if chunk is not None else 0),
+                chunk=chunk is not None)
         if self._recurrent:
             # every row not in the step rode it with its state held; the
             # others' state the step advances, over the positions they hold

@@ -171,6 +171,14 @@ class EngineMetrics:
         # passes over the pool
         self.ssd_state_rows_passed = 0
         self.prefix_cache_disabled_by_model = False
+        # a residual path of several streams (absent for a model of one):
+        # how many, and rows x steps whose streams held a token as a step
+        # was issued (the live decode rows and the chunk's tokens): what the
+        # maps of a hyper-connection must move; the same over the steps
+        # that carried no chunk
+        self.mhc_streams = 0
+        self.mhc_rows_live = 0
+        self.mhc_rows_live_alone = 0
         register(self)
 
     def set_topology(self, **kw: Any) -> None:
@@ -369,6 +377,18 @@ class EngineMetrics:
             self.ssd_positions_live += positions
             self.ssd_state_rows_passed += passed
 
+    def set_residual_streams(self, streams: int) -> None:
+        with self._lock:
+            self.mhc_streams = int(streams)
+
+    def record_stream_rows(self, rows: int, chunk: bool) -> None:
+        """One issued step's rows that hold a token: its decoding rows and,
+        with ``chunk``, the tokens of the chunk it carries."""
+        with self._lock:
+            self.mhc_rows_live += rows
+            if not chunk:
+                self.mhc_rows_live_alone += rows
+
     def record_dropped_step(self) -> None:
         """An issued step nobody read: every row it decoded for had ended
         on EOS one step earlier, or the engine closed."""
@@ -549,6 +569,10 @@ class EngineMetrics:
                 out["ssd_state_rows_passed"] = self.ssd_state_rows_passed
                 out["prefix_cache_disabled_by_model"] = (
                     self.prefix_cache_disabled_by_model)
+            if self.mhc_streams:
+                out["mhc_streams"] = self.mhc_streams
+                out["mhc_rows_live"] = self.mhc_rows_live
+                out["mhc_rows_live_alone"] = self.mhc_rows_live_alone
             if self.steps_issued:
                 out["steps_issued"] = self.steps_issued
                 out["steps_ahead"] = self.steps_ahead

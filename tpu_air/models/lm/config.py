@@ -3,7 +3,7 @@
 from __future__ import annotations
 
 from dataclasses import asdict, dataclass, field
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 
 @dataclass
@@ -76,6 +76,17 @@ class LMConfig:
     routed experts inside a down- and up-projection to that width (the router
     and the shared expert take the full hidden state); ``shared_d_ff`` is the
     shared expert's width where it is not ``num_shared_experts * d_ff``.
+
+    A RESIDUAL PATH OF SEVERAL STREAMS (``hc_mult > 1``; what ``xing4_0``
+    publishes: manifold-constrained hyper-connections, arXiv:2512.24880): a
+    token's state between sublayers is ``hc_mult`` streams of ``d_model``
+    numbers; each sublayer reads a token-dependent mix of them and writes
+    back through a doubly stochastic ``hc_mult x hc_mult`` map, which
+    ``hc_sinkhorn_iters`` rounds of column and row normalisation (``hc_eps``
+    in both denominators) make of ``exp`` of a matrix clamped to
+    ``hc_res_clamp`` (``ops/mhc.py`` has the equations,
+    ``modeling.HyperConnection`` the parameters).  1: ``x + f(norm(x))`` and
+    no parameter of it in the tree.
     """
 
     vocab_size: int = 32000
@@ -131,6 +142,10 @@ class LMConfig:
     ff_act: str = "swiglu"          # swiglu | relu2
     moe_latent_size: int = 0        # > 0: routed experts work in a latent
     shared_d_ff: Optional[int] = None   # default num_shared_experts * d_ff
+    hc_mult: int = 1                # > 1: that many residual streams (mHC)
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -181,6 +196,12 @@ class LMConfig:
             raise ValueError(f"ff_act {self.ff_act!r}")
         if self.shared_d_ff is None:
             self.shared_d_ff = self.num_shared_experts * self.d_ff
+        # a checkpoint's JSON hands the pair back as a list
+        self.hc_res_clamp = tuple(float(v) for v in self.hc_res_clamp)
+        if self.hc_mult < 1 or len(self.hc_res_clamp) != 2:
+            raise ValueError(
+                f"hc_mult {self.hc_mult} streams, hc_res_clamp "
+                f"{self.hc_res_clamp}: one or more streams and a (min, max)")
         if self.layer_pattern is not None:
             p = self.layer_pattern
             if len(p) != self.n_layers or set(p) - set("M*E"):

@@ -1,4 +1,4 @@
-"""Published (Hugging Face) OLMoE, Jamba, DeepSeek-V3 and Nemotron-H
+"""Published (Hugging Face) OLMoE, Jamba, DeepSeek-V3, Xing4.0 and Nemotron-H
 configurations and weights -> ``LMConfig`` and this framework's ``CausalLM``
 parameter tree.
 
@@ -27,6 +27,12 @@ modeling code de-interleaves them before its rotate-half), which is
 ``modeling.LatentAttention`` uses them apart), and the tree may hold a SHARE
 of the model (an expert-parallel rank): a range of the routed experts and a
 range of the vocabulary's rows.
+
+``model_type: xing4_0`` is ``deepseek_v3`` inside a residual path of
+``hc_mult`` streams: the same keys and tensors plus, a sublayer, the three
+tensors of its hyper-connection (``modeling.HyperConnection``), whose
+published NAMES the catalog does not give: :data:`XING_MHC_NAMES` is what is
+assumed, and a caller that knows better hands ``names=``.
 
 ``model_type: nemotron_h`` is renaming and transposition alone: its
 attention has no position encoding, the layer pattern goes in as published
@@ -143,6 +149,22 @@ YARN_KEYS = {
 }
 
 
+#: ``model_type: xing4_0`` (Xing4.0-29B-A4B): ``DEEPSEEK_KEYS`` and these
+XING_KEYS = {
+    "hc_mult": "hc_mult",
+    "hc_sinkhorn_iters": "hc_sinkhorn_iters",
+    "hc_eps": "hc_eps",
+}
+#: the published names of a sublayer's hyper-connection tensors, ASSUMED:
+#: ``{layer}`` the layer's number, ``{sublayer}`` ``attn`` or ``mlp``;
+#: ``phi`` as a linear layer stores its weight, ``[n*n + 2n, n*C]``
+XING_MHC_NAMES = {
+    "phi": "model.layers.{layer}.{sublayer}_hc.phi.weight",
+    "b": "model.layers.{layer}.{sublayer}_hc.bias",
+    "alpha": "model.layers.{layer}.{sublayer}_hc.alpha",
+}
+
+
 #: ``model_type: nemotron_h`` (NVIDIA-Nemotron-3-Super-120B-A12B): the keys
 #: mapped, and the values that must hold.  ``rope_theta`` and
 #: ``partial_rotary_factor`` are published and read by nothing: the family's
@@ -227,6 +249,11 @@ def _deepseek_config_from_hf(hf: Dict[str, Any], dtype: str,
         fields.update({ours: scaling[theirs]
                        for theirs, ours in YARN_KEYS.items()
                        if theirs in scaling})
+    if hf.get("model_type") == "xing4_0":
+        fields.update({ours: hf[theirs]
+                       for theirs, ours in XING_KEYS.items()})
+        fields["hc_res_clamp"] = (hf["mhc_h_res_clamp_min"],
+                                  hf["mhc_h_res_clamp_max"])
     fields.update(
         head_dim=hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"],
         router="sigmoid_groups",
@@ -256,8 +283,8 @@ def _nemotron_h_config_from_hf(hf: Dict[str, Any], dtype: str,
 
 def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
                       **overrides: Any) -> LMConfig:
-    """``LMConfig`` of a published ``olmoe``, ``jamba``, ``deepseek_v3`` or
-    ``nemotron_h`` ``config.json`` (a dict).  Refuses a configuration whose layer this
+    """``LMConfig`` of a published ``olmoe``, ``jamba``, ``deepseek_v3``,
+    ``xing4_0`` or ``nemotron_h`` ``config.json`` (a dict).  Refuses a configuration whose layer this
     framework does not compute.  A tree that holds a share of a
     ``deepseek_v3`` or ``nemotron_h`` model says so in ``overrides``: ``experts_first`` /
     ``experts_held`` (of the ``n_routed_experts`` the router scores) and the
@@ -266,7 +293,7 @@ def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
     not depend on; it is neither made nor run."""
     if hf.get("model_type") == "jamba":
         return _jamba_config_from_hf(hf, dtype, **overrides)
-    if hf.get("model_type") == "deepseek_v3":
+    if hf.get("model_type") in ("deepseek_v3", "xing4_0"):
         return _deepseek_config_from_hf(hf, dtype, **overrides)
     if hf.get("model_type") == "nemotron_h":
         return _nemotron_h_config_from_hf(hf, dtype, **overrides)
@@ -401,9 +428,13 @@ def convert_jamba_state_dict(get: Callable[[str], Any],
 
 
 def convert_deepseek_v3_layer(get: Callable[[str], Any], i: int,
-                              config: LMConfig) -> Dict[str, Any]:
+                              config: LMConfig,
+                              names: Dict[str, str] = XING_MHC_NAMES
+                              ) -> Dict[str, Any]:
     """Layer ``i`` of the tree from the published ``deepseek_v3`` names.
-    Only the experts the configuration holds are asked for."""
+    Only the experts the configuration holds are asked for.  With
+    ``config.hc_mult > 1`` (``xing4_0``) the layer's two hyper-connections
+    come with it, from ``names``."""
     pre = f"model.layers.{i}."
     kernel = lambda name: {"kernel": _t(get(pre + name + ".weight"))}  # noqa: E731
     weight = lambda name: {"weight": np.asarray(  # noqa: E731
@@ -426,6 +457,12 @@ def convert_deepseek_v3_layer(get: Callable[[str], Any], i: int,
             "o": kernel("self_attn.o_proj"),
         },
     }
+    if config.hc_mult > 1:
+        for sub in ("attn", "mlp"):
+            at = lambda k: get(names[k].format(layer=i, sublayer=sub))  # noqa: E731
+            layer[sub + "_hc"] = {"phi": _t(at("phi")),
+                                  "b": np.asarray(at("b")),
+                                  "alpha": np.asarray(at("alpha"))}
     if config.ff_kinds()[i] == "dense":
         layer["mlp"] = {w: kernel(f"mlp.{w}_proj")
                         for w in ("gate", "up", "down")}
@@ -447,8 +484,11 @@ def convert_deepseek_v3_layer(get: Callable[[str], Any], i: int,
 
 def convert_deepseek_v3_state_dict(get: Callable[[str], Any],
                                    config: LMConfig,
-                                   vocab_rows: Any = None) -> Dict[str, Any]:
-    """The ``CausalLM`` parameter tree from a published ``deepseek_v3`` state
+                                   vocab_rows: Any = None,
+                                   names: Dict[str, str] = XING_MHC_NAMES
+                                   ) -> Dict[str, Any]:
+    """The ``CausalLM`` parameter tree from a published ``deepseek_v3`` (or,
+    with its hyper-connections under ``names``, ``xing4_0``) state
     dict, given as ``get(name)``: the first ``config.n_layers`` layers, the
     routed experts ``config.experts_first .. + config.experts_held`` of each
     sparse layer, and the rows ``vocab_rows`` (a ``range`` or slice; default
@@ -470,7 +510,8 @@ def convert_deepseek_v3_state_dict(get: Callable[[str], Any],
             f"{params['embedding'].shape[0]} vocabulary rows for a "
             f"configuration of {config.vocab_size}")
     for i in range(config.n_layers):
-        params[f"layer_{i}"] = convert_deepseek_v3_layer(get, i, config)
+        params[f"layer_{i}"] = convert_deepseek_v3_layer(get, i, config,
+                                                         names)
     return params
 
 

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from tpu_air.ops import decode_attention, ssm
+from tpu_air.ops import decode_attention, mhc, ssm
 from tpu_air.ops.decode_attention import (flat_decode_attention, gather_pages,
                                           latent_decode_attention)
 
@@ -1142,10 +1142,59 @@ class Mamba2Mixer(nn.Module):
         return dense("out_proj", cfg.d_model)(y)
 
 
+class HyperConnection(nn.Module):
+    """The maps of ONE sublayer's manifold-constrained hyper-connection
+    (``ops/mhc.py`` has the equations): ``phi [n*C, n*n + 2n]``, ``b [n*n +
+    2n]`` and ``alpha [3]`` (pre, post, res), float32 whatever the model
+    computes in.  ``streams [..., n, C]`` -> ``(h, H_post, H_res)``: the
+    sublayer's input, and what :func:`mhc.post` writes its output back
+    through.  A fresh module starts where the paper does: ``alpha`` 0.01,
+    ``b`` zeros but for 4 on ``H~res``'s diagonal (streams that mostly keep
+    to themselves)."""
+
+    config: LMConfig
+
+    @nn.compact
+    def __call__(self, streams: Array):
+        cfg = self.config
+        n, c = streams.shape[-2:]
+        phi = self.param("phi", nn.initializers.normal((n * c) ** -0.5),
+                         (n * c, n * n + 2 * n), jnp.float32)
+        b = self.param(
+            "b", lambda key, shape, dt: jnp.concatenate(
+                [jnp.zeros(2 * n, dt), 4.0 * jnp.eye(n, dtype=dt).ravel()]),
+            (n * n + 2 * n,), jnp.float32)
+        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
+                           jnp.float32)
+        h, h_post, res = mhc.pre(streams, phi, b, alpha, cfg.rmsnorm_eps)
+        return h, h_post, mhc.sinkhorn(res, cfg.hc_sinkhorn_iters,
+                                       cfg.hc_eps, cfg.hc_res_clamp)
+
+
+def cast_params(params, dtype):
+    """``params`` with every array leaf in ``dtype`` but those a model of any
+    dtype keeps float32: the maps of a :class:`HyperConnection` (``Block``
+    names the two of a layer ``attn_hc`` and ``mlp_hc``).  The one rule for
+    whoever casts a ``CausalLM`` tree to the dtype it serves or trains in
+    (``CausalLM.cast_params``)."""
+    dtype = jnp.dtype(dtype)
+
+    def cast(path, x):
+        if not hasattr(x, "astype") or any(
+                str(getattr(k, "key", "")).endswith("_hc") for k in path):
+            return x
+        return x.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 class Block(nn.Module):
     """One layer: a sequence mixer (``kind``) and then a feed-forward
     (``ff``), each ``x + f(RMSNorm(x))``; either may be ``"none"`` (a
-    ``layer_pattern``'s layer is ONE of the two)."""
+    ``layer_pattern``'s layer is ONE of the two).  With ``config.hc_mult >
+    1`` ``x`` is ``[b, l, n, C]`` and each of the two goes through its own
+    :class:`HyperConnection` (``attn_hc``, ``mlp_hc``) instead of the
+    sum."""
 
     config: LMConfig
     kind: str = "attention"   # LMConfig.layer_kinds()
@@ -1158,33 +1207,45 @@ class Block(nn.Module):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         drop = nn.Dropout(cfg.dropout_rate, deterministic=deterministic)
+
+        def residual(x, name, f):
+            if cfg.hc_mult == 1:
+                return x + drop(f(x))
+            h, h_post, h_res = HyperConnection(cfg, name=name + "_hc")(x)
+            return mhc.post(x, drop(f(h)), h_res, h_post)
+
         # ``chunk``: only the sequence mixer tells a mixed step's two parts
-        # apart; the norms and the feed-forward below take its rows as rows
+        # apart; the norms, the maps of the residual streams and the
+        # feed-forward below take its rows as rows
         if self.kind in ("mamba", "mamba2"):
             mixer = MambaMixer if self.kind == "mamba" else Mamba2Mixer
-            x = x + drop(mixer(cfg, name="mamba")(
-                RMSNorm(cfg.rmsnorm_eps, dtype, name="mamba_norm")(x),
+            x = residual(x, "mamba", lambda h: mixer(cfg, name="mamba")(
+                RMSNorm(cfg.rmsnorm_eps, dtype, name="mamba_norm")(h),
                 decode=decode, chunk=chunk))
         elif self.kind != "none":
             mixer = (LatentAttention if self.kind == "latent"
                      else CausalSelfAttention)
-            x = x + drop(mixer(cfg, name="attn")(
-                RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(x),
+            x = residual(x, "attn", lambda h: mixer(cfg, name="attn")(
+                RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(h),
                 positions, decode=decode, chunk=chunk,
             ))
         if self.ff == "none":
             return x
         # the feed-forward kind follows from the configuration's numbers
         ffn = ReLU2 if cfg.ff_act == "relu2" else SwiGLU
-        h = RMSNorm(cfg.rmsnorm_eps, dtype, name="mlp_norm")(x)
-        if self.ff != "sparse":
-            return x + drop(ffn(cfg, cfg.dense_d_ff, name="mlp")(h))
-        y = SparseExperts(cfg, name="moe")(h)
-        if cfg.num_shared_experts:
-            # every token's, beside its routed ones
-            with jax.named_scope("moe_shared"):
-                y = y + ffn(cfg, cfg.shared_d_ff, name="shared")(h)
-        return x + drop(y)
+
+        def feed_forward(x):
+            h = RMSNorm(cfg.rmsnorm_eps, dtype, name="mlp_norm")(x)
+            if self.ff != "sparse":
+                return ffn(cfg, cfg.dense_d_ff, name="mlp")(h)
+            y = SparseExperts(cfg, name="moe")(h)
+            if cfg.num_shared_experts:
+                # every token's, beside its routed ones
+                with jax.named_scope("moe_shared"):
+                    y = y + ffn(cfg, cfg.shared_d_ff, name="shared")(h)
+            return y
+
+        return residual(x, "mlp", feed_forward)
 
 
 class CausalLM(nn.Module):
@@ -1197,6 +1258,9 @@ class CausalLM(nn.Module):
     """
 
     config: LMConfig
+
+    #: a loader's cast of this model's tree (the module-level function)
+    cast_params = staticmethod(cast_params)
 
     @nn.compact
     def __call__(self, input_ids: Array, positions: Optional[Array] = None,
@@ -1217,10 +1281,15 @@ class CausalLM(nn.Module):
             jnp.float32,
         )
         x = embed[input_ids].astype(dtype)
+        if cfg.hc_mult > 1:
+            # [b, l, n, C] from here to the sum in front of the last norm
+            x = mhc.expand(x, cfg.hc_mult)
         for i, (kind, ff) in enumerate(zip(cfg.layer_kinds(),
                                            cfg.ff_kinds())):
             x = Block(cfg, kind, ff, name=f"layer_{i}")(
                 x, positions, deterministic, decode=decode, chunk=chunk)
+        if cfg.hc_mult > 1:
+            x = mhc.reduce(x)
         x = RMSNorm(cfg.rmsnorm_eps, dtype, name="final_norm")(x)
         if return_hidden:
             # pre-head hidden states: pair with head_weight() +

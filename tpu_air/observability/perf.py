@@ -408,6 +408,15 @@ class LMCostModel:
     and the layer has the pair ``2*D*l`` beside it; the shared expert stays
     at the model's width, ``shared_d_ff`` wide.
 
+    A residual path of ``n = config.hc_mult > 1`` streams: each sublayer
+    (a mixer, a feed-forward: ``n_sublayers``) stores ``n*D*(n*n + 2n) + n*n
+    + 2n + 3`` float32 parameters of its hyper-connection whatever the
+    model's dtype, computes ``2*(n*D*(n*n + 2n) + 2*n*D + n*n*D)`` operations
+    a token with them (the product onto the maps, the read and the
+    write-back; the Sinkhorn rounds are ``n*n`` numbers) and moves ``(2n +
+    2)*D*b`` bytes of streams a token: the state read once and written
+    once, the sublayer's input written and its output read.
+
     Norms, rotary embeddings and softmax are omitted (≪1% of the matmul
     budget at any real geometry); the model is deliberately closed-form so
     identical claims can be recomputed anywhere (arXiv:2204.06514 §4).
@@ -461,6 +470,10 @@ class LMCostModel:
         self.qk_nope = int(getattr(config, "qk_nope_head_dim", 0) or 0)
         self.qk_rope = int(getattr(config, "qk_rope_head_dim", 0) or 0)
         self.v_head_dim = int(getattr(config, "v_head_dim", 0) or 0)
+        self.hc_mult = int(getattr(config, "hc_mult", 1) or 1)
+        self.n_sublayers = (self.n_attn_layers + self.n_mamba_layers
+                            + self.n_mamba2_layers + self.n_sparse_layers
+                            + self.n_dense_layers)
 
     # -- derived geometry ----------------------------------------------------
     @property
@@ -501,6 +514,30 @@ class LMCostModel:
                                           + self._mamba2_conv_dim * tail))
 
     @property
+    def _mhc_params(self) -> int:
+        """Float32 parameters of the hyper-connections (0: one stream)."""
+        n = self.hc_mult
+        if n == 1:
+            return 0
+        maps = n * n + 2 * n
+        return self.n_sublayers * (n * self.d_model * maps + maps + 3)
+
+    @property
+    def mhc_flops_per_token(self) -> float:
+        n, d = self.hc_mult, self.d_model
+        if n == 1:
+            return 0.0
+        return self.n_sublayers * 2.0 * (
+            n * d * (n * n + 2 * n) + 2 * n * d + n * n * d)
+
+    @property
+    def mhc_stream_bytes_per_token(self) -> float:
+        if self.hc_mult == 1:
+            return 0.0
+        return (self.n_sublayers * (2 * self.hc_mult + 2) * self.d_model
+                * self.dtype_bytes)
+
+    @property
     def _expert_params(self) -> int:
         """One routed expert of width ``d_ff``, in the width it works in."""
         return self.ff_matrices * (self.moe_latent or self.d_model) \
@@ -537,14 +574,17 @@ class LMCostModel:
 
     @property
     def param_count(self) -> int:
-        n = self.vocab_size * self.d_model + self.matmul_params
+        n = (self.vocab_size * self.d_model + self.matmul_params
+             + self._mhc_params)
         if not self.tie_embeddings:
             n += self.d_model * self.vocab_size
         return n
 
     @property
     def param_bytes(self) -> int:
-        return self.param_count * self.dtype_bytes
+        # the hyper-connections are float32 in a model of any dtype
+        return (self.param_count * self.dtype_bytes
+                + self._mhc_params * (4 - self.dtype_bytes))
 
     def experts_touched(self, tokens: int) -> float:
         """Experts of one layer a program over ``tokens`` tokens streams
@@ -563,15 +603,16 @@ class LMCostModel:
         if not self.num_experts:
             return float(self.param_bytes)
         idle = self.experts_held - self.experts_touched(tokens)
-        return (self.param_count - self.n_sparse_layers * idle
-                * self._expert_params) * self.dtype_bytes
+        return (self.param_bytes - self.n_sparse_layers * idle
+                * self._expert_params * self.dtype_bytes)
 
     @property
     def linear_flops_per_token(self) -> float:
         return (2.0 * (self.active_matmul_params
                        + self.d_model * self.vocab_size)
                 + (self.n_mamba_layers + self.n_mamba2_layers) * 7.0
-                * self.d_inner * self.d_state)
+                * self.d_inner * self.d_state
+                + self.mhc_flops_per_token)
 
     @property
     def kv_bytes_per_position(self) -> float:
@@ -599,7 +640,8 @@ class LMCostModel:
         hbm = (self.streamed_param_bytes(rows)
                + rows * attended * self.kv_bytes_per_position   # KV read
                + rows * self.kv_bytes_per_position              # KV write
-               + 2 * rows * self.state_bytes_per_row)           # state r+w
+               + 2 * rows * self.state_bytes_per_row            # state r+w
+               + rows * self.mhc_stream_bytes_per_token)
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=rows)
 
     def prefill_chunk_cost(self, chunk_len: int,
@@ -615,7 +657,8 @@ class LMCostModel:
         hbm = (self.streamed_param_bytes(c)
                + (start_pos + c) * self.kv_bytes_per_position   # prefix read
                + c * self.kv_bytes_per_position                 # KV write
-               + 2 * self.state_bytes_per_row)                  # one row
+               + 2 * self.state_bytes_per_row                   # one row
+               + c * self.mhc_stream_bytes_per_token)
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=c)
 
     def mixed_step_cost(self, rows: int, attended: int, chunk_len: int,

@@ -67,13 +67,40 @@ GMM_TILING_K1024 = (128, 1024, 2048)
 # 1.18 to 1.23 (down), 384 of them 1.16 to 1.27, 256 rows a tile 1.15 to
 # 1.36, a tile of 1344 does not lower, and ``ragged_dot`` 2.9 to 5.4.
 GMM_TILING_WHOLE = (128, 2688, 2688)
+# where none of the three divides them (a side of 3584 = 7 x 512: xing4_0's
+# experts, 3584 x 1024 and back): HALF the long side a tile, 128 x 1792 x
+# 1024 for the up and gate products and 128 x 1024 x 1792 for the down
+# product (3.7 MB a tile; the whole matrix, 7.3 MB, does not fit VMEM twice:
+# the chip's compiler refuses it).  Tried on the v5e (PERF.md, PR 58) at 64
+# groups of 3584 x 1024 and of 1024 x 3584, 256 / 512 / 1024 / 1280 sorted
+# rows, the wall time of one jitted call in ms (0.58-0.60 of it the call's
+# dispatch and read-back, which an empty program reads; the 470 MB of weights
+# take 0.57 at the HBM peak):
+#   up    (128,1792,1024) 1.32-1.48   (128,512,1024) 1.34-1.46
+#         (128,896,1024)  1.33-1.50   (128,3584,512) 1.25-1.54
+#         (128,1792,512)  1.39-1.59   (128,896,512)  1.40-1.57
+#         (128,512,512)   1.45-2.04   (256,1792,1024) 1.36-1.54
+#         ragged_dot      1.72-2.25
+#   down  (128,1024,1792) 1.27-1.41   (128,1024,896) 1.27-1.44
+#         (128,1024,512)  1.30-1.46   (128,512,3584) 1.28-1.49
+#         (128,512,1792)  1.29-1.53   (128,512,896)  1.32-1.52
+#         (128,512,512)   1.61-2.03   (256,1024,1792) 1.29-1.41
+#         ragged_dot      1.69-2.30
+# The tiles that keep the short side whole lie within 3 % of one another at
+# every row count; this one is the fastest or second fastest of the down
+# product throughout and of the up product up to 512 rows, and ONE tuple
+# names both (a contraction tile of 896 beside 1024 columns cannot: 1024 is
+# no multiple of 896).  In the cell's own mixed step the twelve products
+# read 80 % of their HBM roofline (moe_held_expert_roofline, PERF.md PR 58).
+GMM_TILING_HALF = (128, 1792, 1792)
 
 
 def gmm_tiling(m: int, kdim: int, n: int):
     """The kernel tile for ``[m, kdim] x [g, kdim, n]``: the first of the
     measured tilings whose every side divides the shapes and is whole lanes,
     or None (no kernel: the shapes go to ``ragged_dot``)."""
-    for tiling in (GMM_TILING, GMM_TILING_K1024, GMM_TILING_WHOLE):
+    for tiling in (GMM_TILING, GMM_TILING_K1024, GMM_TILING_WHOLE,
+                   GMM_TILING_HALF):
         tm, tk, tn = tiling[0], min(tiling[1], kdim), min(tiling[2], n)
         if (m % tm == 0 and kdim % tk == 0 and n % tn == 0
                 and tk % 128 == 0 and tn % 128 == 0):

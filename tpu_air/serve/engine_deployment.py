@@ -94,11 +94,15 @@ class _EngineServer:
                 import jax
                 import jax.numpy as jnp
 
-                params = jax.tree_util.tree_map(
-                    lambda x: (x.astype(jnp.dtype(self._dtype))
-                               if hasattr(x, "astype") else x),
-                    params,
-                )
+                # the model says which of its leaves keep another dtype
+                # (``CausalLM.cast_params``); one that says nothing has none
+                cast = getattr(model, "cast_params", None)
+                if cast is None:
+                    def cast(tree, dtype):
+                        return jax.tree_util.tree_map(
+                            lambda x: (x.astype(jnp.dtype(dtype))
+                                       if hasattr(x, "astype") else x), tree)
+                params = cast(params, self._dtype)
             # the config type picks the engine family: a T5EngineConfig
             # gets the T5 slot engine (engine/t5_engine.py), any
             # EngineConfig (or None) the causal-LM slot/page engine
