@@ -62,10 +62,13 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
     assert "moe_held_expert_roofline" not in layer
     t = bench.traffic(cell)
     assert (t["trace_delay_s"], t["trace_s"]) == (8, 2)
-    # the new metrics came in together at the end, this cell alone in them,
-    # and the cell is the last of every list it was appended to
-    assert [m["name"] for m in bench.doc["per_layer"][-6:]] == NEW
-    for m in bench.doc["per_layer"][-6:]:
+    # the new metrics came in together (at the end, until PR 59 appended
+    # its two), this cell alone in them, and the cell is the last of every
+    # list it was appended to
+    first = [m["name"] for m in bench.doc["per_layer"]].index(NEW[0])
+    mine = bench.doc["per_layer"][first:first + 6]
+    assert [m["name"] for m in mine] == NEW
+    for m in mine:
         assert m["workloads"] == [CELL] and m["unit"] == "%"
         assert m["source"] == "device_trace"
     for m in bench.doc["per_layer"] + bench.doc["end_to_end"]:

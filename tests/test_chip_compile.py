@@ -335,11 +335,36 @@ def _slabs_made_anew(text, slab):
                 and "dynamic-update-slice" not in name])
 
 
+def _stacked_slabs(text, layers, b, hd, dec_len=129):
+    """``(layouts, ops)`` of the stacked self slabs ``[layers, L, b, h*d]`` in
+    the program: the minor-to-major order of every array of that shape (a
+    loop's carry laid out position-major reads ``3,2,1,0``, batch-major
+    ``3,1,2,0``) and the operations that make one."""
+    shape = rf"bf16\[{layers},{dec_len},{b},{hd}\]\{{([\d,]*)[^}}]*\}}"
+    made = re.findall(rf"%(\S+) = {shape} ([\w-]+)\(", text)
+    return set(re.findall(shape, text)), {
+        "append" if op == "dynamic-update-slice"
+        or "dynamic-update-slice" in name else op for name, _, op in made}
+
+
 def test_slab_counters_see_what_the_parent_programs_did():
-    """The two counters are not vacuous: lines of the programs as they were
+    """The counters are not vacuous: lines of the programs as they were
     compiled before PR 34 (the ``while`` body's write-back of a layer's slab;
-    the one-step program's copy of a layer out of a stacked parameter)."""
+    the one-step program's copy of a layer out of a stacked parameter) and
+    before PR 59 (the ``while``'s carry laid out batch-major, and a layer's
+    slice of it as the flat read's einsums took it)."""
     slab = _self_slab(256, 768)
+    carry = ("  %while.5 = (s32[]{:T(128)}, s32[256]{0:T(256)}, "
+             "bf16[12,129,256,768]{3,1,2,0:T(8,128)(2,1)}, "
+             "bf16[12,129,256,768]{3,1,2,0:T(8,128)(2,1)}) while(%tuple.7)\n"
+             "  ROOT %bitcast.1786 = bf16[256,129,768]{2,1,0:T(8,128)(2,1)} "
+             "bitcast(%slice.351)\n"
+             "  %copy.3 = bf16[12,129,256,768]{3,2,1,0:T(8,128)(2,1)} "
+             "copy(%get-tuple-element.9)")
+    layouts, ops = _stacked_slabs(carry, 12, 256, 768)
+    assert layouts == {"3,1,2,0", "3,2,1,0"}
+    assert ops == {"copy"}
+    assert re.search(slab, carry)
     line = ("  %copy-start.13 = (bf16[256,129,768]{2,1,0:T(8,128)(2,1)}, "
             "bf16[256,129,768]{2,1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) "
             "copy-start(%dynamic_update_slice.226)")
@@ -357,7 +382,7 @@ def test_slab_counters_see_what_the_parent_programs_did():
 
 
 @pytest.mark.parametrize("program", sorted(DECODE_PROGRAMS))
-def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
+def test_generate_streams_the_cache_unpadded_on_v5e(v5e, monkeypatch, program):
     """The programs that carry the T5 decode cache, as the chip's compiler
     makes them: ``generate`` at the W3 shape under both loop forms and the
     engine's donated step at the ``t5large-serve`` shape, over each prefix of
@@ -389,9 +414,24 @@ def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
 
     The temporaries stay near the cache's own 6 GB; with the dense path under
     the while-loop they were 14.1 GB (PERF.md, PR 25).  tests/test_t5.py
-    holds the same at the jaxpr; this holds what XLA makes of it."""
+    holds the same at the jaxpr; this holds what XLA makes of it.
+
+    Under a loop (PR 59) a layer reads the stacked slabs where they lie, one
+    ``prefix_append_decode_attention`` kernel a layer, and the kernel's
+    row-major operand is what lays the carry out: ``bf16[12,129,256,768]``
+    position-major (``{3,2,1,0}``: a position is whole tiles, 129 stays 129)
+    and nowhere batch-major (``{3,1,2,0}``, the flat read's einsums' choice:
+    a row one sublane of every tile, 129 stored as 144), no layer's slice
+    ``[129,256,768]`` / ``[256,129,768]`` is cut or copied out of it for the
+    kernel, and nothing makes a stacked array but the cache's zeros and the
+    append in place.  The engine's step is a ring with no prefix: it keeps
+    the flat read and has no kernel."""
     import itertools
 
+    from tpu_air.ops import decode_attention as da
+
+    # what the program traces on the chip, not on this host
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
     build, temp_limit = DECODE_PROGRAMS[program]
     lowered, cross = build(v5e)
     compiled = lowered.compile()
@@ -415,15 +455,24 @@ def test_generate_streams_the_cache_unpadded_on_v5e(v5e, program):
     assert compiled.memory_analysis().temp_size_in_bytes < temp_limit
 
     slab = _self_slab(b, h * d)
-    assert re.search(slab, text), "no self slab of the expected shape"
+    kernels = sum("tpu_custom_call" in ln and "prefix_append_decode_attention"
+                  in ln for ln in text.splitlines())
     written_back = _slabs_written_back(text, slab)
     if engine:
+        assert re.search(slab, text), "no self slab of the expected shape"
+        assert kernels == 0
         assert written_back <= 4, written_back
         assert _slabs_made_anew(text, slab) == written_back
         prefix = rf"= bf16\[129,{rows},{h * d}\]\{{[^}}]*\}} slice-done\("
         assert len(re.findall(prefix, text)) == (48 if rows < b else 0)
     else:
+        assert kernels == layers
         assert written_back == 0, written_back
+        assert not re.search(slab, text), re.search(slab, text).group(0)
+        layouts, ops = _stacked_slabs(text, layers, b, h * d)
+        assert layouts == {"3,2,1,0"}, layouts
+        assert ops <= {"parameter", "get-tuple-element", "broadcast",
+                       "append"}, ops
 
 
 @pytest.mark.parametrize("length", [128, 512])

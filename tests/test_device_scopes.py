@@ -647,6 +647,10 @@ def test_hand_made_capture_is_read_as_written(capture):
      30.0),
     (dict(scope="^(decode_attention|kv_append)$", under="^self_attn$",
           module="step"), 30.0),
+    # its two halves (PR 59: gen_self_read_share, gen_kv_append_share)
+    (dict(scope="^decode_attention$", under="^self_attn$", module="step"),
+     20.0),
+    (dict(scope="^kv_append$", under="^self_attn$", module="step"), 10.0),
     # "under" is ANOTHER component: kv_append is not under itself
     (dict(scope="^kv_append$", under="^kv_append$", module="step"), None),
     (dict(unscoped=True, module="step"), 20.0),
@@ -746,6 +750,10 @@ NEW = {
     # PR 58: the residual streams' five scopes, and the Sinkhorn rounds alone
     "mhc_share": ["xing4-serve-longdoc"],
     "mhc_sinkhorn_share": ["xing4-serve-longdoc"],
+    # PR 59: the two halves of gen_self_attn_share, a layer's read and the
+    # stacked append
+    "gen_self_read_share": ["t5base-batchgen", "t5large-batchgen"],
+    "gen_kv_append_share": ["t5base-batchgen", "t5large-batchgen"],
 }
 
 

@@ -443,6 +443,79 @@ def test_flat_append_decode_attention_equals_flat_over_the_appended_slab(
                                np.asarray(want, np.float32), atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("heads", [12, 16], ids=["base", "large"])
+@pytest.mark.parametrize("with_mask", [False, True], ids=["nomask", "mask"])
+@pytest.mark.parametrize("cur", [0, 21, 32, 40])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_prefix_append_decode_attention_matches_reference_and_flat_form(
+        dtype, cur, with_mask, heads):
+    """A decode loop's read of the stacked slabs where they lie (the Pallas
+    kernel, interpret mode; FLAN-T5-base's and -large's heads of 64, 16 rows)
+    == the dense reference over the slab with the row in place == the flat
+    read of the layer's slice, with no position written, ``cur`` inside a
+    block, on a block's edge and at the slab's last position (the last
+    block then starts early and masks what the one before it counted), with
+    the causal bias and with or without a key mask.  Only the blocks that
+    hold a written position are copied: every block past them is NaN here,
+    and so is the other layer."""
+    from tpu_air.ops import decode_attention as da
+
+    b, L, d, block = 16, 41, 64, da._PREFIX_BLOCK
+    dt = jnp.dtype(dtype)
+    q, k, v, bias, mask = _dk_inputs(b, L, heads, d, seed=cur, dtype=dt)
+    bias = bias + jnp.where(jnp.arange(L) <= cur, 0.0, -1e9)[None]
+    mask = mask.at[:, cur].set(1.0) if with_mask else None
+    want = da.decode_attention_reference(
+        q, _heads(k, heads), _heads(v, heads), bias=bias, kv_mask=mask)
+    pm = lambda x: jnp.swapaxes(x, 0, 1)  # noqa: E731
+    row = slice(cur, cur + 1)
+    flat = da.flat_append_decode_attention(
+        q, pm(k), pm(v), pm(k)[row], pm(v)[row], jnp.asarray(cur), bias,
+        mask, None, None, heads, dt)
+
+    def stacked(x):
+        # junk where the step's row will go, NaN from the first block with
+        # no written position on, and in the layer this call does not read
+        x = jax.lax.dynamic_update_slice(
+            pm(x), jnp.full((1, b, heads * d), 99, dt), (cur, 0, 0))
+        x = x.at[-(-cur // block) * block:].set(jnp.nan)
+        return jnp.stack([jnp.full_like(x, jnp.nan), x])
+
+    assert da.prefix_blocks_are_whole_tiles(stacked(k), heads)
+    got = da.prefix_append_decode_attention(
+        q, stacked(k), stacked(v), 1, pm(k)[row], pm(v)[row],
+        jnp.asarray(cur), bias, mask, heads, dt)
+    assert got.shape == q.shape and got.dtype == dt
+    for other in (want, flat):
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(other, np.float32),
+            atol=_DK_TOL[dtype], rtol=_DK_TOL[dtype])
+
+
+def test_prefix_read_is_taken_on_a_tpu_for_whole_tiles_alone(monkeypatch):
+    """The trace-time rule: a TPU, no mesh, bf16 or f32 slabs whose position
+    is whole tiles that split into row tiles, and positions enough for a
+    block; everything else keeps the flat read."""
+    from tpu_air.ops import decode_attention as da
+    from tpu_air.ops.flash_attention import kernel_mesh
+
+    def slabs(L=129, b=256, hd=768, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((12, L, b, hd), dtype)
+
+    assert not da.prefix_slabs_read_in_place(slabs(), 12)      # a CPU
+    monkeypatch.setattr(da.jax, "default_backend", lambda: "tpu")
+    assert da.prefix_slabs_read_in_place(slabs(), 12)
+    assert da.prefix_slabs_read_in_place(slabs(b=128, hd=1024), 16)
+    assert da.prefix_slabs_read_in_place(slabs(b=8, dtype=jnp.float32), 12)
+    assert not da.prefix_slabs_read_in_place(slabs(b=8), 12)   # half a tile
+    assert not da.prefix_slabs_read_in_place(slabs(b=3), 12)
+    assert not da.prefix_slabs_read_in_place(slabs(hd=64), 4)
+    assert not da.prefix_slabs_read_in_place(slabs(dtype=jnp.int8), 12)
+    assert not da.prefix_slabs_read_in_place(slabs(L=8), 12)
+    with kernel_mesh(object()):
+        assert not da.prefix_slabs_read_in_place(slabs(), 12)
+
+
 def _minor(x, h):
     """A flat ``[b, L, h*d]`` slab as the length-minor ``[b, h, d, L]`` one
     (no lane padding: the op takes any ``L``)."""

@@ -32,6 +32,35 @@ def tiny():
     return cfg, model, params
 
 
+@pytest.fixture
+def prefix_kernel(monkeypatch):
+    """``(cfg, model, params, calls)``: a tiny float32 model at whole-tile
+    widths (4 heads of 32: a position of 8 rows is one tile) with the decode
+    loop's rule patched to its answer on a TPU, so that the cached steps of a
+    batch of 8 over 16 positions or more read their stacked self slabs
+    through ``prefix_append_decode_attention`` (interpret mode); ``calls``
+    holds the stacked shape of every call traced."""
+    import dataclasses
+
+    from tpu_air.models.t5 import modeling
+    from tpu_air.ops import decode_attention as da
+
+    cfg = dataclasses.replace(T5Config.tiny(), d_kv=32)
+    model = T5ForConditionalGeneration(cfg)
+    one = jnp.ones((2, 8), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), one, one, one)["params"]
+    calls = []
+
+    def counted(*args, **kw):
+        calls.append(args[1].shape)
+        return da.prefix_append_decode_attention(*args, **kw)
+
+    monkeypatch.setattr(modeling, "prefix_append_decode_attention", counted)
+    monkeypatch.setattr(modeling, "prefix_slabs_read_in_place",
+                        da.prefix_blocks_are_whole_tiles)
+    return cfg, model, params, calls
+
+
 def test_forward_shapes(tiny):
     cfg, model, params = tiny
     logits = model.apply(
@@ -204,12 +233,14 @@ def test_byte_tokenizer_save_load(tmp_path):
     assert tok2.model_max_length == 77
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["auto", "int8"])
-def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, int8):
+@pytest.mark.parametrize("cache", ["auto", "int8", "prefix_kernel"])
+def test_generate_early_stop_matches_scan_and_exits_early(
+        tiny, monkeypatch, request, cache):
     """early_stop=True (the torch model.generate stopping criterion) must
     produce the identical sequences as the fixed-budget scan and actually
     stop once every sequence emitted EOS, over a full-width and an int8
-    cache."""
+    cache, and where both loops read their self slabs through the prefix
+    kernel (a layer's call a loop's body: the body is traced once)."""
     import dataclasses
 
     import jax
@@ -217,12 +248,17 @@ def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, int
 
     from tpu_air.models.t5.generate import make_generate_fn
 
-    cfg, _, params = tiny
-    model = T5ForConditionalGeneration(
-        dataclasses.replace(cfg, decode_cache_int8=int8))
+    calls, rows = None, 2
+    if cache == "prefix_kernel":
+        cfg, model, params, calls = request.getfixturevalue("prefix_kernel")
+        rows = 8
+    else:
+        cfg, _, params = tiny
+        model = T5ForConditionalGeneration(
+            dataclasses.replace(cfg, decode_cache_int8=cache == "int8"))
     rng = jax.random.PRNGKey(3)
-    ids = jax.random.randint(rng, (2, 12), 2, cfg.vocab_size, jnp.int32)
-    mask = jnp.ones((2, 12), jnp.int32)
+    ids = jax.random.randint(rng, (rows, 12), 2, cfg.vocab_size, jnp.int32)
+    mask = jnp.ones((rows, 12), jnp.int32)
 
     fn_scan = make_generate_fn(model, 16, early_stop=False)
     fn_early = make_generate_fn(model, 16, early_stop=True)
@@ -230,6 +266,9 @@ def test_generate_early_stop_matches_scan_and_exits_early(tiny, monkeypatch, int
     seq_b, steps_b = fn_early(params, ids, mask, rng)
     np.testing.assert_array_equal(np.asarray(seq_a), np.asarray(seq_b))
     assert int(steps_a) == 16
+    if calls is not None:
+        stacked = (cfg.num_decoder_layers, 17, rows, 4 * 32)
+        assert calls == [stacked] * 2 * cfg.num_decoder_layers, calls
 
     # force EOS on step one by patching the sampler (the loop under test,
     # not the model): a fresh fn traces against the patched module global
@@ -357,31 +396,41 @@ def test_generate_feature_composition_int8_earlystop_bucketing(tiny):
     assert base.shape == (8, 6)
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["auto", "int8"])
-def test_cached_step_logits_match_uncached_forward(tiny, int8):
+@pytest.mark.parametrize("cache", ["auto", "int8", "prefix_kernel"])
+def test_cached_step_logits_match_uncached_forward(tiny, request, cache):
     """Teacher-forced, fp32: the logits of every cached single-token step
     (flat self- and cross-attention slabs) equal the full uncached decoder
     forward's at that position, to 2e-4 of the logits' range; over an int8
     cache to the 5 % of the largest logit that
-    ``test_int8_cross_kv_cache_numerics`` allows quantisation."""
+    ``test_int8_cross_kv_cache_numerics`` allows quantisation.
+    ``prefix_kernel``: 8 rows over 18 positions, every step's self read
+    through the kernel: no position written, inside the first block, on its
+    edge, and in the last block, which starts early."""
     import dataclasses
 
     from tpu_air.models.t5.generate import init_cache
 
-    cfg, _, params = tiny
-    model = T5ForConditionalGeneration(
-        dataclasses.replace(cfg, decode_cache_int8=int8))
+    int8 = cache == "int8"
+    calls, rows, steps = None, 3, 6
+    if cache == "prefix_kernel":
+        cfg, model, params, calls = request.getfixturevalue("prefix_kernel")
+        rows, steps = 8, 18
+    else:
+        cfg, _, params = tiny
+        model = T5ForConditionalGeneration(
+            dataclasses.replace(cfg, decode_cache_int8=int8))
     rng = np.random.default_rng(11)
-    ids = jnp.asarray(rng.integers(2, cfg.vocab_size, (3, 10)), jnp.int32)
-    mask = jnp.asarray([[1] * 10, [1] * 7 + [0] * 3, [1] * 4 + [0] * 6], jnp.int32)
-    dec = jnp.asarray(rng.integers(2, cfg.vocab_size, (3, 6)), jnp.int32)
+    ids = jnp.asarray(rng.integers(2, cfg.vocab_size, (rows, 10)), jnp.int32)
+    mask = jnp.asarray(([[1] * 10, [1] * 7 + [0] * 3, [1] * 4 + [0] * 6]
+                        * 3)[:rows], jnp.int32)
+    dec = jnp.asarray(rng.integers(2, cfg.vocab_size, (rows, steps)), jnp.int32)
     dec = dec.at[:, 0].set(cfg.decoder_start_token_id)
     want = np.asarray(model.apply({"params": params}, ids, mask, dec))
 
     enc = model.apply({"params": params}, ids, mask, method=model.encode)
-    cache = init_cache(model, params, 3, 6, enc, mask)
+    cache = init_cache(model, params, rows, steps, enc, mask)
     got = []
-    for t in range(6):
+    for t in range(steps):
         logits, upd = model.apply(
             {"params": params, "cache": cache}, dec[:, t:t + 1], enc, mask,
             decode=True, mutable=["cache"], method=model.decode)
@@ -393,6 +442,8 @@ def test_cached_step_logits_match_uncached_forward(tiny, int8):
     else:
         atol = 2e-4 * float(want.max() - want.min())
     np.testing.assert_allclose(got, want, atol=atol, rtol=0)
+    if calls is not None:
+        assert len(calls) == steps * cfg.num_decoder_layers, len(calls)
 
 
 def _uncached_greedy(model, params, ids, mask, steps):
@@ -408,32 +459,45 @@ def _uncached_greedy(model, params, ids, mask, steps):
     return np.asarray(dec[:, 1:])
 
 
-@pytest.mark.parametrize("kind", ["while", "scan", "engine"])
-def test_cached_decode_gives_the_uncached_forwards_greedy_tokens(tiny, kind):
+@pytest.mark.parametrize("kind", ["while", "scan", "engine",
+                                  "while-prefix_kernel", "scan-prefix_kernel",
+                                  "engine-prefix_kernel"])
+def test_cached_decode_gives_the_uncached_forwards_greedy_tokens(
+        tiny, request, kind):
     """float32, ragged prompts: each of the three programs that carry the
     decode cache (``generate``'s while-loop and scan over one stacked array a
     kind of self slab, the engine's admit + donated steps over a tuple a
     layer, here three slots taken at once) emits the argmax chain of the full
-    forward, up to a row's EOS."""
+    forward, up to a row's EOS.  ``-prefix_kernel``: 8 rows and 19 steps at
+    whole-tile widths with the loop's rule saying yes: both loops read
+    through the kernel and the engine's ring, which has no prefix, does
+    not."""
     from tpu_air.models.t5.generate import (
         init_slot_state, make_generate_fn, make_t5_admit_fn,
         make_t5_slot_step_fn)
 
-    cfg, model, params = tiny
+    kind, _, forced = kind.partition("-")
+    calls, rows, steps = None, 3, 7
+    if forced:
+        cfg, model, params, calls = request.getfixturevalue("prefix_kernel")
+        rows, steps = 8, 19
+    else:
+        cfg, model, params = tiny
     rng = np.random.default_rng(5)
-    steps = 7
-    ids = jnp.asarray(rng.integers(2, cfg.vocab_size, (3, 10)), jnp.int32)
-    mask = jnp.asarray([[1] * 10, [1] * 6 + [0] * 4, [1] * 3 + [0] * 7], jnp.int32)
+    ids = jnp.asarray(rng.integers(2, cfg.vocab_size, (rows, 10)), jnp.int32)
+    mask = jnp.asarray(([[1] * 10, [1] * 6 + [0] * 4, [1] * 3 + [0] * 7]
+                        * 3)[:rows], jnp.int32)
     ids = ids * mask
     want = _uncached_greedy(model, params, ids, mask, steps)
     if kind == "engine":
-        state, tok = init_slot_state(model, params, 3, steps + 1, 10)
+        state, tok = init_slot_state(model, params, rows, steps + 1, 10)
         prompts = jnp.concatenate(
-            [ids, mask.sum(-1, keepdims=True), jnp.arange(3)[:, None]], axis=1)
+            [ids, mask.sum(-1, keepdims=True), jnp.arange(rows)[:, None]],
+            axis=1)
         admit = make_t5_admit_fn(model, 10)
-        for slot in range(3):       # a program a row, as the engine admits
+        for slot in range(rows):    # a program a row, as the engine admits
             state, tok = admit(params, state, tok, prompts[slot:slot + 1])
-        step = make_t5_slot_step_fn(model, 3)
+        step = make_t5_slot_step_fn(model, rows)
         got = []
         for _ in range(steps):
             state, tok = step(params, state, tok)
@@ -447,6 +511,9 @@ def test_cached_decode_gives_the_uncached_forwards_greedy_tokens(tiny, kind):
         eos = np.flatnonzero(row_want == cfg.eos_token_id)
         n = eos[0] + 1 if eos.size else steps
         np.testing.assert_array_equal(row_got[:n], row_want[:n])
+    if calls is not None:
+        assert len(calls) == (0 if kind == "engine"
+                              else cfg.num_decoder_layers), calls
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["full", "int8"])

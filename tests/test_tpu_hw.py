@@ -425,3 +425,53 @@ def test_held_rows_sum_is_the_float32_sum_on_chip():
     went through the MXU at bfloat16's 8 bits), as the gathered form is."""
     out = _run_on_tpu(_HELD_ROWS_SCRIPT)
     assert out.count("held rows") == 4, out
+
+
+_PREFIX_SCRIPT = """
+import jax, jax.numpy as jnp, numpy as np
+assert jax.devices()[0].platform == "tpu", jax.devices()
+from tpu_air.ops import decode_attention as da
+
+bf = jnp.bfloat16
+# the two batch-inference cells' slabs, two layers of them
+for name, (L, b, h, d) in {"base": (129, 256, 12, 64),
+                           "large": (129, 128, 16, 64)}.items():
+    ks = jax.random.split(jax.random.PRNGKey(59), 7)
+    shape = (2, L, b, h * d)
+    keys, vals = (jax.random.normal(k, shape, jnp.float32).astype(bf)
+                  for k in ks[:2])
+    q = jax.random.normal(ks[2], (b, 1, h, d), jnp.float32).astype(bf)
+    kr, vr = (jax.random.normal(k, (1, b, h * d), jnp.float32).astype(bf)
+              for k in ks[3:5])
+    assert da.prefix_slabs_read_in_place(keys, h)
+    for cur in (0, 5, 64, 127, 128):
+        bias = (jax.random.normal(ks[5], (h, L), jnp.float32)
+                + jnp.where(jnp.arange(L) <= cur, 0.0, -1e9)[None])
+        mask = (jax.random.uniform(ks[6], (b, L)) > 0.3).astype(
+            jnp.float32).at[:, cur].set(1.0)
+        live = -(-cur // da._PREFIX_BLOCK) * da._PREFIX_BLOCK
+        # NaN wherever no block with a written position reaches
+        nan = lambda x: x.at[0].set(jnp.nan).at[1, live:].set(jnp.nan)
+        for m in (None, mask):
+            got = jax.jit(lambda *a: da.prefix_append_decode_attention(
+                *a, h, bf), static_argnums=(3,))(
+                q, nan(keys), nan(vals), 1, kr, vr, jnp.int32(cur), bias, m)
+            want = jax.jit(lambda *a: da.flat_append_decode_attention(
+                *a, None, None, h, bf))(
+                q, keys[1], vals[1], kr, vr, jnp.int32(cur), bias, m)
+            got, want = (np.asarray(x, np.float32) for x in (got, want))
+            assert np.isfinite(got).all(), (name, cur)
+            np.testing.assert_allclose(got, want, atol=3e-2, rtol=3e-2)
+    print("prefix read", name, "ok", flush=True)
+"""
+
+
+def test_prefix_append_decode_attention_reads_the_written_prefix_on_chip():
+    """PR 59: ``ops/decode_attention.prefix_append_decode_attention``
+    compiled and run at the two batch-inference cells' shapes, at no position
+    written, inside a block, mid-slab, and at the last two positions (the
+    last block starts early), with and without a key mask, over slabs that
+    are NaN in the other layer and in every block with no written position:
+    finite, and the flat read's result over the same keys to bf16 rounding."""
+    out = _run_on_tpu(_PREFIX_SCRIPT)
+    assert out.count("prefix read") == 2, out

@@ -2,7 +2,9 @@
 of the lowered text (``jax.jit(body).lower(...).as_text()``, on the CPU, shapes
 only) of every ``InferenceEngine`` program (decode, chunk, mixed) of the tests'
 tiny dense, OLMoE, Jamba, latent-attention and Nemotron-H models: run it from
-the root of two trees and diff the output.
+the root of two trees and diff the output.  After them the T5 programs: the
+engine's two admits and three steps over its eight slots, and ``generate``'s
+``while`` and ``scan``, at widths of whole tiles.
 
     JAX_PLATFORMS=cpu python tools/lowered_programs.py > /tmp/change.txt
     (cd <parent tree> && JAX_PLATFORMS=cpu python <this file> > /tmp/parent.txt)
@@ -15,7 +17,10 @@ patched to say so, so the trace-time rules (``latent_pages_read_in_place``,
 ``state_rows_move_in_place``) pick what they pick on the chip, and the hash is
 of the traced jaxpr (a Mosaic kernel does not lower for the CPU).  The
 Nemotron-H model is there at a state of whole tiles, which the second rule
-wants (PR 48)."""
+wants (PR 48).  Of the T5 programs the engine's are the same text either way
+(a ring of positions has no written prefix: the flat read on every backend)
+and ``generate``'s two loops differ on a TPU alone (PR 59:
+``prefix_append_decode_attention`` under ``prefix_slabs_read_in_place``)."""
 
 import hashlib
 import os
@@ -35,11 +40,15 @@ from tpu_air.models.lm import CausalLM, LMConfig, hf_import  # noqa: E402
 from tpu_air.models.lm.generate import (  # noqa: E402
     init_paged_cache, make_paged_decode_body, make_paged_mixed_body,
     make_prefill_chunk_body)
+from tpu_air.models.t5 import T5Config, T5ForConditionalGeneration  # noqa: E402
+from tpu_air.models.t5.generate import (  # noqa: E402
+    init_slot_state, make_generate_fn, make_t5_admit_fn, make_t5_slot_step_fn)
 
 AS_TPU = "--as-tpu" in sys.argv[1:]
 if AS_TPU:
     jax.default_backend = lambda: "tpu"
 S, C, L = 4, 8, 64
+i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
 npg = L // C
 # a Mamba-2 state [heads, 8, 128]: whole float32 tiles
 nemotron = {**test_nemotron_h.TINY, "ssm_state_size": 128} if AS_TPU else test_nemotron_h.TINY
@@ -52,7 +61,6 @@ for name, cfg in cfgs.items():
     model = CausalLM(cfg)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     cache = jax.eval_shape(lambda: init_paged_cache(model, S, S * npg + 1, C, npg))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     slot = {"slot": i32()} if cfg.has_recurrent_layers else {}
     progs = {
         "decode": (make_paged_decode_body(model, L), (params, cache, i32(S), i32(S), i32(S, npg)), {}),
@@ -65,3 +73,18 @@ for name, cfg in cfgs.items():
         else:
             text = jax.jit(body, donate_argnums=(1,)).lower(*args, **kw).as_text()
         print(name, pn, len(text), hashlib.sha256(text.encode()).hexdigest()[:16])
+
+# T5 at 4 heads of 32 and 8 rows: a position [8, 128] is one float32 tile
+t5 = T5ForConditionalGeneration(T5Config(
+    vocab_size=384, d_model=64, d_kv=32, d_ff=128, num_layers=2, num_heads=4,
+    dropout_rate=0.0))
+params = jax.eval_shape(lambda: t5.init(jax.random.PRNGKey(0), *[jnp.ones((1, 8), jnp.int32)] * 3)["params"])
+state, tok = jax.eval_shape(lambda p: init_slot_state(t5, p, 8, 17, 16), params)
+progs = {f"admit{n}": (make_t5_admit_fn(t5, n), (params, state, tok, i32(1, n + 2))) for n in (8, 16)}
+progs.update({f"step{n}": (make_t5_slot_step_fn(t5, n), (params, state, tok)) for n in (2, 4, 8)})
+progs.update({kind: (make_generate_fn(t5, 16, early_stop=kind == "while"),
+                     (params, i32(8, 16), i32(8, 16), jax.ShapeDtypeStruct((2,), jnp.uint32)))
+              for kind in ("while", "scan")})
+for pn, (fn, args) in progs.items():
+    text = str(jax.make_jaxpr(fn)(*args)) if AS_TPU else fn.lower(*args).as_text()
+    print("t5", pn, len(text), hashlib.sha256(text.encode()).hexdigest()[:16])

@@ -35,6 +35,8 @@ from tpu_air.ops.decode_attention import (
     length_minor,
     length_minor_decode_attention,
     pad_keys,
+    prefix_append_decode_attention,
+    prefix_slabs_read_in_place,
 )
 
 from .config import T5Config
@@ -223,11 +225,15 @@ class Attention(nn.Module):
         cross_decode: bool = False,
         deterministic: bool = True,
     ):
-        """``appending`` ``(k_slab, v_slab, k_scales, v_scales, index)``:
-        this layer's self-attention slabs ``[L, b, h*d]`` as they were
-        before the step (scales ``[L, b, h]`` or None) and the position the
-        step's rows go to.  The result is then ``(out, rows)``, ``rows`` the
-        step's ``(k, v, k_scales, v_scales)`` as stored, ``[new, b, ...]``."""
+        """``appending`` ``(k_slab, v_slab, k_scales, v_scales, index,
+        layer)``: this layer's self-attention slabs ``[L, b, h*d]`` as they
+        were before the step (scales ``[L, b, h]`` or None) and the position
+        the step's rows go to; ``layer`` None, or this layer's index where
+        the slabs are the STACKED ``[layers, L, b, h*d]`` of a decode loop
+        whose positions from ``index`` on no row has written (read where
+        they lie, ``prefix_append_decode_attention``).  The result is then
+        ``(out, rows)``, ``rows`` the step's ``(k, v, k_scales, v_scales)``
+        as stored, ``[new, b, ...]``."""
         cfg = self.config
         dtype = _dtype(cfg)
         init = nn.initializers.normal(stddev=cfg.d_model**-0.5)
@@ -364,7 +370,7 @@ class Attention(nn.Module):
             # step's K/V land — the self-attention half of the
             # decode-bandwidth story (cross is quantized whole at cache
             # init above).
-            k_slab, v_slab, ks_slab, vs_slab, cur = appending
+            k_slab, v_slab, ks_slab, vs_slab, cur, layer = appending
             bsz, new = k.shape[0], k.shape[1]
             hd = cfg.num_heads * cfg.d_kv
             ks_rows = vs_rows = None
@@ -392,9 +398,12 @@ class Attention(nn.Module):
             k, v = k_slab, v_slab
             cached_step = True
 
-        # cached slabs: cross [b, h, d, Lp], self [L, b, h*d]
+        # cached slabs: cross [b, h, d, Lp], self [L, b, h*d] (stacked: of
+        # every layer)
+        stacked = appending is not None and layer is not None
         qlen = q.shape[1]
-        klen = k.shape[-1 if cross_cached else 0 if cached_step else 1]
+        klen = k.shape[-1 if cross_cached else 1 if not cached_step or stacked
+                       else 0]
         # Pallas blockwise path for a DETERMINISTIC pass: eligible when
         # callers passed the structured mask form (causal flag + key-padding
         # row — never a dense (q, k) tensor) and we're not in cached decode
@@ -464,6 +473,11 @@ class Attention(nn.Module):
                         q, k, v, bias_arg, kv_mask,
                         dk_scales[0], dk_scales[1], dtype,
                     )
+                elif stacked:
+                    ctx = prefix_append_decode_attention(
+                        q, k, v, layer, appended[0], appended[1], cur,
+                        bias_arg, kv_mask, cfg.num_heads, dtype,
+                    )
                 else:
                     ctx = flat_append_decode_attention(
                         q, k, v, appended[0], appended[1], cur,
@@ -484,6 +498,9 @@ class Attention(nn.Module):
                 else:
                     # a copy of this layer's slabs with the rows in place,
                     # batch-major again
+                    if stacked:
+                        k, v = k[layer], v[layer]
+
                     def view(slab, rows, scale):
                         x = jnp.swapaxes(jax.lax.dynamic_update_slice(
                             slab, rows, (cur, 0, 0)), 0, 1)
@@ -757,18 +774,31 @@ class Decoder(nn.Module):
             rows = []
             kwargs = dict(**self_masks, cross_kv_mask=enc_mask,
                           decode=True, deterministic=deterministic)
+            # One causal prefix for every row (no ring), all layers in one
+            # array a kind, the step over all its rows: a layer's read may
+            # then walk the stacked slabs where they lie, the positions
+            # written so far alone (ops/decode_attention.py has the rule
+            # for the rest: a TPU, no mesh, bf16 or f32 in whole tiles)
+            in_place = (
+                not is_init and ring_born is None
+                and not isinstance(slabs[0].value, tuple)
+                and slabs[0].value.shape[2] == bsz
+                and prefix_slabs_read_in_place(slabs[0].value, cfg.num_heads))
             for i in range(nl):
                 layer = DecoderLayer(cfg, name=f"layer_{i}")
                 if is_init:
                     x = layer(x, enc, bias, **kwargs)
                     continue
-                own = [s.value[i] for s in slabs]
-                if own[0].shape[1] > bsz:            # a step over a prefix
-                    with jax.named_scope("self_attn/kv_gather"):
-                        own = [o[:, :bsz] for o in own]
+                if in_place:
+                    own = [s.value for s in slabs]
+                else:
+                    own = [s.value[i] for s in slabs]
+                    if own[0].shape[1] > bsz:        # a step over a prefix
+                        with jax.named_scope("self_attn/kv_gather"):
+                            own = [o[:, :bsz] for o in own]
                 own += [None] * (4 - len(own))       # no scales
-                x, new = layer(x, enc, bias, **kwargs,
-                               appending=(*own, pos.value))
+                x, new = layer(x, enc, bias, **kwargs, appending=(
+                    *own, pos.value, i if in_place else None))
                 rows.append(new)
             if not is_init:
                 # the cache-init pass (a real apply now, so cross K/V get

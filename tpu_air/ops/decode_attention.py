@@ -10,6 +10,9 @@ init, only read after  ``[b, h, d, 1]``
 T5 self-attention:     FLAT, position-major        :func:`flat_append_decode_attention`
 grows a position a     ``[L, b, h*d]``, scales
 step                   ``[L, b, h]``
+... in a decode LOOP   the same, all layers one    :func:`prefix_append_decode_attention`
+on a TPU: one causal   array ``[layers, L, b,      (Pallas: the blocks that hold a
+prefix for every row   h*d]``, read where it lies  written position, no other)
 the LM's K/V: pages    FLAT ``[b, L, h*d]``,       :func:`flat_decode_attention`
 gathered for the step  no scales
 the LM's latent,       ONE slab ``[b, L, w]``       :func:`latent_decode_attention`
@@ -38,6 +41,23 @@ it back to HBM whole, every step (PERF.md, PR 34: 23 of FLAN-T5-base's 24
 slabs under ``generate``'s ``while``, a sixth of the step's traffic and none
 of it counted by the roofline).  Whoever holds the slabs appends the rows
 apart from the read (``models/t5/modeling.py``, ``Decoder``).
+
+PREFIX (PR 59): how a loop's carry LIES is the compiler's to choose, and for
+the flat read's two einsums, whose batch dimension is ``b``, it chose
+batch-major: the logical ``[layers, L, b, h*d]`` stored ``[layers, b, L,
+h*d]``.  The step's row was then one sublane of every tile (FLAN-T5-base's 9.4
+MB of rows a step took 0.64 ms, not 11 us), 129 positions were stored and read
+as 144, and the positions not yet written, half of them over a 128-token call,
+were a strided sliver of every tile that no read could skip.
+:func:`prefix_append_decode_attention` is one kernel a layer over the stacked
+array as it is stored logically: a ``pallas_call`` takes its operands
+row-major, so the carry stays position-major, a position is ``b x h*d``
+contiguous whole tiles, the append writes whole tiles, and the kernel copies
+the blocks up to the step's position and no other.
+:func:`prefix_slabs_read_in_place` is the rule: a TPU, no mesh, bf16 or f32
+slabs of whole tiles; that every row shares one causal prefix is the caller's
+to know (``Decoder``: no ring).  A ring (``T5Engine``: any position may be
+live for some row), int8 slabs and every CPU run keep the flat read.
 
 LENGTH-MINOR: ``(d, Lp)`` are whole tiles as stored, so each head's slab is
 contracted directly, with no selector and none of its ``num_heads`` x
@@ -204,10 +224,15 @@ def latent_decode_attention(q, latent, kv_mask, rank, dtype):
     return ctx[..., :rank].astype(dtype)
 
 
+def _sublanes(dtype) -> int:
+    """Rows of a TPU tile of ``dtype``: 8 of 32 bits, 16 of 16, 32 of 8."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
 def pages_are_whole_tiles(pool: jax.Array) -> bool:
     """A page ``[page_len, w]`` of the pool is whole tiles of its dtype."""
     _, page_len, w = pool.shape
-    return page_len % (32 // pool.dtype.itemsize) == 0 and w % _LANES == 0
+    return page_len % _sublanes(pool.dtype) == 0 and w % _LANES == 0
 
 
 def latent_pages_read_in_place(pool: jax.Array) -> bool:
@@ -497,9 +522,13 @@ def flat_append_decode_attention(q, kf, vf, k_row, v_row, cur, bias_hl,
     through the step and writes all of it back to HBM for the next one
     (PERF.md, PR 34).  Read this way the slab is only read, and the append
     is one ``[1, b, h*d]`` block written in place, by whoever holds the
-    slab: whole tiles where the program keeps this order (the engine's step
-    does; a loop's carry the compiler lays out as it likes), where a row of
-    a ``[b, L, h*d]`` slab is one sublane of ``b`` tiles.
+    slab: whole tiles where the program keeps this order, where a row of a
+    ``[b, L, h*d]`` slab is one sublane of ``b`` tiles.  The engine's step
+    keeps it (its slabs are parameters).  A loop's carry the compiler lays
+    out as it likes, and for this function's two einsums it likes ``b``
+    first: which is why a decode loop on a TPU reads through
+    :func:`prefix_append_decode_attention` instead, whose operands fix the
+    order.
 
     The row's scores are written into the small ``[b, L, h]`` score array at
     ``cur`` and its probability is taken out again for the row's own value,
@@ -540,6 +569,265 @@ def flat_append_decode_attention(q, kf, vf, k_row, v_row, cur, bias_hl,
     p = jax.lax.dynamic_update_slice(p, jnp.zeros_like(p_row), (0, cur, 0))
     ctx = context(p, vf) + context(p_row, v_row)
     return ctx.reshape(b, 1, h, d).astype(dtype)
+
+
+_PREFIX_BLOCK = 16      # positions a copy
+_PREFIX_ROWS = 64       # batch rows a copy
+_PREFIX_VMEM = 64 << 20  # the kernel's blocks and their f32 products
+
+
+def _prefix_row_tile(b: int, dtype) -> int:
+    """Batch rows a block of :func:`prefix_append_decode_attention`: the
+    largest divisor of ``b`` up to ``_PREFIX_ROWS`` that is whole sublane
+    tiles of the slab's dtype, or 0 where there is none."""
+    return next((t for t in range(min(b, _PREFIX_ROWS), 0, -1)
+                 if b % t == 0 and t % _sublanes(dtype) == 0), 0)
+
+
+def prefix_blocks_are_whole_tiles(slabs: jax.Array, num_heads: int) -> bool:
+    """Stacked slabs ``[layers, L, b, h*d]`` bf16 or f32 (an int8 slab's
+    scale a position is an operand the kernel does not take) whose position
+    ``[b, h*d]`` is whole tiles that split into row tiles, with positions
+    enough for a block and heads that fit a tile's lanes."""
+    _, L, b, hd = slabs.shape
+    return (slabs.dtype in (jnp.bfloat16, jnp.float32)
+            and hd % _LANES == 0 and hd % num_heads == 0
+            and num_heads <= _LANES and L >= _PREFIX_BLOCK
+            and _prefix_row_tile(b, slabs.dtype) > 0)
+
+
+def prefix_slabs_read_in_place(slabs: jax.Array, num_heads: int) -> bool:
+    """Does a decode loop's step read these stacked self-attention slabs
+    ``[layers, L, b, h*d]`` where they lie, the written prefix alone
+    (:func:`prefix_append_decode_attention`), or a layer's slice whole
+    (:func:`flat_append_decode_attention`)?  Decided at trace time from what
+    the call can observe, no knob: the backend is a TPU (interpret mode is
+    for tests), the program is not traced for a mesh, and the blocks are
+    whole tiles.  That the positions from the step's own on are unwritten
+    for EVERY row is the caller's to know (``Decoder``: no ring)."""
+    return (jax.default_backend() == "tpu" and not traced_for_mesh()
+            and prefix_blocks_are_whole_tiles(slabs, num_heads))
+
+
+def _selected(x, sel, pieces):
+    """``x [n, k]`` f32 times the 0/1 selector ``sel [k, m]`` through the
+    MXU, exact as far as ``pieces`` bf16 pieces hold ``x``: 2 hold the
+    product of two bf16 numbers whole (its 16 leading bits), 3 any f32.
+    With ``sel [h*d, 128]`` a head's lanes are summed, in f32 over exact
+    products; with its transpose a number a head is spread over the head's
+    lanes."""
+    out = None
+    for _ in range(pieces):
+        piece = x.astype(jnp.bfloat16)
+        x = x - piece.astype(jnp.float32)
+        part = jnp.dot(piece, sel, preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+    return out
+
+
+def _key_mask(valid, first, n):
+    """``valid [r, Lp]`` 0/1, a row's keys along the lanes -> the additive
+    mask ``[n, r, 128]`` of keys ``first .. first + n - 1``, a key's number
+    in every lane of its row: one 0/1 column selected a key, by the MXU
+    (a lane that moves at run time is no slice the compiler takes)."""
+    at = jax.lax.broadcasted_iota(jnp.int32, (valid.shape[1], _LANES), 0)
+    keys = [jnp.dot(valid, (at == first + p).astype(valid.dtype),
+                    preferred_element_type=jnp.float32) for p in range(n)]
+    return jnp.where(jnp.stack(keys) > 0.5, 0.0, _NEG_INF_DENSE)
+
+
+def _prefix_kernel(at_ref, q_ref, krow_ref, vrow_ref, bias_ref, sel_ref,
+                   selt_ref, *refs, dtype, masked):
+    """The whole read of one layer (``at_ref``: the step's position, the
+    layer): the step's own row first (it is every
+    row's running maximum to begin with, so no row is ever empty), then a
+    loop over the LIVE blocks ``(positions, rows)``, positions outermost,
+    each copied from the stacked slabs in HBM into one of two buffers while
+    the block before it is computed on; the trip count is taken from ``cur``
+    at run time, so a position not yet written is never copied.  A block
+    that would run off the slab's end starts earlier instead and masks what
+    the block before it has counted."""
+    valid_ref = refs[0] if masked else None
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, m_ref, l_ref, acc_ref = refs[masked:]
+    block, rows, hd = kbuf.shape[1:]
+    L, b = k_hbm.shape[1], k_hbm.shape[2]
+    tiles = b // rows
+    cur = jnp.minimum(at_ref[0], L - 1)     # the step's row has a position
+    layer = at_ref[1]
+    total = pl.cdiv(cur, block) * tiles
+    f32 = jnp.float32
+
+    def at(t):
+        j, i = t // tiles, t % tiles
+        return j, pl.multiple_of(i * rows, rows), jnp.minimum(j * block,
+                                                              L - block)
+
+    def fetch(t, slot):
+        _, row0, start = at(t)
+        src = (layer, pl.ds(start, block), pl.ds(row0, rows))
+        return (pltpu.make_async_copy(k_hbm.at[src], kbuf.at[slot],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[src], vbuf.at[slot],
+                                      sems.at[1, slot]))
+
+    @pl.when(total > 0)
+    def _():
+        for copy in fetch(0, 0):
+            copy.start()
+
+    sel, sel_t = sel_ref[...], selt_ref[...]
+    pieces = 2 if kbuf.dtype == jnp.bfloat16 else 3
+
+    own = _selected(krow_ref[...].astype(f32) * q_ref[...].astype(f32), sel,
+                    pieces)
+    own = own + bias_ref[cur]                              # [b, 128]
+    if masked:
+        own = own + _key_mask(valid_ref[...], cur, 1)[0]
+    m_ref[...] = own
+    l_ref[...] = jnp.ones_like(l_ref)
+    acc_ref[...] = vrow_ref[...].astype(f32)
+
+    def step(t, carry):
+        slot = t % 2
+
+        @pl.when(t + 1 < total)
+        def _():
+            for copy in fetch(t + 1, 1 - slot):
+                copy.start()
+
+        for copy in fetch(t, slot):
+            copy.wait()
+        j, row0, start = at(t)
+        at_rows = pl.ds(row0, rows)
+        q = q_ref[at_rows, :].astype(f32)                  # [rows, hd]
+        s = _selected((kbuf[slot].astype(f32) * q[None]
+                       ).reshape(block * rows, hd), sel, pieces)
+        s = s.reshape(block, rows, _LANES) + bias_ref[pl.ds(start, block)]
+        if masked:
+            s = s + _key_mask(valid_ref[at_rows, :], start, block)
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        s = jnp.where(jnp.logical_and(pos >= j * block, pos < cur), s,
+                      _MASK_FLOOR)
+        m = m_ref[at_rows, :]
+        m_new = jnp.maximum(m, s.max(0))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[None])
+        l_ref[at_rows, :] = alpha * l_ref[at_rows, :] + p.sum(0)
+        m_ref[at_rows, :] = m_new
+        spread = jnp.dot(
+            p.reshape(block * rows, _LANES).astype(dtype), sel_t,
+            preferred_element_type=f32).reshape(block, rows, hd)
+        acc_ref[at_rows, :] = (
+            _selected(alpha, sel_t, 3) * acc_ref[at_rows, :]
+            + (spread * vbuf[slot].astype(f32)).sum(0))
+        return carry
+
+    jax.lax.fori_loop(0, total, step, None)
+    o_ref[...] = (acc_ref[...] / _selected(l_ref[...], sel_t, 3)
+                  ).astype(o_ref.dtype)
+
+
+@jax.named_scope("decode_attention")
+def prefix_append_decode_attention(q, keys, values, layer, k_row, v_row, cur,
+                                   bias_hl, kv_mask, num_heads, dtype,
+                                   interpret=None):
+    """:func:`flat_append_decode_attention` for a decode LOOP, over the
+    stacked slabs where they lie: ``keys``/``values`` ``[layers, L, b,
+    h*d]`` as they were before the step, of which this call reads layer
+    ``layer`` and of that the positions ``< cur`` alone, the step's own row
+    ``k_row``/``v_row`` ``[1, b, h*d]`` apart from them as there.  One
+    Pallas kernel, K and V in one call; it takes the stacked arrays
+    row-major as every ``pallas_call`` does, which is what keeps the loop's
+    carry position-major: a position is ``b x h*d`` contiguous whole tiles,
+    the append after the reads writes whole tiles, and ``L`` is no tiled
+    dimension (the compiler left to itself lays the carry out batch-major
+    for the flat read's two einsums: a row is then one sublane of every tile
+    and 129 positions are stored as 144; docs/KERNELS.md).
+
+    For a caller whose positions from ``cur`` on are unwritten for EVERY row
+    (one causal prefix under one scalar position: ``generate``); a ring
+    (``T5Engine``) has no such prefix and keeps the flat read.
+    :func:`prefix_slabs_read_in_place` is the rule.
+
+    Same mathematics as the flat read over the same keys (positions ``<
+    cur`` and the row): operands in the slab's dtype, a head's products
+    summed in f32 and exact before the sum (``_selected``), ``bias_hl``
+    additive f32 ``[h, L]`` (its column ``cur`` is the row's), ``kv_mask``
+    ``[b, L]`` or None, a running softmax in f32, the probabilities in
+    ``dtype`` against V with the context accumulated in f32; the order of
+    the sums apart.  q and the result ``[b, 1, h, d]``.
+
+    ``layer`` rides to the kernel beside ``cur`` as a run-time scalar and the
+    call is jitted, so the layers of a program share ONE trace and one
+    lowering of the kernel (a trace a layer was 3 s of FLAN-T5-large's first
+    call on the chip's host).
+
+    VMEM (``t5base-batchgen``: blocks of 16 positions x 64 rows x 768
+    bf16): two K and two V buffers 6.3 MB, the f32 products and
+    probabilities of a block about 16 MB, q, the rows and the result 1.6
+    MB, the running maximum, sum and context 1.0 MB.  ``interpret`` None:
+    interpret mode off a TPU (tests)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    at = jnp.stack([jnp.asarray(cur, jnp.int32), jnp.asarray(layer, jnp.int32)])
+    return _prefix_read(at, q, k_row, v_row, bias_hl, kv_mask, keys, values,
+                        num_heads=num_heads, dtype=dtype,
+                        interpret=bool(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "dtype", "interpret"))
+def _prefix_read(at, q, k_row, v_row, bias_hl, kv_mask, keys, values, *,
+                 num_heads, dtype, interpret):
+    """The kernel's call: ``at`` int32 ``[2]``, the step's position and the
+    layer."""
+    _, L, b, hd = keys.shape
+    h, d = num_heads, hd // num_heads
+    rows = _prefix_row_tile(b, keys.dtype)
+    # sel[f, n]: lane f of a row belongs to head n (n < h; 128 wide for the MXU)
+    sel = (jnp.arange(hd)[:, None] // d == jnp.arange(_LANES)[None, :])
+    # a position a tile row, a head a lane
+    bias = (jnp.zeros((L, 1, _LANES), jnp.float32) if bias_hl is None else
+            pad_keys(bias_hl.T.astype(jnp.float32), _LANES)[:, None, :])
+
+    masked = kv_mask is not None
+    valid = []
+    if masked:          # 0/1, a row's keys along whole lanes
+        valid = [pad_keys((kv_mask > 0).astype(jnp.bfloat16), L + -L % _LANES)]
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))
+
+    out = pl.pallas_call(
+        functools.partial(_prefix_kernel, dtype=dtype, masked=masked),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[
+                whole((b, hd)), whole((b, hd)), whole((b, hd)),
+                whole((L, 1, _LANES)), whole((hd, _LANES)),
+                whole((_LANES, hd)), *(whole(v.shape) for v in valid),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=whole((b, hd)),
+            scratch_shapes=[
+                pltpu.VMEM((2, _PREFIX_BLOCK, rows, hd), keys.dtype),
+                pltpu.VMEM((2, _PREFIX_BLOCK, rows, hd), values.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((b, _LANES), jnp.float32),
+                pltpu.VMEM((b, _LANES), jnp.float32),
+                pltpu.VMEM((b, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hd), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_PREFIX_VMEM),
+        interpret=interpret,
+        name="prefix_append_decode_attention",
+    )(at, q.reshape(b, hd).astype(dtype), k_row.reshape(b, hd),
+      v_row.reshape(b, hd), bias, sel.astype(jnp.bfloat16),
+      sel.T.astype(jnp.bfloat16), *valid, keys, values)
+    return out.reshape(b, 1, h, d)
 
 
 def length_minor(x: jax.Array) -> jax.Array:
