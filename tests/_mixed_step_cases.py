@@ -63,7 +63,7 @@ def _two_programs_and_one(model, params):
     step = jax.jit(make_paged_decode_logits_body(model, SLOT_LEN))
     mixed = jax.jit(make_paged_mixed_logits_body(model, C, SLOT_LEN))
     table = 1 + np.arange(S * npg, dtype=np.int32).reshape(S, npg)
-    kw = (lambda s: {"slot": jnp.int32(s)}) if cfg.has_recurrent_layers \
+    kw = (lambda s: {"slot": jnp.int32(s)}) if cfg.keeps_slot_rows \
         else (lambda s: {})
 
     def chunk_args(s, toks, p0):
@@ -92,12 +92,13 @@ def _two_programs_and_one(model, params):
 
 def _per_sequence_leaves(cache):
     """(path, leaf) of the K/V pools without the null page, and of the
-    per-slot state."""
+    per-slot rows (recurrent state, a window layer's ring)."""
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         key = path[-1].key
         if key in ("cached_key", "cached_value"):
             yield jax.tree_util.keystr(path), np.asarray(leaf[1:])
-        elif key in ("conv_state", "ssm_state"):
+        elif key in ("conv_state", "ssm_state", "window_key",
+                     "window_value"):
             yield jax.tree_util.keystr(path), np.asarray(leaf)
 
 
@@ -143,7 +144,7 @@ def the_chunks_slot_keeps_the_chunks_state_and_pages(model, params, check):
             assert np.abs(b[2] - was[2]).max() > 0      # not the held copy
             np.testing.assert_allclose(a[2], b[2], atol=2e-6)
             np.testing.assert_array_equal(b[3], was[3])  # the free row, held
-    assert moved == bool(model.config.has_recurrent_layers)
+    assert moved == bool(model.config.keeps_slot_rows)
 
 
 # -- the engine ---------------------------------------------------------------

@@ -23,6 +23,7 @@ T5, PAGED = ["t5large-serve"], ["olmoe-serve-decode", "jamba2-serve-reason",
                                 "nemotron3-serve-agent"]
 # PR 58 appended its cell to the three of the engine's programs
 XING = "xing4-serve-longdoc"
+LAGUNA = "laguna-serve-mixedlen"       # PR 60 appended its own behind it
 
 # (name, start us, duration us, counts).  Five requests: queue waits 100,
 # 200, 300, 400, 1000 us (median 300; p95 = 400 + 0.8 x 600 = 880), prefill
@@ -178,7 +179,8 @@ def test_the_manifest_appends_the_seven_and_still_validates(bench):
     for m in bench.doc["per_layer"][at:at + 7]:
         assert (m["source"], m["layer"]) == ("program_span", "engine")
         assert m["workloads"] == (
-            T5 if m["moves"] == "serve_ttft_p95_ms" else PAGED + [XING])
+            T5 if m["moves"] == "serve_ttft_p95_ms"
+            else PAGED + [XING, LAGUNA])
         assert set(m["workloads"]) <= cells
         # a cell that lists the metric reports the metric it moves
         assert set(m["workloads"]) <= set(e2e[m["moves"]]["workloads"])

@@ -76,7 +76,8 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
     assert names[at + 7] == combine["name"]
     assert combine["workloads"] == [
         "gigachat-serve-docchat", CELL, "olmoe-serve-decode",
-        "xing4-serve-longdoc"]                     # PR 58 appended its own
+        "xing4-serve-longdoc",                     # PR 58 appended its own
+        "laguna-serve-mixedlen"]                   # and PR 60
     assert (combine["unit"], combine["better"], combine["source"],
             combine["layer"], combine["moves"]) == (
         "%", "lower", "device_trace", "kernels", "serve_tpot_p50_ms")

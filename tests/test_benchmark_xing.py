@@ -42,7 +42,7 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
     cell = bench.cell(CELL)
     assert (cell["config"], cell["chips"]) == (CONFIG, 1)
     assert bench.doc["workloads"][9] is cell        # appended, not inserted
-    assert len(bench.doc["workloads"]) == 10
+    assert len(bench.doc["workloads"]) >= 10        # later PRs append
     assert sum(w["chips"] == 4 for w in bench.doc["workloads"]) == 1
     assert bench.doc["configs"][6]["name"] == CONFIG
     assert bench.doc["configs"][6]["reduced"] == [
@@ -71,9 +71,11 @@ def test_manifest_finds_the_cell_and_lists_it_where_it_reports(bench):
     for m in mine:
         assert m["workloads"] == [CELL] and m["unit"] == "%"
         assert m["source"] == "device_trace"
+    # (PR 60 appended its own cell behind it where both report)
     for m in bench.doc["per_layer"] + bench.doc["end_to_end"]:
         if CELL in m.get("workloads", []):
-            assert m["workloads"][-1] == CELL
+            assert [w for w in m["workloads"]
+                    if w != "laguna-serve-mixedlen"][-1] == CELL
     # no other cell meets a reader or a hook of this PR
     for other in (w["name"] for w in bench.doc["workloads"][:9]):
         names = {m["name"] for m in bench.metrics("per_layer", other)}

@@ -1036,3 +1036,73 @@ def test_residual_streams_programs_fit_the_chip_with_their_kernels_on_v5e(
     assert mem.argument_size_in_bytes == pytest.approx(11.46e9, rel=0.01)
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 15.75 * 2 ** 30)
+
+
+# -- window layers that keep a ring beside full layers that keep pages (PR 60)
+
+@pytest.mark.parametrize("program", ["decode", "mixed", "chunk"])
+def test_window_rings_programs_fit_the_chip_and_hold_no_slot_len_array_on_v5e(
+        v5e, monkeypatch, program):
+    """``laguna-serve-mixedlen``'s three programs at its widths, depth and
+    geometry (64 slots x 4096, pages of 256): the chip's compiler takes each;
+    the three products of the four sparse layers are the grouped kernel (256
+    experts of 2048 x 512: the first tile fits) beside the sum over a token's
+    choices; NO array ``[64, 4096, .]`` (every slot at ``slot_len``) belongs
+    to a window layer, whose rings ``[64, 768, 1024]`` come back aliased with
+    the full layers' pools; and the program fits the chip beside its 10.5 GB
+    of arguments."""
+    import json
+    import os
+
+    from benchmark import weights_swa
+    from tpu_air.models.lm import CausalLM
+    from tpu_air.models.lm.generate import (make_paged_decode_body,
+                                            make_paged_mixed_body,
+                                            make_prefill_chunk_body)
+    from tpu_air.ops import moe
+
+    monkeypatch.setattr(moe.jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "benchmark", "configs", "laguna-xs.2.json")) as f:
+        cfg = weights_swa.lm_config(json.load(f), "bfloat16", 4096)
+    model = CausalLM(cfg)
+    slots, slot_len, page = 64, 4096, 256
+    npg = slot_len // page
+    assert cfg.window_ring_len(page) == 768
+    params, cache, i32 = _serving_pool(model, slots, slot_len, page, v5e)
+    step = (i32(slots), i32(slots), i32(slots, npg))
+    chunk = (i32(1, page), i32(), i32(), i32(npg))
+    slot = {"slot": i32()}
+    body, args, kw = {
+        "decode": (make_paged_decode_body(model, slot_len), step, {}),
+        "chunk": (make_prefill_chunk_body(model, page, slot_len), chunk, slot),
+        "mixed": (make_paged_mixed_body(model, page, slot_len), step + chunk,
+                  slot),
+    }[program]
+    compiled = jax.jit(body, donate_argnums=(1,)).lower(
+        params, cache, *args, **kw).compile()
+    text = compiled.as_text()
+    sums = sum("tpu_custom_call" in line and "held_rows_sum" in line
+               for line in text.splitlines())
+    assert sums == 4
+    # what is left: three grouped products a sparse layer, none ragged_dot
+    assert text.count("tpu_custom_call") == sums + 4 * 3
+    assert "ragged" not in text
+    assert '"estimated_cycles":"9223372036854775807"' not in text
+    whole = re.compile(r"\[64,4096,\d+\]")
+    of_window = [line for line in text.splitlines() if whole.search(
+        line.split(" = ")[-1].split("(")[0] if " = " in line else "")
+        and re.search(r"layer_[123]/", line)]
+    assert not of_window, of_window[:3]
+    if program != "chunk":      # the full layers' gathered read is there
+        assert any(whole.search(line) and re.search(r"layer_[04]/", line)
+                   for line in text.splitlines())
+    held = (2 * 2 * (slots * npg + 1) * page * 1024 * 2
+            + 3 * 2 * slots * 768 * 1024 * 2)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= held            # written to in place
+    assert mem.argument_size_in_bytes == pytest.approx(
+        7.74e9 + held, rel=0.01)
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 15.75 * 2 ** 30)

@@ -371,6 +371,48 @@ def _lower_mixed(model, params, cache, i32, slots, slot_len, page, npg):
         i32(), i32(), i32(npg)).compile()
 
 
+def _laguna(build):
+    """Window layers that keep a ring beside full layers that keep pages,
+    heads by kind, a gate a head (PR 60): F-dense S S S F at the tiny size
+    of ``tests/test_laguna.py``; its chunk programs are told their slot."""
+    import test_laguna
+    from tpu_air.models.lm import CausalLM, hf_import
+    from tpu_air.models.lm.generate import init_paged_cache
+
+    def run():
+        cfg = hf_import.lm_config_from_hf(test_laguna.TINY, max_seq_len=32)
+        model = CausalLM(cfg)
+        params = jax.eval_shape(lambda: model.init(
+            jax.random.PRNGKey(0), jnp.ones((1, 8), jnp.int32)))["params"]
+        slots, slot_len, page = 3, 32, 8
+        npg = slot_len // page
+        cache = jax.eval_shape(lambda: init_paged_cache(
+            model, slots, 1 + slots * npg, page, npg))
+        i32 = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+            shape, jnp.int32)
+        return build(model, params, cache, i32, slots, slot_len, page, npg)
+
+    return run
+
+
+def _lower_chunk_of_a_slot(model, params, cache, i32, slots, slot_len, page,
+                           npg):
+    from tpu_air.models.lm.generate import make_lm_prefill_chunk_fn
+
+    return make_lm_prefill_chunk_fn(model, page, slot_len).lower(
+        params, cache, i32(1, page), i32(), i32(), i32(npg),
+        slot=i32()).compile()
+
+
+def _lower_mixed_of_a_slot(model, params, cache, i32, slots, slot_len, page,
+                           npg):
+    from tpu_air.models.lm.generate import make_lm_paged_mixed_step_fn
+
+    return make_lm_paged_mixed_step_fn(model, page, slot_len).lower(
+        params, cache, i32(slots), i32(slots), i32(slots, npg), i32(1, page),
+        i32(), i32(), i32(npg), slot=i32()).compile()
+
+
 # the five scopes of the residual streams, in each of the engine's three
 # programs: the maps under their sublayer's module
 _MHC = {"mhc_expand": None, "mhc_pre": "attn_hc", "mhc_sinkhorn": "mlp_hc",
@@ -389,14 +431,16 @@ def _gigachat_mixed_step():
 
 
 # the words that came after benchmark/scopes.py wrote its list down (PR 41,
-# PR 43, PR 47, PR 58: lower-case words, which its reader takes for parts of a model
+# PR 43, PR 47, PR 58, PR 60: lower-case words, which its reader takes for parts of a model
 # as they are)
 LATER_WORDS = {"ssm_conv", "ssm_scan", "ssm_state_update",
                "mla_q", "mla_latent", "mla_out", "moe_shared",
                "ssd_conv", "ssd_scan", "ssd_state_update", "ssd_gate_norm",
                "moe_latent_down", "moe_latent_up",
                "mhc_expand", "mhc_pre", "mhc_sinkhorn", "mhc_post",
-               "mhc_reduce"}
+               "mhc_reduce",
+               "window_attention", "window_append", "full_attention",
+               "attn_gate"}
 WORDS = set(scopes.VOCABULARY) | LATER_WORDS
 
 # program -> the words it must carry, and for some the module around them
@@ -430,6 +474,21 @@ PROGRAMS = {
         **_MHC, "attn_scores": "attn", "moe_experts": "moe"}),
     "xing_mixed_step": (_xing(_lower_mixed), {
         **_MHC, "decode_attention": "attn", "attn_scores": "attn"}),
+    # two kinds of attention layer in one model (PR 60): the ring's write and
+    # read, the full kind's gathered read under its own word, the gate
+    "laguna_paged_step": (_laguna(_lower_step), {
+        "window_attention": "attn", "window_append": "attn",
+        "full_attention": "attn", "kv_gather": "attn", "kv_append": "attn",
+        "decode_attention": "attn", "attn_gate": "attn",
+        "moe_experts": "moe", "moe_shared": "shared"}),
+    "laguna_prefill_chunk": (_laguna(_lower_chunk_of_a_slot), {
+        "window_attention": "attn", "window_append": "attn",
+        "full_attention": "attn", "attn_scores": "attn",
+        "attn_gate": "attn"}),
+    "laguna_mixed_step": (_laguna(_lower_mixed_of_a_slot), {
+        "window_attention": "attn", "window_append": "attn",
+        "full_attention": "attn", "decode_attention": "attn",
+        "attn_scores": "attn", "attn_gate": "attn"}),
     # a layer that is one thing (PR 47): Mamba-2's scopes under ``mamba``,
     # the latent pair around the routed experts under ``moe``
     "nemotron_paged_step": (_nemotron_paged_step, {
@@ -729,7 +788,8 @@ NEW = {
     "engine_unscoped_share": ["t5large-serve", "olmoe-serve-decode",
                               "jamba2-serve-reason",
                               "gigachat-serve-docchat",
-                              "nemotron3-serve-agent", "xing4-serve-longdoc"],
+                              "nemotron3-serve-agent", "xing4-serve-longdoc",
+                              "laguna-serve-mixedlen"],
     # PR 41: the hybrid's decode step (a flax module's name and a scope word)
     "ssm_mixer_share": ["jamba2-serve-reason"],
     "ssm_state_share": ["jamba2-serve-reason"],
@@ -739,7 +799,7 @@ NEW = {
     # (PR 58 appended its cell to the three it shares the scopes of)
     "mla_latent_share": ["gigachat-serve-docchat", "xing4-serve-longdoc"],
     "moe_shared_share": ["gigachat-serve-docchat", "nemotron3-serve-agent",
-                         "xing4-serve-longdoc"],
+                         "xing4-serve-longdoc", "laguna-serve-mixedlen"],
     # PR 45: a chunk's attention over its slot's latent pages, of the mixed
     # step (the dense form's three words; the walk's kernel is under the last)
     "mla_chunk_attention_share": ["gigachat-serve-docchat", "xing4-serve-longdoc"],
@@ -754,6 +814,9 @@ NEW = {
     # stacked append
     "gen_self_read_share": ["t5base-batchgen", "t5large-batchgen"],
     "gen_kv_append_share": ["t5base-batchgen", "t5large-batchgen"],
+    # PR 60: each kind of attention layer's share of a decode step
+    "swa_window_share": ["laguna-serve-mixedlen"],
+    "swa_full_share": ["laguna-serve-mixedlen"],
 }
 
 

@@ -2,7 +2,8 @@
 layer keeps in the engine's cache, held against the flax modules that create
 the leaves and against every reader of the tree (the programs' pushes,
 copy-on-write, page shipping, the mesh's shardings, the refusals), for the
-four families ``tools/lowered_programs.py`` builds."""
+four families ``tools/lowered_programs.py`` builds and the one whose window
+layers keep a ring a slot."""
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 import test_gigachat
 import test_jamba
+import test_laguna
 import test_olmoe
 from tpu_air.engine import (EngineConfig, InferenceEngine,
                             RecurrentStateUnsupported)
@@ -37,6 +39,8 @@ FAMILIES = {
                                                  max_seq_len=256),
     "latent": lambda: hf_import.lm_config_from_hf(
         test_gigachat.TINY, max_seq_len=256, experts_first=4, experts_held=8),
+    "laguna": lambda: hf_import.lm_config_from_hf(
+        test_laguna.TINY, max_seq_len=256),
 }
 
 
@@ -93,7 +97,7 @@ def test_the_table_names_the_leaves_the_modules_create(model):
     after, _ = jax.eval_shape(make_paged_decode_body(model, L), params, cache,
                               i32(S), i32(S), i32(S, NPG))
     assert after == want
-    slot = {"slot": i32()} if cfg.has_recurrent_layers else {}
+    slot = {"slot": i32()} if cfg.keeps_slot_rows else {}
     after, _ = jax.eval_shape(
         make_prefill_chunk_body(model, C, L), params, cache, i32(1, C), i32(),
         i32(), i32(NPG), **slot)
@@ -104,11 +108,13 @@ def test_init_paged_cache_shapes_and_dtypes_by_kind(model):
     cfg = model.config
     dtype = jnp.dtype(cfg.dtype)
     pool_width = {"attention": cfg.n_kv_heads * cfg.head_dim,
-                  "latent": cfg.latent_row_width}
+                  "latent": cfg.latent_row_width}   # a window layer: no pool
     c, n, k = cfg.mamba_d_inner, cfg.mamba_d_state, cfg.mamba_d_conv
+    ring = (S, cfg.window_ring_len(C), cfg.n_kv_heads * cfg.head_dim)
     rows = {"conv_state": ((S, (k - 1) * c), dtype),
-            "ssm_state": ((S, n, c), jnp.float32)}
-    state = 0
+            "ssm_state": ((S, n, c), jnp.float32),
+            "window_key": (ring, dtype), "window_value": (ring, dtype)}
+    state = rings = 0
     for kind, layer in _by_kind(model, _cache(model)):
         fmt = paged_cache.FORMATS[kind]
         for leaf in fmt.pools:
@@ -116,14 +122,20 @@ def test_init_paged_cache_shapes_and_dtypes_by_kind(model):
             assert layer[leaf].dtype == dtype
         for leaf in fmt.rows:
             assert (layer[leaf].shape, layer[leaf].dtype) == rows[leaf]
-            state += layer[leaf].size * layer[leaf].dtype.itemsize
+            size = layer[leaf].size * layer[leaf].dtype.itemsize
+            if kind == "window":
+                rings += size
+            else:
+                state += size
         for leaf in fmt.pushed:
             table = leaf == paged_cache.BLOCK_TABLE
             assert layer[leaf].shape == ((S, NPG) if table else (S,))
             assert layer[leaf].dtype == jnp.int32
         assert not any(np.asarray(v).any() for v in layer.values())
     assert paged_cache.recurrent_state_bytes(_cache(model)) == state
+    assert paged_cache.window_ring_bytes(_cache(model)) == rings
     assert (state > 0) == cfg.has_recurrent_layers
+    assert (state + rings > 0) == cfg.keeps_slot_rows
 
 
 def test_pushes_touch_exactly_the_pushed_leaves(model):
@@ -153,7 +165,7 @@ def test_pushes_touch_exactly_the_pushed_leaves(model):
                     assert after[leaf] is before[leaf], leaf
     # a step pushes no state_row: it is the chunk's
     assert set(want_chunk) - set(want_step) == {"state_row"}
-    if model.config.has_recurrent_layers:
+    if model.config.keeps_slot_rows:
         with pytest.raises(ValueError, match="slot="):
             paged_cache.push_chunk(cache, jnp.int32(0), jnp.int32(4), row)
     else:
@@ -251,7 +263,7 @@ ENTRY_POINTS = {"engine": _engine_migrates, "mesh_engine": _mesh_engine_builds,
 
 
 def test_what_ships_pages_alone_is_refused_for_a_model_with_slot_state(model):
-    if model.config.has_recurrent_layers:
+    if model.config.keeps_slot_rows:
         with pytest.raises(RecurrentStateUnsupported, match="M6"):
             refuse_pages_only(model, "pages alone")
     else:

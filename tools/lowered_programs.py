@@ -1,8 +1,9 @@
 """Did an edit leave the engine's compiled programs alone?  Prints the sha256
 of the lowered text (``jax.jit(body).lower(...).as_text()``, on the CPU, shapes
 only) of every ``InferenceEngine`` program (decode, chunk, mixed) of the tests'
-tiny dense, OLMoE, Jamba, latent-attention and Nemotron-H models: run it from
-the root of two trees and diff the output.  After them the T5 programs: the
+tiny dense, OLMoE, Jamba, latent-attention and Nemotron-H models (and, in a
+tree that has it, the one with window layers: PR 60): run it from the root of
+two trees and diff the output.  After them the T5 programs: the
 engine's two admits and three steps over its eight slots, and ``generate``'s
 ``while`` and ``scan``, at widths of whole tiles.
 
@@ -57,11 +58,17 @@ cfgs = {"dense": LMConfig.tiny(),
         "jamba": hf_import.lm_config_from_hf(test_jamba.TINY, max_seq_len=256),
         "gigachat": hf_import.lm_config_from_hf(test_gigachat.TINY, max_seq_len=256, experts_first=4, experts_held=8),
         "nemotron": hf_import.lm_config_from_hf(nemotron, max_seq_len=256, experts_first=2, experts_held=4)}
+try:    # a tree from before PR 60 has no such model: its lines are the others'
+    import test_laguna  # noqa: E402
+
+    cfgs["laguna"] = hf_import.lm_config_from_hf(test_laguna.TINY, max_seq_len=256)
+except ImportError:
+    pass
 for name, cfg in cfgs.items():
     model = CausalLM(cfg)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     cache = jax.eval_shape(lambda: init_paged_cache(model, S, S * npg + 1, C, npg))
-    slot = {"slot": i32()} if cfg.has_recurrent_layers else {}
+    slot = {"slot": i32()} if getattr(cfg, "keeps_slot_rows", cfg.has_recurrent_layers) else {}
     progs = {
         "decode": (make_paged_decode_body(model, L), (params, cache, i32(S), i32(S), i32(S, npg)), {}),
         "chunk": (make_prefill_chunk_body(model, C, L), (params, cache, i32(1, C), i32(), i32(), i32(npg)), slot),

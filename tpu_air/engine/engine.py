@@ -96,6 +96,22 @@ zeroes it.  Prefix sharing is off for such a model (a page hit without the
 state at that boundary is wrong), seen in the model and not set by an option,
 and whatever ships pages alone is refused (``RecurrentStateUnsupported``).
 
+WINDOW LAYERS.  A model whose window layers sit beside full ones
+(``LMConfig.layer_mixers``) keeps the window layers' K and V as a RING of
+rows a slot (models/lm/paged_cache.py: ``window_key`` / ``window_value [S, R,
+g*d]``, no pool, no table) and the full layers' as pages.  The ring is rows a
+slot like a Mamba layer's state, and the engine treats it so: the chunk
+program is told its ``slot=``, prefix sharing is off by the model, what ships
+pages alone is refused.  Nothing is zeroed for a new tenant: an entry's
+position follows from where the row IS, so what a former tenant left reads as
+older than any window.  A row that rides a step (free, mid-prefill) has no
+null page to scatter to: the step drops its write (``valid_len``).  The pool's
+bytes are the full layers' alone; ``stats()`` counts the rings beside them
+(``window_ring_bytes``, ``kv_page_bytes``, ``window_ring_bytes_as_pages``) and,
+as each step is read back, the positions x layers its cached reads had live by
+kind (``window_positions_live``, ``kv_page_positions_live``; ``_alone``: over
+the steps that carried no chunk).
+
 LATENT ATTENTION, HELD EXPERTS.  A latent-attention layer keeps ONE page
 pool (the normalised latent and the shared roped key a position:
 models/lm/modeling.LatentAttention) where an attention layer keeps K and V
@@ -138,8 +154,10 @@ from tpu_air.models.lm.generate import (
     make_lm_step_feed_fns,
     make_page_copy_fn,
 )
-from tpu_air.models.lm.paged_cache import (recurrent_state_bytes,
-                                            state_rows_move_in_place)
+from tpu_air.models.lm.paged_cache import (page_pool_bytes,
+                                            recurrent_state_bytes,
+                                            state_rows_move_in_place,
+                                            window_ring_bytes)
 
 from tpu_air.faults import plan as _faults
 from tpu_air.observability import tracing as _tracing
@@ -233,6 +251,9 @@ class InferenceEngine:
         # pjit-wrapped step fns, same host loop; it builds no mixed step,
         # and an engine without one issues every chunk alone)
         self._mixed_step = None
+        # bytes of recurrent state and of window rings the cache holds
+        # beside its pages (the builder below counts them)
+        self._state_bytes = self._ring_bytes = 0
         self._build_paged_state()
 
         # the step's inputs as they lie on the device between steps (see
@@ -269,9 +290,23 @@ class InferenceEngine:
         self._streams = int(getattr(self.model.config, "hc_mult", 1))
         if self._streams > 1:
             self.metrics.set_residual_streams(self._streams)
-        if self._recurrent:
+        if self._state_bytes:
             self.metrics.set_recurrent_state(
                 self._state_bytes,
+                prefix_cache_disabled=bool(cfg.prefix_cache))
+        if self._ring_bytes:
+            # what the window layers hold as rings, beside what the same
+            # layers would hold as pages at slot_len (every layer's K and V
+            # are equally wide: the full layers' pools say what a page costs)
+            kinds = model.config.layer_kinds()
+            self._window_layers = kinds.count("window")
+            self._full_layers = len(kinds) - self._window_layers
+            pages = page_pool_bytes(self.cache)
+            self.metrics.set_window_rings(
+                self._ring_bytes, pages,
+                as_pages=pages * self._window_layers // max(
+                    self._full_layers, 1),
+                window=int(model.config.sliding_window),
                 prefix_cache_disabled=bool(cfg.prefix_cache))
         # airscope: analytic flops/bytes per compiled program, fed into the
         # metrics ledger with each program's measured wall time.  The
@@ -332,6 +367,7 @@ class InferenceEngine:
             cfg.pages_per_slot(),
         )
         self._state_bytes = recurrent_state_bytes(self.cache)
+        self._ring_bytes = window_ring_bytes(self.cache)
         # does a step's pass move the state of the rows it advances alone
         # (asked once: the rule reads what does not change under an engine)
         self._state_in_place = state_rows_move_in_place(self.cache)
@@ -593,8 +629,8 @@ class InferenceEngine:
     def _refuse_pages_only(self, what: str) -> None:
         refuse_pages_only(
             self.model, f"{what} ships K/V pages only and this model keeps "
-            f"{self._state_bytes} bytes of recurrent state a pool beside "
-            "them")
+            f"{self._state_bytes} bytes of recurrent state and "
+            f"{self._ring_bytes} bytes of window rings a pool beside them")
 
     def migrate_out(self) -> List[Dict[str, Any]]:
         """Preemption drain: freeze the loop, settle the step in flight
@@ -983,7 +1019,7 @@ class InferenceEngine:
         ``tok`` as the prompt's first token."""
         slot = chunk.slot
         plan, req = slot.plan, slot.request
-        if self._recurrent and chunk.start == 0:
+        if self._state_bytes and chunk.start == 0:
             self.metrics.record_state_reset()
         plan.chunks_done += 1
         self._chunks_run += 1
@@ -1234,7 +1270,7 @@ class InferenceEngine:
         ahead = bool(rows) and unread is not None
         # the rows whose recurrent state the issued program's pass moves
         passed = ({"state_rows": self._state_rows_passed(len(rows))}
-                  if self._recurrent and rows else {})
+                  if self._state_bytes and rows else {})
         with phase("engine.step", live=len(reading if unread else rows),
                    batch=self.config.num_slots, ahead=int(ahead),
                    steps=int(bool(rows)), chunk=int(chunk is not None),
@@ -1332,7 +1368,7 @@ class InferenceEngine:
             self.metrics.record_stream_rows(
                 len(rows) + (chunk.tokens if chunk is not None else 0),
                 chunk=chunk is not None)
-        if self._recurrent:
+        if self._state_bytes:
             # every row not in the step rode it with its state held; the
             # others' state the step advances, over the positions they hold
             self.metrics.record_rows_held(
@@ -1406,6 +1442,17 @@ class InferenceEngine:
             self.metrics.record_latent_live(
                 sum(slot.pos + 1 for slot in reading),
                 sum(slot.pos // page + 1 for slot in reading))
+        if self._ring_bytes:
+            # what the step's cached reads had live, by kind of layer: a row
+            # at position p read p + 1 positions of each full layer's pages
+            # and the window's share of them of each ring
+            window = self.model.config.sliding_window
+            self.metrics.record_kv_live(
+                ring=self._window_layers * sum(
+                    min(slot.pos + 1, window) for slot in reading),
+                pages=self._full_layers * sum(
+                    slot.pos + 1 for slot in reading),
+                chunk=mixed)
         # one phase around the walk over the rows, none per row
         with phase("engine.emit", emitted=len(reading)):
             for slot in reading:

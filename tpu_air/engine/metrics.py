@@ -179,6 +179,22 @@ class EngineMetrics:
         self.mhc_streams = 0
         self.mhc_rows_live = 0
         self.mhc_rows_live_alone = 0
+        # window layers beside full ones (absent for a model without window
+        # layers): the bytes of the rings, of the full layers' page pools,
+        # and of what the window layers would hold as pages at slot_len; and
+        # positions x layers the steps' cached reads had LIVE as each was read
+        # back, by kind
+        # (a row at position p: ``min(p + 1, window)`` of each ring, ``p +
+        # 1`` of each full layer's pages), the same over the steps that
+        # carried no chunk
+        self.window_ring_bytes = 0
+        self.kv_page_bytes = 0
+        self.window_ring_bytes_as_pages = 0
+        self.window_len = 0
+        self.window_positions_live = 0
+        self.window_positions_live_alone = 0
+        self.kv_page_positions_live = 0
+        self.kv_page_positions_live_alone = 0
         register(self)
 
     def set_topology(self, **kw: Any) -> None:
@@ -377,6 +393,26 @@ class EngineMetrics:
             self.ssd_positions_live += positions
             self.ssd_state_rows_passed += passed
 
+    def set_window_rings(self, ring_bytes: int, page_bytes: int,
+                         as_pages: int, window: int,
+                         prefix_cache_disabled: bool) -> None:
+        with self._lock:
+            self.window_ring_bytes = int(ring_bytes)
+            self.kv_page_bytes = int(page_bytes)
+            self.window_ring_bytes_as_pages = int(as_pages)
+            self.window_len = int(window)
+            self.prefix_cache_disabled_by_model = bool(prefix_cache_disabled)
+
+    def record_kv_live(self, ring: int, pages: int, chunk: bool) -> None:
+        """One step's live cached positions x layers as it is read back, by
+        kind of layer; ``chunk``: the step carried a prefill chunk."""
+        with self._lock:
+            self.window_positions_live += int(ring)
+            self.kv_page_positions_live += int(pages)
+            if not chunk:
+                self.window_positions_live_alone += int(ring)
+                self.kv_page_positions_live_alone += int(pages)
+
     def set_residual_streams(self, streams: int) -> None:
         with self._lock:
             self.mhc_streams = int(streams)
@@ -569,6 +605,15 @@ class EngineMetrics:
                 out["ssd_state_rows_passed"] = self.ssd_state_rows_passed
                 out["prefix_cache_disabled_by_model"] = (
                     self.prefix_cache_disabled_by_model)
+            if self.window_ring_bytes:
+                for key in ("window_ring_bytes", "kv_page_bytes",
+                            "window_ring_bytes_as_pages", "window_len",
+                            "window_positions_live",
+                            "window_positions_live_alone",
+                            "kv_page_positions_live",
+                            "kv_page_positions_live_alone",
+                            "prefix_cache_disabled_by_model"):
+                    out[key] = getattr(self, key)
             if self.mhc_streams:
                 out["mhc_streams"] = self.mhc_streams
                 out["mhc_rows_live"] = self.mhc_rows_live

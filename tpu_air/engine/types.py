@@ -44,16 +44,17 @@ class EngineClosedError(TpuAirError):
 
 
 class RecurrentStateUnsupported(NotImplementedError, TpuAirError):
-    """The model keeps per-slot recurrent state (Mamba layers) and the
-    operation moves or shares K/V PAGES only: pages without the state at
-    that boundary are a wrong answer, so it is refused by name (preemption
-    migration, disaggregated prefill, the mesh engine; ROADMAP.md M6)."""
+    """The model keeps rows a slot beside its pages (Mamba layers' recurrent
+    state, window layers' rings of K and V) and the operation moves or shares
+    K/V PAGES only: pages without the rows at that boundary are a wrong
+    answer, so it is refused by name (preemption migration, disaggregated
+    prefill, the mesh engine; ROADMAP.md M6, M4)."""
 
 
 def keeps_slot_state(model) -> bool:
-    """The model keeps, beside its pages, a row of state a slot (the one
-    place the engines ask: models/lm/paged_cache.py has what it keeps)."""
-    return bool(model.config.has_recurrent_layers)
+    """The model keeps, beside its pages, rows a slot (the one place the
+    engines ask: models/lm/paged_cache.py has what it keeps)."""
+    return bool(model.config.keeps_slot_rows)
 
 
 def refuse_pages_only(model, why: str) -> None:
@@ -61,7 +62,7 @@ def refuse_pages_only(model, why: str) -> None:
     keeps per-slot state beside them; ``why`` says what was asked and why it
     cannot be given."""
     if keeps_slot_state(model):
-        raise RecurrentStateUnsupported(f"{why} (ROADMAP.md M6)")
+        raise RecurrentStateUnsupported(f"{why} (ROADMAP.md M6, M4)")
 
 
 class ExpertExchangeUnsupported(NotImplementedError, TpuAirError):

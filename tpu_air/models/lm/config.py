@@ -146,6 +146,13 @@ class LMConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    layer_mixers: Optional[Tuple[str, ...]] = None  # attention | window a layer
+    sliding_window: int = 0         # keys a window layer's position sees
+    window_n_heads: Optional[int] = None    # default n_heads
+    window_rope_theta: Optional[float] = None   # default rope_theta
+    window_rope_fraction: float = 1.0
+    rope_fraction: float = 1.0      # the share of a head that turns
+    attn_gate: str = "none"         # none | per_head
 
     def __post_init__(self):
         if self.n_kv_heads is None:
@@ -202,6 +209,45 @@ class LMConfig:
             raise ValueError(
                 f"hc_mult {self.hc_mult} streams, hc_res_clamp "
                 f"{self.hc_res_clamp}: one or more streams and a (min, max)")
+        if self.window_n_heads is None:
+            self.window_n_heads = self.n_heads
+        if self.window_rope_theta is None:
+            self.window_rope_theta = self.rope_theta
+        if self.attn_gate not in ("none", "per_head"):
+            raise ValueError(f"attn_gate {self.attn_gate!r}")
+        for name in ("rope_fraction", "window_rope_fraction"):
+            turns = getattr(self, name) * self.head_dim
+            if not 0 < turns <= self.head_dim or turns % 2:
+                raise ValueError(
+                    f"{name} {getattr(self, name)} of a head of "
+                    f"{self.head_dim} is not a whole number of pairs")
+        if self.layer_mixers is not None:
+            # a checkpoint's JSON hands the tuple back as a list
+            self.layer_mixers = tuple(self.layer_mixers)
+            if (len(self.layer_mixers) != self.n_layers
+                    or set(self.layer_mixers) - {"attention", "window"}):
+                raise ValueError(
+                    f"layer_mixers {self.layer_mixers!r} is not "
+                    f"{self.n_layers} entries of 'attention' and 'window'")
+            if self.layer_pattern is not None or self.kv_lora_rank \
+                    or self.attn_layer_period != 1:
+                raise ValueError(
+                    "layer_mixers lists attention layers of two kinds: no "
+                    "layer_pattern, latent or attn_layer_period beside it")
+            if "window" in self.layer_mixers \
+                    and self.sequence_axis is not None:
+                raise ValueError(
+                    "a window layer is dense; sequence_axis="
+                    f"{self.sequence_axis!r} asks for the ring")
+            if "window" in self.layer_mixers and (
+                    self.sliding_window < 1 or self.rope_theta is None
+                    or self.window_n_heads % self.n_kv_heads):
+                raise ValueError(
+                    f"a window layer wants sliding_window "
+                    f"({self.sliding_window}) keys, a rope (rope_theta "
+                    f"{self.rope_theta}: a ring keeps no order of its own) "
+                    f"and window_n_heads ({self.window_n_heads}) a multiple "
+                    f"of n_kv_heads ({self.n_kv_heads})")
         if self.layer_pattern is not None:
             p = self.layer_pattern
             if len(p) != self.n_layers or set(p) - set("M*E"):
@@ -231,7 +277,10 @@ class LMConfig:
         """The sequence mixer of each layer, in order: ``"attention"``
         (``"latent"`` where the configuration has a latent) or ``"mamba"``;
         under a ``layer_pattern`` ``"mamba2"``, ``"attention"`` or, for a
-        layer that is experts alone, ``"none"``."""
+        layer that is experts alone, ``"none"``; under ``layer_mixers`` that
+        list (``"attention"`` or ``"window"`` a layer)."""
+        if self.layer_mixers is not None:
+            return list(self.layer_mixers)
         attention = "latent" if self.kv_lora_rank else "attention"
         if self.layer_pattern is not None:
             return [{"M": "mamba2", "*": attention, "E": "none"}[c]
@@ -267,6 +316,29 @@ class LMConfig:
         PERF.md, PR 43)."""
         return -(-self.latent_width // 128) * 128
 
+    def heads_of(self, kind: str) -> int:
+        """Query heads of an attention layer of ``kind``."""
+        return self.window_n_heads if kind == "window" else self.n_heads
+
+    def rope_of(self, kind: str) -> Tuple[Optional[float], int]:
+        """``(theta, turned)`` of an attention layer of ``kind``: the rope's
+        base (None: no position encoding) and how many leading numbers of a
+        head it turns."""
+        if kind == "window":
+            return (self.window_rope_theta,
+                    int(self.window_rope_fraction * self.head_dim))
+        return self.rope_theta, int(self.rope_fraction * self.head_dim)
+
+    def window_ring_len(self, chunk_len: int) -> int:
+        """Positions a window layer keeps a slot where prefill chunks are
+        ``chunk_len`` long: whole chunks (a chunk lands in one piece), enough
+        that a chunk written BEFORE it is read leaves its first query the
+        ``sliding_window - 1`` positions behind it: the least multiple of
+        ``chunk_len`` that holds ``sliding_window + chunk_len - 1`` (768 for
+        a window of 512 under chunks of 256)."""
+        return -(-(self.sliding_window + chunk_len - 1) // chunk_len
+                 ) * chunk_len
+
     @property
     def holds_all_experts(self) -> bool:
         return self.experts_held == self.num_experts
@@ -275,6 +347,13 @@ class LMConfig:
     def has_recurrent_layers(self) -> bool:
         """Some layer keeps per-sequence state that is not K/V pages."""
         return bool({"mamba", "mamba2"} & set(self.layer_kinds()))
+
+    @property
+    def keeps_slot_rows(self) -> bool:
+        """Some layer keeps ROWS a slot beside the pages (recurrent state, a
+        window layer's ring): what no block table reaches, so whatever
+        shares or ships pages alone does not carry it."""
+        return self.has_recurrent_layers or "window" in self.layer_kinds()
 
     @property
     def mamba_d_inner(self) -> int:

@@ -460,10 +460,10 @@ def make_paged_mixed_logits_body(model: CausalLM, page_len: int,
 
     def logits_mixed(params, cache, tok, pos, block_table, ids, p0,
                      last_local, table_row, slot=None):
-        if cfg.has_recurrent_layers and slot is None:
+        if cfg.keeps_slot_rows and slot is None:
             raise ValueError(
-                "a model with recurrent layers keeps state a slot: the "
-                "chunk needs slot=")
+                "a model with recurrent or window layers keeps rows a slot: "
+                "the chunk needs slot=")
         s = tok.shape[0]
         pos, p0 = pos.astype(jnp.int32), p0.astype(jnp.int32)
         last = last_local.astype(jnp.int32)

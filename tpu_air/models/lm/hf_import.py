@@ -1,5 +1,5 @@
-"""Published (Hugging Face) OLMoE, Jamba, DeepSeek-V3, Xing4.0 and Nemotron-H
-configurations and weights -> ``LMConfig`` and this framework's ``CausalLM``
+"""Published (Hugging Face) OLMoE, Jamba, DeepSeek-V3, Xing4.0, Nemotron-H and
+Laguna configurations and weights -> ``LMConfig`` and this framework's ``CausalLM``
 parameter tree.
 
 Beside T5's importer (models/t5/hf_import.py).  Pure numpy: the converter
@@ -39,6 +39,16 @@ attention has no position encoding, the layer pattern goes in as published
 (``hybrid_override_pattern``), and the Mamba-2 mixer's ``in_proj`` keeps its
 published column order ``[z | x B C | dt]``.  The tree may hold a share, as
 above.
+
+``model_type: laguna`` (window layers beside full ones): ``layer_types``
+goes in as ``LMConfig.layer_mixers``, the per-layer lists of heads and of
+feed-forward kinds as the by-kind numbers they are a function of (refused
+where they are not), ``rope_parameters`` a kind.  Its rotary embedding is
+rotate-half over the LEADING share of a head that turns, so the q and k
+columns are reordered as OLMoE's are, inside that share alone
+(``rope_columns(.., turned)``).  The catalog gives no tensor names:
+:data:`LAGUNA_NAMES` is what is assumed, and a caller that knows better hands
+``names=``.
 """
 
 from __future__ import annotations
@@ -208,6 +218,58 @@ NEMOTRON_H_FIXED = {
 }
 
 
+#: ``model_type: laguna`` (Laguna-XS.2): the keys mapped, and the values that
+#: must hold (no bias, top-k weights renormalised, no soft cap on the router's
+#: logits, every layer past the dense ones sparse, a gate a head)
+LAGUNA_KEYS = {
+    "hidden_size": "d_model",
+    "num_hidden_layers": "n_layers",
+    "num_attention_heads": "n_heads",        # a full layer's
+    "num_key_value_heads": "n_kv_heads",
+    "head_dim": "head_dim",
+    "intermediate_size": "dense_d_ff",
+    "moe_intermediate_size": "d_ff",         # the width of ONE expert
+    "shared_expert_intermediate_size": "shared_d_ff",
+    "num_experts": "num_experts",
+    "num_experts_per_tok": "num_experts_per_tok",
+    "vocab_size": "vocab_size",
+    "rms_norm_eps": "rmsnorm_eps",
+    "tie_word_embeddings": "tie_embeddings",
+    "sliding_window": "sliding_window",
+    "moe_routed_scaling_factor": "router_scale",
+}
+LAGUNA_FIXED = {
+    "attention_bias": False,
+    "norm_topk_prob": True,
+    "moe_router_logit_softcapping": 0,
+    "moe_apply_router_weight_on_input": False,
+    "decoder_sparse_step": 1,
+}
+#: the kinds of layer as published -> ``LMConfig.layer_kinds()``'s
+LAGUNA_LAYER_TYPES = {"full_attention": "attention",
+                      "sliding_attention": "window"}
+#: tensor names ASSUMED for ``laguna`` (``qwen2_moe``'s, whose key names the
+#: config carries; the gate as ``g_proj``, the selection bias under
+#: ``deepseek_v3``'s name)
+LAGUNA_NAMES = {
+    "embed": "model.embed_tokens.weight",
+    "head": "lm_head.weight",
+    "final_norm": "model.norm.weight",
+    "attn_norm": "model.layers.{i}.input_layernorm.weight",
+    "mlp_norm": "model.layers.{i}.post_attention_layernorm.weight",
+    "q": "model.layers.{i}.self_attn.q_proj.weight",
+    "k": "model.layers.{i}.self_attn.k_proj.weight",
+    "v": "model.layers.{i}.self_attn.v_proj.weight",
+    "o": "model.layers.{i}.self_attn.o_proj.weight",
+    "g": "model.layers.{i}.self_attn.g_proj.weight",
+    "dense": "model.layers.{i}.mlp.{m}_proj.weight",
+    "router": "model.layers.{i}.mlp.gate.weight",
+    "router_bias": "model.layers.{i}.mlp.gate.e_score_correction_bias",
+    "expert": "model.layers.{i}.mlp.experts.{e}.{m}_proj.weight",
+    "shared": "model.layers.{i}.mlp.shared_expert.{m}_proj.weight",
+}
+
+
 def _check_fixed(hf: Dict[str, Any], fixed: Dict[str, Any]) -> None:
     for key, want in fixed.items():
         if hf.get(key, want) != want:
@@ -281,6 +343,78 @@ def _nemotron_h_config_from_hf(hf: Dict[str, Any], dtype: str,
     return LMConfig(**fields)
 
 
+def _laguna_config_from_hf(hf: Dict[str, Any], dtype: str,
+                           **overrides: Any) -> LMConfig:
+    _check_fixed(hf, LAGUNA_FIXED)
+    if hf.get("gating") not in (True, "per-head"):
+        raise ValueError(f"published gating={hf.get('gating')!r}: only a "
+                         "gate a head is implemented (models/lm/modeling.py)")
+    fields = {ours: hf[theirs] for theirs, ours in LAGUNA_KEYS.items()}
+    n = fields["n_layers"]
+    # a tree of fewer layers than the lists publish holds their first ones
+    lists = {key: list(hf[key])[:n] for key in (
+        "layer_types", "mlp_layer_types", "num_attention_heads_per_layer")}
+    for key, got in lists.items():
+        if len(got) != n:
+            raise ValueError(f"published {key} has {len(got)} entries for "
+                             f"{n} layers")
+    mixers = [LAGUNA_LAYER_TYPES.get(t) for t in lists["layer_types"]]
+    if None in mixers:
+        raise ValueError(f"published layer_types {lists['layer_types']}: "
+                         f"only {sorted(LAGUNA_LAYER_TYPES)} are implemented")
+    heads = {m: {h for m2, h in zip(
+        mixers, lists["num_attention_heads_per_layer"]) if m2 == m}
+        for m in set(mixers)}
+    if any(len(v) != 1 for v in heads.values()) or heads.get(
+            "attention", {fields["n_heads"]}) != {fields["n_heads"]}:
+        raise ValueError(
+            "published num_attention_heads_per_layer "
+            f"{lists['num_attention_heads_per_layer']} is not one count a "
+            "kind of layer with num_attention_heads on the full kind")
+    dense = sum(t == "dense" for t in lists["mlp_layer_types"])
+    if lists["mlp_layer_types"] != ["dense"] * dense + ["sparse"] * (
+            n - dense):
+        raise ValueError(
+            f"published mlp_layer_types {lists['mlp_layer_types']}: only "
+            "leading dense layers are implemented")
+    full = hf["rope_parameters"]["full_attention"]
+    slide = hf["rope_parameters"].get("sliding_attention", full)
+    if slide.get("rope_type", "default") != "default":
+        raise ValueError("a sliding layer's rope is plain: rope_type "
+                         f"{slide.get('rope_type')!r}")
+    fields.update(
+        layer_mixers=tuple(mixers),
+        window_n_heads=next(iter(heads.get("window", {fields["n_heads"]}))),
+        first_dense_layers=dense, num_shared_experts=1,
+        router="sigmoid_groups", attn_gate="per_head",
+        rope_theta=full["rope_theta"],
+        rope_fraction=full.get("partial_rotary_factor", 1.0),
+        window_rope_theta=slide["rope_theta"],
+        window_rope_fraction=slide.get("partial_rotary_factor", 1.0),
+        max_seq_len=hf.get("max_position_embeddings", 2048),
+        pad_token_id=hf.get("pad_token_id") or 0,
+        eos_token_id=hf.get("eos_token_id"),
+        dtype=dtype)
+    kind = full.get("rope_type", "default")
+    if kind == "yarn":
+        import math
+
+        fields.update({ours: full[theirs]
+                       for theirs, ours in YARN_KEYS.items()
+                       if theirs in full})
+        # cos and sin times attention_factor, which LMConfig holds as the
+        # mscale yarn's own formula makes it of: 0.1 mscale ln(factor) + 1
+        factor = full.get("attention_factor")
+        if factor is not None:
+            fields["rope_mscale"] = (factor - 1.0) / (
+                0.1 * math.log(full["factor"]))
+    elif kind != "default":
+        raise ValueError(f"rope_type {kind!r}: only yarn is implemented "
+                         "(models/lm/modeling.py)")
+    fields.update(overrides)
+    return LMConfig(**fields)
+
+
 def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
                       **overrides: Any) -> LMConfig:
     """``LMConfig`` of a published ``olmoe``, ``jamba``, ``deepseek_v3``,
@@ -297,6 +431,8 @@ def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
         return _deepseek_config_from_hf(hf, dtype, **overrides)
     if hf.get("model_type") == "nemotron_h":
         return _nemotron_h_config_from_hf(hf, dtype, **overrides)
+    if hf.get("model_type") == "laguna":
+        return _laguna_config_from_hf(hf, dtype, **overrides)
     _check_fixed(hf, HF_FIXED)
     kv = hf.get("num_key_value_heads", hf["num_attention_heads"])
     if kv != hf["num_attention_heads"]:
@@ -314,13 +450,17 @@ def lm_config_from_hf(hf: Dict[str, Any], dtype: str = "float32",
     return LMConfig(**fields)
 
 
-def rope_columns(n_heads: int, head_dim: int) -> np.ndarray:
+def rope_columns(n_heads: int, head_dim: int,
+                 turned: int = None) -> np.ndarray:
     """``perm`` with ``program[..., j] = published[..., perm[j]]`` over the
-    ``n_heads * head_dim`` outputs of a q or k projection."""
-    half = head_dim // 2
-    inside = np.empty(head_dim, np.int64)
-    inside[0::2] = np.arange(half)
-    inside[1::2] = np.arange(half) + half
+    ``n_heads * head_dim`` outputs of a q or k projection.  ``turned``: how
+    many leading numbers of a head the rope turns (default all): the
+    reordering is inside those, the rest stay."""
+    turned = head_dim if turned is None else turned
+    half = turned // 2
+    inside = np.arange(head_dim, dtype=np.int64)
+    inside[0:turned:2] = np.arange(half)
+    inside[1:turned:2] = np.arange(half) + half
     return (np.arange(n_heads)[:, None] * head_dim + inside[None]).reshape(-1)
 
 
@@ -579,4 +719,58 @@ def convert_nemotron_h_state_dict(get: Callable[[str], Any],
             f"configuration of {config.vocab_size}")
     for i in range(config.n_layers):
         params[f"layer_{i}"] = convert_nemotron_h_layer(get, i, config)
+    return params
+
+
+def convert_laguna_layer(get: Callable[[str], Any], i: int, config: LMConfig,
+                         names: Dict[str, str] = LAGUNA_NAMES
+                         ) -> Dict[str, Any]:
+    """Layer ``i`` of the tree from a ``laguna`` state dict under ``names``:
+    the layer's kind says how many query heads ``q``, ``o`` and the gate
+    have and how much of a head the rope turns."""
+    at = lambda key, **kw: get(names[key].format(i=i, **kw))  # noqa: E731
+    kind = config.layer_kinds()[i]
+    turned = config.rope_of(kind)[1]
+    d = config.head_dim
+    layer = {
+        "attn_norm": {"weight": np.asarray(at("attn_norm"))},
+        "mlp_norm": {"weight": np.asarray(at("mlp_norm"))},
+        "attn": {
+            "q": {"kernel": _t(at("q"))[:, rope_columns(
+                config.heads_of(kind), d, turned)]},
+            "k": {"kernel": _t(at("k"))[:, rope_columns(
+                config.n_kv_heads, d, turned)]},
+            "v": {"kernel": _t(at("v"))},
+            "o": {"kernel": _t(at("o"))},
+            "gate": {"kernel": _t(at("g"))},
+        },
+    }
+    three = lambda key, **kw: {  # noqa: E731
+        m: {"kernel": _t(at(key, m=m, **kw))} for m in ("gate", "up", "down")}
+    if config.ff_kinds()[i] == "dense":
+        layer["mlp"] = three("dense")
+        return layer
+    stack = lambda m: np.stack([  # noqa: E731
+        _t(at("expert", e=e, m=m)) for e in range(config.num_experts)])
+    layer["moe"] = {
+        "router": _t(at("router")),
+        "router_bias": np.asarray(at("router_bias")),
+        "gate": stack("gate"), "up": stack("up"), "down": stack("down")}
+    layer["shared"] = three("shared")
+    return layer
+
+
+def convert_laguna_state_dict(get: Callable[[str], Any], config: LMConfig,
+                              names: Dict[str, str] = LAGUNA_NAMES
+                              ) -> Dict[str, Any]:
+    """The ``CausalLM`` parameter tree from a ``laguna`` state dict, given as
+    ``get(name)``: the first ``config.n_layers`` layers, every expert."""
+    params: Dict[str, Any] = {
+        "embedding": np.asarray(get(names["embed"])),
+        "final_norm": {"weight": np.asarray(get(names["final_norm"]))},
+    }
+    if not config.tie_embeddings:
+        params["lm_head"] = {"kernel": _t(get(names["head"]))}
+    for i in range(config.n_layers):
+        params[f"layer_{i}"] = convert_laguna_layer(get, i, config, names)
     return params

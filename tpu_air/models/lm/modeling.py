@@ -17,6 +17,8 @@ from any reference code).
 
 from __future__ import annotations
 
+import contextlib
+import functools
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -119,9 +121,13 @@ def _repeat_kv(x: Array, n_heads: int) -> Array:
     return x if g == n_heads else jnp.repeat(x, n_heads // g, axis=1)
 
 
-def _dense_causal_attention(q, k, v, scale, q_offset=0):
+def _dense_causal_attention(q, k, v, scale, q_offset=0, window=0,
+                            key_pos=None):
     """(B,H,L,D) einsum attention with causal mask; baseline path.  k, v may
-    carry fewer heads than q (grouped K/V)."""
+    carry fewer heads than q (grouped K/V).  ``window`` > 0: a query sees the
+    ``window`` positions up to its own, itself counted.  ``key_pos [Lk]``:
+    the position each key holds where the keys do not lie in order (a window
+    layer's ring; negative: nothing written there yet)."""
     k, v = _repeat_kv(k, q.shape[1]), _repeat_kv(v, q.shape[1])
     with jax.named_scope("attn_scores"):
         s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
@@ -130,7 +136,13 @@ def _dense_causal_attention(q, k, v, scale, q_offset=0):
         lq, lk = q.shape[2], k.shape[2]
         qi = q_offset + jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 0)
         kj = jax.lax.broadcasted_iota(jnp.int32, (lq, lk), 1)
-        s = jnp.where(qi >= kj, s, NEG_INF)
+        seen = qi >= kj
+        if key_pos is not None:
+            kj = jnp.broadcast_to(key_pos.astype(jnp.int32)[None], (lq, lk))
+            seen = (qi >= kj) & (kj >= 0)
+        if window:
+            seen = seen & (qi - kj < window)
+        s = jnp.where(seen, s, NEG_INF)
     with jax.named_scope("attn_softmax"):
         p = jax.nn.softmax(s, axis=-1)
     with jax.named_scope("attn_context"):
@@ -291,8 +303,130 @@ def _paged_attend_chunk(q, ck, cv, table, p0, scale, g):
     return o.transpose(0, 2, 1, 3).reshape(1, q.shape[2], -1)
 
 
+# CausalSelfAttention's writes to and reads of a WINDOW layer's ring over the
+# engine's cache: ``window_key`` / ``window_value [S, R, g*d]``, position p of
+# slot s at ``[s, p % R]`` (models/lm/paged_cache.py; R is
+# ``LMConfig.window_ring_len``: whole chunks, and a chunk written before it
+# is read still finds the ``window - 1`` positions behind its first query).
+
+def _ring_held(at, R):
+    """The position each of a ring's ``R`` entries holds once position ``at``
+    is written (``at`` of any shape; one more axis of ``R`` behind it): the
+    largest ``p <= at`` with ``p % R`` the entry's index; negative where
+    nothing was written yet (what a former tenant left there is older than
+    any window)."""
+    at = at[..., None]
+    return at - (at - jnp.arange(R)) % R
+
+
+def _ring_append_rows(rings, i, live, new):
+    """Row ``s``'s new ``k``/``v`` ``[S, 1, g*d]`` to ``[s, i % R]``, for the
+    rows ``live [S]`` marks alone: a row that rides along has no null page
+    to scatter to, so its write is dropped."""
+    S, R = rings[0].shape[:2]
+    at = jnp.where(live > 0, i % R, R)          # R: out of range, dropped
+    with jax.named_scope("window_append"):
+        return tuple(r.at[jnp.arange(S), at].set(
+            x[:, 0].astype(r.dtype), mode="drop") for r, x in zip(rings, new))
+
+
+def _ring_attend_rows(q, rk, rv, i, window, scale, h, g, dtype):
+    """``q [S, h, 1, d]``, each row over the ring of its slot AS IT LIES,
+    under a mask of what is written and inside the window at its position
+    ``i [S]`` -> ``[S, 1, h*d]``: the flat read, no gather."""
+    held = _ring_held(i, rk.shape[1])
+    live = (held >= 0) & (held > i[:, None] - window)
+    with jax.named_scope("window_attention"):
+        o4 = flat_decode_attention(q.transpose(0, 2, 1, 3) * scale, rk, rv,
+                                   live, h, dtype, g)
+    return o4.reshape(q.shape[0], 1, -1)
+
+
+def _ring_append_chunk(rings, slot, p0, new):
+    """One slot's chunk ``k``/``v`` ``[1, C, g*d]`` at positions ``p0 ..``
+    (chunk-aligned, and ``R`` is whole chunks: one piece)."""
+    R = rings[0].shape[1]
+    with jax.named_scope("window_append"):
+        return tuple(jax.lax.dynamic_update_slice(
+            r, x.astype(r.dtype), (slot, p0 % R, 0))
+            for r, x in zip(rings, new))
+
+
+def _ring_attend_chunk(q, rk, rv, slot, p0, window, scale, g):
+    """``q [1, h, C, d]`` at positions ``p0 ..`` over the slot's ring, the
+    chunk written into it: dense under the window mask -> ``[1, C, h*d]``."""
+    R, C, d = rk.shape[1], q.shape[2], q.shape[-1]
+    k4, v4 = (jax.lax.dynamic_slice_in_dim(r, slot, 1, axis=0).reshape(
+        1, R, g, d).transpose(0, 2, 1, 3) for r in (rk, rv))
+    with jax.named_scope("window_attention"):
+        o = _dense_causal_attention(
+            q, k4, v4, scale, q_offset=p0, window=window,
+            key_pos=_ring_held(p0 + C - 1, R))
+    return o.transpose(0, 2, 1, 3).reshape(1, C, -1)
+
+
+def _ring_attention(mod: nn.Module, q, new, rings, idx, chunk, window, scale,
+                    h, g, dtype):
+    """The cached call of a WINDOW layer over the engine's cache, for
+    :func:`_cached_attention`'s three paged callers (a decode step, a
+    prefill chunk of slot ``state_row[0]``, a mixed step): writes, then
+    reads, as there.  ``valid_len [S]`` says which rows of a step decode."""
+    b, l = new[0].shape[:2]
+    i, live = idx.value, mod.get_variable("cache", VALID_LEN)
+    held = tuple(r.value for r in rings)
+    S, R = held[0].shape[:2]
+
+    def keep(held):
+        for r, v in zip(rings, held):
+            r.value = v
+        idx.value = i + l
+
+    if chunk is not None:
+        C = b - S
+        if l != 1 or C < 1 or R % C:
+            raise ValueError(
+                f"mixed step wants {S} rows of one token and a chunk the "
+                f"ring of {R} is whole numbers of; got b={b}, l={l}")
+        held = _ring_append_rows(held, i, live, tuple(x[:S] for x in new))
+        held = _ring_append_chunk(held, chunk.slot, chunk.start,
+                                  tuple(x[S:, 0][None] for x in new))
+        keep(held)
+        return jnp.concatenate([
+            _ring_attend_rows(q[:S], *held, i, window, scale, h, g, dtype),
+            _ring_attend_chunk(q[S:].transpose(2, 1, 0, 3), *held,
+                               chunk.slot, chunk.start, window, scale, g
+                               ).reshape(C, 1, -1)])
+    if l == 1:
+        held = _ring_append_rows(held, i, live, new)
+        keep(held)
+        return _ring_attend_rows(q, *held, i, window, scale, h, g, dtype)
+    if b != 1 or R % l:
+        raise ValueError(
+            f"a chunk over a window layer's ring of {R} wants b=1 and a "
+            f"length the ring is whole numbers of; got b={b}, l={l}")
+    slot, p0 = mod.get_variable("cache", STATE_ROW)[0], i[0]
+    held = _ring_append_chunk(held, slot, p0, new)
+    keep(held)
+    return _ring_attend_chunk(q, *held, slot, p0, window, scale, g)
+
+
 class CausalSelfAttention(nn.Module):
+    """Attention over K and V as projected (no latent).  ``kind`` is the
+    layer's (``LMConfig.layer_kinds()``): ``"attention"`` sees every
+    position up to its own, ``"window"`` the last ``config.sliding_window``
+    of them; heads and rope follow the kind (``LMConfig.heads_of``,
+    ``rope_of``).  Over a cache the full kind keeps slabs or pages
+    (:func:`_cached_attention`); the window kind keeps slabs under
+    ``generate`` (the same reads under the window's mask) and a ring a slot
+    under the engine (:func:`_ring_attention`).  Scopes
+    (docs/OBSERVABILITY.md), in a model that has both kinds:
+    ``full_attention`` around a full layer's cached read and
+    ``window_attention`` around a window layer's, ``window_append`` the
+    ring's write (a full layer's is ``kv_append``), ``attn_gate`` the output
+    gate."""
+
     config: LMConfig
+    kind: str = "attention"
 
     @nn.compact
     def __call__(self, x: Array, positions: Array, decode: bool = False,
@@ -300,7 +434,9 @@ class CausalSelfAttention(nn.Module):
         cfg = self.config
         dtype = jnp.dtype(cfg.dtype)
         b, l, _ = x.shape
-        h, g, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        h, g, d = cfg.heads_of(self.kind), cfg.n_kv_heads, cfg.head_dim
+        window = cfg.sliding_window if self.kind == "window" else 0
+        theta, turned = cfg.rope_of(self.kind)
 
         def proj(name, out):
             return nn.Dense(out, use_bias=False, dtype=dtype,
@@ -314,11 +450,38 @@ class CausalSelfAttention(nn.Module):
         q = q.reshape(b, l, h, d).transpose(0, 2, 1, 3)
         k = k.reshape(b, l, g, d).transpose(0, 2, 1, 3)
         v = proj("v", g * d)(x).reshape(b, l, g, d).transpose(0, 2, 1, 3)
-        if cfg.rope_theta is not None:
+        if theta is not None:
             # None: the family has no position encoding (LMConfig)
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+            inv_freq, wave = None, 1.0
+            if cfg.rope_factor > 1 and not window:
+                # yarn, on the full kind alone: over the numbers that turn
+                inv_freq = yarn_inv_freq(
+                    turned, theta, cfg.rope_factor, cfg.rope_original_len,
+                    cfg.rope_beta_fast, cfg.rope_beta_slow)
+                wave = yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+
+            def turn(v):
+                if turned == d and wave == 1.0:
+                    return rope(v, positions, theta, inv_freq)
+                # the leading ``turned`` numbers of a head turn (cos and sin
+                # times yarn's factor), the rest pass
+                t = rope(v[..., :turned].astype(jnp.float32), positions,
+                         theta, inv_freq) * wave
+                return jnp.concatenate([t.astype(v.dtype), v[..., turned:]],
+                                       -1)
+
+            q, k = turn(q), turn(k)
         scale = 1.0 / (d ** 0.5)
+
+        def out(o):
+            """``o [b, l, h*d]`` through the gate, then ``W_o``."""
+            if cfg.attn_gate == "per_head":
+                with jax.named_scope("attn_gate"):
+                    gate = jax.nn.sigmoid(
+                        proj("gate", h)(x).astype(jnp.float32))
+                    o = (o.reshape(o.shape[:2] + (h, d)).astype(jnp.float32)
+                         * gate[..., None]).astype(dtype).reshape(o.shape)
+            return proj("o", cfg.d_model)(o)
 
         if decode:
             # KV-cache path (autoregressive generate, SURVEY.md §7
@@ -331,16 +494,21 @@ class CausalSelfAttention(nn.Module):
             # (ops/decode_attention.py) that streams the slab once in
             # storage layout, over the plain slab or the gathered pages.
             max_len = cfg.max_seq_len
-            ck = self.variable(
-                "cache", "cached_key",
-                lambda: jnp.zeros((b, max_len, g * d), dtype))
-            cv = self.variable(
-                "cache", "cached_value",
-                lambda: jnp.zeros((b, max_len, g * d), dtype))
+            names = (("window_key", "window_value") if window
+                     else ("cached_key", "cached_value"))
+            ck, cv = (self.variable(
+                "cache", name, lambda: jnp.zeros((b, max_len, g * d), dtype))
+                for name in names)
             idx = self.variable(
                 "cache", CACHE_INDEX, lambda: jnp.array(0, jnp.int32))
             kflat = k.transpose(0, 2, 1, 3).reshape(b, l, g * d)
             vflat = v.transpose(0, 2, 1, 3).reshape(b, l, g * d)
+            if window and self.has_variable("cache", STATE_ROW):
+                # the engine's cache: a ring a slot (what the host pushes in
+                # tells it from the plain one, as for a Mamba layer's rows)
+                return out(_ring_attention(
+                    self, q, (kflat, vflat), (ck, cv), idx, chunk, window,
+                    scale, h, g, dtype))
             if chunk is not None:
                 # the chunk's reads take the slot's row as a table of one
                 chunk = chunk._replace(table_row=chunk.table_row[None])
@@ -355,13 +523,15 @@ class CausalSelfAttention(nn.Module):
                 (q,) = q
                 if ahead is not None:   # C rows of one token: one row of C
                     q = q[ahead:].transpose(2, 1, 0, 3)
-                o = _paged_attend_chunk(q, *pools, table, start, scale, g)
+                with full():
+                    o = _paged_attend_chunk(q, *pools, table, start, scale, g)
                 return o if ahead is None else o.reshape(-1, 1, h * d)
 
             def attend_token(q, pools, i):
                 # future cache slots are zeros; the kv_mask hides them
-                kvm = jnp.broadcast_to(
-                    (jnp.arange(max_len) <= i)[None], (b, max_len))
+                at = jnp.arange(max_len)
+                seen = (at <= i) & (at > i - window) if window else at <= i
+                kvm = jnp.broadcast_to(seen[None], (b, max_len))
                 o4 = flat_decode_attention(
                     q[0].transpose(0, 2, 1, 3) * scale, *pools, kvm, h,
                     dtype, g)
@@ -373,25 +543,40 @@ class CausalSelfAttention(nn.Module):
                 # slots are zeros but kj > qi masks them out.
                 ck4, cv4 = (p.reshape(b, max_len, g, d).transpose(0, 2, 1, 3)
                             for p in pools)
-                o = _dense_causal_attention(q[0], ck4, cv4, scale, q_offset=i)
+                o = _dense_causal_attention(q[0], ck4, cv4, scale, q_offset=i,
+                                            window=window)
                 return o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
 
+            def full():
+                # a model of both kinds says which one a cached read is
+                return (jax.named_scope("full_attention")
+                        if cfg.layer_mixers is not None
+                        else contextlib.nullcontext())
+
+            def attend_rows(q, pools, table, i):
+                with full():
+                    return _paged_attend_rows(q[0], *pools, table, i, scale,
+                                              h, g, dtype)
+
             o = _cached_attention(self, CacheKind(
-                append_rows=_paged_append_rows,
-                attend_rows=lambda q, pools, table, i: _paged_attend_rows(
-                    q[0], *pools, table, i, scale, h, g, dtype),
+                append_rows=_paged_append_rows, attend_rows=attend_rows,
                 append_chunk=_paged_append_chunk,
                 attend_chunk=attend_chunk, append_plain=append_plain,
                 attend_token=attend_token, attend_prompt=attend_prompt,
             ), (q,), (kflat, vflat), (ck, cv), idx, chunk)
-            return proj("o", cfg.d_model)(o)
+            return out(o)
 
         k, v = _repeat_kv(k, h), _repeat_kv(v, h)  # the kernels want h heads
         # imported here: a test replaces auto_dispatch_ok on its module
         from tpu_air.ops.flash_attention import (auto_dispatch_ok,
                                                  flash_attention)
 
-        if cfg.sequence_axis is not None:
+        if window:
+            # neither kernel below takes a window: a window layer's
+            # full-sequence pass is the einsum under the window's mask (the
+            # configuration refuses sequence_axis beside window layers)
+            o = _dense_causal_attention(q, k, v, scale, window=window)
+        elif cfg.sequence_axis is not None:
             from tpu_air.ops.ring_attention import ring_attention
 
             # fold heads into batch: ring expects (B·H, L_local, D)
@@ -413,8 +598,7 @@ class CausalSelfAttention(nn.Module):
         else:
             o = _dense_causal_attention(q, k, v, scale)
 
-        o = o.transpose(0, 2, 1, 3).reshape(b, l, h * d)
-        return proj("o", cfg.d_model)(o)
+        return out(o.transpose(0, 2, 1, 3).reshape(b, l, h * d))
 
 
 class LatentAttention(nn.Module):
@@ -1224,7 +1408,8 @@ class Block(nn.Module):
                 decode=decode, chunk=chunk))
         elif self.kind != "none":
             mixer = (LatentAttention if self.kind == "latent"
-                     else CausalSelfAttention)
+                     else functools.partial(CausalSelfAttention,
+                                            kind=self.kind))
             x = residual(x, "attn", lambda h: mixer(cfg, name="attn")(
                 RMSNorm(cfg.rmsnorm_eps, dtype, name="attn_norm")(h),
                 positions, decode=decode, chunk=chunk,

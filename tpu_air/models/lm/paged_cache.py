@@ -14,7 +14,13 @@ there for a sequence follows from its kind (``LMConfig.layer_kinds()``), and
   1) * d_inner]`` and float32 ``ssm_state [S, d_state, d_inner]``, which no
   table reaches and the slot's index does; a Mamba-2 layer's are the same two
   leaves at its own shapes (``[S, (d_conv - 1) * conv_dim]`` over x, B and C
-  together, ``[S, heads, head_dim, d_state]`` as published).
+  together, ``[S, heads, head_dim, d_state]`` as published).  A WINDOW
+  layer's K and V are rows too: ``window_key`` / ``window_value [S, R, g*d]``,
+  a RING of ``R = LMConfig.window_ring_len(page_len)`` positions a slot
+  (position ``p`` at ``p % R``, roped before the write, so the order within
+  the ring does not matter to the read); no pool, no table, and nothing of it
+  is ``slot_len`` long (``modeling.CausalSelfAttention`` has its writes and
+  reads).
 * **pushed leaves**, host state (engine/kvpool/) written into the tree before
   every program so the donated cache never round-trips: ``cache_index [S]``
   (where each row's call starts), ``block_table [S, pages_per_slot]``,
@@ -67,11 +73,16 @@ FORMATS: Dict[str, LayerFormat] = {
     # dict reads as "mamba" in layer_kind, whose format is this one
     "mamba2": LayerFormat({}, ("conv_state", "ssm_state"),
                           (CACHE_INDEX, STATE_ROW, VALID_LEN)),
+    # a window layer's ring: ``valid_len`` says which rows of a step write
+    # (a row that rides along has no null page to scatter to)
+    "window": LayerFormat({}, ("window_key", "window_value"),
+                          (CACHE_INDEX, STATE_ROW, VALID_LEN)),
 }
 
 _POOLS = {leaf: short for fmt in FORMATS.values()
           for leaf, short in fmt.pools.items()}
 _ROWS = {leaf for fmt in FORMATS.values() for leaf in fmt.rows}
+_RING = set(FORMATS["window"].rows)
 
 #: the mesh axes of every leaf over a ``(data, model)`` mesh, a name a
 #: dimension (engine/dist/sharded.py makes the ``NamedSharding``s): pools by
@@ -149,9 +160,11 @@ def init_paged_cache(model, num_slots: int, num_pages: int, page_len: int,
     ``num_slots`` rows, its pushed leaves int32 ``[num_slots]`` (the table
     ``[num_slots, pages_per_slot]``, 0 = unreached/null), by its row of
     :data:`FORMATS`; widths and dtypes are what the model's modules make for
-    a plain cache."""
+    a plain cache; a window layer's ring is sized by the window and the
+    chunk (``LMConfig.window_ring_len``), whatever a slot's length."""
     cfg = LMConfig.from_dict(
         {**model.config.to_dict(), "max_seq_len": page_len})
+    ring = cfg.window_ring_len(page_len)
     plain = jax.eval_shape(lambda: model.clone(config=cfg).init(
         jax.random.PRNGKey(0), jnp.zeros((num_slots, 1), jnp.int32),
         decode=True))["cache"]
@@ -160,8 +173,9 @@ def init_paged_cache(model, num_slots: int, num_pages: int, page_len: int,
         fmt = FORMATS[layer_kind(made)]
         out = {leaf: jnp.zeros((num_pages, page_len, made[leaf].shape[-1]),
                                made[leaf].dtype) for leaf in fmt.pools}
-        out.update({leaf: jnp.zeros(made[leaf].shape, made[leaf].dtype)
-                    for leaf in fmt.rows})
+        out.update({leaf: jnp.zeros(
+            (num_slots, ring, made[leaf].shape[-1]) if leaf in _RING
+            else made[leaf].shape, made[leaf].dtype) for leaf in fmt.rows})
         out.update({leaf: jnp.zeros(
             (num_slots, pages_per_slot) if leaf == BLOCK_TABLE
             else (num_slots,), jnp.int32) for leaf in fmt.pushed})
@@ -170,10 +184,25 @@ def init_paged_cache(model, num_slots: int, num_pages: int, page_len: int,
     return map_layers(plain, paged)
 
 
-def recurrent_state_bytes(cache) -> int:
-    """Bytes of per-slot state the cache holds that is not pages."""
+def _leaf_bytes(cache, names) -> int:
     return sum(v.size * v.dtype.itemsize for _, layer in layers(cache)
-               for k, v in layer.items() if k in _ROWS)
+               for k, v in layer.items() if k in names)
+
+
+def recurrent_state_bytes(cache) -> int:
+    """Bytes of the per-slot recurrent state (Mamba layers' rows) the cache
+    holds beside its pages."""
+    return _leaf_bytes(cache, _ROWS - _RING)
+
+
+def window_ring_bytes(cache) -> int:
+    """Bytes of the window layers' rings."""
+    return _leaf_bytes(cache, _RING)
+
+
+def page_pool_bytes(cache) -> int:
+    """Bytes of every page pool (what the block tables reach)."""
+    return _leaf_bytes(cache, _POOLS)
 
 
 def state_rows_move_in_place(cache) -> bool:
@@ -210,8 +239,8 @@ def push_chunk(cache, p0, last_local, table_row, slot=None):
     def state_row(v):
         if slot is None:
             raise ValueError(
-                "a model with recurrent layers keeps state a slot: the "
-                "chunk program needs slot=")
+                "a model with recurrent or window layers keeps rows a slot: "
+                "the chunk program needs slot=")
         return jnp.full(v.shape, jnp.asarray(slot).astype(jnp.int32),
                         jnp.int32)
 

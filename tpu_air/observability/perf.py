@@ -417,6 +417,12 @@ class LMCostModel:
     2)*D*b`` bytes of streams a token: the state read once and written
     once, the sublayer's input written and its output read.
 
+    Window layers beside full ones (``layer_kinds()`` holds ``"window"``):
+    each of the ``W`` window layers has q and o at ``window_n_heads`` heads
+    and keeps K and V like a full layer, but a token reads at most
+    ``sliding_window`` positions of it, whatever the context; ``attn_gate``
+    adds ``D*H`` a layer of either kind.
+
     Norms, rotary embeddings and softmax are omitted (≪1% of the matmul
     budget at any real geometry); the model is deliberately closed-form so
     identical claims can be recomputed anywhere (arXiv:2204.06514 §4).
@@ -443,6 +449,11 @@ class LMCostModel:
         self.n_mamba2_layers = kinds.count("mamba2")
         self.n_attn_layers = (kinds.count("attention")
                               + kinds.count("latent"))
+        self.n_window_layers = kinds.count("window")
+        self.window_n_heads = int(getattr(config, "window_n_heads", None)
+                                  or self.n_heads)
+        self.sliding_window = int(getattr(config, "sliding_window", 0) or 0)
+        self.attn_gate = getattr(config, "attn_gate", "none") != "none"
         self.mamba_n_heads = int(getattr(config, "mamba_n_heads", 0) or 0)
         self.mamba_n_groups = int(getattr(config, "mamba_n_groups", 1) or 1)
         self.d_inner = int(getattr(config, "mamba_d_inner", 0) or 0)
@@ -471,7 +482,8 @@ class LMCostModel:
         self.qk_rope = int(getattr(config, "qk_rope_head_dim", 0) or 0)
         self.v_head_dim = int(getattr(config, "v_head_dim", 0) or 0)
         self.hc_mult = int(getattr(config, "hc_mult", 1) or 1)
-        self.n_sublayers = (self.n_attn_layers + self.n_mamba_layers
+        self.n_sublayers = (self.n_attn_layers + self.n_window_layers
+                            + self.n_mamba_layers
                             + self.n_mamba2_layers + self.n_sparse_layers
                             + self.n_dense_layers)
 
@@ -485,8 +497,12 @@ class LMCostModel:
                     + d * (r + self.qk_rope)
                     + r * h * (self.qk_nope + self.v_head_dim)
                     + h * self.v_head_dim * d)
-        return 2 * self.d_model * self.head_dim * (
-            self.n_heads + self.n_kv_heads)
+        return self._qkvo_params(self.n_heads)
+
+    def _qkvo_params(self, heads: int) -> int:
+        """q and o at ``heads`` heads, k and v at the K/V heads, the gate."""
+        return (2 * self.d_model * self.head_dim * (heads + self.n_kv_heads)
+                + self.d_model * heads * self.attn_gate)
 
     @property
     def _mamba_params(self) -> int:
@@ -553,6 +569,8 @@ class LMCostModel:
                   + self.d_model * self.num_experts)
         dense = self.ff_matrices * self.d_model * self.dense_d_ff
         return (self.n_attn_layers * self._attn_params
+                + self.n_window_layers * self._qkvo_params(
+                    self.window_n_heads)
                 + self.n_mamba_layers * self._mamba_params
                 + self.n_mamba2_layers * self._mamba2_params
                 + self.n_sparse_layers * sparse
@@ -622,12 +640,28 @@ class LMCostModel:
         return self.n_attn_layers * 2 * self.n_kv_heads * self.head_dim \
             * self.dtype_bytes
 
-    def attention_flops(self, attended_positions: float) -> float:
-        """Per ONE token attending over ``attended_positions``."""
+    @property
+    def window_kv_bytes_per_position(self) -> float:
+        """K and V bytes a position keeps over the WINDOW layers (a token
+        reads at most ``sliding_window`` positions of them)."""
+        return self.n_window_layers * 2 * self.n_kv_heads * self.head_dim \
+            * self.dtype_bytes
+
+    def _window_attended(self, attended: float, tokens: float = 1) -> float:
+        """Of ``attended`` positions summed over ``tokens`` tokens, those a
+        window layer's reads reach."""
+        return min(attended, tokens * self.sliding_window)
+
+    def attention_flops(self, attended_positions: float,
+                        tokens: float = 1) -> float:
+        """``tokens`` tokens (default ONE) attending ``attended_positions``
+        positions between them."""
         width = (self.kv_lora_rank + self.qk_rope if self.kv_lora_rank
                  else self.head_dim)
-        return self.n_attn_layers * 4.0 * self.n_heads * width \
-            * attended_positions
+        return 4.0 * width * (
+            self.n_attn_layers * self.n_heads * attended_positions
+            + self.n_window_layers * self.window_n_heads
+            * self._window_attended(attended_positions, tokens))
 
     # -- program costs -------------------------------------------------------
     def decode_step_cost(self, rows: int, attended: int) -> ProgramCost:
@@ -639,7 +673,10 @@ class LMCostModel:
                         + self.attention_flops(attended))
         hbm = (self.streamed_param_bytes(rows)
                + rows * attended * self.kv_bytes_per_position   # KV read
-               + rows * self.kv_bytes_per_position              # KV write
+               + rows * self._window_attended(attended)
+               * self.window_kv_bytes_per_position              # ring read
+               + rows * (self.kv_bytes_per_position             # KV write
+                         + self.window_kv_bytes_per_position)
                + 2 * rows * self.state_bytes_per_row            # state r+w
                + rows * self.mhc_stream_bytes_per_token)
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=rows)
@@ -653,10 +690,13 @@ class LMCostModel:
         c = int(chunk_len)
         attended_sum = c * start_pos + c * (c + 1) / 2.0
         flops = (c * self.linear_flops_per_token
-                 + self.attention_flops(attended_sum))
+                 + self.attention_flops(attended_sum, c))
         hbm = (self.streamed_param_bytes(c)
                + (start_pos + c) * self.kv_bytes_per_position   # prefix read
-               + c * self.kv_bytes_per_position                 # KV write
+               + min(start_pos + c, self.sliding_window + c)
+               * self.window_kv_bytes_per_position              # ring read
+               + c * (self.kv_bytes_per_position                # KV write
+                      + self.window_kv_bytes_per_position)
                + 2 * self.state_bytes_per_row                   # one row
                + c * self.mhc_stream_bytes_per_token)
         return ProgramCost(flops=flops, hbm_bytes=hbm, tokens=c)
