@@ -48,17 +48,20 @@ def train_flops_per_token(cfg: Dict[str, Any], enc_len: int,
 
 
 def decode_step_bytes(cfg: Dict[str, Any], batch: int, enc_len: int,
-                      max_decode_len: int, bytes_el: int = 2) -> Dict[str, int]:
+                      self_len: float, bytes_el: int = 2) -> Dict[str, float]:
     """Bytes ONE cached decode step must stream from HBM (copied from
     bench.py ``_decode_step_bytes``, full-width caches): the cross-attention
-    K/V cache, read in full; the self-attention slabs (the step reads the
-    whole slab, padded to ``max_decode_len``); the decoder-side parameters
-    and the head matrix.  Activations at query length 1 are negligible."""
+    K/V cache, read in full; the self-attention slabs at ``self_len``, the
+    positions the step reads (written so far, its own among them: for the
+    MEAN step of a call the mean written length, not the slab's capacity;
+    since PR 59 ``generate``'s loop reads that prefix alone); the
+    decoder-side parameters and the head matrix.  Activations at query length
+    1 are negligible."""
     h = cfg["num_heads"] * cfg["d_kv"]
     layers = cfg["num_decoder_layers"]
     d, ff = cfg["d_model"], cfg["d_ff"]
     cross_kv = 2 * batch * enc_len * h * bytes_el * layers
-    self_kv = 2 * batch * max_decode_len * h * bytes_el * layers
+    self_kv = 2 * batch * self_len * h * bytes_el * layers
     # per layer: self q/k/v/o + cross q/o (cross k/v are cached) + FFN
     p_layer = 4 * d * h + 2 * d * h + _ffn_mats(cfg) * d * ff
     params = (layers * p_layer + d * cfg["vocab_size"]) * bytes_el

@@ -1,8 +1,8 @@
 """What a hybrid state-space / attention decoder (``model_type: jamba``)
-needs, from the published shapes alone: the bytes one paged decode step
-streams, and the bytes of the recurrent state it reads and writes.  Counted
-from the configuration file's dict (``cfg``); a change to the program cannot
-move them.
+needs: the bytes one paged decode step must move for the rows and cached
+positions it has live, and the bytes of the recurrent state it reads and
+writes.  Counted from the configuration file's dict (``cfg``) and the run's
+live counts; a change to the program cannot move them.
 """
 
 from __future__ import annotations
@@ -36,8 +36,8 @@ def attention_mixer_params(cfg: Dict[str, Any]) -> int:
     return 2 * d * d + 2 * d * cfg["num_key_value_heads"] * hd
 
 
-def state_bytes(cfg: Dict[str, Any], slots: int, state_el: int = 4,
-                tail_el: int = 2) -> int:
+def state_bytes(cfg: Dict[str, Any], slots: float, state_el: int = 4,
+                tail_el: int = 2) -> float:
     """The recurrent state of ``slots`` sequences over all Mamba layers: the
     float32 state ``[d_inner, d_state]`` and the bf16 convolution tail
     ``[d_conv - 1, d_inner]`` of each."""
@@ -47,19 +47,22 @@ def state_bytes(cfg: Dict[str, Any], slots: int, state_el: int = 4,
     return layer_counts(cfg)["mamba"] * slots * per_row
 
 
-def decode_step_bytes(cfg: Dict[str, Any], slots: int, slot_len: int,
-                      bytes_el: int = 2) -> Dict[str, int]:
-    """Bytes ONE decode step over ``slots`` rows streams from HBM:
+def live_step_bytes(cfg: Dict[str, Any], rows_live: float,
+                    positions_live: float,
+                    bytes_el: int = 2) -> Dict[str, float]:
+    """Bytes ONE decode step MUST move through HBM for what it has live:
 
     * every weight once: the Mamba mixers, the attention mixers, one SwiGLU a
       layer (``num_experts`` 1), the tied head (the embedding read as the
-      head's matrix; the ``slots`` rows gathered for the input, and the
-      norms, are left out);
-    * the recurrent state of every slot TWICE, read and written: an idle
-      slot's row is computed, and held, like a live one's;
-    * K and V of the attention layers for every slot at the full
-      ``slot_len``: what the paged step is compiled to read
-      (ops/decode_attention.gather_pages over the whole block table).
+      head's matrix; the rows gathered for the input, and the norms, are
+      left out);
+    * the recurrent state of the ``rows_live`` rows whose state the step
+      ADVANCED, TWICE (read and written): an idle slot's state need not move,
+      whatever the program passes over;
+    * K and V of the attention layers at ``positions_live``: the cached
+      positions those rows hold, summed over the rows, whatever the pool
+      could hold and whatever the program gathers (``costs_ssd.
+      decode_step_bytes`` counts a Mamba-2 rank the same way).
     """
     d, f = cfg["hidden_size"], cfg["intermediate_size"]
     kinds = layer_counts(cfg)
@@ -68,10 +71,20 @@ def decode_step_bytes(cfg: Dict[str, Any], slots: int, slot_len: int,
     attention = kinds["attention"] * attention_mixer_params(cfg) * bytes_el
     mlp = cfg["num_hidden_layers"] * 3 * d * f * bytes_el
     head = d * cfg["vocab_size"] * bytes_el
-    state = 2 * state_bytes(cfg, slots)
-    kv = (kinds["attention"] * 2 * slots * slot_len
+    state = 2 * state_bytes(cfg, rows_live)
+    kv = (kinds["attention"] * 2 * positions_live
           * cfg["num_key_value_heads"] * hd * bytes_el)
     return {"mamba_weight_bytes": mamba, "attention_weight_bytes": attention,
             "mlp_bytes": mlp, "head_bytes": head, "state_bytes": state,
             "kv_bytes": kv,
             "total_bytes": mamba + attention + mlp + head + state + kv}
+
+
+def decode_step_bytes(cfg: Dict[str, Any], slots: int, slot_len: int,
+                      bytes_el: int = 2) -> Dict[str, float]:
+    """:func:`live_step_bytes` with every slot live and full to ``slot_len``:
+    what the pool and the state could hold.  No metric's reader prices a
+    step at it since PR 61; it stays for ``tests/test_benchmark_ssm.py`` and
+    the reader that file pins (``readers/ssm_hbm_share.py``), until a PR
+    that may edit the test moves it to :func:`live_step_bytes` (PERF.md 7)."""
+    return live_step_bytes(cfg, slots, slots * slot_len, bytes_el)

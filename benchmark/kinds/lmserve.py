@@ -18,7 +18,8 @@ from typing import Any, Dict
 import numpy as np
 
 from benchmark import stats, weights_lm
-from benchmark.kinds.serve import _free_port, _post, offer_load, summarize
+from benchmark.kinds.serve import (_free_port, _post, offer_load,
+                                   read_per_step, summarize)
 
 __all__ = ["deploy", "offer_load", "summarize", "run"]
 
@@ -182,6 +183,12 @@ def run(ctx) -> None:
               f"differs by a median {np.median(low):.4f}: the limit {tol} "
               "would pass a system computing in that precision")
     facts2 = tpu_air.get(handle.method("bench_facts")())
+    # what the CAPTURED steps had live, by the program that ran them: the
+    # replica's count over the profiler's own window (a traced run alone
+    # asks for it, and alone has a watch to ask)
+    seen = (tpu_air.get(handle.method("bench_traced_counts")())
+            if ctx.trace else {})
+    kv_per_step = read_per_step(seen, "kv_positions_read")
 
     late95 = stats.percentile(summary["client_late_ms"], 0.95)
     if late95 is not None and late95 > float(t["poll_ms"]):
@@ -213,6 +220,7 @@ def run(ctx) -> None:
         "page_len": int(t["page_len"]),
         "moe_load_max_over_mean": float(per_expert.max()
                                         / per_expert.mean()),
+        "lm_kv_positions_per_step": kv_per_step,
         "moe_experts_streamed_per_layer_step": (
             (stats1["moe_experts_streamed"]
              - stats0.get("moe_experts_streamed", 0))
@@ -252,4 +260,7 @@ def run(ctx) -> None:
         check_gap_p10=float(np.quantile(gap, 0.1)),
         check_lowprec_err_p50=float(np.median(low)),
         check_lowprec_err_min=float(low.min()),
+        counts_of=seen,
+        lm_kv_positions_per_step=kv_per_step,
+        lm_rows_per_step=read_per_step(seen, "rows_read"),
         traced=load["traced"])

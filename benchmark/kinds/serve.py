@@ -129,6 +129,20 @@ def offer_load(ctx, handle, port: int, params: Dict[str, Any], seed: int,
             "started_at": time.time() - (time.monotonic() - start_at)}
 
 
+def read_per_step(seen: Dict[str, int], key: str) -> Dict[str, float]:
+    """``key`` a step, by the program that ran the step, of what the
+    replica's ``worker_hooks.ReadWatch`` counted over the profiler's window
+    (``bench_traced_counts``): the decode program alone, and the mixed step
+    that carried a chunk; a program none of whose steps was read is left
+    out, and without a watch's counts (an untraced run) so are both."""
+    alone = (seen.get("steps_read_alone", 0), seen.get(key + "_alone", 0))
+    mixed = (seen.get("steps_read", 0) - alone[0],
+             seen.get(key, 0) - alone[1])
+    return {program: value / steps for program, (steps, value) in (
+        ("lm_paged_decode_step", alone), ("lm_paged_mixed_step", mixed))
+        if steps}
+
+
 def summarize(rows: List[Dict[str, Any]], seconds: float,
               drain_s: float) -> Dict[str, Any]:
     """Client-side series.  A request that failed, was shed or did not

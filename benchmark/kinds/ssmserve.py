@@ -20,7 +20,8 @@ from typing import Any, Dict
 import numpy as np
 
 from benchmark import stats
-from benchmark.kinds.serve import _free_port, _post, offer_load, summarize
+from benchmark.kinds.serve import (_free_port, _post, offer_load,
+                                   read_per_step, summarize)
 
 __all__ = ["deploy", "offer_load", "summarize", "run"]
 
@@ -204,6 +205,15 @@ def run(ctx) -> None:
               "pass a system that keeps the state-space state in that "
               "precision")
     facts2 = tpu_air.get(handle.method("bench_facts")())
+    # what a CAPTURED step had live: the engine's own counters over the
+    # profiler's window (rows whose state it advanced, the positions they
+    # held, as issued: not split by program), a traced run alone
+    seen = (tpu_air.get(handle.method("bench_traced_counts")())
+            if ctx.trace else {})
+    issued = seen.get("steps_issued", 0)
+    live = ({"ssm_rows_live_per_step": seen["ssd_rows_live"] / issued,
+             "ssm_positions_live_per_step":
+                 seen["ssd_positions_live"] / issued} if issued else {})
 
     late95 = stats.percentile(summary["client_late_ms"], 0.95)
     if late95 is not None and late95 > float(t["poll_ms"]):
@@ -234,6 +244,7 @@ def run(ctx) -> None:
         "num_slots": int(t["num_slots"]), "slot_len": int(t["slot_len"]),
         "page_len": int(t["page_len"]),
         "ssm_state_bytes": stats1.get("ssm_state_bytes"),
+        **live,
         "memory_peak_bytes": facts2.get("memory_peak_bytes"),
         "worker_compile_s": facts2["compile_s"],
         "worker_cold_compiles": facts2["cold_compiles"],
@@ -281,4 +292,10 @@ def run(ctx) -> None:
         check_answer_lens=[len(a) for a in answers],
         check_slots=slots,
         check_seconds=verdicts[0].get("seconds"),
+        # the replica's own watch beside the engine's counters: the same
+        # steps as they were READ, by program
+        counts_of=seen, **live,
+        watched_rows_per_step=read_per_step(seen, "rows_read"),
+        watched_kv_positions_per_step=read_per_step(
+            seen, "kv_positions_read"),
         traced=load["traced"])

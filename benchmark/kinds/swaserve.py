@@ -32,7 +32,8 @@ import numpy as np
 from benchmark import stats
 from benchmark.kinds.mhcserve import reference_verdicts
 from benchmark.kinds.mlaserve import _weights_seed
-from benchmark.kinds.serve import _free_port, _post, offer_load, summarize
+from benchmark.kinds.serve import (_free_port, _post, offer_load,
+                                   read_per_step, summarize)
 
 __all__ = ["deploy", "offer_load", "summarize", "run"]
 
@@ -354,6 +355,10 @@ def step_facts(ctx, call, delta) -> Dict[str, Any]:
         if count}
     return {
         "counts_of": seen, "counts_over": over,
+        # the replica's ReadWatch beside the engine's own count of the page
+        # positions (x the full layers): for the record, read by no metric
+        "watched_kv_positions_per_step": read_per_step(
+            seen, "kv_positions_read"),
         "swa_experts_streamed_per_step": by_program(
             "moe_experts_streamed", "moe_steps", "moe_steps_alone"),
         # counted as a step is READ, as the experts are: the same steps

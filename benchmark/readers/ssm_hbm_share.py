@@ -6,7 +6,14 @@ K/V at the full ``slot_len``) at the peak bandwidth, over the median device
 time of one execution of the decode program (``module``, found on the
 capture's ``XLA Modules`` line as ``module_hbm_share`` finds it).  Not this
 family's configuration, no such line or no such program (an older tree):
-nothing to read."""
+nothing to read.
+
+NO METRIC NAMES THIS READER since PR 61: it prices every slot's state and
+K/V at ``slot_len`` over a MEDIAN time, where ``ssm_roofline`` (part
+``step``) reads what the captured steps had live, mean over mean.
+It stays because ``tests/test_benchmark_ssm.py`` (tier-1, not a benchmark
+PR's to edit) pins it: a PR that may edit that file moves the test, and the
+next benchmark PR deletes this file (PERF.md 7)."""
 
 from benchmark import costs_ssm, spans, stats
 from benchmark.readers.module_hbm_share import module_durations

@@ -9,7 +9,14 @@ capture's event metadata; by scope and not by kernel name, so it reads the
 same work whatever implements it).  An execution's time is the union of the
 intervals of its operations in the scope; the median over the executions that
 lie whole inside the capture is taken.  Not this family's configuration, no
-such program or no operation in the scope (an older tree): nothing to read."""
+such program or no operation in the scope (an older tree): nothing to read.
+
+NO METRIC NAMES THIS READER since PR 61: it prices every slot's state over
+a MEDIAN time, where ``ssm_roofline`` (part ``state_update``) reads the rows
+the captured steps advanced, mean over mean.
+It stays because ``tests/test_benchmark_ssm.py`` (tier-1, not a benchmark
+PR's to edit) pins it: a PR that may edit that file moves the test, and the
+next benchmark PR deletes this file (PERF.md 7)."""
 
 import re
 

@@ -102,7 +102,8 @@ def unscoped(plane, pid, runs, top):
 
 def report(path, module=None, depth=None, out=sys.stdout, ops=None,
            top_unscoped=0):
-    from benchmark import scopes
+    from benchmark import scopes, stats
+    from benchmark.readers.module_hbm_share import whole_executions
 
     planes = scopes.read(path)
     print(f"{path}: {os.path.getsize(path) / 1e6:.1f} MB, read in "
@@ -124,9 +125,14 @@ def report(path, module=None, depth=None, out=sys.stdout, ops=None,
         if module is None and busy < 0.01 * total:
             continue
         rows, ms = table(plane, pid, runs, depth)
+        whole = whole_executions(plane, name[len("jit_"):])
         print(f"\n{name}: {runs:.3g} executions, {ms:.3f} ms of operations an "
               f"execution, {100.0 * busy / total:.1f} % of the capture's "
-              "operation time", file=out)
+              f"operation time; {len(whole)} whole executions of mean "
+              f"{1e3 * sum(whole) / len(whole):.3f} ms, median "
+              f"{1e3 * stats.percentile(whole, 0.5):.3f} ms (what a "
+              "whole-step roofline divides its floor by: the mean)",
+              file=out)
         print(f"{'ms':>9} {'share %':>8} {'ops':>7} {'GB/s':>8} "
               f"{'TFLOP/s':>8}  scope", file=out)
         for path_, t, share, count, gbs, tfs in rows:

@@ -15,7 +15,7 @@ from __future__ import annotations
 import json
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from tpu_air.predict import T5GenerativePredictor
 from tpu_air.serve.deployment import Deployment
@@ -60,6 +60,68 @@ def _trace_for(trace_dir: str, seconds: float) -> Dict[str, float]:
     jax.profiler.stop_trace()
     return {"start_call_s": t1 - t0, "traced_from": t1,
             "traced_to": time.time()}
+
+
+class ReadWatch:
+    """What the decode steps READ while it is installed had live, by the
+    program that ran them: the steps, their decoding rows and the cached
+    positions those rows held.  ``InferenceEngine`` reads an issued step back
+    in ``_read(step, reading)``: ``reading`` the rows it decoded that still
+    hold their request, each at ``pos`` (a row at position ``p`` read ``p +
+    1`` positions of every attention layer: the sum the engine itself takes
+    for ``record_latent_live`` / ``record_kv_live`` where it keeps such a
+    count, a line above the walk that moves ``pos`` on), ``step.chunk_start``
+    None where the step carried no prefill chunk.  The watch shadows that
+    one bound method with a wrapper that adds the sums up and calls it, and
+    takes the wrapper off again: exact, and nothing of the engine changed.
+    Every counted capture (``_trace_with_counts``) keeps one, in a TRACED
+    run only (an untraced run never builds one): its steps by program say
+    when the capture holds both (:meth:`wants`), and its positions are the
+    K/V count of a model the engine's ``stats()`` give none for (neither
+    rings nor a latent pool).
+
+    An engine without ``_read`` (``T5Engine``, a later tree) is left alone
+    and :attr:`counts` stays empty: the readers then find nothing to read."""
+
+    KEYS = ("steps_read", "steps_read_alone", "rows_read", "rows_read_alone",
+            "kv_positions_read", "kv_positions_read_alone")
+
+    def __init__(self, engine):
+        self.engine = engine
+        self.counts: Dict[str, int] = {}
+
+    def _watched(self, step, reading):
+        rows = len(reading)
+        positions = sum(slot.pos + 1 for slot in reading)
+        c = self.counts
+        c["steps_read"] += 1
+        c["rows_read"] += rows
+        c["kv_positions_read"] += positions
+        if step.chunk_start is None:
+            c["steps_read_alone"] += 1
+            c["rows_read_alone"] += rows
+            c["kv_positions_read_alone"] += positions
+        return self._read(step, reading)
+
+    def wants(self, steps: int) -> bool:
+        """Whether the watch is on an engine it fits and has read fewer than
+        ``steps`` steps of either program so far."""
+        if not self.counts:
+            return False
+        alone = self.counts["steps_read_alone"]
+        return min(alone, self.counts["steps_read"] - alone) < steps
+
+    def __enter__(self):
+        self._read = getattr(self.engine, "_read", None)
+        if self._read is not None:
+            self.counts = dict.fromkeys(self.KEYS, 0)
+            self.engine._read = self._watched
+        return self
+
+    def __exit__(self, *exc):
+        if self._read is not None:
+            del self.engine._read
+        return False
 
 
 # -- fine-tune -------------------------------------------------------------------
@@ -153,15 +215,63 @@ class ObservedEngineServer(_EngineServer):
     window from a thread of its own, so the replica's message loop stays
     free while it is traced."""
 
+    #: ``stats()`` counters whose change over the profiler's window says what
+    #: the CAPTURED steps did (a kind's own; none: a bare capture)
+    TRACED_COUNTERS: Tuple[str, ...] = ()
+
     def bench_facts(self) -> Dict[str, Any]:
         self._ensure_engine()
         return device_facts()
 
     def bench_trace(self, trace_dir: str, seconds: float) -> bool:
         self._ensure_engine()
-        threading.Thread(target=_trace_for, args=(trace_dir, seconds),
-                         daemon=True).start()
+        threading.Thread(
+            target=self._trace_with_counts if self.TRACED_COUNTERS
+            else _trace_for, args=(trace_dir, seconds), daemon=True).start()
         return True
+
+    #: a capture ends after ``seconds`` once its :class:`ReadWatch` has read
+    #: this many steps of EACH program (the decode step alone, the mixed
+    #: step), and after ``CAPTURE_MOST`` times ``seconds`` whatever it read
+    CAPTURE_STEPS = 8
+    CAPTURE_MOST = 3.0
+
+    def _trace_with_counts(self, trace_dir: str, seconds: float) -> None:
+        """``_trace_for`` with the engine's ``TRACED_COUNTERS`` read, and a
+        :class:`ReadWatch` kept, once the capture has started and before it
+        is stopped: what the CAPTURED steps did (the profiler's own window,
+        not the run's average), which the roofline readers divide the
+        captured programs' time by.  Kept as soon as it is read.
+
+        The capture lasts ``seconds``, and longer, in tenths of a second up
+        to ``CAPTURE_MOST`` times that, while the watch has read fewer than
+        ``CAPTURE_STEPS`` steps of either program: a cell's per-layer
+        metrics read BOTH programs, and where the window's prefill backlog
+        keeps a chunk on every step for two seconds (``laguna-serve-
+        mixedlen``'s seconds 10 to 12 offer 101 chunks where 92 steps fit;
+        a capture asked for at 8 s that the host starts a second late is
+        there) a capture of fixed length holds no decode step and half the
+        metrics have nothing to read (PERF.md, PR 61)."""
+        import jax
+
+        engine = self._ensure_engine()
+        jax.profiler.start_trace(trace_dir)
+        with ReadWatch(engine) as watch:
+            before = engine.metrics.snapshot()
+            until = time.monotonic() + self.CAPTURE_MOST * seconds
+            time.sleep(seconds)
+            while watch.wants(self.CAPTURE_STEPS) and time.monotonic() < until:
+                time.sleep(0.1)
+            after = engine.metrics.snapshot()
+        self._traced = {k: after.get(k, 0) - before.get(k, 0)
+                        for k in self.TRACED_COUNTERS}
+        self._traced.update(watch.counts)
+        jax.profiler.stop_trace()
+
+    def bench_traced_counts(self) -> Dict[str, int]:
+        """What ``_trace_with_counts`` left (a kind with ``TRACED_COUNTERS``);
+        empty before a capture and in an untraced run."""
+        return dict(getattr(self, "_traced", {}))
 
     def bench_teacher_forced(self, prompts: List[List[int]],
                              answers: List[List[int]]) -> List[Dict[str, Any]]:

@@ -89,6 +89,14 @@ def paged_logits(model, params, page_len: int, prompts: List[List[int]],
 
 
 class ObservedLMEngineServer(ObservedEngineServer):
+    #: read inside the profiler's window (``_trace_with_counts``), beside the
+    #: K/V positions the captured steps had live (its ``ReadWatch``: the
+    #: engine's ``stats()`` hold no such count for a model that keeps
+    #: neither rings nor a latent pool)
+    TRACED_COUNTERS = ("moe_steps", "moe_steps_alone",
+                       "moe_experts_streamed", "moe_experts_streamed_alone",
+                       "steps_issued", "mixed_steps")
+
     def bench_reference_check(self, cfg: Dict[str, Any], seed: int,
                               dtype: str, prompts: List[List[int]],
                               answers: List[List[int]],

@@ -9,7 +9,6 @@ measured window, ON requests the window finished.
 
 from __future__ import annotations
 
-import threading
 from typing import Any, Dict, List
 
 import numpy as np
@@ -35,31 +34,12 @@ TRACED_COUNTERS = ("moe_steps", "moe_steps_alone", "moe_experts_streamed",
 
 
 class ObservedMHCEngineServer(ObservedMLAEngineServer):
-    def bench_trace(self, trace_dir: str, seconds: float) -> bool:
-        """``ObservedMLAEngineServer.bench_trace`` over this kind's
-        counters, kept as soon as they are read and not behind
-        ``stop_trace``: two traced runs of this cell (PR 58, the capture at
-        8 s, a warm compile cache) ended with the capture written and no
-        counts, and the kind then pairs the WHOLE window's counts with the
-        capture's times (a decode step's experts read 104.8 % and 123 % of
-        their roofline so)."""
-        import time
-
-        import jax
-
-        engine = self._ensure_engine()
-
-        def run():
-            jax.profiler.start_trace(trace_dir)
-            before = engine.metrics.snapshot()
-            time.sleep(seconds)
-            after = engine.metrics.snapshot()
-            self._traced = {k: after.get(k, 0) - before.get(k, 0)
-                            for k in TRACED_COUNTERS}
-            jax.profiler.stop_trace()
-
-        threading.Thread(target=run, daemon=True).start()
-        return True
+    #: read inside the profiler's window (``_trace_with_counts``, which keeps
+    #: them as soon as they are read and not behind ``stop_trace``: two
+    #: traced runs of this cell (PR 58) ended with the capture written and
+    #: no counts, and the kind then pairs the WHOLE window's counts with the
+    #: capture's times: a decode step's experts read 104.8 % and 123 % so)
+    TRACED_COUNTERS = TRACED_COUNTERS
 
     def bench_reference_check(self, cfg: Dict[str, Any], seed: int,
                               dtype: str, prompts: List[List[int]],

@@ -9,7 +9,6 @@ finished.
 
 from __future__ import annotations
 
-import threading
 from typing import Any, Dict, List
 
 import numpy as np
@@ -158,33 +157,10 @@ TRACED_COUNTERS = ("moe_steps", "moe_steps_alone", "moe_experts_streamed",
 
 
 class ObservedMLAEngineServer(ObservedEngineServer):
-    def bench_trace(self, trace_dir: str, seconds: float) -> bool:
-        """``ObservedEngineServer.bench_trace`` (the profiler's defaults,
-        ``seconds`` between start and stop), with the engine's counters read
-        once the capture has started and before it is stopped: the per-step
-        counts the roofline readers divide the CAPTURED programs' time by.
-        Starting and stopping the profiler take seconds in which the engine
-        steps on; counts over those would be another stretch's."""
-        import time
-
-        import jax
-
-        engine = self._ensure_engine()
-
-        def run():
-            jax.profiler.start_trace(trace_dir)
-            before = engine.metrics.snapshot()
-            time.sleep(seconds)
-            after = engine.metrics.snapshot()
-            jax.profiler.stop_trace()
-            self._traced = {k: after.get(k, 0) - before.get(k, 0)
-                            for k in TRACED_COUNTERS}
-
-        threading.Thread(target=run, daemon=True).start()
-        return True
-
-    def bench_traced_counts(self) -> Dict[str, int]:
-        return dict(getattr(self, "_traced", {}))
+    #: read inside the profiler's window (``_trace_with_counts``: starting
+    #: and stopping the profiler take seconds in which the engine steps on,
+    #: and counts over those would be another stretch's)
+    TRACED_COUNTERS = TRACED_COUNTERS
 
     def bench_reference_check(self, cfg: Dict[str, Any], seed: int,
                               dtype: str, prompts: List[List[int]],

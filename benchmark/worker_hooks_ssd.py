@@ -9,7 +9,6 @@ finished.
 
 from __future__ import annotations
 
-import threading
 from typing import Any, Dict, List, Tuple
 
 import numpy as np
@@ -141,31 +140,8 @@ def slow_heads(pub: "weights_nemotron.Published", layer: int) -> np.ndarray:
 
 
 class ObservedSSDEngineServer(ObservedEngineServer):
-    def bench_trace(self, trace_dir: str, seconds: float) -> bool:
-        """``ObservedEngineServer.bench_trace`` with the engine's counters
-        read once the capture has started and before it is stopped (as
-        ``worker_hooks_mla`` does): the per-step counts the roofline readers
-        divide the CAPTURED programs' time by."""
-        import time
-
-        import jax
-
-        engine = self._ensure_engine()
-
-        def run():
-            jax.profiler.start_trace(trace_dir)
-            before = engine.metrics.snapshot()
-            time.sleep(seconds)
-            after = engine.metrics.snapshot()
-            jax.profiler.stop_trace()
-            self._traced = {k: after.get(k, 0) - before.get(k, 0)
-                            for k in TRACED_COUNTERS}
-
-        threading.Thread(target=run, daemon=True).start()
-        return True
-
-    def bench_traced_counts(self) -> Dict[str, int]:
-        return dict(getattr(self, "_traced", {}))
+    #: read inside the profiler's window (``_trace_with_counts``)
+    TRACED_COUNTERS = TRACED_COUNTERS
 
     def bench_reference_check(self, cfg: Dict[str, Any], seed: int,
                               dtype: str, prompts: List[List[int]],

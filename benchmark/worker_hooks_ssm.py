@@ -128,6 +128,11 @@ def replayed_logits(engine, prompts: List[List[int]],
 
 
 class ObservedSSMEngineServer(ObservedEngineServer):
+    #: read inside the profiler's window (``_trace_with_counts``): the rows
+    #: whose state a step advanced and the positions they held, as issued
+    TRACED_COUNTERS = ("steps_issued", "mixed_steps", "ssd_rows_live",
+                       "ssd_positions_live", "ssm_rows_held")
+
     def bench_reference_check(self, cfg: Dict[str, Any], seed: int,
                               dtype: str, prompts: List[List[int]],
                               answers: List[List[int]], slots: List[int],

@@ -10,7 +10,6 @@ window finished.
 
 from __future__ import annotations
 
-import threading
 from typing import Any, Dict, List
 
 import numpy as np
@@ -26,14 +25,6 @@ from benchmark.worker_hooks_mla import round_mantissa
 #: each tensor): each keeps its float32 rows and a layer's q, k, v on the
 #: device, 0.25 GB at 4,096 positions, beside an engine that holds 11.6 GB
 REFERENCE_TOGETHER = 3
-
-#: ``stats()`` counters whose change over the profiler's window says what
-#: the CAPTURED steps did (the window's own, not the run's average)
-TRACED_COUNTERS = ("moe_steps", "moe_steps_alone", "moe_experts_streamed",
-                   "moe_experts_streamed_alone", "window_positions_live",
-                   "window_positions_live_alone", "kv_page_positions_live",
-                   "kv_page_positions_live_alone", "steps_issued",
-                   "mixed_steps")
 
 
 def replayed_logits(engine, prompts: List[List[int]],
@@ -134,32 +125,16 @@ def replayed_logits(engine, prompts: List[List[int]],
 
 
 class ObservedSWAEngineServer(ObservedEngineServer):
-    def bench_trace(self, trace_dir: str, seconds: float) -> bool:
-        """``ObservedEngineServer.bench_trace`` (the profiler's defaults,
-        ``seconds`` between start and stop), with the engine's counters read
-        once the capture has started and before it is stopped, and kept as
-        soon as they are read: the per-step counts the roofline readers
-        divide the CAPTURED programs' time by."""
-        import time
-
-        import jax
-
-        engine = self._ensure_engine()
-
-        def run():
-            jax.profiler.start_trace(trace_dir)
-            before = engine.metrics.snapshot()
-            time.sleep(seconds)
-            after = engine.metrics.snapshot()
-            self._traced = {k: after.get(k, 0) - before.get(k, 0)
-                            for k in TRACED_COUNTERS}
-            jax.profiler.stop_trace()
-
-        threading.Thread(target=run, daemon=True).start()
-        return True
-
-    def bench_traced_counts(self) -> Dict[str, int]:
-        return dict(getattr(self, "_traced", {}))
+    #: read inside the profiler's window (``_trace_with_counts``): the
+    #: per-step counts the roofline readers divide the CAPTURED programs'
+    #: time by; its ``ReadWatch`` counts the page positions a second time,
+    #: for the run's notes to set beside the engine's own
+    TRACED_COUNTERS = ("moe_steps", "moe_steps_alone",
+                       "moe_experts_streamed", "moe_experts_streamed_alone",
+                       "window_positions_live", "window_positions_live_alone",
+                       "kv_page_positions_live",
+                       "kv_page_positions_live_alone", "steps_issued",
+                       "mixed_steps")
 
     def bench_reference_check(self, cfg: Dict[str, Any], seed: int,
                               dtype: str, prompts: List[List[int]],
